@@ -1,6 +1,6 @@
 """Per-component timing breakdown of the training step on the real chip.
 
-The headline bench (bench.py) reports one number for the whole update; this
+A whole-step benchmark reports one number for the whole update; this
 script decomposes it so an MFU gap can be attributed to a specific stage
 (forward, backward, optimizer, attention impl, CE chunking) instead of
 guessed at.  Measurement is `telemetry.attribution`'s shared path —
@@ -24,7 +24,6 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
-import os
 import sys
 import time
 from pathlib import Path
@@ -33,7 +32,6 @@ import numpy as np
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from _accel import accelerator_up  # noqa: E402  (benchmarks/_accel.py)
 
 
 def main() -> int:
@@ -67,20 +65,21 @@ def main() -> int:
         "--mfu-push", action="store_true",
         help="training-MFU knob matrix (ISSUE 13): one full_step row per "
         "(remat_policy, grads_dtype, scan_layers) combination with "
-        "implied tok/s + mfu + peak_hbm_bytes, so the tpu_queue "
-        "self-report can diff each knob against the BENCH_r04 headline",
+        "implied tok/s + mfu + peak_hbm_bytes, so `bpe-tpu report "
+        "--compare` can diff each knob against a headline capture",
     )
     args = parser.parse_args()
 
-    # BREAKDOWN_ALLOW_CPU=1 is a functional smoke for the script itself
-    # (CI/dev); rows it emits carry platform "cpu" and the queue's run_job
-    # discards them, so they can never pollute TPU evidence.
-    if os.environ.get("BREAKDOWN_ALLOW_CPU") != "1" and not accelerator_up():
-        print("accelerator unreachable; refusing to record CPU numbers", file=sys.stderr)
-        return 3
-
     import jax
     import jax.numpy as jnp
+
+    from bpe_transformer_tpu.utils.chip_probe import require_tpu
+    from bpe_transformer_tpu.utils.compile_cache import enable_compile_cache
+
+    # JAX_PLATFORMS=cpu given explicitly is a functional smoke for the
+    # script itself; the rows it emits carry platform "cpu".
+    require_tpu(Path(__file__).stem)
+    cache_dir = enable_compile_cache()
 
     import bpe_transformer_tpu.models as models
     from bpe_transformer_tpu.models import init_params
@@ -161,8 +160,7 @@ def main() -> int:
         # Training-MFU knob matrix: the graduated remat ladder at f32
         # grads, then the bf16-collective and scan-layers combinations on
         # the selective-recompute point.  Each row carries implied tok/s +
-        # mfu so the queue's jax-free self-report can diff it against the
-        # BENCH_r04 headline capture without re-deriving geometry.
+        # mfu so a reader can diff rows without re-deriving geometry.
         from bpe_transformer_tpu.utils.flops import mfu as mfu_of
 
         matrix = [
@@ -207,12 +205,11 @@ def main() -> int:
         )
         key = jax.random.PRNGKey(1)
         n_long = 33  # per-token cost = (t(33) - t(1)) / 32
-        # Honesty marker for the compile row: the queue's persistent
-        # compile cache means a RETRY measures a warm "compile" — record
-        # how many cache entries existed so the row is self-describing.
-        cache_dir = Path(os.environ.get("JAX_COMPILATION_CACHE_DIR", ""))
+        # Honesty marker for the compile row: the persistent compile
+        # cache means a RETRY measures a warm "compile" — record how many
+        # cache entries existed so the row is self-describing.
         ccache_entries = (
-            len(list(cache_dir.iterdir())) if cache_dir.is_dir() else 0
+            len(list(cache_dir.iterdir())) if cache_dir is not None else 0
         )
         for impl in ("xla", "pallas"):
             cfg_d = dataclasses.replace(base, decode_attention_impl=impl)

@@ -31,6 +31,13 @@ a serve replica from process spawn to first token THROUGH the router's
 rejoin path, cold versus ``bpe-tpu warmup``-warmed compile cache — one
 JSON row with ``cold_s``/``warm_s``/``warmup_s``.
 
+One process per chip: the in-process modes import jax in THIS process and
+start no child; the subprocess modes (``--restart``, ``--controller*``)
+keep this process jax-free — the random-weight checkpoint is written by a
+short CPU child (`_write_random_checkpoint`) and the ``bpe-tpu serve``
+children own the chip.  Compile caches follow the one rule in
+``utils/compile_cache.py`` (no per-run temporary cache directory).
+
 Run on a TPU host:  python benchmarks/bench_serving.py [--qps 8 --paged]
 Prints one JSON line per cell.
 """
@@ -43,13 +50,10 @@ import sys
 import time
 from pathlib import Path
 
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+REPO_ROOT = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(REPO_ROOT))
 
-from _accel import require_accelerator  # noqa: E402  (benchmarks/_accel.py)
-
-import numpy as np
-
-import jax
+import numpy as np  # noqa: E402
 
 CONFIGS = {
     "tinystories-4l": "TINYSTORIES_4L",
@@ -568,48 +572,71 @@ def _serve_flags(args) -> list:
     return flags
 
 
+#: Run by a short child on the CPU backend: the parent of the subprocess
+#: modes never imports jax (a parent that touched it would hold the chip
+#: its `bpe-tpu serve` children need).
+_CHECKPOINT_CHILD = """
+import dataclasses, sys
+import jax
+import bpe_transformer_tpu.models as models
+from bpe_transformer_tpu.checkpointing import save_checkpoint
+config = getattr(models, sys.argv[2])
+save_checkpoint(
+    sys.argv[1],
+    params=models.init_params(jax.random.PRNGKey(0), config),
+    extra={"model_config": dataclasses.asdict(config)},
+)
+"""
+
+
+def _write_random_checkpoint(workdir: Path, config_attr: str):
+    """Random-weight checkpoint + byte-level tokenizer files under
+    ``workdir`` for the subprocess modes; returns ``(ckpt, tok_dir)``."""
+    import os
+    import pickle
+    import subprocess
+
+    ckpt = workdir / "model.ckpt"
+    subprocess.run(
+        [sys.executable, "-c", _CHECKPOINT_CHILD, str(ckpt), config_attr],
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "PYTHONPATH": str(REPO_ROOT)},
+        check=True, timeout=600,
+    )
+    tok_dir = workdir / "tok"
+    tok_dir.mkdir()
+    with open(tok_dir / "vocab.pkl", "wb") as f:
+        pickle.dump({i: bytes([i]) for i in range(256)}, f)
+    with open(tok_dir / "merges.pkl", "wb") as f:
+        pickle.dump([], f)
+    return ckpt, tok_dir
+
+
 def run_restart(args) -> dict:
     """Restart-to-traffic (ROADMAP item 5): time a replica from SPAWN to
     first token served THROUGH the router's rejoin path, cold (empty
     compile cache) vs `bpe-tpu warmup`-warmed — the rolling-deploy number
-    a fleet operator actually waits on.  The parent stays on CPU (jax
-    init would hold the accelerator the child serve needs); the router is
-    the in-process jax-free `serving.router.Router` driven by hand."""
-    import dataclasses
+    a fleet operator actually waits on.  This process stays jax-free (a
+    parent that touched jax would hold the chip the child serve needs);
+    the router is the in-process jax-free `serving.router.Router` driven
+    by hand.  "Cold" is a child with the persistent cache switched off
+    (``JAX_ENABLE_COMPILATION_CACHE=false``); "warm" is a child that finds
+    what `bpe-tpu warmup` left in the directory the one cache rule
+    resolves — no temporary cache directory anywhere."""
     import os
-    import pickle
     import shutil
     import signal
     import subprocess
     import tempfile
 
-    child_jax_platforms = os.environ.get("JAX_PLATFORMS")
-    os.environ["JAX_PLATFORMS"] = "cpu"  # parent: params init only
-
-    import jax as _jax
-
-    import bpe_transformer_tpu.models as models
-    from bpe_transformer_tpu.checkpointing import save_checkpoint
-    from bpe_transformer_tpu.models import init_params
     from bpe_transformer_tpu.serving.router import Router
 
-    config = getattr(models, CONFIGS[args.config])
     workdir = Path(tempfile.mkdtemp(prefix="bpe_restart_"))
     procs: list = []
     try:
-        ckpt = workdir / "model.ckpt"
-        save_checkpoint(
-            ckpt,
-            params=init_params(_jax.random.PRNGKey(0), config),
-            extra={"model_config": dataclasses.asdict(config)},
+        ckpt, tok_dir = _write_random_checkpoint(
+            workdir, CONFIGS[args.config]
         )
-        tok_dir = workdir / "tok"
-        tok_dir.mkdir()
-        with open(tok_dir / "vocab.pkl", "wb") as f:
-            pickle.dump({i: bytes([i]) for i in range(256)}, f)
-        with open(tok_dir / "merges.pkl", "wb") as f:
-            pickle.dump([], f)
-        cache_dir = workdir / "xla_cache"
 
         import socket
 
@@ -618,12 +645,8 @@ def run_restart(args) -> dict:
         port = sock.getsockname()[1]
         sock.close()
 
-        child_env = dict(os.environ)
-        if child_jax_platforms is None:
-            child_env.pop("JAX_PLATFORMS", None)
-        else:
-            child_env["JAX_PLATFORMS"] = child_jax_platforms
-        child_env["PYTHONPATH"] = str(Path(__file__).resolve().parent.parent)
+        child_env = {**os.environ, "PYTHONPATH": str(REPO_ROOT)}
+        cold_env = {**child_env, "JAX_ENABLE_COMPILATION_CACHE": "false"}
 
         base_cmd = [
             sys.executable, "-m", "bpe_transformer_tpu.training.cli",
@@ -635,15 +658,15 @@ def run_restart(args) -> dict:
             "--max-new-tokens", "4",
         ] + _serve_flags(args)
 
-        def spawn(extra):
+        def spawn(env):
             proc = subprocess.Popen(
-                base_cmd + extra, stdout=subprocess.DEVNULL,
-                stderr=subprocess.DEVNULL, env=child_env,
+                base_cmd, stdout=subprocess.DEVNULL,
+                stderr=subprocess.DEVNULL, env=env,
             )
             procs.append(proc)
             return proc
 
-        def time_to_first_token(extra, timeout_s=900.0):
+        def time_to_first_token(env, timeout_s=900.0):
             """Spawn the replica and drive the router by hand until a
             generate lands: the router marks the (absent) replica down,
             sees it rejoin via /statusz polls, and the first 200 is
@@ -658,7 +681,7 @@ def run_restart(args) -> dict:
                  "max_new_tokens": 4, "temperature": 0.0}
             ).encode()
             t0 = time.perf_counter()
-            proc = spawn(extra)
+            proc = spawn(env)
             deadline = t0 + timeout_s
             while time.perf_counter() < deadline:
                 if proc.poll() is not None:
@@ -682,7 +705,7 @@ def run_restart(args) -> dict:
                 proc.kill()
                 proc.wait(timeout=10)
 
-        cold_s, proc = time_to_first_token([])
+        cold_s, proc = time_to_first_token(cold_env)
         stop(proc)
 
         t0 = time.perf_counter()
@@ -690,7 +713,6 @@ def run_restart(args) -> dict:
             [
                 sys.executable, "-m", "bpe_transformer_tpu.training.cli",
                 "warmup",
-                "--compile-cache", str(cache_dir),
                 "--checkpoint", str(ckpt),
                 "--slots", "2",
             ] + _serve_flags(args)
@@ -705,9 +727,7 @@ def run_restart(args) -> dict:
             warm_proc.stdout.strip().splitlines()[-1]
         )
 
-        warm_s, proc = time_to_first_token(
-            ["--compile-cache", str(cache_dir)]
-        )
+        warm_s, proc = time_to_first_token(child_env)
         stop(proc)
     finally:
         for proc in procs:
@@ -765,21 +785,11 @@ def run_controller_ramp(args) -> dict:
 
     The parent stays jax-free on CPU (router, aggregator, and controller
     are all pure-stdlib); replicas own the chip.  One JSON row."""
-    import dataclasses
-    import os
-    import pickle
     import shutil
     import tempfile
     import threading
 
-    child_jax_platforms = os.environ.get("JAX_PLATFORMS")
-    os.environ["JAX_PLATFORMS"] = "cpu"  # parent: params init only
-
-    import jax as _jax
-
-    import bpe_transformer_tpu.models as models
-    from bpe_transformer_tpu.checkpointing import save_checkpoint
-    from bpe_transformer_tpu.models import init_params
+    from bpe_transformer_tpu.models import config as model_configs
     from bpe_transformer_tpu.serving.controller import (
         FleetController,
         ReplicaSpawner,
@@ -794,7 +804,7 @@ def run_controller_ramp(args) -> dict:
     )
 
     managed = bool(args.controller)
-    config = getattr(models, CONFIGS[args.config])
+    config = getattr(model_configs, CONFIGS[args.config])
     new_tokens = min(args.new_tokens, 16)
     n_requests = args.requests or 48
     base_qps = args.qps or 4.0
@@ -811,26 +821,10 @@ def run_controller_ramp(args) -> dict:
     fleet = None
     stop = threading.Event()
     try:
-        ckpt = workdir / "model.ckpt"
-        save_checkpoint(
-            ckpt,
-            params=init_params(_jax.random.PRNGKey(0), config),
-            extra={"model_config": dataclasses.asdict(config)},
+        ckpt, tok_dir = _write_random_checkpoint(
+            workdir, CONFIGS[args.config]
         )
-        tok_dir = workdir / "tok"
-        tok_dir.mkdir()
-        with open(tok_dir / "vocab.pkl", "wb") as f:
-            pickle.dump({i: bytes([i]) for i in range(256)}, f)
-        with open(tok_dir / "merges.pkl", "wb") as f:
-            pickle.dump([], f)
-        cache_dir = workdir / "xla_cache"
-        repo_root = str(Path(__file__).resolve().parent.parent)
-
-        env_prefix = ["env", f"PYTHONPATH={repo_root}"] + (
-            [f"JAX_PLATFORMS={child_jax_platforms}"]
-            if child_jax_platforms is not None
-            else ["-u", "JAX_PLATFORMS"]
-        )
+        env_prefix = ["env", f"PYTHONPATH={REPO_ROOT}"]
 
         def serve_argv(port, role, extra_env=(), extra=()):
             return (
@@ -842,7 +836,6 @@ def run_controller_ramp(args) -> dict:
                     "--port", str(port),
                     "--slots", "4",
                     "--max-new-tokens", str(new_tokens),
-                    "--compile-cache", str(cache_dir),
                     "--paged", "--block-size", str(args.block_size),
                     "--role", role,
                 ] + list(extra)
@@ -1072,7 +1065,6 @@ def run_controller_ramp(args) -> dict:
 
 
 def main() -> int:
-    require_accelerator(Path(__file__).stem)
     parser = argparse.ArgumentParser()
     parser.add_argument("--config", choices=sorted(CONFIGS), default="tinystories-4l")
     parser.add_argument("--concurrency", type=int, action="append", default=None,
@@ -1223,11 +1215,19 @@ def main() -> int:
         ), flush=True)
         return 0
 
+    # In-process modes from here on: THIS process owns the chip and no
+    # child is started.
     import dataclasses
+
+    import jax
 
     import bpe_transformer_tpu.models as models
     from bpe_transformer_tpu.models import init_params
+    from bpe_transformer_tpu.utils.chip_probe import require_tpu
+    from bpe_transformer_tpu.utils.compile_cache import enable_compile_cache
 
+    require_tpu(Path(__file__).stem)
+    enable_compile_cache()
     on_accel = jax.default_backend() != "cpu"
     config = dataclasses.replace(
         getattr(models, CONFIGS[args.config]),

@@ -33,11 +33,13 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
-from _accel import require_accelerator  # noqa: E402  (benchmarks/_accel.py)
+from bpe_transformer_tpu.utils.chip_probe import require_tpu  # noqa: E402
+from bpe_transformer_tpu.utils.compile_cache import (  # noqa: E402
+    enable_compile_cache,
+)
 
 import numpy as np
 
-import bpe_transformer_tpu  # noqa: F401  (re-asserts JAX_PLATFORMS before backend init)
 import jax
 
 
@@ -105,7 +107,8 @@ def run_variant(
 
 
 def main() -> int:
-    require_accelerator(Path(__file__).stem)
+    require_tpu(Path(__file__).stem)
+    enable_compile_cache()
     parser = argparse.ArgumentParser()
     on_accel = jax.default_backend() != "cpu"
     parser.add_argument(

@@ -31,8 +31,7 @@ SAMPLE = Path("/root/reference/tests/fixtures/tinystories_sample.txt")
 def usable_cores() -> int:
     """Cores this process can actually burn: CPU affinity intersected with
     the cgroup-v2 quota (this container advertises many host CPUs but pins
-    the quota to 1 — `cpu_count()` alone would report a fantasy grid;
-    VERDICT r4 #7 / benchmarks/RESULTS.md host-tokenization caveat)."""
+    the quota to 1 — `cpu_count()` alone would report a fantasy grid)."""
     n = len(os.sched_getaffinity(0))
     try:
         quota_raw, period_raw = (
@@ -67,7 +66,7 @@ def main() -> int:
     parser.add_argument(
         "--grid-if-multicore",
         action="store_true",
-        help="armed-trap mode (VERDICT r4 #7): exit immediately with no "
+        help="armed-trap mode: exit immediately with no "
         "rows unless >1 core is actually usable; otherwise capture the "
         "2/4/8-worker scaling grid the parallel-scaling claim needs",
     )

@@ -7,9 +7,9 @@
 #      record;
 #   2. a --resume run continues from that checkpoint and completes with
 #      exit 0.
-# Emits one JSON verdict line on stdout (tpu_queue.sh appends it to the
-# job's outfile); any assertion failure exits nonzero so the queue marks
-# the job failed instead of recording a hollow pass.
+# Emits one JSON verdict line on stdout (a caller may append it to its
+# job's outfile); any assertion failure exits nonzero, so a
+# caller never records a hollow pass.
 set -u
 WORK=$(mktemp -d /tmp/kill_resume.XXXXXX)
 trap 'rm -rf "$WORK"' EXIT

@@ -1,9 +1,10 @@
-"""One-screen summary of every persisted TPU capture + queue artifact.
+"""One-screen summary of every persisted TPU capture.
 
-Reads benchmarks/captures/*.json (bench.py per-config captures,
-northstar.json) and the attention/decode/breakdown/moe-dispatch JSONL
-files, and prints a compact table per group — what's measured, when, and
-at what knobs.  Pure host-side file reads: safe to run any time (no jax).
+Reads benchmarks/captures/*.json (the `tpu_capture_*.json` per-config
+captures of the bench.py deleted in PR 22, northstar.json) and the
+attention/decode/breakdown/moe-dispatch JSONL files, and prints a compact
+table per group — what's measured, when, and at what knobs.  Pure
+host-side file reads: safe to run any time (no jax).
 
     python benchmarks/summarize_captures.py
 """
@@ -34,7 +35,7 @@ def _rows(path: Path):
 def _manifest_line(m: dict | None) -> str | None:
     """Compact provenance from a telemetry run-manifest record (the
     ``kind="manifest"`` header every training/benchmark stream now writes,
-    also embedded as ``"manifest"`` in bench.py/northstar captures)."""
+    also embedded as ``"manifest"`` in the per-config and northstar captures)."""
     if not m:
         return None
     parts = [f"git={str(m.get('git_sha'))[:12]}"]
@@ -60,7 +61,7 @@ def main() -> int:
         print("no captures directory", file=sys.stderr)
         return 1
 
-    print("== bench.py captures (tokens/sec/chip) ==")
+    print("== per-config captures (tokens/sec/chip) ==")
     for p in sorted(CAP.glob("tpu_capture_*.json")):
         try:
             c = json.loads(p.read_text())
@@ -83,7 +84,7 @@ def main() -> int:
             f"  mfu={c.get('mfu')}  vs_torch={c.get('vs_baseline')}"
             f"  B={c.get('batch')} steps={c.get('measure_steps')}"
             # `or '?'` not a .get default: the key can be present with a JSON
-            # null (ADVICE r4), and None[:16] would kill the whole summary.
+            # null, and None[:16] would kill the whole summary.
             f"  @{(c.get('captured_at_utc') or '?')[:16]}  [{', '.join(knobs)}]"
         )
         provenance = _manifest_line(c.get("manifest"))

@@ -2,7 +2,7 @@
 
 Trains the TinyStories-class 4L/256d LM with the framework's own BPE
 tokenizer and training step, and the byte-identical architecture/update in
-PyTorch on the host CPU (`bench.make_torch_lm`, the reference's execution
+PyTorch on the host CPU (`make_torch_lm` below, the reference's execution
 substrate — it defines the model via `/root/reference/tests/adapters.py:282-
 361` but never ships a loop), under the SAME token budget, batch schedule,
 and train/val split.  Writes `benchmarks/val_parity_results.json` with both
@@ -132,10 +132,110 @@ def _load_jax_params_into_torch(model, params):
             blk.ln2.copy_(t(lp["ln2"]))
 
 
+def make_torch_lm(C):
+    """The identical model + update step in PyTorch on the host CPU (the
+    reference's execution substrate; it defines this architecture via its
+    test contract, `/root/reference/tests/adapters.py:282-361`, but never
+    ships a training loop).  Returns ``(model, train_step(ids, labels),
+    eval_loss(ids, labels))`` — shared with benchmarks/northstar.py."""
+    import torch
+    import torch.nn.functional as F
+
+    torch.manual_seed(0)
+    dh = C.d_model // C.num_heads
+
+    class Block(torch.nn.Module):
+        def __init__(self):
+            super().__init__()
+            mk = lambda o, i: torch.nn.Linear(i, o, bias=False)
+            self.q, self.k, self.v, self.o = (mk(C.d_model, C.d_model) for _ in range(4))
+            self.w1, self.w3 = mk(C.d_ff, C.d_model), mk(C.d_ff, C.d_model)
+            self.w2 = mk(C.d_model, C.d_ff)
+            self.ln1 = torch.nn.Parameter(torch.ones(C.d_model))
+            self.ln2 = torch.nn.Parameter(torch.ones(C.d_model))
+
+        @staticmethod
+        def rms(x, w):
+            return x * torch.rsqrt(x.pow(2).mean(-1, keepdim=True) + 1e-5) * w
+
+        def forward(self, x, rope_cos, rope_sin, mask):
+            b, s, d = x.shape
+            h = self.rms(x, self.ln1)
+            split = lambda t: t(h).view(b, s, C.num_heads, dh).transpose(1, 2)
+            q, k, v = split(self.q), split(self.k), split(self.v)
+
+            def rope(t):
+                te, to = t[..., 0::2], t[..., 1::2]
+                out = torch.empty_like(t)
+                out[..., 0::2] = te * rope_cos - to * rope_sin
+                out[..., 1::2] = te * rope_sin + to * rope_cos
+                return out
+
+            q, k = rope(q), rope(k)
+            scores = q @ k.transpose(-1, -2) / dh**0.5
+            scores = scores.masked_fill(~mask, float("-inf"))
+            a = (F.softmax(scores, dim=-1) @ v).transpose(1, 2).reshape(b, s, d)
+            x = x + self.o(a)
+            h = self.rms(x, self.ln2)
+            return x + self.w2(F.silu(self.w1(h)) * self.w3(h))
+
+    class LM(torch.nn.Module):
+        def __init__(self):
+            super().__init__()
+            self.emb = torch.nn.Embedding(C.vocab_size, C.d_model)
+            self.blocks = torch.nn.ModuleList(Block() for _ in range(C.num_layers))
+            self.ln_f = torch.nn.Parameter(torch.ones(C.d_model))
+            self.head = torch.nn.Linear(C.d_model, C.vocab_size, bias=False)
+
+        def forward(self, ids, cos, sin, mask):
+            x = self.emb(ids)
+            for blk in self.blocks:
+                x = blk(x, cos, sin, mask)
+            x = Block.rms(x, self.ln_f)
+            return self.head(x)
+
+    model = LM()
+    opt = torch.optim.AdamW(model.parameters(), lr=3e-4, weight_decay=0.01)
+    s = C.context_length
+    inv = C.rope_theta ** (-torch.arange(0, dh, 2, dtype=torch.float32) / dh)
+    ang = torch.arange(s, dtype=torch.float32)[:, None] * inv[None, :]
+    cos, sin = torch.cos(ang), torch.sin(ang)
+    mask = torch.tril(torch.ones(s, s, dtype=torch.bool))
+
+    from bpe_transformer_tpu.optim.schedule import cosine_schedule
+
+    step_count = [0]
+
+    def train_step(ids, labels):
+        # The SAME warmup+cosine schedule as the JAX side's TrainHParams
+        # defaults — val_parity.py compares the two steps under identical
+        # hyperparameters (an unscheduled torch baseline learns faster over
+        # the first 100 warmup steps and the comparison stops being
+        # apples-to-apples).
+        lr = cosine_schedule(step_count[0], 3e-4, 3e-5, 100, 10_000)
+        for group in opt.param_groups:
+            group["lr"] = lr
+        step_count[0] += 1
+        opt.zero_grad()
+        logits = model(ids, cos, sin, mask)
+        loss = F.cross_entropy(logits.view(-1, C.vocab_size), labels.view(-1))
+        loss.backward()
+        torch.nn.utils.clip_grad_norm_(model.parameters(), 1.0)
+        opt.step()
+        return float(loss.detach())
+
+    @torch.no_grad()
+    def eval_loss(ids, labels):
+        logits = model(ids, cos, sin, mask)
+        return float(
+            F.cross_entropy(logits.view(-1, C.vocab_size), labels.view(-1))
+        )
+
+    return model, train_step, eval_loss
+
+
 def run_torch(cfg, train_toks, val_toks, n_steps, init_params_tree=None):
     import torch
-
-    from bench import make_torch_lm
 
     model, train_step, eval_loss = make_torch_lm(cfg)
     if init_params_tree is not None:

@@ -1,5 +1,4 @@
-"""Compatibility seams: the torch-shaped reference adapters and jax-version
-shims.
+"""Compatibility seam: the torch-shaped reference adapters.
 
 The reference's test suite never imports implementation modules — only the
 21 adapter functions in its ``tests/adapters.py``
@@ -10,13 +9,8 @@ boundary, so the reference (CS336-derived) suite runs green against the
 TPU-native core.
 
 The adapter names resolve lazily (PEP 562): ``adapters`` imports torch,
-and the torch-free members of this package — :func:`ensure_shard_map`,
-which the parallel subpackage applies at import so ``jax.shard_map``
-exists on jax 0.4.x runtimes too — must stay importable in jax-only
-processes.
+which jax-only processes must not pay for.
 """
-
-from bpe_transformer_tpu.compat.shardmap import ensure_shard_map
 
 _ADAPTER_NAMES = (
     "get_adamw_cls",
@@ -54,4 +48,4 @@ def __getattr__(name: str):
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
-__all__ = ["ensure_shard_map", *_ADAPTER_NAMES]
+__all__ = list(_ADAPTER_NAMES)

@@ -18,7 +18,6 @@ import torch
 import jax
 import jax.numpy as jnp
 
-from bpe_transformer_tpu.compat.shardmap import ensure_shard_map
 from bpe_transformer_tpu.models.config import ModelConfig
 from bpe_transformer_tpu.models.transformer import forward as lm_forward
 from bpe_transformer_tpu.models.transformer import (
@@ -42,11 +41,6 @@ from bpe_transformer_tpu.ops import (
 from bpe_transformer_tpu.optim.adamw import adamw_init, adamw_update
 from bpe_transformer_tpu.optim.schedule import cosine_schedule
 from bpe_transformer_tpu.tokenization import BPETokenizer, train_bpe
-
-# jax 0.4.x ships shard_map only under jax.experimental; alias it onto the
-# jax module here so any consumer of this compat surface (the reference
-# suite, scripts importing adapters first) can call jax.shard_map.
-ensure_shard_map()
 
 
 def _j(t: torch.Tensor) -> jnp.ndarray:

@@ -240,7 +240,8 @@ def _paged_decode_kernel(
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    pos = pos_ref[pl.program_id(0) // kv_heads]
+    slot = pl.program_id(0) // kv_heads
+    pos = pos_ref[slot]
     # This program's kv head (hoisted: program_id is a top-level-only
     # primitive under the interpreter) — used to select the dequant scale.
     head = pl.program_id(0) % kv_heads
@@ -251,12 +252,20 @@ def _paged_decode_kernel(
         k = k_ref[0, 0].astype(jnp.float32)       # (block_size, d)
         v = v_ref[0, 0].astype(jnp.float32)
         if quantized:
-            # Per-block-per-head dequant IN REGISTERS: the scale row is
-            # (1, kv_heads) f32; this program's head is selected by lane
-            # mask (dynamic lane indexing is not a TPU vector primitive).
-            lane = jax.lax.broadcasted_iota(jnp.int32, (1, kv_heads), 1)
-            k = k * jnp.sum(jnp.where(lane == head, kscale_ref[...], 0.0))
-            v = v * jnp.sum(jnp.where(lane == head, vscale_ref[...], 0.0))
+            # Per-block-per-head dequant IN REGISTERS.  The scale tile is
+            # the 8-row group of the (num_blocks, kv_heads) f32 pool that
+            # holds this block (a 1-row tile is not a legal TPU block);
+            # the block's row and this program's head are selected by
+            # mask (dynamic sublane/lane indexing is not a TPU vector
+            # primitive).  Rows of a ragged last group are never selected.
+            blk = tables_ref[slot, jnp.minimum(j, pos // block_size)]
+            shape = (SUBLANES, kv_heads)
+            pick = (
+                jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                == blk % SUBLANES
+            ) & (jax.lax.broadcasted_iota(jnp.int32, shape, 1) == head)
+            k = k * jnp.sum(jnp.where(pick, kscale_ref[...], 0.0))
+            v = v * jnp.sum(jnp.where(pick, vscale_ref[...], 0.0))
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (G_pad, block_size)
@@ -313,8 +322,9 @@ def paged_decode_attention(
     exactly when the pool is int8-quantized (per-block-per-head scales, the
     serving pool's ``kv_dtype="int8"`` layout); the kernel dequantizes each
     block in registers, so the HBM side of the stream stays 1 byte/value.
-    TPU note: int8 tiles want ``block_size`` >= 32 (sublane alignment at 8
-    bits); the interpreter path (CPU tests) has no such constraint.
+    The v5e compiler accepts int8 pools at every block size from 4 up
+    (AOT, ``tests/test_chip_compile.py``); what it refuses is a 1-row
+    scale tile, so the scales ride as 8-row groups (see the kernel).
 
     ``pos`` is the per-slot causal frontier ``(slots,)`` (scalar broadcast
     accepted).  Returns ``(slots, num_heads, d_head)`` like
@@ -389,12 +399,15 @@ def paged_decode_attention(
     inputs = [qg, k_pool, v_pool]
     if quantized:
 
+        # A (1, kv_heads) row is not a legal TPU block (sublane dim must
+        # be a multiple of 8 or the whole axis): DMA the 8-row group that
+        # holds the block's row; the kernel selects the row by mask.
         def scale_index(b, j, t, p):
             s = b // kv_heads
-            return (t[s, jnp.minimum(j, p[s] // block_size)], 0)
+            return (t[s, jnp.minimum(j, p[s] // block_size)] // SUBLANES, 0)
 
         sspec = pl.BlockSpec(
-            (1, kv_heads), scale_index, memory_space=pltpu.VMEM
+            (SUBLANES, kv_heads), scale_index, memory_space=pltpu.VMEM
         )
         in_specs += [sspec, sspec]
         inputs += [k_scale, v_scale]

@@ -23,11 +23,13 @@ tiles (the weight stream) and ``m`` row tiles — the same dispatch serves
 the 1-token decode tick (m = slots, one tile) and full prefill buckets
 (m = bucket length), so the activation block must never assume
 decode-sized m or a long bucket would blow the VMEM budget.  TPU note:
-int8 weight tiles want 32-sublane alignment, so the d_out tile prefers
-multiples of 32; dimensions with no aligned divisor fall back to a
-single whole-axis tile (physically lane/sublane-padded by the layout,
-like the decode kernel's narrow head dims).  Interpret mode runs
-everywhere else (CPU tests), as with the sibling kernels.
+the d_out tile is the LANE axis of the output block, so it must be a
+multiple of 128 (which also satisfies the int8 weight tile's 32-sublane
+alignment) or span the whole axis.  A d_out with no such divisor (683,
+1365, 2731, vocab 10,000) runs a ragged last tile — Pallas pads the edge block's reads and drops its
+out-of-range writes, and every output column depends only on its own
+weight row, so the padding never reaches a kept value.  Interpret mode
+runs everywhere else (CPU tests), as with the sibling kernels.
 """
 
 from __future__ import annotations
@@ -37,22 +39,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from bpe_transformer_tpu.kernels.pallas.runtime import pick_block
+
 SUBLANES = 8
-#: Preferred int8 sublane alignment (min int8 tile is (32, 128)).
-INT8_SUBLANES = 32
+LANES = 128
+#: d_out tile ceiling (a (512, d_in) int8 tile plus its f32 conversion
+#: stays a few MB of VMEM at every shipped d_in).
+BLOCK_N = 512
 
 
-def _pick_block(n: int, target: int = 512, step: int = INT8_SUBLANES) -> int:
-    """Largest divisor of ``n`` that is a multiple of ``step`` and <=
-    ``target``; falls back to ``n`` itself (whole-axis tile) when no
-    aligned divisor exists."""
-    best = 0
-    b = step
-    while b <= min(target, n):
-        if n % b == 0:
-            best = b
-        b += step
-    return best or n
+def _pick_block_n(n: int) -> int:
+    """The d_out tile: the largest 128-multiple divisor up to
+    :data:`BLOCK_N`; with none (683, 1365, 2731, vocab 10,000) a
+    128-multiple tile with a ragged last one (see module docstring)."""
+    return pick_block(n, BLOCK_N, LANES) or min(
+        BLOCK_N, pl.cdiv(n, LANES) * LANES
+    )
 
 
 def _quant_matmul_kernel(x_ref, q_ref, s_ref, o_ref):
@@ -93,16 +95,20 @@ def quant_matmul(
     m_pad = pl.cdiv(max(m, 1), SUBLANES) * SUBLANES
     if m_pad != m:
         x2 = jnp.pad(x2, ((0, m_pad - m), (0, 0)))
-    bn = block_n or _pick_block(n)
-    if n % bn:
-        raise ValueError(f"block_n={bn} must divide d_out={n}")
+    bn = block_n or _pick_block_n(n)
+    if bn != n and bn % LANES:
+        raise ValueError(
+            f"block_n={bn} must be a multiple of {LANES} or the whole "
+            f"d_out={n}: it is the lane axis of the output tile, and the "
+            "TPU lowering refuses any other width"
+        )
     # Tile m too: a full prefill bucket's activations must not ride VMEM
     # whole (m_pad is a SUBLANES multiple, so a divisor always exists).
-    bm = _pick_block(m_pad, target=256, step=SUBLANES)
+    bm = pick_block(m_pad, target=256, step=SUBLANES)
 
     out = pl.pallas_call(
         _quant_matmul_kernel,
-        grid=(m_pad // bm, n // bn),
+        grid=(m_pad // bm, pl.cdiv(n, bn)),
         in_specs=[
             pl.BlockSpec((bm, d_in), lambda i, j: (i, 0),
                          memory_space=pltpu.VMEM),

@@ -55,32 +55,33 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from bpe_transformer_tpu.kernels.pallas.runtime import pick_block
 from bpe_transformer_tpu.ops.core import MASK_VALUE as NEG_INF
 
 SUBLANES = 8
 LANE = 128
 
 
-def _pick_block(n: int, target: int, step: int) -> int:
-    """Largest multiple-of-``step`` divisor of ``n`` up to ``target``;
-    falls back to ``n`` itself when no aligned divisor exists."""
-    best = 0
-    b = step
-    while b <= min(target, n):
-        if n % b == 0:
-            best = b
-        b += step
-    return best or n
+def _pick_block_v(v: int, target: int = 2048) -> tuple[int, int]:
+    """``(vocab tile, padded vocab)``.  The tile is a multiple of 128 —
+    Mosaic must prove the dynamic-offset scratch store lane-aligned — and
+    the scratch spans a whole number of tiles.  A vocabulary with an
+    aligned divisor (32,000 -> 1,280) tiles exactly; any other (10,000)
+    takes ``target``-wide tiles over a scratch padded up to the next tile
+    boundary: the last head tile is ragged (Pallas pads the read) and the
+    finalize masks the padding columns to ``NEG_INF`` before any use."""
+    bv = pick_block(v, target, LANE) or min(target, pl.cdiv(v, LANE) * LANE)
+    return bv, pl.cdiv(v, bv) * bv
 
 
-def _pick_block_v(v: int, target: int = 2048) -> int:
-    """Vocab tile: multiple-of-128 (lane alignment for the
-    dynamic-offset scratch stores); vocabularies with no aligned divisor
-    run as a single whole-V head block — fine for the shipped configs
-    (10k x d int8 is a ~2.5 MB tile) but a large unaligned vocab at the
-    activation width can exceed VMEM on TPU; pick a 128-multiple vocab
-    (or serve unfused) there."""
-    return _pick_block(v, target, LANE)
+def _pick_block_r(r_pad: int, v_pad: int) -> int:
+    """Row tile: every vocab-sized operand, the scratch and the finalize's
+    temporaries live at this many rows, and Mosaic unrolls the 64 radix
+    passes over all of them — so rows shrink as the vocabulary grows (32
+    rows up to 8k, 8 rows at 32k; 24 rows at 32k overflowed the v5e's
+    16 MB scoped VMEM)."""
+    rows = 32 * 8192 // v_pad // SUBLANES * SUBLANES
+    return pick_block(r_pad, min(32, max(SUBLANES, rows)), SUBLANES)
 
 
 def _okey(x):
@@ -133,19 +134,24 @@ def _nucleus_threshold(keys, e, p_mass):
     return t
 
 
-def _filter_rows(logits, temps, top_ks, top_ps):
-    """The `filter_logits` keep-set + masked logits for (R, V) rows with
-    per-row runtime knobs, sort-free (see module docstring).  Returns
-    ``(masked, keep, e_kept, greedy)``: the -inf-masked scaled logits,
-    the boolean keep set, the kept entries' ``exp(x - rowmax)`` weights
-    (softmax numerators), and the raw-logits argmax."""
-    v = logits.shape[-1]
+def _filter_rows(logits, temps, top_ks, top_ps, vocab):
+    """The `filter_logits` keep-set + masked logits for (R, V_pad) rows
+    with per-row runtime knobs, sort-free (see module docstring).  Columns
+    at and beyond ``vocab`` are scratch padding: forced to ``NEG_INF``
+    here, they rank below every real logit, carry zero softmax mass, and
+    never win an argmax.  Returns ``(masked, keep, e_kept, greedy)``: the
+    -inf-masked scaled logits, the boolean keep set, the kept entries'
+    ``exp(x - rowmax)`` weights (softmax numerators), and the raw-logits
+    argmax."""
+    if logits.shape[-1] != vocab:
+        cols = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+        logits = jnp.where(cols < vocab, logits, NEG_INF)
     greedy = _argmax_first(logits)
     scaled = logits / jnp.maximum(temps, 1e-6)
     keys = _okey(scaled)
 
     kk_raw = top_ks.astype(jnp.int32)
-    kk = jnp.where(kk_raw > 0, jnp.clip(kk_raw, 1, v), v)
+    kk = jnp.where(kk_raw > 0, jnp.clip(kk_raw, 1, vocab), vocab)
     tk = _topk_threshold(keys, kk)
     keep_k = keys >= tk
     masked1 = jnp.where(keep_k, scaled, NEG_INF)
@@ -178,7 +184,7 @@ def _accumulate_logits(x_ref, h_ref, s_ref, acc_ref, *, block_v, quantized):
 
 
 def _sample_kernel(
-    x_ref, h_ref, *refs, block_v, num_v_blocks, quantized,
+    x_ref, h_ref, *refs, block_v, num_v_blocks, quantized, vocab,
 ):
     if quantized:
         s_ref, knobs_ref, g_ref, tok_ref, acc_ref = refs
@@ -194,7 +200,7 @@ def _sample_kernel(
         logits = acc_ref[...]
         temps = knobs_ref[:, 0:1]
         masked, _, _, greedy = _filter_rows(
-            logits, temps, knobs_ref[:, 1:2], knobs_ref[:, 2:3]
+            logits, temps, knobs_ref[:, 1:2], knobs_ref[:, 2:3], vocab
         )
         sampled = _argmax_first(masked + g_ref[...])
         tok_ref[...] = jnp.where(temps > 0.0, sampled, greedy).astype(
@@ -203,7 +209,7 @@ def _sample_kernel(
 
 
 def _verify_kernel(
-    x_ref, h_ref, *refs, block_v, num_v_blocks, quantized,
+    x_ref, h_ref, *refs, block_v, num_v_blocks, quantized, vocab,
 ):
     if quantized:
         (s_ref, knobs_ref, judge_ref, q_ref, g_ref,
@@ -219,10 +225,9 @@ def _verify_kernel(
     @pl.when(pl.program_id(1) == num_v_blocks - 1)
     def _finalize():
         logits = acc_ref[...]
-        v = logits.shape[-1]
         temps = knobs_ref[:, 0:1]
         _, _, e_kept, greedy = _filter_rows(
-            logits, temps, knobs_ref[:, 1:2], knobs_ref[:, 2:3]
+            logits, temps, knobs_ref[:, 1:2], knobs_ref[:, 2:3], vocab
         )
         iota = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
         # Filtered target distribution p: softmax over the keep set for
@@ -293,9 +298,9 @@ def _run(kernel_body, hidden, head, knobs, extra_inputs, out_shapes,
         else jnp.pad(a, ((0, r_pad - a.shape[0]),) + ((0, 0),) * (a.ndim - 1))
     )
     head_inputs, quantized = _head_operands(head, vocab, d)
-    bv = _pick_block_v(vocab)
-    nv = vocab // bv
-    br = _pick_block(r_pad, 32, SUBLANES)
+    bv, v_pad = _pick_block_v(vocab)
+    nv = v_pad // bv
+    br = _pick_block_r(r_pad, v_pad)
 
     rowspec = lambda minor: pl.BlockSpec(
         (br, minor), lambda i, j: (i, 0), memory_space=pltpu.VMEM
@@ -312,11 +317,17 @@ def _run(kernel_body, hidden, head, knobs, extra_inputs, out_shapes,
     in_specs.append(rowspec(knobs.shape[1]))
     inputs = [pad(hidden), *head_inputs, pad(knobs)]
     for arr in extra_inputs:
+        if arr.shape[1] == vocab and v_pad != vocab:
+            # Vocab-sized per-row operands (gumbel, the verify q) meet the
+            # padded scratch elementwise; their padding columns only ever
+            # touch masked logits.
+            arr = jnp.pad(arr, ((0, 0), (0, v_pad - vocab)))
         inputs.append(pad(arr))
         in_specs.append(rowspec(arr.shape[1]))
 
     kernel = functools.partial(
-        kernel_body, block_v=bv, num_v_blocks=nv, quantized=quantized
+        kernel_body, block_v=bv, num_v_blocks=nv, quantized=quantized,
+        vocab=vocab,
     )
     outs = pl.pallas_call(
         kernel,
@@ -326,7 +337,7 @@ def _run(kernel_body, hidden, head, knobs, extra_inputs, out_shapes,
         out_shape=[
             jax.ShapeDtypeStruct((r_pad, 1), dt) for dt in out_shapes
         ],
-        scratch_shapes=[pltpu.VMEM((br, vocab), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((br, v_pad), jnp.float32)],
         interpret=interpret,
     )(*inputs)
     return [o[:r, 0] for o in outs]

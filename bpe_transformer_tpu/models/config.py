@@ -107,9 +107,8 @@ class ModelConfig:
     #: attention_impl="flash_fused" auto-falls-back to the plain flash
     #: kernel (RoPE outside) below this sequence length: the in-kernel RoPE
     #: rematerialization only pays off once the sequence is long enough
-    #: (round-2 v5e measurements: plain wins at 1k — 2.168 vs 2.330 ms —
-    #: fused wins at 4k — 2.468 vs 5.256 ms; benchmarks/RESULTS.md).
-    #: Set to 0 to force the fused kernel at every length.
+    #: (builder capture benchmarks/captures/attention.jsonl; no ledger
+    #: number yet).  Set to 0 to force the fused kernel at every length.
     flash_fused_min_seq: int = 2048
     # Sequence-chunked LM loss: cap peak logits memory at
     # O(batch * chunk * vocab) instead of O(batch * seq * vocab).
@@ -295,10 +294,10 @@ TINYSTORIES_MOE = ModelConfig(
     n_experts=8,
     router_top_k=2,
     capacity_factor=1.25,
-    # Chip-confirmed 2026-08-02 (TPU v5 lite0, bench.py --config
-    # tinystories-moe): gather 118,025 tok/s / MFU 26.7% vs einsum 69,896 /
-    # 15.8% — the dense dispatch/combine einsums cost more than the expert
-    # FFN itself at this shape.  Identical routing; einsum stays selectable.
+    # Builder capture 2026-08-02 (benchmarks/captures/
+    # tpu_capture_tinystories-moe*.json): gather beat einsum — the dense
+    # dispatch/combine einsums cost more than the expert FFN itself at this
+    # shape.  Identical routing; einsum stays selectable.
     moe_dispatch="gather",
 )
 

@@ -481,11 +481,13 @@ def paged_decode_step(
                      "k_scale": k_scale, "v_scale": v_scale}
                 )
             else:
+                # Explicit cast to the pool width: jax 0.9 deprecates the
+                # implicit one (an f32 row into a bf16 pool).
                 k_pool = layer_pool["k"].at[write_ids, :, offsets, :].set(
-                    k[:, :, 0, :]
+                    k[:, :, 0, :].astype(layer_pool["k"].dtype)
                 )
                 v_pool = layer_pool["v"].at[write_ids, :, offsets, :].set(
-                    v[:, :, 0, :]
+                    v[:, :, 0, :].astype(layer_pool["v"].dtype)
                 )
                 new_pool.append({"k": k_pool, "v": v_pool})
             if config.decode_attention_impl == "paged":
@@ -643,10 +645,10 @@ def paged_chunk_prefill(
                 )
             else:
                 k_pool = layer_pool["k"].at[write_ids, :, offsets, :].set(
-                    jnp.transpose(k[0], (1, 0, 2))
+                    jnp.transpose(k[0], (1, 0, 2)).astype(layer_pool["k"].dtype)
                 )
                 v_pool = layer_pool["v"].at[write_ids, :, offsets, :].set(
-                    jnp.transpose(v[0], (1, 0, 2))
+                    jnp.transpose(v[0], (1, 0, 2)).astype(layer_pool["v"].dtype)
                 )
                 new_pool.append({"k": k_pool, "v": v_pool})
                 k_cache = gather_paged_kv(k_pool, table_row[None])

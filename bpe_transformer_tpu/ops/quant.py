@@ -94,8 +94,8 @@ def dequantize(w: dict, dtype=jnp.float32) -> Array:
 def quant_linear_xla(x: Array, w: dict) -> Array:
     """XLA reference for the quantized matmul: f32 accumulation over the
     int8 tile, ONE scale multiply per output element, output back at
-    ``x``'s dtype.  The Pallas kernel's parity oracle (and the fallback
-    where Pallas is unavailable)."""
+    ``x``'s dtype.  The Pallas kernel's parity oracle — never a fallback:
+    a shape the kernel cannot tile raises at trace time."""
     out = jax.lax.dot_general(
         x.astype(jnp.float32), w["q"].astype(jnp.float32),
         (((x.ndim - 1,), (1,)), ((), ())),

@@ -1,13 +1,6 @@
 """Multi-chip parallelism: meshes, shardings, and collective train steps."""
 
-from bpe_transformer_tpu.compat.shardmap import ensure_shard_map
-
-# Every module below (and their callers) uses jax.shard_map; on jax 0.4.x
-# that name only exists under jax.experimental — alias it before anything
-# can call it.
-ensure_shard_map()
-
-from bpe_transformer_tpu.parallel.mesh import (  # noqa: E402
+from bpe_transformer_tpu.parallel.mesh import (
     batch_sharding,
     initialize_distributed,
     make_hybrid_mesh,

@@ -6,7 +6,7 @@ launched it.  ``run_manifest`` collects exactly that — config dicts, mesh
 layout, jax/device versions, git SHA, host — as one JSON-serializable dict
 with ``kind="manifest"``, logged first into every stream
 (``training/loop.py``, ``benchmarks/northstar.py``) and embedded in
-``bench.py`` captures.
+bench captures.
 
 Everything here degrades gracefully: no git checkout, no jax backend, or no
 mesh just omits those fields rather than failing the run it describes.
@@ -128,7 +128,7 @@ def run_manifest(
 
 def attach_manifest(payload: dict, kind: str, **kwargs) -> dict:
     """Best-effort: embed ``run_manifest(kind, **kwargs)`` as
-    ``payload["manifest"]``.  Capture payloads (bench.py, northstar.py)
+    ``payload["manifest"]``.  Capture payloads (northstar.py)
     share one contract here: manifest trouble must never lose the
     measurement — on any failure the payload is returned un-annotated and
     the error goes to stderr."""

@@ -1712,8 +1712,8 @@ def extract_compare_metrics(summary: dict) -> dict:
 
 
 def baseline_capture_metrics(capture: dict) -> dict:
-    """Comparable metrics out of a bench capture JSON (``bench.py``'s
-    ``tpu_capture_*.json`` / the driver's ``BENCH_*.json`` with its payload
+    """Comparable metrics out of a bench capture JSON (a
+    ``benchmarks/captures/tpu_capture_*.json``, or one with its payload
     under ``"parsed"``), mapped onto the stream metric names."""
     if isinstance(capture.get("parsed"), dict):
         capture = capture["parsed"]
@@ -1832,8 +1832,7 @@ def render_compare(
 def _load_capture_json(path: str | Path) -> dict | None:
     """A bench capture JSON (one pretty-printed object, not JSONL), or None
     when the file isn't one.  Lets the compare gate run capture-vs-capture
-    (``report new_capture.json --baseline prev_capture.json``) — the shape
-    ``benchmarks/tpu_queue.sh`` self-reports with after each pass."""
+    (``report new_capture.json --baseline prev_capture.json``)."""
     try:
         with open(path) as f:
             payload = json.load(f)
@@ -1916,7 +1915,7 @@ def main(argv: list[str] | None = None) -> int:
         records = []
     if not records and capture_current is None:
         # Not a JSONL stream — maybe a bench capture JSON (capture-vs-
-        # capture compare, the tpu_queue.sh self-report shape).
+        # capture compare).
         capture_current = _load_capture_json(args.metrics)
         if capture_current is None:
             print(

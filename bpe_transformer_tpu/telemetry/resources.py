@@ -156,9 +156,10 @@ def host_rss_bytes() -> int | None:
 
 def device_memory_stats() -> dict | None:
     """Summed ``memory_stats()`` across local devices: ``{"bytes_in_use",
-    "peak_bytes_in_use", "bytes_limit", "n_devices"}``, or None when the
-    backend exposes no stats (CPU) or jax is absent.  Metadata-only — never
-    syncs the device."""
+    "peak_bytes_in_use", "bytes_limit", "n_devices"}`` plus
+    ``"bytes_in_use_per_device"`` (a list — whether sharded state really
+    landed on every chip), or None when the backend exposes no stats (CPU)
+    or jax is absent.  Metadata-only — never syncs the device."""
     try:
         import jax
 
@@ -166,6 +167,7 @@ def device_memory_stats() -> dict | None:
     except Exception:
         return None
     totals = {"bytes_in_use": 0, "peak_bytes_in_use": 0, "bytes_limit": 0}
+    per_device = []
     n = 0
     for device in devices:
         try:
@@ -175,6 +177,7 @@ def device_memory_stats() -> dict | None:
         if not stats:
             continue
         n += 1
+        per_device.append(stats.get("bytes_in_use"))
         for key in totals:
             value = stats.get(key)
             if isinstance(value, int):
@@ -182,6 +185,7 @@ def device_memory_stats() -> dict | None:
     if n == 0:
         return None
     totals["n_devices"] = n
+    totals["bytes_in_use_per_device"] = per_device
     return totals
 
 
@@ -256,5 +260,10 @@ def sample_resources(**extra) -> dict:
     record["hbm_bytes_in_use"] = mem["bytes_in_use"] if mem else None
     record["hbm_peak_bytes_in_use"] = mem["peak_bytes_in_use"] if mem else None
     record["hbm_bytes_limit"] = mem["bytes_limit"] if mem else None
+    # Not schema-required (older streams predate it): one entry per local
+    # device, so a sharded run shows every chip holding its share.
+    record["hbm_bytes_in_use_per_device"] = (
+        mem["bytes_in_use_per_device"] if mem else None
+    )
     record.update(extra)
     return record

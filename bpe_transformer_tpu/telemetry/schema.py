@@ -41,7 +41,11 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # ``opt_state_bytes`` (PER-CHIP state bytes from shard-shape metadata —
     # the ZeRO-1 optimizer-sharding memory win reads directly off them) and
     # ``compile_time_s``; all three are optional — older streams predate
-    # them.
+    # them.  ``hbm_bytes_in_use_per_device`` (PR 22, optional, None on CPU)
+    # lists one entry per local device: a sharded run shows every chip
+    # holding its share.  ``hbm_peak_bytes_in_use`` is the allocator's
+    # high-water mark, which on the v5e runtime does NOT include a
+    # program's temporaries (PERF.md §6, PR 22) — a floor, not the peak.
     "resources": {
         "kind", "time_unix", "host_rss_bytes", "live_buffer_bytes",
         "compile_events", "hbm_bytes_in_use", "hbm_peak_bytes_in_use",

@@ -41,9 +41,6 @@ def profile_trace(logdir: str, create_perfetto_link: bool = False):
 
 
 def _sync(value) -> None:
-    # jax.block_until_ready is the documented fence; fetching one leaf also
-    # works on relayed/remote device transports where block_until_ready has
-    # been observed to return early (see bench.py).
     jax.block_until_ready(value)
 
 

@@ -1,7 +1,7 @@
 """Watchdog: hung-step detection and a non-finite-state policy.
 
 Two failure modes kill long TPU runs silently: a hung collective/dispatch
-(the loop blocks forever, the queue window burns with no output) and a
+(the loop blocks forever and the run burns its time with no output) and a
 NaN/Inf that poisons the state steps before anyone reads a loss.  The
 watchdog covers both:
 

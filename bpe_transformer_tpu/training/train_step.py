@@ -519,9 +519,9 @@ def scanned_step_fn(
     """Un-jitted body: ``inner_steps`` optimizer updates via ``lax.scan``.
 
     For small models a single update is microseconds of device work, so
-    throughput is bounded by per-dispatch host latency (severe on relayed/
-    tunneled backends); scanning the update body amortizes that launch cost
-    over ``inner_steps`` real updates — identical math, one dispatch.
+    throughput is bounded by per-dispatch host latency; scanning the update
+    body amortizes that launch cost over ``inner_steps`` real updates —
+    identical math, one dispatch.
 
     ``reduce_axis`` threads through to each inner update's gradient pmean
     (the shard_map dp path).  ``body`` overrides the default single-update

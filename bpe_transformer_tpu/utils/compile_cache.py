@@ -1,63 +1,85 @@
-"""JAX persistent compilation cache wiring (`--compile-cache DIR`).
+"""The one rule for JAX's persistent compilation cache.
 
-Every train/serve start pays a full XLA compile per program (visible in
-the PR-3 compile counters) — 20-40 s each on the real chip — which taxes
-exactly the respawn loop the supervisor runs and every rolling-restart of
-a serve replica.  JAX ships a content-addressed persistent cache keyed on
-the lowered program + compile options + backend version; pointing it at a
-directory that outlives the process turns all of those into disk reads.
+Every process start pays a full XLA compile per program; JAX ships a
+content-addressed persistent cache keyed on the lowered program + compile
+options + backend version, so a directory that outlives the process turns
+those into disk reads.  The directory is part of what makes a hit: one
+that moves (a temp name, a pid, the time) never hits.  So the directory is
+resolved in exactly one place, :func:`resolve_cache_dir`, and ``train``,
+``serve``, ``warmup``, ``chip_smoke.py`` and every bench script call
+:func:`enable_compile_cache` before their first compile:
 
-One function so the CLI, bench queue (``tpu_queue.sh`` exports
-``JAX_COMPILATION_CACHE_DIR`` the env-var way), and tests share the exact
-config-knob set.
+1. ``JAX_COMPILATION_CACHE_DIR`` set  ->  that directory, and no code sets
+   another (JAX reads the variable itself; ``--compile-cache`` is ignored);
+2. else ``--compile-cache DIR`` where the user gave one;
+3. else ``<checkout>/.scratch/jax_ccache`` (git-ignored).
 
-Stability caveat (jax 0.4.x): with the VIRTUAL multi-device CPU platform
-(``--xla_force_host_platform_device_count=N``, the test mesh) the cache
-has been observed aborting the process under donated sharded executions —
-which is why the test suite does not enable it globally and the
-warm-restart test runs single-device subprocesses.  Real single-device
-CPU and TPU backends (where the bench queue has exported the env var for
-rounds) are unaffected.
+One exception: on the CPU backend with neither the variable nor the flag
+the cache stays OFF, so the test suite (and any CPU debugging run) does
+not start writing one.  An explicit variable or flag enables it on the CPU
+too — that is how the warm-restart tests run.
 """
 
 from __future__ import annotations
 
+import os
+import warnings
 from pathlib import Path
 
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+#: Rule 3: a fixed path inside the checkout (``.scratch/`` is git-ignored).
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".scratch" / "jax_ccache"
 
-def enable_compile_cache(cache_dir: str | Path) -> Path:
-    """Enable JAX's persistent compilation cache rooted at ``cache_dir``.
 
-    Creates the directory, points ``jax_compilation_cache_dir`` at it, and
-    zeroes the min-compile-time / min-entry-size thresholds so even the
-    fast-compiling programs of the test/serve ladder are cached (the
-    defaults skip sub-second compiles, which is every program on the CPU
-    test platform).  Threshold knobs that this jax version doesn't have
-    are skipped — the cache still works with its defaults.
+def resolve_cache_dir(
+    flag: str | Path | None = None, *, backend: str | None = None
+) -> Path | None:
+    """The cache directory under the module's rule, or None when the cache
+    stays off (``backend == "cpu"`` with neither the variable nor the
+    flag).  Pure: touches neither jax nor the filesystem."""
+    env = os.environ.get(ENV_VAR)
+    if env:
+        return Path(env)
+    if flag:
+        return Path(flag)
+    if backend == "cpu":
+        return None
+    return DEFAULT_CACHE_DIR
 
-    Safe to call after compiles have already happened: jax latches the
-    cache-disabled state at the first compile of the process, so the
-    latched cache object is reset (best-effort, private API) to pick the
-    new directory up.  Programs compiled before the call are simply not
-    cached.  Returns the cache directory.
+
+def enable_compile_cache(flag: str | Path | None = None) -> Path | None:
+    """Turn the persistent cache on at the resolved directory (see the
+    module docstring) and return it; None when the rule leaves it off.
+
+    Call before the first compile of the process.  The min-compile-time /
+    min-entry-size thresholds are zeroed so even the fast-compiling
+    programs of the serve ladder are cached (the defaults skip sub-second
+    compiles).
+
+    A directory the variable or the flag named must be creatable — failing
+    there is loud.  The checkout default is nobody's request (for an
+    installed package it is the parent of ``site-packages``): when it
+    cannot be created the run warns and goes on uncached.
     """
     import jax
 
-    cache_dir = Path(cache_dir)
-    cache_dir.mkdir(parents=True, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-    for option, value in (
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(option, value)
-        except Exception:
-            pass
+    cache_dir = resolve_cache_dir(flag, backend=jax.default_backend())
+    if cache_dir is None:
+        return None
     try:
-        from jax._src import compilation_cache
-
-        compilation_cache.reset_cache()
-    except Exception:
-        pass
+        cache_dir.mkdir(parents=True, exist_ok=True)
+    except OSError as err:
+        if os.environ.get(ENV_VAR) or flag:
+            raise
+        warnings.warn(
+            f"compile cache off: cannot create {cache_dir} ({err}); set "
+            f"{ENV_VAR} or --compile-cache to a writable directory",
+            RuntimeWarning,
+            stacklevel=2,
+        )
+        return None
+    if not os.environ.get(ENV_VAR):
+        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return cache_dir

@@ -1,0 +1,226 @@
+"""Ahead-of-time compiles for the TPU v5e of every Pallas kernel at the
+widths of the shipped presets.
+
+Interpret mode (what every other kernel test runs) accepts block shapes,
+unaligned stores and VMEM footprints that the chip's compiler refuses.
+The TPU compiler is installed in the sandbox and compiles for a chip that
+is described, not attached — so each case here lowers one kernel with
+``interpret=False`` and compiles it for one device of a ``v5e:2x2``
+topology.  Nothing runs: a pass means "Mosaic and XLA accept this shape",
+never a timing or a result.
+
+The topology is described inside a module-scoped fixture (only the xdist
+worker that is handed this file loads libtpu) and the compile happens in
+the test's own process.  Whole train steps and serving ticks (35-45 s
+each) are NOT here — they live in the builder's scratch script.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from bpe_transformer_tpu.kernels.pallas.decode_attention import (
+    decode_attention,
+    paged_decode_attention,
+)
+from bpe_transformer_tpu.kernels.pallas.flash_attention import flash_attention
+from bpe_transformer_tpu.kernels.pallas.quant_matmul import quant_matmul
+from bpe_transformer_tpu.kernels.pallas.sample import (
+    fused_head_sample,
+    fused_verify_head,
+)
+from bpe_transformer_tpu.kernels.pallas.swiglu import swiglu_fused
+from bpe_transformer_tpu.models.config import GPT2_MEDIUM, GPT2_SMALL_32K
+
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One described v5e device; the persistent compile cache is off while
+    this module runs (a described-device executable cannot be read back)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:  # no libtpu / lock held by another process
+        pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    """Lower ``fn`` on ShapeDtypeStructs placed on the described chip and
+    compile; returns the compiled text (must hold a Mosaic custom call)."""
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in shapes
+    ]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+# ------------------------------------------------------------ training
+
+
+@pytest.mark.parametrize("block", [256, 512])
+def test_flash_attention_fwd_bwd(one_chip, block):
+    qkv = ((8, 12, 1024, 64), BF16)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, True, block, block, False)
+        return jnp.sum(out.astype(F32))
+
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip, qkv, qkv, qkv)
+
+
+@pytest.mark.parametrize(
+    "config", [GPT2_SMALL_32K, GPT2_MEDIUM], ids=["d_ff2048", "d_ff2731"]
+)
+def test_swiglu(one_chip, config):
+    """Forward only: the kernel's backward is plain XLA recompute."""
+    d, ff = config.d_model, config.d_ff
+    _compile(
+        lambda x, w1, w2, w3: swiglu_fused(x, w1, w2, w3, interpret=False),
+        one_chip,
+        ((512, d), BF16), ((ff, d), BF16), ((d, ff), BF16), ((ff, d), BF16),
+    )
+
+
+# ------------------------------------------------------------- serving
+
+
+def _matmul_shapes(config):
+    """Every (d_out, d_in) `ops.quant.quantize_params` produces for a dense
+    config: q/k/v/o, FFN w1/w3 and w2, and the LM head."""
+    d, ff, v = config.d_model, config.d_ff, config.vocab_size
+    return [(d, d), (ff, d), (d, ff), (v, d)]
+
+
+#: + the tinystories-12l head: vocab 10,000 has no 128-multiple divisor.
+QUANT_SHAPES = sorted(
+    set(_matmul_shapes(GPT2_SMALL_32K))
+    | set(_matmul_shapes(GPT2_MEDIUM))
+    | {(10_000, 512)}
+)
+
+
+@pytest.mark.parametrize("rows", [8, 512], ids=["tick", "prefill512"])
+@pytest.mark.parametrize(
+    "d_out,d_in", QUANT_SHAPES, ids=[f"{o}x{i}" for o, i in QUANT_SHAPES]
+)
+def test_quant_matmul(one_chip, d_out, d_in, rows):
+    _compile(
+        lambda x, q, s: quant_matmul(x, q, s, interpret=False), one_chip,
+        ((rows, d_in), BF16), ((d_out, d_in), I8), ((d_out,), F32),
+    )
+
+
+@pytest.mark.parametrize(
+    "heads,d_head", [(12, 64), (16, 64)], ids=["small", "medium"]
+)
+def test_decode_attention(one_chip, heads, d_head):
+    cache = ((8, heads, 1024, d_head), BF16)
+    _compile(
+        lambda q, k, v, pos: decode_attention(q, k, v, pos, interpret=False),
+        one_chip, ((8, heads, d_head), BF16), cache, cache, ((8,), I32),
+    )
+
+
+@pytest.mark.parametrize("block_size", [16, 32, 128])
+@pytest.mark.parametrize("kv_dtype", [BF16, I8], ids=["bf16", "int8"])
+def test_paged_decode_attention(one_chip, kv_dtype, block_size):
+    slots, heads, d_head, num_blocks = 8, 12, 64, 515
+    pool = ((num_blocks, heads, block_size, d_head), kv_dtype)
+    shapes = [
+        ((slots, heads, d_head), BF16), pool, pool,
+        ((slots, 1024 // block_size), I32), ((slots,), I32),
+    ]
+    if kv_dtype == I8:
+        shapes += [((num_blocks, heads), F32)] * 2
+
+        def fn(q, k, v, tables, pos, ks, vs):
+            return paged_decode_attention(
+                q, k, v, tables, pos, k_scale=ks, v_scale=vs, interpret=False
+            )
+    else:
+
+        def fn(q, k, v, tables, pos):
+            return paged_decode_attention(
+                q, k, v, tables, pos, interpret=False
+            )
+
+    _compile(fn, one_chip, *shapes)
+
+
+def _head_shapes(vocab, d, head_dtype):
+    if head_dtype == I8:
+        return [((vocab, d), I8), ((vocab,), F32)]
+    return [((vocab, d), BF16)]
+
+
+def _as_head(head_args):
+    return (
+        {"q": head_args[0], "scale": head_args[1]}
+        if len(head_args) == 2 else head_args[0]
+    )
+
+
+#: One head width per (kernel, vocab): the int8 head differs from the bf16
+#: one by a (block_v, 1) scale tile, and each of these compiles costs 2-7 s
+#: (Mosaic unrolls the 64 radix passes over the whole row tile).
+SAMPLE_HEADS = [
+    pytest.param(10_000, 512, I8, id="v10000-int8"),
+    pytest.param(32_000, 768, BF16, id="v32000-bf16"),
+]
+VERIFY_HEADS = [
+    pytest.param(10_000, 256, BF16, id="v10000-bf16"),
+    pytest.param(32_000, 1024, I8, id="v32000-int8"),
+]
+
+
+@pytest.mark.parametrize("vocab,d,head_dtype", SAMPLE_HEADS)
+def test_fused_head_sample(one_chip, vocab, d, head_dtype):
+    rows = 8
+    head = _head_shapes(vocab, d, head_dtype)
+
+    def fn(hidden, temps, top_ks, top_ps, gumbel, *head_args):
+        return fused_head_sample(
+            hidden, _as_head(head_args), temps, top_ks, top_ps, gumbel,
+            interpret=False,
+        )
+
+    _compile(
+        fn, one_chip, ((rows, d), BF16), ((rows,), F32), ((rows,), I32),
+        ((rows,), F32), ((rows, vocab), F32), *head,
+    )
+
+
+@pytest.mark.parametrize("vocab,d,head_dtype", VERIFY_HEADS)
+def test_fused_verify_head(one_chip, vocab, d, head_dtype):
+    rows = 8 * 3  # slots * (K + 1)
+    head = _head_shapes(vocab, d, head_dtype)
+
+    def fn(hidden, temps, top_ks, top_ps, judge, q_probs, gumbel, *head_args):
+        return fused_verify_head(
+            hidden, _as_head(head_args), temps, top_ks, top_ps, judge,
+            q_probs, gumbel, interpret=False,
+        )
+
+    _compile(
+        fn, one_chip, ((rows, d), BF16), ((rows,), F32), ((rows,), I32),
+        ((rows,), F32), ((rows,), I32), ((rows, vocab), F32),
+        ((rows, vocab), F32), *head,
+    )

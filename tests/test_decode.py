@@ -18,6 +18,18 @@ from bpe_transformer_tpu.models.decode import (
 
 CFG = dataclasses.replace(TS_TEST_CONFIG, vocab_size=512, context_length=32)
 
+# Compiled, not eager: an eager call dispatches (and compiles) every op of
+# the model one by one, and these tests call the three functions dozens of
+# times.  ModelConfig is a frozen dataclass, so it rides as a static arg.
+forward = jax.jit(
+    forward, static_argnums=(2,), static_argnames=("config", "return_aux")
+)
+prefill = jax.jit(prefill, static_argnums=(2,), static_argnames=("config",))
+decode_step = jax.jit(
+    decode_step, static_argnums=(4,),
+    static_argnames=("config", "return_hidden"),
+)
+
 
 @pytest.fixture(scope="module")
 def setup():

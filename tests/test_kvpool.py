@@ -737,21 +737,25 @@ def test_int8_logit_error_bound(setup):
     tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
     chunk = jnp.asarray([prompt + [0] * (16 - len(prompt))], jnp.int32)
 
+    # Compiled once per pool width: eager calls would dispatch (and
+    # compile) every op of the model one by one, nine times over.
+    active = jnp.asarray([True, False])
+    prefill = jax.jit(lambda pool: paged_chunk_prefill(
+        params, chunk, jnp.int32(0), jnp.int32(len(prompt)), tables[0],
+        pool, CFG, block_size=bs,
+    ))
+    step = jax.jit(lambda toks, pos, pool: paged_decode_step(
+        params, toks, pos, pool, tables, CFG, active=active, block_size=bs,
+    ))
+
     def drive(kv_dtype):
         pool = init_kv_pool(CFG, 9, bs, kv_dtype=kv_dtype)
-        logits, pool = paged_chunk_prefill(
-            params, chunk, jnp.int32(0), jnp.int32(len(prompt)), tables[0],
-            pool, CFG, block_size=bs,
-        )
+        logits, pool = prefill(pool)
         rows = [logits]
         tok = int(jnp.argmax(logits[0]))
         pos = jnp.asarray([len(prompt), 0], jnp.int32)
-        active = jnp.asarray([True, False])
         for _ in range(8):
-            logits, pool = paged_decode_step(
-                params, jnp.asarray([tok, 0], jnp.int32), pos, pool, tables,
-                CFG, active=active, block_size=bs,
-            )
+            logits, pool = step(jnp.asarray([tok, 0], jnp.int32), pos, pool)
             rows.append(logits[0:1])
             tok = int(jnp.argmax(logits[0]))  # teacher = fp32 path's argmax
             pos = pos + jnp.asarray([1, 0], jnp.int32)

@@ -270,6 +270,22 @@ def test_gqa_equals_mha_with_repeated_kv_weights():
     )
 
 
+def _greedy_reference(params, cfg, prompt, n_new):
+    """The full-forward argmax loop cached decode is compared with, as ONE
+    compiled program: the growing sequence is right-padded to the context
+    (causal attention never looks at what follows), where an eager forward
+    per length would re-trace every op n_new times."""
+    from bpe_transformer_tpu.models import forward
+
+    fwd = jax.jit(lambda p, x: forward(p, x, cfg))
+    seq = list(prompt)
+    for _ in range(n_new):
+        padded = seq + [0] * (cfg.context_length - len(seq))
+        logits = fwd(params, jnp.asarray([padded], jnp.int32))
+        seq.append(int(jnp.argmax(logits[0, len(seq) - 1])))
+    return seq[len(prompt):]
+
+
 def test_gqa_cached_decode_parity_and_cache_shape():
     """GQA: the KV cache holds only num_kv_heads, and cached greedy decode
     matches the full-forward argmax loop."""
@@ -294,11 +310,9 @@ def test_gqa_cached_decode_parity_and_cache_shape():
         max_new_tokens=8,
         temperature=0.0,
     )
-    seq = list(prompt)
-    for _ in range(8):
-        logits = forward(params, jnp.asarray([seq], jnp.int32), cfg)
-        seq.append(int(jnp.argmax(logits[0, -1])))
-    assert [int(t) for t in np.asarray(out[0])] == seq[len(prompt):]
+    assert [int(t) for t in np.asarray(out[0])] == _greedy_reference(
+        params, cfg, prompt, 8
+    )
 
 
 def test_tied_embeddings_share_head():
@@ -345,11 +359,9 @@ def test_tied_embeddings_share_head():
         params, jnp.asarray([prompt], jnp.int32), jax.random.PRNGKey(0),
         config=cfg, max_new_tokens=6, temperature=0.0,
     )
-    seq = list(prompt)
-    for _ in range(6):
-        lg = forward(params, jnp.asarray([seq], jnp.int32), cfg)
-        seq.append(int(jnp.argmax(lg[0, -1])))
-    assert [int(t) for t in np.asarray(out[0])] == seq[len(prompt):]
+    assert [int(t) for t in np.asarray(out[0])] == _greedy_reference(
+        params, cfg, prompt, 6
+    )
 
     # Chunked-loss path exercises lm_head_weight too.
     cfg_chunk = dataclasses.replace(cfg, loss_chunk_size=8)

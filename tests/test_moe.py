@@ -78,8 +78,10 @@ def test_gather_dispatch_matches_einsum(top_k):
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.normal(size=(4, 8, cfg.d_model)).astype(np.float32))
 
-    out_e, aux_e = switch_ffn(x, params, cfg)
-    out_g, aux_g = switch_ffn(x, params, cfg_g)
+    # Compiled (config static), not eager op-by-op dispatch.
+    ffn = jax.jit(switch_ffn, static_argnums=2)
+    out_e, aux_e = ffn(x, params, cfg)
+    out_g, aux_g = ffn(x, params, cfg_g)
     np.testing.assert_allclose(np.asarray(out_g), np.asarray(out_e), atol=1e-5)
     np.testing.assert_allclose(float(aux_g), float(aux_e), rtol=1e-6)
 
@@ -87,8 +89,9 @@ def test_gather_dispatch_matches_einsum(top_k):
         o, a = switch_ffn(x, p, c)
         return jnp.sum(o**2) + a
 
-    g_e = jax.grad(loss)(params, cfg)
-    g_g = jax.grad(loss)(params, cfg_g)
+    grad = jax.jit(jax.grad(loss), static_argnums=1)
+    g_e = grad(params, cfg)
+    g_g = grad(params, cfg_g)
     for k in g_e:
         np.testing.assert_allclose(
             np.asarray(g_g[k]), np.asarray(g_e[k]), atol=1e-4

@@ -1,9 +1,8 @@
-"""Regression tests for benchmarks/northstar.py's jax phase — the TPU
-queue's highest-priority job (VERDICT r4 #1).  Runs the real phase_jax on
-CPU at a 4-step protocol against a temp torch-reference artifact, covering
-the self-describing capture fields (ADVICE r4), the exhausted-checkpoint
-cleanup, the mismatched-checkpoint discard, and the legacy /tmp checkpoint
-migration (VERDICT r4 #8) — the paths a tunnel window exercises with no
+"""Regression tests for benchmarks/northstar.py's jax phase.  Runs the real
+phase_jax on CPU at a 4-step protocol against a temp torch-reference
+artifact, covering the self-describing capture fields, the
+exhausted-checkpoint cleanup, the mismatched-checkpoint discard, and the
+legacy /tmp checkpoint migration — the paths a chip call exercises with no
 chance to debug."""
 
 import json

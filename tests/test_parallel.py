@@ -49,16 +49,10 @@ def _setup(seed=0):
     return params, opt_state, jnp.asarray(x), jnp.asarray(y)
 
 
-def test_shard_map_shim_exposes_modern_api():
-    """compat.shardmap: `jax.shard_map` resolves on jax 0.4.x (aliased from
-    jax.experimental) and accepts the modern check_vma= keyword this repo
-    uses; jax.lax.axis_size exists alongside it.  Idempotent."""
-    from bpe_transformer_tpu.compat.shardmap import ensure_shard_map
-
-    fn = ensure_shard_map()
-    assert fn is ensure_shard_map()  # second call returns the same object
-    assert jax.shard_map is fn
-    assert callable(jax.lax.axis_size)
+def test_shard_map_modern_api_is_what_the_installed_jax_exports():
+    """The parallel strategies call `jax.shard_map(check_vma=...)` and
+    `jax.lax.axis_size` directly (no shim since PR 22): the installed jax
+    must export both, with the semantics the strategies rely on."""
     mesh = make_mesh({"data": 8})
     mapped = jax.shard_map(
         lambda x: jax.lax.psum(x, "data") + jax.lax.axis_size("data"),
@@ -197,7 +191,7 @@ def test_sp_grad_accum_matches_full_batch_step(zigzag):
     """Gradient accumulation INSIDE the sp (ring attention) program: each
     chip scans its local microbatch shards, one pmean over (data, seq) per
     update, and the result equals the single-device full-batch update —
-    the long-context HBM-relief combo (VERDICT r3 #9)."""
+    the long-context HBM-relief combo."""
     from bpe_transformer_tpu.parallel import make_sp_train_step, shard_sp_batch
 
     accum = 2
@@ -332,7 +326,7 @@ def test_pp_grad_accum_matches_full_batch_step():
     """Gradient accumulation AROUND the pipeline: each accumulation slice
     runs the full GPipe schedule, gradients sum in f32 through the shared
     accumulate_grads, and one update equals the single-device full-batch
-    step (closes the last pp NotImplementedError; VERDICT r4 minor)."""
+    step (closes the last pp NotImplementedError)."""
     from bpe_transformer_tpu.parallel.pp import (
         init_pp_opt_state,
         make_pp_train_step,
@@ -616,7 +610,7 @@ def test_sp_flash_with_ring_kv_chunk_raises():
 def test_dp_grad_accum_matches_full_batch_step():
     """Gradient accumulation under the explicit-collective dp mesh: scanning
     2 microbatches per chip then one all-reduced update equals the
-    single-device full-batch step (VERDICT r2 #5)."""
+    single-device full-batch step."""
     params, opt_state, x, y = _setup()
     single = make_train_step(CFG, HP)
     p1, s1, m1 = single(params, opt_state, x, y)
@@ -677,7 +671,7 @@ def test_gspmd_grad_accum_matches_full_batch_step(strategy, axes, accum):
 @pytest.mark.slow
 def test_dp_inner_steps_match_sequential_dp_steps():
     """inner_steps under the dp mesh: one scanned dispatch of 3 updates
-    equals 3 sequential dp steps (VERDICT r2 #5)."""
+    equals 3 sequential dp steps."""
     mesh = make_mesh({"data": 8})
     params, opt_state, x, y = _setup()
     seq_step = make_dp_train_step(CFG, HP, mesh)

@@ -95,21 +95,27 @@ def test_quantize_weight_layout_and_error_bound():
     assert float(jnp.abs(dequantize(w0)[5]).max()) == 0.0
 
 
-@pytest.mark.parametrize("shape", [(8, 683, 256), (3, 97, 64), (1, 40, 32)])
+@pytest.mark.parametrize(
+    "shape", [(8, 683, 256), (3, 97, 64), (1, 40, 32), (2, 4100, 16)]
+)
 def test_quant_matmul_kernel_matches_xla_reference(shape):
-    """The Pallas dequant-in-register matmul equals the XLA reference
-    bitwise-close on every block layout (odd d_out falls back to the
-    whole-array tile)."""
+    """The Pallas dequant-in-register matmul equals the XLA reference to
+    f32 rounding on every block layout: a d_out with no 128-multiple
+    divisor runs 128-multiple tiles with a ragged last one (683, 4100) or
+    one padded tile (97, 40), so the dot's summation order differs from
+    XLA's whole-axis one — the bound is a few ulp of the output's scale
+    (measured <= 8e-7 of it, and within 2x of XLA's own error against an
+    f64 product)."""
     m, o, i = shape
     rng = np.random.default_rng(1)
     wq = quantize_weight(
         jnp.asarray(rng.normal(size=(o, i)).astype(np.float32))
     )
     x = jnp.asarray(rng.normal(size=(m, i)).astype(np.float32))
+    ref = np.asarray(quant_linear_xla(x, wq))
     np.testing.assert_allclose(
-        np.asarray(quant_linear(x, wq)),
-        np.asarray(quant_linear_xla(x, wq)),
-        rtol=0, atol=1e-5,
+        np.asarray(quant_linear(x, wq)), ref,
+        rtol=0, atol=2e-6 * float(np.abs(ref).max()),
     )
 
 

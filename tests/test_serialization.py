@@ -13,15 +13,20 @@ from bpe_transformer_tpu.models import TS_TEST_CONFIG, forward, init_params
 from bpe_transformer_tpu.optim import adamw_init, adamw_update
 
 
-def _train_a_bit(params, state, steps=3):
-    def loss_fn(p, ids):
-        logits = forward(p, ids, TS_TEST_CONFIG)
-        return logits.mean()
+@jax.jit
+def _train_step(params, state, ids):
+    """One compiled update (an eager grad + AdamW dispatches — and
+    compiles — every op of the model one by one, three times over)."""
+    def loss_fn(p):
+        return forward(p, ids, TS_TEST_CONFIG).mean()
 
+    return adamw_update(params, jax.grad(loss_fn)(params), state, lr=1e-3)
+
+
+def _train_a_bit(params, state, steps=3):
     ids = jnp.zeros((2, 8), dtype=jnp.int32)
     for _ in range(steps):
-        grads = jax.grad(loss_fn)(params, ids)
-        params, state = adamw_update(params, grads, state, lr=1e-3)
+        params, state = _train_step(params, state, ids)
     return params, state
 
 

@@ -603,7 +603,7 @@ def test_report_compare_fixture_pair_gates_regression(capsys):
 
 def test_report_baseline_capture_gate(tmp_path, capsys):
     """--baseline gates a stream against a bench capture JSON (and a
-    capture against a previous capture — the tpu_queue.sh self-report)."""
+    capture against a previous capture)."""
     from bpe_transformer_tpu.telemetry.report import main as report_main
 
     capture = tmp_path / "tpu_capture_test.json"

@@ -1,5 +1,5 @@
 """Unit tests for benchmarks/bench_tokenization.usable_cores — the gate of
-the armed multi-worker capture trap (VERDICT r4 #7).  A wrong answer either
+the armed multi-worker capture trap.  A wrong answer either
 keeps the trap disarmed forever on a real multicore host or fires it with a
 fantasy grid on a quota-throttled one, so the affinity ∧ cgroup-quota logic
 gets direct tests."""

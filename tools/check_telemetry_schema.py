@@ -7,7 +7,7 @@ Three checks, all static/jax-free (wired into tier-1 via
 
 1. **Source sweep** — grep ``bpe_transformer_tpu/`` (every subpackage: the
    ``resilience/`` emitters' preemption/recovery kinds included, plus
-   ``bench.py``, ``benchmarks/`` and ``tools/``) for every
+   ``chip_smoke.py``, ``benchmarks/`` and ``tools/``) for every
    ``"kind": "..."`` / ``kind="..."`` literal an emitter writes; each must
    be a key of ``RECORD_SCHEMAS``.  A new record kind cannot ship
    undocumented.
@@ -57,7 +57,7 @@ def emitted_kinds() -> dict[str, list[str]]:
     kinds: dict[str, list[str]] = {}
     roots = [REPO / "bpe_transformer_tpu", REPO / "benchmarks", REPO / "tools"]
     files = [p for root in roots for p in sorted(root.rglob("*.py"))]
-    files += [REPO / "bench.py"]
+    files += [REPO / "chip_smoke.py"]
     for path in files:
         if path == Path(__file__).resolve():
             continue

@@ -2,7 +2,9 @@
 """Guard: the tier-1 (``-m 'not slow'``) suite must stay inside its wall
 budget.
 
-The driver gives tier-1 a hard 870 s timeout (ROADMAP "Tier-1 verify");
+The driver gives tier-1 a hard timeout (1,470 s with ``-n 6``
+as of PR 22 — the commands it really runs are in
+``/root/TESTS_LAST_RUN.json``; ROADMAP's "Tier-1 verify" line is older);
 PR 9 already had to sweep 27 heavy tests behind ``slow`` to fit it, and
 every PR since has grown the suite.  A suite that silently creeps past
 the budget doesn't fail gracefully — it gets KILLED mid-run and reports
@@ -12,11 +14,11 @@ that happens, two jax-free ways:
 * **Log mode** (default, given a pytest log file): parse the summary
   trailer (``... passed ... in 612.34s``) of a finished tier-1 run —
   e.g. the ``/tmp/_t1.log`` the ROADMAP verify command tees — and fail
-  when the measured wall exceeds ``--budget`` (default 800 s, a ~8%
-  margin under the 870 s kill).
+  when the measured wall exceeds ``--budget`` (default 800 s; a whole
+  run took 89-214 s at PR 22, so that is a wide margin under the kill).
 * **Count mode** (``--collect``): run ``pytest --collect-only -q -m 'not
   slow'`` and fail when the tier-1 test COUNT exceeds ``--max-tests``
-  (default 520).  A proxy, not a measurement — but it runs in seconds,
+  (default: :data:`DEFAULT_MAX_TESTS`).  A proxy, not a measurement — but it runs in seconds,
   so it can gate a commit that adds a pile of unmarked tests without
   re-running the suite.  When the ceiling is hit legitimately (cheap
   tests), raise it here *in the same commit* that adds them — the point
@@ -36,8 +38,8 @@ from pathlib import Path
 
 REPO = Path(__file__).resolve().parent.parent
 
-#: Wall budget for a finished tier-1 run (seconds) — under the driver's
-#: 870 s timeout with margin for runner variance.
+#: Wall budget for a finished tier-1 run (seconds) — well under the
+#: driver's 1,470 s timeout, with margin for runner variance.
 DEFAULT_BUDGET_S = 800.0
 
 #: Tier-1 test-count ceiling for --collect mode.  ~430 tests ran in
@@ -49,8 +51,12 @@ DEFAULT_BUDGET_S = 800.0
 #: 545 -> 570 in PR 17 for the self-healing control plane
 #: (tests/test_controller.py decide/breaker/spawner pins, migration wire
 #: v2 CRC+codec, router suspect quarantine, serving fault hooks, import
-#: idempotency); its heavy fleet chaos e2e is marked slow.
-DEFAULT_MAX_TESTS = 570
+#: idempotency); its heavy fleet chaos e2e is marked slow.  Raised
+#: 570 -> 630 in PR 22 (627 collected) for the v5e AOT kernel compiles
+#: (tests/test_chip_compile.py, 34 cases, ~1-5 s each) and the chip_smoke
+#: / compile-cache contract tests (tests/test_chip_smoke.py, 40 cases, no
+#: model); tests/test_bench_capture.py (13) went with bench.py.
+DEFAULT_MAX_TESTS = 630
 
 #: Pytest summary trailer: "== 398 passed, 27 deselected in 612.34s =="
 #: (also plain "in 612.34s (0:10:12)" forms).
@@ -87,7 +93,7 @@ def check_log(path: Path, budget_s: float) -> int:
         print(
             "tier1-budget: the 'not slow' suite is over budget — move "
             "heavy tests behind the slow marker (PR 9 precedent) before "
-            "the driver's 870s timeout starts killing runs",
+            "the driver's timeout starts killing runs",
             file=sys.stderr,
         )
         return 1
