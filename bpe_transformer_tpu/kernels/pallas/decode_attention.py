@@ -104,6 +104,7 @@ def _decode_kernel(
         o_ref[0] = (acc_ref[:] / denom).astype(o_ref.dtype)
 
 
+@jax.named_scope("decode_attn")
 def decode_attention(
     q: jax.Array,
     k_cache: jax.Array,
@@ -215,6 +216,7 @@ def decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bkv, g_pad, d), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(pos_arr, qg, kp, vp)
     return out[:, :group, :].reshape(batch, num_heads, d)
 
@@ -290,6 +292,7 @@ def _paged_decode_kernel(
         o_ref[0] = (acc_ref[:] / denom).astype(o_ref.dtype)
 
 
+@jax.named_scope("decode_attn")
 def paged_decode_attention(
     q: jax.Array,
     k_pool: jax.Array,
@@ -429,10 +432,12 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((skv, g_pad, d), out_dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(tables, pos_arr, *inputs)
     return out[:, :group, :].reshape(slots, num_heads, d)
 
 
+@jax.named_scope("decode_attn")
 def xla_decode_attention(q, k_cache, v_cache, pos):
     """Materialized-scores formulation: the grouped einsum straight against
     the compact GQA cache (the per-token hot path reads only

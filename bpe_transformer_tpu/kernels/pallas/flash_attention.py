@@ -269,6 +269,7 @@ def _flash_impl(
         out_specs=out_spec,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_attention_fwd",
     )(*operands)
 
     if return_lse:
@@ -434,6 +435,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_k, d_pad), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dkdv",
     )(qp, dop, lse_b, delta_b, kp, vp)
 
     # dQ: grid (bh, nq, nk), key axis innermost.
@@ -452,6 +454,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
         out_specs=qspec(by_q2),
         scratch_shapes=[pltpu.VMEM((block_q, d_pad), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(qp, dop, lse_b, delta_b, kp, vp)
 
     unpad = lambda x: x[:, :s, :d].reshape(*batch, s, d)

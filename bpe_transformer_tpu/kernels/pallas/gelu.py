@@ -74,6 +74,7 @@ def gelu(x: jax.Array) -> jax.Array:
             memory_space=pltpu.VMEM,
         ),
         interpret=interpret,
+        name="gelu",
     )(tiled)
 
     return out.reshape(-1)[:n].reshape(original_shape)

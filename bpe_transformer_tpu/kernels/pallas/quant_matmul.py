@@ -121,5 +121,6 @@ def quant_matmul(
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m_pad, n), jnp.float32),
         interpret=interpret,
+        name="quant_matmul",
     )(x2, q, scale.reshape(n, 1))
     return out[:m].reshape(*lead, n)

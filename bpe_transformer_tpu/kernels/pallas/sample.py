@@ -277,7 +277,7 @@ def _head_operands(head, v, d):
 
 
 def _run(kernel_body, hidden, head, knobs, extra_inputs, out_shapes,
-         *, vocab, interpret):
+         *, vocab, interpret, name):
     """Shared pallas_call assembly for both entry points: grid =
     ``(row tiles, vocab tiles)`` with the vocab axis innermost, so each
     row tile's logits fully accumulate in the ``(block_r, vocab)``
@@ -339,6 +339,7 @@ def _run(kernel_body, hidden, head, knobs, extra_inputs, out_shapes,
         ],
         scratch_shapes=[pltpu.VMEM((br, v_pad), jnp.float32)],
         interpret=interpret,
+        name=name,
     )(*inputs)
     return [o[:r, 0] for o in outs]
 
@@ -376,7 +377,7 @@ def fused_head_sample(
     (tok,) = _run(
         _sample_kernel, hidden, head, knobs,
         [gumbel.astype(jnp.float32)], [jnp.int32],
-        vocab=vocab, interpret=interpret,
+        vocab=vocab, interpret=interpret, name="fused_head_sample",
     )
     return tok
 
@@ -419,6 +420,6 @@ def fused_verify_head(
             gumbel.astype(jnp.float32),
         ],
         [jnp.int32, jnp.float32, jnp.int32],
-        vocab=vocab, interpret=interpret,
+        vocab=vocab, interpret=interpret, name="fused_verify_head",
     )
     return greedy, p_d, bonus

@@ -69,6 +69,7 @@ def _swiglu_impl(x2d, w1, w3, w2, block_m, block_f, interpret):
             (block_m, d), lambda i, j: (i, 0), memory_space=pltpu.VMEM
         ),
         interpret=interpret,
+        name="swiglu_fused",
     )(x2d, w1, w3, w2)
 
 

@@ -99,17 +99,31 @@ def _block_apply(x, block_params, config, attend):
     default, post-norm under the ablation flag.
     """
     if config.use_post_norm:
-        x = _norm(x + attend(x), block_params["ln1"], config)
-        f = _ffn_decode(x, block_params["ffn"], config)
-        return _norm(x + f, block_params["ln2"], config)
-    h = _norm(x, block_params["ln1"], config)
-    x = x + attend(h)
-    h = _norm(x, block_params["ln2"], config)
-    return x + _ffn_decode(h, block_params["ffn"], config)
+        with jax.named_scope("block/attn"):
+            x = _norm(x + attend(x), block_params["ln1"], config)
+        with jax.named_scope("block/ffn"):
+            f = _ffn_decode(x, block_params["ffn"], config)
+            return _norm(x + f, block_params["ln2"], config)
+    with jax.named_scope("block/attn"):
+        h = _norm(x, block_params["ln1"], config)
+        x = x + attend(h)
+    with jax.named_scope("block/ffn"):
+        h = _norm(x, block_params["ln2"], config)
+        return x + _ffn_decode(h, block_params["ffn"], config)
 
 
 def _norm(x, w, config):
     return x if config.remove_rmsnorm else rmsnorm(x, w)
+
+
+def _embed(params, token_ids):
+    with jax.named_scope("embed"):
+        return embedding(params["token_embeddings"], token_ids)
+
+
+def _final_norm(x, params, config):
+    with jax.named_scope("final_norm"):
+        return _norm(x, params["ln_final"], config)
 
 
 def _project_qkv(h, attn, config):
@@ -153,7 +167,7 @@ def prefill(
     """
     batch, plen = token_ids.shape
     positions = jnp.arange(plen)
-    x = embedding(params["token_embeddings"], token_ids)
+    x = _embed(params, token_ids)
     # Long prompts honor the config's flash kernel: the materialized path
     # needs an O(plen^2) score buffer per layer, which is exactly the
     # memory wall the training side removes with flash attention.  RoPE is
@@ -194,7 +208,7 @@ def prefill(
 
         x = _block_apply(x, block_params, config, attend)
 
-    x = _norm(x, params["ln_final"], config)
+    x = _final_norm(x, params, config)
     head = lm_head_weight(params, config) if lm_head is None else lm_head
     # head_logits: activation-dtype matmul, f32 accumulation — the
     # head read (decode's per-token bandwidth bottleneck alongside the
@@ -247,7 +261,7 @@ def decode_step(
     fused sample-in-kernel tick (`kernels/pallas/sample.py`) owns the
     projection then, so logits never materialize in HBM.
     """
-    x = embedding(params["token_embeddings"], token[:, None])  # (B, 1, d)
+    x = _embed(params, token[:, None])  # (B, 1, d)
     positions = pos[None] if jnp.ndim(pos) == 0 else pos[:, None]  # (1,)|(B,1)
 
     new_cache = []
@@ -291,7 +305,7 @@ def decode_step(
 
         x = _block_apply(x, block_params, config, attend)
 
-    x = _norm(x, params["ln_final"], config)
+    x = _final_norm(x, params, config)
     if return_hidden:
         return x[:, 0], new_cache
     head = lm_head_weight(params, config) if lm_head is None else lm_head
@@ -344,6 +358,7 @@ def init_kv_pool(
     return layers
 
 
+@jax.named_scope("pool_gather")
 def gather_paged_kv(buf: Array, tables: Array) -> Array:
     """Materialize contiguous per-slot KV from the pool through the block
     table: ``buf`` (num_blocks, kv_heads, block_size, d_head) gathered by
@@ -372,11 +387,13 @@ def gather_paged_kv_dequant(
     ever materializing this buffer."""
     bs = buf.shape[2]
     gathered = gather_paged_kv(buf, tables)          # (S, kv, nb*bs, dh)
-    scales = jnp.transpose(scale[tables], (0, 2, 1))  # (S, kv, nb)
-    scales = jnp.repeat(scales, bs, axis=2)[..., None]
-    return (gathered.astype(jnp.float32) * scales).astype(dtype)
+    with jax.named_scope("pool_gather"):
+        scales = jnp.transpose(scale[tables], (0, 2, 1))  # (S, kv, nb)
+        scales = jnp.repeat(scales, bs, axis=2)[..., None]
+        return (gathered.astype(jnp.float32) * scales).astype(dtype)
 
 
+@jax.named_scope("pool_write")
 def _quantize_decode_row(
     pool_arr: Array, scale_arr: Array, new_row: Array, write_ids, offsets
 ) -> tuple[Array, Array]:
@@ -448,7 +465,7 @@ def paged_decode_step(
     ``"pallas"``/``"xla"`` keep the :func:`gather_paged_kv` reference path
     (dequantizing on gather for int8 pools).
     """
-    x = embedding(params["token_embeddings"], token[:, None])  # (S, 1, d)
+    x = _embed(params, token[:, None])  # (S, 1, d)
     positions = pos[:, None]
     block_col = (pos // block_size).astype(jnp.int32)
     offsets = (pos % block_size).astype(jnp.int32)
@@ -483,12 +500,13 @@ def paged_decode_step(
             else:
                 # Explicit cast to the pool width: jax 0.9 deprecates the
                 # implicit one (an f32 row into a bf16 pool).
-                k_pool = layer_pool["k"].at[write_ids, :, offsets, :].set(
-                    k[:, :, 0, :].astype(layer_pool["k"].dtype)
-                )
-                v_pool = layer_pool["v"].at[write_ids, :, offsets, :].set(
-                    v[:, :, 0, :].astype(layer_pool["v"].dtype)
-                )
+                with jax.named_scope("pool_write"):
+                    k_pool = layer_pool["k"].at[write_ids, :, offsets, :].set(
+                        k[:, :, 0, :].astype(layer_pool["k"].dtype)
+                    )
+                    v_pool = layer_pool["v"].at[write_ids, :, offsets, :].set(
+                        v[:, :, 0, :].astype(layer_pool["v"].dtype)
+                    )
                 new_pool.append({"k": k_pool, "v": v_pool})
             if config.decode_attention_impl == "paged":
                 from bpe_transformer_tpu.kernels.pallas.decode_attention import (
@@ -529,7 +547,7 @@ def paged_decode_step(
 
         x = _block_apply(x, block_params, config, attend)
 
-    x = _norm(x, params["ln_final"], config)
+    x = _final_norm(x, params, config)
     if return_hidden:
         return x[:, 0], new_pool
     head = lm_head_weight(params, config) if lm_head is None else lm_head
@@ -593,13 +611,14 @@ def paged_chunk_prefill(
     offsets = safe_positions % block_size
     quantized = "k_scale" in pool[0]
 
-    x = embedding(params["token_embeddings"], chunk_tokens)
+    x = _embed(params, chunk_tokens)
     scale = 1.0 / jnp.sqrt(jnp.asarray(config.d_head, jnp.float32))
     # (cb, ctx) causal frontier: key j visible to chunk row i iff j <= start+i.
     mask = (
         jnp.arange(nb * block_size)[None, :] <= (start + jnp.arange(cb))[:, None]
     )
 
+    @jax.named_scope("pool_write")
     def _quant_chunk_rows(pool_arr, scale_arr, rows):
         """Per-block scatter of this chunk's (cb, kv, d) rows: reset the
         written blocks' scales, scatter-max the rows' absmax in, quantize
@@ -644,28 +663,36 @@ def paged_chunk_prefill(
                     v_pool, v_scale, table_row[None], h.dtype
                 )
             else:
-                k_pool = layer_pool["k"].at[write_ids, :, offsets, :].set(
-                    jnp.transpose(k[0], (1, 0, 2)).astype(layer_pool["k"].dtype)
-                )
-                v_pool = layer_pool["v"].at[write_ids, :, offsets, :].set(
-                    jnp.transpose(v[0], (1, 0, 2)).astype(layer_pool["v"].dtype)
-                )
+                with jax.named_scope("pool_write"):
+                    k_pool = layer_pool["k"].at[write_ids, :, offsets, :].set(
+                        jnp.transpose(k[0], (1, 0, 2)).astype(
+                            layer_pool["k"].dtype
+                        )
+                    )
+                    v_pool = layer_pool["v"].at[write_ids, :, offsets, :].set(
+                        jnp.transpose(v[0], (1, 0, 2)).astype(
+                            layer_pool["v"].dtype
+                        )
+                    )
                 new_pool.append({"k": k_pool, "v": v_pool})
                 k_cache = gather_paged_kv(k_pool, table_row[None])
                 v_cache = gather_paged_kv(v_pool, table_row[None])
-            k_full = _expand_kv(k_cache, config)
-            v_full = _expand_kv(v_cache, config)
-            scores = jnp.einsum("bhqd,bhkd->bhqk", q, k_full) * scale
-            scores = jnp.where(mask[None, None], scores, -jnp.inf)
-            probs = jax.nn.softmax(
-                scores.astype(jnp.float32), axis=-1
-            ).astype(h.dtype)
-            att = merge_heads(jnp.einsum("bhqk,bhkd->bhqd", probs, v_full))
+            with jax.named_scope("chunk_attn"):
+                k_full = _expand_kv(k_cache, config)
+                v_full = _expand_kv(v_cache, config)
+                scores = jnp.einsum("bhqd,bhkd->bhqk", q, k_full) * scale
+                scores = jnp.where(mask[None, None], scores, -jnp.inf)
+                probs = jax.nn.softmax(
+                    scores.astype(jnp.float32), axis=-1
+                ).astype(h.dtype)
+                att = merge_heads(
+                    jnp.einsum("bhqk,bhkd->bhqd", probs, v_full)
+                )
             return linear(att, block_params["attn"]["output_proj"])
 
         x = _block_apply(x, block_params, config, attend)
 
-    x = _norm(x, params["ln_final"], config)
+    x = _final_norm(x, params, config)
     head = lm_head_weight(params, config) if lm_head is None else lm_head
     idx = jnp.reshape(jnp.clip(chunk_len - 1, 0, cb - 1), (1, 1, 1))
     last = jnp.take_along_axis(x, idx, axis=1)[:, 0]
@@ -733,7 +760,7 @@ def paged_verify_step(
     offsets = safe_pos % block_size
     quantized = "k_scale" in pool[0]
 
-    x = embedding(params["token_embeddings"], tokens)  # (S, K+1, d)
+    x = _embed(params, tokens)  # (S, K+1, d)
     scale = 1.0 / jnp.sqrt(jnp.asarray(config.d_head, jnp.float32))
     # (S, K+1, ctx) causal frontier: key j visible to row i iff j <= pos_i.
     mask = jnp.arange(nb * block_size)[None, None, :] <= pos_j[:, :, None]
@@ -806,7 +833,7 @@ def paged_verify_step(
 
         x = _block_apply(x, block_params, config, attend)
 
-    x = _norm(x, params["ln_final"], config)
+    x = _final_norm(x, params, config)
     if return_hidden:
         return x, new_pool
     head = lm_head_weight(params, config) if lm_head is None else lm_head

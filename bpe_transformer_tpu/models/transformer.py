@@ -264,19 +264,20 @@ def _attn_half(
     """
     from jax.ad_checkpoint import checkpoint_name
 
-    h = x if config.use_post_norm else _maybe_norm(
-        x, block_params["ln1"], config
-    )
-    attn_out = checkpoint_name(
-        _attention(
-            h, block_params["attn"], config, rope_cos_sin, positions,
-            attention_fn, entropy_tap,
-        ),
-        "flash_attn_out",
-    )
-    if config.use_post_norm:
-        return _maybe_norm(x + attn_out, block_params["ln1"], config)
-    return x + attn_out
+    with jax.named_scope("block/attn"):
+        h = x if config.use_post_norm else _maybe_norm(
+            x, block_params["ln1"], config
+        )
+        attn_out = checkpoint_name(
+            _attention(
+                h, block_params["attn"], config, rope_cos_sin, positions,
+                attention_fn, entropy_tap,
+            ),
+            "flash_attn_out",
+        )
+        if config.use_post_norm:
+            return _maybe_norm(x + attn_out, block_params["ln1"], config)
+        return x + attn_out
 
 
 def _ffn_half(
@@ -285,12 +286,13 @@ def _ffn_half(
     """The residual FFN half of one block; returns ``(x, aux_loss)``.
     Cheap flops, heavy memory (the ``d_ff`` expansion) — the part
     ``remat_policy="save_attn"`` rematerializes."""
-    if config.use_post_norm:
-        f, aux = _ffn(x, block_params["ffn"], config)
-        return _maybe_norm(x + f, block_params["ln2"], config), aux
-    h = _maybe_norm(x, block_params["ln2"], config)
-    f, aux = _ffn(h, block_params["ffn"], config)
-    return x + f, aux
+    with jax.named_scope("block/ffn"):
+        if config.use_post_norm:
+            f, aux = _ffn(x, block_params["ffn"], config)
+            return _maybe_norm(x + f, block_params["ln2"], config), aux
+        h = _maybe_norm(x, block_params["ln2"], config)
+        f, aux = _ffn(h, block_params["ffn"], config)
+        return x + f, aux
 
 
 def transformer_block_aux(
@@ -437,7 +439,10 @@ def _forward_prologue(
             lambda p: p.astype(act_dtype), params
         )
 
-    x = embedding(compute_params["token_embeddings"], token_ids).astype(act_dtype)
+    with jax.named_scope("embed"):
+        x = embedding(
+            compute_params["token_embeddings"], token_ids
+        ).astype(act_dtype)
 
     rope_cos_sin = None
     if not config.remove_rope:
@@ -479,8 +484,12 @@ def forward_hidden(
             )
             aux_total = aux_total + aux
 
-    x = _maybe_norm(x, compute_params["ln_final"], config)
-    return x, aux_total
+    return _final_norm(x, compute_params, config), aux_total
+
+
+def _final_norm(x: Array, compute_params: Params, config: ModelConfig) -> Array:
+    with jax.named_scope("final_norm"):
+        return _maybe_norm(x, compute_params["ln_final"], config)
 
 
 def _scan_blocks(
@@ -601,8 +610,7 @@ def forward_hidden_stats(
             x, aux_total, compute_params["layers"], config, rope_cos_sin,
             positions, attention_fn, with_stats=True,
         )
-        x = _maybe_norm(x, compute_params["ln_final"], config)
-        return x, aux_total, act_stats
+        return _final_norm(x, compute_params, config), aux_total, act_stats
 
     block = policy_block(config, with_stats=True)
     per_layer: list[dict] = []
@@ -617,8 +625,7 @@ def forward_hidden_stats(
         for key in per_layer[0]
     }
 
-    x = _maybe_norm(x, compute_params["ln_final"], config)
-    return x, aux_total, act_stats
+    return _final_norm(x, compute_params, config), aux_total, act_stats
 
 
 def forward(

@@ -53,15 +53,16 @@ def head_logits(hidden: Array, head_w: Array) -> Array:
     dequant-in-register kernel; its accumulator is already f32, so the
     float32-clean logits contract holds unchanged.
     """
-    if isinstance(head_w, dict):
-        from bpe_transformer_tpu.ops.quant import quant_linear
+    with jax.named_scope("lm_head"):
+        if isinstance(head_w, dict):
+            from bpe_transformer_tpu.ops.quant import quant_linear
 
-        return quant_linear(hidden, head_w, preserve_f32=True)
-    return jax.lax.dot_general(
-        hidden, head_w.astype(hidden.dtype),
-        (((hidden.ndim - 1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+            return quant_linear(hidden, head_w, preserve_f32=True)
+        return jax.lax.dot_general(
+            hidden, head_w.astype(hidden.dtype),
+            (((hidden.ndim - 1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
 
 def embedding(weight: Array, token_ids: Array) -> Array:

@@ -23,6 +23,7 @@ def global_norm(tree) -> Array:
     )
 
 
+@jax.named_scope("grad_clip")
 def clip_by_global_norm(grads, max_norm: float, eps: float = 1e-6):
     """Scale ``grads`` so their combined L2 norm is at most ``max_norm``.
 

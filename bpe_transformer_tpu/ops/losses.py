@@ -15,6 +15,7 @@ from jax.scipy.special import logsumexp
 from bpe_transformer_tpu.ops.core import head_logits
 
 
+@jax.named_scope("loss")
 def cross_entropy(logits: Array, targets: Array) -> Array:
     """Mean negative log-likelihood of ``targets`` under ``logits``.
 
@@ -30,6 +31,7 @@ def cross_entropy(logits: Array, targets: Array) -> Array:
     return nll.mean()
 
 
+@jax.named_scope("loss")
 def chunked_lm_cross_entropy(
     hidden: Array,
     lm_head_w: Array,

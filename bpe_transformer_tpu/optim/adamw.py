@@ -34,6 +34,7 @@ def adamw_init(params) -> AdamWState:
     )
 
 
+@jax.named_scope("optimizer")
 def adamw_update(
     params,
     grads,

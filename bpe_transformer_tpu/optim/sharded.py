@@ -150,6 +150,7 @@ def sharded_adamw_init(
     return state
 
 
+@jax.named_scope("optimizer")
 def sharded_adamw_update(
     params,
     grads,
@@ -197,10 +198,11 @@ def sharded_adamw_update(
 
     # Global clip norm from the shards: they tile the full vector, so the
     # psum of local sums-of-squares IS the full sum (pad contributes 0).
-    grad_norm = jnp.sqrt(lax.psum(jnp.sum(jnp.square(g_local)), axis))
-    if grad_clip_norm is not None:
-        scale = jnp.minimum(1.0, grad_clip_norm / (grad_norm + clip_eps))
-        g_local = g_local * scale
+    with jax.named_scope("grad_clip"):
+        grad_norm = jnp.sqrt(lax.psum(jnp.sum(jnp.square(g_local)), axis))
+        if grad_clip_norm is not None:
+            scale = jnp.minimum(1.0, grad_clip_norm / (grad_norm + clip_eps))
+            g_local = g_local * scale
 
     m_local = state.m.reshape(-1)
     v_local = state.v.reshape(-1)

@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +46,7 @@ from jax import lax
 from bpe_transformer_tpu.models.config import ModelConfig
 from bpe_transformer_tpu.models.decode import decode_step, init_kv_cache, prefill
 from bpe_transformer_tpu.models.transformer import lm_head_weight
+from bpe_transformer_tpu.telemetry.spans import Phase
 
 #: Runtime encodings for "knob disabled" — the sampler is branch-free so
 #: every slot shares one program regardless of which knobs are in play.
@@ -135,20 +137,22 @@ def filter_logits(logits, temps, top_ks, top_ps):
 
     # top-k: keep everything >= the k-th largest (ties included, matching
     # the static sampler); k <= 0 disables by using the minimum as cutoff.
-    sorted_desc = jnp.sort(scaled, axis=-1)[..., ::-1]
-    k_idx = jnp.where(top_ks > 0, jnp.clip(top_ks, 1, vocab), vocab) - 1
-    kth = jnp.take_along_axis(sorted_desc, k_idx[:, None], axis=-1)
-    masked = jnp.where(scaled < kth, -jnp.inf, scaled)
+    with jax.named_scope("sample/top_k"):
+        sorted_desc = jnp.sort(scaled, axis=-1)[..., ::-1]
+        k_idx = jnp.where(top_ks > 0, jnp.clip(top_ks, 1, vocab), vocab) - 1
+        kth = jnp.take_along_axis(sorted_desc, k_idx[:, None], axis=-1)
+        masked = jnp.where(scaled < kth, -jnp.inf, scaled)
 
     # top-p over the top-k-masked distribution (softmax renormalizes the
     # survivors, as the static sampler does by masking before nucleus).
-    sorted_m = jnp.sort(masked, axis=-1)[..., ::-1]
-    probs = jax.nn.softmax(sorted_m, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep = (cum - probs) < top_ps[:, None]  # mass BEFORE each token
-    keep = keep.at[:, 0].set(True)  # the argmax always survives
-    cutoff = jnp.min(jnp.where(keep, sorted_m, jnp.inf), axis=-1)
-    return jnp.where(masked < cutoff[:, None], -jnp.inf, masked)
+    with jax.named_scope("sample/top_p"):
+        sorted_m = jnp.sort(masked, axis=-1)[..., ::-1]
+        probs = jax.nn.softmax(sorted_m, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        keep = (cum - probs) < top_ps[:, None]  # mass BEFORE each token
+        keep = keep.at[:, 0].set(True)  # the argmax always survives
+        cutoff = jnp.min(jnp.where(keep, sorted_m, jnp.inf), axis=-1)
+        return jnp.where(masked < cutoff[:, None], -jnp.inf, masked)
 
 
 def sample_tokens(logits, keys, temps, top_ks, top_ps):
@@ -163,10 +167,14 @@ def sample_tokens(logits, keys, temps, top_ks, top_ps):
     ``lax.top_k`` — the price of runtime ``k``; at serving batch sizes the
     decode forward dominates.
     """
-    greedy = jnp.argmax(logits, axis=-1)
+    # Two blocks of one scope: the ops keep the order they had, so the
+    # compiled program is the one it was (scopes are metadata only).
+    with jax.named_scope("sample/draw"):
+        greedy = jnp.argmax(logits, axis=-1)
     masked = filter_logits(logits, temps, top_ks, top_ps)
-    sampled = jax.vmap(jax.random.categorical)(keys, masked)
-    return jnp.where(temps > 0.0, sampled, greedy)
+    with jax.named_scope("sample/draw"):
+        sampled = jax.vmap(jax.random.categorical)(keys, masked)
+        return jnp.where(temps > 0.0, sampled, greedy)
 
 
 def _prefill_program(
@@ -191,7 +199,8 @@ def _prefill_program(
         }
         for c, f in zip(cache, filled)
     ]
-    key, sub = jax.random.split(key)
+    with jax.named_scope("key_split"):
+        key, sub = jax.random.split(key)
     tok = sample_tokens(
         logits, sub[None], temp[None], top_k[None], top_p[None]
     )[0]
@@ -214,8 +223,9 @@ def _tick_program(
     token-identical to the unfused path; sampled output is too whenever
     the kernel's logits match the XLA matmul bitwise.
     """
-    split = jax.vmap(jax.random.split)(keys)
-    keys_next, subs = split[:, 0], split[:, 1]
+    with jax.named_scope("key_split"):
+        split = jax.vmap(jax.random.split)(keys)
+        keys_next, subs = split[:, 0], split[:, 1]
     if fused:
         from bpe_transformer_tpu.kernels.pallas.sample import (
             fused_head_sample,
@@ -272,6 +282,10 @@ class SlotPoolEngine:
     Single-threaded: exactly one caller (the serving worker loop) may call
     :meth:`admit` / :meth:`tick` / :meth:`release`.
     """
+
+    #: Seconds of the last tick's ``(dispatch, wait, emit)`` phases: see
+    #: the paged twin, `kvpool.paged_engine.PagedEngine.last_tick_s`.
+    last_tick_s = (0.0, 0.0, 0.0)
 
     def __init__(
         self,
@@ -347,6 +361,8 @@ class SlotPoolEngine:
 
         self.ticks = 0
         self.tokens_emitted = 0
+        #: The clock of the tick phases; the serving worker sets its own.
+        self.clock = time.monotonic
 
     # ------------------------------------------------------------- queries
 
@@ -478,28 +494,34 @@ class SlotPoolEngine:
         or token budget."""
         if not self._active.any():
             return []
-        tokens, positions, keys, self._cache = self._tick_jit(
-            self._params, self._lm_head, self._cache, self._tokens,
-            self._positions, self._active, self._keys, self._temps,
-            self._top_ks, self._top_ps,
-        )
-        tokens = np.asarray(tokens)
-        self._tokens = tokens.copy()
-        self._positions = np.asarray(positions).copy()
-        self._keys = np.asarray(keys).copy()
+        with Phase("serve/tick_dispatch", self.clock) as dispatch:
+            tokens, positions, keys, self._cache = self._tick_jit(
+                self._params, self._lm_head, self._cache, self._tokens,
+                self._positions, self._active, self._keys, self._temps,
+                self._top_ks, self._top_ps,
+            )
+        with Phase("serve/tick_wait", self.clock) as wait:
+            tokens = np.asarray(tokens)
+            self._tokens = tokens.copy()
+            self._positions = np.asarray(positions).copy()
+            self._keys = np.asarray(keys).copy()
         self.ticks += 1
 
         events: list[TickEvent] = []
-        for slot in np.flatnonzero(self._active):
-            slot = int(slot)
-            info = self._slots[slot]
-            token = int(tokens[slot])
-            info.generated += 1
-            self.tokens_emitted += 1
-            finished = self._finish_reason(info, token)
-            if finished:
-                self.release(slot)
-            events.append(TickEvent(slot=slot, token=token, finished=finished))
+        with Phase("serve/tick_emit", self.clock) as emit:
+            for slot in np.flatnonzero(self._active):
+                slot = int(slot)
+                info = self._slots[slot]
+                token = int(tokens[slot])
+                info.generated += 1
+                self.tokens_emitted += 1
+                finished = self._finish_reason(info, token)
+                if finished:
+                    self.release(slot)
+                events.append(
+                    TickEvent(slot=slot, token=token, finished=finished)
+                )
+        self.last_tick_s = (dispatch.dur_s, wait.dur_s, emit.dur_s)
         return events
 
     def release(self, slot: int) -> None:

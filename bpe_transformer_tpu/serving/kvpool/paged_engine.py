@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 
 import jax
 import jax.numpy as jnp
@@ -65,6 +66,7 @@ from bpe_transformer_tpu.serving.kvpool.blocks import (
     NoFreeBlocksError,
 )
 from bpe_transformer_tpu.serving.kvpool.radix import RadixPrefixCache
+from bpe_transformer_tpu.telemetry.spans import Phase
 
 __all__ = ["PagedEngine", "PagedSlotInfo", "NoFreeBlocksError"]
 
@@ -81,7 +83,8 @@ def _chunk_program(
         params, chunk, start, chunk_len, table_row, pool, config,
         lm_head=lm_head, block_size=block_size,
     )
-    key, sub = jax.random.split(key)
+    with jax.named_scope("key_split"):
+        key, sub = jax.random.split(key)
     tok = sample_tokens(
         logits, sub[None], temp[None], top_k[None], top_p[None]
     )[0]
@@ -97,8 +100,9 @@ def _paged_tick_program(
     dense `_tick_program`, decode reads/writes through the block table.
     ``fused=True`` runs the head projection + filter + sample tail as ONE
     Pallas kernel (see the dense twin's docstring)."""
-    split = jax.vmap(jax.random.split)(keys)
-    keys_next, subs = split[:, 0], split[:, 1]
+    with jax.named_scope("key_split"):
+        split = jax.vmap(jax.random.split)(keys)
+        keys_next, subs = split[:, 0], split[:, 1]
     if fused:
         from bpe_transformer_tpu.kernels.pallas.sample import (
             fused_head_sample,
@@ -191,6 +195,13 @@ class PagedEngine:
     #: the serving engine: KV rewinds (speculative rejections, host-side
     #: truncations) are pool decisions the incident ring should show.
     recorder = None
+    #: Seconds of the last tick's three phases, ``(dispatch, wait, emit)``
+    #: — the ``_tick_jit`` call until it returns (argument transfer and
+    #: enqueue), the reads that block on the device, the Python loop from
+    #: arrays to events — handed to the serving worker as plain data for
+    #: its ``tick`` record.  Each is also a ``serve/tick_*`` annotation in
+    #: a profiler's trace.
+    last_tick_s = (0.0, 0.0, 0.0)
 
     def __init__(
         self,
@@ -343,6 +354,8 @@ class PagedEngine:
 
         self.ticks = 0
         self.tokens_emitted = 0
+        #: The clock of the tick phases; the serving worker sets its own.
+        self.clock = time.monotonic
 
     # ------------------------------------------------------------- queries
 
@@ -991,30 +1004,34 @@ class PagedEngine:
         identical to the dense engine's tick."""
         if not self._active.any():
             return []
-        tokens, positions, keys, self._pool = self._tick_jit(
-            self._params, self._lm_head, self._pool, self._tables,
-            self._tokens, self._positions, self._active, self._keys,
-            self._temps, self._top_ks, self._top_ps,
-        )
-        tokens = np.asarray(tokens)
-        self._tokens = tokens.copy()
-        self._positions = np.asarray(positions).copy()
-        self._keys = np.asarray(keys).copy()
+        with Phase("serve/tick_dispatch", self.clock) as dispatch:
+            tokens, positions, keys, self._pool = self._tick_jit(
+                self._params, self._lm_head, self._pool, self._tables,
+                self._tokens, self._positions, self._active, self._keys,
+                self._temps, self._top_ks, self._top_ps,
+            )
+        with Phase("serve/tick_wait", self.clock) as wait:
+            tokens = np.asarray(tokens)
+            self._tokens = tokens.copy()
+            self._positions = np.asarray(positions).copy()
+            self._keys = np.asarray(keys).copy()
         self.ticks += 1
 
         events: list[TickEvent] = []
-        for slot in np.flatnonzero(self._active):
-            slot = int(slot)
-            info = self._slots[slot]
-            token = int(tokens[slot])
-            info.generated += 1
-            self.tokens_emitted += 1
-            finished = SlotPoolEngine._finish_reason(info, token)
-            if finished:
-                self.release(slot)
-            events.append(
-                TickEvent(slot=slot, token=token, finished=finished)
-            )
+        with Phase("serve/tick_emit", self.clock) as emit:
+            for slot in np.flatnonzero(self._active):
+                slot = int(slot)
+                info = self._slots[slot]
+                token = int(tokens[slot])
+                info.generated += 1
+                self.tokens_emitted += 1
+                finished = SlotPoolEngine._finish_reason(info, token)
+                if finished:
+                    self.release(slot)
+                events.append(
+                    TickEvent(slot=slot, token=token, finished=finished)
+                )
+        self.last_tick_s = (dispatch.dur_s, wait.dur_s, emit.dur_s)
         return events
 
     def release(self, slot: int) -> None:

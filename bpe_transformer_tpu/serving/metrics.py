@@ -91,6 +91,14 @@ class LatencyHistogram:
         return self.buckets[-1]
 
 
+#: The phases of the serving worker's tick period, in loop order, plus the
+#: remainder (``other``) — the ``<phase>_s`` fields of a ``kind="tick"``
+#: record and the ``phase`` label of ``worker_phase_seconds_total``.
+WORKER_PHASES = (
+    "admit", "prefill", "dispatch", "wait", "emit", "deliver", "idle", "other",
+)
+
+
 class ServingMetrics:
     """Thread-safe aggregate of everything a scrape needs.
 
@@ -133,6 +141,11 @@ class ServingMetrics:
         #: wall seconds those ticks took (throughput = tokens / seconds).
         self.decode_tokens = 0
         self.decode_seconds = 0.0
+        #: Cumulative seconds of the worker per phase of its tick period
+        #: (the sums of the ``kind="tick"`` records' fields, accounted when
+        #: a period closes): is a slow replica waiting on the device
+        #: (``wait``) or on its own host (everything else)?
+        self.worker_phase_seconds = dict.fromkeys(WORKER_PHASES, 0.0)
         #: KV migration traffic (ISSUE 15): sessions and payload bytes
         #: that LEFT this replica (prefill-role exports + drain
         #: evacuations) and that ARRIVED (grafted imports).
@@ -191,6 +204,13 @@ class ServingMetrics:
         with self._lock:
             self.decode_tokens += int(tokens)
             self.decode_seconds += max(float(seconds), 0.0)
+
+    def on_worker_period(self, seconds: dict) -> None:
+        """Account one closed tick period: ``{phase: seconds}`` over
+        :data:`WORKER_PHASES`."""
+        with self._lock:
+            for phase, value in seconds.items():
+                self.worker_phase_seconds[phase] += value
 
     def on_migration(self, direction: str, nbytes: int) -> None:
         """Account one KV-slot migration: ``direction`` is ``"out"``
@@ -261,6 +281,10 @@ class ServingMetrics:
                     if self.decode_seconds > 0
                     else None
                 ),
+                "worker_phase_seconds": {
+                    phase: round(value, 6)
+                    for phase, value in self.worker_phase_seconds.items()
+                },
                 "migrations_out": self.migrations_out,
                 "migrations_in": self.migrations_in,
                 "migration_bytes_out": self.migration_bytes_out,
@@ -329,6 +353,7 @@ def render_prometheus(
         }
         decode_tokens = metrics.decode_tokens
         decode_seconds = metrics.decode_seconds
+        worker_seconds = dict(metrics.worker_phase_seconds)
         migrations = (
             metrics.migrations_out, metrics.migrations_in,
             metrics.migration_bytes_out, metrics.migration_bytes_in,
@@ -400,6 +425,13 @@ def render_prometheus(
         emit("decode_tokens_per_sec", "gauge",
              "Cumulative decode token throughput.",
              [({}, round(decode_tokens / decode_seconds, 3))])
+
+    emit("worker_phase_seconds_total", "counter",
+         "Wall seconds of the serving worker per phase of its tick period "
+         "(admit | prefill | dispatch | wait | emit | deliver | idle | "
+         "other): wait is blocked on the device, the rest is host work.",
+         [({"phase": phase}, round(value, 6))
+          for phase, value in worker_seconds.items()])
 
     # KV migration traffic (ISSUE 15): how many sessions left/arrived as
     # KV payloads, and the bytes moved — the disaggregated fleet's

@@ -55,6 +55,7 @@ from bpe_transformer_tpu.telemetry.resources import (
     install_compile_counter,
     sample_resources,
 )
+from bpe_transformer_tpu.telemetry.spans import Phase
 
 __all__ = [
     "Request",
@@ -296,6 +297,9 @@ class ServingEngine:
                 weight_dtype=weight_dtype, fused_sampling=fused_sampling,
             )
         self.paged = paged
+        # The engine times its tick's dispatch/wait/emit phases on the
+        # worker's clock, so they add up with the worker's own phases.
+        self.engine.clock = clock
         #: Disaggregated-fleet role (ISSUE 15): ``"prefill"`` replicas run
         #: the chunk machine then stream finished prefixes out over
         #: ``/kv/export`` instead of ticking (plain /generate refused);
@@ -391,6 +395,9 @@ class ServingEngine:
         self._t0 = clock()
         self._last_record_t = self._t0
         self._last_record_tokens = 0
+        #: The open tick period (see :meth:`_open_period`): what the worker
+        #: has spent, phase by phase, since the last decode tick's deliver.
+        self._period = self._open_period(self._t0)
         self._entries: dict[str, _Entry] = {}
         self._entries_lock = threading.Lock()
         self._slot_entries: dict[int, _Entry] = {}
@@ -423,6 +430,7 @@ class ServingEngine:
         self._running = True
         self._t0 = self._clock()
         self._last_record_t = self._t0
+        self._period = self._open_period(self._t0)
         self._thread = threading.Thread(
             target=self._run, name="serving-engine", daemon=True
         )
@@ -1043,6 +1051,12 @@ class ServingEngine:
             # Decision-ring counters (GET /debug/flightrecorder holds the
             # ring itself; the operator page just shows it is alive).
             "flightrecorder": self.flightrecorder.stats(),
+            # Cumulative seconds of the worker per phase of its tick
+            # period: waiting on the device, or on its own host?
+            "worker_phase_seconds": {
+                phase: round(seconds, 6)
+                for phase, seconds in self.metrics.worker_phase_seconds.items()
+            },
             "resources": resources,
             "last_errors": self.metrics.last_errors(),
         }
@@ -1128,8 +1142,14 @@ class ServingEngine:
     def _run(self) -> None:
         try:
             while self._running:
-                if not self._step():
-                    self.scheduler.wait_for_work(self._idle_poll_s)
+                # The outer label: what falls between two phases of an
+                # iteration (a tick record's ``other_s``) lies under it.
+                with self._phase("step"):
+                    worked = self._step()
+                if not worked:
+                    with self._phase("idle_wait") as idle:
+                        self.scheduler.wait_for_work(self._idle_poll_s)
+                    self._period["idle_s"] += idle.dur_s
         except BaseException as exc:  # noqa: BLE001 — fail loudly, unblock callers
             self._worker_error = exc
             self._running = False
@@ -1185,6 +1205,32 @@ class ServingEngine:
         if self._rebalance_queue:
             worked |= self._rebalance_step()
 
+        with self._phase("admit") as admit:
+            worked |= self._admit_step()
+        self._period["admit_s"] += admit.dur_s
+
+        worked |= self._advance_prefills()
+
+        if self.engine.active_count:
+            # Chaos hook: SIGKILL-mid-decode fires here, between slots
+            # holding live KV and the tick that would advance them — the
+            # worst instant a replica can die.
+            self._decode_ticks += 1
+            self.faults.at_decode_tick(self._decode_ticks)
+            events = self.engine.tick()
+            tick = self.engine.last_tick_s  # (dispatch, wait, emit)
+            with self._phase("deliver") as deliver:
+                self._deliver(events, sum(tick))
+            self._close_period(tick, deliver, n_events=len(events))
+            worked = True
+        self._maybe_emit_engine_record()
+        return worked
+
+    def _admit_step(self) -> bool:
+        """The admission part of one iteration (the ``serve/admit`` phase):
+        cancellations, backlog expiry, inbound grafts, the scheduler pop and
+        every admission.  Returns whether any work happened."""
+        worked = False
         # In-flight cancellations retire their slots before the next tick
         # — decoding slots, slots mid-chunked-prefill, and block-starved
         # parked admissions alike.
@@ -1260,33 +1306,71 @@ class ServingEngine:
             if self._admit_backlog or not self._try_admit(qe.item):
                 self._admit_backlog.append(qe.item)
             worked = True
-
-        worked |= self._advance_prefills()
-
-        if self.engine.active_count:
-            # Chaos hook: SIGKILL-mid-decode fires here, between slots
-            # holding live KV and the tick that would advance them — the
-            # worst instant a replica can die.
-            self._decode_ticks += 1
-            self.faults.at_decode_tick(self._decode_ticks)
-            t0 = self._clock()
-            events = self.engine.tick()
-            tick_s = self._clock() - t0
-            self._deliver(events, tick_s)
-            # Tick summary, coalesced: consecutive ticks merge into one
-            # ring entry (count + refreshed fields) so steady-state decode
-            # chatter cannot evict the rare decision events around it.
-            self.flightrecorder.record(
-                "tick",
-                coalesce=True,
-                n_events=len(events),
-                tick_s=round(tick_s, 6),
-                active_slots=self.engine.active_count,
-                queue_depth=self.scheduler.depth,
-            )
-            worked = True
-        self._maybe_emit_engine_record()
         return worked
+
+    # ------------------------------------------------------- tick periods
+
+    def _phase(self, name: str) -> Phase:
+        """One worker phase: a clock pair on the worker's clock that is
+        also a ``serve/<name>`` annotation in a profiler's trace.  It
+        writes no span record; its seconds go into the ``tick`` record."""
+        return Phase(f"serve/{name}", self._clock)
+
+    def _open_period(self, t: float) -> dict:
+        """A tick period runs from the end of one decode tick's deliver to
+        the end of the next one's, so the periods tile the worker's time.
+        The phases before the tick accumulate here as they happen."""
+        return {
+            "t": t, "admit_s": 0.0, "prefill_s": 0.0, "chunks": 0,
+            "prefill_tokens": 0, "idle_s": 0.0,
+            "tokens_before": self.engine.tokens_emitted,
+        }
+
+    def _close_period(self, tick, deliver: Phase, n_events: int) -> None:
+        """End the period at the end of ``deliver`` and account for it
+        once: the ``kind="tick"`` record, the cumulative phase seconds of
+        ``ServingMetrics`` and the flight recorder's coalesced tick entry
+        all carry these same clock pairs."""
+        end = deliver.start + deliver.dur_s
+        period, self._period = self._period, self._open_period(end)
+        dispatch_s, wait_s, emit_s = tick
+        seconds = {
+            "admit": period["admit_s"], "prefill": period["prefill_s"],
+            "dispatch": dispatch_s, "wait": wait_s, "emit": emit_s,
+            "deliver": deliver.dur_s, "idle": period["idle_s"],
+        }
+        dur_s = end - period["t"]
+        # Kept explicit so nothing hides: the engine record, the request
+        # spans' emission, a finished prefill's hand-over, loop overhead.
+        seconds["other"] = dur_s - sum(seconds.values())
+        self.metrics.on_worker_period(seconds)
+        # Tick summary, coalesced: consecutive ticks merge into one ring
+        # entry (count + refreshed fields) so steady-state decode chatter
+        # cannot evict the rare decision events around it.
+        self.flightrecorder.record(
+            "tick",
+            coalesce=True,
+            n_events=n_events,
+            tick_s=round(dispatch_s + wait_s + emit_s, 6),
+            active_slots=self.engine.active_count,
+            queue_depth=self.scheduler.depth,
+        )
+        if self._telemetry is None:
+            return
+        self._telemetry.emit(
+            {
+                "kind": "tick",
+                "t": round(period["t"] - self._t0, 6),
+                "dur_s": round(dur_s, 6),
+                **{f"{k}_s": round(v, 6) for k, v in seconds.items()},
+                "chunks": period["chunks"],
+                "prefill_tokens": period["prefill_tokens"],
+                # Tokens the engine emitted in the period: the tick's, and
+                # the first token of each prefill that completed in it.
+                "batch": self.engine.tokens_emitted - period["tokens_before"],
+                "queue_depth": self.scheduler.depth,
+            }
+        )
 
     def _try_admit(self, entry: _Entry) -> bool:
         """Admit one popped entry into the engine.  Dense engine: one-shot
@@ -1906,9 +1990,12 @@ class ServingEngine:
                 chunk_tokens = self.engine.next_chunk_tokens(slot)
                 if not budget.admits(chunk_tokens):
                     return worked  # budget spent: decode tick runs next
-                t0 = self._clock()
-                event = self.engine.prefill_step(slot)
-                entry.prefill_s += self._clock() - t0
+                with self._phase("prefill_chunk") as chunk:
+                    event = self.engine.prefill_step(slot)
+                entry.prefill_s += chunk.dur_s
+                self._period["prefill_s"] += chunk.dur_s
+                self._period["chunks"] += 1
+                self._period["prefill_tokens"] += chunk_tokens
                 budget.spend(chunk_tokens)
                 worked = True
                 if event is not None:
@@ -2123,6 +2210,13 @@ class ServingEngine:
         elapsed = now - self._last_record_t
         if elapsed < self._record_every_s:
             return
+        # Milliseconds of host work once a second, between a tick's deliver
+        # and the next dispatch: annotated so the device's idle gap under it
+        # has a name; its seconds stay in the tick record's ``other_s``.
+        with self._phase("engine_record"):
+            self._emit_engine_record(now, elapsed)
+
+    def _emit_engine_record(self, now: float, elapsed: float) -> None:
         # Sampled UNCONDITIONALLY (sync-free, jax-optional — see
         # telemetry/resources.py): the compile-storm rule must see the
         # compile counter even on a server run without --metrics-jsonl.

@@ -64,6 +64,7 @@ from bpe_transformer_tpu.serving.spec.draft import (
     _draft_prefill_program,
     _propose_program,
 )
+from bpe_transformer_tpu.telemetry.spans import Phase
 
 __all__ = ["SpecEngine"]
 
@@ -442,10 +443,19 @@ class SpecEngine(PagedEngine):
         return event
 
     def tick(self) -> list[TickEvent]:
-        """One speculative tick: draft-propose K, target-verify K+1,
-        accept/resample, emit 1..K+1 tokens per slot, rewind the rejected
-        tail.  Event contract: per-slot events in emission order,
-        ``finished`` set on the slot's last event."""
+        """One speculative tick (:meth:`_spec_tick`).  Its draft and verify
+        programs each sync, so the worker's tick record gets the whole
+        tick as its wait phase and no dispatch or emit share."""
+        with Phase("serve/tick_wait", self.clock) as wait:
+            events = self._spec_tick()
+        self.last_tick_s = (0.0, wait.dur_s, 0.0)
+        return events
+
+    def _spec_tick(self) -> list[TickEvent]:
+        """Draft-propose K, target-verify K+1, accept/resample, emit
+        1..K+1 tokens per slot, rewind the rejected tail.  Event contract:
+        per-slot events in emission order, ``finished`` set on the slot's
+        last event."""
         if not self._active.any():
             return []
         t0 = time.perf_counter()

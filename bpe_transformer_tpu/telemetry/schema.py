@@ -35,6 +35,27 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
         "kind", "t", "active_slots", "queue_depth", "tokens_per_sec",
         "tokens_total", "ticks", "requests_finished", "compiled_programs",
     },
+    # One decode tick of the serving worker (serving/server.py): where the
+    # tick *period* went.  A period runs from the end of the previous
+    # tick's deliver to the end of this one's, so consecutive records tile
+    # the worker's time (``t`` + ``dur_s`` = the next record's ``t``, on
+    # the serve/* spans' axis).  The phase fields are the clock pairs of
+    # the worker's ``serve/*`` profiler annotations, summed over the
+    # period: ``admit_s`` (cancellations, backlog expiry, grafts, the
+    # scheduler pop, every admission), ``prefill_s`` over ``chunks``
+    # prefill-chunk calls of ``prefill_tokens`` prompt tokens,
+    # ``dispatch_s`` / ``wait_s`` / ``emit_s`` (the tick: the program's call
+    # until it returns, blocked on the device, arrays to events),
+    # ``deliver_s`` (tokens to streams, finished requests), ``idle_s``
+    # (waiting for work) and ``other_s`` = ``dur_s`` minus the rest, kept
+    # explicit.  ``batch`` is the tokens the engine emitted in the period
+    # (the tick's, plus the first token of each prefill that completed in
+    # it); ``queue_depth`` the scheduler's at the period's end.
+    "tick": {
+        "kind", "t", "dur_s", "admit_s", "prefill_s", "chunks",
+        "prefill_tokens", "dispatch_s", "wait_s", "emit_s", "deliver_s",
+        "idle_s", "other_s", "batch", "queue_depth",
+    },
     # Resource accounting sample (telemetry/resources.py): HBM fields are
     # None on backends without memory_stats (CPU), never absent.  Training
     # records additionally carry optional ``params_bytes`` /

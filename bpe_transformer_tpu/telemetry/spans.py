@@ -17,15 +17,56 @@ JSONL schema):
   (NaN detection, watchdog trips, checkpoint completions).
 - ``{"kind": "manifest", ...}`` / ``{"kind": "footer", ...}`` — run header
   and trailer (see `telemetry.manifest` and :meth:`Telemetry.footer`).
+
+Every span is made in one place, :class:`Phase`: a clock pair that is also a
+``jax.profiler.TraceAnnotation`` of the same name, so under any profiler
+session (``bpe-tpu train --profile-trace``, a benchmark's tracer) the
+program's spans sit on the ``/host:CPU`` plane, on the device trace's clock.
+This module stays jax-free: the annotation type is looked up in
+``sys.modules``, so only a process that already holds jax annotates.
 """
 
 from __future__ import annotations
 
 import contextlib
+import sys
 import threading
 import time
 from collections import Counter
 from typing import Callable
+
+
+class Phase:
+    """One timed stretch of host work: ``start`` and ``dur_s`` on ``clock``,
+    and a profiler annotation called ``name`` over the same stretch.
+
+    ``with Phase("serve/admit", clock) as phase: ...`` then ``phase.dur_s``.
+    It emits no record: the caller decides what the seconds feed (a span
+    record, a ``tick`` record's field, a counter).  With no profiler session
+    the annotation costs one ``TraceMe`` check; in a process without jax it
+    is not made at all.  Enter and exit on one thread.
+    """
+
+    __slots__ = ("start", "dur_s", "_clock", "_annotation")
+
+    def __init__(self, name: str, clock=time.perf_counter):
+        self._clock = clock
+        self.dur_s = 0.0
+        jax = sys.modules.get("jax")
+        self._annotation = (
+            jax.profiler.TraceAnnotation(name) if jax is not None else None
+        )
+
+    def __enter__(self) -> "Phase":
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self.start = self._clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.dur_s = self._clock() - self.start
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
 
 
 class SpanHandle:
@@ -37,7 +78,8 @@ class SpanHandle:
         self.name = name
         self.path = path
         self._attrs = attrs
-        self._start = telemetry._clock()
+        self._phase = Phase(path, telemetry._clock).__enter__()
+        self._start = self._phase.start
         self._closed = False
 
     def end(self, **extra_attrs) -> float:
@@ -45,9 +87,9 @@ class SpanHandle:
         if self._closed:
             return 0.0
         self._closed = True
-        dur = self._telemetry._clock() - self._start
-        self._telemetry._close_span(self, dur, extra_attrs)
-        return dur
+        self._phase.__exit__(None, None, None)
+        self._telemetry._close_span(self, self._phase.dur_s, extra_attrs)
+        return self._phase.dur_s
 
 
 class Telemetry:
@@ -135,6 +177,13 @@ class Telemetry:
             yield handle
         finally:
             handle.end()
+
+    def phase(self, name: str) -> Phase:
+        """``with telemetry.phase("train/sync"): ...`` — a :class:`Phase`
+        on this narrator's clock: an annotation in a profiler's trace, no
+        record.  For what happens every step, where a span record each
+        time would swamp the stream."""
+        return Phase(name, self._clock)
 
     def event(self, name: str, **attrs) -> None:
         """Emit a point-in-time event record."""

@@ -39,19 +39,30 @@ TRACE_ASSUMPTIONS: dict[str, set[str]] = {
     "resources": {"kind", "time_unix"},
     "attribution": {"kind", "t"},
     "kvpool": {"kind", "t"},
+    "tick": {"kind", "t"},
     "fleet": {"kind", "t"},
     "alert": {"kind", "t", "rule", "state"},
     "event": {"kind", "name", "t"},
     "blackbox": {"kind", "t", "trigger"},
 }
 
-#: Counter series pulled from each periodic record kind.
-_ENGINE_COUNTERS = ("active_slots", "queue_depth", "tokens_per_sec")
-_KVPOOL_COUNTERS = ("blocks_free", "blocks_shared", "prefill_pending_tokens")
-_FLEET_COUNTERS = (
-    "replicas_online", "queue_depth", "tokens_per_sec", "active_slots"
-)
-_ATTRIBUTION_COUNTERS = ("compute_frac", "collective_frac", "host_gap_frac")
+#: Counter series pulled from each periodic record kind whose ``t`` is on
+#: the run-relative axis: one counter track per kind, named for it.  A
+#: ``tick`` record's phase seconds draw the serving worker's period as
+#: stacked counters (the phases of one period interleave, so only their
+#: sums are known — a lane of boxes would invent an order).
+_COUNTERS: dict[str, tuple[str, ...]] = {
+    "engine": ("active_slots", "queue_depth", "tokens_per_sec"),
+    "kvpool": ("blocks_free", "blocks_shared", "prefill_pending_tokens"),
+    "fleet": (
+        "replicas_online", "queue_depth", "tokens_per_sec", "active_slots"
+    ),
+    "attribution": ("compute_frac", "collective_frac", "host_gap_frac"),
+    "tick": (
+        "admit_s", "prefill_s", "dispatch_s", "wait_s", "emit_s",
+        "deliver_s", "idle_s", "other_s",
+    ),
+}
 _RESOURCE_COUNTERS = (
     "host_rss_bytes",
     "live_buffer_bytes",
@@ -175,13 +186,13 @@ def trace_events(records: list[dict]) -> list[dict]:
                     **({"args": args} if args else {}),
                 }
             )
-        elif kind == "engine":
+        elif kind in _COUNTERS:
             t = record.get("t")
             if not isinstance(t, (int, float)):
                 continue
             series = {
                 k: record[k]
-                for k in _ENGINE_COUNTERS
+                for k in _COUNTERS[kind]
                 if isinstance(record.get(k), (int, float))
             }
             if series:
@@ -189,64 +200,7 @@ def trace_events(records: list[dict]) -> list[dict]:
                     {
                         "ph": "C",
                         "pid": _PID,
-                        "name": "engine",
-                        "ts": round(t * 1e6, 1),
-                        "args": series,
-                    }
-                )
-        elif kind == "kvpool":
-            t = record.get("t")
-            if not isinstance(t, (int, float)):
-                continue
-            series = {
-                k: record[k]
-                for k in _KVPOOL_COUNTERS
-                if isinstance(record.get(k), (int, float))
-            }
-            if series:
-                events.append(
-                    {
-                        "ph": "C",
-                        "pid": _PID,
-                        "name": "kvpool",
-                        "ts": round(t * 1e6, 1),
-                        "args": series,
-                    }
-                )
-        elif kind == "attribution":
-            t = record.get("t")
-            if not isinstance(t, (int, float)):
-                continue
-            series = {
-                k: record[k]
-                for k in _ATTRIBUTION_COUNTERS
-                if isinstance(record.get(k), (int, float))
-            }
-            if series:
-                events.append(
-                    {
-                        "ph": "C",
-                        "pid": _PID,
-                        "name": "attribution",
-                        "ts": round(t * 1e6, 1),
-                        "args": series,
-                    }
-                )
-        elif kind == "fleet":
-            t = record.get("t")
-            if not isinstance(t, (int, float)):
-                continue
-            series = {
-                k: record[k]
-                for k in _FLEET_COUNTERS
-                if isinstance(record.get(k), (int, float))
-            }
-            if series:
-                events.append(
-                    {
-                        "ph": "C",
-                        "pid": _PID,
-                        "name": "fleet",
+                        "name": kind,
                         "ts": round(t * 1e6, 1),
                         "args": series,
                     }

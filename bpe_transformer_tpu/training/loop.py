@@ -929,7 +929,8 @@ def train(
             # folds in the post-rollback salt).  The prefetcher hands back
             # the worker-built host batch when one is ready, else builds it
             # synchronously (first step, post-rollback).
-            hx, hy, n, plain = prefetcher.get(iteration)
+            with telemetry.phase("train/next_batch"):
+                hx, hy, n, plain = prefetcher.get(iteration)
             if stride > 1 and n != stride:
                 # Tail shorter than the compiled scan length.  The rebuilt
                 # step pays a fresh jit compile on dispatch: route it
@@ -950,9 +951,10 @@ def train(
                 if future_it < loop.steps:
                     prefetcher.schedule(future_it)
             # Device placement (async enqueue) on the main thread only.
-            x, y = (place_plain if plain else place)(
-                (jax.numpy.asarray(hx), jax.numpy.asarray(hy))
-            )
+            with telemetry.phase("train/next_batch"):
+                x, y = (place_plain if plain else place)(
+                    (jax.numpy.asarray(hx), jax.numpy.asarray(hy))
+                )
             if first_dispatch:
                 # The first dispatch of a (re)built step pays the jit
                 # compile; span it (with a sync fence so the span measures
@@ -972,7 +974,10 @@ def train(
                 excluded_steps += n
                 first_dispatch = False
             else:
-                params, opt_state, metrics = step_fn(params, opt_state, x, y)
+                with telemetry.phase("train/step_dispatch"):
+                    params, opt_state, metrics = step_fn(
+                        params, opt_state, x, y
+                    )
                 timer.update(tokens_per_step * n)
             iteration += n
             if injector.active:
@@ -983,7 +988,8 @@ def train(
 
             is_last = iteration == loop.steps
             if iteration % loop.log_every == 0 or is_last:
-                fetched = jax.device_get(metrics)  # the device sync point
+                with telemetry.phase("train/sync"):
+                    fetched = jax.device_get(metrics)  # the device sync point
                 dyn_flat = None
                 if dynamics:
                     # Already on host — the dynamics pytree rode the fetch

@@ -1,0 +1,395 @@
+"""The serving worker accounts for its tick period (ISSUE 25): one ``tick``
+record per decode tick whose phases add up and whose periods tile; the same
+phases as annotations in a profiler's trace; named scopes in the compiled
+programs; and the jax-free tools stay jax-free."""
+
+import ast
+import dataclasses
+import functools
+import re
+import subprocess
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from bpe_transformer_tpu.models import TS_TEST_CONFIG, ModelConfig, init_params
+from bpe_transformer_tpu.serving.metrics import WORKER_PHASES
+from bpe_transformer_tpu.serving.server import Request, ServingEngine
+from bpe_transformer_tpu.telemetry.schema import validate_record
+from bpe_transformer_tpu.telemetry.spans import Phase, Telemetry
+from bpe_transformer_tpu.training import LoopConfig, TrainHParams, train
+
+CFG = dataclasses.replace(TS_TEST_CONFIG, vocab_size=128, context_length=32)
+TINY = ModelConfig(
+    vocab_size=256, context_length=32, d_model=64, num_layers=2, num_heads=4,
+    d_ff=128, loss_chunk_size=16,
+)
+HP = TrainHParams(
+    max_learning_rate=1e-3, min_learning_rate=1e-4, warmup_iters=2,
+    cosine_cycle_iters=20,
+)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_params(jax.random.PRNGKey(0), CFG)
+
+
+def serve_some(params, telemetry=None, **engine):
+    """A paged engine serves five requests of unlike sizes; returns its
+    ``stats()`` before and after, once it has gone quiet."""
+    with ServingEngine(
+        params, CFG, slots=3, min_bucket=8, paged=True, block_size=4,
+        prefill_chunk=8, telemetry=telemetry, engine_record_every_s=0.05,
+        **engine,
+    ) as serving:
+        before = serving.stats()
+        handles = [
+            serving.submit(Request(
+                prompt_ids=tuple(range(20 * i + 1, 20 * i + 4 + 3 * i)),
+                max_new_tokens=5 + i,
+                temperature=0.0 if i % 2 else 1.0, top_k=20, seed=i,
+            ))
+            for i in range(5)
+        ]
+        results = [h.result(timeout=300) for h in handles]
+        after = serving.stats()
+        page = serving.statusz()
+        text = serving.prometheus_metrics()
+    return before, after, results, page, text
+
+
+@pytest.fixture(scope="module")
+def served(params):
+    records = []
+    out = serve_some(params, Telemetry(sink=records.append))
+    return [r for r in records if r.get("kind") == "tick"], records, out
+
+
+def test_one_tick_record_per_decode_tick(served):
+    ticks, _, (before, after, results, _, _) = served
+    assert all(r.finish_reason == "length" for r in results)
+    assert len(ticks) == after["ticks"] - before["ticks"] > 0
+    assert all(validate_record(t) == [] for t in ticks)
+    assert sum(t["batch"] for t in ticks) == (
+        after["tokens_emitted"] - before["tokens_emitted"]
+    )
+    # Every prompt (no two share a prefix) went through in chunks of <= 8.
+    assert sum(t["prefill_tokens"] for t in ticks) == sum(3 + 3 * i for i in range(5))
+    assert sum(t["chunks"] for t in ticks) >= 5
+
+
+@pytest.mark.parametrize("phase", WORKER_PHASES)
+def test_phase_is_nonnegative_and_matches_stats(served, phase):
+    ticks, _, (_, after, _, page, text) = served
+    values = [t[f"{phase}_s"] for t in ticks]
+    # The remainder is a difference of clock pairs: it may round to -1e-6.
+    assert min(values) >= (-2e-6 if phase == "other" else 0.0)
+    total = sum(values)
+    assert after["worker_phase_seconds"][phase] == pytest.approx(total, abs=1e-5)
+    assert page["worker_phase_seconds"][phase] == pytest.approx(total, abs=1e-5)
+    assert f'bpe_tpu_worker_phase_seconds_total{{phase="{phase}"}}' in text
+
+
+def test_phases_add_up_and_periods_tile(served):
+    ticks, _, _ = served
+    for tick in ticks:
+        parts = sum(tick[f"{phase}_s"] for phase in WORKER_PHASES)
+        assert parts == pytest.approx(tick["dur_s"], abs=1e-5)
+    for a, b in zip(ticks, ticks[1:]):
+        assert b["t"] == pytest.approx(a["t"] + a["dur_s"], abs=3e-6)
+    assert ticks[0]["t"] == 0.0  # the first period opens when the worker starts
+
+
+def test_request_spans_stay_and_phases_write_no_span_lines(served):
+    _, records, _ = served
+    spans = [r for r in records if r.get("kind") == "span"]
+    assert {s["name"] for s in spans} == {"queue_wait", "prefill", "decode"}
+    assert all("request_id" in s for s in spans)
+
+
+def test_worker_counts_phases_without_a_sink(params):
+    _, after, results, _, _ = serve_some(params, telemetry=None)
+    assert all(r.finish_reason == "length" for r in results)
+    assert after["worker_phase_seconds"]["wait"] > 0.0
+    assert after["decode_seconds"] == pytest.approx(
+        sum(after["worker_phase_seconds"][p] for p in ("dispatch", "wait", "emit")),
+        abs=1e-4,
+    )
+
+
+@pytest.mark.parametrize("kind", ["dense", "spec"])
+def test_other_engines_hand_over_their_tick_split(params, kind):
+    records = []
+    engine = {}
+    if kind == "spec":
+        from bpe_transformer_tpu.serving.spec.draft import DraftSpec
+
+        engine = dict(
+            paged=True, block_size=4, speculate_k=2,
+            draft_spec=DraftSpec(truncate_layers=1),
+        )
+    with ServingEngine(
+        params, CFG, slots=2, min_bucket=8, telemetry=Telemetry(sink=records.append),
+        **engine,
+    ) as serving:
+        serving.generate((1, 2, 3, 4), max_new_tokens=6, temperature=0.0)
+        ticks_run = serving.stats()["ticks"]
+    ticks = [r for r in records if r.get("kind") == "tick"]
+    assert len(ticks) == ticks_run > 0
+    assert all(t["wait_s"] > 0 for t in ticks)
+    if kind == "spec":  # the whole speculative tick counts as wait
+        assert all(t["dispatch_s"] == t["emit_s"] == 0 for t in ticks)
+    else:
+        assert all(t["dispatch_s"] > 0 and t["emit_s"] > 0 for t in ticks)
+
+
+# ------------------------------------------------------------ the profiler
+
+
+def host_event_names(trace_dir) -> set:
+    files = sorted(trace_dir.glob("plugins/profile/*/*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(str(files[-1]))
+    return {
+        ev.name
+        for plane in data.planes if plane.name == "/host:CPU"
+        for line in plane.lines
+        for ev in line.events
+    }
+
+
+@pytest.fixture(scope="module")
+def byte_data():
+    text = b"hello world. " * 2000
+    return np.frombuffer(text, dtype=np.uint8).astype(np.uint16)
+
+
+def train_some(byte_data):
+    loop = LoopConfig(steps=6, batch_size=4, log_every=3, eval_every=1000)
+    return train(TINY, HP, loop, byte_data, log_fn=lambda *_: None)
+
+
+@pytest.fixture(scope="module")
+def traced(params, byte_data, tmp_path_factory):
+    """The same serving and training code, once under a profiler session
+    and once with none."""
+    trace_dir = tmp_path_factory.mktemp("trace")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    try:
+        records = []
+        serve_some(params, Telemetry(sink=records.append))
+        summary = train_some(byte_data)
+    finally:
+        jax.profiler.stop_trace()
+    return host_event_names(trace_dir), records, summary
+
+
+@pytest.mark.parametrize(
+    "name",
+    [
+        "serve/admit", "serve/prefill_chunk", "serve/tick_dispatch",
+        "serve/tick_wait", "serve/tick_emit", "serve/deliver",
+        "serve/idle_wait", "serve/engine_record", "serve/step",
+        "train/next_batch", "train/step_dispatch",
+        "train/sync", "setup", "compile_first_step",
+    ],
+)
+def test_phase_is_on_the_host_plane_under_a_profiler_session(traced, name):
+    names, _, _ = traced
+    assert name in names
+
+
+def test_same_records_with_and_without_a_session(traced, served, byte_data):
+    _, records, summary = traced
+    ticks, plain, _ = served
+    traced_ticks = [r for r in records if r.get("kind") == "tick"]
+    assert len(traced_ticks) == len(ticks)
+    assert [t["batch"] for t in traced_ticks] == [t["batch"] for t in ticks]
+    # The engine record and what rides its cadence come by the clock; every
+    # other record comes by the work, with a session or without.
+    by_clock = {"engine", "resources", "roofline", "kvpool"}
+
+    def by_work(stream):
+        return sorted(k for k in (r["kind"] for r in stream) if k not in by_clock)
+
+    assert by_work(records) == by_work(plain)
+    untraced = train_some(byte_data)
+    assert [r["loss"] for r in summary["history"]] == [
+        r["loss"] for r in untraced["history"]
+    ]
+
+
+def test_phase_outside_jax_makes_no_annotation(monkeypatch):
+    monkeypatch.setitem(sys.modules, "jax", None)
+    ticks = iter([1.0, 3.5])
+    with Phase("serve/admit", lambda: next(ticks)) as phase:
+        assert phase._annotation is None
+    assert (phase.start, phase.dur_s) == (1.0, 2.5)
+
+
+# ---------------------------------------------------------- named scopes
+
+
+def compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def carries(text: str, scope: str) -> bool:
+    """``scope`` as one whole part of an instruction's ``op_name`` path:
+    ``jit(step)/optimizer/mul``, ``jit(step)/transpose(jvp(loss))/while``."""
+    return re.search(rf'[/(]{re.escape(scope)}[/)]', text) is not None
+
+
+@pytest.fixture(scope="module")
+def train_step_text():
+    from bpe_transformer_tpu.optim.adamw import adamw_init
+    from bpe_transformer_tpu.training.train_step import train_step_fn
+
+    p = init_params(jax.random.PRNGKey(1), TINY)
+    x = jnp.zeros((2, 32), jnp.int32)
+    return compiled_text(train_step_fn(TINY, HP), p, adamw_init(p), x, x)
+
+
+@pytest.fixture(scope="module")
+def paged_programs(params):
+    """The compiled text of a paged engine's tick and chunk programs, by
+    the arguments the engine itself passes."""
+    from bpe_transformer_tpu.serving.kvpool import paged_engine as pe
+
+    eng = pe.PagedEngine(params, CFG, slots=2, block_size=4, min_bucket=8)
+    tick = compiled_text(
+        functools.partial(pe._paged_tick_program, config=CFG, block_size=4),
+        eng._params, eng._lm_head, eng._pool, eng._tables, eng._tokens,
+        eng._positions, eng._active, eng._keys, eng._temps, eng._top_ks,
+        eng._top_ps,
+    )
+    chunk = compiled_text(
+        functools.partial(pe._chunk_program, config=CFG, block_size=4),
+        eng._params, eng._lm_head, eng._pool, eng._tables[0],
+        np.zeros((1, 8), np.int32), np.int32(0), np.int32(5),
+        jax.random.PRNGKey(0), np.float32(1.0), np.int32(0), np.float32(1.0),
+    )
+    return {"tick": tick, "chunk": chunk}
+
+
+@pytest.mark.parametrize(
+    "scope",
+    ["embed", "block/attn", "block/ffn", "final_norm", "lm_head", "loss",
+     "optimizer", "grad_clip"],
+)
+def test_train_step_carries_scope(train_step_text, scope):
+    assert carries(train_step_text, scope)
+
+
+@pytest.mark.parametrize("program", ["tick", "chunk"])
+@pytest.mark.parametrize(
+    "scope",
+    ["embed", "block/attn", "block/ffn", "final_norm", "lm_head",
+     "pool_gather", "pool_write", "attn", "sample/top_k", "sample/top_p",
+     "sample/draw", "key_split"],
+)
+def test_serving_program_carries_scope(paged_programs, program, scope):
+    if scope == "attn":  # the tick's decode attention, the chunk's own
+        scope = "decode_attn" if program == "tick" else "chunk_attn"
+    assert carries(paged_programs[program], scope)
+
+
+def test_scopes_are_metadata_only(params):
+    """The tick program compiles to the same instructions with the scopes
+    as without: only ``metadata={...}`` differs."""
+    import contextlib
+
+    from bpe_transformer_tpu.serving.kvpool import paged_engine as pe
+
+    eng = pe.PagedEngine(params, CFG, slots=2, block_size=4, min_bucket=8)
+    args = (
+        eng._params, eng._lm_head, eng._pool, eng._tables, eng._tokens,
+        eng._positions, eng._active, eng._keys, eng._temps, eng._top_ks,
+        eng._top_ps,
+    )
+    program = functools.partial(pe._paged_tick_program, config=CFG, block_size=4)
+    with_scopes = compiled_text(program, *args)
+
+    def no_scope(name):
+        return contextlib.nullcontext()
+
+    original = jax.named_scope
+    jax.named_scope = no_scope
+    try:
+        bare = compiled_text(
+            functools.partial(pe._paged_tick_program, config=CFG, block_size=4),
+            *args,
+        )
+    finally:
+        jax.named_scope = original
+
+    def instructions(text):
+        """The computations, without metadata and the source tables."""
+        body = text[text.index("\n%"):]
+        return re.sub(r",? ?metadata=\{[^}]*\}", "", body).splitlines()
+
+    assert carries(with_scopes, "sample/top_k")
+    assert not carries(bare, "sample/top_k")
+    assert instructions(with_scopes) == instructions(bare)
+
+
+PALLAS = Path(__file__).resolve().parents[1] / "bpe_transformer_tpu" / "kernels" / "pallas"
+
+
+@pytest.mark.parametrize(
+    "filename, calls",
+    [("decode_attention.py", 2), ("flash_attention.py", 3), ("gelu.py", 1),
+     ("quant_matmul.py", 1), ("sample.py", 1), ("swiglu.py", 1)],
+)
+def test_every_pallas_call_has_a_name(filename, calls):
+    tree = ast.parse((PALLAS / filename).read_text())
+    found = [
+        node for node in ast.walk(tree)
+        if isinstance(node, ast.Call)
+        and isinstance(node.func, ast.Attribute)
+        and node.func.attr == "pallas_call"
+    ]
+    assert len(found) == calls
+    for call in found:
+        assert "name" in {kw.arg for kw in call.keywords}, (filename, call.lineno)
+
+
+def test_pallas_name_reaches_the_program():
+    from bpe_transformer_tpu.kernels.pallas.gelu import gelu
+
+    assert "gelu" in str(jax.make_jaxpr(gelu)(jnp.ones((8, 128))).eqns[0].params)
+
+
+# ------------------------------------------------------------- jax-free
+
+
+@pytest.mark.parametrize(
+    "module",
+    [
+        "bpe_transformer_tpu.telemetry.spans",
+        "bpe_transformer_tpu.telemetry.monitor",
+        "bpe_transformer_tpu.telemetry.report",
+        "bpe_transformer_tpu.telemetry.schema",
+        "bpe_transformer_tpu.serving.metrics",
+    ],
+)
+def test_module_imports_without_jax(module):
+    code = (
+        "import sys; sys.modules['jax'] = None; "
+        f"import {module} as m; "
+        "from bpe_transformer_tpu.telemetry.spans import Telemetry; "
+        "t = Telemetry(sink=lambda r: None); "
+        "h = t.start_span('x'); h.end(); "
+        "assert sys.modules['jax'] is None"
+    )
+    done = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True, timeout=120
+    )
+    assert done.returncode == 0, done.stderr[-1500:]
