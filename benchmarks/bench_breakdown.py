@@ -246,8 +246,7 @@ def main() -> int:
     # 1. The full update as shipped.
     ms, cost = step_row(base)
     emit("full_step", ms, attention=base.attention_impl,
-         flash_block=base.flash_block_size, loss_chunk=base.loss_chunk,
-         **cost)
+         loss_chunk=base.loss_chunk, **cost)
 
     # 2. Forward-only and grad-only splits (optimizer cost = full - valgrad).
     params = init_params(jax.random.PRNGKey(0), base)
@@ -257,17 +256,15 @@ def main() -> int:
     vg = jax.jit(jax.value_and_grad(loss_fn))
     emit("value_and_grad", time_call(lambda p: vg(p, x, y)[0], params, iters=args.iters))
 
-    # 3. Attention impl / tile size at this exact shape.
-    for attn, block in (("xla", None), ("flash", 256), ("flash", 512)):
-        if attn == base.attention_impl and (block or 256) == base.flash_block_size:
+    # 3. The other forced attention path at this exact shape (the flash
+    # kernel's tiles come from the shape: kernels/pallas/runtime.py).
+    for attn in ("xla", "flash"):
+        if attn == base.attention_impl:
             continue  # already row 1
-        over = {"attention_impl": attn}
-        if block:
-            over["flash_block_size"] = block
-        ms, cost = step_row(dataclasses.replace(base, **over))
+        ms, cost = step_row(dataclasses.replace(base, attention_impl=attn))
         emit(
             "full_step", ms,
-            attention=attn, flash_block=block, loss_chunk=base.loss_chunk,
+            attention=attn, loss_chunk=base.loss_chunk,
             **cost,
         )
 
@@ -282,8 +279,7 @@ def main() -> int:
         ms, cost = step_row(cfg)
         emit(
             "full_step", ms,
-            attention=base.attention_impl, flash_block=base.flash_block_size,
-            loss_chunk=cfg.loss_chunk,
+            attention=base.attention_impl, loss_chunk=cfg.loss_chunk,
             **cost,
         )
     return 0

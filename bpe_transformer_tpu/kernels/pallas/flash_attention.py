@@ -12,9 +12,22 @@ Kernel structure (classic FlashAttention on the MXU):
   fastest; VMEM scratch (f32 accumulator + running max/denominator) persists
   across the key axis so each query block is normalized online, never
   materializing more than a ``(block_q, block_k)`` score tile.
+* the MXU takes q, k, v, dO — and the probabilities / dS of each pair's
+  second matmul — in the dtype the caller passed, accumulating in float32
+  (``preferred_element_type``); running max, denominator, logsumexp,
+  ``delta`` and every accumulator stay float32.  That is the precision of
+  the materialized XLA path (`ops/core.py`), whose scores and
+  probabilities are the inputs' dtype too.
+* blocks carry the head dim at its own width (a block whose last dimension
+  is the array's own is legal), so d_head 64 is never padded to 128 lanes
+  in HBM, and the row statistics travel as compact ``(bh, 1, S)`` rows.
 * causal masking happens at block granularity: key blocks strictly above the
-  diagonal are predicated off, the diagonal block gets the triangular mask,
+  diagonal are predicated off AND not fetched (their index map stays on the
+  last block needed), blocks crossing the diagonal get the triangular mask,
   blocks below run unmasked.
+* the tiles come from the shape (`runtime.flash_tiles`), as does the choice
+  between this kernel and materialized scores (`runtime.attention_path`);
+  `attention_plan` / `flash_attention_for_config` are what the model asks.
 * sequence padding to the block size is sound under causal masking (padded
   keys sit above every valid query's diagonal) and padded query rows are
   sliced off on the way out.
@@ -26,11 +39,13 @@ Kernel structure (classic FlashAttention on the MXU):
   cos/sin tiles (``rot = x * C + swap(x) * S``) with no strided access —
   the rotated Q/K never round-trip through HBM.
 
-The backward pass is the standard FlashAttention-2 split: the forward
-additionally emits the per-row logsumexp; the backward recomputes score
-tiles in VMEM (never materializing S^2) in two kernels — dK/dV with the
-query axis innermost (accumulators live in VMEM scratch per key block) and
-dQ with the key axis innermost.  ``delta = rowsum(dO * O)`` is one cheap
+The backward pass is FlashAttention-2's recompute in ONE kernel: the forward
+additionally emits the per-row logsumexp; the backward recomputes each score
+tile once in VMEM, transposed (``S^T = K Q^T``, so the row statistics
+broadcast along sublanes and four of the five matmuls need no transpose),
+with the query axis innermost — dK/dV accumulate in VMEM scratch per key
+block, dQ in a whole-sequence float32 scratch written when the head is done.
+``delta = rowsum(dO * O)`` is one cheap
 elementwise XLA pass.  For the RoPE-fused variant the backward applies the
 (orthogonal) rotation to Q/K outside the kernel — elementwise, O(S*d) — and
 un-rotates dQ/dK with the transposed rotation, so the O(S^2) part still
@@ -47,6 +62,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from bpe_transformer_tpu.kernels.pallas.runtime import (
+    attention_path,
+    flash_tiles,
+    interpret_mode,
+    pick_block,
+)
 from bpe_transformer_tpu.ops.core import MASK_VALUE as NEG_INF
 from bpe_transformer_tpu.ops.core import causal_mask, scaled_dot_product_attention
 
@@ -67,6 +88,58 @@ def _rotate_half_layout(x, c, s, half: int):
     return x * c + swapped * s
 
 
+#: Contraction patterns of the kernels' matmuls: ``A @ B``, ``A @ B.T``
+#: and ``A.T @ B`` over 2-D tiles.
+_NN = (((1,), (0,)), ((), ()))
+_NT = (((1,), (1,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
+
+
+def _mxu(a, b, dims):
+    """One MXU matmul: operands as given (the callers keep them in the
+    inputs' own dtype), float32 accumulation."""
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _scaled(x, scale: float):
+    """``x * scale`` rounded back to ``x``'s dtype (exact for the
+    power-of-two scales of d_head 16/64/256)."""
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+def _crosses_diagonal(iq, ik, block_q: int, block_k: int):
+    """Does tile (iq, ik) hold an entry with key index > query index?"""
+    return ik * block_k + block_k - 1 > iq * block_q
+
+
+def _below_or_on_diagonal(iq, ik, block_q: int, block_k: int):
+    """Does tile (iq, ik) hold any entry the causal mask keeps?"""
+    return ik * block_k <= iq * block_q + block_q - 1
+
+
+def _run_tile(step, iq, ik, block_q: int, block_k: int, causal: bool):
+    """Run ``step(masked)`` for tile (iq, ik): skipped above the causal
+    diagonal, with the triangular mask only where the tile crosses it."""
+    if not causal:
+        step(False)
+        return
+    crosses = _crosses_diagonal(iq, ik, block_q, block_k)
+    pl.when(crosses & _below_or_on_diagonal(iq, ik, block_q, block_k))(
+        lambda: step(True)
+    )
+    pl.when(jnp.logical_not(crosses))(lambda: step(False))
+
+
+def _compiler_params(semantics: tuple[str, ...], block_q: int, block_k: int):
+    """Grid semantics plus a scoped-VMEM limit sized for the score tiles
+    (the 16 MiB default is short of what 1,024-wide tiles keep live)."""
+    tile_bytes = 4 * block_q * block_k
+    return pltpu.CompilerParams(
+        dimension_semantics=semantics,
+        vmem_limit_bytes=int(min(100 << 20, max(32 << 20, 10 * tile_bytes))),
+    )
+
+
 def _flash_kernel(
     *refs,
     scale: float, block_q: int, block_k: int, causal: bool, num_k_blocks: int,
@@ -81,8 +154,7 @@ def _flash_kernel(
         del refs[:3]
     o_ref = refs.pop(0)
     lse_ref = refs.pop(0) if with_lse else None
-    acc_ref, m_ref, l_ref = refs[:3]
-    qrot_ref = refs[3] if rope_half else None
+    acc_ref, m_ref, l_ref, qs_ref = refs
     iq = pl.program_id(1)
     ik = pl.program_id(2)
 
@@ -91,38 +163,31 @@ def _flash_kernel(
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
+        # The scaled (and, fused, rotated) query block is made once per
+        # (batch*head, q-block) and reused across every key block.
         if rope_half:
-            # Rotate the query block once per (batch*head, q-block); it is
-            # reused across every key block from VMEM scratch.
-            qrot_ref[:] = _rotate_half_layout(
+            qs_ref[:] = _rotate_half_layout(
                 q_ref[0].astype(jnp.float32) * scale,
                 cq_ref[:].astype(jnp.float32),
                 sq_ref[:].astype(jnp.float32),
                 rope_half,
-            )
+            ).astype(qs_ref.dtype)
+        else:
+            qs_ref[:] = _scaled(q_ref[0], scale)
 
-    # Key blocks entirely above the causal diagonal contribute nothing.
-    compute = (block_k * ik) <= (block_q * iq + block_q - 1) if causal else True
-
-    @pl.when(compute)
-    def _block():
+    def _step(masked: bool):
         if rope_half:
-            q = qrot_ref[:]
             k = _rotate_half_layout(
                 k_ref[0].astype(jnp.float32),
                 ck_ref[:].astype(jnp.float32),
                 sk_ref[:].astype(jnp.float32),
                 rope_half,
-            )
+            ).astype(k_ref.dtype)
         else:
-            q = q_ref[0].astype(jnp.float32) * scale
-            k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (block_q, block_k)
-
-        if causal:
+            k = k_ref[0]
+        v = v_ref[0]
+        s = _mxu(qs_ref[:], k, _NT)  # (block_q, block_k), float32
+        if masked:
             rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + iq * block_q
             cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + ik * block_k
             s = jnp.where(rows >= cols, s, NEG_INF)
@@ -133,22 +198,24 @@ def _flash_kernel(
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        acc_ref[:] = acc_ref[:] * alpha + _mxu(p.astype(v.dtype), v, _NN)
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
+    _run_tile(_step, iq, ik, block_q, block_k, causal)
+
     @pl.when(ik == num_k_blocks - 1)
     def _finalize():
-        denom = jnp.maximum(l_ref[:, 0:1], 1e-30)  # fully-masked rows -> 0
-        o_ref[0] = (acc_ref[:] / denom).astype(o_ref.dtype)
+        denom = jnp.maximum(l_ref[:], 1e-30)  # fully-masked rows -> 0
+        o_ref[0] = (acc_ref[:] / denom[:, 0:1]).astype(o_ref.dtype)
         if with_lse:
-            # Per-row logsumexp for the FA-2 backward.  Under the causal
-            # mask every row sees at least its diagonal, so l > 0 and the
-            # value is finite (padded rows included).
-            lse = m_ref[:, 0:1] + jnp.log(denom)
-            lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+            # Per-row logsumexp for the FA-2 backward, stored as a ROW
+            # (1, block_q): the statistics travel compact through HBM and
+            # the backward kernel broadcasts them along sublanes.  Under
+            # the causal mask every row sees at least its diagonal, so
+            # l > 0 and the value is finite (padded rows included).
+            lse = m_ref[:] + jnp.log(denom)  # lane-broadcast (block_q, LANES)
+            lse_ref[0] = jnp.transpose(lse)[0:1, :]
 
 
 def _xla_attention(q, k, v, causal: bool):
@@ -161,26 +228,12 @@ def _xla_attention(q, k, v, causal: bool):
     return out.astype(q.dtype)
 
 
-def _flash_impl(
-    q, k, v, causal, block_q, block_k, interpret, cos=None, sin=None,
-    return_lse=False,
-):
-    *batch, s, d = q.shape
-    bh = 1
-    for dim in batch:
-        bh *= dim
-    rope = cos is not None
-    if rope and (cos.shape != (s, d // 2) or sin.shape != (s, d // 2)):
-        raise ValueError(
-            f"cos/sin must be position-gathered to shape (seq, d//2) = "
-            f"{(s, d // 2)}, got {cos.shape} / {sin.shape}; select rows from "
-            "rope_tables(...) by token position before calling"
-        )
-
+def _tiling(s: int, block_q: int, block_k: int, causal: bool):
+    """``(block_q, block_k, s_pad)``: the tiles clamped to the sequence and
+    the length padded so BOTH divide it (or the grid would skip trailing
+    query/key blocks and return garbage rows)."""
     block_q = min(block_q, s)
     block_k = min(block_k, s)
-    # Pad so BOTH block sizes divide the padded length, or the grid would
-    # skip trailing query/key blocks and return garbage rows.
     block = math.lcm(block_q, block_k)
     s_pad = pl.cdiv(s, block) * block
     if s_pad != s and not causal:
@@ -188,36 +241,66 @@ def _flash_impl(
             f"non-causal flash attention requires seq ({s}) divisible by the "
             f"block size ({block})"
         )
-    d_pad = pl.cdiv(d, LANES) * LANES
+    return block_q, block_k, s_pad
 
-    def prep(x):
-        x = x.reshape(bh, s, d)
-        return jnp.pad(x, ((0, 0), (0, s_pad - s), (0, d_pad - d)))
+
+def _flatten(x, bh: int, s_pad: int):
+    """``(..., s, d) -> (bh, s_pad, d)``; the head dim keeps its own width
+    (a block whose last dimension is the array's own is legal, so d_head 64
+    is NOT padded to 128 lanes in HBM)."""
+    *_, s, d = x.shape
+    x = x.reshape(bh, s, d)
+    if s_pad != s:
+        x = jnp.pad(x, ((0, 0), (0, s_pad - s), (0, 0)))
+    return x
+
+
+# The two launchers below are jitted so that a model's layers share ONE
+# traced kernel and ONE lowered Mosaic module each: inside an outer trace
+# every further call at the same shapes is a cache hit, where a bare
+# pallas_call would trace and lower its kernel again (0.12 s a call: 3 s of
+# every process start of the 12-layer step, compile cache or not).
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("causal", "block_q", "block_k", "interpret", "return_lse"),
+)
+def _flash_impl(
+    q, k, v, causal, block_q, block_k, interpret, cos=None, sin=None,
+    return_lse=False,
+):
+    *batch, s, d = q.shape
+    bh = math.prod(batch)
+    rope = cos is not None
+    if rope and (cos.shape != (s, d // 2) or sin.shape != (s, d // 2)):
+        raise ValueError(
+            f"cos/sin must be position-gathered to shape (seq, d//2) = "
+            f"{(s, d // 2)}, got {cos.shape} / {sin.shape}; select rows from "
+            "rope_tables(...) by token position before calling"
+        )
+    block_q, block_k, s_pad = _tiling(s, block_q, block_k, causal)
 
     if rope:
-        half = d // 2
         # Scores are invariant to a fixed feature permutation applied to both
         # Q and K: move from the interleaved pair convention to a half-split
         # layout so the in-kernel rotation needs no strided access.
         to_half = lambda x: jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
         q, k = to_half(q), to_half(k)
-        # Full-width tiles [cos|cos|0] / [sin|sin|0], padded to (s_pad, d_pad).
-        ctile = jnp.pad(
-            jnp.concatenate([cos, cos], axis=-1).astype(jnp.float32),
-            ((0, s_pad - s), (0, d_pad - d)),
+        # Full-width tiles [cos|cos] / [sin|sin], padded to (s_pad, d).
+        tile = lambda t: jnp.pad(
+            jnp.concatenate([t, t], axis=-1).astype(jnp.float32),
+            ((0, s_pad - s), (0, 0)),
         )
-        stile = jnp.pad(
-            jnp.concatenate([sin, sin], axis=-1).astype(jnp.float32),
-            ((0, s_pad - s), (0, d_pad - d)),
-        )
+        ctile, stile = tile(cos), tile(sin)
 
-    qp, kp, vp = prep(q), prep(k), prep(v)
+    qp, kp, vp = (_flatten(x, bh, s_pad) for x in (q, k, v))
     nq = s_pad // block_q
     nk = s_pad // block_k
 
     kernel = functools.partial(
         _flash_kernel,
-        scale=1.0 / (d**0.5),  # true head dim, not the lane-padded one
+        scale=1.0 / (d**0.5),
         block_q=block_q,
         block_k=block_k,
         causal=causal,
@@ -225,39 +308,41 @@ def _flash_impl(
         rope_half=(d // 2) if rope else 0,
         with_lse=return_lse,
     )
-    qspec = pl.BlockSpec((1, block_q, d_pad), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM)
-    kspec = pl.BlockSpec((1, block_k, d_pad), lambda b, i, j: (b, j, 0), memory_space=pltpu.VMEM)
+    if causal:
+        # Above the diagonal the key index stays on the last block the query
+        # block needs, so a skipped grid step fetches nothing.
+        k_index = lambda b, i, j: (
+            b, jnp.minimum(j, (i * block_q + block_q - 1) // block_k), 0
+        )
+    else:
+        k_index = lambda b, i, j: (b, j, 0)
+    q_index = lambda b, i, j: (b, i, 0)
+    qspec = pl.BlockSpec((1, block_q, d), q_index, memory_space=pltpu.VMEM)
+    kspec = pl.BlockSpec((1, block_k, d), k_index, memory_space=pltpu.VMEM)
     in_specs = [qspec, kspec, kspec]
     operands = [qp, kp, vp]
     scratch = [
-        pltpu.VMEM((block_q, d_pad), jnp.float32),  # output accumulator
+        pltpu.VMEM((block_q, d), jnp.float32),  # output accumulator
         pltpu.VMEM((block_q, LANES), jnp.float32),  # running row max
         pltpu.VMEM((block_q, LANES), jnp.float32),  # running denominator
+        pltpu.VMEM((block_q, d), q.dtype),  # scaled (rotated) Q block
     ]
     if rope:
-        tile_q = pl.BlockSpec((block_q, d_pad), lambda b, i, j: (i, 0), memory_space=pltpu.VMEM)
-        tile_k = pl.BlockSpec((block_k, d_pad), lambda b, i, j: (j, 0), memory_space=pltpu.VMEM)
+        tile_q = pl.BlockSpec((block_q, d), lambda b, i, j: (i, 0), memory_space=pltpu.VMEM)
+        tile_k = pl.BlockSpec(
+            (block_k, d), lambda b, i, j: k_index(b, i, j)[1:], memory_space=pltpu.VMEM
+        )
         in_specs += [tile_q, tile_q, tile_k, tile_k]
         operands += [ctile, stile, ctile, stile]
-        scratch.append(pltpu.VMEM((block_q, d_pad), jnp.float32))  # rotated Q
 
     out_shape = jax.ShapeDtypeStruct(qp.shape, qp.dtype)
-    out_spec = pl.BlockSpec(
-        (1, block_q, d_pad), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM
-    )
+    out_spec = qspec
     if return_lse:
-        # lse is written lane-broadcast (LANES copies per row) so both the
-        # forward store and the backward loads stay plain (8,128)-tiled
-        # VMEM traffic — same layout trick as the m/l scratch above.
-        out_shape = (
-            out_shape,
-            jax.ShapeDtypeStruct((bh, s_pad, LANES), jnp.float32),
-        )
+        out_shape = (out_shape, jax.ShapeDtypeStruct((bh, 1, s_pad), jnp.float32))
         out_spec = (
             out_spec,
             pl.BlockSpec(
-                (1, block_q, LANES), lambda b, i, j: (b, i, 0),
-                memory_space=pltpu.VMEM,
+                (1, 1, block_q), lambda b, i, j: (b, 0, i), memory_space=pltpu.VMEM
             ),
         )
 
@@ -268,196 +353,172 @@ def _flash_impl(
         in_specs=in_specs,
         out_specs=out_spec,
         scratch_shapes=scratch,
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary"), block_q, block_k
+        ),
         interpret=interpret,
         name="flash_attention_fwd",
     )(*operands)
 
     if return_lse:
         out, lse = out
-        return out[:, :s, :d].reshape(*batch, s, d), lse[:, :, 0]
-    return out[:, :s, :d].reshape(*batch, s, d)
+        return out[:, :s].reshape(*batch, s, d), lse[:, 0]
+    return out[:, :s].reshape(*batch, s, d)
 
 
 # ------------------------------------------------- FlashAttention-2 backward
 
 
-def _bwd_score_block(q_ref, k_ref, lse_ref, scale, block_q, block_k, causal, i, j):
-    """Recompute one (block_q, block_k) probability tile from VMEM refs."""
-    qs = q_ref[0].astype(jnp.float32) * scale
-    kb = k_ref[0].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        qs, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    if causal:
-        rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + i * block_q
-        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * block_k
-        s = jnp.where(rows >= cols, s, NEG_INF)
-    # exp(NEG_INF - lse) underflows to exactly 0, so masked entries drop out.
-    p = jnp.exp(s - lse_ref[0][:, 0:1])
-    return qs, p
-
-
-def _flash_bwd_dkdv_kernel(
+def _flash_bwd_kernel(
     q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-    dk_ref, dv_ref, dk_acc, dv_acc,
-    *, scale, block_q, block_k, causal, num_q_blocks,
+    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
+    *, scale, block_q, block_k, causal, num_q_blocks, num_k_blocks,
 ):
-    """Grid (batch*heads, S/block_k, S/block_q): the query axis iterates
-    fastest; dK/dV accumulate in VMEM scratch per key block."""
+    """dQ, dK and dV of one (batch*head) in one pass over its score tiles.
+
+    Grid ``(batch*heads, S/block_k, S/block_q)``, query axis innermost.
+    Every tile is recomputed ONCE, transposed — ``S^T = K Q^T`` of shape
+    (block_k, block_q) — so four of the five matmuls are plain ``A @ B`` /
+    ``A @ B.T`` and the row statistics (logsumexp, delta) broadcast along
+    sublanes from their compact (1, block_q) rows.  dK/dV accumulate per
+    key block; dQ accumulates in a whole-sequence float32 scratch (S x d:
+    256 KiB at S=1,024) and is written when the head's last tile is done.
+    """
     j = pl.program_id(1)  # key block
     i = pl.program_id(2)  # query block (innermost)
 
+    @pl.when((j == 0) & (i == 0))
+    def _init_dq():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
     @pl.when(i == 0)
-    def _init():
+    def _init_dkdv():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    compute = (block_k * j) <= (block_q * i + block_q - 1) if causal else True
+    def _step(masked: bool):
+        k, do, v = k_ref[0], do_ref[0], v_ref[0]
+        # The scores the forward took its logsumexp from: the scaled q,
+        # rounded to the operands' dtype as there (not k * scale, which
+        # rounds differently when the scale is no power of two: d_head 128).
+        qs = _scaled(q_ref[0], scale)
+        s_t = _mxu(k, qs, _NT)  # (block_k, block_q), float32
+        if masked:
+            keys = jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 0) + j * block_k
+            queries = jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 1) + i * block_q
+            s_t = jnp.where(queries >= keys, s_t, NEG_INF)
+        # exp(NEG_INF - lse) underflows to exactly 0, so masked entries drop out.
+        p_t = jnp.exp(s_t - lse_ref[0])
+        # dV += P^T dO ; dS = P * (dO V^T - delta) ; dK += dS^T (Q * scale) ;
+        # dQ += dS K, scaled when the head is done
+        dv_acc[:] += _mxu(p_t.astype(do.dtype), do, _NN)
+        dp_t = _mxu(v, do, _NT)
+        ds_t = (p_t * (dp_t - delta_ref[0])).astype(qs.dtype)
+        dk_acc[:] += _mxu(ds_t, qs, _NN)
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        dq_acc[rows, :] += _mxu(ds_t, k, _TN)
 
-    @pl.when(compute)
-    def _block():
-        qs, p = _bwd_score_block(
-            q_ref, k_ref, lse_ref, scale, block_q, block_k, causal, i, j
-        )
-        do = do_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        # dV += P^T dO ; dS = P * (dO V^T - delta) ; dK += dS^T (Q * scale)
-        dv_acc[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta_ref[0][:, 0:1])
-        dk_acc[:] += jax.lax.dot_general(
-            ds, qs, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    _run_tile(_step, i, j, block_q, block_k, causal)
 
     @pl.when(i == num_q_blocks - 1)
-    def _finalize():
+    def _finalize_dkdv():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
-
-def _flash_bwd_dq_kernel(
-    q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-    dq_ref, dq_acc,
-    *, scale, block_q, block_k, causal, num_k_blocks,
-):
-    """Grid (batch*heads, S/block_q, S/block_k): the key axis iterates
-    fastest; dQ accumulates in VMEM scratch per query block."""
-    i = pl.program_id(1)  # query block
-    j = pl.program_id(2)  # key block (innermost)
-
-    @pl.when(j == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    compute = (block_k * j) <= (block_q * i + block_q - 1) if causal else True
-
-    @pl.when(compute)
-    def _block():
-        _, p = _bwd_score_block(
-            q_ref, k_ref, lse_ref, scale, block_q, block_k, causal, i, j
-        )
-        do = do_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta_ref[0][:, 0:1])
-        dq_acc[:] += jax.lax.dot_general(
-            ds, k_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(j == num_k_blocks - 1)
-    def _finalize():
-        # S = (Q * scale) K^T, so dQ picks up the remaining scale factor.
+    @pl.when((j == num_k_blocks - 1) & (i == num_q_blocks - 1))
+    def _finalize_dq():
         dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
+#: Widest backward tile.  The one-pass backward keeps S^T, P^T, dP^T and
+#: dS^T tiles live at once; on the v5e 512 x 512 beat 1,024 x 1,024 there
+#: (3.5 ms a layer against 4.1 at gpt2-small-32k's shape) while the forward
+#: wants the larger tile.
+BWD_MAX_TILE = 512
+
+
+def _bwd_tiles(block_q: int, block_k: int) -> tuple[int, int]:
+    """The backward's tiles for a forward run at ``(block_q, block_k)``: a
+    tile over :data:`BWD_MAX_TILE` becomes its largest lane-aligned divisor
+    under it (1,024 -> 512, 768 -> 384), or stays whole where that would
+    fall under 256 (896 has only 128): a divisor keeps dividing the
+    forward's padded length."""
+    def cap(block: int) -> int:
+        if block <= BWD_MAX_TILE:
+            return block
+        smaller = pick_block(block, BWD_MAX_TILE, LANES)
+        return smaller if smaller >= 256 else block
+
+    return cap(block_q), cap(block_k)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
+)
 def _flash_bwd_impl(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
-    """Blockwise dQ/dK/dV: two pallas_calls, no S^2 materialization.
+    """Blockwise dQ/dK/dV: one pallas_call, no S^2 materialization.
 
     ``lse`` is the forward's per-row logsumexp, shape ``(batch*heads,
-    s_pad)`` in the padded sequence length.
+    s_pad)`` in the padded sequence length of the forward's tiles.
     """
     *batch, s, d = q.shape
-    bh = 1
-    for dim in batch:
-        bh *= dim
-
-    block_q = min(block_q, s)
-    block_k = min(block_k, s)
-    block = math.lcm(block_q, block_k)
-    s_pad = pl.cdiv(s, block) * block
-    d_pad = pl.cdiv(d, LANES) * LANES
+    bh = math.prod(batch)
+    s_pad = _tiling(s, block_q, block_k, causal)[2]
+    block_q, block_k = _bwd_tiles(min(block_q, s), min(block_k, s))
     nq = s_pad // block_q
     nk = s_pad // block_k
-    scale = 1.0 / (d**0.5)
 
-    def prep(x):
-        x = x.reshape(bh, s, d)
-        return jnp.pad(x, ((0, 0), (0, s_pad - s), (0, d_pad - d)))
-
-    qp, kp, vp, dop, outp = prep(q), prep(k), prep(v), prep(g), prep(out)
+    qp, kp, vp, dop = (_flatten(x, bh, s_pad) for x in (q, k, v, g))
     # delta = rowsum(dO * O): one elementwise pass, O(S*d).  Padded rows have
     # dO = 0, so their delta is 0 and their dS vanishes.
-    delta = jnp.sum(dop.astype(jnp.float32) * outp.astype(jnp.float32), axis=-1)
-    # Lane-broadcast the row statistics (see the forward's lse store).
-    lane = lambda x: jnp.broadcast_to(x[:, :, None], (bh, s_pad, LANES))
-    lse_b, delta_b = lane(lse), lane(delta)
+    delta = jnp.sum(
+        g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
+    ).reshape(bh, 1, s)
+    delta = jnp.pad(delta, ((0, 0), (0, 0), (0, s_pad - s)))
+    lse = lse.reshape(bh, 1, s_pad)
 
-    qspec = lambda im: pl.BlockSpec((1, block_q, d_pad), im, memory_space=pltpu.VMEM)
-    kspec = lambda im: pl.BlockSpec((1, block_k, d_pad), im, memory_space=pltpu.VMEM)
-    rowspec = lambda im: pl.BlockSpec((1, block_q, LANES), im, memory_space=pltpu.VMEM)
-
-    # dK/dV: grid (bh, nk, nq), query axis innermost.
-    by_q = lambda b, j, i: (b, i, 0)
+    if causal:
+        # Key block j needs query blocks from (j * block_k) // block_q on;
+        # before that the query index stays put, so a skipped step fetches
+        # nothing.
+        q_block = lambda j, i: jnp.maximum(i, (j * block_k) // block_q)
+    else:
+        q_block = lambda j, i: i
+    by_q = lambda b, j, i: (b, q_block(j, i), 0)
     by_k = lambda b, j, i: (b, j, 0)
-    dk, dv = pl.pallas_call(
+    stat = lambda b, j, i: (b, 0, q_block(j, i))
+    qspec = pl.BlockSpec((1, block_q, d), by_q, memory_space=pltpu.VMEM)
+    kspec = pl.BlockSpec((1, block_k, d), by_k, memory_space=pltpu.VMEM)
+    rowspec = pl.BlockSpec((1, 1, block_q), stat, memory_space=pltpu.VMEM)
+    whole = pl.BlockSpec((1, s_pad, d), lambda b, j, i: (b, 0, 0), memory_space=pltpu.VMEM)
+
+    dq, dk, dv = pl.pallas_call(
         functools.partial(
-            _flash_bwd_dkdv_kernel,
-            scale=scale, block_q=block_q, block_k=block_k, causal=causal,
-            num_q_blocks=nq,
+            _flash_bwd_kernel,
+            scale=1.0 / (d**0.5), block_q=block_q, block_k=block_k,
+            causal=causal, num_q_blocks=nq, num_k_blocks=nk,
         ),
         out_shape=(
+            jax.ShapeDtypeStruct(qp.shape, q.dtype),
             jax.ShapeDtypeStruct(kp.shape, k.dtype),
             jax.ShapeDtypeStruct(vp.shape, v.dtype),
         ),
         grid=(bh, nk, nq),
-        in_specs=[qspec(by_q), qspec(by_q), rowspec(by_q), rowspec(by_q),
-                  kspec(by_k), kspec(by_k)],
-        out_specs=(kspec(by_k), kspec(by_k)),
+        in_specs=[qspec, qspec, rowspec, rowspec, kspec, kspec],
+        out_specs=(whole, kspec, kspec),
         scratch_shapes=[
-            pltpu.VMEM((block_k, d_pad), jnp.float32),
-            pltpu.VMEM((block_k, d_pad), jnp.float32),
+            pltpu.VMEM((s_pad, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        interpret=interpret,
-        name="flash_attention_bwd_dkdv",
-    )(qp, dop, lse_b, delta_b, kp, vp)
-
-    # dQ: grid (bh, nq, nk), key axis innermost.
-    by_q2 = lambda b, i, j: (b, i, 0)
-    by_k2 = lambda b, i, j: (b, j, 0)
-    dq = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dq_kernel,
-            scale=scale, block_q=block_q, block_k=block_k, causal=causal,
-            num_k_blocks=nk,
+        compiler_params=_compiler_params(
+            ("parallel", "arbitrary", "arbitrary"), block_q, block_k
         ),
-        out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
-        grid=(bh, nq, nk),
-        in_specs=[qspec(by_q2), qspec(by_q2), rowspec(by_q2), rowspec(by_q2),
-                  kspec(by_k2), kspec(by_k2)],
-        out_specs=qspec(by_q2),
-        scratch_shapes=[pltpu.VMEM((block_q, d_pad), jnp.float32)],
         interpret=interpret,
-        name="flash_attention_bwd_dq",
-    )(qp, dop, lse_b, delta_b, kp, vp)
+        name="flash_attention_bwd",
+    )(qp, dop, lse, delta, kp, vp)
 
-    unpad = lambda x: x[:, :s, :d].reshape(*batch, s, d)
+    unpad = lambda x: x[:, :s].reshape(*batch, s, d)
     return unpad(dq), unpad(dk), unpad(dv)
 
 
@@ -474,22 +535,33 @@ def flash_attention(
     """Blockwise attention over ``(..., seq, head_dim)`` inputs.
 
     Leading dims (batch, heads) are arbitrary; seq is padded to the block
-    size internally (sound under ``causal=True``); head_dim is zero-padded
-    to the 128-lane width and sliced back.
+    size internally (sound under ``causal=True``); head_dim reaches the
+    kernels at its own width.
     """
     return _flash_impl(q, k, v, causal, block_q, block_k, interpret)
 
 
+def attention_plan(config, seq_len: int) -> tuple[str, tuple[int, int]]:
+    """``(path, (block_q, block_k))`` of causal self-attention for this
+    config at ``seq_len``: ``config.attention_impl`` forces ``"xla"`` or the
+    flash kernel (``"flash"``/``"flash_fused"``), ``"auto"`` asks
+    :func:`runtime.attention_path` — the shape decides.  The tiles are the
+    ones :func:`flash_attention_for_config` runs, whatever the path."""
+    if config.attention_impl == "auto":
+        path = attention_path(seq_len, config.d_head)
+    else:
+        path = "xla" if config.attention_impl == "xla" else "flash"
+    return path, flash_tiles(seq_len)
+
+
 def flash_attention_for_config(q, k, v, config, *, causal: bool = True) -> jax.Array:
-    """Config-driven plain-flash dispatch: block size from
-    ``config.flash_block_size``, interpret mode from the backend.  The ONE
+    """Config-driven plain-flash dispatch: tiles from the operands' shape
+    (:func:`runtime.flash_tiles`), interpret mode from the backend.  The ONE
     call shared by the training attention (`models/transformer.py`), the
     decode prefill (`models/decode.py`), and future sites — so the call
     signature and interpret-mode policy can't drift between copies."""
-    from bpe_transformer_tpu.kernels.pallas.runtime import interpret_mode
-
-    block = config.flash_block_size
-    return flash_attention(q, k, v, causal, block, block, interpret_mode())
+    block_q, block_k = flash_tiles(q.shape[-2])
+    return flash_attention(q, k, v, causal, block_q, block_k, interpret_mode())
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):

@@ -89,8 +89,20 @@ class ModelConfig:
     #: on bf16 configs.  Requires num_layers >= 1 and homogeneous blocks
     #: (always true for this architecture).
     scan_layers: bool = False
-    # "xla" (materialized) | "flash" (Pallas) | "flash_fused" (RoPE in-kernel)
-    attention_impl: str = "xla"
+    #: Causal self-attention of the training forward, eval and the dense
+    #: engine's prefill.  ``"auto"`` (the default; presets set nothing): the
+    #: shape decides — `kernels.pallas.runtime.attention_path` picks the
+    #: Pallas flash kernel where materialized S x S scores cost more than
+    #: they save (on the TPU, S >= 512 that 128-lane tiles divide, d_head
+    #: >= 64, bfloat16 and float32 alike), the materialized XLA path
+    #: elsewhere, and `runtime.flash_tiles` picks the kernel's tiles.  A
+    #: program that XLA's SPMD partitioner splits (the GSPMD steps, eval on
+    #: a sharded batch) cannot hold a Mosaic kernel and takes "auto" as
+    #: "xla" (`parallel.train_step.partitioned_config`).  The other values
+    #: FORCE a path (tests, benchmarks, `parallel/sp.py`, which only runs
+    #: ring-flash when told to): ``"xla"`` (materialized) | ``"flash"``
+    #: (Pallas) | ``"flash_fused"`` (RoPE in-kernel).
+    attention_impl: str = "auto"
     # "xla" | "pallas" (fused SwiGLU kernel; swiglu FFNs only)
     ffn_impl: str = "xla"
     #: Decode-step attention against the KV cache: "xla" (grouped einsum,
@@ -103,7 +115,11 @@ class ModelConfig:
     #: it as "pallas").  Inference-only knob — the training attention path
     #: is attention_impl.
     decode_attention_impl: str = "xla"
-    flash_block_size: int = 256  # q/k tile size for the flash kernel
+    #: q/k tile of the ring-flash schedules (`parallel/sp.py`, the only
+    #: reader: its per-device shards are not the sequence, and tiny in
+    #: tests).  Every other flash call takes its tiles from the shape
+    #: (`kernels.pallas.runtime.flash_tiles`).
+    flash_block_size: int = 256
     #: attention_impl="flash_fused" auto-falls-back to the plain flash
     #: kernel (RoPE outside) below this sequence length: the in-kernel RoPE
     #: rematerialization only pays off once the sequence is long enough

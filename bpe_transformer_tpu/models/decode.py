@@ -168,12 +168,18 @@ def prefill(
     batch, plen = token_ids.shape
     positions = jnp.arange(plen)
     x = _embed(params, token_ids)
-    # Long prompts honor the config's flash kernel: the materialized path
-    # needs an O(plen^2) score buffer per layer, which is exactly the
-    # memory wall the training side removes with flash attention.  RoPE is
-    # already applied outside (decode owns per-position tables), so both
-    # "flash" and "flash_fused" map to the plain flash kernel here.
-    use_flash = config.attention_impl in ("flash", "flash_fused")
+    # Long prompts take the flash kernel (forced by the config, or chosen
+    # from the prompt's shape under "auto"): the materialized path needs an
+    # O(plen^2) score buffer per layer, which is exactly the memory wall
+    # the training side removes with flash attention.  RoPE is already
+    # applied outside (decode owns per-position tables), so both "flash"
+    # and "flash_fused" map to the plain flash kernel here.
+    from bpe_transformer_tpu.kernels.pallas.flash_attention import (
+        attention_plan,
+        flash_attention_for_config,
+    )
+
+    use_flash = attention_plan(config, plen)[0] == "flash"
     if not use_flash:
         scale = 1.0 / jnp.sqrt(jnp.asarray(config.d_head, jnp.float32))
         mask = jnp.tril(jnp.ones((plen, plen), bool))
@@ -192,10 +198,6 @@ def prefill(
             )
             k, v = _expand_kv(k, config), _expand_kv(v, config)
             if use_flash:
-                from bpe_transformer_tpu.kernels.pallas.flash_attention import (
-                    flash_attention_for_config,
-                )
-
                 att = merge_heads(flash_attention_for_config(q, k, v, config))
                 return linear(att, block_params["attn"]["output_proj"])
             scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale

@@ -158,18 +158,27 @@ def _attention(
     attention_fn=None,
     entropy_tap: dict | None = None,
 ) -> Array:
-    if attention_fn is None and config.attention_impl == "flash":
+    if attention_fn is None and config.attention_impl in ("auto", "flash"):
         from bpe_transformer_tpu.kernels.pallas.flash_attention import (
+            attention_plan,
             flash_attention_for_config,
         )
 
-        attention_fn = lambda q, k, v: flash_attention_for_config(q, k, v, config)
+        # "auto": the shape decides; "xla" leaves attention_fn None, the
+        # materialized path inside multihead_self_attention.
+        if attention_plan(config, x.shape[-2])[0] == "flash":
+            attention_fn = lambda q, k, v: flash_attention_for_config(
+                q, k, v, config
+            )
     elif attention_fn is None and config.attention_impl == "flash_fused":
         from bpe_transformer_tpu.kernels.pallas.flash_attention import (
             flash_attention_for_config,
             flash_attention_with_rope,
         )
-        from bpe_transformer_tpu.kernels.pallas.runtime import interpret_mode
+        from bpe_transformer_tpu.kernels.pallas.runtime import (
+            flash_tiles,
+            interpret_mode,
+        )
 
         if rope_cos_sin is None:
             raise ValueError("attention_impl='flash_fused' requires RoPE enabled")
@@ -181,7 +190,6 @@ def _attention(
                 f"the batch, so positions must be 1-D, got {positions.shape}; "
                 "use attention_impl='flash' for per-example positions"
             )
-        block = config.flash_block_size
         if x.shape[-2] < config.flash_fused_min_seq:
             # Below the measured crossover the in-kernel RoPE recompute
             # costs more than it saves: dispatch the plain flash kernel
@@ -195,8 +203,9 @@ def _attention(
             cos, sin = rope_cos_sin
             cos_p, sin_p = cos[positions], sin[positions]
             rope_cos_sin = None
+            block_q, block_k = flash_tiles(x.shape[-2])
             attention_fn = lambda q, k, v: flash_attention_with_rope(
-                q, k, v, cos_p, sin_p, True, block, block, interpret_mode()
+                q, k, v, cos_p, sin_p, True, block_q, block_k, interpret_mode()
             )
     elif attention_fn is None and config.attention_impl != "xla":
         raise ValueError(f"unknown attention_impl: {config.attention_impl!r}")

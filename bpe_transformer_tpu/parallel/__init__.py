@@ -34,6 +34,7 @@ from bpe_transformer_tpu.parallel.sp import (
 from bpe_transformer_tpu.parallel.train_step import (
     make_dp_train_step,
     make_gspmd_train_step,
+    partitioned_config,
     shard_batch,
 )
 
@@ -57,6 +58,7 @@ __all__ = [
     "make_mesh",
     "param_shardings",
     "param_specs",
+    "partitioned_config",
     "replicated",
     "shard_batch",
     "shard_params",

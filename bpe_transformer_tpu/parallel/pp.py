@@ -38,7 +38,7 @@ from bpe_transformer_tpu.ops.core import embedding, rmsnorm
 from bpe_transformer_tpu.ops.rope import rope_tables
 from bpe_transformer_tpu.optim.adamw import AdamWState, adamw_init, adamw_update
 from bpe_transformer_tpu.optim.schedule import cosine_schedule_jax
-from bpe_transformer_tpu.training.train_step import TrainHParams
+from bpe_transformer_tpu.training.train_step import TrainHParams, jit_step
 
 P = PartitionSpec
 
@@ -368,7 +368,7 @@ def make_pp_train_step(
         out_specs=(param_specs, opt_specs, metric_specs),
         check_vma=False,
     )
-    return jax.jit(mapped, donate_argnums=(0, 1))
+    return jit_step(mapped)
 
 
 def shard_pp_params(pp_params: dict, mesh: Mesh, pp_axis: str = "pp"):

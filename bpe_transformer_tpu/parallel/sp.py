@@ -40,6 +40,7 @@ from bpe_transformer_tpu.parallel.ulysses import ulysses_attention
 from bpe_transformer_tpu.training.train_step import (
     TrainHParams,
     accumulate_grads,
+    jit_step,
     scanned_step_fn,
 )
 
@@ -282,7 +283,7 @@ def make_sp_train_step(
         out_specs=(P(), P(), P()),
         check_vma=False,
     )
-    return jax.jit(mapped, donate_argnums=(0, 1))
+    return jit_step(mapped)
 
 
 def shard_sp_batch(

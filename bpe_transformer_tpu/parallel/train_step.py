@@ -19,6 +19,7 @@ Two complementary executions of the same update body
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Callable
 
@@ -31,11 +32,28 @@ from bpe_transformer_tpu.parallel.sharding import param_shardings
 from bpe_transformer_tpu.training.train_step import (
     TrainHParams,
     grad_accum_step_fn,
+    jit_step,
     scanned_step_fn,
     train_step_fn,
 )
 
 P = PartitionSpec
+
+
+def partitioned_config(config: ModelConfig, mesh: Mesh | None) -> ModelConfig:
+    """``config`` as a program that XLA's SPMD partitioner splits over
+    ``mesh`` must see it: a ``jax.jit`` whose operands are sharded over more
+    than one device — the GSPMD steps below, the loop's eval forward on a
+    sharded batch.  The partitioner cannot split a Mosaic kernel ("Mosaic
+    kernels cannot be automatically partitioned"), and the shape that
+    ``attention_impl="auto"`` chooses from does not say who partitions the
+    program, so "auto" becomes the materialized ``"xla"`` path there: what
+    every such program ran before the choice existed.  Bodies under
+    ``shard_map`` (dp, pp) are per-device programs and keep "auto"; a
+    forced ``"flash"`` is left to fail as loudly as it always has."""
+    if mesh is not None and mesh.size > 1 and config.attention_impl == "auto":
+        return dataclasses.replace(config, attention_impl="xla")
+    return config
 
 
 def _multi_step_body(
@@ -145,7 +163,7 @@ def make_dp_train_step(
         out_specs=(P(), opt_spec, P()),
         check_vma=False,
     )
-    return jax.jit(mapped, donate_argnums=(0, 1))
+    return jit_step(mapped)
 
 
 def make_gspmd_train_step(
@@ -184,8 +202,8 @@ def make_gspmd_train_step(
     if opt_sharding not in (None, "zero1"):
         raise ValueError(f"unknown opt_sharding: {opt_sharding!r}")
     body, stacked = _multi_step_body(
-        config, hparams, accum_steps, inner_steps, reduce_axis=None,
-        health=health, dynamics=dynamics,
+        partitioned_config(config, mesh), hparams, accum_steps, inner_steps,
+        reduce_axis=None, health=health, dynamics=dynamics,
     )
     p_sh = param_shardings(example_params, mesh, strategy)
     replicated = NamedSharding(mesh, P())
@@ -204,11 +222,10 @@ def make_gspmd_train_step(
     # The metrics out-sharding is a pytree PREFIX: one replicated sharding
     # covers the whole dict regardless of which keys (health sub-dicts
     # included) the body emits — all metrics are scalars.
-    return jax.jit(
+    return jit_step(
         body,
         in_shardings=(p_sh, opt_sh, batch_sh, batch_sh),
         out_shardings=(p_sh, opt_sh, replicated),
-        donate_argnums=(0, 1),
     )
 
 

@@ -321,11 +321,20 @@ class StepProbe:
         the gradient ``pmean`` dropped — it runs over the same mesh on the
         same sharded batch, so ``full − local`` isolates exactly the
         collective (placement, shapes, and per-chip compute identical)."""
-        from bpe_transformer_tpu.parallel.train_step import _multi_step_body
+        from bpe_transformer_tpu.parallel.train_step import (
+            _multi_step_body,
+            partitioned_config,
+        )
+
+        # The probe compiles what the loop's step holds: a GSPMD program
+        # takes the config its partitioner can split.
+        config = self.config
+        if self.mesh is not None and self.parallel != "dp":
+            config = partitioned_config(config, self.mesh)
 
         def body(reduce_axis, zero1_shards=None):
             b, _ = _multi_step_body(
-                self.config, self.hparams, self.accum_steps,
+                config, self.hparams, self.accum_steps,
                 self.inner_steps, reduce_axis=reduce_axis,
                 zero1_shards=zero1_shards,
             )

@@ -25,6 +25,13 @@ from __future__ import annotations
 #: registered under the pseudo-kind ``"metric"``.
 RECORD_SCHEMAS: dict[str, set[str]] = {
     # Run header: config, mesh, versions, git SHA, host (telemetry/manifest.py).
+    # A training header additionally carries (optional; absent under
+    # ``parallel="sp"``, whose ring schedules bring their own attention)
+    # ``attention_path`` — ``"flash"`` or ``"xla"``, the causal
+    # self-attention the compiled step holds, forced by the config or chosen
+    # from (S, d_head, dtype, backend) — and ``flash_tiles``, the flash
+    # kernel's ``[block_q, block_k]`` (null on the xla path); ``bpe-tpu
+    # train``'s summary row and ``summary.json`` repeat both.
     "manifest": {"kind", "run_kind", "time_utc", "host"},
     # Closed wall-clock span; ``path`` is the /-joined nesting (spans.py).
     "span": {"kind", "name", "path", "t", "dur_s"},

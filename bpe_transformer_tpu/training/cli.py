@@ -67,7 +67,7 @@ def _load_model_config(args, stored: dict | None = None) -> ModelConfig:
         cfg = ModelConfig.from_dict(stored)
         return dataclasses.replace(
             cfg,
-            attention_impl="xla",
+            attention_impl="auto",
             ffn_impl="xla",
             decode_attention_impl="xla",
             remat=False,

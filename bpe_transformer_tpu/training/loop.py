@@ -196,6 +196,7 @@ def train(
         make_gspmd_train_step,
         make_mesh,
         make_sp_train_step,
+        partitioned_config,
         shard_batch,
         shard_params,
         shard_sp_batch,
@@ -652,7 +653,9 @@ def train(
 
         async_saver = AsyncCheckpointer()
 
-    eval_step = make_eval_step(model_config)
+    # Eval batches are sharded over the mesh, so XLA partitions the eval
+    # forward: no Mosaic kernel may be in it (`partitioned_config`).
+    eval_step = make_eval_step(partitioned_config(model_config, mesh))
     n_chips = len(jax.devices()) if mesh is not None else 1
     tokens_per_step = loop.batch_size * model_config.context_length
 
@@ -713,6 +716,25 @@ def train(
     # JSONL this loop produces is self-describing (config, mesh, versions,
     # git SHA) before any metric lands in it.
     telemetry.attach(sinks.log)
+    # The attention path is chosen once per compile, from the shape (and,
+    # for a GSPMD step, from who partitions the program): the header (and
+    # the summary) say which one this run's step holds.  The sp schedules
+    # bring their own attention and are not labelled.
+    attention = {}
+    if loop.parallel != "sp":
+        from bpe_transformer_tpu.kernels.pallas.flash_attention import (
+            attention_plan,
+        )
+
+        gspmd = mesh is not None and loop.parallel not in ("dp", "pp")
+        path, tiles = attention_plan(
+            partitioned_config(model_config, mesh) if gspmd else model_config,
+            model_config.context_length,
+        )
+        attention = {
+            "attention_path": path,
+            "flash_tiles": list(tiles) if path == "flash" else None,
+        }
     telemetry.emit(
         run_manifest(
             kind="train",
@@ -720,7 +742,10 @@ def train(
             loop_config=loop,
             mesh=mesh,
             parallel=loop.parallel,
-            extra={"start_iteration": start_iteration, "n_chips": n_chips},
+            extra={
+                "start_iteration": start_iteration, "n_chips": n_chips,
+                **attention,
+            },
         )
     )
     #: Always-on decision ring (telemetry/flightrecorder.py): rollback,
@@ -1355,6 +1380,7 @@ def train(
         # JSON consumers of summary.json / the CLI's summary line.
         "final_val_loss": None if math.isnan(val_loss) else val_loss,
         "history": history,
+        **attention,
     }
     if preempted is not None:
         summary["preempted"] = preempted

@@ -28,6 +28,10 @@ from bpe_transformer_tpu.kernels.pallas.decode_attention import (
 )
 from bpe_transformer_tpu.kernels.pallas.flash_attention import flash_attention
 from bpe_transformer_tpu.kernels.pallas.quant_matmul import quant_matmul
+from bpe_transformer_tpu.kernels.pallas.runtime import (
+    attention_path,
+    flash_tiles,
+)
 from bpe_transformer_tpu.kernels.pallas.sample import (
     fused_head_sample,
     fused_verify_head,
@@ -39,9 +43,10 @@ BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One described v5e device; the persistent compile cache is off while
-    this module runs (a described-device executable cannot be read back)."""
+def four_chips():
+    """The four described devices of a v5e:2x2; the persistent compile
+    cache is off while this module runs (a described-device executable
+    cannot be read back)."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -55,9 +60,15 @@ def one_chip():
     was_enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", was_enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(four_chips):
+    """One described v5e device."""
+    return SingleDeviceSharding(four_chips[0])
 
 
 def _compile(fn, one_chip, *shapes):
@@ -75,15 +86,110 @@ def _compile(fn, one_chip, *shapes):
 # ------------------------------------------------------------ training
 
 
-@pytest.mark.parametrize("block", [256, 512])
-def test_flash_attention_fwd_bwd(one_chip, block):
+@pytest.mark.parametrize(
+    "tiles", [(256, 256), (512, 512), "picked"], ids=["256", "512", "picked"]
+)
+def test_flash_attention_fwd_bwd(one_chip, tiles):
+    """Forward and the one-pass backward at gpt2-small-32k's attention
+    shape, at fixed tiles and at the tiles `flash_tiles` picks for
+    (1024, 64, bf16); d_head 64 reaches the kernels at its own width (no
+    128-lane copy of q/k/v/dO in HBM) and the row statistics compact."""
+    if tiles == "picked":
+        tiles = flash_tiles(1024)
+    block_q, block_k = tiles
     qkv = ((8, 12, 1024, 64), BF16)
 
     def loss(q, k, v):
-        out = flash_attention(q, k, v, True, block, block, False)
+        out = flash_attention(q, k, v, True, block_q, block_k, False)
         return jnp.sum(out.astype(F32))
 
-    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip, qkv, qkv, qkv)
+    text = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip, qkv, qkv, qkv
+    )
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    assert len(calls) == 2  # forward, backward
+    for call in calls:
+        assert "bf16[96,1024,64]" in call and "bf16[96,1024,128]" not in call
+        assert "f32[96,1,1024]" in call and "f32[96,1024,128]" not in call
+
+
+@pytest.mark.parametrize("seq", [600, 1000])
+def test_flash_attention_unaligned_sequence(one_chip, seq):
+    """A sequence that no 128-lane tile divides (a raw prompt length in
+    `decode.prefill`): "auto" sends it to XLA, and a forced "flash" runs
+    256-wide tiles over the sequence padded to their multiple, so Mosaic
+    never sees an unaligned S x S tile."""
+    assert attention_path(seq, 64, "tpu") == "xla"
+    block_q, block_k = flash_tiles(seq)
+    assert (block_q, block_k) == (256, 256)
+    qkv = ((4, 12, seq, 64), BF16)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, True, block_q, block_k, False)
+        return jnp.sum(out.astype(F32))
+
+    text = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip, qkv, qkv, qkv
+    )
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    padded = -(-seq // 256) * 256
+    assert len(calls) == 2
+    assert all(f"bf16[48,{padded},64]" in call for call in calls)
+
+
+@pytest.mark.parametrize(
+    "strategy,axes,path",
+    [
+        ("fsdp", {"data": 4}, "xla"),
+        ("tp", {"model": 4}, "xla"),
+        ("fsdp_tp", {"data": 2, "model": 2}, "xla"),
+        ("dp", {"data": 4}, "flash"),
+        ("pp", {"pp": 2, "data": 2}, "flash"),
+    ],
+)
+def test_auto_attention_lowers_under_every_partitioning(
+    four_chips, monkeypatch, strategy, axes, path
+):
+    """gpt2-small-32k's shape says "flash" on the TPU, but XLA's SPMD
+    partitioner cannot split a Mosaic kernel: the GSPMD steps lower with
+    materialized attention (`partitioned_config`), the explicit-dp and
+    pipeline steps — per-device bodies under shard_map — with the kernels.  Lowered for the
+    described 2x2 with the TPU's branch of the predicate (the CPU's always
+    materializes); two layers, nothing compiled."""
+    import dataclasses
+
+    from bpe_transformer_tpu.models import init_params
+    from bpe_transformer_tpu.optim.adamw import adamw_init
+    from bpe_transformer_tpu.parallel import (
+        make_dp_train_step,
+        make_gspmd_train_step,
+        make_mesh,
+        make_pp_train_step,
+        stack_pipeline_params,
+    )
+    from bpe_transformer_tpu.training.train_step import TrainHParams
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config = dataclasses.replace(GPT2_SMALL_32K, num_layers=2)
+    assert config.attention_impl == "auto"
+    mesh = make_mesh(axes, devices=four_chips)
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), config))
+    if strategy == "pp":
+        params = jax.eval_shape(lambda p: stack_pipeline_params(p, 2), params)
+    opt_state = jax.eval_shape(adamw_init, params)
+    ids = jax.ShapeDtypeStruct((8, config.context_length), I32)
+    if strategy == "dp":
+        step = make_dp_train_step(config, TrainHParams(), mesh)
+    elif strategy == "pp":
+        step = make_pp_train_step(
+            config, TrainHParams(), mesh, num_microbatches=2
+        )
+    else:
+        step = make_gspmd_train_step(
+            config, TrainHParams(), mesh, strategy, example_params=params
+        )
+    text = step.lower(params, opt_state, ids, ids).as_text()
+    assert ("tpu_custom_call" in text) == (path == "flash")
 
 
 @pytest.mark.parametrize(

@@ -180,6 +180,51 @@ def test_flash_attention_bf16():
     )
 
 
+@pytest.mark.parametrize("seq,d_head", [(256, 64), (1024, 64), (512, 128)])
+def test_flash_attention_bf16_within_the_xla_paths_own_error(seq, d_head):
+    """bf16 operands on the MXU, float32 statistics: at the tiles the shape
+    picks, the kernel's forward and gradients on bf16 inputs sit within the
+    error the model's materialized XLA path (bf16 scores and probabilities,
+    float32 softmax) itself holds against a float32 reference.  d_head 128
+    has a scale that is no power of two: the forward and the backward must
+    round the same scaled operand, or the recomputed scores leave the
+    forward's logsumexp."""
+    from bpe_transformer_tpu.kernels.pallas.runtime import flash_tiles
+    from bpe_transformer_tpu.ops.core import (
+        causal_mask,
+        scaled_dot_product_attention,
+    )
+
+    rng = np.random.default_rng(7)
+    shape = (1, 2, seq, d_head)
+    q, k, v, w = (
+        jnp.asarray(rng.standard_normal(shape), dtype=jnp.bfloat16)
+        for _ in range(4)
+    )
+    block_q, block_k = flash_tiles(seq)
+    f32 = lambda x: x.astype(jnp.float32)
+    materialized = lambda q, k, v: scaled_dot_product_attention(
+        q, k, v, causal_mask(seq)
+    )
+    flash = lambda q, k, v: flash_attention(q, k, v, True, block_q, block_k, True)
+
+    def out_and_grads(fn, *qkv):
+        loss = lambda q, k, v: jnp.sum(f32(fn(q, k, v)) * f32(w))
+        out = fn(*qkv)
+        grads = jax.grad(loss, argnums=(0, 1, 2))(*qkv)
+        return [np.asarray(f32(x)) for x in (out, *grads)]
+
+    want = out_and_grads(materialized, f32(q), f32(k), f32(v))
+    xla = out_and_grads(materialized, q, k, v)
+    got = out_and_grads(flash, q, k, v)
+    assert flash(q, k, v).dtype == jnp.bfloat16
+    gap = lambda a, b: np.linalg.norm(a - b) / np.linalg.norm(b)
+    for name, g, x, ref in zip(("out", "dq", "dk", "dv"), got, xla, want):
+        assert gap(g, ref) <= 1.05 * gap(x, ref) + 1e-4, (
+            name, gap(g, ref), gap(x, ref)
+        )
+
+
 # ------------------------------------------------ fused RoPE + attention
 
 
@@ -337,6 +382,119 @@ def test_model_flash_attention_matches_xla_impl():
     np.testing.assert_allclose(
         np.asarray(base), np.asarray(flashed), atol=2e-4, rtol=1e-3
     )
+
+
+#: (seq, d_head, dtype, backend) -> (path, forward tiles).  Every preset's
+#: training shape is a row; the rest pin the line and the tile rule.
+ATTENTION_TABLE = [
+    # presets: ts-test, tinystories-4l, tinystories-12l / -moe,
+    # gpt2-small-32k, gpt2-medium
+    (16, 16, "float32", "tpu", "xla", (16, 16)),
+    (256, 32, "float32", "tpu", "xla", (256, 256)),
+    (512, 64, "float32", "tpu", "flash", (512, 512)),
+    (1024, 64, "bfloat16", "tpu", "flash", (1024, 1024)),
+    # the line: S >= 512 and d_head >= 64, either dtype, on the TPU only
+    (256, 64, "bfloat16", "tpu", "xla", (256, 256)),
+    (384, 64, "bfloat16", "tpu", "xla", (384, 384)),
+    (512, 64, "bfloat16", "tpu", "flash", (512, 512)),
+    (512, 32, "bfloat16", "tpu", "xla", (512, 512)),
+    (1024, 64, "float32", "tpu", "flash", (1024, 1024)),
+    (1024, 128, "bfloat16", "tpu", "flash", (1024, 1024)),
+    (1024, 64, "bfloat16", "cpu", "xla", (1024, 1024)),
+    (1024, 64, "bfloat16", "gpu", "xla", (1024, 1024)),
+    # tiles: the largest 128-multiple divisor up to 1,024; a sequence that
+    # has none goes to XLA, and a forced flash pads it to 256-wide tiles
+    # (one tile under 256 positions)
+    (2048, 64, "bfloat16", "tpu", "flash", (1024, 1024)),
+    (1536, 64, "bfloat16", "tpu", "flash", (768, 768)),
+    (4096, 128, "bfloat16", "tpu", "flash", (1024, 1024)),
+    (1000, 64, "bfloat16", "tpu", "xla", (256, 256)),
+    (600, 64, "bfloat16", "tpu", "xla", (256, 256)),
+    (640, 64, "bfloat16", "tpu", "flash", (640, 640)),
+    (200, 64, "float32", "tpu", "xla", (200, 200)),
+]
+
+
+@pytest.mark.parametrize("seq,d_head,dtype,backend,path,tiles", ATTENTION_TABLE)
+def test_attention_path_and_tiles_from_the_shape(
+    seq, d_head, dtype, backend, path, tiles
+):
+    from bpe_transformer_tpu.kernels.pallas.runtime import (
+        attention_path,
+        flash_tiles,
+    )
+
+    del dtype  # names the preset's row; neither choice asks it
+    assert attention_path(seq, d_head, backend) == path
+    assert flash_tiles(seq) == tiles
+
+
+@pytest.mark.parametrize(
+    "forward,backward",
+    [
+        ((1024, 1024), (512, 512)),  # halved: four score tiles live at once
+        ((768, 768), (384, 384)),
+        ((896, 896), (896, 896)),  # only 128 divides it lane-aligned: whole
+        ((512, 256), (512, 256)),
+        ((200, 128), (200, 128)),
+    ],
+)
+def test_flash_backward_tiles_divide_the_forwards(forward, backward):
+    from bpe_transformer_tpu.kernels.pallas.flash_attention import _bwd_tiles
+
+    assert _bwd_tiles(*forward) == backward
+    assert all(f % b == 0 for f, b in zip(forward, backward))
+
+
+def test_attention_table_holds_every_presets_shape(monkeypatch):
+    """The table above is not allowed to miss a preset, and
+    `attention_plan` (what `_attention`, `decode.prefill` and the run
+    manifest ask) agrees with it: forced values force, "auto" chooses."""
+    import dataclasses
+
+    from bpe_transformer_tpu.kernels.pallas.flash_attention import attention_plan
+    from bpe_transformer_tpu.training.cli import PRESETS
+
+    rows = {(s, d, dt, b): (p, t) for s, d, dt, b, p, t in ATTENTION_TABLE}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for name, cfg in PRESETS.items():
+        key = (cfg.context_length, cfg.d_head, cfg.activation_dtype, "tpu")
+        assert key in rows, name
+        assert cfg.attention_impl == "auto", name
+        assert attention_plan(cfg, cfg.context_length) == rows[key], name
+        for forced, path in (("xla", "xla"), ("flash", "flash"), ("flash_fused", "flash")):
+            plan = attention_plan(
+                dataclasses.replace(cfg, attention_impl=forced), cfg.context_length
+            )
+            assert plan == (path, rows[key][1]), (name, forced)
+
+
+@pytest.mark.parametrize("preset", ["ts-test", "tinystories-4l"])
+def test_auto_attention_below_the_line_is_the_materialized_step(
+    preset, monkeypatch
+):
+    """Below the line "auto" hands `multihead_self_attention` no
+    attention_fn, so the step lowers to exactly what the forced-"xla"
+    config (the default before the choice existed) lowers to — steered to
+    the TPU's branch of the predicate, since the CPU's always materializes."""
+    import dataclasses
+
+    from bpe_transformer_tpu.models import init_params
+    from bpe_transformer_tpu.training.cli import PRESETS
+    from bpe_transformer_tpu.training.train_step import make_loss_fn
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = PRESETS[preset]
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    ids = jax.ShapeDtypeStruct((2, cfg.context_length), jnp.int32)
+
+    def lowered(config):
+        fn = jax.jit(jax.value_and_grad(make_loss_fn(config)))
+        return fn.lower(params, ids, ids).as_text()
+
+    auto = lowered(cfg)
+    assert auto == lowered(dataclasses.replace(cfg, attention_impl="xla"))
+    assert "tpu_custom_call" not in auto
 
 
 def test_model_gelu_ffn_trains():

@@ -40,6 +40,28 @@ CFG = dataclasses.replace(TS_TEST_CONFIG, vocab_size=512)
 HP = TrainHParams(warmup_iters=2, cosine_cycle_iters=10)
 
 
+@pytest.mark.parametrize(
+    "devices,impl,want",
+    [
+        (None, "auto", "auto"),  # no mesh: the shape decides
+        (1, "auto", "auto"),  # a one-device mesh partitions nothing
+        (8, "auto", "xla"),  # XLA's partitioner cannot split a Mosaic kernel
+        (8, "xla", "xla"),
+        (8, "flash", "flash"),  # a forced path stays forced
+    ],
+)
+def test_partitioned_config_resolves_auto_attention(devices, impl, want):
+    from bpe_transformer_tpu.parallel import partitioned_config
+
+    mesh = None
+    if devices:
+        mesh = make_mesh({"data": devices}, devices=jax.devices()[:devices])
+    config = dataclasses.replace(CFG, attention_impl=impl)
+    resolved = partitioned_config(config, mesh)
+    assert resolved.attention_impl == want
+    assert dataclasses.replace(resolved, attention_impl=impl) == config
+
+
 def _setup(seed=0):
     params = init_params(jax.random.PRNGKey(seed), CFG)
     opt_state = adamw_init(params)

@@ -826,6 +826,39 @@ def byte_data():
     return np.frombuffer(text, dtype=np.uint8).astype(np.uint16)
 
 
+@pytest.mark.parametrize(
+    "attention_impl,path,tiles",
+    [("auto", "xla", None), ("flash", "flash", [16, 16])],
+)
+def test_train_manifest_and_summary_name_the_attention_path(
+    tmp_path, byte_data, attention_impl, path, tiles
+):
+    """The attention path is chosen once per compile, so its "hit share" is
+    a label: the run-manifest header and the summary row both say which
+    path the step holds and, on the flash path, the kernel's tiles.  (On
+    the CPU "auto" always materializes; gpt2-small-32k's shape resolving to
+    flash on the TPU is pinned in tests/test_kernels.py.)"""
+    import dataclasses
+
+    from bpe_transformer_tpu.training import LoopConfig, TrainHParams, train
+
+    jsonl = tmp_path / "metrics.jsonl"
+    loop = LoopConfig(
+        steps=2, batch_size=4, log_every=2, eval_every=100,
+        checkpoint_every=100, metrics_jsonl=str(jsonl),
+    )
+    summary = train(
+        dataclasses.replace(TINY, attention_impl=attention_impl),
+        TrainHParams(**HP), loop, byte_data, log_fn=lambda *_: None,
+    )
+    manifest = load_records(jsonl)[0]
+    assert manifest["kind"] == "manifest"
+    for record in (manifest, summary):
+        assert record["attention_path"] == path
+        assert record["flash_tiles"] == tiles
+    assert np.isfinite(summary["final_train_loss"])
+
+
 def test_train_emits_unified_stream_and_report_reads_it(tmp_path, byte_data):
     """The acceptance run: health stats + spans + watchdog on a short CPU
     training run produce one self-describing JSONL — manifest header, span

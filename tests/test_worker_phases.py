@@ -210,11 +210,13 @@ def test_same_records_with_and_without_a_session(traced, served, byte_data):
     _, records, summary = traced
     ticks, plain, _ = served
     traced_ticks = [r for r in records if r.get("kind") == "tick"]
-    assert len(traced_ticks) == len(ticks)
-    assert [t["batch"] for t in traced_ticks] == [t["batch"] for t in ticks]
+    # How many requests the worker finds at its first look is a race with
+    # the submitting thread, so the ticks' count and their batches may
+    # differ between two runs; the tokens they emit may not.
+    assert sum(t["batch"] for t in traced_ticks) == sum(t["batch"] for t in ticks)
     # The engine record and what rides its cadence come by the clock; every
     # other record comes by the work, with a session or without.
-    by_clock = {"engine", "resources", "roofline", "kvpool"}
+    by_clock = {"engine", "resources", "roofline", "kvpool", "tick"}
 
     def by_work(stream):
         return sorted(k for k in (r["kind"] for r in stream) if k not in by_clock)
@@ -345,7 +347,7 @@ PALLAS = Path(__file__).resolve().parents[1] / "bpe_transformer_tpu" / "kernels"
 
 @pytest.mark.parametrize(
     "filename, calls",
-    [("decode_attention.py", 2), ("flash_attention.py", 3), ("gelu.py", 1),
+    [("decode_attention.py", 2), ("flash_attention.py", 2), ("gelu.py", 1),
      ("quant_matmul.py", 1), ("sample.py", 1), ("swiglu.py", 1)],
 )
 def test_every_pallas_call_has_a_name(filename, calls):

@@ -59,7 +59,13 @@ DEFAULT_BUDGET_S = 800.0
 #: 630 -> 710 in PR 25 (700 collected) for tests/test_worker_phases.py (73
 #: cases in 21 s: one parametrised case per worker phase, profiler event
 #: and named scope, so each counts; the whole tier-1 run took 169 s).
-DEFAULT_MAX_TESTS = 710
+#: Raised 710 -> 770 in PR 27 (754 collected) for the attention path/tile
+#: table (tests/test_kernels.py: one parametrised row per preset shape and
+#: per side of the line, 19 cases in 2 s), the bf16 kernel parity and
+#: backward-tile cases, the run-manifest label, the AOT flash compiles at
+#: the picked and the unaligned tiles, and the multi-chip lowerings of
+#: "auto" attention for the described v5e:2x2 (5 cases, 1 s each).
+DEFAULT_MAX_TESTS = 770
 
 #: Pytest summary trailer: "== 398 passed, 27 deselected in 612.34s =="
 #: (also plain "in 612.34s (0:10:12)" forms).
