@@ -438,12 +438,14 @@ def paged_decode_attention(
 
 
 @jax.named_scope("decode_attn")
-def xla_decode_attention(q, k_cache, v_cache, pos):
+def xla_decode_attention(q, k_cache, v_cache, pos, window: int | None = None):
     """Materialized-scores formulation: the grouped einsum straight against
     the compact GQA cache (the per-token hot path reads only
     ``kv_heads * ctx`` values — no head expansion), f32 scores + softmax.
     This IS `models/decode.py:decode_step`'s xla attention (that path calls
     here — single implementation) and the kernel's parity oracle.
+    ``window`` keeps only the keys ``pos - window < j <= pos`` (a
+    sliding-window layer reading a full dense cache).
     """
     batch, num_heads, d = q.shape
     kv_heads, ctx = k_cache.shape[1], k_cache.shape[2]
@@ -461,6 +463,9 @@ def xla_decode_attention(q, k_cache, v_cache, pos):
         visible = (jnp.arange(ctx)[None, :] <= pos[:, None])[
             :, None, None, None, :
         ]
+    if window is not None:
+        behind = jnp.reshape(pos, (-1, 1)) - jnp.arange(ctx)[None, :] < window
+        visible = visible & behind[:, None, None, None, :]
     scores = jnp.where(visible, scores, -jnp.inf)
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
     att = jnp.einsum("bkgqc,bkcd->bkgqd", probs, v_cache)

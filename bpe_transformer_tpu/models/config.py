@@ -134,6 +134,41 @@ class ModelConfig:
     # logits.  0 -> force full logits.  N -> chunk N (must divide the
     # sequence; `ops.losses.lm_loss` falls back when it doesn't).
     loss_chunk_size: int | None = None
+    # ---- per-layer attention kinds, the parallel block and the dropless
+    # expert layer (the Cohere2-MoE family; every default is the block
+    # above, so existing configs and checkpoints load unchanged) ----------
+    #: Width of one attention head where it is not ``d_model // num_heads``
+    #: (128 heads x 128 over a hidden size of 4,096).
+    head_dim: int | None = None
+    #: Sliding-window attention: key j is visible to query i iff
+    #: ``0 <= i - j < sliding_window``.  Layers ``l`` with ``(l + 1) %
+    #: sliding_window_pattern != 0`` are window layers and every
+    #: ``sliding_window_pattern``-th layer attends to everything; with the
+    #: default pattern of 1 every layer is a full layer.
+    sliding_window: int | None = None
+    sliding_window_pattern: int = 1
+    #: Whether full-attention layers rotate q and k.  False: no positional
+    #: transform at all on them (window layers keep RoPE).
+    rope_on_full_layers: bool = True
+    #: "rmsnorm" | "layernorm" (mean-subtracting, no bias, eps 1e-5).
+    norm_type: str = "rmsnorm"
+    #: One norm a block and both branches from it:
+    #: ``x + attn(norm(x)) + ffn(norm(x))``.
+    parallel_block: bool = False
+    #: Router scores of the MoE layer: "softmax" over all experts (Switch /
+    #: GShard) or "sigmoid" per expert; with ``router_top_k > 1`` the chosen
+    #: gates are renormalized to sum to one either way.
+    moe_router: str = "softmax"
+    #: Shared experts every token passes through (same SwiGLU width as a
+    #: routed expert); their outputs are averaged and added to the routed
+    #: part.
+    n_shared_experts: int = 0
+    #: Expert parallelism without the exchange: this process holds experts
+    #: ``expert_offset .. expert_offset + experts_held - 1`` of the
+    #: ``n_experts`` the router scores, and computes their part of the
+    #: result only (None = all of them).  Serving only.
+    experts_held: int | None = None
+    expert_offset: int = 0
     # Sequence-parallel ring attention: sub-chunk each visiting K/V shard
     # so per-device score memory is O(S_local * chunk) instead of
     # O(S_local^2).  Must divide the local shard length.  None -> one full
@@ -146,7 +181,47 @@ class ModelConfig:
 
     @property
     def d_head(self) -> int:
+        if self.head_dim is not None:
+            return self.head_dim
         return self.d_model // self.num_heads
+
+    @property
+    def local_experts(self) -> int:
+        """Experts whose weights this process holds."""
+        return self.n_experts if self.experts_held is None else self.experts_held
+
+    @property
+    def has_window_layers(self) -> bool:
+        return self.sliding_window is not None and self.sliding_window_pattern > 1
+
+    def layer_window(self, layer: int) -> int | None:
+        """The sliding window of layer ``layer`` (0-based), None for a
+        full-attention layer."""
+        if self.has_window_layers and (layer + 1) % self.sliding_window_pattern:
+            return self.sliding_window
+        return None
+
+    def layer_rope(self, layer: int) -> bool:
+        """Whether layer ``layer`` rotates q and k."""
+        if self.remove_rope:
+            return False
+        return self.rope_on_full_layers or self.layer_window(layer) is not None
+
+    @property
+    def dropless_block(self) -> bool:
+        """True for what only the serving paths and the plain forward run
+        (no training step, no ``scan_layers``, no int8 weights): a layer
+        pattern, a parallel block, LayerNorm, shared or held experts."""
+        return (
+            self.has_window_layers
+            or self.parallel_block
+            or self.norm_type != "rmsnorm"
+            or not self.rope_on_full_layers
+            or self.n_shared_experts > 0
+            or self.experts_held is not None
+            or self.moe_router != "softmax"
+            or self.head_dim is not None
+        )
 
     @property
     def resolved_remat_policy(self) -> str:
@@ -174,7 +249,55 @@ class ModelConfig:
         return None
 
     def __post_init__(self):
-        if self.d_model % self.num_heads:
+        if self.head_dim is not None and self.head_dim < 1:
+            raise ValueError(f"head_dim={self.head_dim} must be positive")
+        if self.sliding_window is not None and self.sliding_window < 1:
+            raise ValueError(
+                f"sliding_window={self.sliding_window} must be positive"
+            )
+        if self.sliding_window_pattern < 1:
+            raise ValueError(
+                f"sliding_window_pattern={self.sliding_window_pattern} must "
+                "be >= 1"
+            )
+        if self.norm_type not in ("rmsnorm", "layernorm"):
+            raise ValueError(
+                f'norm_type={self.norm_type!r} must be "rmsnorm" or "layernorm"'
+            )
+        if self.moe_router not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f'moe_router={self.moe_router!r} must be "softmax" or "sigmoid"'
+            )
+        if self.n_shared_experts and self.ffn_type != "moe":
+            raise ValueError('n_shared_experts needs ffn_type="moe"')
+        if self.experts_held is not None and not (
+            self.ffn_type == "moe"
+            and 1 <= self.experts_held
+            and 0 <= self.expert_offset
+            and self.expert_offset + self.experts_held <= self.n_experts
+        ):
+            raise ValueError(
+                f"experts_held={self.experts_held} at expert_offset="
+                f"{self.expert_offset} must name experts of a MoE layer with "
+                f"n_experts={self.n_experts}"
+            )
+        if self.parallel_block and self.use_post_norm:
+            raise ValueError("parallel_block has one pre-norm; use_post_norm contradicts it")
+        if self.dropless_block and not self.parallel_block:
+            raise ValueError(
+                "a layer pattern, LayerNorm, head_dim, sigmoid routing, shared "
+                "or held experts run in the parallel block only "
+                "(parallel_block=True): no configuration has them in a "
+                "sequential block"
+            )
+        if self.scan_layers and self.dropless_block:
+            raise ValueError(
+                "scan_layers runs homogeneous training blocks; a layer "
+                "pattern, the parallel block, LayerNorm, shared or held "
+                "experts are served and not trained (ROADMAP: what cannot "
+                "run yet)"
+            )
+        if self.head_dim is None and self.d_model % self.num_heads:
             raise ValueError(
                 f"d_model={self.d_model} not divisible by num_heads={self.num_heads}"
             )

@@ -18,7 +18,6 @@ Weights use the same param pytree as training — no export/conversion step.
 
 from __future__ import annotations
 
-import math
 from functools import partial
 
 import jax
@@ -30,6 +29,7 @@ from bpe_transformer_tpu.models.transformer import Params, lm_head_weight
 from bpe_transformer_tpu.ops.core import (
     embedding,
     head_logits,
+    layernorm,
     linear,
     merge_heads,
     rmsnorm,
@@ -51,8 +51,8 @@ def init_kv_cache(config: ModelConfig, batch: int, dtype=jnp.float32) -> KVCache
     ]
 
 
-def _rope_qk(q, k, positions, config):
-    if config.remove_rope:
+def _rope_qk(q, k, positions, config, layer: int = 0):
+    if not config.layer_rope(layer):
         return q, k
     cos, sin = rope_tables(config.d_head, config.context_length, config.rope_theta)
     # Keep the compute dtype (bf16 decode must not promote to f32 here).
@@ -61,59 +61,55 @@ def _rope_qk(q, k, positions, config):
     return apply_rope(q, pos, cos, sin), apply_rope(k, pos, cos, sin)
 
 
-def _ffn_decode(x, ffn, config):
-    """The training forward's FFN dispatch with the aux loss discarded.
+def _ffn_decode(x, ffn, config, valid=None, tally=None):
+    """The block's FFN as it is served.  A MoE layer is the dropless one
+    (`models/moe.dropless_moe`): a decode step's few tokens and a prefill's
+    many are routed alike and nothing is dropped, whatever capacity the
+    training forward runs at.  ``valid`` leaves rows out of the expert
+    computation; ``tally`` (a list) collects the layer's routing counts."""
+    if config.ffn_type == "moe":
+        from bpe_transformer_tpu.models.moe import dropless_moe
 
-    MoE note: a per-call default capacity (``batch`` tokens at a decode
-    step, the prompt at prefill) would drop tokens the full forward keeps.
-    Instead the capacity is derived from ``context_length`` — what the full
-    uncached forward at max length would use — clamped to this call's token
-    count (a token fills at most one slot per expert, so ``n`` slots is
-    already drop-free).  Decode steps therefore never drop; residual
-    divergence vs the uncached path exists only when the uncached forward
-    itself would drop (see training/sampling.generate_ids).
-    """
+        out, counts = dropless_moe(x, ffn, config, valid=valid)
+        if tally is not None:
+            tally.append(counts)
+        return out
     from bpe_transformer_tpu.models.transformer import _ffn
 
-    moe_capacity = None
-    if config.ffn_type == "moe":
-        from bpe_transformer_tpu.models.moe import expert_capacity
-
-        n_tokens = math.prod(x.shape[:-1])
-        full_forward_cap = expert_capacity(
-            x.shape[0] * config.context_length,
-            config.n_experts,
-            config.capacity_factor,
-        )
-        # Floor at the batch size so single-token decode steps stay
-        # drop-free even for degenerate configs where the full-length
-        # capacity is below the batch (many experts, tiny context).
-        moe_capacity = min(n_tokens, max(full_forward_cap, x.shape[0]))
-    return _ffn(x, ffn, config, moe_capacity=moe_capacity)[0]
+    return _ffn(x, ffn, config)[0]
 
 
-def _block_apply(x, block_params, config, attend):
+def _block_apply(x, block_params, config, attend, valid=None, tally=None):
     """One block around a caller-supplied ``attend(h) -> attention output``.
 
     Mirrors `transformer_block_aux` (models/transformer.py): pre-norm by
-    default, post-norm under the ablation flag.
+    default, post-norm under the ablation flag, both branches from one norm
+    under ``parallel_block``.
     """
+    if config.parallel_block:
+        h = _norm(x, block_params["ln1"], config)
+        with jax.named_scope("block/attn"):
+            a = attend(h)
+        with jax.named_scope("block/ffn"):
+            return x + a + _ffn_decode(h, block_params["ffn"], config, valid, tally)
     if config.use_post_norm:
         with jax.named_scope("block/attn"):
             x = _norm(x + attend(x), block_params["ln1"], config)
         with jax.named_scope("block/ffn"):
-            f = _ffn_decode(x, block_params["ffn"], config)
+            f = _ffn_decode(x, block_params["ffn"], config, valid, tally)
             return _norm(x + f, block_params["ln2"], config)
     with jax.named_scope("block/attn"):
         h = _norm(x, block_params["ln1"], config)
         x = x + attend(h)
     with jax.named_scope("block/ffn"):
         h = _norm(x, block_params["ln2"], config)
-        return x + _ffn_decode(h, block_params["ffn"], config)
+        return x + _ffn_decode(h, block_params["ffn"], config, valid, tally)
 
 
 def _norm(x, w, config):
-    return x if config.remove_rmsnorm else rmsnorm(x, w)
+    if config.remove_rmsnorm:
+        return x
+    return layernorm(x, w) if config.norm_type == "layernorm" else rmsnorm(x, w)
 
 
 def _embed(params, token_ids):
@@ -179,17 +175,34 @@ def prefill(
         flash_attention_for_config,
     )
 
-    use_flash = attention_plan(config, plen)[0] == "flash"
+    # A window layer's band mask has no flash kernel: a config with window
+    # layers prefills its dense cache with materialized scores throughout.
+    use_flash = (
+        attention_plan(config, plen)[0] == "flash"
+        and not config.has_window_layers
+    )
     if not use_flash:
         scale = 1.0 / jnp.sqrt(jnp.asarray(config.d_head, jnp.float32))
-        mask = jnp.tril(jnp.ones((plen, plen), bool))
+        causal = jnp.tril(jnp.ones((plen, plen), bool))
 
     new_cache = []
-    for block_params, layer_cache in zip(params["layers"], cache):
+    for layer, (block_params, layer_cache) in enumerate(
+        zip(params["layers"], cache)
+    ):
+        window = config.layer_window(layer)
+        if not use_flash:
+            mask = causal
+            if window is not None:
+                from bpe_transformer_tpu.ops.core import window_causal_mask
 
-        def attend(h, block_params=block_params, layer_cache=layer_cache):
+                mask = window_causal_mask(plen, window)
+
+        def attend(
+            h, block_params=block_params, layer_cache=layer_cache,
+            layer=layer, mask=None if use_flash else mask,
+        ):
             q, k, v = _project_qkv(h, block_params["attn"], config)
-            q, k = _rope_qk(q, k, positions, config)
+            q, k = _rope_qk(q, k, positions, config, layer)
             new_cache.append(
                 {
                     "k": lax.dynamic_update_slice(layer_cache["k"], k, (0, 0, 0, 0)),
@@ -267,11 +280,17 @@ def decode_step(
     positions = pos[None] if jnp.ndim(pos) == 0 else pos[:, None]  # (1,)|(B,1)
 
     new_cache = []
-    for block_params, layer_cache in zip(params["layers"], cache):
+    for layer, (block_params, layer_cache) in enumerate(
+        zip(params["layers"], cache)
+    ):
+        window = config.layer_window(layer)
 
-        def attend(h, block_params=block_params, layer_cache=layer_cache):
+        def attend(
+            h, block_params=block_params, layer_cache=layer_cache,
+            layer=layer, window=window,
+        ):
             q, k, v = _project_qkv(h, block_params["attn"], config)
-            q, k = _rope_qk(q, k, positions, config)
+            q, k = _rope_qk(q, k, positions, config, layer)
             k_cache = _cache_write(layer_cache["k"], k, pos)
             v_cache = _cache_write(layer_cache["v"], v, pos)
             if active is not None:
@@ -284,7 +303,17 @@ def decode_step(
             # would forfeit GQA's decode-bandwidth win.  "paged" names the
             # block-pool-native kernel; the dense cache has no block table,
             # so it degrades to the contiguous flash-decoding kernel here.
-            if config.decode_attention_impl in ("pallas", "paged"):
+            if window is not None:
+                # The window is a mask over the full dense cache (the
+                # streamed kernel has no lower frontier).
+                from bpe_transformer_tpu.kernels.pallas.decode_attention import (
+                    xla_decode_attention,
+                )
+
+                att = xla_decode_attention(
+                    q[:, :, 0], k_cache, v_cache, pos, window=window
+                )
+            elif config.decode_attention_impl in ("pallas", "paged"):
                 # Flash-decoding kernel: the cache streams through VMEM
                 # once, scores never reach HBM
                 # (kernels/pallas/decode_attention.py; parity pinned by
@@ -840,6 +869,200 @@ def paged_verify_step(
         return x, new_pool
     head = lm_head_weight(params, config) if lm_head is None else lm_head
     return head_logits(x, head), new_pool
+
+
+# ----------------------------------------------- grouped pools (two kinds)
+#
+# A config whose layers differ in kind (sliding-window and full attention)
+# keeps two pool groups: a full layer's pool holds a slot's whole chain, a
+# window layer's only the pages still inside the window.  Both use the page
+# layout of `kernels/pallas/ragged_attention.py` - ``(pages, page_size,
+# 2 * kv_heads, d_head)``, K and V of a head side by side - which the
+# device's default tiling holds as is, so the programs donate the pool and
+# update it in place with no copy at their edges.  ``tables`` is a dict:
+# ``"full"`` and ``"window"`` page rows per slot, and ``"window_base"``, the
+# absolute position of the first row entry of the window group (rows there
+# start at the slot's first live page; full rows start at position 0).
+
+
+def init_grouped_kv_pool(
+    config: ModelConfig, num_full_blocks: int, num_window_blocks: int,
+    block_size: int, dtype=jnp.float32,
+) -> list:
+    """One page array a layer, sized by its group.  Page 0 of every array is
+    the trash page."""
+    kv_heads = config.num_kv_heads or config.num_heads
+    return [
+        jnp.zeros(
+            (
+                num_full_blocks if config.layer_window(layer) is None
+                else num_window_blocks,
+                block_size, 2 * kv_heads, config.d_head,
+            ),
+            dtype,
+        )
+        for layer in range(config.num_layers)
+    ]
+
+
+def _group_rows(tables: dict, config: ModelConfig, layer: int):
+    """``(page rows, base position, window)`` of the layer's group."""
+    window = config.layer_window(layer)
+    if window is None:
+        return tables["full"], 0, None
+    return tables["window"], tables["window_base"], window
+
+
+@jax.named_scope("pool_write")
+def _write_pages(pages, k, v, page_ids, offsets):
+    """Scatter one K and one V row a token, ``(tokens, kv_heads, d_head)``
+    each, to ``pages[page_ids, offsets]``: a whole (2 * kv_heads, d_head)
+    tile a token."""
+    tokens, kv_heads, d_head = k.shape
+    rows = jnp.stack([k, v], axis=2).reshape(tokens, 2 * kv_heads, d_head)
+    return pages.at[page_ids, offsets].set(rows.astype(pages.dtype))
+
+
+def _attn_scope(window):
+    return jax.named_scope("attn_window" if window is not None else "attn_full")
+
+
+def grouped_decode_step(
+    params: Params,
+    token: Array,
+    pos: Array,
+    pool: list,
+    tables: dict,
+    config: ModelConfig,
+    lm_head: Array | None = None,
+    active: Array | None = None,
+    *,
+    block_size: int,
+) -> tuple[Array, list, Array]:
+    """:func:`paged_decode_step` over the grouped pools: one new token a
+    slot, written to its group's page and attended from there by the ragged
+    paged kernel, which reads the pages a slot holds and no others.  Returns
+    ``(logits, pool, moe_counts)``; ``moe_counts`` sums the layers'
+    ``dropless_moe`` counts (zeros without a MoE layer), idle slots left
+    out."""
+    from bpe_transformer_tpu.kernels.pallas.ragged_attention import (
+        ragged_paged_attention,
+    )
+
+    slots = token.shape[0]
+    x = _embed(params, token[:, None])  # (S, 1, d)
+    positions = pos[:, None]
+    live = jnp.ones((slots,), bool) if active is None else active
+    cu_q_lens = jnp.arange(slots + 1, dtype=jnp.int32)
+    num_seqs = jnp.full((1,), slots, jnp.int32)
+    tally: list = []
+    new_pool = []
+    for layer, (block_params, pages) in enumerate(zip(params["layers"], pool)):
+        rows, base, window = _group_rows(tables, config, layer)
+        rel = (pos - base).astype(jnp.int32)
+        page_ids = jnp.take_along_axis(rows, (rel // block_size)[:, None], axis=1)[:, 0]
+        page_ids = jnp.where(live, page_ids, 0)
+        kv_lens = jnp.where(live, rel + 1, 1).astype(jnp.int32)
+
+        def attend(
+            h, block_params=block_params, pages=pages, layer=layer, rows=rows,
+            window=window, rel=rel, page_ids=page_ids, kv_lens=kv_lens,
+        ):
+            q, k, v = _project_qkv(h, block_params["attn"], config)
+            q, k = _rope_qk(q, k, positions, config, layer)
+            pages = _write_pages(
+                pages, k[:, :, 0], v[:, :, 0], page_ids, rel % block_size
+            )
+            new_pool.append(pages)
+            with _attn_scope(window):
+                att = ragged_paged_attention(
+                    q[:, :, 0], pages, kv_lens, rows, cu_q_lens, num_seqs,
+                    window=window, one_query_per_seq=True,
+                )
+            att = att.reshape(slots, 1, -1)
+            return linear(att, block_params["attn"]["output_proj"])
+
+        x = _block_apply(x, block_params, config, attend, valid=live, tally=tally)
+
+    x = _final_norm(x, params, config)
+    head = lm_head_weight(params, config) if lm_head is None else lm_head
+    return head_logits(x[:, 0], head), new_pool, _sum_counts(tally)
+
+
+def grouped_chunk_prefill(
+    params: Params,
+    chunk_tokens: Array,
+    start: Array,
+    chunk_len: Array,
+    table_rows: dict,
+    pool: list,
+    config: ModelConfig,
+    lm_head: Array | None = None,
+    *,
+    block_size: int,
+) -> tuple[Array, list, Array]:
+    """:func:`paged_chunk_prefill` over the grouped pools: the chunk's K/V
+    goes to its group's pages, then its queries attend to the slot's pages
+    through the ragged paged kernel - causal in a full layer, inside the
+    window in a window layer, whose row must reach back to ``start -
+    window + 1``.  ``table_rows`` holds one slot's rows.  Returns
+    ``(last real position's logits, pool, moe_counts)``."""
+    from bpe_transformer_tpu.kernels.pallas.ragged_attention import (
+        ragged_paged_attention,
+    )
+
+    _, cb = chunk_tokens.shape
+    positions = start + jnp.arange(cb)
+    safe_positions = jnp.clip(positions, 0, config.context_length - 1)
+    in_chunk = jnp.arange(cb) < chunk_len
+    cu_q_lens = jnp.stack([0, chunk_len]).astype(jnp.int32)
+    num_seqs = jnp.ones((1,), jnp.int32)
+    x = _embed(params, chunk_tokens)
+    tally: list = []
+    new_pool = []
+    for layer, (block_params, pages) in enumerate(zip(params["layers"], pool)):
+        row, base, window = _group_rows(table_rows, config, layer)
+        rel = (safe_positions - base).astype(jnp.int32)
+        idx = jnp.clip(rel // block_size, 0, row.shape[0] - 1)
+        page_ids = jnp.where(in_chunk, row[idx], 0)
+        kv_lens = jnp.reshape(start + chunk_len - base, (1,)).astype(jnp.int32)
+
+        def attend(
+            h, block_params=block_params, pages=pages, layer=layer, row=row,
+            window=window, rel=rel, page_ids=page_ids, kv_lens=kv_lens,
+        ):
+            q, k, v = _project_qkv(h, block_params["attn"], config)
+            q, k = _rope_qk(q, k, safe_positions, config, layer)
+            pages = _write_pages(
+                pages, jnp.swapaxes(k[0], 0, 1), jnp.swapaxes(v[0], 0, 1),
+                page_ids, rel % block_size,
+            )
+            new_pool.append(pages)
+            with _attn_scope(window):
+                att = ragged_paged_attention(
+                    jnp.swapaxes(q[0], 0, 1), pages, kv_lens, row[None],
+                    cu_q_lens, num_seqs, window=window, one_query_per_seq=False,
+                )
+            # Padded rows are not computed by the kernel: whatever it left
+            # there must not reach the next layer's K/V.
+            att = jnp.where(in_chunk[:, None, None], att, 0)
+            return linear(att.reshape(1, cb, -1), block_params["attn"]["output_proj"])
+
+        x = _block_apply(
+            x, block_params, config, attend, valid=in_chunk[None], tally=tally
+        )
+
+    x = _final_norm(x, params, config)
+    head = lm_head_weight(params, config) if lm_head is None else lm_head
+    idx = jnp.reshape(jnp.clip(chunk_len - 1, 0, cb - 1), (1, 1, 1))
+    last = jnp.take_along_axis(x, idx, axis=1)[:, 0]
+    return head_logits(last, head), new_pool, _sum_counts(tally)
+
+
+def _sum_counts(tally: list) -> Array:
+    if not tally:
+        return jnp.zeros((3,), jnp.int32)
+    return jnp.sum(jnp.stack(tally), axis=0)
 
 
 def _sample_from_logits(

@@ -41,8 +41,12 @@ from bpe_transformer_tpu.ops.core import silu
 
 
 def init_moe_params(rng: jax.Array, config: ModelConfig, dtype=jnp.float32) -> dict:
-    """Stacked expert weights + router for one MoE FFN layer."""
+    """Stacked expert weights + router for one MoE FFN layer: the router
+    over all ``n_experts``, the stacks of the experts held here
+    (``config.local_experts``), and a ``"shared"`` stack where the config
+    has shared experts."""
     e, d, ff = config.n_experts, config.d_model, config.d_ff
+    held, shared = config.local_experts, config.n_shared_experts
 
     def dense(key, shape, std=0.02):
         return (
@@ -50,12 +54,20 @@ def init_moe_params(rng: jax.Array, config: ModelConfig, dtype=jnp.float32) -> d
         ).astype(dtype)
 
     k = jax.random.split(rng, 4)
-    return {
+    params = {
         "router": dense(k[0], (e, d)),
-        "w1": dense(k[1], (e, ff, d)),
-        "w2": dense(k[2], (e, d, ff)),
-        "w3": dense(k[3], (e, ff, d)),
+        "w1": dense(k[1], (held, ff, d)),
+        "w2": dense(k[2], (held, d, ff)),
+        "w3": dense(k[3], (held, ff, d)),
     }
+    if shared:
+        ks = jax.random.split(jax.random.fold_in(rng, 1), 3)
+        params["shared"] = {
+            "w1": dense(ks[0], (shared, ff, d)),
+            "w2": dense(ks[1], (shared, d, ff)),
+            "w3": dense(ks[2], (shared, ff, d)),
+        }
+    return params
 
 
 def expert_capacity(n_tokens: int, n_experts: int, capacity_factor: float) -> int:
@@ -63,7 +75,7 @@ def expert_capacity(n_tokens: int, n_experts: int, capacity_factor: float) -> in
 
 
 def switch_ffn(
-    x: Array, moe_params: dict, config: ModelConfig, capacity: int | None = None
+    x: Array, moe_params: dict, config: ModelConfig
 ) -> tuple[Array, Array]:
     """Top-k routed SwiGLU experts.  Returns ``(output, aux_loss)``.
 
@@ -73,9 +85,8 @@ def switch_ffn(
     choice is queued before any token's second choice — so a congested
     expert sheds low-priority assignments first.
 
-    ``capacity`` overrides the default per-call ``expert_capacity`` (the
-    KV-cached decode path derives a generous one from ``context_length`` so
-    a few-token call can't drop tokens the full forward would have kept).
+    This is the training layer.  Serving (`models/decode.py`) takes
+    :func:`dropless_moe`, which has no capacity to run out of.
 
     ``x``: (..., d_model); routing flattens all leading dims into one token
     axis (static shape under jit).
@@ -86,11 +97,7 @@ def switch_ffn(
     tokens = x.reshape(n, d)
     e = config.n_experts
     top_k = config.router_top_k
-    cap = (
-        capacity
-        if capacity is not None
-        else expert_capacity(n, e, config.capacity_factor)
-    )
+    cap = expert_capacity(n, e, config.capacity_factor)
 
     # Router in float32 for stable softmax/argmax.
     logits = jnp.einsum(
@@ -183,3 +190,99 @@ def switch_ffn(
     aux = e * jnp.sum(frac_tokens * frac_probs)
 
     return out.reshape(orig_shape), aux
+
+
+# ------------------------------------------------------------ dropless path
+
+
+def route(tokens: Array, router: Array, config: ModelConfig) -> tuple[Array, Array]:
+    """Scores over all ``n_experts`` in float32, the ``router_top_k``
+    largest and their gates: ``(expert ids (n, k), gates (n, k))``.  Softmax
+    top-1 keeps the raw probability (Switch); every other case renormalizes
+    the chosen scores to sum to one."""
+    logits = jnp.einsum(
+        "nd,ed->ne", tokens.astype(jnp.float32), router.astype(jnp.float32)
+    )
+    if config.moe_router == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    top_s, top_i = jax.lax.top_k(scores, config.router_top_k)
+    if config.moe_router == "softmax" and config.router_top_k == 1:
+        return top_i, top_s
+    return top_i, top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+
+
+def dropless_moe(
+    x: Array, moe_params: dict, config: ModelConfig, valid: Array | None = None
+) -> tuple[Array, Array]:
+    """The served expert layer: nothing is dropped and there is no
+    capacity.  Returns ``(output, counts)``.
+
+    Every token is routed over all ``n_experts``; the assignments that land
+    on the experts held here (``expert_offset .. + local_experts``) are
+    sorted by expert and go through one grouped matmul per SwiGLU matrix
+    (`kernels/pallas/grouped_matmul.py`), which visits only experts that got
+    a row; each token's output is the gate-weighted sum of its held
+    experts' results.  What the absent experts would add is left out: with
+    ``experts_held=None`` that is nothing, with a share it is the other
+    processes' part of an expert-parallel layer.  Shared experts, where the
+    config has them, see every token and are averaged and added.
+
+    ``valid`` (tokens,) bool leaves rows out of the expert computation
+    altogether (padded chunk rows, idle slots); their output is the shared
+    part alone.  Shapes are static: the sorted buffer has a row for every
+    assignment there could be, ``tokens * router_top_k``.
+
+    ``counts`` is int32 ``[tokens routed, assignments held here, non-empty
+    expert groups computed]`` - the ``moe_*`` counters of ``stats()``.
+    """
+    from bpe_transformer_tpu.kernels.pallas.grouped_matmul import grouped_matmul
+
+    orig_shape = x.shape
+    d = orig_shape[-1]
+    n = math.prod(orig_shape[:-1])
+    tokens = x.reshape(n, d)
+    top_k, held = config.router_top_k, config.local_experts
+    kn = n * top_k
+
+    with jax.named_scope("block/moe/router"):
+        top_i, gates = route(tokens, moe_params["router"], config)
+        local = top_i - config.expert_offset
+        is_local = (local >= 0) & (local < held)
+        if valid is not None:
+            is_local &= valid.reshape(n)[:, None]
+        # Row r of the flat assignment list is (token r // k, rank r % k);
+        # assignments that are not ours sort behind every held expert.
+        key = jnp.where(is_local, local, held).reshape(kn)
+        order = jnp.argsort(key, stable=True)
+        group_sizes = jnp.zeros((held,), jnp.int32).at[key].add(1, mode="drop")
+        rows_local = jnp.sum(group_sizes)
+        routed = n if valid is None else jnp.sum(valid)
+        counts = jnp.stack([
+            jnp.asarray(routed, jnp.int32), rows_local,
+            jnp.sum(group_sizes > 0).astype(jnp.int32),
+        ])
+
+    with jax.named_scope("block/moe/experts"):
+        sorted_in = jnp.take(tokens, order // top_k, axis=0)  # (kn, d)
+        up = grouped_matmul(sorted_in, moe_params["w1"], group_sizes)
+        lin = grouped_matmul(sorted_in, moe_params["w3"], group_sizes)
+        sorted_out = grouped_matmul(silu(up) * lin, moe_params["w2"], group_sizes)
+        # Back to assignment order.  Rows past rows_local were not computed
+        # (their memory is whatever it was): selected out, never scaled.
+        sorted_row = jnp.zeros((kn,), jnp.int32).at[order].set(
+            jnp.arange(kn, dtype=jnp.int32)
+        )
+        picked = jnp.take(sorted_out, sorted_row, axis=0).astype(jnp.float32)
+        picked = jnp.where(is_local.reshape(kn, 1), picked * gates.reshape(kn, 1), 0.0)
+        out = jnp.sum(picked.reshape(n, top_k, d), axis=1).astype(tokens.dtype)
+
+    if config.n_shared_experts:
+        with jax.named_scope("block/moe/shared"):
+            shared = moe_params["shared"]
+            up = jnp.einsum("nd,jfd->njf", tokens, shared["w1"])
+            lin = jnp.einsum("nd,jfd->njf", tokens, shared["w3"])
+            both = jnp.einsum("njf,jdf->nd", silu(up) * lin, shared["w2"])
+            out = out + (both / config.n_shared_experts).astype(tokens.dtype)
+    return out.reshape(orig_shape), counts

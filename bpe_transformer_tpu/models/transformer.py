@@ -27,9 +27,11 @@ from bpe_transformer_tpu.models.config import ModelConfig
 from bpe_transformer_tpu.ops.core import (
     embedding,
     head_logits,
+    layernorm,
     linear,
     multihead_self_attention,
     rmsnorm,
+    scaled_dot_product_attention,
     silu,
     swiglu,
 )
@@ -53,7 +55,9 @@ def init_params(
         ).astype(dtype)
 
     d, ff, v = config.d_model, config.d_ff, config.vocab_size
-    # GQA: K/V project to num_kv_heads * d_head rows (== d for plain MHA).
+    # GQA: K/V project to num_kv_heads * d_head rows (== d for plain MHA);
+    # Q to num_heads * d_head (== d unless the config sets head_dim).
+    d_q = config.num_heads * config.d_head
     d_kv = (config.num_kv_heads or config.num_heads) * config.d_head
     keys = jax.random.split(rng, 2 + config.num_layers)
     layers = []
@@ -72,16 +76,17 @@ def init_params(
         layers.append(
             {
                 "attn": {
-                    "q_proj": dense(k[0], d, d),
+                    "q_proj": dense(k[0], d_q, d),
                     "k_proj": dense(k[1], d_kv, d),
                     "v_proj": dense(k[2], d_kv, d),
-                    "output_proj": dense(k[3], d, d),
+                    "output_proj": dense(k[3], d, d_q),
                 },
                 "ln1": jnp.ones((d,), dtype),
-                "ln2": jnp.ones((d,), dtype),
                 "ffn": ffn_params,
             }
         )
+        if not config.parallel_block:  # one norm a block otherwise
+            layers[-1]["ln2"] = jnp.ones((d,), dtype)
     params = {
         "token_embeddings": dense(keys[0], v, d),
         "layers": layers,
@@ -103,15 +108,9 @@ def lm_head_weight(params: Params, config: ModelConfig) -> Array:
 
 
 def _ffn(
-    x: Array,
-    ffn_params: dict,
-    config: ModelConfig,
-    moe_capacity: int | None = None,
+    x: Array, ffn_params: dict, config: ModelConfig
 ) -> tuple[Array, Array]:
-    """FFN dispatch; returns ``(output, aux_loss)`` (aux is 0 except MoE).
-
-    ``moe_capacity`` is threaded to :func:`switch_ffn` (decode-path
-    override); ignored by the dense FFN kinds."""
+    """FFN dispatch; returns ``(output, aux_loss)`` (aux is 0 except MoE)."""
     zero = jnp.zeros((), jnp.float32)
     if config.ffn_type in (None, "swiglu"):
         # int8-quantized serving weights (dict leaves, ops/quant.py) take
@@ -137,16 +136,67 @@ def _ffn(
 
         return linear(gelu(linear(x, ffn_params["w1"])), ffn_params["w2"]), zero
     if config.ffn_type == "moe":
-        from bpe_transformer_tpu.models.moe import switch_ffn
+        from bpe_transformer_tpu.models.moe import dropless_moe, switch_ffn
 
-        return switch_ffn(x, ffn_params, config, capacity=moe_capacity)
+        if config.dropless_block:
+            # Shared or held experts, sigmoid routing: the served layer is
+            # the only one there is (no capacity, no aux loss, no training).
+            return dropless_moe(x, ffn_params, config)[0], zero
+        return switch_ffn(x, ffn_params, config)
     raise ValueError(f"unknown ffn_type: {config.ffn_type!r}")
 
 
 def _maybe_norm(x: Array, weight: Array, config: ModelConfig) -> Array:
     if config.remove_rmsnorm:
         return x
+    if config.norm_type == "layernorm":
+        return layernorm(x, weight)
     return rmsnorm(x, weight)
+
+
+def _patterned_block(
+    x: Array,
+    block_params: dict,
+    config: ModelConfig,
+    layer: int,
+    rope_cos_sin: tuple[Array, Array] | None,
+    positions: Array,
+) -> Array:
+    """One block of a config with per-layer attention kinds: window layers
+    mask keys outside ``0 <= i - j < sliding_window`` (materialized scores:
+    this is the plain forward the serving paths are checked against), full
+    layers are causal and rotate q and k only under
+    ``rope_on_full_layers``; the block is parallel (one norm, both branches
+    from it: `ModelConfig` refuses such a config without ``parallel_block``)."""
+    from bpe_transformer_tpu.ops.core import window_causal_mask
+
+    window = config.layer_window(layer)
+    attention_fn = None
+    if window is not None:
+        mask = window_causal_mask(x.shape[-2], window)
+        attention_fn = lambda q, k, v: scaled_dot_product_attention(q, k, v, mask)
+
+    def attend(h):
+        scope = "attn_window" if window is not None else "attn_full"
+        with jax.named_scope("block/attn"), jax.named_scope(scope):
+            return multihead_self_attention(
+                h,
+                block_params["attn"]["q_proj"],
+                block_params["attn"]["k_proj"],
+                block_params["attn"]["v_proj"],
+                block_params["attn"]["output_proj"],
+                config.num_heads,
+                num_kv_heads=config.num_kv_heads,
+                positions=positions,
+                rope_cos_sin=rope_cos_sin if config.layer_rope(layer) else None,
+                causal=True,
+                attention_fn=attention_fn,
+            )
+
+    h = _maybe_norm(x, block_params["ln1"], config)
+    with jax.named_scope("block/ffn"):
+        f, _ = _ffn(h, block_params["ffn"], config)
+    return x + attend(h) + f
 
 
 def _attention(
@@ -480,7 +530,17 @@ def forward_hidden(
     )
 
     aux_total = jnp.zeros((), jnp.float32)
-    if config.scan_layers:
+    if config.dropless_block:
+        if attention_fn is not None:
+            raise ValueError(
+                "an attention_fn override replaces every layer's attention; "
+                "this config's layers differ in kind"
+            )
+        for layer, block_params in enumerate(compute_params["layers"]):
+            x = _patterned_block(
+                x, block_params, config, layer, rope_cos_sin, positions
+            )
+    elif config.scan_layers:
         x, aux_total = _scan_blocks(
             x, aux_total, compute_params["layers"], config, rope_cos_sin,
             positions, attention_fn,

@@ -77,6 +77,23 @@ def rmsnorm(x: Array, weight: Array, eps: float = 1e-5) -> Array:
     return (x32 * scale).astype(x.dtype) * weight
 
 
+def layernorm(x: Array, weight: Array, eps: float = 1e-5) -> Array:
+    """Mean-subtracting layer norm with affine scale and no bias;
+    accumulates in float32."""
+    x32 = x.astype(jnp.float32)
+    centered = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    scale = jax.lax.rsqrt(jnp.mean(centered * centered, axis=-1, keepdims=True) + eps)
+    return (centered * scale).astype(x.dtype) * weight
+
+
+def window_causal_mask(seq_len: int, window: int) -> Array:
+    """``(seq, seq)`` keep-mask: key j visible to query i iff
+    ``0 <= i - j < window``."""
+    i = jnp.arange(seq_len)[:, None]
+    j = jnp.arange(seq_len)[None, :]
+    return (j <= i) & (i - j < window)
+
+
 def silu(x: Array) -> Array:
     """``x * sigmoid(x)``."""
     return x * jax.nn.sigmoid(x)

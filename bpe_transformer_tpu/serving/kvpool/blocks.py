@@ -123,3 +123,50 @@ class BlockAllocator:
             "kv_blocks_free": self.free_count,
             "kv_blocks_shared": self.shared_count,
         }
+
+
+class WindowChain:
+    """One slot's chain of blocks in a sliding-window pool group.
+
+    A window layer reads the keys ``p - window < j <= p`` of a query at
+    position ``p``, so a block that lies wholly below ``p - window + 1`` is
+    dead.  The chain holds the blocks from ``first`` (a logical block index)
+    on, at most ``cap`` of them; :meth:`advance` returns dead blocks to the
+    allocator's free list and takes as many again for the positions ahead.
+    With ``cap = (window + chunk) // block_size`` and block-aligned chunks
+    that is always enough for the next chunk or the next token, and because
+    a chain frees before it takes, it never asks the allocator for more than
+    it was admitted with: a reservation, like the full group's whole chain.
+    A request that fits under ``cap`` never recycles and is today's chain.
+    """
+
+    def __init__(self, allocator: BlockAllocator, cap: int, need: int):
+        #: Blocks the request will ever touch (its full group's chain length).
+        self.need = need
+        self.cap = cap
+        self.allocator = allocator
+        self.first = 0
+        self.ids = allocator.alloc(min(need, cap))
+        self.recycled = 0
+
+    def advance(self, lo_pos: int) -> int:
+        """Positions below ``lo_pos`` will not be read again: free the
+        blocks wholly below it, extend the chain by as many (never past the
+        request's end).  Returns how many blocks were recycled."""
+        dead = min(max(lo_pos, 0) // self.allocator.block_size - self.first, len(self.ids))
+        if dead <= 0:
+            return 0
+        self.allocator.deref(self.ids[:dead])
+        self.first += dead
+        ahead = min(self.cap, self.need - self.first) - (len(self.ids) - dead)
+        self.ids = self.ids[dead:] + self.allocator.alloc(max(ahead, 0))
+        self.recycled += dead
+        return dead
+
+    def covers(self, pos: int) -> bool:
+        block = pos // self.allocator.block_size
+        return self.first <= block < self.first + len(self.ids)
+
+    def release(self) -> None:
+        self.allocator.deref(self.ids)
+        self.ids = []

@@ -47,6 +47,9 @@ import numpy as np
 
 from bpe_transformer_tpu.models.config import ModelConfig
 from bpe_transformer_tpu.models.decode import (
+    grouped_chunk_prefill,
+    grouped_decode_step,
+    init_grouped_kv_pool,
     init_kv_pool,
     paged_chunk_prefill,
     paged_decode_step,
@@ -64,6 +67,7 @@ from bpe_transformer_tpu.serving.engine import (
 from bpe_transformer_tpu.serving.kvpool.blocks import (
     BlockAllocator,
     NoFreeBlocksError,
+    WindowChain,
 )
 from bpe_transformer_tpu.serving.kvpool.radix import RadixPrefixCache
 from bpe_transformer_tpu.telemetry.spans import Phase
@@ -127,6 +131,53 @@ def _paged_tick_program(
     keys_next = jnp.where(active[:, None], keys_next, keys)
     positions = jnp.where(active, positions + 1, positions)
     return nxt, positions, keys_next, pool
+
+
+def _grouped_chunk_program(
+    params, lm_head, pool, moe_pending, table_rows, chunk, start, chunk_len,
+    key, temp, top_k, top_p, *, config: ModelConfig, block_size: int,
+):
+    """:func:`_chunk_program` over the grouped pools (`models/decode.py`).
+    The pool is donated and comes back updated.  ``moe_pending`` holds the
+    routing counts of the chunks since the last tick; the chunk's own are
+    added and the next tick hands them to the host, never this program."""
+    logits, pool, counts = grouped_chunk_prefill(
+        params, chunk, start, chunk_len, table_rows, pool, config,
+        lm_head=lm_head, block_size=block_size,
+    )
+    with jax.named_scope("key_split"):
+        key, sub = jax.random.split(key)
+    tok = sample_tokens(
+        logits, sub[None], temp[None], top_k[None], top_p[None]
+    )[0]
+    return tok, key, pool, moe_pending + counts
+
+
+def _grouped_tick_program(
+    params, lm_head, pool, moe_pending, tables, tokens, positions, active,
+    keys, temps, top_ks, top_ps, *, config: ModelConfig, block_size: int,
+):
+    """:func:`_paged_tick_program` over the grouped pools.  Returns the
+    routing counts since the last tick (the chunks' and its own) and its
+    own beside the usual outputs: they come back with the tokens, in the
+    same read, and the host keeps the running totals (int64; a device
+    int32 would wrap within a day of this traffic).  The last output is the
+    next ``moe_pending``, zeros: a program's output like a chunk's, so that
+    neither program ever sees a second kind of argument there (a host array
+    would be one, and a compile in the middle of serving)."""
+    with jax.named_scope("key_split"):
+        split = jax.vmap(jax.random.split)(keys)
+        keys_next, subs = split[:, 0], split[:, 1]
+    logits, pool, counts = grouped_decode_step(
+        params, tokens, positions, pool, tables, config, lm_head=lm_head,
+        active=active, block_size=block_size,
+    )
+    nxt = sample_tokens(logits, subs, temps, top_ks, top_ps)
+    nxt = jnp.where(active, nxt, tokens)
+    keys_next = jnp.where(active[:, None], keys_next, keys)
+    positions = jnp.where(active, positions + 1, positions)
+    since = moe_pending + counts
+    return nxt, positions, keys_next, pool, since, counts, jnp.zeros_like(since)
 
 
 def _copy_block_program(pool, src, dst):
@@ -232,6 +283,37 @@ class PagedEngine:
                 f"block_size={block_size} must divide "
                 f"context_length={ctx}"
             )
+        #: Two pool groups (`models/decode.py`, "grouped pools"): a config
+        #: with sliding-window layers keeps a window group beside the full
+        #: one.  Every other config is the one-group case: the full group
+        #: alone, in the layout and programs it always had.
+        self.grouped = config.has_window_layers
+        if config.dropless_block and weight_dtype is not None:
+            raise ValueError(
+                "weight_dtype quantizes the dense block's weight tree "
+                "(ops/quant.py); this config's parallel block, held and "
+                "shared experts are served at the activation width only"
+            )
+        if self.grouped:
+            unsupported = {
+                "prefix_cache=True (the radix cache shares whole chains; a "
+                "window group recycles its blocks)": prefix_cache,
+                'kv_dtype="int8"': kv_dtype is not None,
+                "fused_sampling": fused_sampling,
+            }
+            for what, asked in unsupported.items():
+                if asked:
+                    raise ValueError(
+                        f"{what} is not supported over window pool groups "
+                        "(ROADMAP: what cannot run yet); pass it off"
+                    )
+            window = config.sliding_window
+            chunk = min(prefill_chunk or ctx, ctx)
+            if window % block_size or chunk % block_size:
+                raise ValueError(
+                    f"sliding_window={window} and prefill_chunk={chunk} must "
+                    f"be multiples of block_size={block_size}"
+                )
         self.config = config
         self.n_slots = slots
         self.block_size = block_size
@@ -270,6 +352,20 @@ class PagedEngine:
         if num_blocks is None:
             num_blocks = slots * self.blocks_per_slot + 1
         self.allocator = BlockAllocator(num_blocks, block_size)
+        #: The window group's allocator and, per slot, its chain.  The group
+        #: is a reservation, not a knob: a chain holds at most window + one
+        #: chunk of positions, and every slot can hold that much.
+        self.window_allocator = None
+        self.window_cap = 0
+        if self.grouped:
+            self.window_cap = min(
+                (config.sliding_window + self.prefill_chunk) // block_size,
+                self.blocks_per_slot,
+            )
+            num_window_blocks = slots * self.window_cap + 1
+            self.window_allocator = BlockAllocator(num_window_blocks, block_size)
+        self._chains: list[WindowChain | None] = [None] * slots
+        self._window_recycled = 0
         self.prefix_cache = (
             RadixPrefixCache(self.allocator) if prefix_cache else None
         )
@@ -283,9 +379,14 @@ class PagedEngine:
             self.params_bytes, self.tick_weight_bytes,
         ) = prepare_serving_weights(params, config, weight_dtype)
         self.fused_sampling = bool(fused_sampling)
-        self._pool = init_kv_pool(
-            config, num_blocks, block_size, act_dtype, kv_dtype=kv_dtype
-        )
+        if self.grouped:
+            self._pool = init_grouped_kv_pool(
+                config, num_blocks, num_window_blocks, block_size, act_dtype
+            )
+        else:
+            self._pool = init_kv_pool(
+                config, num_blocks, block_size, act_dtype, kv_dtype=kv_dtype
+            )
         #: "int8" for quantized pools, else the activation dtype name —
         #: the /statusz + stats() label.
         self.kv_dtype = kv_dtype or str(act_dtype)
@@ -296,8 +397,7 @@ class PagedEngine:
         #: or, held fixed, buys 2-4x the blocks.
         self.kv_pool_bytes = sum(
             int(arr.size) * arr.dtype.itemsize
-            for layer in self._pool
-            for arr in layer.values()
+            for arr in jax.tree_util.tree_leaves(self._pool)
         )
         #: KV footprint per token POSITION at pool width across all layers
         #: (k + v) — the unit of the attention READ stream, which scales
@@ -311,6 +411,28 @@ class PagedEngine:
         )
 
         self._tables = np.zeros((slots, self.blocks_per_slot), np.int32)
+        # Window group: a slot's row starts at its first live block, whose
+        # first position is the slot's base.
+        self._window_tables = np.zeros((slots, max(self.window_cap, 1)), np.int32)
+        self._window_base = np.zeros(slots, np.int32)
+        #: What the attention of the grouped programs needs, summed over
+        #: layers, counted here from the positions (no device read):
+        #: visible (query, key) pairs and distinct KV positions to stream,
+        #: a window layer's capped by its window.
+        self.attn_pairs = 0
+        self.attn_kv_positions = 0
+        self._window_layers = sum(
+            config.layer_window(layer) is not None
+            for layer in range(config.num_layers)
+        )
+        #: Routing counts of the dropless expert layers, summed over layers:
+        #: [tokens routed, assignments on held experts, non-empty expert
+        #: groups].  The totals are kept here; the device carries only the
+        #: chunks' counts since the last tick, which the tick hands over
+        #: with its own.
+        self.moe_counts = np.zeros(3, np.int64)
+        self.last_tick_moe_rows_local = 0
+        self._moe_pending = jnp.zeros((3,), jnp.int32)
         self._tokens = np.zeros(slots, np.int32)
         self._positions = np.zeros(slots, np.int32)
         self._active = np.zeros(slots, bool)
@@ -323,17 +445,35 @@ class PagedEngine:
 
         # Per-engine jit closures: compiled_programs() is an exact
         # per-engine compile counter, as in the dense engine.
-        self._chunk_jit = jax.jit(
-            functools.partial(
-                _chunk_program, config=config, block_size=block_size
+        if self.grouped:
+            # The pool (argument 2) is donated: both programs update it in
+            # place.
+            self._chunk_jit = jax.jit(
+                functools.partial(
+                    _grouped_chunk_program, config=config,
+                    block_size=block_size,
+                ),
+                donate_argnums=(2,),
             )
-        )
-        self._tick_jit = jax.jit(
-            functools.partial(
-                _paged_tick_program, config=config, block_size=block_size,
-                fused=self.fused_sampling,
+            self._tick_jit = jax.jit(
+                functools.partial(
+                    _grouped_tick_program, config=config,
+                    block_size=block_size,
+                ),
+                donate_argnums=(2,),
             )
-        )
+        else:
+            self._chunk_jit = jax.jit(
+                functools.partial(
+                    _chunk_program, config=config, block_size=block_size
+                )
+            )
+            self._tick_jit = jax.jit(
+                functools.partial(
+                    _paged_tick_program, config=config, block_size=block_size,
+                    fused=self.fused_sampling,
+                )
+            )
         # Copy-on-write block copy (rewind into a shared block): compiled
         # only the first time a CoW rewind actually runs.  Per-engine
         # partial for the same reason as the migration jits below — a
@@ -433,6 +573,18 @@ class PagedEngine:
                     "prefix_cache_nodes": 0,
                 }
             )
+        # The groups by name.  One group: the full group is the pool.
+        out["kv_full_blocks_total"] = self.allocator.usable_blocks
+        out["kv_full_blocks_free"] = self.allocator.free_count
+        window = self.window_allocator
+        out["kv_window_blocks_total"] = window.usable_blocks if window else 0
+        out["kv_window_blocks_free"] = window.free_count if window else 0
+        out["kv_window_blocks_recycled"] = self._window_recycled
+        out["attn_pairs"] = self.attn_pairs
+        out["attn_kv_positions"] = self.attn_kv_positions
+        out["moe_tokens_routed"] = int(self.moe_counts[0])
+        out["moe_rows_local"] = int(self.moe_counts[1])
+        out["moe_expert_groups"] = int(self.moe_counts[2])
         out["prefill_pending_tokens"] = self.pending_prefill_tokens()
         out["prefill_pending_slots"] = len(self._prefilling)
         out["kv_pool_bytes"] = self.kv_pool_bytes
@@ -467,6 +619,51 @@ class PagedEngine:
         return states
 
     # ------------------------------------------------------------ lifecycle
+
+    def _refuse_grouped(self, what: str) -> None:
+        if self.grouped:
+            raise NotImplementedError(
+                f"{what} is not supported over window pool groups: a "
+                "recycled window block cannot be rolled back, copied or "
+                "shipped as part of a whole chain (ROADMAP: what cannot "
+                "run yet)"
+            )
+
+    def _advance_window(self, slot: int, lo_pos: int) -> None:
+        """Recycle ``slot``'s window blocks that lie wholly below
+        ``lo_pos`` (positions no later query of the slot reads)."""
+        recycled = self._chains[slot].advance(lo_pos)
+        if recycled:
+            self._window_recycled += recycled
+            self._write_window_row(slot)
+
+    def _count_attention(self, start: int, end: int) -> None:
+        """Add what the attention of queries ``start .. end - 1`` of one
+        slot needs, over the layers of both kinds (plain integers)."""
+        window = self.config.sliding_window
+        full_layers = self.config.num_layers - self._window_layers
+        full_pairs = (end * (end + 1) - start * (start + 1)) // 2
+        # Queries from position window - 1 on see exactly window keys.
+        capped = max(end - max(start, window - 1), 0)
+        uncapped_end = end - capped
+        window_pairs = (
+            uncapped_end * (uncapped_end + 1) - start * (start + 1)
+        ) // 2 + capped * window
+        self.attn_pairs += (
+            full_layers * full_pairs + self._window_layers * window_pairs
+        )
+        self.attn_kv_positions += full_layers * end + self._window_layers * (
+            end - max(start - window + 1, 0)
+        )
+
+    def _write_window_row(self, slot: int) -> None:
+        """The slot's window row starts at its chain's first live block,
+        whose first position is the slot's base."""
+        chain = self._chains[slot]
+        row = self._window_tables[slot]
+        row[:] = 0
+        row[: len(chain.ids)] = chain.ids
+        self._window_base[slot] = chain.first * self.block_size
 
     def _validate(self, prompt: np.ndarray, max_new_tokens: int) -> None:
         plen = prompt.shape[0]
@@ -508,6 +705,7 @@ class PagedEngine:
         :meth:`rewind` returns whatever the acceptance didn't keep).
         Raises :class:`NoFreeBlocksError` when the pool is dry — the
         caller shrinks its speculation window instead of parking."""
+        self._refuse_grouped("extend_blocks (speculative scratch)")
         info = self._slots[slot]
         if info is None:
             raise ValueError(f"slot {slot} is not occupied")
@@ -555,6 +753,7 @@ class PagedEngine:
         Returns ``{"released": n_blocks, "cow": bool}``.  The caller owns
         position/sampling state — this is a KV-memory primitive.
         """
+        self._refuse_grouped("rewind")
         info = self._slots[slot]
         if info is None:
             raise ValueError(f"slot {slot} is not occupied")
@@ -626,6 +825,7 @@ class PagedEngine:
         a speculative importer re-prefills its draft from) is merged into
         the payload meta.
         """
+        self._refuse_grouped("KV migration (export_slot)")
         info = self._slots[slot]
         if info is None:
             raise ValueError(f"slot {slot} is not occupied")
@@ -692,6 +892,7 @@ class PagedEngine:
         """Reject a payload this engine cannot graft — geometry or pool
         dtype mismatch is a configuration error, caught before any block
         is allocated (HTTP 400, not a half-grafted slot)."""
+        self._refuse_grouped("KV migration (import_slot)")
         if meta.get("format") != 1:
             raise ValueError(
                 f"unsupported payload format {meta.get('format')!r}"
@@ -875,6 +1076,13 @@ class PagedEngine:
                 f"request needs {need} KV blocks; the pool holds "
                 f"{self.allocator.usable_blocks}"
             )
+        if self.grouped and min(need, self.window_cap) > (
+            self.window_allocator.usable_blocks
+        ):
+            raise ValueError(
+                f"request needs {min(need, self.window_cap)} window KV "
+                f"blocks; the pool holds {self.window_allocator.usable_blocks}"
+            )
         matched: list[int] = []
         if self.prefix_cache is not None:
             matched = self.prefix_cache.match([int(t) for t in prompt])
@@ -884,6 +1092,15 @@ class PagedEngine:
             if matched:
                 self.allocator.deref(matched)
             raise
+        if self.grouped:
+            try:
+                self._chains[slot] = WindowChain(
+                    self.window_allocator, self.window_cap, need
+                )
+            except NoFreeBlocksError:
+                self.allocator.deref(fresh)
+                raise
+            self._write_window_row(slot)
         block_ids = matched + fresh
         self._tables[slot, : len(block_ids)] = block_ids
         self._tables[slot, len(block_ids):] = 0
@@ -914,6 +1131,13 @@ class PagedEngine:
         self._prefilling.append(slot)
         return slot
 
+    def _slot_rows(self, slot: int) -> dict:
+        return {
+            "full": self._tables[slot],
+            "window": self._window_tables[slot],
+            "window_base": self._window_base[slot],
+        }
+
     def prefill_step(self, slot: int) -> TickEvent | None:
         """Run ONE prefill chunk for ``slot``.  Returns ``None`` while
         chunks remain; on the final chunk, samples the request's first
@@ -935,12 +1159,25 @@ class PagedEngine:
         # the final chunk; earlier chunks get a throwaway key and their
         # sampled token/key outputs are discarded.
         key_in = jax.random.PRNGKey(info.seed)
-        tok, key, self._pool = self._chunk_jit(
-            self._params, self._lm_head, self._pool,
-            self._tables[slot], padded, np.int32(info.next_pos),
-            np.int32(chunk_len), key_in, info.temp_enc, info.top_k_enc,
-            info.top_p_enc,
-        )
+        if self.grouped:
+            # The chunk's first query reads back to next_pos - window + 1.
+            self._advance_window(
+                slot, info.next_pos - self.config.sliding_window + 1
+            )
+            self._count_attention(info.next_pos, info.next_pos + chunk_len)
+            tok, key, self._pool, self._moe_pending = self._chunk_jit(
+                self._params, self._lm_head, self._pool, self._moe_pending,
+                self._slot_rows(slot), padded, np.int32(info.next_pos),
+                np.int32(chunk_len), key_in, info.temp_enc, info.top_k_enc,
+                info.top_p_enc,
+            )
+        else:
+            tok, key, self._pool = self._chunk_jit(
+                self._params, self._lm_head, self._pool,
+                self._tables[slot], padded, np.int32(info.next_pos),
+                np.int32(chunk_len), key_in, info.temp_enc, info.top_k_enc,
+                info.top_p_enc,
+            )
         info.next_pos += chunk_len
         if not final:
             return None
@@ -1005,16 +1242,47 @@ class PagedEngine:
         if not self._active.any():
             return []
         with Phase("serve/tick_dispatch", self.clock) as dispatch:
-            tokens, positions, keys, self._pool = self._tick_jit(
-                self._params, self._lm_head, self._pool, self._tables,
-                self._tokens, self._positions, self._active, self._keys,
-                self._temps, self._top_ks, self._top_ps,
-            )
+            if self.grouped:
+                window = self.config.sliding_window
+                for slot in np.flatnonzero(self._active):
+                    self._advance_window(
+                        int(slot), int(self._positions[slot]) - window + 1
+                    )
+                # One query a live slot: pairs and KV positions are alike.
+                seen = self._positions[self._active].astype(np.int64) + 1
+                keys_read = int(
+                    (self.config.num_layers - self._window_layers) * seen.sum()
+                    + self._window_layers * np.minimum(seen, window).sum()
+                )
+                self.attn_pairs += keys_read
+                self.attn_kv_positions += keys_read
+                tables = {
+                    "full": self._tables, "window": self._window_tables,
+                    "window_base": self._window_base,
+                }
+                (
+                    tokens, positions, keys, self._pool, moe_since, moe_tick,
+                    self._moe_pending,
+                ) = self._tick_jit(
+                    self._params, self._lm_head, self._pool, self._moe_pending,
+                    tables, self._tokens, self._positions, self._active,
+                    self._keys, self._temps, self._top_ks, self._top_ps,
+                )
+            else:
+                tokens, positions, keys, self._pool = self._tick_jit(
+                    self._params, self._lm_head, self._pool, self._tables,
+                    self._tokens, self._positions, self._active, self._keys,
+                    self._temps, self._top_ks, self._top_ps,
+                )
         with Phase("serve/tick_wait", self.clock) as wait:
             tokens = np.asarray(tokens)
             self._tokens = tokens.copy()
             self._positions = np.asarray(positions).copy()
             self._keys = np.asarray(keys).copy()
+            if self.grouped:
+                # The same read as the tokens: no sync of its own.
+                self.moe_counts += np.asarray(moe_since)
+                self.last_tick_moe_rows_local = int(np.asarray(moe_tick)[1])
         self.ticks += 1
 
         events: list[TickEvent] = []
@@ -1045,3 +1313,8 @@ class PagedEngine:
         if info is not None and info.block_ids:
             self.allocator.deref(info.block_ids)
         self._tables[slot, :] = 0
+        if self._chains[slot] is not None:
+            self._chains[slot].release()
+            self._chains[slot] = None
+            self._window_tables[slot, :] = 0
+            self._window_base[slot] = 0

@@ -260,6 +260,17 @@ class ServingEngine:
                 "through the paged scatter; the KV rewind lives in the "
                 "block pool)"
             )
+        if config.has_window_layers and not paged:
+            raise ValueError(
+                "a config with sliding-window layers is served by the paged "
+                "engine (paged=True): its window pool group lives there"
+            )
+        if config.has_window_layers and (speculate_k or role != "both"):
+            raise ValueError(
+                "speculative decoding (its KV rewind) and the prefill/decode "
+                "roles (KV migration) are not supported over window pool "
+                "groups (ROADMAP: what cannot run yet)"
+            )
         if speculate_k:
             from bpe_transformer_tpu.serving.spec.engine import SpecEngine
 
@@ -1369,6 +1380,12 @@ class ServingEngine:
                 # the first token of each prefill that completed in it.
                 "batch": self.engine.tokens_emitted - period["tokens_before"],
                 "queue_depth": self.scheduler.depth,
+                # Assignments of the tick's tokens that landed on experts
+                # held here (dropless expert layers of the grouped engine;
+                # 0 elsewhere).
+                "moe_rows_local": getattr(
+                    self.engine, "last_tick_moe_rows_local", 0
+                ),
             }
         )
 

@@ -244,6 +244,7 @@ class SpecEngine(PagedEngine):
                 f"speculate_k must be >= 1, got {speculate_k}"
             )
         super().__init__(params, config, min_bucket=min_bucket, **paged_kwargs)
+        self._refuse_grouped("speculative decoding (its verify pass rewinds)")
         if isinstance(draft, DraftSpec):
             # Build the draft from the engine's COMPUTE-DTYPE params: a
             # truncated view then shares the very arrays the target runs

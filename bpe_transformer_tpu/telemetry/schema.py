@@ -57,7 +57,9 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # (waiting for work) and ``other_s`` = ``dur_s`` minus the rest, kept
     # explicit.  ``batch`` is the tokens the engine emitted in the period
     # (the tick's, plus the first token of each prefill that completed in
-    # it); ``queue_depth`` the scheduler's at the period's end.
+    # it); ``queue_depth`` the scheduler's at the period's end.  Optional
+    # ``moe_rows_local``: the tick's expert assignments that landed on
+    # experts held here (grouped paged engine; 0 elsewhere).
     "tick": {
         "kind", "t", "dur_s", "admit_s", "prefill_s", "chunks",
         "prefill_tokens", "dispatch_s", "wait_s", "emit_s", "deliver_s",

@@ -66,6 +66,14 @@ def make_loss_fn(
     returns ``(loss, (aux, act_stats))`` with the per-layer activation
     statistics from ``forward_hidden_stats`` — same forward, same math,
     plus cheap in-graph reductions."""
+    if config.dropless_block:
+        raise ValueError(
+            "training is not supported for this block (a layer pattern, the "
+            "parallel block, LayerNorm, sigmoid routing, shared or held "
+            "experts): it has no load-balance loss and no backward-tested "
+            "path; it is served and checked against its plain forward only "
+            "(ROADMAP: what cannot run yet)"
+        )
     is_moe = config.ffn_type == "moe"
 
     if with_stats:

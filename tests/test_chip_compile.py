@@ -27,7 +27,11 @@ from bpe_transformer_tpu.kernels.pallas.decode_attention import (
     paged_decode_attention,
 )
 from bpe_transformer_tpu.kernels.pallas.flash_attention import flash_attention
+from bpe_transformer_tpu.kernels.pallas.grouped_matmul import grouped_matmul
 from bpe_transformer_tpu.kernels.pallas.quant_matmul import quant_matmul
+from bpe_transformer_tpu.kernels.pallas.ragged_attention import (
+    ragged_paged_attention,
+)
 from bpe_transformer_tpu.kernels.pallas.runtime import (
     attention_path,
     flash_tiles,
@@ -329,4 +333,44 @@ def test_fused_verify_head(one_chip, vocab, d, head_dtype):
         fn, one_chip, ((rows, d), BF16), ((rows,), F32), ((rows,), I32),
         ((rows,), F32), ((rows,), I32), ((rows, vocab), F32),
         ((rows, vocab), F32), *head,
+    )
+
+
+# ----------------------------- command-a-plus-05-2026 (Cohere2-MoE) serving
+
+
+@pytest.mark.parametrize(
+    "rows", [256, 4096, 16384], ids=["tick_32_slots", "chunk_512", "chunk_2048"]
+)
+def test_grouped_matmul(one_chip, monkeypatch, rows):
+    """The expert layer's grouped matmul at the published widths: 8
+    assignments a token, 16 experts held, 4,096 x 4,096 matrices."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _compile(
+        grouped_matmul, one_chip,
+        ((rows, 4096), BF16), ((16, 4096, 4096), BF16), ((16,), I32),
+    )
+
+
+@pytest.mark.parametrize(
+    "tokens,seqs,pages,window",
+    [(32, 32, 1024, None), (32, 32, 384, 4096), (2048, 1, 1024, None),
+     (2048, 1, 384, 4096), (512, 1, 384, 4096)],
+    ids=["tick_full", "tick_window", "chunk_full", "chunk_window", "chunk512_window"],
+)
+def test_ragged_paged_attention(one_chip, monkeypatch, tokens, seqs, pages, window):
+    """The grouped pools' attention: 128 query heads on 8 KV heads of 128,
+    pages of 16 positions, a full group's row of 1,024 pages and a window
+    group's of (4,096 + 2,048) / 16."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def fn(q, kv_pages, kv_lens, rows, cu_q_lens, num_seqs):
+        return ragged_paged_attention(
+            q, kv_pages, kv_lens, rows, cu_q_lens, num_seqs, window=window,
+            one_query_per_seq=seqs > 1,
+        )
+
+    _compile(
+        fn, one_chip, ((tokens, 128, 128), BF16), ((2049, 16, 16, 128), BF16),
+        ((seqs,), I32), ((seqs, pages), I32), ((seqs + 1,), I32), ((1,), I32),
     )

@@ -244,10 +244,10 @@ def test_top_p_sampling_masks_tail(setup):
 
 @pytest.mark.slow  # 870s tier-1 budget (PR 11 sweep; ISSUE 11 tooling guard) — runs in the full matrix
 def test_moe_decode_default_capacity_no_drops():
-    """At the DEFAULT capacity_factor the cached path must not drop tokens
-    its full forward keeps: decode derives capacity from context_length
-    (decode._ffn_decode), so the per-step few-token calls are drop-free and
-    the whole cached chain reproduces a drop-free full forward exactly."""
+    """At the DEFAULT capacity_factor the cached path drops nothing: serving
+    takes the dropless expert layer (decode._ffn_decode ->
+    moe.dropless_moe; no capacity at all), so the whole cached chain
+    reproduces a drop-free full forward exactly."""
     cfg = dataclasses.replace(
         CFG, ffn_type="moe", n_experts=4, capacity_factor=1.25
     )
@@ -262,9 +262,9 @@ def test_moe_decode_default_capacity_no_drops():
 
 @pytest.mark.slow
 def test_moe_decode_step_dropfree_with_degenerate_capacity():
-    """Even when the full-length expert capacity is below the batch size
-    (many experts, tiny context), single-token decode steps must stay
-    drop-free: the derived capacity floors at the batch."""
+    """Even when the training forward's expert capacity is below the batch
+    size (many experts, tiny context), single-token decode steps stay
+    drop-free: the served layer is dropless whatever capacity_factor says."""
     cfg = dataclasses.replace(
         CFG,
         context_length=16,
