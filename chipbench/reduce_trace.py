@@ -21,7 +21,7 @@ there, on the same clock.
 
 from __future__ import annotations
 
-import bisect
+import heapq
 import re
 from collections import defaultdict
 from pathlib import Path
@@ -140,12 +140,21 @@ def matching_seconds(events, pattern: str, window=None) -> float | None:
     return sum(totals) / len(totals)
 
 
-def idle_gaps(events, window, top: int = 10, skip: str = "chipbench/") -> list:
+def idle_gaps(events, window, top: int = 10, skip: str = "chipbench/", prefer=()) -> list:
     """The idle time of the first device plane inside ``window``, labelled
     by what the host was doing: each gap between busy intervals gets the
     name of the shortest host event that covers its midpoint (the innermost
     one; the harness's own ``chipbench/`` annotations do not count), and
-    gaps are summed by label.  ``[[label, seconds]]``, largest first."""
+    gaps are summed by label.  Where ``prefer`` names prefixes
+    (``("serve/", "train/")``: the program's own phases), the shortest
+    covering event with such a name wins, and the runtime's names
+    (``np.asarray(jax.Array)``) label a gap only where no phase covers it.
+    ``[[label, seconds]]``, largest first.
+
+    One sweep: gaps and host events are walked together in time order, the
+    host events that have started wait in a heap by duration, and one that
+    has ended is dropped when it reaches the top - it cannot cover a later
+    midpoint either.  O((G + H) log H) for G gaps and H host events."""
     per_plane = device_events(events, window)
     if not per_plane:
         return []
@@ -158,33 +167,41 @@ def idle_gaps(events, window, top: int = 10, skip: str = "chipbench/") -> list:
         for plane, _, name, start, dur in events
         if plane == HOST_PLANE and dur > 0 and not name.startswith(skip)
     )
-    starts = [h[0] for h in host]
+    prefer = tuple(prefer)
+    # Entries are (duration, -index, end, name): of two events equally long
+    # the later in the sorted order wins, as it always has.
+    phases, others = [], []
     sums = defaultdict(float)
-    for g0, g1 in gaps:
+    nxt = 0
+    for g0, g1 in gaps:  # disjoint and in time order, so are their midpoints
         mid = 0.5 * (g0 + g1)
-        best = None
-        # Host events that start before the midpoint; the scan is bounded
-        # because a trace holds a window of seconds, not hours.
-        for i in range(bisect.bisect_right(starts, mid) - 1, -1, -1):
-            s, e, name = host[i]
-            if e >= mid and (best is None or e - s < best[0]):
-                best = (e - s, name)
-            if mid - s > 5.0:
+        while nxt < len(host) and host[nxt][0] <= mid:
+            s, e, name = host[nxt]
+            heap = phases if prefer and name.startswith(prefer) else others
+            heapq.heappush(heap, (e - s, -nxt, e, name))
+            nxt += 1
+        label = "no host event"
+        for heap in (phases, others):
+            while heap and heap[0][2] < mid:
+                heapq.heappop(heap)
+            if heap:
+                label = heap[0][3]
                 break
-        sums[best[1] if best else "no host event"] += g1 - g0
+        sums[label] += g1 - g0
     return sorted(([k, v] for k, v in sums.items()), key=lambda kv: -kv[1])[:top]
 
 
-def reduce(events, window) -> dict:
+def reduce(events, window, prefer=()) -> dict:
     """The numbers a traced run reports: ``busy_s`` (mean over chips),
-    ``window_s``, the ten ops with most time and the idle gaps by label."""
+    ``window_s``, the ten ops with most time and the idle gaps by label
+    (``prefer`` as in :func:`idle_gaps`)."""
     busy = busy_seconds(events, window)
     return {
         "busy_s": sum(busy.values()) / len(busy) if busy else 0.0,
         "busy_s_by_plane": busy,
         "window_s": window[1] - window[0],
         "device_ops": op_sums(events, window)[:10],
-        "idle_gaps": idle_gaps(events, window),
+        "idle_gaps": idle_gaps(events, window, prefer=prefer),
     }
 
 

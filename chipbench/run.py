@@ -35,6 +35,9 @@ if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
 TRACE_ANNOTATION = "chipbench/traced"
+#: Host events with these prefixes are the program's own phases (PERF.md,
+#: section 3): an idle gap is labelled by one of them where one covers it.
+PROGRAM_PHASES = ("serve/", "train/")
 #: Traces land here: inside the checkout, git-ignored (``.scratch/``).
 SCRATCH = ROOT / ".scratch" / "chipbench"
 
@@ -96,6 +99,8 @@ class Tracer:
     def __init__(self, directory: Path):
         self.directory = directory
         self._annotation = None
+        #: What the traced run paid for its trace, for the "trace_cost" line.
+        self.cost = {}
 
     def start(self) -> None:
         import jax
@@ -114,17 +119,24 @@ class Tracer:
         import jax
 
         self._annotation.__exit__(None, None, None)
+        t0 = time.perf_counter()
         jax.profiler.stop_trace()
+        self.cost["stop_s"] = time.perf_counter() - t0
 
     def reduce(self) -> dict:
         from chipbench import reduce_trace
 
+        t0 = time.perf_counter()
         events = reduce_trace.read_events(reduce_trace.find_xplane(self.directory))
+        t1 = time.perf_counter()
         window = reduce_trace.annotation_window(events, TRACE_ANNOTATION)
         if window is None:
             raise RuntimeError("the trace holds no chipbench/traced annotation")
-        out = reduce_trace.reduce(events, window)
+        out = reduce_trace.reduce(events, window, prefer=PROGRAM_PHASES)
         out["events"], out["window"] = events, window
+        self.cost.update(
+            read_s=t1 - t0, reduce_s=time.perf_counter() - t1, events=len(events)
+        )
         return out
 
 
@@ -232,9 +244,15 @@ def run_cell(
             "stats_samples": out.get("stats_samples", ()),
         }
         emit({"info": "context", **scalars})
+        t0 = time.perf_counter()
         result["metrics"] = layer_metrics.evaluate_all(
             HERE / "layer_metrics", name, ctx, also=workload.get("layer_metrics", ())
         )
+        # What the trace cost this run: whether a cell is nearing the run's
+        # time limit shows here first.  reduce_s holds the readers too.
+        cost = env["tracer"].cost
+        cost["reduce_s"] += time.perf_counter() - t0
+        emit({"info": "trace_cost", **cost, **out["traced"]})
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
         result["breakdown"] = {
@@ -243,6 +261,11 @@ def run_cell(
     else:
         result["metrics"] = out["metrics"]
     result["device"] = device
+    # What `correct` compared, each number beside its limit: last in the line.
+    result["compared"] = {
+        row["number"]: {"value": row["value"], "limit": row["limit"]}
+        for row in out["compared"]
+    }
     return result
 
 
@@ -281,6 +304,9 @@ def cli(argv, load=load_cell, expect_platform: str = "tpu") -> int:
         seconds=args.seconds, trace=bool(args.trace), emit=emit,
         t_start=t_start, expect_platform=expect_platform,
     )
+    for number, row in result["compared"].items():
+        print(f"compared {number}: {row['value']} (limit {row['limit']})", file=sys.stderr)
+    sys.stderr.flush()
     emit(result)
     out.close()
     return 0

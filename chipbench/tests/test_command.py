@@ -11,7 +11,7 @@ from chipbench.tests.tiny import BENCH
 
 ROOT = BENCH.parent
 ENV = {**os.environ, "JAX_PLATFORMS": "cpu"}
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 
 
 def run(script, *args):
@@ -70,3 +70,10 @@ def test_last_line_is_the_result_and_nothing_follows(cell, trace, e2e):
         assert {"busy_s", "window_s"} <= set(result["device"])
     compared = [o for o in parsed if o.get("info") == "correct"][0]["compared"]
     assert all({"number", "value", "limit", "ok"} <= set(row) for row in compared)
+    # The same numbers come last in the result's line and end standard error.
+    assert list(result)[-1] == "compared"
+    assert result["compared"] == {
+        row["number"]: {"value": row["value"], "limit": row["limit"]} for row in compared
+    }
+    tail = done.stderr.strip().splitlines()[-len(compared):]
+    assert [line.split()[1].rstrip(":") for line in tail] == [r["number"] for r in compared]

@@ -7,7 +7,7 @@ import re
 import pytest
 
 from chipbench import counts
-from chipbench.tests.tiny import BENCH
+from chipbench.tests.tiny import BENCH, metrics_of_cell
 
 ROOT = BENCH.parent
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -72,4 +72,7 @@ def test_benchmark_json_names_units_and_files():
         spec = json.loads((BENCH / "layer_metrics" / f"{metric['name']}.json").read_text())
         for key in ("layer", "unit", "better", "moves", "source"):
             assert spec[key] == metric[key], (metric["name"], key)
-        assert set(metric["workloads"]) <= set(spec["workloads"])
+        # Declared for a cell = read there: the metric's file lists the cell
+        # or the cell's own file takes the metric up.
+        assert set(spec["workloads"]) <= set(metric["workloads"])
+        assert all(metric["name"] in metrics_of_cell(c) for c in metric["workloads"])

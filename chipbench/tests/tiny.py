@@ -6,6 +6,18 @@ from pathlib import Path
 
 BENCH = Path(__file__).resolve().parents[1]
 
+
+def metrics_of_cell(cell: str) -> set:
+    """The per-layer metrics the harness evaluates in a cell, by its own
+    rule: a metric file lists the cell, or the cell's workload file takes
+    the metric up under ``layer_metrics``."""
+    from chipbench import layer_metrics
+
+    workload = json.loads((BENCH / "workloads" / f"{cell}.json").read_text())
+    return set(layer_metrics.load_metrics(
+        BENCH / "layer_metrics", cell, also=workload.get("layer_metrics", ())
+    ))
+
 TINY_CONFIG = {
     "vocab_size": 10000, "context_length": 16, "d_model": 64, "num_layers": 3,
     "num_heads": 4, "d_ff": 128, "rope_theta": 10000.0,

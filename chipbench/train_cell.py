@@ -234,7 +234,6 @@ def run(env: dict) -> dict:
     )
     _, grad_leaf = worst_leaf_gap(first_grad, ref["first_grad_leaf_norms"])
     _, change_leaf = worst_leaf_gap(change, ref["change_leaf_norms"])
-    compared = compare(losses, first_grad, change, ref, limits)
     if env["control"]:
         low = env["reference"].reference_train(
             seed, config, hp, probe.batches, quant="fp8", rows_per_block=rows
@@ -246,14 +245,16 @@ def run(env: dict) -> dict:
                 low["change_leaf_norms"], ref, limits,
             )
         ]})
+    compared = [
+        {"number": n, "value": v, "limit": l, "ok": v <= l}
+        for n, v, l in compare(losses, first_grad, change, ref, limits)
+    ]
     correct = (
-        all(value <= limit for _, value, limit in compared)
+        all(row["ok"] for row in compared)
         and all(finite) and window_losses[-1] < window_losses[0] and rows_differ
     )
     emit({
-        "info": "correct", "compared": [
-            {"number": n, "value": v, "limit": l, "ok": v <= l} for n, v, l in compared
-        ],
+        "info": "correct", "compared": compared,
         "program_losses": losses, "reference_losses": ref["losses"],
         "first_grad_worst_leaf": ref["leaf_names"][grad_leaf],
         "param_change_worst_leaf": ref["leaf_names"][change_leaf],
@@ -265,11 +266,12 @@ def run(env: dict) -> dict:
 
     out = {
         "correct": correct, "attempted": steps, "failed": finite[1:].count(False),
-        "device": device,
+        "device": device, "compared": compared,
     }
     setup_s = (time.time() - env["t_start"]) - (time.perf_counter() - probe.t_open)
     if env["trace"]:
         out["trace"] = env["tracer"].reduce()
+        out["traced"] = {"launches": steps, "traced_s": wall_s, "ended_by": "seconds"}
         out["scalars"] = {
             "wall_s": wall_s, "steps": steps,
             "flops_required": flops_per_token * tokens / chips,
