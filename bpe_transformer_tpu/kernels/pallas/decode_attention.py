@@ -224,16 +224,18 @@ def decode_attention(
 def _paged_decode_kernel(
     tables_ref, pos_ref, q_ref, k_ref, v_ref, *refs,
     scale: float, block_size: int, num_blocks_per_slot: int, kv_heads: int,
-    quantized: bool,
+    d_head: int, quantized: bool,
 ):
     """Block-table flash decode: grid axis 1 walks a slot's KV BLOCKS (the
     block table was already consumed by the BlockSpec index maps, so
-    ``k_ref``/``v_ref`` hold one pool block each) with the same online
-    softmax as :func:`_decode_kernel`."""
+    ``k_ref``/``v_ref`` hold one pool block each: ``block_size`` rows, every
+    kv head's ``d_head`` lanes side by side) with the same online softmax as
+    :func:`_decode_kernel`, one kv head after the other."""
     if quantized:
         kscale_ref, vscale_ref, o_ref, acc_ref, m_ref, l_ref = refs
     else:
         o_ref, acc_ref, m_ref, l_ref = refs
+    slot = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -242,53 +244,61 @@ def _paged_decode_kernel(
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    slot = pl.program_id(0) // kv_heads
     pos = pos_ref[slot]
-    # This program's kv head (hoisted: program_id is a top-level-only
-    # primitive under the interpreter) — used to select the dequant scale.
-    head = pl.program_id(0) % kv_heads
 
     @pl.when(j * block_size <= pos)
     def _block():
-        q = q_ref[0].astype(jnp.float32) * scale  # (G_pad, d)
-        k = k_ref[0, 0].astype(jnp.float32)       # (block_size, d)
-        v = v_ref[0, 0].astype(jnp.float32)
+        k_rows = k_ref[0].astype(jnp.float32)  # (block_size, kv * d)
+        v_rows = v_ref[0].astype(jnp.float32)
         if quantized:
             # Per-block-per-head dequant IN REGISTERS.  The scale tile is
             # the 8-row group of the (num_blocks, kv_heads) f32 pool that
             # holds this block (a 1-row tile is not a legal TPU block);
-            # the block's row and this program's head are selected by
-            # mask (dynamic sublane/lane indexing is not a TPU vector
+            # the block's row and a head's column are selected by mask
+            # (dynamic sublane/lane indexing is not a TPU vector
             # primitive).  Rows of a ragged last group are never selected.
             blk = tables_ref[slot, jnp.minimum(j, pos // block_size)]
             shape = (SUBLANES, kv_heads)
-            pick = (
+            in_row = (
                 jax.lax.broadcasted_iota(jnp.int32, shape, 0)
                 == blk % SUBLANES
-            ) & (jax.lax.broadcasted_iota(jnp.int32, shape, 1) == head)
-            k = k * jnp.sum(jnp.where(pick, kscale_ref[...], 0.0))
-            v = v * jnp.sum(jnp.where(pick, vscale_ref[...], 0.0))
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (G_pad, block_size)
-        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * block_size
-        s = jnp.where(cols <= pos, s, NEG_INF)
+            )
+            head_col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        for head in range(kv_heads):
+            lanes = slice(head * d_head, (head + 1) * d_head)
+            q = q_ref[0, head].astype(jnp.float32) * scale  # (G_pad, d)
+            k = k_rows[:, lanes]                            # (block_size, d)
+            v = v_rows[:, lanes]
+            if quantized:
+                pick = in_row & (head_col == head)
+                k = k * jnp.sum(jnp.where(pick, kscale_ref[...], 0.0))
+                v = v * jnp.sum(jnp.where(pick, vscale_ref[...], 0.0))
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # (G_pad, block_size)
+            cols = (
+                jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                + j * block_size
+            )
+            s = jnp.where(cols <= pos, s, NEG_INF)
 
-        m_prev = m_ref[:, 0:1]
-        l_prev = l_ref[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+            m_prev = m_ref[head, :, 0:1]
+            l_prev = l_ref[head, :, 0:1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[head] = acc_ref[head] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_ref[head] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[head] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
     @pl.when(j == num_blocks_per_slot - 1)
     def _finalize():
-        denom = jnp.maximum(l_ref[:, 0:1], 1e-30)
+        denom = jnp.maximum(l_ref[:, :, 0:1], 1e-30)
         o_ref[0] = (acc_ref[:] / denom).astype(o_ref.dtype)
 
 
@@ -308,26 +318,29 @@ def paged_decode_attention(
     out of the KV block pool — no contiguous per-slot gather ever exists.
 
     The serving block pool (`models/decode.init_kv_pool`) stores KV as
-    ``(num_blocks, kv_heads, block_size, d_head)``; each slot's cache is a
-    chain of block ids in ``tables`` ``(slots, blocks_per_slot)``.  Where
-    `gather_paged_kv` materializes a ``(slots, blocks_per_slot*block_size)``
+    ``(num_blocks, block_size, kv_heads * d_head)`` — block-major rows, a
+    row's heads side by side along the lanes; each slot's cache is a chain
+    of block ids in ``tables`` ``(slots, blocks_per_slot)``.  Where
+    `gather_paged_rows` materializes a ``(slots, blocks_per_slot*block_size)``
     transient per layer per tick before any kernel runs, here the grid is
-    ``(slots*kv_heads, blocks_per_slot)`` and the BLOCK TABLE IS CONSUMED
-    INSIDE THE K/V BlockSpec INDEX MAPS: ``tables``/``pos`` ride scalar
-    prefetch (SMEM), so grid step ``(b, j)`` DMAs pool block
-    ``tables[slot, min(j, pos[slot] // block_size)]`` directly into VMEM.
-    HBM traffic per tick drops to one streaming read of the LIVE blocks —
-    the gather's extra write+read round trip of the whole transient is
-    gone, and (as in :func:`decode_attention`) blocks beyond the causal
-    frontier clamp to the frontier block so their DMAs are elided.
+    ``(slots, blocks_per_slot)`` and the BLOCK TABLE IS CONSUMED INSIDE THE
+    K/V BlockSpec INDEX MAPS: ``tables``/``pos`` ride scalar prefetch
+    (SMEM), so grid step ``(s, j)`` DMAs pool block ``tables[s, min(j,
+    pos[s] // block_size)]`` — every kv head's rows, one contiguous
+    ``block_size * kv_heads * d_head`` stretch — directly into VMEM, and the
+    kernel walks the heads.  HBM traffic per tick drops to one streaming
+    read of the LIVE blocks — the gather's extra write+read round trip of
+    the whole transient is gone, and (as in :func:`decode_attention`)
+    blocks beyond the causal frontier clamp to the frontier block so their
+    DMAs are elided.
 
     ``k_scale``/``v_scale`` ``(num_blocks, kv_heads)`` f32 must be given
     exactly when the pool is int8-quantized (per-block-per-head scales, the
     serving pool's ``kv_dtype="int8"`` layout); the kernel dequantizes each
     block in registers, so the HBM side of the stream stays 1 byte/value.
-    The v5e compiler accepts int8 pools at every block size from 4 up
-    (AOT, ``tests/test_chip_compile.py``); what it refuses is a 1-row
-    scale tile, so the scales ride as 8-row groups (see the kernel).
+    What the v5e compiler refuses is a 1-row scale tile, so the scales ride
+    as 8-row groups (see the kernel); the pool shapes it accepts are
+    compiled in ``tests/test_chip_compile.py``.
 
     ``pos`` is the per-slot causal frontier ``(slots,)`` (scalar broadcast
     accepted).  Returns ``(slots, num_heads, d_head)`` like
@@ -338,12 +351,14 @@ def paged_decode_attention(
 
         interpret = interpret_mode()
     slots, num_heads, d = q.shape
-    num_blocks, kv_heads, block_size, d2 = k_pool.shape
-    if d2 != d or v_pool.shape != k_pool.shape:
+    if k_pool.ndim != 3 or v_pool.shape != k_pool.shape or k_pool.shape[2] % d:
         raise ValueError(
             f"shape mismatch: q {q.shape}, k_pool {k_pool.shape}, "
-            f"v_pool {v_pool.shape}"
+            f"v_pool {v_pool.shape} (pools are (num_blocks, block_size, "
+            "kv_heads * d_head))"
         )
+    num_blocks, block_size, width = k_pool.shape
+    kv_heads = width // d
     if tables.ndim != 2 or tables.shape[0] != slots:
         raise ValueError(
             f"tables {tables.shape} must be (slots={slots}, blocks_per_slot)"
@@ -367,10 +382,9 @@ def paged_decode_attention(
     group = num_heads // kv_heads
     g_pad = pl.cdiv(group, SUBLANES) * SUBLANES
     nbs = tables.shape[1]
-    skv = slots * kv_heads
 
-    qg = q.reshape(slots, kv_heads, group, d).reshape(skv, group, d)
-    qg = jnp.pad(qg, ((0, 0), (0, g_pad - group), (0, 0)))
+    qg = q.reshape(slots, kv_heads, group, d)
+    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_pad - group), (0, 0)))
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (slots,))
     tables = jnp.asarray(tables, jnp.int32)
 
@@ -380,6 +394,7 @@ def paged_decode_attention(
         block_size=block_size,
         num_blocks_per_slot=nbs,
         kv_heads=kv_heads,
+        d_head=d,
         quantized=quantized,
     )
     # Index maps receive the scalar-prefetch refs as trailing args: the
@@ -388,53 +403,95 @@ def paged_decode_attention(
     # block (same id -> the pipeline elides the refetch) and are
     # compute-predicated off in the kernel, exactly like the dense kernel.
     qspec = pl.BlockSpec(
-        (1, g_pad, d), lambda b, j, t, p: (b, 0, 0), memory_space=pltpu.VMEM
+        (1, kv_heads, g_pad, d), lambda s, j, t, p: (s, 0, 0, 0),
+        memory_space=pltpu.VMEM,
     )
 
-    def kv_index(b, j, t, p):
-        s = b // kv_heads
-        return (t[s, jnp.minimum(j, p[s] // block_size)], b % kv_heads, 0, 0)
+    def block_id(s, j, t, p):
+        return t[s, jnp.minimum(j, p[s] // block_size)]
 
     kvspec = pl.BlockSpec(
-        (1, 1, block_size, d), kv_index, memory_space=pltpu.VMEM
+        (1, block_size, width),
+        lambda s, j, t, p: (block_id(s, j, t, p), 0, 0),
+        memory_space=pltpu.VMEM,
     )
     in_specs = [qspec, kvspec, kvspec]
     inputs = [qg, k_pool, v_pool]
     if quantized:
-
         # A (1, kv_heads) row is not a legal TPU block (sublane dim must
         # be a multiple of 8 or the whole axis): DMA the 8-row group that
         # holds the block's row; the kernel selects the row by mask.
-        def scale_index(b, j, t, p):
-            s = b // kv_heads
-            return (t[s, jnp.minimum(j, p[s] // block_size)] // SUBLANES, 0)
-
         sspec = pl.BlockSpec(
-            (SUBLANES, kv_heads), scale_index, memory_space=pltpu.VMEM
+            (SUBLANES, kv_heads),
+            lambda s, j, t, p: (block_id(s, j, t, p) // SUBLANES, 0),
+            memory_space=pltpu.VMEM,
         )
         in_specs += [sspec, sspec]
         inputs += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(skv, nbs),
+        grid=(slots, nbs),
         in_specs=in_specs,
         out_specs=qspec,
         scratch_shapes=[
-            pltpu.VMEM((g_pad, d), jnp.float32),      # output accumulator
-            pltpu.VMEM((g_pad, LANES), jnp.float32),  # running row max
-            pltpu.VMEM((g_pad, LANES), jnp.float32),  # running denominator
+            pltpu.VMEM((kv_heads, g_pad, d), jnp.float32),      # accumulator
+            pltpu.VMEM((kv_heads, g_pad, LANES), jnp.float32),  # running max
+            pltpu.VMEM((kv_heads, g_pad, LANES), jnp.float32),  # denominator
         ],
     )
-    out_dtype = q.dtype
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((skv, g_pad, d), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((slots, kv_heads, g_pad, d), q.dtype),
         interpret=interpret,
         name="paged_decode_attention",
     )(tables, pos_arr, *inputs)
-    return out[:, :group, :].reshape(slots, num_heads, d)
+    return out[:, :, :group, :].reshape(slots, num_heads, d)
+
+
+@jax.named_scope("decode_attn")
+def xla_rows_attention(q, k_rows, v_rows, visible):
+    """Materialized-scores attention over KV kept AS THE POOL HOLDS IT:
+    ``k_rows``/``v_rows`` ``(batch, keys, kv_heads * d_head)``, a key's
+    heads side by side along the lanes (`models/decode.gather_paged_rows`).
+    ``q`` is ``(batch, heads, queries, d_head)``, ``visible`` ``(batch,
+    queries, keys)``; returns ``(batch, heads, queries, d_head)``.
+
+    The same mathematics as :func:`xla_decode_attention` (f32 scores and
+    softmax, probabilities back at the query's width), arranged so that
+    the rows are never split into heads: on the TPU a ``(..., 768)`` array
+    and its ``(..., 12, 64)`` view are different tilings, and at serving
+    batch the gathered rows are as large as the pool, so splitting them
+    costs a pool-sized copy per K and V per layer.  Instead the QUERIES
+    are laid out block-diagonally - column ``(head, query)`` of ``q_cols``
+    holds that query in the lanes of its kv head and zeros elsewhere - so
+    ``k_rows @ q_cols`` is every head's scores in one batched matmul over
+    the rows' own layout (the zeros add nothing), and ``probs @ v_rows``
+    gives every head's output in every kv head's lanes, of which each head
+    keeps its own.  The wasted products (``kv_heads`` times the scores'
+    and the outputs') are activation-sized; the rows are read once each.
+    """
+    batch, heads, queries, d = q.shape
+    keys, width = k_rows.shape[1:]
+    kv_heads = width // d
+    # owner[h, k]: query head h reads kv head k (GQA groups are contiguous).
+    owner = (
+        jnp.arange(heads)[:, None] // (heads // kv_heads)
+        == jnp.arange(kv_heads)[None, :]
+    ).astype(q.dtype)
+    q_cols = jnp.einsum("bhqd,hk->bkdhq", q, owner).reshape(
+        batch, width, heads * queries
+    )
+    scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    scores = jnp.einsum("bcj,bjn->bnc", k_rows, q_cols) * scale
+    scores = scores.reshape(batch, heads, queries, keys)
+    scores = jnp.where(visible[:, None], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
+    out = jnp.einsum(
+        "bnc,bcj->bnj", probs.reshape(batch, heads * queries, keys), v_rows
+    ).reshape(batch, heads, queries, kv_heads, d)
+    return jnp.einsum("bhqkd,hk->bhqd", out, owner)
 
 
 @jax.named_scope("decode_attn")

@@ -360,24 +360,37 @@ def init_kv_pool(
     dtype=jnp.float32,
     kv_dtype: str | None = None,
 ) -> KVCache:
-    """A paged KV pool: per layer ``(num_blocks, kv_heads, block_size,
-    d_head)`` K and V block arrays.  Block 0 is the serving layer's trash
-    block (masked writes are steered to it); a request's cache is a chain
-    of block ids, not a row index.
+    """A paged KV pool: per layer K and V block arrays ``(num_blocks,
+    block_size, kv_heads * d_head)`` - block-major rows, one row a token
+    position, its heads side by side along the lanes (head ``h`` is lanes
+    ``h * d_head .. (h + 1) * d_head``).  Block 0 is the serving layer's
+    trash block (masked writes are steered to it); a request's cache is a
+    chain of block ids, not a row index.
 
-    ``kv_dtype="int8"`` stores quantized K/V at one byte per value with
-    per-block-per-head f32 scales in parallel ``k_scale``/``v_scale``
-    pools ``(num_blocks, kv_heads)`` — HBM traffic per decoded token drops
-    ~2x vs bf16 (4x vs f32) and the freed bytes buy more blocks at fixed
-    memory.  A block's scale covers its whole ``(block_size, d_head)``
-    tile; writers keep it valid by rescale-on-grow (see
-    :func:`_quantize_decode_row`).  ``kv_dtype=None`` stores at ``dtype``
-    (the activation width) with no scale pools.
+    The shape is the layout: it is the one the v5e compiler takes in and
+    hands back as it rests on the device, ``{2,1,0}`` in whole ``(8, 128)``
+    tiles with no padding, so a program that scatters rows into the pool
+    (``.at[block_ids, offsets]``) and gathers blocks out of it
+    (``buf[tables]``) with the pool donated updates it in place and holds no
+    copy of a pool-sized array (`tests/test_chip_compile.py` reads the
+    compiled text).  Any four-dimensional shape with ``d_head`` = 64 as its
+    minor dimension rests with the block axis folded into the tile
+    (``{0,3,2,1}``), which no scatter or gather indexes: every program then
+    re-lays the whole pool out on the way in and again on the way out.
+
+    ``kv_dtype="int8"`` stores quantized K/V at one byte per value, in the
+    same shape, with per-block-per-head f32 scales in parallel
+    ``k_scale``/``v_scale`` pools ``(num_blocks, kv_heads)`` — HBM traffic
+    per decoded token drops ~2x vs bf16 (4x vs f32) and the freed bytes buy
+    more blocks at fixed memory.  A block's scale covers its whole
+    ``(block_size, d_head)`` tile of that head; writers keep it valid by
+    rescale-on-grow (see :func:`_quantize_decode_row`).  ``kv_dtype=None``
+    stores at ``dtype`` (the activation width) with no scale pools.
     """
     if kv_dtype not in (None, "int8"):
         raise ValueError(f'kv_dtype={kv_dtype!r} must be None or "int8"')
     kv_heads = config.num_kv_heads or config.num_heads
-    shape = (num_blocks, kv_heads, block_size, config.d_head)
+    shape = (num_blocks, block_size, kv_heads * config.d_head)
     store = jnp.int8 if kv_dtype == "int8" else dtype
     layers: KVCache = []
     for _ in range(config.num_layers):
@@ -390,38 +403,58 @@ def init_kv_pool(
 
 
 @jax.named_scope("pool_gather")
-def gather_paged_kv(buf: Array, tables: Array) -> Array:
-    """Materialize contiguous per-slot KV from the pool through the block
-    table: ``buf`` (num_blocks, kv_heads, block_size, d_head) gathered by
-    ``tables`` (slots, blocks_per_slot) -> (slots, kv_heads,
-    blocks_per_slot * block_size, d_head).
-
-    This one gather is the whole paged-attention read path: its output is
-    layout-identical to the dense cache, so BOTH decode attention
-    implementations (`xla_decode_attention` and the Pallas flash-decoding
-    kernel) serve the paged pool unchanged.  The buffer is transient
-    (activation-sized, one layer at a time) — only the block pool is
-    resident, which is where paging's memory win lives.
-    """
-    gathered = buf[tables]  # (S, nb, kv, bs, dh)
-    s, nb, kv, bs, dh = gathered.shape
-    return jnp.transpose(gathered, (0, 2, 1, 3, 4)).reshape(s, kv, nb * bs, dh)
-
-
-def gather_paged_kv_dequant(
-    buf: Array, scale: Array, tables: Array, dtype
+def gather_paged_rows(
+    buf: Array, tables: Array, scale: Array | None = None, dtype=None
 ) -> Array:
-    """:func:`gather_paged_kv` for an int8 pool: gather the quantized
-    blocks AND their per-block-per-head scales through the table, dequant
-    to ``dtype``.  This is the XLA reference read path (and chunked
-    prefill's) — the paged-native kernel dequantizes in registers without
-    ever materializing this buffer."""
-    bs = buf.shape[2]
-    gathered = gather_paged_kv(buf, tables)          # (S, kv, nb*bs, dh)
+    """Materialize contiguous per-slot KV from the pool through the block
+    table, AS THE POOL HOLDS IT: ``buf`` (num_blocks, block_size, kv_heads *
+    d_head) gathered by ``tables`` (slots, blocks_per_slot) -> (slots,
+    blocks_per_slot * block_size, kv_heads * d_head), a key position a row.
+
+    This one gather is the XLA read path of the paged pool: the decode
+    tick and the verify pass attend over the rows as they are
+    (`xla_rows_attention`), so nothing as large as the gathered chains is
+    ever re-laid out.  The buffer is transient (one layer at a time) —
+    only the block pool is resident, which is where paging's memory win
+    lives.
+
+    An int8 pool passes its per-block-per-head ``scale`` pool
+    ``(num_blocks, kv_heads)`` and the ``dtype`` to dequantize to: the
+    scales are gathered through the same table and spread over their
+    head's lanes (the paged-native kernel dequantizes in registers without
+    ever materializing this buffer).
+    """
+    gathered = buf[tables]  # (S, nb, bs, kv * dh)
+    s, nb, bs, width = gathered.shape
+    if scale is not None:
+        lanes = jnp.repeat(scale[tables], width // scale.shape[1], axis=-1)
+        gathered = (
+            gathered.astype(jnp.float32) * lanes[:, :, None, :]
+        ).astype(dtype)
+    return gathered.reshape(s, nb * bs, width)
+
+
+def gather_paged_kv(
+    buf: Array, tables: Array, kv_heads: int, scale: Array | None = None,
+    dtype=None,
+) -> Array:
+    """:func:`gather_paged_rows` with the heads split out: (slots, kv_heads,
+    blocks_per_slot * block_size, d_head), layout-identical to the dense
+    cache, for the readers that want that (chunked prefill's one slot, the
+    contiguous Pallas flash-decoding kernel).  The split is a transpose of
+    the gathered transient, never of the pool."""
+    rows = gather_paged_rows(buf, tables, scale, dtype)
+    s, keys, width = rows.shape
     with jax.named_scope("pool_gather"):
-        scales = jnp.transpose(scale[tables], (0, 2, 1))  # (S, kv, nb)
-        scales = jnp.repeat(scales, bs, axis=2)[..., None]
-        return (gathered.astype(jnp.float32) * scales).astype(dtype)
+        return jnp.transpose(
+            rows.reshape(s, keys, kv_heads, width // kv_heads), (0, 2, 1, 3)
+        )
+
+
+def _pool_rows(rows: Array, dtype) -> Array:
+    """``(..., kv_heads, d_head)`` K or V rows as the pool holds them:
+    ``(..., kv_heads * d_head)`` at the pool's width."""
+    return rows.reshape(*rows.shape[:-2], -1).astype(dtype)
 
 
 @jax.named_scope("pool_write")
@@ -431,9 +464,9 @@ def _quantize_decode_row(
     """Scatter one new KV row per slot into an int8 block pool, keeping the
     per-block-per-head scale sound under incremental writes.
 
-    ``new_row`` (slots, kv_heads, d_head) lands at ``(write_ids[s], :,
-    offsets[s], :)``.  The block scale grows monotonically within one
-    occupancy: ``offset == 0`` starts a FRESH block (blocks are recycled
+    ``new_row`` (slots, kv_heads, d_head) lands at ``(write_ids[s],
+    offsets[s])``, one pool row.  The block scale grows monotonically within
+    one occupancy: ``offset == 0`` starts a FRESH block (blocks are recycled
     without zeroing, so the previous owner's scale must not leak) and
     resets the base scale to 0; otherwise the new row's absmax is folded
     in and — when the scale grew — the block's already-written int8 rows
@@ -442,7 +475,9 @@ def _quantize_decode_row(
     per-token scales).  One block per slot is touched — activation-sized
     work, no pool-wide traffic.
     """
-    blk = pool_arr[write_ids].astype(jnp.float32)       # (S, kv, bs, d)
+    slots, kv_heads, d_head = new_row.shape
+    blk = pool_arr[write_ids].astype(jnp.float32)       # (S, bs, kv * d)
+    blk = blk.reshape(slots, -1, kv_heads, d_head)      # (S, bs, kv, d)
     s_old = scale_arr[write_ids]                        # (S, kv)
     s_base = jnp.where(offsets[:, None] == 0, 0.0, s_old)
     amax = jnp.max(jnp.abs(new_row.astype(jnp.float32)), axis=-1)  # (S, kv)
@@ -450,17 +485,17 @@ def _quantize_decode_row(
     safe = jnp.maximum(s_new, 1e-30)
     # factor 0 on fresh blocks zeroes the recycled garbage rows too.
     factor = s_base / safe
-    blk = jnp.round(blk * factor[:, :, None, None])
+    blk = jnp.round(blk * factor[:, None, :, None])
     row_q = jnp.clip(
         jnp.round(new_row.astype(jnp.float32) / safe[:, :, None]), -127, 127
     )
     sel = (
-        jax.lax.broadcasted_iota(jnp.int32, blk.shape, 2)
+        jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
         == offsets[:, None, None, None]
     )
-    blk = jnp.where(sel, row_q[:, :, None, :], blk)
+    blk = jnp.where(sel, row_q[:, None, :, :], blk)
     return (
-        pool_arr.at[write_ids].set(blk.astype(jnp.int8)),
+        pool_arr.at[write_ids].set(_pool_rows(blk, jnp.int8)),
         scale_arr.at[write_ids].set(s_new),
     )
 
@@ -493,8 +528,10 @@ def paged_decode_step(
     honors ``config.decode_attention_impl``: ``"paged"`` runs the
     paged-NATIVE flash kernel straight against the pool (the block table is
     consumed inside the kernel's index maps — no contiguous transient);
-    ``"pallas"``/``"xla"`` keep the :func:`gather_paged_kv` reference path
-    (dequantizing on gather for int8 pools).
+    ``"xla"`` gathers the slots' rows (:func:`gather_paged_rows`,
+    dequantizing on gather for int8 pools) and attends over them as they
+    are (`xla_rows_attention`); ``"pallas"`` splits the heads out of the
+    gathered rows for the contiguous flash-decoding kernel.
     """
     x = _embed(params, token[:, None])  # (S, 1, d)
     positions = pos[:, None]
@@ -504,6 +541,12 @@ def paged_decode_step(
     if active is not None:
         write_ids = jnp.where(active, write_ids, 0)
     quantized = "k_scale" in pool[0]
+    kv_heads = config.num_kv_heads or config.num_heads
+    # (S, 1, keys): key j visible to slot s iff j <= pos[s].
+    visible = (
+        jnp.arange(tables.shape[1] * block_size)[None, None, :]
+        <= pos[:, None, None]
+    )
 
     new_pool = []
     for block_params, layer_pool in zip(params["layers"], pool):
@@ -513,7 +556,7 @@ def paged_decode_step(
             q, k = _rope_qk(q, k, positions, config)
             # Scatter the one new token's K/V into each slot's frontier
             # block (advanced-index scatter: (S,) block ids x (S,) offsets
-            # address (S, kv_heads, d_head) values).
+            # address (S, kv_heads * d_head) rows).
             k_scale = v_scale = None
             if quantized:
                 k_pool, k_scale = _quantize_decode_row(
@@ -532,11 +575,11 @@ def paged_decode_step(
                 # Explicit cast to the pool width: jax 0.9 deprecates the
                 # implicit one (an f32 row into a bf16 pool).
                 with jax.named_scope("pool_write"):
-                    k_pool = layer_pool["k"].at[write_ids, :, offsets, :].set(
-                        k[:, :, 0, :].astype(layer_pool["k"].dtype)
+                    k_pool = layer_pool["k"].at[write_ids, offsets].set(
+                        _pool_rows(k[:, :, 0, :], layer_pool["k"].dtype)
                     )
-                    v_pool = layer_pool["v"].at[write_ids, :, offsets, :].set(
-                        v[:, :, 0, :].astype(layer_pool["v"].dtype)
+                    v_pool = layer_pool["v"].at[write_ids, offsets].set(
+                        _pool_rows(v[:, :, 0, :], layer_pool["v"].dtype)
                     )
                 new_pool.append({"k": k_pool, "v": v_pool})
             if config.decode_attention_impl == "paged":
@@ -547,34 +590,31 @@ def paged_decode_step(
                 att = paged_decode_attention(
                     q[:, :, 0], k_pool, v_pool, tables, pos,
                     k_scale=k_scale, v_scale=v_scale,
+                )[:, :, None, :]
+            elif config.decode_attention_impl == "pallas":
+                # The contiguous kernel wants the dense cache's layout.
+                from bpe_transformer_tpu.kernels.pallas.decode_attention import (
+                    decode_attention,
                 )
+
+                att = decode_attention(
+                    q[:, :, 0],
+                    gather_paged_kv(k_pool, tables, kv_heads, k_scale, h.dtype),
+                    gather_paged_kv(v_pool, tables, kv_heads, v_scale, h.dtype),
+                    pos,
+                )[:, :, None, :]
             else:
-                if quantized:
-                    k_cache = gather_paged_kv_dequant(
-                        k_pool, k_scale, tables, h.dtype
-                    )
-                    v_cache = gather_paged_kv_dequant(
-                        v_pool, v_scale, tables, h.dtype
-                    )
-                else:
-                    k_cache = gather_paged_kv(k_pool, tables)
-                    v_cache = gather_paged_kv(v_pool, tables)
-                if config.decode_attention_impl == "pallas":
-                    from bpe_transformer_tpu.kernels.pallas.decode_attention import (
-                        decode_attention,
-                    )
+                from bpe_transformer_tpu.kernels.pallas.decode_attention import (
+                    xla_rows_attention,
+                )
 
-                    att = decode_attention(q[:, :, 0], k_cache, v_cache, pos)
-                else:
-                    from bpe_transformer_tpu.kernels.pallas.decode_attention import (
-                        xla_decode_attention,
-                    )
-
-                    att = xla_decode_attention(
-                        q[:, :, 0], k_cache, v_cache, pos
-                    )
-            att = merge_heads(att[:, :, None, :])
-            return linear(att, block_params["attn"]["output_proj"])
+                att = xla_rows_attention(
+                    q,
+                    gather_paged_rows(k_pool, tables, k_scale, h.dtype),
+                    gather_paged_rows(v_pool, tables, v_scale, h.dtype),
+                    visible,
+                )
+            return linear(merge_heads(att), block_params["attn"]["output_proj"])
 
         x = _block_apply(x, block_params, config, attend)
 
@@ -641,6 +681,7 @@ def paged_chunk_prefill(
     write_ids = jnp.where(in_chunk, table_row[idx_in_table], 0)
     offsets = safe_positions % block_size
     quantized = "k_scale" in pool[0]
+    kv_heads = config.num_kv_heads or config.num_heads
 
     x = _embed(params, chunk_tokens)
     scale = 1.0 / jnp.sqrt(jnp.asarray(config.d_head, jnp.float32))
@@ -664,7 +705,7 @@ def paged_chunk_prefill(
             -127, 127,
         )
         return (
-            pool_arr.at[write_ids, :, offsets, :].set(rows_q.astype(jnp.int8)),
+            pool_arr.at[write_ids, offsets].set(_pool_rows(rows_q, jnp.int8)),
             scales,
         )
 
@@ -674,6 +715,7 @@ def paged_chunk_prefill(
         def attend(h, block_params=block_params, layer_pool=layer_pool):
             q, k, v = _project_qkv(h, block_params["attn"], config)
             q, k = _rope_qk(q, k, safe_positions, config)
+            k_scale = v_scale = None
             if quantized:
                 k_pool, k_scale = _quant_chunk_rows(
                     layer_pool["k"], layer_pool["k_scale"],
@@ -687,27 +729,29 @@ def paged_chunk_prefill(
                     {"k": k_pool, "v": v_pool,
                      "k_scale": k_scale, "v_scale": v_scale}
                 )
-                k_cache = gather_paged_kv_dequant(
-                    k_pool, k_scale, table_row[None], h.dtype
-                )
-                v_cache = gather_paged_kv_dequant(
-                    v_pool, v_scale, table_row[None], h.dtype
-                )
             else:
                 with jax.named_scope("pool_write"):
-                    k_pool = layer_pool["k"].at[write_ids, :, offsets, :].set(
-                        jnp.transpose(k[0], (1, 0, 2)).astype(
-                            layer_pool["k"].dtype
+                    k_pool = layer_pool["k"].at[write_ids, offsets].set(
+                        _pool_rows(
+                            jnp.transpose(k[0], (1, 0, 2)),
+                            layer_pool["k"].dtype,
                         )
                     )
-                    v_pool = layer_pool["v"].at[write_ids, :, offsets, :].set(
-                        jnp.transpose(v[0], (1, 0, 2)).astype(
-                            layer_pool["v"].dtype
+                    v_pool = layer_pool["v"].at[write_ids, offsets].set(
+                        _pool_rows(
+                            jnp.transpose(v[0], (1, 0, 2)),
+                            layer_pool["v"].dtype,
                         )
                     )
                 new_pool.append({"k": k_pool, "v": v_pool})
-                k_cache = gather_paged_kv(k_pool, table_row[None])
-                v_cache = gather_paged_kv(v_pool, table_row[None])
+            # One slot's chain, heads split out: an activation-sized
+            # transpose (the scores below are per head).
+            k_cache = gather_paged_kv(
+                k_pool, table_row[None], kv_heads, k_scale, h.dtype
+            )
+            v_cache = gather_paged_kv(
+                v_pool, table_row[None], kv_heads, v_scale, h.dtype
+            )
             with jax.named_scope("chunk_attn"):
                 k_full = _expand_kv(k_cache, config)
                 v_full = _expand_kv(v_cache, config)
@@ -756,8 +800,8 @@ def paged_verify_step(
     one fixed-``K`` program serves every per-slot headroom).  All K+1
     tokens' K/V scatter into the pool through the block table exactly as a
     chunk prefill would (a K-length chunk IS a scoring pass), then every
-    row attends to the slot's full gathered cache under the causal frontier
-    ``key_pos <= positions + row``.  Returns logits ``(slots, K+1, vocab)``
+    row attends to the slot's full gathered rows under the causal frontier
+    ``key_pos <= positions + row`` (`xla_rows_attention`, as the tick's).  Returns logits ``(slots, K+1, vocab)``
     — row ``j`` is the target distribution for position ``positions+j+1``
     — and the updated pool.
 
@@ -791,8 +835,11 @@ def paged_verify_step(
     offsets = safe_pos % block_size
     quantized = "k_scale" in pool[0]
 
+    from bpe_transformer_tpu.kernels.pallas.decode_attention import (
+        xla_rows_attention,
+    )
+
     x = _embed(params, tokens)  # (S, K+1, d)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(config.d_head, jnp.float32))
     # (S, K+1, ctx) causal frontier: key j visible to row i iff j <= pos_i.
     mask = jnp.arange(nb * block_size)[None, None, :] <= pos_j[:, :, None]
 
@@ -825,6 +872,7 @@ def paged_verify_step(
             q, k = _rope_qk(q, k, safe_pos, config)
             k_rows = jnp.swapaxes(k, 1, 2)  # (S, K+1, kv, d)
             v_rows = jnp.swapaxes(v, 1, 2)
+            k_scale = v_scale = None
             if quantized:
                 k_pool, k_scale = _quant_verify_rows(
                     layer_pool["k"], layer_pool["k_scale"], k_rows
@@ -836,31 +884,22 @@ def paged_verify_step(
                     {"k": k_pool, "v": v_pool,
                      "k_scale": k_scale, "v_scale": v_scale}
                 )
-                k_cache = gather_paged_kv_dequant(
-                    k_pool, k_scale, tables, h.dtype
-                )
-                v_cache = gather_paged_kv_dequant(
-                    v_pool, v_scale, tables, h.dtype
-                )
             else:
-                k_pool = layer_pool["k"].at[write_ids, :, offsets, :].set(
-                    k_rows
+                k_pool = layer_pool["k"].at[write_ids, offsets].set(
+                    _pool_rows(k_rows, layer_pool["k"].dtype)
                 )
-                v_pool = layer_pool["v"].at[write_ids, :, offsets, :].set(
-                    v_rows
+                v_pool = layer_pool["v"].at[write_ids, offsets].set(
+                    _pool_rows(v_rows, layer_pool["v"].dtype)
                 )
                 new_pool.append({"k": k_pool, "v": v_pool})
-                k_cache = gather_paged_kv(k_pool, tables)
-                v_cache = gather_paged_kv(v_pool, tables)
-            k_full = _expand_kv(k_cache, config)
-            v_full = _expand_kv(v_cache, config)
-            scores = jnp.einsum("bhqd,bhkd->bhqk", q, k_full) * scale
-            scores = jnp.where(mask[:, None], scores, -jnp.inf)
-            probs = jax.nn.softmax(
-                scores.astype(jnp.float32), axis=-1
-            ).astype(h.dtype)
-            att = merge_heads(jnp.einsum("bhqk,bhkd->bhqd", probs, v_full))
-            return linear(att, block_params["attn"]["output_proj"])
+            # Every slot's chain: as large as the pool, so attended as rows.
+            att = xla_rows_attention(
+                q,
+                gather_paged_rows(k_pool, tables, k_scale, h.dtype),
+                gather_paged_rows(v_pool, tables, v_scale, h.dtype),
+                mask,
+            )
+            return linear(merge_heads(att), block_params["attn"]["output_proj"])
 
         x = _block_apply(x, block_params, config, attend)
 

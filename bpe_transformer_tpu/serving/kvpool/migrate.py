@@ -15,6 +15,16 @@ host-side dict <-> bytes codec so the HTTP transport (``/kv/export`` ->
 ``/kv/import``), the router, and the in-process drain-evacuation path all
 speak the same format.
 
+**Wire form and at-rest form.**  On the wire a layer's K and V are
+heads-major blocks, ``(n_blocks, kv_heads, block_size, d_head)``, and its
+int8 scales ``(n_blocks, kv_heads)`` — the form every payload since PR 14
+has had, which the version and the CRC cover.  On the device the pool
+rests as block-major rows, ``(num_blocks, block_size, kv_heads * d_head)``
+(`models/decode.init_kv_pool`: the layout its programs index, so that none
+copies it).  ``export_slot`` / ``import_slot`` turn one slot's blocks from
+the one into the other on the host; nothing in this module knows the
+at-rest form, and a change of it is not a change of the wire.
+
 The byte format is deliberately boring — magic + JSON header + raw
 little-endian array bytes — so it is decodable with numpy alone (no
 pickle, no jax): the router can size/forward payloads opaquely, and a
@@ -278,16 +288,11 @@ def synthetic_decode_payload(
     n_blocks = -(-span // block_size)
     store = "int8" if kv_dtype == "int8" else kv_dtype
     layers = []
+    wire_blocks = (n_blocks, kv_heads, block_size, config.d_head)
     for _ in range(config.num_layers):
         layer = {
-            "k": np.zeros(
-                (n_blocks, kv_heads, block_size, config.d_head),
-                np.dtype(store),
-            ),
-            "v": np.zeros(
-                (n_blocks, kv_heads, block_size, config.d_head),
-                np.dtype(store),
-            ),
+            "k": np.zeros(wire_blocks, np.dtype(store)),
+            "v": np.zeros(wire_blocks, np.dtype(store)),
         }
         if kv_dtype == "int8":
             layer["k_scale"] = np.zeros((n_blocks, kv_heads), np.float32)

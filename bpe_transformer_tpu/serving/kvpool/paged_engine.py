@@ -6,10 +6,20 @@ lifecycle, same ``TickEvent`` vocabulary, same one-jitted-tick and
 bounded-compile-count guarantees), with three new behaviors:
 
 * **paged KV** — the cache is a flat pool of ``block_size``-token blocks
-  (`models/decode.init_kv_pool`); each slot owns a *chain of block ids*
-  in a block table that the decode tick and chunk prefill read through
-  (gather) and write through (scatter).  Pool capacity is a knob
-  (``num_blocks``) decoupled from ``slots * context_length``;
+  (`models/decode.init_kv_pool`: per layer K and V arrays ``(num_blocks,
+  block_size, kv_heads * d_head)``, block-major rows); each slot owns a
+  *chain of block ids* in a block table that the decode tick and chunk
+  prefill read through (gather) and write through (scatter).  Pool
+  capacity is a knob (``num_blocks``) decoupled from ``slots *
+  context_length``.  **One pool is alive at a time and no program copies
+  it:** it rests on the device in the layout the programs index, and every
+  program that updates it (tick, chunk, verify, copy-block, inject-block)
+  takes it donated and hands it back (:meth:`PagedEngine._in_place`
+  rebinds ``_pool`` from the program's output before anything else can
+  read it); ``stats()`` says so from the compiled programs themselves
+  (``kv_pool_aliased_bytes``, ``tick_temp_bytes``).  A program that raises
+  after it was handed the pool has lost it: the next use of the pool
+  fails ("Array has been deleted"), loudly, as over the grouped pools;
 * **radix prefix sharing** — prompts consult the `RadixPrefixCache`
   before computing: matched full blocks are reference-counted into the
   slot's table and prefill starts at the first unmatched position, so a
@@ -71,6 +81,7 @@ from bpe_transformer_tpu.serving.kvpool.blocks import (
 )
 from bpe_transformer_tpu.serving.kvpool.radix import RadixPrefixCache
 from bpe_transformer_tpu.telemetry.spans import Phase
+from bpe_transformer_tpu.utils.compile_cache import layered_program_options
 
 __all__ = ["PagedEngine", "PagedSlotInfo", "NoFreeBlocksError"]
 
@@ -184,7 +195,8 @@ def _copy_block_program(pool, src, dst):
     """Copy one block's rows (K/V and, for int8 pools, their scale rows)
     from pool block ``src`` to ``dst`` — the device half of a
     copy-on-write rewind (`PagedEngine.rewind`).  ``src``/``dst`` are
-    traced scalars, so every copy shares one compiled program."""
+    traced scalars, so every copy shares one compiled program; the pool is
+    donated, so one block moves and nothing else."""
     return [
         {name: arr.at[dst].set(arr[src]) for name, arr in layer.items()}
         for layer in pool
@@ -204,11 +216,32 @@ def _inject_block_program(pool, rows, dst):
     the device half of :meth:`PagedEngine.import_slot` (the
     `_copy_block_program` idiom with host-supplied rows).  ``dst`` is a
     traced scalar and ``rows`` mirrors the pool's per-layer dict
-    structure, so every grafted block shares ONE compiled program."""
+    structure (one block of each array, as the pool holds it), so every
+    grafted block shares ONE compiled program; the pool is donated."""
     return [
         {name: arr.at[dst].set(row[name]) for name, arr in layer.items()}
         for layer, row in zip(pool, rows)
     ]
+
+
+def _blocks_to_wire(blocks: np.ndarray, kv_heads: int) -> np.ndarray:
+    """K or V blocks as the pool holds them, ``(n, block_size, kv_heads *
+    d_head)``, in the migration wire's heads-major form ``(n, kv_heads,
+    block_size, d_head)`` (`kvpool/migrate.py`): one slot's blocks, on the
+    host."""
+    n, block_size, width = blocks.shape
+    return np.ascontiguousarray(
+        blocks.reshape(n, block_size, kv_heads, width // kv_heads)
+        .transpose(0, 2, 1, 3)
+    )
+
+
+def _blocks_from_wire(blocks: np.ndarray) -> np.ndarray:
+    """The inverse of :func:`_blocks_to_wire`."""
+    n, kv_heads, block_size, d_head = blocks.shape
+    return np.ascontiguousarray(
+        blocks.transpose(0, 2, 1, 3).reshape(n, block_size, kv_heads * d_head)
+    )
 
 
 @dataclasses.dataclass
@@ -286,7 +319,7 @@ class PagedEngine:
         #: Two pool groups (`models/decode.py`, "grouped pools"): a config
         #: with sliding-window layers keeps a window group beside the full
         #: one.  Every other config is the one-group case: the full group
-        #: alone, in the layout and programs it always had.
+        #: alone, K and V apart (`init_kv_pool`), in its own programs.
         self.grouped = config.has_window_layers
         if config.dropless_block and weight_dtype is not None:
             raise ValueError(
@@ -463,16 +496,23 @@ class PagedEngine:
                 donate_argnums=(2,),
             )
         else:
+            # With one pool alive the chip has memory to spare, and XLA then
+            # writes every layer's code out: ask for the layers as calls
+            # (`layered_program_options`), as the train step does.
             self._chunk_jit = jax.jit(
                 functools.partial(
                     _chunk_program, config=config, block_size=block_size
-                )
+                ),
+                donate_argnums=(2,),
+                compiler_options=layered_program_options(),
             )
             self._tick_jit = jax.jit(
                 functools.partial(
                     _paged_tick_program, config=config, block_size=block_size,
                     fused=self.fused_sampling,
-                )
+                ),
+                donate_argnums=(2,),
+                compiler_options=layered_program_options(),
             )
         # Copy-on-write block copy (rewind into a shared block): compiled
         # only the first time a CoW rewind actually runs.  Per-engine
@@ -480,7 +520,9 @@ class PagedEngine:
         # bare ``jax.jit(fn)`` shares one cache across engines (keyed by
         # function identity), which would make compiled_programs() read
         # ANOTHER engine's CoW compile as this engine's.
-        self._copy_jit = jax.jit(functools.partial(_copy_block_program))
+        self._copy_jit = jax.jit(
+            functools.partial(_copy_block_program), donate_argnums=(0,)
+        )
         # KV migration halves (ISSUE 15): per-block extract (export) and
         # inject (import) — each compiled only when a migration runs, and
         # ONCE regardless of chain length (traced block ids).  Wrapped in
@@ -490,7 +532,17 @@ class PagedEngine:
         self._extract_jit = jax.jit(
             functools.partial(_extract_block_program)
         )
-        self._inject_jit = jax.jit(functools.partial(_inject_block_program))
+        self._inject_jit = jax.jit(
+            functools.partial(_inject_block_program), donate_argnums=(0,)
+        )
+        #: ``{program: (aliased bytes, temporary bytes)}`` from XLA's
+        #: ``memory_analysis()`` of each pool program this engine has run
+        #: (:meth:`_in_place`), and the pool's own size on the device.
+        self._program_memory: dict = {}
+        self._pool_device_bytes = sum(
+            arr.on_device_size_in_bytes()
+            for arr in jax.tree_util.tree_leaves(self._pool)
+        )
 
         self.ticks = 0
         self.tokens_emitted = 0
@@ -589,6 +641,21 @@ class PagedEngine:
         out["prefill_pending_slots"] = len(self._prefilling)
         out["kv_pool_bytes"] = self.kv_pool_bytes
         out["kv_bytes_per_token"] = self.kv_bytes_per_token
+        # From the compiled programs themselves (`_in_place`): the pool's
+        # bytes less what the least-aliasing program run so far does NOT
+        # alias - kv_pool_bytes while every program takes the whole pool
+        # donated - and the tick's temporaries, where a pool-sized layout
+        # copy would show.  None before a program has run (and over the
+        # grouped pools, whose programs are not asked).
+        memory = self._program_memory
+        out["kv_pool_aliased_bytes"] = None
+        if memory:
+            aliased = min(aliased for aliased, _ in memory.values())
+            out["kv_pool_aliased_bytes"] = max(
+                self.kv_pool_bytes
+                - max(self._pool_device_bytes - aliased, 0), 0
+            )
+        out["tick_temp_bytes"] = memory["tick"][1] if "tick" in memory else None
         return out
 
     def slot_states(self) -> list[dict]:
@@ -619,6 +686,23 @@ class PagedEngine:
         return states
 
     # ------------------------------------------------------------ lifecycle
+
+    def _in_place(self, program: str, jit_fn, *args):
+        """Run a dense-pool program that takes the pool donated and returns
+        it updated as its last or only output; ``_pool`` is rebound before
+        anything else can read the buffers the program consumed.  The first
+        run of each ``program`` also notes what XLA says of its memory,
+        before the call while its arguments are live: the call's lowering
+        is this one, so the program is compiled once."""
+        if program not in self._program_memory:
+            analysis = jit_fn.lower(*args).compile().memory_analysis()
+            self._program_memory[program] = (
+                int(analysis.alias_size_in_bytes),
+                int(analysis.temp_size_in_bytes),
+            )
+        out = jit_fn(*args)
+        self._pool = out[-1] if isinstance(out, tuple) else out
+        return out
 
     def _refuse_grouped(self, what: str) -> None:
         if self.grouped:
@@ -783,8 +867,9 @@ class PagedEngine:
             shared = info.block_ids[idx]
             if self.allocator.refcount(shared) > 1:
                 fresh = self._alloc_blocks(1)[0]
-                self._pool = self._copy_jit(
-                    self._pool, np.int32(shared), np.int32(fresh)
+                self._in_place(
+                    "copy_block", self._copy_jit, self._pool,
+                    np.int32(shared), np.int32(fresh),
                 )
                 self.allocator.deref([shared])
                 info.block_ids[idx] = fresh
@@ -847,18 +932,22 @@ class PagedEngine:
             )
             for bid in ids
         ]
-        layers = [
-            {
-                name: np.stack([blk[li][name] for blk in per_block])
-                for name in per_block[0][li]
-            }
-            for li in range(len(self._pool))
-        ] if per_block else [
-            {name: np.zeros((0,) + tuple(arr.shape[1:]), arr.dtype)
-             for name, arr in layer.items()}
-            for layer in self._pool
-        ]
+        # One slot's blocks, stacked on the host and turned from the
+        # pool's rows into the wire's heads-major blocks (scale rows are
+        # the same in both).
         kv_heads = self.config.num_kv_heads or self.config.num_heads
+        layers = []
+        for li, layer in enumerate(self._pool):
+            shipped = {}
+            for name, arr in layer.items():
+                blocks = np.stack(
+                    [blk[li][name] for blk in per_block]
+                ) if per_block else np.zeros((0,) + arr.shape[1:], arr.dtype)
+                shipped[name] = (
+                    _blocks_to_wire(blocks, kv_heads) if name in ("k", "v")
+                    else blocks
+                )
+            layers.append(shipped)
         meta = {
             "format": 1,
             "block_size": self.block_size,
@@ -952,6 +1041,8 @@ class PagedEngine:
             )
         names = set(self._pool[0])
         n = int(meta["n_blocks"])
+        kv_heads = self.config.num_kv_heads or self.config.num_heads
+        wire_block = (kv_heads, self.block_size, self.config.d_head)
         for li, (layer, pool_layer) in enumerate(zip(layers, self._pool)):
             if set(layer) != names:
                 raise ValueError(
@@ -959,7 +1050,10 @@ class PagedEngine:
                     f"match the pool's {sorted(names)}"
                 )
             for name, arr in layer.items():
-                want_shape = (n,) + tuple(pool_layer[name].shape[1:])
+                # K/V ride the wire as heads-major blocks, scales as rows.
+                want_shape = (n,) + (
+                    wire_block if name in ("k", "v") else (kv_heads,)
+                )
                 want_dtype = pool_layer[name].dtype
                 arr = np.asarray(arr)
                 if tuple(arr.shape) != want_shape or arr.dtype != want_dtype:
@@ -999,12 +1093,25 @@ class PagedEngine:
         fresh = self._alloc_blocks(chain)
         self._tables[slot, :chain] = fresh
         self._tables[slot, chain:] = 0
+        # The wire's heads-major blocks become the pool's rows on the host
+        # (one slot's blocks), then land through the inject program.
+        at_rest = [
+            {
+                name: _blocks_from_wire(np.asarray(arr)) if name in ("k", "v")
+                else np.asarray(arr)
+                for name, arr in layer.items()
+            }
+            for layer in payload["layers"]
+        ]
         for i, dst in enumerate(fresh[:n]):
             rows = [
                 {name: arr[i] for name, arr in layer.items()}
-                for layer in payload["layers"]
+                for layer in at_rest
             ]
-            self._pool = self._inject_jit(self._pool, rows, np.int32(dst))
+            self._in_place(
+                "inject_block", self._inject_jit, self._pool, rows,
+                np.int32(dst),
+            )
 
         prompt = np.asarray(meta["prompt"], np.int32)
         plen = int(meta["prompt_len"])
@@ -1172,7 +1279,8 @@ class PagedEngine:
                 info.top_p_enc,
             )
         else:
-            tok, key, self._pool = self._chunk_jit(
+            tok, key, _ = self._in_place(
+                f"chunk_{bucket}", self._chunk_jit,
                 self._params, self._lm_head, self._pool,
                 self._tables[slot], padded, np.int32(info.next_pos),
                 np.int32(chunk_len), key_in, info.temp_enc, info.top_k_enc,
@@ -1269,7 +1377,8 @@ class PagedEngine:
                     self._keys, self._temps, self._top_ks, self._top_ps,
                 )
             else:
-                tokens, positions, keys, self._pool = self._tick_jit(
+                tokens, positions, keys, _ = self._in_place(
+                    "tick", self._tick_jit,
                     self._params, self._lm_head, self._pool, self._tables,
                     self._tokens, self._positions, self._active, self._keys,
                     self._temps, self._top_ks, self._top_ps,

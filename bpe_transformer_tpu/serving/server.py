@@ -2323,6 +2323,11 @@ class ServingEngine:
                     # gate's KV-memory regression rows.
                     "kv_pool_bytes": gauges["kv_pool_bytes"],
                     "kv_bytes_per_token": gauges["kv_bytes_per_token"],
+                    # The compiled programs' own account: is the pool
+                    # still updated in place, and what the tick holds
+                    # beside it (ISSUE 30).
+                    "kv_pool_aliased_bytes": gauges["kv_pool_aliased_bytes"],
+                    "tick_temp_bytes": gauges["tick_temp_bytes"],
                 }
             )
             if self.spec:

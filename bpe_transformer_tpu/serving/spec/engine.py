@@ -65,6 +65,7 @@ from bpe_transformer_tpu.serving.spec.draft import (
     _propose_program,
 )
 from bpe_transformer_tpu.telemetry.spans import Phase
+from bpe_transformer_tpu.utils.compile_cache import layered_program_options
 
 __all__ = ["SpecEngine"]
 
@@ -289,7 +290,10 @@ class SpecEngine(PagedEngine):
             functools.partial(
                 _spec_verify_program, config=config,
                 block_size=self.block_size, fused=self.fused_sampling,
-            )
+            ),
+            # The pool and the layers, as in the base engine's tick.
+            donate_argnums=(2,),
+            compiler_options=layered_program_options(),
         )
 
         # Acceptance telemetry (cumulative; the serving layer snapshots
@@ -487,7 +491,9 @@ class SpecEngine(PagedEngine):
                 room = min(room, backed - 1 - p)
             rooms[slot] = room
 
-        out, n_emit, keys, self._pool = self._verify_jit(
+        # The spec engine's tick program: the pool goes through it donated.
+        out, n_emit, keys, _ = self._in_place(
+            "tick", self._verify_jit,
             self._params, self._lm_head, self._pool, self._tables,
             self._tokens, d_toks, d_probs, self._positions, rooms,
             self._active, self._keys, self._temps, self._top_ks,

@@ -133,7 +133,17 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # pools included — and ``kv_bytes_per_token`` — the per-position KV
     # footprint at pool width, the attention read stream's unit, which
     # int8 quantization halves/quarters; both feed the
-    # report --baseline regression gate; older streams predate them).
+    # report --baseline regression gate; older streams predate them), and
+    # what the compiled pool programs say of themselves (optional, also in
+    # ``stats()`` / ``/statusz``; XLA ``memory_analysis()`` of each program
+    # the engine has run): ``kv_pool_aliased_bytes`` — ``kv_pool_bytes``
+    # less the bytes of the pool that the least-aliasing program does not
+    # alias from its donated argument to its output, so EQUAL to
+    # ``kv_pool_bytes`` while the pool goes through every program in place
+    # and lower by an array's size as soon as one stops being donated —
+    # and ``tick_temp_bytes``, the tick (or spec verify) program's
+    # temporaries, where a pool-sized layout copy coming back would show.
+    # Both null before a program has run and over window pool groups.
     "kvpool": {
         "kind", "t", "blocks_total", "blocks_free", "blocks_shared",
         "prefix_hits", "prefix_misses",

@@ -11,8 +11,10 @@ never a timing or a result.
 
 The topology is described inside a module-scoped fixture (only the xdist
 worker that is handed this file loads libtpu) and the compile happens in
-the test's own process.  Whole train steps and serving ticks (35-45 s
-each) are NOT here — they live in the builder's scratch script.
+the test's own process.  Whole train steps and serving ticks at full depth
+(35-45 s each) are NOT here — they live in the builder's scratch script;
+the paged engine's pool programs are, cut to two layers (ISSUE 30: the
+compiled text is what says that no program copies the pool).
 """
 
 import os
@@ -253,7 +255,7 @@ def test_decode_attention(one_chip, heads, d_head):
 @pytest.mark.parametrize("kv_dtype", [BF16, I8], ids=["bf16", "int8"])
 def test_paged_decode_attention(one_chip, kv_dtype, block_size):
     slots, heads, d_head, num_blocks = 8, 12, 64, 515
-    pool = ((num_blocks, heads, block_size, d_head), kv_dtype)
+    pool = ((num_blocks, block_size, heads * d_head), kv_dtype)
     shapes = [
         ((slots, heads, d_head), BF16), pool, pool,
         ((slots, 1024 // block_size), I32), ((slots,), I32),
@@ -273,6 +275,187 @@ def test_paged_decode_attention(one_chip, kv_dtype, block_size):
             )
 
     _compile(fn, one_chip, *shapes)
+
+
+# ------------------------------------------- the paged pool, in place
+#
+# gpt2-small-32k's widths cut to 2 layers and 8 slots, over a pool of 2,049
+# blocks of 16 (four times what the slots can hold, so that a pool array is
+# larger than the tick's gathered chains): the engine's own programs with
+# the pool donated, as `PagedEngine` jits them.  The pool's shape IS its
+# layout on the device (`init_kv_pool`), so the compiled text must hold no
+# copy or transpose of a pool-shaped array, on the way in, inside, or on the
+# way out.
+
+POOL_SLOTS, POOL_BLOCK, POOL_BLOCKS = 8, 16, 2049
+
+
+def _described(tree, one_chip):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree,
+    )
+
+
+def _pool_program(name, config, one_chip, kv_dtype, layers_as_calls=True):
+    """``(jitted program, its arguments described on the chip, the pool)``
+    for one of the engine's pool programs, jitted as `PagedEngine` jits it
+    on the TPU (``layers_as_calls=False``: without the compiler option)."""
+    import functools
+
+    from bpe_transformer_tpu.utils.compile_cache import layered_program_options
+
+    options = layered_program_options("tpu") if layers_as_calls else None
+
+    from bpe_transformer_tpu.models.decode import init_kv_pool
+    from bpe_transformer_tpu.models.transformer import init_params
+    from bpe_transformer_tpu.serving.engine import prepare_serving_weights
+    from bpe_transformer_tpu.serving.kvpool import paged_engine as pe
+
+    slots, bs = POOL_SLOTS, POOL_BLOCK
+    nbs = config.context_length // bs
+
+    def weights():
+        params = init_params(jax.random.PRNGKey(0), config)
+        return prepare_serving_weights(params, config, None)[:2]
+
+    params, lm_head = _described(jax.eval_shape(weights), one_chip)
+    pool = _described(
+        jax.eval_shape(
+            lambda: init_kv_pool(
+                config, POOL_BLOCKS, bs, BF16, kv_dtype=kv_dtype
+            )
+        ),
+        one_chip,
+    )
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    scalar = arr((), I32)
+    layered = dict(donate_argnums=(2,), compiler_options=options)
+    if name == "tick":
+        fn = functools.partial(
+            pe._paged_tick_program, config=config, block_size=bs
+        )
+        args = (
+            params, lm_head, pool, arr((slots, nbs), I32), arr((slots,), I32),
+            arr((slots,), I32), arr((slots,), jnp.bool_),
+            arr((slots, 2), jnp.uint32), arr((slots,), F32),
+            arr((slots,), I32), arr((slots,), F32),
+        )
+        return jax.jit(fn, **layered), args, pool
+    if name == "chunk":
+        fn = functools.partial(pe._chunk_program, config=config, block_size=bs)
+        args = (
+            params, lm_head, pool, arr((nbs,), I32), arr((1, 256), I32),
+            scalar, scalar, arr((2,), jnp.uint32), arr((), F32), scalar,
+            arr((), F32),
+        )
+        return jax.jit(fn, **layered), args, pool
+    if name == "copy_block":
+        return (
+            jax.jit(pe._copy_block_program, donate_argnums=(0,)),
+            (pool, scalar, scalar), pool,
+        )
+    rows = _described(
+        jax.eval_shape(lambda p: pe._extract_block_program(p, 0), pool),
+        one_chip,
+    )
+    return (
+        jax.jit(pe._inject_block_program, donate_argnums=(0,)),
+        (pool, rows, scalar), pool,
+    )
+
+
+def _shape_text(a):
+    tag = {"bfloat16": "bf16", "int8": "s8", "float32": "f32"}[str(a.dtype)]
+    return f"{tag}[{','.join(str(n) for n in a.shape)}]"
+
+
+def _pool_copies(text, pool_shapes):
+    """Lines of the compiled text that copy or transpose an array of one of
+    ``pool_shapes`` (as operand or result: a layout change keeps the
+    shape)."""
+    import re
+
+    moves = re.compile(r" = \S+ (copy|copy-start|transpose)\(")
+    return [
+        line.strip()[:200] for line in text.splitlines()
+        if moves.search(line) and any(s in line for s in pool_shapes)
+    ]
+
+
+@pytest.mark.parametrize(
+    "name,kv_dtype",
+    [("tick", None), ("chunk", None), ("copy_block", None),
+     ("inject_block", None), ("tick", "int8")],
+    ids=["tick", "chunk256", "copy_block", "inject_block", "tick-int8"],
+)
+def test_pool_programs_hold_no_pool_copy(one_chip, name, kv_dtype):
+    import dataclasses
+
+    config = dataclasses.replace(GPT2_SMALL_32K, num_layers=2)
+    jitted, args, pool = _pool_program(name, config, one_chip, kv_dtype)
+    compiled = jitted.lower(*args).compile()
+    leaves = jax.tree_util.tree_leaves(pool)
+    shapes = {_shape_text(a) for a in leaves if a.ndim == 3}
+    assert shapes == {f"{'s8' if kv_dtype else 'bf16'}[2049,16,768]"}
+    assert _pool_copies(compiled.as_text(), shapes) == []
+    memory = compiled.memory_analysis()
+    kv_bytes = sum(a.size * a.dtype.itemsize for a in leaves if a.ndim == 3)
+    # The K/V arrays rest unpadded and are aliased whole (an int8 pool's
+    # (blocks, heads) scale rows are padded to the lane width, so they
+    # alias to more than they hold); all the temporaries of a program over
+    # the activation-width pool are smaller than one of its arrays.
+    assert memory.alias_size_in_bytes >= kv_bytes
+    if kv_dtype is None:
+        assert memory.alias_size_in_bytes == kv_bytes
+        assert memory.temp_size_in_bytes < kv_bytes // (2 * config.num_layers)
+
+
+def test_pool_programs_compile_their_layers_as_calls(one_chip):
+    """With one pool alive the chip has memory to spare, and XLA then
+    writes every layer's code out: gpt2-medium's tick went 20 -> 78 MB and
+    its 1,024-token chunk program 26 -> 126 MB, which a 192 MiB compile
+    cache cannot keep, so every start compiled cold (PERF.md §6 PR 30).
+    `layered_program_options` asks for the layers as calls; at two layers
+    the executable is already a third smaller, and the gap grows with the
+    depth."""
+    import dataclasses
+
+    from jax.experimental import serialize_executable
+
+    config = dataclasses.replace(GPT2_SMALL_32K, num_layers=2)
+    size = {}
+    for as_calls in (True, False):
+        jitted, args, _ = _pool_program("tick", config, one_chip, None, as_calls)
+        compiled = jitted.lower(*args).compile()
+        size[as_calls] = len(serialize_executable.serialize(compiled)[0])
+    assert size[True] < 0.8 * size[False], size
+
+
+def test_a_four_dimensional_pool_would_be_copied(one_chip):
+    """Why the pool's rows are ``kv_heads * d_head`` wide and not ``(...,
+    kv_heads, d_head)``: with 64 as the minor dimension of a
+    four-dimensional array the chip rests it block-axis-minor, which no
+    scatter or gather indexes, and the detector above sees the copies.  If
+    this stops holding, the comment in `init_kv_pool` is out of date."""
+    shape = (513, 16, 12, 64)
+
+    def program(pool, ids, offsets, rows, tables):
+        pool = pool.at[ids, offsets].set(rows)
+        return pool, jnp.sum(pool[tables].astype(F32), axis=1)
+
+    args = [
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+        for s, d in (
+            (shape, BF16), ((8,), I32), ((8,), I32), ((8, 12, 64), BF16),
+            ((8, 64), I32),
+        )
+    ]
+    text = jax.jit(program, donate_argnums=(0,)).lower(*args).compile().as_text()
+    assert len(_pool_copies(text, {"bf16[513,16,12,64]"})) >= 2
 
 
 def _head_shapes(vocab, d, head_dtype):

@@ -712,11 +712,18 @@ def test_decode_attention_gpt2_shape():
 
 
 def _paged_pool(rng, num_blocks, kv_heads, block_size, d, dtype=np.float32):
+    """A pool array as `init_kv_pool` shapes it: block-major rows, a row's
+    heads side by side."""
     return jnp.asarray(
-        rng.standard_normal((num_blocks, kv_heads, block_size, d)).astype(
+        rng.standard_normal((num_blocks, block_size, kv_heads * d)).astype(
             dtype
         )
     )
+
+
+def _per_head(scale, d):
+    """(num_blocks, kv_heads) block scales spread over the pool's shape."""
+    return jnp.repeat(scale, d, axis=1)[:, None, :]
 
 
 @pytest.mark.parametrize(
@@ -756,10 +763,62 @@ def test_paged_decode_attention_matches_gathered_xla(
     out = paged_decode_attention(q, k_pool, v_pool, tables, pos,
                                  interpret=True)
     ref = xla_decode_attention(
-        q, gather_paged_kv(k_pool, tables), gather_paged_kv(v_pool, tables),
-        pos,
+        q, gather_paged_kv(k_pool, tables, kv_heads),
+        gather_paged_kv(v_pool, tables, kv_heads), pos,
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize(
+    "batch,heads,kv_heads,queries,d",
+    [
+        (3, 8, 4, 1, 16),   # GQA decode tick: one query a slot
+        (2, 4, 4, 1, 64),   # MHA at a production head width
+        (2, 6, 2, 3, 8),    # the verify pass: K+1 queries a slot
+        (1, 6, 1, 2, 48),   # MQA, odd head width
+    ],
+)
+def test_rows_attention_matches_per_head_attention(
+    batch, heads, kv_heads, queries, d
+):
+    """`xla_rows_attention` over KV kept as pool rows (heads side by side
+    along the lanes, never split) equals plain per-head attention over the
+    same keys: the block-diagonal queries add only zeros."""
+    from bpe_transformer_tpu.kernels.pallas.decode_attention import (
+        xla_decode_attention,
+        xla_rows_attention,
+    )
+
+    rng = np.random.default_rng(batch * 100 + heads)
+    keys = 24
+    k_rows = jnp.asarray(
+        rng.standard_normal((batch, keys, kv_heads * d)).astype(np.float32)
+    )
+    v_rows = jnp.asarray(
+        rng.standard_normal((batch, keys, kv_heads * d)).astype(np.float32)
+    )
+    q = jnp.asarray(
+        rng.standard_normal((batch, heads, queries, d)).astype(np.float32)
+    )
+    pos = jnp.asarray(rng.integers(0, keys - queries, batch), jnp.int32)
+    # Query j of slot b sees keys up to pos[b] + j.
+    frontier = pos[:, None] + jnp.arange(queries)[None, :]
+    visible = jnp.arange(keys)[None, None, :] <= frontier[:, :, None]
+    out = xla_rows_attention(q, k_rows, v_rows, visible)
+    assert out.shape == (batch, heads, queries, d)
+
+    def split(rows):  # (B, keys, kv * d) -> the dense cache's (B, kv, keys, d)
+        return jnp.transpose(
+            rows.reshape(batch, keys, kv_heads, d), (0, 2, 1, 3)
+        )
+
+    for j in range(queries):
+        ref = xla_decode_attention(
+            q[:, :, j], split(k_rows), split(v_rows), frontier[:, j]
+        )
+        np.testing.assert_allclose(
+            np.asarray(out[:, :, j]), np.asarray(ref), atol=2e-5
+        )
 
 
 def test_paged_decode_attention_int8_matches_dequant_reference():
@@ -785,12 +844,10 @@ def test_paged_decode_attention_int8_matches_dequant_reference():
         (np.abs(rng.standard_normal((num_blocks, kv_heads))) / 40 + 0.01)
         .astype(np.float32)
     )
-    kq = jnp.clip(
-        jnp.round(kf / k_scale[:, :, None, None]), -127, 127
-    ).astype(jnp.int8)
-    vq = jnp.clip(
-        jnp.round(vf / v_scale[:, :, None, None]), -127, 127
-    ).astype(jnp.int8)
+    k_per_head = _per_head(k_scale, d)
+    v_per_head = _per_head(v_scale, d)
+    kq = jnp.clip(jnp.round(kf / k_per_head), -127, 127).astype(jnp.int8)
+    vq = jnp.clip(jnp.round(vf / v_per_head), -127, 127).astype(jnp.int8)
     tables = jnp.asarray(
         rng.permutation(np.arange(1, num_blocks)).reshape(slots, nbs),
         jnp.int32,
@@ -802,10 +859,11 @@ def test_paged_decode_attention_int8_matches_dequant_reference():
         q, kq, vq, tables, pos, k_scale=k_scale, v_scale=v_scale,
         interpret=True,
     )
-    kd = kq.astype(jnp.float32) * k_scale[:, :, None, None]
-    vd = vq.astype(jnp.float32) * v_scale[:, :, None, None]
+    kd = kq.astype(jnp.float32) * k_per_head
+    vd = vq.astype(jnp.float32) * v_per_head
     ref = xla_decode_attention(
-        q, gather_paged_kv(kd, tables), gather_paged_kv(vd, tables), pos
+        q, gather_paged_kv(kd, tables, kv_heads),
+        gather_paged_kv(vd, tables, kv_heads), pos,
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
@@ -840,8 +898,8 @@ def test_paged_decode_attention_single_compile_across_state():
         pos = jnp.asarray(r2.integers(0, nbs * block_size, slots), jnp.int32)
         out = f(q, k_pool, v_pool, tables, pos)
         ref = xla_decode_attention(
-            q, gather_paged_kv(k_pool, tables),
-            gather_paged_kv(v_pool, tables), pos,
+            q, gather_paged_kv(k_pool, tables, kv_heads),
+            gather_paged_kv(v_pool, tables, kv_heads), pos,
         )
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5)
@@ -854,15 +912,20 @@ def test_paged_decode_attention_rejects_bad_shapes():
     )
 
     q = jnp.zeros((2, 4, 16))
-    pool = jnp.zeros((9, 2, 8, 16))
+    pool = jnp.zeros((9, 8, 2 * 16))
     tables = jnp.zeros((2, 4), jnp.int32)
     pos = jnp.zeros((2,), jnp.int32)
     with pytest.raises(ValueError, match="tables"):
         paged_decode_attention(q, pool, pool, jnp.zeros((3, 4), jnp.int32),
                                pos, interpret=True)
     with pytest.raises(ValueError, match="shape mismatch"):
-        paged_decode_attention(q, pool, jnp.zeros((9, 2, 8, 8)), tables,
+        paged_decode_attention(q, pool, jnp.zeros((9, 8, 2 * 8)), tables,
                                pos, interpret=True)
+    # The heads-major blocks of the migration wire are not the pool's form.
+    with pytest.raises(ValueError, match="shape mismatch"):
+        old_form = jnp.zeros((9, 2, 8, 16))
+        paged_decode_attention(q, old_form, old_form, tables, pos,
+                               interpret=True)
     with pytest.raises(ValueError, match="int8"):
         paged_decode_attention(q, pool, pool, tables, pos,
                                k_scale=jnp.zeros((9, 2)), interpret=True)

@@ -68,7 +68,18 @@ DEFAULT_BUDGET_S = 800.0
 #: 793 at PR 28 (whole run 228 s): the Cohere2-MoE block against its
 #: reference, the window pool group's allocator, every refusal (31 cases,
 #: 105 s in one process) and 8 AOT compiles of its two kernels (33 s).
-DEFAULT_MAX_TESTS = 820
+#: Raised 820 -> 845 in PR 30 (828 collected, 29 added): the dense pool in
+#: place - every pool program's aliasing from its own memory analysis, at
+#: both pool widths, over an audited begin-to-release lifecycle (one case a
+#: program), the compiler option its layered programs are jitted with
+#: (tests/test_kvpool.py, 18 cases in 25 s), the parent's wire fixtures,
+#: attention over pool rows against per-head attention
+#: (tests/test_kernels.py, 4 cases), and the tick, chunk, copy-block and
+#: inject-block programs compiled for the described v5e at gpt2-small-32k
+#: widths and two layers, the tick once more without the option for the
+#: executable's size (tests/test_chip_compile.py, 7 cases, 17-24 s a whole
+#: program).
+DEFAULT_MAX_TESTS = 845
 
 #: Pytest summary trailer: "== 398 passed, 27 deselected in 612.34s =="
 #: (also plain "in 612.34s (0:10:12)" forms).
