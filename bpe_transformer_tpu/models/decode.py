@@ -347,10 +347,11 @@ def decode_step(
 # --------------------------------------------------------- paged KV memory
 #
 # The serving kvpool layer (serving/kvpool/) replaces the dense per-slot
-# cache rows with a flat pool of fixed-size blocks; these are the device
-# programs that read/write KV *through a block table* instead of a
-# contiguous row.  Both live here (not in serving/) because they are the
-# paged twins of prefill/decode_step above and share every building block.
+# cache rows with a flat pool of fixed-size blocks; what follows reads and
+# writes KV *through a block table* instead of a contiguous row: the pools,
+# the cache kinds that own their formats, and the one forward over them
+# (here, not in serving/: the paged twin of prefill/decode_step above,
+# sharing every building block).
 
 
 def init_kv_pool(
@@ -500,428 +501,239 @@ def _quantize_decode_row(
     )
 
 
-def paged_decode_step(
-    params: Params,
-    token: Array,
-    pos: Array,
-    pool: KVCache,
-    tables: Array,
-    config: ModelConfig,
-    lm_head: Array | None = None,
-    active: Array | None = None,
-    return_hidden: bool = False,
-    *,
-    block_size: int,
-) -> tuple[Array, KVCache]:
-    """One cached decode step against the paged pool — the block-table twin
-    of :func:`decode_step` (``return_hidden`` as there: the fused
-    sample-in-kernel tick takes the final-norm hidden state and owns the
-    head projection).
-
-    ``token``/``pos``/``active``: per-slot ``(slots,)`` vectors as in the
-    serving slot pool.  ``tables`` (slots, blocks_per_slot) int32 maps each
-    slot's logical block index to a pool block id (0 = trash).  The new
-    K/V is scattered into the pool at ``(tables[slot, pos // block_size],
-    pos % block_size)`` — inactive slots scatter to the trash block, so one
-    compiled program serves every occupancy pattern (int8 pools quantize
-    the row at scatter time, :func:`_quantize_decode_row`).  Attention then
-    honors ``config.decode_attention_impl``: ``"paged"`` runs the
-    paged-NATIVE flash kernel straight against the pool (the block table is
-    consumed inside the kernel's index maps — no contiguous transient);
-    ``"xla"`` gathers the slots' rows (:func:`gather_paged_rows`,
-    dequantizing on gather for int8 pools) and attends over them as they
-    are (`xla_rows_attention`); ``"pallas"`` splits the heads out of the
-    gathered rows for the contiguous flash-decoding kernel.
-    """
-    x = _embed(params, token[:, None])  # (S, 1, d)
-    positions = pos[:, None]
-    block_col = (pos // block_size).astype(jnp.int32)
-    offsets = (pos % block_size).astype(jnp.int32)
-    write_ids = jnp.take_along_axis(tables, block_col[:, None], axis=1)[:, 0]
-    if active is not None:
-        write_ids = jnp.where(active, write_ids, 0)
-    quantized = "k_scale" in pool[0]
-    kv_heads = config.num_kv_heads or config.num_heads
-    # (S, 1, keys): key j visible to slot s iff j <= pos[s].
-    visible = (
-        jnp.arange(tables.shape[1] * block_size)[None, None, :]
-        <= pos[:, None, None]
+@jax.named_scope("pool_write")
+def _quantize_chunk_rows(
+    pool_arr: Array, scale_arr: Array, rows: Array, write_ids, offsets, valid
+) -> tuple[Array, Array]:
+    """Scatter one slot's chunk of rows ``(rows, kv_heads, d_head)`` into an
+    int8 block pool whose blocks the chunk owns FRESH (chunks start
+    block-aligned: the radix-shared prefix is whole blocks, non-final chunks
+    are block multiples): each written block's scale is RESET to the max
+    over the chunk's rows in that block (a scatter-max after a scatter-zero;
+    the recycled block's leftover scale never leaks), then the rows quantize
+    against it.  A final partial block's scale keeps growing under
+    :func:`_quantize_decode_row`."""
+    amax = jnp.max(jnp.abs(rows.astype(jnp.float32)), axis=-1)  # (rows, kv)
+    amax = jnp.where(valid[:, None], amax, 0.0)
+    scales = scale_arr.at[write_ids, :].set(0.0)
+    scales = scales.at[write_ids, :].max(amax / 127.0)
+    per_row = jnp.maximum(scales[write_ids], 1e-30)  # (rows, kv)
+    rows_q = jnp.clip(
+        jnp.round(rows.astype(jnp.float32) / per_row[..., None]), -127, 127
+    )
+    return (
+        pool_arr.at[write_ids, offsets].set(_pool_rows(rows_q, jnp.int8)),
+        scales,
     )
 
-    new_pool = []
-    for block_params, layer_pool in zip(params["layers"], pool):
 
-        def attend(h, block_params=block_params, layer_pool=layer_pool):
-            q, k, v = _project_qkv(h, block_params["attn"], config)
-            q, k = _rope_qk(q, k, positions, config)
-            # Scatter the one new token's K/V into each slot's frontier
-            # block (advanced-index scatter: (S,) block ids x (S,) offsets
-            # address (S, kv_heads * d_head) rows).
-            k_scale = v_scale = None
-            if quantized:
-                k_pool, k_scale = _quantize_decode_row(
-                    layer_pool["k"], layer_pool["k_scale"],
-                    k[:, :, 0, :], write_ids, offsets,
-                )
-                v_pool, v_scale = _quantize_decode_row(
-                    layer_pool["v"], layer_pool["v_scale"],
-                    v[:, :, 0, :], write_ids, offsets,
-                )
-                new_pool.append(
-                    {"k": k_pool, "v": v_pool,
-                     "k_scale": k_scale, "v_scale": v_scale}
-                )
-            else:
-                # Explicit cast to the pool width: jax 0.9 deprecates the
-                # implicit one (an f32 row into a bf16 pool).
-                with jax.named_scope("pool_write"):
-                    k_pool = layer_pool["k"].at[write_ids, offsets].set(
-                        _pool_rows(k[:, :, 0, :], layer_pool["k"].dtype)
-                    )
-                    v_pool = layer_pool["v"].at[write_ids, offsets].set(
-                        _pool_rows(v[:, :, 0, :], layer_pool["v"].dtype)
-                    )
-                new_pool.append({"k": k_pool, "v": v_pool})
-            if config.decode_attention_impl == "paged":
-                from bpe_transformer_tpu.kernels.pallas.decode_attention import (
-                    paged_decode_attention,
-                )
-
-                att = paged_decode_attention(
-                    q[:, :, 0], k_pool, v_pool, tables, pos,
-                    k_scale=k_scale, v_scale=v_scale,
-                )[:, :, None, :]
-            elif config.decode_attention_impl == "pallas":
-                # The contiguous kernel wants the dense cache's layout.
-                from bpe_transformer_tpu.kernels.pallas.decode_attention import (
-                    decode_attention,
-                )
-
-                att = decode_attention(
-                    q[:, :, 0],
-                    gather_paged_kv(k_pool, tables, kv_heads, k_scale, h.dtype),
-                    gather_paged_kv(v_pool, tables, kv_heads, v_scale, h.dtype),
-                    pos,
-                )[:, :, None, :]
-            else:
-                from bpe_transformer_tpu.kernels.pallas.decode_attention import (
-                    xla_rows_attention,
-                )
-
-                att = xla_rows_attention(
-                    q,
-                    gather_paged_rows(k_pool, tables, k_scale, h.dtype),
-                    gather_paged_rows(v_pool, tables, v_scale, h.dtype),
-                    visible,
-                )
-            return linear(merge_heads(att), block_params["attn"]["output_proj"])
-
-        x = _block_apply(x, block_params, config, attend)
-
-    x = _final_norm(x, params, config)
-    if return_hidden:
-        return x[:, 0], new_pool
-    head = lm_head_weight(params, config) if lm_head is None else lm_head
-    logits = head_logits(x[:, 0], head)
-    return logits, new_pool
+# ------------------------------------------------------------ cache kinds
+#
+# One forward (:func:`paged_forward`) serves every program that reads and
+# writes KV through a block table: a decode tick is ``(slots, 1)`` tokens, a
+# speculative verify pass ``(slots, K + 1)``, a prefill chunk ``(1, chunk
+# bucket)``.  What differs between pools is a *cache kind*: the one place
+# that knows how a pool is laid out.  A kind provides
+#
+# * init          the pool's arrays (`init_kv_pool`, `init_grouped_kv_pool`,
+#                  chosen by `init_paged_pool`);
+# * addresses     computed at construction, once a program, from where each
+#                  row goes (``positions``), the block tables and which rows
+#                  are real (``valid``): block ids, offsets, visibility;
+# * ``write``      one layer's new K/V rows into its pool arrays;
+# * ``attend``     the queries against the layer's pool, ``(slots, rows,
+#                  heads * d_head)`` out;
+# * ``ffn_rows`` / ``tally`` / ``counts``   the rows a dropless expert layer
+#                  routes, where its counts are collected and what the
+#                  program hands back (None where the kind carries none:
+#                  its programs then have no such output).
+#
+# ``positions`` and ``valid`` come as the program has them and are used as
+# they come, never reshaped (the ``(n, 1)`` form of the same arithmetic
+# compiles into other fusions than the programs were measured with):
+# ``(slots,)`` for one row a slot, against ``tables`` (slots, blocks);
+# ``(slots, rows)`` for several; and with ``chunk`` (its first position and
+# the count of its real rows) ``(rows,)`` of ONE slot, whose ``tables`` are
+# its own rows ``(blocks,)``.
+#
+# A new architecture's cache (latent, recurrent) is one more kind.
 
 
-def paged_chunk_prefill(
-    params: Params,
-    chunk_tokens: Array,
-    start: Array,
-    chunk_len: Array,
-    table_row: Array,
-    pool: KVCache,
-    config: ModelConfig,
-    lm_head: Array | None = None,
-    *,
-    block_size: int,
-) -> tuple[Array, KVCache]:
-    """Prefill ONE chunk of one slot's prompt into the paged pool.
+def _clamped(x, hi: int, rows: int):
+    """``x``, positions or block columns of ``rows`` rows a slot, held to
+    ``0 .. hi`` for indexing the RoPE tables or a block table.  Further
+    rows of a verify pass and a chunk's padded tail may lie past the context
+    or the table (their writes go to trash, their outputs nowhere); a
+    one-row step never does - the engine retires a slot at the context's
+    end - and is indexed as it is."""
+    return x if rows == 1 else jnp.clip(x, 0, hi)
 
-    ``chunk_tokens`` (1, chunk_bucket) is the chunk padded to its program
-    bucket; ``start`` (traced scalar) its first absolute position;
-    ``chunk_len`` (traced) the real token count; ``table_row``
-    (blocks_per_slot,) the slot's block chain.  The chunk's K/V is
-    scattered straight into the pool per position (padded tail positions
-    steer to the trash block), then the chunk's queries attend to the
-    slot's FULL gathered cache under the causal mask ``key_pos <= start +
-    row`` — which is what lets a chunk resume after a radix-cache-shared
-    prefix (positions < start were written by an earlier request's
-    prefill) and is also how long prompts prefill incrementally, chunk by
-    chunk, between decode ticks.
 
-    Returns logits at the chunk's last real position (the serving layer
-    samples the first token from the FINAL chunk's logits and discards the
-    others) and the updated pool.  Non-final chunks must have ``chunk_len
-    % block_size == 0`` so the next chunk starts block-aligned.
+def _token_rows(x, chunk: bool, one_row: bool):
+    """``(slots, heads, rows, d_head)`` as its addresses come: ``(rows,
+    heads, d_head)`` of a chunk's one slot, ``(slots, heads, d_head)`` for
+    one row a slot, else ``(slots, rows, heads, d_head)``."""
+    if chunk:
+        return jnp.swapaxes(x[0], 0, 1)
+    return x[:, :, 0] if one_row else jnp.swapaxes(x, 1, 2)
 
-    Attention here is the materialized-scores formulation (transient
-    O(chunk x context) score buffer) regardless of ``attention_impl`` —
-    the chunk-vs-whole-cache shape has no flash kernel yet.
 
-    int8 pools: chunks always start block-aligned (the radix-shared prefix
-    is whole blocks; non-final chunks are block multiples), so every block
-    this chunk touches is freshly owned — its per-block scale is RESET to
-    the max over the chunk's rows in that block (a scatter-max after a
-    scatter-zero; the recycled block's leftover scale never leaks), then
-    the rows quantize against it.  A final partial block's scale keeps
-    growing under decode's rescale-on-grow writes.
-    """
-    _, cb = chunk_tokens.shape
-    ctx = config.context_length
-    nb = table_row.shape[0]
-    positions = start + jnp.arange(cb)
-    # Padded tail rows may index past the RoPE/context tables: clamp them
-    # (their outputs are discarded; their pool writes go to trash below).
-    safe_positions = jnp.clip(positions, 0, ctx - 1)
-    in_chunk = jnp.arange(cb) < chunk_len
-    idx_in_table = jnp.clip(safe_positions // block_size, 0, nb - 1)
-    write_ids = jnp.where(in_chunk, table_row[idx_in_table], 0)
-    offsets = safe_positions % block_size
-    quantized = "k_scale" in pool[0]
-    kv_heads = config.num_kv_heads or config.num_heads
+def _block_addresses(tables, at, valid, block_size: int, rows: int):
+    """``(block ids, offsets)`` of the rows at table-relative positions
+    ``at``: ``tables[slot, at // block_size]``, the trash block 0 where
+    ``valid`` is False."""
+    col = _clamped(at // block_size, tables.shape[-1] - 1, rows).astype(jnp.int32)
+    if tables.ndim == 1:
+        ids = tables[col]
+    elif at.ndim == 1:
+        ids = jnp.take_along_axis(tables, col[:, None], axis=1)[:, 0]
+    else:
+        ids = jnp.take_along_axis(tables, col, axis=1)
+    if valid is not None:
+        ids = jnp.where(valid, ids, 0)
+    return ids, (at % block_size).astype(jnp.int32)
 
-    x = _embed(params, chunk_tokens)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(config.d_head, jnp.float32))
-    # (cb, ctx) causal frontier: key j visible to chunk row i iff j <= start+i.
-    mask = (
-        jnp.arange(nb * block_size)[None, :] <= (start + jnp.arange(cb))[:, None]
-    )
 
-    @jax.named_scope("pool_write")
-    def _quant_chunk_rows(pool_arr, scale_arr, rows):
-        """Per-block scatter of this chunk's (cb, kv, d) rows: reset the
-        written blocks' scales, scatter-max the rows' absmax in, quantize
-        each row against its block's fresh scale."""
-        amax = jnp.max(jnp.abs(rows.astype(jnp.float32)), axis=-1)  # (cb, kv)
-        amax = jnp.where(in_chunk[:, None], amax, 0.0)
-        scales = scale_arr.at[write_ids, :].set(0.0)
-        scales = scales.at[write_ids, :].max(amax / 127.0)
-        per_row = jnp.maximum(scales[write_ids], 1e-30)  # (cb, kv)
-        rows_q = jnp.clip(
-            jnp.round(rows.astype(jnp.float32) / per_row[..., None]),
-            -127, 127,
+class DenseRows:
+    """`init_kv_pool`'s pool: per layer K and V rows ``(blocks, block,
+    kv_heads * d_head)``, at the activation width or int8 with per-block
+    scales.  ``tables`` maps a slot's logical block to a pool block id (0 =
+    trash: rows that are not ``valid`` are written there, so one program
+    serves every occupancy).
+
+    A chunk's rows begin blocks it owns fresh: an int8 pool resets their
+    scales (:func:`_quantize_chunk_rows`), and its queries attend to the
+    slot's gathered chain by per-head materialized scores (an O(chunk x
+    context) transient: the chunk-vs-whole-cache shape has no flash kernel).
+    Otherwise rows land mid-block beside earlier ones: int8 rows
+    rescale-on-grow one after another (:func:`_quantize_decode_row`, the
+    write order of as many plain ticks; the readers of a several-row pass
+    see each block's FINAL scale, so its int8 logits match plain ticks
+    within quantization error, not bitwise), and every slot's chain, as
+    large as the pool, is attended as rows (`xla_rows_attention`).
+    ``config.decode_attention_impl`` governs the one-row step alone."""
+
+    #: Every row goes through the FFN, and a MoE layer's counts are dropped.
+    ffn_rows = tally = None
+
+    def __init__(self, config, tables, positions, valid, block_size, chunk=None):
+        self.config, self.tables, self.valid = config, tables, valid
+        self.chunk = chunk is not None
+        self.one_row = positions.ndim == 1 and not self.chunk
+        rows = 1 if self.one_row else positions.shape[-1]
+        at = _clamped(positions, config.context_length - 1, rows)
+        self.positions = positions
+        self.rope_positions = at[:, None] if self.one_row else at
+        self.write_ids, self.offsets = _block_addresses(
+            tables, at, valid, block_size, rows
         )
-        return (
-            pool_arr.at[write_ids, offsets].set(_pool_rows(rows_q, jnp.int8)),
-            scales,
+        # (slots, rows, keys): key j visible to a row iff j <= its position.
+        visible = jnp.arange(tables.shape[-1] * block_size) <= positions[..., None]
+        self.visible = (
+            visible[None] if self.chunk else visible[:, None] if self.one_row
+            else visible
         )
 
-    new_pool = []
-    for block_params, layer_pool in zip(params["layers"], pool):
-
-        def attend(h, block_params=block_params, layer_pool=layer_pool):
-            q, k, v = _project_qkv(h, block_params["attn"], config)
-            q, k = _rope_qk(q, k, safe_positions, config)
-            k_scale = v_scale = None
-            if quantized:
-                k_pool, k_scale = _quant_chunk_rows(
-                    layer_pool["k"], layer_pool["k_scale"],
-                    jnp.transpose(k[0], (1, 0, 2)),
-                )
-                v_pool, v_scale = _quant_chunk_rows(
-                    layer_pool["v"], layer_pool["v_scale"],
-                    jnp.transpose(v[0], (1, 0, 2)),
-                )
-                new_pool.append(
-                    {"k": k_pool, "v": v_pool,
-                     "k_scale": k_scale, "v_scale": v_scale}
+    def write(self, layer, layer_pool, k, v):
+        """``k``/``v`` (slots, kv_heads, rows, d_head) to ``(write_ids,
+        offsets)``: an advanced-index scatter of ``kv_heads * d_head`` wide
+        rows, cast to the pool's width."""
+        out = {}
+        for name, new in (("k", k), ("v", v)):
+            rows = _token_rows(new, self.chunk, self.one_row)
+            arr = layer_pool[name]
+            if f"{name}_scale" in layer_pool:
+                out[name], out[f"{name}_scale"] = self._write_int8(
+                    arr, layer_pool[f"{name}_scale"], rows
                 )
             else:
                 with jax.named_scope("pool_write"):
-                    k_pool = layer_pool["k"].at[write_ids, offsets].set(
-                        _pool_rows(
-                            jnp.transpose(k[0], (1, 0, 2)),
-                            layer_pool["k"].dtype,
-                        )
+                    out[name] = arr.at[self.write_ids, self.offsets].set(
+                        _pool_rows(rows, arr.dtype)
                     )
-                    v_pool = layer_pool["v"].at[write_ids, offsets].set(
-                        _pool_rows(
-                            jnp.transpose(v[0], (1, 0, 2)),
-                            layer_pool["v"].dtype,
-                        )
-                    )
-                new_pool.append({"k": k_pool, "v": v_pool})
+        return out
+
+    def _write_int8(self, arr, scale, rows):
+        at = self.write_ids, self.offsets
+        if self.chunk:
+            return _quantize_chunk_rows(arr, scale, rows, *at, self.valid)
+        if self.one_row:
+            return _quantize_decode_row(arr, scale, rows, *at)
+        return lax.scan(
+            lambda carry, row: (_quantize_decode_row(*carry, *row), None),
+            (arr, scale), tuple(jnp.swapaxes(a, 0, 1) for a in (rows, *at)),
+        )[0]
+
+    def attend(self, layer, q, layer_pool):
+        from bpe_transformer_tpu.kernels.pallas.decode_attention import (
+            decode_attention,
+            paged_decode_attention,
+            xla_rows_attention,
+        )
+
+        config, tables = self.config, self.tables
+        kv_heads = config.num_kv_heads or config.num_heads
+        k_pool, v_pool = layer_pool["k"], layer_pool["v"]
+        k_scale, v_scale = layer_pool.get("k_scale"), layer_pool.get("v_scale")
+        impl = config.decode_attention_impl if self.one_row else "xla"
+        if self.chunk:
             # One slot's chain, heads split out: an activation-sized
-            # transpose (the scores below are per head).
-            k_cache = gather_paged_kv(
-                k_pool, table_row[None], kv_heads, k_scale, h.dtype
-            )
-            v_cache = gather_paged_kv(
-                v_pool, table_row[None], kv_heads, v_scale, h.dtype
-            )
+            # transpose (the scores are per head).
+            k_cache = gather_paged_kv(k_pool, tables[None], kv_heads, k_scale, q.dtype)
+            v_cache = gather_paged_kv(v_pool, tables[None], kv_heads, v_scale, q.dtype)
             with jax.named_scope("chunk_attn"):
-                k_full = _expand_kv(k_cache, config)
-                v_full = _expand_kv(v_cache, config)
-                scores = jnp.einsum("bhqd,bhkd->bhqk", q, k_full) * scale
-                scores = jnp.where(mask[None, None], scores, -jnp.inf)
+                scale = 1.0 / jnp.sqrt(jnp.asarray(config.d_head, jnp.float32))
+                scores = jnp.einsum(
+                    "bhqd,bhkd->bhqk", q, _expand_kv(k_cache, config)
+                ) * scale
+                scores = jnp.where(self.visible[:, None], scores, -jnp.inf)
                 probs = jax.nn.softmax(
                     scores.astype(jnp.float32), axis=-1
-                ).astype(h.dtype)
-                att = merge_heads(
-                    jnp.einsum("bhqk,bhkd->bhqd", probs, v_full)
+                ).astype(q.dtype)
+                att = jnp.einsum(
+                    "bhqk,bhkd->bhqd", probs, _expand_kv(v_cache, config)
                 )
-            return linear(att, block_params["attn"]["output_proj"])
-
-        x = _block_apply(x, block_params, config, attend)
-
-    x = _final_norm(x, params, config)
-    head = lm_head_weight(params, config) if lm_head is None else lm_head
-    idx = jnp.reshape(jnp.clip(chunk_len - 1, 0, cb - 1), (1, 1, 1))
-    last = jnp.take_along_axis(x, idx, axis=1)[:, 0]
-    return head_logits(last, head), new_pool
-
-
-def paged_verify_step(
-    params: Params,
-    tokens: Array,
-    positions: Array,
-    rooms: Array,
-    pool: KVCache,
-    tables: Array,
-    config: ModelConfig,
-    lm_head: Array | None = None,
-    active: Array | None = None,
-    return_hidden: bool = False,
-    *,
-    block_size: int,
-) -> tuple[Array, KVCache]:
-    """Batched multi-position scoring pass — the speculative-decoding
-    verify program's forward (`serving/spec/`), generalizing
-    :func:`paged_decode_step` from one token per slot to ``K+1``.
-
-    ``tokens`` (slots, K+1): each slot's not-yet-written last token followed
-    by its K draft proposals; ``positions`` (slots,) the absolute position
-    of ``tokens[:, 0]``; ``rooms`` (slots,) how many PROPOSAL rows are real
-    for this slot (rows ``0..rooms[s]`` are written/scored; beyond that the
-    scatter steers to the trash block and the outputs are host-ignored —
-    one fixed-``K`` program serves every per-slot headroom).  All K+1
-    tokens' K/V scatter into the pool through the block table exactly as a
-    chunk prefill would (a K-length chunk IS a scoring pass), then every
-    row attends to the slot's full gathered rows under the causal frontier
-    ``key_pos <= positions + row`` (`xla_rows_attention`, as the tick's).  Returns logits ``(slots, K+1, vocab)``
-    — row ``j`` is the target distribution for position ``positions+j+1``
-    — and the updated pool.
-
-    The serving layer rolls the written frontier back over rejected rows
-    afterwards (`PagedEngine.rewind`): positions beyond the accepted
-    prefix hold stale K/V that the mask keeps invisible until the next
-    verify overwrites them.
-
-    int8 pools quantize rows SEQUENTIALLY via a ``lax.scan`` over the K+1
-    rows with the decode-row quantizer (`_quantize_decode_row`), preserving
-    its rescale-on-grow semantics: rows land mid-block next to earlier
-    valid rows, so the chunk-prefill scale RESET would corrupt them.  The
-    pass's readers then see each block's FINAL scale (plain ticks see the
-    scale as of their own step), so int8 verify logits match K+1 plain
-    ticks within quantization error, not bitwise — the act-width path is
-    exact.  Attention is the materialized-scores formulation (as in
-    :func:`paged_chunk_prefill`): the chunk-vs-whole-cache shape has no
-    flash kernel, and ``decode_attention_impl`` only governs the 1-token
-    tick.
-    """
-    s, k1 = tokens.shape
-    ctx = config.context_length
-    nb = tables.shape[1]
-    pos_j = positions[:, None] + jnp.arange(k1)[None, :]  # (S, K+1)
-    safe_pos = jnp.clip(pos_j, 0, ctx - 1)
-    valid = (jnp.arange(k1)[None, :] <= rooms[:, None]) & (pos_j <= ctx - 1)
-    if active is not None:
-        valid = valid & active[:, None]
-    idx = jnp.clip(safe_pos // block_size, 0, nb - 1)
-    write_ids = jnp.where(valid, jnp.take_along_axis(tables, idx, axis=1), 0)
-    offsets = safe_pos % block_size
-    quantized = "k_scale" in pool[0]
-
-    from bpe_transformer_tpu.kernels.pallas.decode_attention import (
-        xla_rows_attention,
-    )
-
-    x = _embed(params, tokens)  # (S, K+1, d)
-    # (S, K+1, ctx) causal frontier: key j visible to row i iff j <= pos_i.
-    mask = jnp.arange(nb * block_size)[None, None, :] <= pos_j[:, :, None]
-
-    def _quant_verify_rows(pool_arr, scale_arr, rows):
-        """Sequential per-row int8 scatter (rows (S, K+1, kv, d)): each row
-        applies the decode quantizer against the scale state the previous
-        row left — the same write order as K+1 plain decode ticks."""
-
-        def step(carry, inp):
-            arr, sc = carry
-            row, ids, off = inp
-            return _quantize_decode_row(arr, sc, row, ids, off), None
-
-        (pool_arr, scale_arr), _ = jax.lax.scan(
-            step,
-            (pool_arr, scale_arr),
-            (
-                jnp.swapaxes(rows, 0, 1),
-                jnp.swapaxes(write_ids, 0, 1),
-                jnp.swapaxes(offsets, 0, 1),
-            ),
-        )
-        return pool_arr, scale_arr
-
-    new_pool = []
-    for block_params, layer_pool in zip(params["layers"], pool):
-
-        def attend(h, block_params=block_params, layer_pool=layer_pool):
-            q, k, v = _project_qkv(h, block_params["attn"], config)
-            q, k = _rope_qk(q, k, safe_pos, config)
-            k_rows = jnp.swapaxes(k, 1, 2)  # (S, K+1, kv, d)
-            v_rows = jnp.swapaxes(v, 1, 2)
-            k_scale = v_scale = None
-            if quantized:
-                k_pool, k_scale = _quant_verify_rows(
-                    layer_pool["k"], layer_pool["k_scale"], k_rows
-                )
-                v_pool, v_scale = _quant_verify_rows(
-                    layer_pool["v"], layer_pool["v_scale"], v_rows
-                )
-                new_pool.append(
-                    {"k": k_pool, "v": v_pool,
-                     "k_scale": k_scale, "v_scale": v_scale}
-                )
-            else:
-                k_pool = layer_pool["k"].at[write_ids, offsets].set(
-                    _pool_rows(k_rows, layer_pool["k"].dtype)
-                )
-                v_pool = layer_pool["v"].at[write_ids, offsets].set(
-                    _pool_rows(v_rows, layer_pool["v"].dtype)
-                )
-                new_pool.append({"k": k_pool, "v": v_pool})
-            # Every slot's chain: as large as the pool, so attended as rows.
+        elif impl == "paged":
+            # The paged-NATIVE flash kernel, straight against the pool: the
+            # block table is consumed inside its index maps, int8 blocks
+            # dequantize in registers, no contiguous transient.
+            att = paged_decode_attention(
+                q[:, :, 0], k_pool, v_pool, tables, self.positions,
+                k_scale=k_scale, v_scale=v_scale,
+            )[:, :, None, :]
+        elif impl == "pallas":
+            # The contiguous flash-decoding kernel wants the dense cache's
+            # layout: the heads split out of the gathered rows.
+            att = decode_attention(
+                q[:, :, 0],
+                gather_paged_kv(k_pool, tables, kv_heads, k_scale, q.dtype),
+                gather_paged_kv(v_pool, tables, kv_heads, v_scale, q.dtype),
+                self.positions,
+            )[:, :, None, :]
+        else:
             att = xla_rows_attention(
                 q,
-                gather_paged_rows(k_pool, tables, k_scale, h.dtype),
-                gather_paged_rows(v_pool, tables, v_scale, h.dtype),
-                mask,
+                gather_paged_rows(k_pool, tables, k_scale, q.dtype),
+                gather_paged_rows(v_pool, tables, v_scale, q.dtype),
+                self.visible,
             )
-            return linear(merge_heads(att), block_params["attn"]["output_proj"])
+        return merge_heads(att)
 
-        x = _block_apply(x, block_params, config, attend)
+    @staticmethod
+    def zero_counts():
+        """No routing counts ride along."""
+        return None
 
-    x = _final_norm(x, params, config)
-    if return_hidden:
-        return x, new_pool
-    head = lm_head_weight(params, config) if lm_head is None else lm_head
-    return head_logits(x, head), new_pool
+    counts = zero_counts
 
 
-# ----------------------------------------------- grouped pools (two kinds)
-#
 # A config whose layers differ in kind (sliding-window and full attention)
 # keeps two pool groups: a full layer's pool holds a slot's whole chain, a
 # window layer's only the pages still inside the window.  Both use the page
 # layout of `kernels/pallas/ragged_attention.py` - ``(pages, page_size,
 # 2 * kv_heads, d_head)``, K and V of a head side by side - which the
 # device's default tiling holds as is, so the programs donate the pool and
-# update it in place with no copy at their edges.  ``tables`` is a dict:
-# ``"full"`` and ``"window"`` page rows per slot, and ``"window_base"``, the
-# absolute position of the first row entry of the window group (rows there
-# start at the slot's first live page; full rows start at position 0).
+# update it in place with no copy at their edges.
 
 
 def init_grouped_kv_pool(
@@ -944,164 +756,210 @@ def init_grouped_kv_pool(
     ]
 
 
-def _group_rows(tables: dict, config: ModelConfig, layer: int):
-    """``(page rows, base position, window)`` of the layer's group."""
-    window = config.layer_window(layer)
-    if window is None:
-        return tables["full"], 0, None
-    return tables["window"], tables["window_base"], window
+class GroupedPages:
+    """`init_grouped_kv_pool`'s pool.  ``tables`` is a dict: ``"full"`` and
+    ``"window"`` page rows, and ``"window_base"``, the absolute position of
+    the first row entry of the window group (rows there start at the slot's
+    first live page; full rows start at position 0).  A layer's new K/V
+    goes to its group's pages and is attended from there by the ragged
+    paged kernel, which reads the pages a slot holds and no others - causal
+    in a full layer, inside the window in a window layer, whose row must
+    reach back to the first query's ``position - window + 1``.  Either one
+    row a slot (a tick) or one slot's chunk.
+    Routing counts of the dropless expert layers ride along, rows that are
+    not ``valid`` left out."""
 
-
-@jax.named_scope("pool_write")
-def _write_pages(pages, k, v, page_ids, offsets):
-    """Scatter one K and one V row a token, ``(tokens, kv_heads, d_head)``
-    each, to ``pages[page_ids, offsets]``: a whole (2 * kv_heads, d_head)
-    tile a token."""
-    tokens, kv_heads, d_head = k.shape
-    rows = jnp.stack([k, v], axis=2).reshape(tokens, 2 * kv_heads, d_head)
-    return pages.at[page_ids, offsets].set(rows.astype(pages.dtype))
-
-
-def _attn_scope(window):
-    return jax.named_scope("attn_window" if window is not None else "attn_full")
-
-
-def grouped_decode_step(
-    params: Params,
-    token: Array,
-    pos: Array,
-    pool: list,
-    tables: dict,
-    config: ModelConfig,
-    lm_head: Array | None = None,
-    active: Array | None = None,
-    *,
-    block_size: int,
-) -> tuple[Array, list, Array]:
-    """:func:`paged_decode_step` over the grouped pools: one new token a
-    slot, written to its group's page and attended from there by the ragged
-    paged kernel, which reads the pages a slot holds and no others.  Returns
-    ``(logits, pool, moe_counts)``; ``moe_counts`` sums the layers'
-    ``dropless_moe`` counts (zeros without a MoE layer), idle slots left
-    out."""
-    from bpe_transformer_tpu.kernels.pallas.ragged_attention import (
-        ragged_paged_attention,
-    )
-
-    slots = token.shape[0]
-    x = _embed(params, token[:, None])  # (S, 1, d)
-    positions = pos[:, None]
-    live = jnp.ones((slots,), bool) if active is None else active
-    cu_q_lens = jnp.arange(slots + 1, dtype=jnp.int32)
-    num_seqs = jnp.full((1,), slots, jnp.int32)
-    tally: list = []
-    new_pool = []
-    for layer, (block_params, pages) in enumerate(zip(params["layers"], pool)):
-        rows, base, window = _group_rows(tables, config, layer)
-        rel = (pos - base).astype(jnp.int32)
-        page_ids = jnp.take_along_axis(rows, (rel // block_size)[:, None], axis=1)[:, 0]
-        page_ids = jnp.where(live, page_ids, 0)
-        kv_lens = jnp.where(live, rel + 1, 1).astype(jnp.int32)
-
-        def attend(
-            h, block_params=block_params, pages=pages, layer=layer, rows=rows,
-            window=window, rel=rel, page_ids=page_ids, kv_lens=kv_lens,
-        ):
-            q, k, v = _project_qkv(h, block_params["attn"], config)
-            q, k = _rope_qk(q, k, positions, config, layer)
-            pages = _write_pages(
-                pages, k[:, :, 0], v[:, :, 0], page_ids, rel % block_size
+    def __init__(self, config, tables, positions, valid, block_size, chunk=None):
+        if positions.ndim != 1:
+            raise NotImplementedError(
+                "several rows a slot (a verify pass) over window pool groups"
             )
-            new_pool.append(pages)
-            with _attn_scope(window):
-                att = ragged_paged_attention(
-                    q[:, :, 0], pages, kv_lens, rows, cu_q_lens, num_seqs,
-                    window=window, one_query_per_seq=True,
-                )
-            att = att.reshape(slots, 1, -1)
-            return linear(att, block_params["attn"]["output_proj"])
+        self.config, self.chunk = config, chunk is not None
+        #: The layers' ``dropless_moe`` counts, as the forward collects them.
+        self.tally: list = []
+        tokens = positions.shape[0]
+        self.ffn_rows = jnp.ones((tokens,), bool) if valid is None else valid
+        if self.chunk:
+            rows = tokens
+            start, chunk_len = chunk
+            self.cu_q_lens = jnp.stack([0, chunk_len]).astype(jnp.int32)
+            self.num_seqs = jnp.ones((1,), jnp.int32)
+        else:
+            rows = 1
+            self.cu_q_lens = jnp.arange(tokens + 1, dtype=jnp.int32)
+            self.num_seqs = jnp.full((1,), tokens, jnp.int32)
+        at = _clamped(positions, config.context_length - 1, rows)
+        self.rope_positions = at if self.chunk else at[:, None]
 
-        x = _block_apply(x, block_params, config, attend, valid=live, tally=tally)
-
-    x = _final_norm(x, params, config)
-    head = lm_head_weight(params, config) if lm_head is None else lm_head
-    return head_logits(x[:, 0], head), new_pool, _sum_counts(tally)
-
-
-def grouped_chunk_prefill(
-    params: Params,
-    chunk_tokens: Array,
-    start: Array,
-    chunk_len: Array,
-    table_rows: dict,
-    pool: list,
-    config: ModelConfig,
-    lm_head: Array | None = None,
-    *,
-    block_size: int,
-) -> tuple[Array, list, Array]:
-    """:func:`paged_chunk_prefill` over the grouped pools: the chunk's K/V
-    goes to its group's pages, then its queries attend to the slot's pages
-    through the ragged paged kernel - causal in a full layer, inside the
-    window in a window layer, whose row must reach back to ``start -
-    window + 1``.  ``table_rows`` holds one slot's rows.  Returns
-    ``(last real position's logits, pool, moe_counts)``."""
-    from bpe_transformer_tpu.kernels.pallas.ragged_attention import (
-        ragged_paged_attention,
-    )
-
-    _, cb = chunk_tokens.shape
-    positions = start + jnp.arange(cb)
-    safe_positions = jnp.clip(positions, 0, config.context_length - 1)
-    in_chunk = jnp.arange(cb) < chunk_len
-    cu_q_lens = jnp.stack([0, chunk_len]).astype(jnp.int32)
-    num_seqs = jnp.ones((1,), jnp.int32)
-    x = _embed(params, chunk_tokens)
-    tally: list = []
-    new_pool = []
-    for layer, (block_params, pages) in enumerate(zip(params["layers"], pool)):
-        row, base, window = _group_rows(table_rows, config, layer)
-        rel = (safe_positions - base).astype(jnp.int32)
-        idx = jnp.clip(rel // block_size, 0, row.shape[0] - 1)
-        page_ids = jnp.where(in_chunk, row[idx], 0)
-        kv_lens = jnp.reshape(start + chunk_len - base, (1,)).astype(jnp.int32)
-
-        def attend(
-            h, block_params=block_params, pages=pages, layer=layer, row=row,
-            window=window, rel=rel, page_ids=page_ids, kv_lens=kv_lens,
-        ):
-            q, k, v = _project_qkv(h, block_params["attn"], config)
-            q, k = _rope_qk(q, k, safe_positions, config, layer)
-            pages = _write_pages(
-                pages, jnp.swapaxes(k[0], 0, 1), jnp.swapaxes(v[0], 0, 1),
-                page_ids, rel % block_size,
+        def addresses(window):
+            """``(page rows, page ids, offsets, kv_lens)`` of a layer: its
+            group's row entries start at absolute position ``base``."""
+            page_rows, base = (
+                (tables["full"], 0) if window is None
+                else (tables["window"], tables["window_base"])
             )
-            new_pool.append(pages)
-            with _attn_scope(window):
-                att = ragged_paged_attention(
-                    jnp.swapaxes(q[0], 0, 1), pages, kv_lens, row[None],
-                    cu_q_lens, num_seqs, window=window, one_query_per_seq=False,
-                )
-            # Padded rows are not computed by the kernel: whatever it left
-            # there must not reach the next layer's K/V.
-            att = jnp.where(in_chunk[:, None, None], att, 0)
-            return linear(att.reshape(1, cb, -1), block_params["attn"]["output_proj"])
+            rel = (at - base).astype(jnp.int32)
+            if self.chunk:
+                kv_lens = jnp.reshape(start + chunk_len - base, (1,))
+            else:
+                kv_lens = jnp.where(self.ffn_rows, rel + 1, 1)
+            return (
+                page_rows if page_rows.ndim == 2 else page_rows[None],
+                *_block_addresses(page_rows, rel, self.ffn_rows, block_size, rows),
+                kv_lens.astype(jnp.int32),
+            )
 
-        x = _block_apply(
-            x, block_params, config, attend, valid=in_chunk[None], tally=tally
+        # A layer each, not a group each: XLA merges the equal ones, and
+        # fuses these few integers otherwise when they come merged.
+        self.layers = [
+            addresses(config.layer_window(layer))
+            for layer in range(config.num_layers)
+        ]
+
+    @jax.named_scope("pool_write")
+    def write(self, layer, pages, k, v):
+        """One K and one V row a token to ``pages[page_ids, offsets]``: a
+        whole ``(2 * kv_heads, d_head)`` tile a token."""
+        _, page_ids, offsets, _ = self.layers[layer]
+        k, v = (_token_rows(x, self.chunk, True) for x in (k, v))
+        tokens, kv_heads, d_head = k.shape
+        tiles = jnp.stack([k, v], axis=2).reshape(tokens, 2 * kv_heads, d_head)
+        return pages.at[page_ids, offsets].set(tiles.astype(pages.dtype))
+
+    def attend(self, layer, q, pages):
+        from bpe_transformer_tpu.kernels.pallas.ragged_attention import (
+            ragged_paged_attention,
         )
 
-    x = _final_norm(x, params, config)
-    head = lm_head_weight(params, config) if lm_head is None else lm_head
-    idx = jnp.reshape(jnp.clip(chunk_len - 1, 0, cb - 1), (1, 1, 1))
-    last = jnp.take_along_axis(x, idx, axis=1)[:, 0]
-    return head_logits(last, head), new_pool, _sum_counts(tally)
+        window = self.config.layer_window(layer)
+        page_rows, _, _, kv_lens = self.layers[layer]
+        slots, heads, rows, d_head = q.shape
+        with jax.named_scope("attn_window" if window is not None else "attn_full"):
+            att = ragged_paged_attention(
+                _token_rows(q, self.chunk, True), pages, kv_lens,
+                page_rows, self.cu_q_lens,
+                self.num_seqs, window=window, one_query_per_seq=not self.chunk,
+            )
+        if self.chunk:
+            # Padded rows are not computed by the kernel: whatever it left
+            # there must not reach the next layer's K/V.
+            att = jnp.where(self.ffn_rows[:, None, None], att, 0)
+        return att.reshape(slots, rows, heads * d_head)
 
-
-def _sum_counts(tally: list) -> Array:
-    if not tally:
+    @staticmethod
+    def zero_counts():
+        """[tokens routed, assignments on held experts, non-empty expert
+        groups], int32: what a program is handed and hands back."""
         return jnp.zeros((3,), jnp.int32)
-    return jnp.sum(jnp.stack(tally), axis=0)
+
+    def counts(self):
+        """The tally summed over the layers (zeros without a MoE layer)."""
+        if not self.tally:
+            return self.zero_counts()
+        return jnp.sum(jnp.stack(self.tally), axis=0)
+
+
+def cache_kind(config: ModelConfig):
+    return GroupedPages if config.has_window_layers else DenseRows
+
+
+def init_paged_pool(
+    config: ModelConfig, num_blocks: int, block_size: int, dtype=jnp.float32,
+    *, kv_dtype: str | None = None, num_window_blocks: int = 0,
+):
+    """The pool of the config's cache kind.  ``num_window_blocks`` sizes the
+    window group where the kind has one."""
+    if cache_kind(config) is GroupedPages:
+        if kv_dtype is not None:
+            raise ValueError("window pool groups hold K/V at the activation width")
+        return init_grouped_kv_pool(
+            config, num_blocks, num_window_blocks, block_size, dtype
+        )
+    return init_kv_pool(config, num_blocks, block_size, dtype, kv_dtype=kv_dtype)
+
+
+def slot_cache(config, tables, positions, valid=None, *, block_size: int):
+    """The cache as a decode tick (``positions`` (slots,): one row a slot)
+    or a verify pass ((slots, K + 1)) addresses it: the token at absolute
+    position ``positions[s]`` / ``positions[s, j]`` is a row of slot ``s``;
+    rows where ``valid`` is False (idle slots, proposals beyond a slot's
+    room) are written to trash."""
+    return cache_kind(config)(config, tables, positions, valid, block_size)
+
+
+def chunk_cache(
+    config, table_row, start, chunk_len, bucket: int, *, block_size: int
+):
+    """The cache as ONE slot's prefill chunk addresses it: ``bucket`` rows
+    from absolute position ``start`` (traced), the first ``chunk_len``
+    (traced) real, through the slot's own ``table_row`` (one row of each
+    table).  Non-final chunks must have ``chunk_len % block_size == 0`` so
+    the next chunk starts block-aligned; a chunk may resume after a
+    radix-shared prefix (positions < start were written by an earlier
+    request's prefill)."""
+    return cache_kind(config)(
+        config, table_row, start + jnp.arange(bucket),
+        jnp.arange(bucket) < chunk_len, block_size, (start, chunk_len),
+    )
+
+
+def _cached_attention(h, attn, config, cache, layer, layer_pool, new_pool):
+    q, k, v = _project_qkv(h, attn, config)
+    q, k = _rope_qk(q, k, cache.rope_positions, config, layer)
+    layer_pool = cache.write(layer, layer_pool, k, v)
+    new_pool.append(layer_pool)
+    return linear(cache.attend(layer, q, layer_pool), attn["output_proj"])
+
+
+def paged_forward(
+    params: Params,
+    tokens: Array,
+    pool: list,
+    cache,
+    config: ModelConfig,
+    lm_head: Array | None = None,
+    *,
+    row=None,
+    return_hidden: bool = False,
+):
+    """``tokens`` (slots, rows) through the model over a paged pool: each
+    layer's new K/V is written where ``cache`` (a cache kind, built by
+    :func:`slot_cache` or :func:`chunk_cache`) says and attended from
+    there.  The block-table twin of :func:`prefill` and :func:`decode_step`:
+    a decode tick is ``rows = 1``, a verify pass ``rows = K + 1`` (row ``j``
+    is the target distribution for position ``positions + j + 1``; the
+    serving layer rolls the frontier back over rejected rows afterwards,
+    `PagedEngine.rewind`, and the mask keeps them invisible until
+    overwritten), a chunk ``slots = 1``.
+
+    Returns ``(logits, pool, counts)``: float32 logits of every row
+    ``(slots, rows, vocab)``, or ``(slots, vocab)`` of row ``row`` - an int
+    for all slots alike, or (slots,) indices (a chunk's last real row); the
+    final-norm hidden state in their place under ``return_hidden`` (the
+    fused sample-in-kernel tail owns the head projection then, so logits
+    never materialize in HBM); the updated pool; and the kind's routing
+    counts (None for a kind that carries none)."""
+    x = _embed(params, tokens)
+    new_pool: list = []
+    for layer, (block_params, layer_pool) in enumerate(zip(params["layers"], pool)):
+        x = _block_apply(
+            x, block_params, config,
+            partial(
+                _cached_attention, attn=block_params["attn"], config=config,
+                cache=cache, layer=layer, layer_pool=layer_pool,
+                new_pool=new_pool,
+            ),
+            valid=cache.ffn_rows, tally=cache.tally,
+        )
+    x = _final_norm(x, params, config)
+    if isinstance(row, int):
+        x = x[:, row]
+    elif row is not None:
+        x = jnp.take_along_axis(x, jnp.reshape(row, (-1, 1, 1)), axis=1)[:, 0]
+    if not return_hidden:
+        head = lm_head_weight(params, config) if lm_head is None else lm_head
+        x = head_logits(x, head)
+    return x, new_pool, cache.counts()
 
 
 def _sample_from_logits(

@@ -9,7 +9,11 @@ bounded-compile-count guarantees), with three new behaviors:
   (`models/decode.init_kv_pool`: per layer K and V arrays ``(num_blocks,
   block_size, kv_heads * d_head)``, block-major rows); each slot owns a
   *chain of block ids* in a block table that the decode tick and chunk
-  prefill read through (gather) and write through (scatter).  Pool
+  prefill read through (gather) and write through (scatter).  How a pool
+  is laid out, written and attended is its *cache kind*'s business
+  (`models/decode.py`: `DenseRows`, or `GroupedPages` for a config with
+  sliding-window layers); the two programs here are one forward over
+  whichever the config has.  Pool
   capacity is a knob (``num_blocks``) decoupled from ``slots *
   context_length``.  **One pool is alive at a time and no program copies
   it:** it rests on the device in the layout the programs index, and every
@@ -19,7 +23,7 @@ bounded-compile-count guarantees), with three new behaviors:
   read it); ``stats()`` says so from the compiled programs themselves
   (``kv_pool_aliased_bytes``, ``tick_temp_bytes``).  A program that raises
   after it was handed the pool has lost it: the next use of the pool
-  fails ("Array has been deleted"), loudly, as over the grouped pools;
+  fails ("Array has been deleted"), loudly;
 * **radix prefix sharing** — prompts consult the `RadixPrefixCache`
   before computing: matched full blocks are reference-counted into the
   slot's table and prefill starts at the first unmatched position, so a
@@ -57,12 +61,11 @@ import numpy as np
 
 from bpe_transformer_tpu.models.config import ModelConfig
 from bpe_transformer_tpu.models.decode import (
-    grouped_chunk_prefill,
-    grouped_decode_step,
-    init_grouped_kv_pool,
-    init_kv_pool,
-    paged_chunk_prefill,
-    paged_decode_step,
+    cache_kind,
+    chunk_cache,
+    init_paged_pool,
+    paged_forward,
+    slot_cache,
 )
 from bpe_transformer_tpu.serving.engine import (
     TOP_K_DISABLED,
@@ -87,108 +90,78 @@ __all__ = ["PagedEngine", "PagedSlotInfo", "NoFreeBlocksError"]
 
 
 def _chunk_program(
-    params, lm_head, pool, table_row, chunk, start, chunk_len, key, temp,
-    top_k, top_p, *, config: ModelConfig, block_size: int,
+    params, lm_head, pool, moe_pending, table_row, chunk, start, chunk_len,
+    key, temp, top_k, top_p, *, config: ModelConfig, block_size: int,
 ):
     """One chunk-bucket-shaped prefill step + first-token sampling.  The
     sampled token/key are meaningful only for a prompt's FINAL chunk (the
     host passes the request key there and ignores the outputs earlier),
-    so key handling stays byte-identical to the dense prefill program."""
-    logits, pool = paged_chunk_prefill(
-        params, chunk, start, chunk_len, table_row, pool, config,
-        lm_head=lm_head, block_size=block_size,
+    so key handling stays byte-identical to the dense prefill program.
+
+    The pool is donated and comes back updated.  ``moe_pending`` holds the
+    routing counts of the chunks since the last tick where the cache kind
+    carries any (None where it does not: no argument, no output); the
+    chunk's own are added and the next tick hands them to the host, never
+    this program."""
+    bucket = chunk.shape[1]
+    cache = chunk_cache(
+        config, table_row, start, chunk_len, bucket, block_size=block_size
+    )
+    logits, pool, counts = paged_forward(
+        params, chunk, pool, cache, config, lm_head,
+        row=jnp.clip(chunk_len - 1, 0, bucket - 1),
     )
     with jax.named_scope("key_split"):
         key, sub = jax.random.split(key)
     tok = sample_tokens(
         logits, sub[None], temp[None], top_k[None], top_p[None]
     )[0]
-    return tok, key, pool
+    return tok, key, pool, None if counts is None else moe_pending + counts
 
 
-def _paged_tick_program(
-    params, lm_head, pool, tables, tokens, positions, active, keys, temps,
-    top_ks, top_ps, *, config: ModelConfig, block_size: int,
+def _tick_program(
+    params, lm_head, pool, moe_pending, tables, tokens, positions, active,
+    keys, temps, top_ks, top_ps, *, config: ModelConfig, block_size: int,
     fused: bool = False,
 ):
     """One engine tick over the paged pool — sampling identical to the
     dense `_tick_program`, decode reads/writes through the block table.
     ``fused=True`` runs the head projection + filter + sample tail as ONE
-    Pallas kernel (see the dense twin's docstring)."""
+    Pallas kernel (see the dense twin's docstring).
+
+    Where the cache kind carries routing counts, the last output is
+    ``(counts since the last tick - the chunks' and its own -, its own, the
+    next moe_pending)``: they come back with the tokens, in the same read,
+    and the host keeps the running totals (int64; a device int32 would wrap
+    within a day of this traffic).  The next ``moe_pending``, zeros, is a
+    program's output like a chunk's, so that neither program ever sees a
+    second kind of argument there (a host array would be one, and a compile
+    in the middle of serving).  None where the kind carries none."""
     with jax.named_scope("key_split"):
         split = jax.vmap(jax.random.split)(keys)
         keys_next, subs = split[:, 0], split[:, 1]
+    cache = slot_cache(config, tables, positions, active, block_size=block_size)
+    out, pool, counts = paged_forward(
+        params, tokens[:, None], pool, cache, config, lm_head, row=0,
+        return_hidden=fused,
+    )
     if fused:
         from bpe_transformer_tpu.kernels.pallas.sample import (
             fused_head_sample,
         )
 
-        hidden, pool = paged_decode_step(
-            params, tokens, positions, pool, tables, config,
-            lm_head=lm_head, active=active, return_hidden=True,
-            block_size=block_size,
-        )
         gumbel = gumbel_rows(subs, config.vocab_size)
-        nxt = fused_head_sample(
-            hidden, lm_head, temps, top_ks, top_ps, gumbel
-        )
+        nxt = fused_head_sample(out, lm_head, temps, top_ks, top_ps, gumbel)
     else:
-        logits, pool = paged_decode_step(
-            params, tokens, positions, pool, tables, config,
-            lm_head=lm_head, active=active, block_size=block_size,
-        )
-        nxt = sample_tokens(logits, subs, temps, top_ks, top_ps)
+        nxt = sample_tokens(out, subs, temps, top_ks, top_ps)
     nxt = jnp.where(active, nxt, tokens)
     keys_next = jnp.where(active[:, None], keys_next, keys)
     positions = jnp.where(active, positions + 1, positions)
-    return nxt, positions, keys_next, pool
-
-
-def _grouped_chunk_program(
-    params, lm_head, pool, moe_pending, table_rows, chunk, start, chunk_len,
-    key, temp, top_k, top_p, *, config: ModelConfig, block_size: int,
-):
-    """:func:`_chunk_program` over the grouped pools (`models/decode.py`).
-    The pool is donated and comes back updated.  ``moe_pending`` holds the
-    routing counts of the chunks since the last tick; the chunk's own are
-    added and the next tick hands them to the host, never this program."""
-    logits, pool, counts = grouped_chunk_prefill(
-        params, chunk, start, chunk_len, table_rows, pool, config,
-        lm_head=lm_head, block_size=block_size,
-    )
-    with jax.named_scope("key_split"):
-        key, sub = jax.random.split(key)
-    tok = sample_tokens(
-        logits, sub[None], temp[None], top_k[None], top_p[None]
-    )[0]
-    return tok, key, pool, moe_pending + counts
-
-
-def _grouped_tick_program(
-    params, lm_head, pool, moe_pending, tables, tokens, positions, active,
-    keys, temps, top_ks, top_ps, *, config: ModelConfig, block_size: int,
-):
-    """:func:`_paged_tick_program` over the grouped pools.  Returns the
-    routing counts since the last tick (the chunks' and its own) and its
-    own beside the usual outputs: they come back with the tokens, in the
-    same read, and the host keeps the running totals (int64; a device
-    int32 would wrap within a day of this traffic).  The last output is the
-    next ``moe_pending``, zeros: a program's output like a chunk's, so that
-    neither program ever sees a second kind of argument there (a host array
-    would be one, and a compile in the middle of serving)."""
-    with jax.named_scope("key_split"):
-        split = jax.vmap(jax.random.split)(keys)
-        keys_next, subs = split[:, 0], split[:, 1]
-    logits, pool, counts = grouped_decode_step(
-        params, tokens, positions, pool, tables, config, lm_head=lm_head,
-        active=active, block_size=block_size,
-    )
-    nxt = sample_tokens(logits, subs, temps, top_ks, top_ps)
-    nxt = jnp.where(active, nxt, tokens)
-    keys_next = jnp.where(active[:, None], keys_next, keys)
-    positions = jnp.where(active, positions + 1, positions)
-    since = moe_pending + counts
-    return nxt, positions, keys_next, pool, since, counts, jnp.zeros_like(since)
+    moe = None
+    if counts is not None:
+        since = moe_pending + counts
+        moe = (since, counts, jnp.zeros_like(since))
+    return nxt, positions, keys_next, pool, moe
 
 
 def _copy_block_program(pool, src, dst):
@@ -316,10 +289,12 @@ class PagedEngine:
                 f"block_size={block_size} must divide "
                 f"context_length={ctx}"
             )
-        #: Two pool groups (`models/decode.py`, "grouped pools"): a config
-        #: with sliding-window layers keeps a window group beside the full
-        #: one.  Every other config is the one-group case: the full group
-        #: alone, K and V apart (`init_kv_pool`), in its own programs.
+        #: Two pool groups (`models/decode.GroupedPages`): a config with
+        #: sliding-window layers keeps a window group beside the full one.
+        #: Every other config is the one-group case: the full group alone
+        #: (`DenseRows`).  The programs and the pool are the cache kind's;
+        #: what is read here is the host's own bookkeeping of the window
+        #: group, and what cannot run over it yet.
         self.grouped = config.has_window_layers
         if config.dropless_block and weight_dtype is not None:
             raise ValueError(
@@ -390,6 +365,7 @@ class PagedEngine:
         #: chunk of positions, and every slot can hold that much.
         self.window_allocator = None
         self.window_cap = 0
+        num_window_blocks = 0
         if self.grouped:
             self.window_cap = min(
                 (config.sliding_window + self.prefill_chunk) // block_size,
@@ -412,14 +388,10 @@ class PagedEngine:
             self.params_bytes, self.tick_weight_bytes,
         ) = prepare_serving_weights(params, config, weight_dtype)
         self.fused_sampling = bool(fused_sampling)
-        if self.grouped:
-            self._pool = init_grouped_kv_pool(
-                config, num_blocks, num_window_blocks, block_size, act_dtype
-            )
-        else:
-            self._pool = init_kv_pool(
-                config, num_blocks, block_size, act_dtype, kv_dtype=kv_dtype
-            )
+        self._pool = init_paged_pool(
+            config, num_blocks, block_size, act_dtype, kv_dtype=kv_dtype,
+            num_window_blocks=num_window_blocks,
+        )
         #: "int8" for quantized pools, else the activation dtype name —
         #: the /statusz + stats() label.
         self.kv_dtype = kv_dtype or str(act_dtype)
@@ -465,7 +437,7 @@ class PagedEngine:
         #: with its own.
         self.moe_counts = np.zeros(3, np.int64)
         self.last_tick_moe_rows_local = 0
-        self._moe_pending = jnp.zeros((3,), jnp.int32)
+        self._moe_pending = cache_kind(config).zero_counts()
         self._tokens = np.zeros(slots, np.int32)
         self._positions = np.zeros(slots, np.int32)
         self._active = np.zeros(slots, bool)
@@ -477,43 +449,29 @@ class PagedEngine:
         self._prefilling: list[int] = []  # slots mid-prefill, begin order
 
         # Per-engine jit closures: compiled_programs() is an exact
-        # per-engine compile counter, as in the dense engine.
-        if self.grouped:
-            # The pool (argument 2) is donated: both programs update it in
-            # place.
-            self._chunk_jit = jax.jit(
-                functools.partial(
-                    _grouped_chunk_program, config=config,
-                    block_size=block_size,
-                ),
-                donate_argnums=(2,),
-            )
-            self._tick_jit = jax.jit(
-                functools.partial(
-                    _grouped_tick_program, config=config,
-                    block_size=block_size,
-                ),
-                donate_argnums=(2,),
-            )
-        else:
-            # With one pool alive the chip has memory to spare, and XLA then
-            # writes every layer's code out: ask for the layers as calls
-            # (`layered_program_options`), as the train step does.
-            self._chunk_jit = jax.jit(
-                functools.partial(
-                    _chunk_program, config=config, block_size=block_size
-                ),
-                donate_argnums=(2,),
-                compiler_options=layered_program_options(),
-            )
-            self._tick_jit = jax.jit(
-                functools.partial(
-                    _paged_tick_program, config=config, block_size=block_size,
-                    fused=self.fused_sampling,
-                ),
-                donate_argnums=(2,),
-                compiler_options=layered_program_options(),
-            )
+        # per-engine compile counter, as in the dense engine.  The pool
+        # (argument 2) is donated: both programs update it in place.  With
+        # one pool alive the chip has memory to spare, and XLA then writes
+        # every layer's code out: ask for the layers as calls
+        # (`layered_program_options`), as the train step does - not yet
+        # over the window pool groups, whose programs compile as they did
+        # (ROADMAP D10 measures that on the chip before it changes).
+        options = None if self.grouped else layered_program_options()
+        self._chunk_jit = jax.jit(
+            functools.partial(
+                _chunk_program, config=config, block_size=block_size
+            ),
+            donate_argnums=(2,),
+            compiler_options=options,
+        )
+        self._tick_jit = jax.jit(
+            functools.partial(
+                _tick_program, config=config, block_size=block_size,
+                fused=self.fused_sampling,
+            ),
+            donate_argnums=(2,),
+            compiler_options=options,
+        )
         # Copy-on-write block copy (rewind into a shared block): compiled
         # only the first time a CoW rewind actually runs.  Per-engine
         # partial for the same reason as the migration jits below — a
@@ -645,8 +603,7 @@ class PagedEngine:
         # bytes less what the least-aliasing program run so far does NOT
         # alias - kv_pool_bytes while every program takes the whole pool
         # donated - and the tick's temporaries, where a pool-sized layout
-        # copy would show.  None before a program has run (and over the
-        # grouped pools, whose programs are not asked).
+        # copy would show.  None before a program has run.
         memory = self._program_memory
         out["kv_pool_aliased_bytes"] = None
         if memory:
@@ -687,13 +644,14 @@ class PagedEngine:
 
     # ------------------------------------------------------------ lifecycle
 
-    def _in_place(self, program: str, jit_fn, *args):
-        """Run a dense-pool program that takes the pool donated and returns
-        it updated as its last or only output; ``_pool`` is rebound before
-        anything else can read the buffers the program consumed.  The first
-        run of each ``program`` also notes what XLA says of its memory,
-        before the call while its arguments are live: the call's lowering
-        is this one, so the program is compiled once."""
+    def _in_place(self, program: str, jit_fn, *args, pool_at: int = -1):
+        """Run a program that takes the pool donated and returns it updated
+        as its only output or as output ``pool_at`` (the chunk and tick
+        programs' last is the routing counts); ``_pool`` is rebound
+        before anything else can read the buffers the program consumed.  The
+        first run of each ``program`` also notes what XLA says of its
+        memory, before the call while its arguments are live: the call's
+        lowering is this one, so the program is compiled once."""
         if program not in self._program_memory:
             analysis = jit_fn.lower(*args).compile().memory_analysis()
             self._program_memory[program] = (
@@ -701,7 +659,7 @@ class PagedEngine:
                 int(analysis.temp_size_in_bytes),
             )
         out = jit_fn(*args)
-        self._pool = out[-1] if isinstance(out, tuple) else out
+        self._pool = out[pool_at] if isinstance(out, tuple) else out
         return out
 
     def _refuse_grouped(self, what: str) -> None:
@@ -1238,11 +1196,23 @@ class PagedEngine:
         self._prefilling.append(slot)
         return slot
 
-    def _slot_rows(self, slot: int) -> dict:
+    def _table_rows(self, slot: int | None = None):
+        """The block tables as the cache kind takes them: every slot's rows
+        (a tick), or one slot's (a chunk).  A chunk's are its own COPY.  A
+        non-final chunk returns without reading anything back, so its
+        program may still be waiting when the host next rewrites the row
+        (`_write_window_row` recycles in place), and the CPU backend reads a
+        numpy argument that happens to lie 64-byte aligned where it lies,
+        without copying it: the chunk then attended through the next
+        chunk's row (ROADMAP D11).  A tick's caller reads its results back
+        before it touches a table."""
+        pick = (lambda a: a) if slot is None else (lambda a: a[slot].copy())
+        if not self.grouped:
+            return pick(self._tables)
         return {
-            "full": self._tables[slot],
-            "window": self._window_tables[slot],
-            "window_base": self._window_base[slot],
+            "full": pick(self._tables),
+            "window": pick(self._window_tables),
+            "window_base": pick(self._window_base),
         }
 
     def prefill_step(self, slot: int) -> TickEvent | None:
@@ -1272,20 +1242,13 @@ class PagedEngine:
                 slot, info.next_pos - self.config.sliding_window + 1
             )
             self._count_attention(info.next_pos, info.next_pos + chunk_len)
-            tok, key, self._pool, self._moe_pending = self._chunk_jit(
-                self._params, self._lm_head, self._pool, self._moe_pending,
-                self._slot_rows(slot), padded, np.int32(info.next_pos),
-                np.int32(chunk_len), key_in, info.temp_enc, info.top_k_enc,
-                info.top_p_enc,
-            )
-        else:
-            tok, key, _ = self._in_place(
-                f"chunk_{bucket}", self._chunk_jit,
-                self._params, self._lm_head, self._pool,
-                self._tables[slot], padded, np.int32(info.next_pos),
-                np.int32(chunk_len), key_in, info.temp_enc, info.top_k_enc,
-                info.top_p_enc,
-            )
+        tok, key, _, self._moe_pending = self._in_place(
+            f"chunk_{bucket}", self._chunk_jit,
+            self._params, self._lm_head, self._pool, self._moe_pending,
+            self._table_rows(slot), padded, np.int32(info.next_pos),
+            np.int32(chunk_len), key_in, info.temp_enc, info.top_k_enc,
+            info.top_p_enc, pool_at=-2,
+        )
         info.next_pos += chunk_len
         if not final:
             return None
@@ -1364,32 +1327,21 @@ class PagedEngine:
                 )
                 self.attn_pairs += keys_read
                 self.attn_kv_positions += keys_read
-                tables = {
-                    "full": self._tables, "window": self._window_tables,
-                    "window_base": self._window_base,
-                }
-                (
-                    tokens, positions, keys, self._pool, moe_since, moe_tick,
-                    self._moe_pending,
-                ) = self._tick_jit(
-                    self._params, self._lm_head, self._pool, self._moe_pending,
-                    tables, self._tokens, self._positions, self._active,
-                    self._keys, self._temps, self._top_ks, self._top_ps,
-                )
-            else:
-                tokens, positions, keys, _ = self._in_place(
-                    "tick", self._tick_jit,
-                    self._params, self._lm_head, self._pool, self._tables,
-                    self._tokens, self._positions, self._active, self._keys,
-                    self._temps, self._top_ks, self._top_ps,
-                )
+            tokens, positions, keys, _, moe = self._in_place(
+                "tick", self._tick_jit,
+                self._params, self._lm_head, self._pool, self._moe_pending,
+                self._table_rows(), self._tokens, self._positions,
+                self._active, self._keys, self._temps, self._top_ks,
+                self._top_ps, pool_at=-2,
+            )
         with Phase("serve/tick_wait", self.clock) as wait:
             tokens = np.asarray(tokens)
             self._tokens = tokens.copy()
             self._positions = np.asarray(positions).copy()
             self._keys = np.asarray(keys).copy()
-            if self.grouped:
+            if moe is not None:
                 # The same read as the tokens: no sync of its own.
+                moe_since, moe_tick, self._moe_pending = moe
                 self.moe_counts += np.asarray(moe_since)
                 self.last_tick_moe_rows_local = int(np.asarray(moe_tick)[1])
         self.ticks += 1

@@ -6,8 +6,9 @@ rewind) riding the paged serving engine.
   device programs (K-step propose scan, bucketed draft prefill);
 - `engine` — `SpecEngine`: the PagedEngine contract where one tick emits
   1..K+1 tokens per slot via one batched target verify pass
-  (`models/decode.paged_verify_step`) and Leviathan rejection sampling,
-  with the rejected tail rolled back through `PagedEngine.rewind`.
+  (`models/decode.paged_forward`, K+1 rows a slot) and Leviathan
+  rejection sampling, with the rejected tail rolled back through
+  `PagedEngine.rewind`.
 
 `DraftSpec` imports no jax — the CLI validates ``--draft-config`` (vocab
 compatibility, geometry completeness) before any accelerator work.

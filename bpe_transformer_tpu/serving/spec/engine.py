@@ -6,10 +6,10 @@ through HBM to emit ONE token per slot.  `SpecEngine` cuts the *number*
 of ticks: a small `DraftModel` guesses K tokens per slot (its own dense
 KV, a few percent of the target's bytes), then ONE target pass scores
 all K+1 positions through the same paged scatter + masked attention a
-chunk prefill uses (`models/decode.paged_verify_step`), and rejection
-sampling accepts a per-slot variable prefix.  Each tick emits between 1
-token (first guess rejected — the tick degenerates to a plain decode
-step plus the cheap draft) and K+1 tokens (all accepted + the bonus),
+chunk prefill uses (`models/decode.paged_forward`, K+1 rows a slot), and
+rejection sampling accepts a per-slot variable prefix.  Each tick emits
+between 1 token (first guess rejected — the tick degenerates to a plain
+decode step plus the cheap draft) and K+1 tokens (all accepted + the bonus),
 so the HBM sweeps per emitted token drop by the acceptance rate.
 
 **Distribution preservation** (Leviathan et al.): with target
@@ -49,7 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bpe_transformer_tpu.models.config import ModelConfig
-from bpe_transformer_tpu.models.decode import paged_verify_step
+from bpe_transformer_tpu.models.decode import paged_forward, slot_cache
 from bpe_transformer_tpu.serving.engine import (
     SlotPoolEngine,
     TickEvent,
@@ -103,6 +103,17 @@ def _spec_verify_program(
     k1 = k + 1
     vocab = config.vocab_size
     tokens = jnp.concatenate([base_tokens[:, None], draft_tokens], axis=1)
+    # Rows 0..rooms[s] of slot s are written and scored; beyond that (and
+    # past the context, and in idle slots) the scatter steers to the trash
+    # block and the outputs are host-ignored: one fixed-K program serves
+    # every per-slot headroom.
+    pos_j = positions[:, None] + jnp.arange(k1)[None, :]
+    valid = (
+        (jnp.arange(k1)[None, :] <= rooms[:, None])
+        & (pos_j <= config.context_length - 1)
+        & active[:, None]
+    )
+    cache = slot_cache(config, tables, pos_j, valid, block_size=block_size)
 
     split = jax.vmap(lambda kk: jax.random.split(kk, 3))(keys)
     keys_next, u_keys, b_keys = split[:, 0], split[:, 1], split[:, 2]
@@ -127,10 +138,8 @@ def _spec_verify_program(
         )
         from bpe_transformer_tpu.serving.engine import gumbel_rows
 
-        hidden, pool = paged_verify_step(
-            params, tokens, positions, rooms, pool, tables, config,
-            lm_head=lm_head, active=active, return_hidden=True,
-            block_size=block_size,
+        hidden, pool, _ = paged_forward(
+            params, tokens, pool, cache, config, lm_head, return_hidden=True
         )  # (S, K+1, d)
         rep = lambda a: jnp.repeat(a, k1, axis=0)  # noqa: E731
         judge = jnp.concatenate(
@@ -161,9 +170,8 @@ def _spec_verify_program(
             bonus_rows.reshape(s, k1), n_acc[:, None], axis=1
         )[:, 0]
     else:
-        logits, pool = paged_verify_step(
-            params, tokens, positions, rooms, pool, tables, config,
-            lm_head=lm_head, active=active, block_size=block_size,
+        logits, pool, _ = paged_forward(
+            params, tokens, pool, cache, config, lm_head
         )
 
         # Target distribution per row under the slot's runtime knobs;

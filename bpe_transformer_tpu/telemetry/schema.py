@@ -143,7 +143,7 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # and lower by an array's size as soon as one stops being donated —
     # and ``tick_temp_bytes``, the tick (or spec verify) program's
     # temporaries, where a pool-sized layout copy coming back would show.
-    # Both null before a program has run and over window pool groups.
+    # Both null before a program has run.
     "kvpool": {
         "kind", "t", "blocks_total", "blocks_free", "blocks_shared",
         "prefix_hits", "prefix_misses",

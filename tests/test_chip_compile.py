@@ -335,11 +335,9 @@ def _pool_program(name, config, one_chip, kv_dtype, layers_as_calls=True):
     scalar = arr((), I32)
     layered = dict(donate_argnums=(2,), compiler_options=options)
     if name == "tick":
-        fn = functools.partial(
-            pe._paged_tick_program, config=config, block_size=bs
-        )
+        fn = functools.partial(pe._tick_program, config=config, block_size=bs)
         args = (
-            params, lm_head, pool, arr((slots, nbs), I32), arr((slots,), I32),
+            params, lm_head, pool, None, arr((slots, nbs), I32), arr((slots,), I32),
             arr((slots,), I32), arr((slots,), jnp.bool_),
             arr((slots, 2), jnp.uint32), arr((slots,), F32),
             arr((slots,), I32), arr((slots,), F32),
@@ -348,9 +346,22 @@ def _pool_program(name, config, one_chip, kv_dtype, layers_as_calls=True):
     if name == "chunk":
         fn = functools.partial(pe._chunk_program, config=config, block_size=bs)
         args = (
-            params, lm_head, pool, arr((nbs,), I32), arr((1, 256), I32),
+            params, lm_head, pool, None, arr((nbs,), I32), arr((1, 256), I32),
             scalar, scalar, arr((2,), jnp.uint32), arr((), F32), scalar,
             arr((), F32),
+        )
+        return jax.jit(fn, **layered), args, pool
+    if name == "verify":
+        from bpe_transformer_tpu.serving.spec.engine import _spec_verify_program
+
+        k = 2
+        fn = functools.partial(_spec_verify_program, config=config, block_size=bs)
+        args = (
+            params, lm_head, pool, arr((slots, nbs), I32), arr((slots,), I32),
+            arr((slots, k), I32), arr((slots, k, config.vocab_size), F32),
+            arr((slots,), I32), arr((slots,), I32), arr((slots,), jnp.bool_),
+            arr((slots, 2), jnp.uint32), arr((slots,), F32),
+            arr((slots,), I32), arr((slots,), F32),
         )
         return jax.jit(fn, **layered), args, pool
     if name == "copy_block":
@@ -389,8 +400,9 @@ def _pool_copies(text, pool_shapes):
 @pytest.mark.parametrize(
     "name,kv_dtype",
     [("tick", None), ("chunk", None), ("copy_block", None),
-     ("inject_block", None), ("tick", "int8")],
-    ids=["tick", "chunk256", "copy_block", "inject_block", "tick-int8"],
+     ("inject_block", None), ("tick", "int8"), ("verify", None)],
+    ids=["tick", "chunk256", "copy_block", "inject_block", "tick-int8",
+         "verify2"],
 )
 def test_pool_programs_hold_no_pool_copy(one_chip, name, kv_dtype):
     import dataclasses

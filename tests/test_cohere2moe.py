@@ -18,9 +18,10 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from bpe_transformer_tpu.models.config import TS_TEST_CONFIG, ModelConfig  # noqa: E402
 from bpe_transformer_tpu.models.decode import (  # noqa: E402
     decode_step,
-    grouped_decode_step,
     init_kv_cache,
+    paged_forward,
     prefill,
+    slot_cache,
 )
 from bpe_transformer_tpu.models.moe import dropless_moe  # noqa: E402
 from bpe_transformer_tpu.models.transformer import forward, init_params  # noqa: E402
@@ -232,13 +233,43 @@ def small_engine(c, **more) -> PagedEngine:
     return PagedEngine(ref.weights_from_seed(3, c), program_cfg(c), **args)
 
 
+def aligned64(array: np.ndarray) -> np.ndarray:
+    """A copy of ``array`` that starts on a 64-byte boundary: the CPU backend
+    reads such a jit argument where it lies, without copying it."""
+    raw = np.zeros(array.nbytes + 64, np.uint8)
+    start = (-raw.ctypes.data) % 64
+    out = raw[start:start + array.nbytes].view(array.dtype).reshape(array.shape)
+    out[...] = array
+    return out
+
+
+def test_a_chunk_is_handed_its_own_rows():
+    """`_write_window_row` rewrites a slot's row in place while the last
+    chunk's program may still be waiting: what a chunk program is handed
+    shares no memory with the engine's tables."""
+    eng = small_engine(reference_cfg(2, 2, layers=4))
+    for slot in range(eng.n_slots):
+        for handed in eng._table_rows(slot).values():
+            for table in (eng._tables, eng._window_tables, eng._window_base):
+                assert not np.shares_memory(handed, table)
+    one_group = PagedEngine(
+        init_params(jax.random.PRNGKey(0), TS_TEST_CONFIG), TS_TEST_CONFIG,
+        slots=2, block_size=4, prefix_cache=False,
+    )
+    assert not np.shares_memory(one_group._table_rows(1), one_group._tables)
+
+
 def test_paged_two_groups_match_reference_logits_past_the_window():
     """Prefill in chunks of 4 and teacher-forced decode through the paged
     two-group pools, 30 positions against a window of 6 and blocks of 2:
     logits (not tokens) against the reference's full forward, with window
-    blocks recycled mid-request."""
+    blocks recycled mid-request.  The host's tables lie 64-byte aligned,
+    where the CPU backend reads a jit argument in place: a chunk that was
+    handed a view of its row attended through the next chunk's (ROADMAP
+    D11: 0.29 here, one run in five where the alignment was chance)."""
     c = reference_cfg(2, 2)
     eng = small_engine(c)
+    eng._tables, eng._window_tables = aligned64(eng._tables), aligned64(eng._window_tables)
     pc, w = eng.config, ref.weights_from_seed(3, c)
     tokens = np.random.default_rng(2).integers(0, 64, 30)
     full = ref.forward_logits(w, tokens[None], c)[0]
@@ -257,13 +288,13 @@ def test_paged_two_groups_match_reference_logits_past_the_window():
         tok = np.zeros(eng.n_slots, np.int32)
         pos = np.zeros(eng.n_slots, np.int32)
         tok[slot], pos[slot] = tokens[t], t
-        tables = {
-            "full": eng._tables, "window": eng._window_tables,
-            "window_base": eng._window_base,
-        }
-        logits, eng._pool, _ = grouped_decode_step(
-            eng._params, jnp.asarray(tok), jnp.asarray(pos), eng._pool, tables,
-            pc, lm_head=eng._lm_head, active=jnp.asarray(active), block_size=2,
+        cache = slot_cache(
+            pc, eng._table_rows(), jnp.asarray(pos), jnp.asarray(active),
+            block_size=2,
+        )
+        logits, eng._pool, _ = paged_forward(
+            eng._params, jnp.asarray(tok)[:, None], eng._pool, cache, pc,
+            eng._lm_head, row=0,
         )
         worst = max(worst, float(jnp.max(jnp.abs(logits[slot] - full[t]))))
     assert worst < 2e-6

@@ -645,9 +645,7 @@ def test_paged_native_tick_contains_no_gather_transient(setup):
         TOP_K_DISABLED,
         TOP_P_DISABLED,
     )
-    from bpe_transformer_tpu.serving.kvpool.paged_engine import (
-        _paged_tick_program,
-    )
+    from bpe_transformer_tpu.serving.kvpool.paged_engine import _tick_program
     from bpe_transformer_tpu.telemetry.attribution import program_cost
 
     params, _ = setup
@@ -657,7 +655,7 @@ def test_paged_native_tick_contains_no_gather_transient(setup):
     pool = init_kv_pool(CFG, slots * nbs + 1, bs)
     tables = np.arange(1, slots * nbs + 1, dtype=np.int32).reshape(slots, nbs)
     argvals = (
-        params, lm_head_weight(params, CFG), pool, tables,
+        params, lm_head_weight(params, CFG), pool, None, tables,
         np.zeros(slots, np.int32), np.full(slots, 12, np.int32),
         np.ones(slots, bool), np.zeros((slots, 2), np.uint32),
         np.zeros(slots, np.float32),
@@ -668,7 +666,7 @@ def test_paged_native_tick_contains_no_gather_transient(setup):
     compiled = {}
     for name, cfg in (("gather", CFG), ("native", CFG_NATIVE)):
         fn = jax.jit(
-            functools.partial(_paged_tick_program, config=cfg, block_size=bs)
+            functools.partial(_tick_program, config=cfg, block_size=bs)
         )
         compiled[name] = fn.lower(*argvals).compile()
     hlo = {
@@ -722,9 +720,10 @@ def test_int8_logit_error_bound(setup):
     ~2e-3 at this config's ~0.5 logit scale; the bound leaves 20x
     headroom.)"""
     from bpe_transformer_tpu.models.decode import (
+        chunk_cache,
         init_kv_pool,
-        paged_chunk_prefill,
-        paged_decode_step,
+        paged_forward,
+        slot_cache,
     )
 
     params, prompts = setup
@@ -738,13 +737,16 @@ def test_int8_logit_error_bound(setup):
     # Compiled once per pool width: eager calls would dispatch (and
     # compile) every op of the model one by one, nine times over.
     active = jnp.asarray([True, False])
-    prefill = jax.jit(lambda pool: paged_chunk_prefill(
-        params, chunk, jnp.int32(0), jnp.int32(len(prompt)), tables[0],
-        pool, CFG, block_size=bs,
-    ))
-    step = jax.jit(lambda toks, pos, pool: paged_decode_step(
-        params, toks, pos, pool, tables, CFG, active=active, block_size=bs,
-    ))
+    prefill = jax.jit(lambda pool: paged_forward(
+        params, chunk, pool,
+        chunk_cache(CFG, tables[0], jnp.int32(0), jnp.int32(len(prompt)), 16,
+                    block_size=bs),
+        CFG, row=jnp.int32(len(prompt) - 1),
+    )[:2])
+    step = jax.jit(lambda toks, pos, pool: paged_forward(
+        params, toks[:, None], pool,
+        slot_cache(CFG, tables, pos, active, block_size=bs), CFG, row=0,
+    )[:2])
 
     def drive(kv_dtype):
         pool = init_kv_pool(CFG, 9, bs, kv_dtype=kv_dtype)
@@ -763,6 +765,73 @@ def test_int8_logit_error_bound(setup):
     i8 = drive("int8")
     err = float(jnp.max(jnp.abs(fp - i8)))
     assert err < 0.05, f"int8 KV logit error {err} exceeds the 0.05 bound"
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["act", "int8"])
+def test_a_tick_is_a_verify_pass_of_one_row(setup, kv_dtype):
+    """One forward, two shapes of the same step: a decode tick (one row a
+    slot, addressed by vectors, ``decode_attention_impl``'s branch) and a
+    verify pass with no proposals (``K = 0``: ``(slots, 1)`` rows, the
+    several-row writer and attend) leave the same logits and the same pool,
+    over the activation-width and the int8 pool, two slots at ragged
+    depths."""
+    from bpe_transformer_tpu.models.decode import (
+        chunk_cache,
+        init_kv_pool,
+        paged_forward,
+        slot_cache,
+    )
+
+    params, prompts = setup
+    import jax.numpy as jnp
+
+    bs = 8
+    tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
+    filled = [prompts[2], prompts[2][:5]]  # 12 and 5 tokens
+    pool = init_kv_pool(CFG, 9, bs, kv_dtype=kv_dtype)
+
+    @jax.jit
+    def fill(pool, chunk, row, n):
+        cache = chunk_cache(CFG, row, jnp.int32(0), n, 16, block_size=bs)
+        return paged_forward(params, chunk, pool, cache, CFG, row=n - 1)[1]
+
+    for slot, prompt in enumerate(filled):
+        chunk = jnp.asarray([prompt + [0] * (16 - len(prompt))], jnp.int32)
+        pool = fill(pool, chunk, tables[slot], jnp.int32(len(prompt)))
+    toks = jnp.asarray([[7], [11]], jnp.int32)
+    pos = jnp.asarray([len(p) for p in filled], jnp.int32)
+    live = jnp.asarray([True, True])
+
+    tick = jax.jit(lambda pool: paged_forward(
+        params, toks, pool, slot_cache(CFG, tables, pos, live, block_size=bs),
+        CFG, row=0,
+    )[:2])
+    one_row_pass = jax.jit(lambda pool: paged_forward(
+        params, toks, pool,
+        slot_cache(CFG, tables, pos[:, None], live[:, None], block_size=bs),
+        CFG,
+    )[:2])
+    tick_logits, tick_pool = tick(pool)
+    pass_logits, pass_pool = one_row_pass(pool)
+    assert pass_logits.shape == (2, 1, CFG.vocab_size)
+    np.testing.assert_allclose(
+        np.asarray(pass_logits[:, 0]), np.asarray(tick_logits), atol=1e-6
+    )
+    assert float(jnp.max(jnp.abs(tick_logits))) > 1e-3
+    for ours, theirs in zip(
+        jax.tree_util.tree_leaves(pass_pool), jax.tree_util.tree_leaves(tick_pool)
+    ):
+        np.testing.assert_allclose(
+            np.asarray(ours, np.float32), np.asarray(theirs, np.float32),
+            atol=1e-6,
+        )
+    written = [
+        bool(jnp.any(a != b))
+        for a, b in zip(
+            jax.tree_util.tree_leaves(tick_pool), jax.tree_util.tree_leaves(pool)
+        )
+    ]
+    assert all(written)  # the step wrote every layer's K and V
 
 
 @pytest.mark.slow  # 870s tier-1 budget (PR 11 sweep; ISSUE 11 tooling guard) — runs in the full matrix
@@ -1696,7 +1765,7 @@ def test_pool_programs_ask_the_tpu_for_layers_as_calls(monkeypatch, setup, spec)
             )
         else:
             PagedEngine(params, CFG, slots=1, block_size=8, min_bucket=8)
-        layered = ["_paged_tick_program", "_chunk_program"]
+        layered = ["_tick_program", "_chunk_program"]
         return {
             name: seen[name]
             for name in layered + ["_spec_verify_program"] * spec

@@ -267,14 +267,14 @@ def paged_programs(params):
 
     eng = pe.PagedEngine(params, CFG, slots=2, block_size=4, min_bucket=8)
     tick = compiled_text(
-        functools.partial(pe._paged_tick_program, config=CFG, block_size=4),
-        eng._params, eng._lm_head, eng._pool, eng._tables, eng._tokens,
+        functools.partial(pe._tick_program, config=CFG, block_size=4),
+        eng._params, eng._lm_head, eng._pool, None, eng._tables, eng._tokens,
         eng._positions, eng._active, eng._keys, eng._temps, eng._top_ks,
         eng._top_ps,
     )
     chunk = compiled_text(
         functools.partial(pe._chunk_program, config=CFG, block_size=4),
-        eng._params, eng._lm_head, eng._pool, eng._tables[0],
+        eng._params, eng._lm_head, eng._pool, None, eng._tables[0],
         np.zeros((1, 8), np.int32), np.int32(0), np.int32(5),
         jax.random.PRNGKey(0), np.float32(1.0), np.int32(0), np.float32(1.0),
     )
@@ -312,11 +312,11 @@ def test_scopes_are_metadata_only(params):
 
     eng = pe.PagedEngine(params, CFG, slots=2, block_size=4, min_bucket=8)
     args = (
-        eng._params, eng._lm_head, eng._pool, eng._tables, eng._tokens,
+        eng._params, eng._lm_head, eng._pool, None, eng._tables, eng._tokens,
         eng._positions, eng._active, eng._keys, eng._temps, eng._top_ks,
         eng._top_ps,
     )
-    program = functools.partial(pe._paged_tick_program, config=CFG, block_size=4)
+    program = functools.partial(pe._tick_program, config=CFG, block_size=4)
     with_scopes = compiled_text(program, *args)
 
     def no_scope(name):
@@ -326,7 +326,7 @@ def test_scopes_are_metadata_only(params):
     jax.named_scope = no_scope
     try:
         bare = compiled_text(
-            functools.partial(pe._paged_tick_program, config=CFG, block_size=4),
+            functools.partial(pe._tick_program, config=CFG, block_size=4),
             *args,
         )
     finally:
