@@ -221,85 +221,265 @@ def decode_attention(
     return out[:, :group, :].reshape(batch, num_heads, d)
 
 
+def _head_owner(heads: int, kv_heads: int, rows: int | None = None):
+    """``owner[h, k]``: query head ``h`` reads kv head ``k`` (GQA groups are
+    contiguous); bool ``(rows or heads, kv_heads)``, rows past ``heads``
+    (padding) own nothing."""
+    h = jnp.arange(rows or heads)[:, None]
+    return (h // (heads // kv_heads) == jnp.arange(kv_heads)[None, :]) & (
+        h < heads
+    )
+
+
 def _paged_decode_kernel(
-    tables_ref, pos_ref, q_ref, k_ref, v_ref, *refs,
-    scale: float, block_size: int, num_blocks_per_slot: int, kv_heads: int,
-    d_head: int, quantized: bool,
+    tables_ref, counts_ref, live_from_ref, q_ref, pick_ref, k_hbm, v_hbm,
+    *refs, scale: float, block_size: int, group_blocks: int, slots: int,
+    heads_per_kv: int, quantized: bool,
 ):
-    """Block-table flash decode: grid axis 1 walks a slot's KV BLOCKS (the
-    block table was already consumed by the BlockSpec index maps, so
-    ``k_ref``/``v_ref`` hold one pool block each: ``block_size`` rows, every
-    kv head's ``d_head`` lanes side by side) with the same online softmax as
-    :func:`_decode_kernel`, one kv head after the other."""
+    """One slot a grid step; inside it a loop over the slot's live *groups*
+    of ``group_blocks`` pool blocks (trip count from the slot's key count).
+    ``k_hbm``/``v_hbm`` are the whole pools, left in HBM: each live block of
+    a group is copied through the block table into one of two VMEM buffers
+    while the other buffer's group is computed on, and the last group of a
+    slot starts the copies of the next live slot's first group, so the
+    stream never waits on a slot's edge.
+
+    A group is computed as it lies, heads side by side: ``q_ref`` holds the
+    slot's queries block-diagonally (row ``h`` is head ``h`` in the lanes of
+    its kv head, zeros elsewhere), so one ``(heads, width) x (keys, width)``
+    contraction gives every head's scores and one ``(heads, keys) x (keys,
+    width)`` product every head's output in every kv head's lanes;
+    ``pick_ref`` keeps each head's own lanes at the end.  An int8 pool's
+    per-block-per-head scales multiply the scores and the probabilities
+    (row ``h`` only ever keeps lanes of one kv head, so the scale of that
+    head's block is a factor of the whole row entry), which is the
+    dequantization, done in registers on ``(heads, keys)`` values."""
     if quantized:
-        kscale_ref, vscale_ref, o_ref, acc_ref, m_ref, l_ref = refs
+        ks_ref, vs_ref, spread_ref, o_ref, k_buf, v_buf, sems, turn = refs
     else:
-        o_ref, acc_ref, m_ref, l_ref = refs
+        o_ref, k_buf, v_buf, sems, turn = refs
     slot = pl.program_id(0)
-    j = pl.program_id(1)
+    group_keys = group_blocks * block_size
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def copies(s, group, buf, go):
+        """Start (``go``) or await the copies of group ``group`` of slot
+        ``s`` into buffer ``buf``: its live blocks and no others."""
+        first = group * group_blocks
+        live = jnp.minimum(
+            pl.cdiv(counts_ref[s], block_size) - first, group_blocks
+        )
 
-    pos = pos_ref[slot]
+        def one(i, carry):
+            block = tables_ref[s, first + i] if go else 0
+            rows = pl.ds(pl.multiple_of(i * block_size, block_size), block_size)
+            for hbm, vmem, sem in (
+                (k_hbm, k_buf, sems.at[buf, 0]), (v_hbm, v_buf, sems.at[buf, 1])
+            ):
+                copy = pltpu.make_async_copy(
+                    hbm.at[block], vmem.at[buf, rows], sem
+                )
+                copy.start() if go else copy.wait()
+            return carry
 
-    @pl.when(j * block_size <= pos)
-    def _block():
-        k_rows = k_ref[0].astype(jnp.float32)  # (block_size, kv * d)
-        v_rows = v_ref[0].astype(jnp.float32)
+        jax.lax.fori_loop(0, live, one, 0)
+
+    @pl.when(slot == 0)
+    def _open():
+        if not quantized:
+            # Rows no copy has reached are multiplied by a probability of
+            # exactly zero: they must hold numbers.
+            k_buf[...] = jnp.zeros_like(k_buf)
+            v_buf[...] = jnp.zeros_like(v_buf)
+        turn[0] = 0
+
+        @pl.when(live_from_ref[0] < slots)
+        def _():
+            copies(live_from_ref[0], 0, 0, True)
+
+    count = counts_ref[slot]
+    groups = pl.cdiv(count, group_keys)
+    q = q_ref[0]                                    # (heads_pad, width)
+    heads_pad, width = q.shape
+
+    def per_key(scales_ref, g):
+        """A group's block scales ``(heads_pad, group_blocks)`` spread over
+        the keys of their blocks, exactly (a 0/1 matrix at full precision)."""
+        return jax.lax.dot_general(
+            scales_ref[0, g], spread_ref[...], (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+
+    def group_step(g, carry):
+        m_prev, l_prev, acc = carry
+        buf = turn[0]
+        last = g + 1 == groups
+        nxt_slot = jnp.where(last, live_from_ref[slot + 1], slot)
+
+        @pl.when(nxt_slot < slots)
+        def _():
+            copies(nxt_slot, jnp.where(last, 0, g + 1), 1 - buf, True)
+
+        copies(slot, g, buf, False)
+        k = k_buf[buf]                              # (group_keys, width)
+        v = v_buf[buf]
         if quantized:
-            # Per-block-per-head dequant IN REGISTERS.  The scale tile is
-            # the 8-row group of the (num_blocks, kv_heads) f32 pool that
-            # holds this block (a 1-row tile is not a legal TPU block);
-            # the block's row and a head's column are selected by mask
-            # (dynamic sublane/lane indexing is not a TPU vector
-            # primitive).  Rows of a ragged last group are never selected.
-            blk = tables_ref[slot, jnp.minimum(j, pos // block_size)]
-            shape = (SUBLANES, kv_heads)
-            in_row = (
-                jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-                == blk % SUBLANES
-            )
-            head_col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-        for head in range(kv_heads):
-            lanes = slice(head * d_head, (head + 1) * d_head)
-            q = q_ref[0, head].astype(jnp.float32) * scale  # (G_pad, d)
-            k = k_rows[:, lanes]                            # (block_size, d)
-            v = v_rows[:, lanes]
-            if quantized:
-                pick = in_row & (head_col == head)
-                k = k * jnp.sum(jnp.where(pick, kscale_ref[...], 0.0))
-                v = v * jnp.sum(jnp.where(pick, vscale_ref[...], 0.0))
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # (G_pad, block_size)
-            cols = (
-                jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                + j * block_size
-            )
-            s = jnp.where(cols <= pos, s, NEG_INF)
+            k = k.astype(jnp.float32).astype(q.dtype)
+            v = v.astype(jnp.float32).astype(q.dtype)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale                                   # (heads_pad, group_keys)
+        if quantized:
+            s = s * per_key(ks_ref, g)
+        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + g * group_keys
+        s = jnp.where(cols < count, s, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        if quantized:
+            p = p * per_key(vs_ref, g)
+        acc = acc * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        turn[0] = 1 - buf
+        return m_new, l_new, acc
 
-            m_prev = m_ref[head, :, 0:1]
-            l_prev = l_ref[head, :, 0:1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_ref[head] = acc_ref[head] * alpha + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_ref[head] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-            l_ref[head] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+    _, l, acc = jax.lax.fori_loop(
+        0, groups, group_step,
+        (
+            jnp.full((heads_pad, 1), NEG_INF, jnp.float32),
+            jnp.zeros((heads_pad, 1), jnp.float32),
+            jnp.zeros((heads_pad, width), jnp.float32),
+        ),
+    )
+    # A slot with no keys walks no group: zeros over the guard, finite.
+    out = acc / jnp.maximum(l, 1e-30)
+    for j in range(heads_per_kv):
+        o_ref[0, j:j + 1, :] = jnp.sum(
+            out * pick_ref[j], axis=0, keepdims=True
+        ).astype(o_ref.dtype)
 
-    @pl.when(j == num_blocks_per_slot - 1)
-    def _finalize():
-        denom = jnp.maximum(l_ref[:, :, 0:1], 1e-30)
-        o_ref[0] = (acc_ref[:] / denom).astype(o_ref.dtype)
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _paged_decode_impl(
+    q, k_pool, v_pool, tables, key_counts, k_scale, v_scale, interpret
+):
+    from bpe_transformer_tpu.kernels.pallas.runtime import paged_group_blocks
+
+    slots, num_heads, d = q.shape
+    _, block_size, width = k_pool.shape
+    kv_heads = width // d
+    per_kv = num_heads // kv_heads
+    nbs = tables.shape[1]
+    quantized = k_scale is not None
+    group = min(
+        paged_group_blocks(block_size, width, k_pool.dtype.itemsize), nbs
+    )
+    group_keys = group * block_size
+    heads_pad = pl.cdiv(num_heads, 2 * SUBLANES) * 2 * SUBLANES
+
+    head_kv = jnp.arange(heads_pad) // per_kv
+    owner = _head_owner(num_heads, kv_heads, heads_pad)
+    q_rows = jnp.einsum(
+        "shd,hk->shkd",
+        jnp.pad(q, ((0, 0), (0, heads_pad - num_heads), (0, 0))),
+        owner.astype(q.dtype),
+    ).reshape(slots, heads_pad, width)
+    # pick[j, h, lane]: head h is the j-th of its kv head, and the lane is
+    # that kv head's.
+    own_lanes = jnp.repeat(owner, d, axis=1)
+    pick = (
+        own_lanes[None]
+        & (jnp.arange(heads_pad) % per_kv == jnp.arange(per_kv)[:, None])[
+            :, :, None
+        ]
+    ).astype(jnp.float32)
+
+    counts = jnp.broadcast_to(
+        jnp.asarray(key_counts, jnp.int32).reshape(-1), (slots,)
+    )
+    tables = jnp.asarray(tables, jnp.int32)
+    # live_from[s]: the first slot from s on that holds a key, ``slots``
+    # where none does (entry ``slots`` too).
+    index = jnp.where(counts > 0, jnp.arange(slots, dtype=jnp.int32), slots)
+    live_from = jnp.append(
+        jax.lax.cummin(index, reverse=True), jnp.int32(slots)
+    )
+
+    def at_slot(*block):
+        return pl.BlockSpec(
+            (1, *block), lambda s, *_: (s,) + (0,) * len(block),
+            memory_space=pltpu.VMEM,
+        )
+
+    def whole(*shape):
+        return pl.BlockSpec(
+            shape, lambda s, *_: (0,) * len(shape), memory_space=pltpu.VMEM
+        )
+
+    in_specs = [
+        at_slot(heads_pad, width), whole(per_kv, heads_pad, width),
+        pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY),
+    ]
+    inputs = [q_rows, pick, k_pool, v_pool]
+    if quantized:
+        # The scale of head h's kv head in each block of a slot's chain,
+        # a group of blocks a row: (slots, groups, heads_pad, group).
+        groups = pl.cdiv(nbs, group)
+
+        def by_group(scale):
+            rows = jnp.swapaxes(scale[tables][:, :, head_kv], 1, 2)
+            rows = jnp.pad(rows, ((0, 0), (0, 0), (0, groups * group - nbs)))
+            return jnp.swapaxes(
+                rows.reshape(slots, heads_pad, groups, group), 1, 2
+            )
+
+        # spread[i, key]: key lies in block i of its group.
+        spread = (
+            jnp.arange(group)[:, None] == jnp.arange(group_keys)[None, :] // block_size
+        ).astype(jnp.float32)
+        in_specs += [
+            at_slot(groups, heads_pad, group), at_slot(groups, heads_pad, group),
+            whole(group, group_keys),
+        ]
+        inputs += [by_group(k_scale), by_group(v_scale), spread]
+
+    kernel = functools.partial(
+        _paged_decode_kernel,
+        scale=1.0 / (d**0.5),
+        block_size=block_size,
+        group_blocks=group,
+        slots=slots,
+        heads_per_kv=per_kv,
+        quantized=quantized,
+    )
+    buffers = pltpu.VMEM((2, group_keys, width), k_pool.dtype)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(slots,),
+            in_specs=in_specs,
+            out_specs=at_slot(per_kv, width),
+            scratch_shapes=[
+                buffers, buffers,
+                pltpu.SemaphoreType.DMA((2, 2)),   # [buffer, K | V]
+                pltpu.SMEM((1,), jnp.int32),       # the buffer in turn
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((slots, per_kv, width), jnp.float32),
+        # The buffers and the turn are carried from slot to slot.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        interpret=interpret,
+        name="paged_decode_attention",
+    )(tables, counts, live_from, *inputs)
+    # out[s, j, k * d + i] is element i of head k * per_kv + j.
+    out = jnp.swapaxes(out.reshape(slots, per_kv, kv_heads, d), 1, 2)
+    return out.reshape(slots, num_heads, d).astype(q.dtype)
 
 
 @jax.named_scope("decode_attn")
@@ -308,43 +488,39 @@ def paged_decode_attention(
     k_pool: jax.Array,
     v_pool: jax.Array,
     tables: jax.Array,
-    pos: jax.Array,
+    key_counts: jax.Array,
     *,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Paged-NATIVE flash decode: one decode step of attention read straight
-    out of the KV block pool — no contiguous per-slot gather ever exists.
+    out of the KV block pool, and only the blocks the slots hold.
 
     The serving block pool (`models/decode.init_kv_pool`) stores KV as
-    ``(num_blocks, block_size, kv_heads * d_head)`` — block-major rows, a
+    ``(num_blocks, block_size, kv_heads * d_head)`` - block-major rows, a
     row's heads side by side along the lanes; each slot's cache is a chain
     of block ids in ``tables`` ``(slots, blocks_per_slot)``.  Where
-    `gather_paged_rows` materializes a ``(slots, blocks_per_slot*block_size)``
-    transient per layer per tick before any kernel runs, here the grid is
-    ``(slots, blocks_per_slot)`` and the BLOCK TABLE IS CONSUMED INSIDE THE
-    K/V BlockSpec INDEX MAPS: ``tables``/``pos`` ride scalar prefetch
-    (SMEM), so grid step ``(s, j)`` DMAs pool block ``tables[s, min(j,
-    pos[s] // block_size)]`` — every kv head's rows, one contiguous
-    ``block_size * kv_heads * d_head`` stretch — directly into VMEM, and the
-    kernel walks the heads.  HBM traffic per tick drops to one streaming
-    read of the LIVE blocks — the gather's extra write+read round trip of
-    the whole transient is gone, and (as in :func:`decode_attention`)
-    blocks beyond the causal frontier clamp to the frontier block so their
-    DMAs are elided.
+    `gather_paged_rows` materializes ``(slots, blocks_per_slot * block_size)``
+    rows a layer a tick whatever the slots hold, here the pools stay in HBM
+    and the kernel (:func:`_paged_decode_kernel`) walks each slot's chain a
+    *group* of blocks at a time - `runtime.paged_group_blocks` of them,
+    256 keys' worth where the buffers allow - copying a group's live blocks
+    through the table into VMEM while the group before it is computed on.
+    ``key_counts`` ``(slots,)`` (a scalar is broadcast) says how many keys
+    of its chain a slot attends to, ``position + 1`` for the token just
+    written; work and HBM traffic follow it: a slot at 0 (idle) copies
+    nothing, walks no group and yields zeros.  ``tables`` and the counts
+    ride scalar prefetch, so one compiled program serves every state.
 
     ``k_scale``/``v_scale`` ``(num_blocks, kv_heads)`` f32 must be given
     exactly when the pool is int8-quantized (per-block-per-head scales, the
-    serving pool's ``kv_dtype="int8"`` layout); the kernel dequantizes each
-    block in registers, so the HBM side of the stream stays 1 byte/value.
-    What the v5e compiler refuses is a 1-row scale tile, so the scales ride
-    as 8-row groups (see the kernel); the pool shapes it accepts are
-    compiled in ``tests/test_chip_compile.py``.
+    serving pool's ``kv_dtype="int8"`` layout); the HBM side of the stream
+    stays 1 byte a value, the scales are gathered through the table
+    (activation-sized) and applied inside the kernel.  The pool shapes the
+    v5e compiler accepts are compiled in ``tests/test_chip_compile.py``.
 
-    ``pos`` is the per-slot causal frontier ``(slots,)`` (scalar broadcast
-    accepted).  Returns ``(slots, num_heads, d_head)`` like
-    :func:`decode_attention`.
+    Returns ``(slots, num_heads, d_head)`` like :func:`decode_attention`.
     """
     if interpret is None:
         from bpe_transformer_tpu.kernels.pallas.runtime import interpret_mode
@@ -357,7 +533,7 @@ def paged_decode_attention(
             f"v_pool {v_pool.shape} (pools are (num_blocks, block_size, "
             "kv_heads * d_head))"
         )
-    num_blocks, block_size, width = k_pool.shape
+    num_blocks, _, width = k_pool.shape
     kv_heads = width // d
     if tables.ndim != 2 or tables.shape[0] != slots:
         raise ValueError(
@@ -379,75 +555,9 @@ def paged_decode_attention(
             f"k_scale {k_scale.shape} must be (num_blocks={num_blocks}, "
             f"kv_heads={kv_heads})"
         )
-    group = num_heads // kv_heads
-    g_pad = pl.cdiv(group, SUBLANES) * SUBLANES
-    nbs = tables.shape[1]
-
-    qg = q.reshape(slots, kv_heads, group, d)
-    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_pad - group), (0, 0)))
-    pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (slots,))
-    tables = jnp.asarray(tables, jnp.int32)
-
-    kernel = functools.partial(
-        _paged_decode_kernel,
-        scale=1.0 / (d**0.5),
-        block_size=block_size,
-        num_blocks_per_slot=nbs,
-        kv_heads=kv_heads,
-        d_head=d,
-        quantized=quantized,
+    return _paged_decode_impl(
+        q, k_pool, v_pool, tables, key_counts, k_scale, v_scale, interpret
     )
-    # Index maps receive the scalar-prefetch refs as trailing args: the
-    # block-table lookup happens HERE, steering each grid step's DMA to
-    # its pool block.  Steps beyond the frontier clamp to the frontier
-    # block (same id -> the pipeline elides the refetch) and are
-    # compute-predicated off in the kernel, exactly like the dense kernel.
-    qspec = pl.BlockSpec(
-        (1, kv_heads, g_pad, d), lambda s, j, t, p: (s, 0, 0, 0),
-        memory_space=pltpu.VMEM,
-    )
-
-    def block_id(s, j, t, p):
-        return t[s, jnp.minimum(j, p[s] // block_size)]
-
-    kvspec = pl.BlockSpec(
-        (1, block_size, width),
-        lambda s, j, t, p: (block_id(s, j, t, p), 0, 0),
-        memory_space=pltpu.VMEM,
-    )
-    in_specs = [qspec, kvspec, kvspec]
-    inputs = [qg, k_pool, v_pool]
-    if quantized:
-        # A (1, kv_heads) row is not a legal TPU block (sublane dim must
-        # be a multiple of 8 or the whole axis): DMA the 8-row group that
-        # holds the block's row; the kernel selects the row by mask.
-        sspec = pl.BlockSpec(
-            (SUBLANES, kv_heads),
-            lambda s, j, t, p: (block_id(s, j, t, p) // SUBLANES, 0),
-            memory_space=pltpu.VMEM,
-        )
-        in_specs += [sspec, sspec]
-        inputs += [k_scale, v_scale]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(slots, nbs),
-        in_specs=in_specs,
-        out_specs=qspec,
-        scratch_shapes=[
-            pltpu.VMEM((kv_heads, g_pad, d), jnp.float32),      # accumulator
-            pltpu.VMEM((kv_heads, g_pad, LANES), jnp.float32),  # running max
-            pltpu.VMEM((kv_heads, g_pad, LANES), jnp.float32),  # denominator
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((slots, kv_heads, g_pad, d), q.dtype),
-        interpret=interpret,
-        name="paged_decode_attention",
-    )(tables, pos_arr, *inputs)
-    return out[:, :, :group, :].reshape(slots, num_heads, d)
 
 
 @jax.named_scope("decode_attn")
@@ -475,11 +585,7 @@ def xla_rows_attention(q, k_rows, v_rows, visible):
     batch, heads, queries, d = q.shape
     keys, width = k_rows.shape[1:]
     kv_heads = width // d
-    # owner[h, k]: query head h reads kv head k (GQA groups are contiguous).
-    owner = (
-        jnp.arange(heads)[:, None] // (heads // kv_heads)
-        == jnp.arange(kv_heads)[None, :]
-    ).astype(q.dtype)
+    owner = _head_owner(heads, kv_heads).astype(q.dtype)
     q_cols = jnp.einsum("bhqd,hk->bkdhq", q, owner).reshape(
         batch, width, heads * queries
     )

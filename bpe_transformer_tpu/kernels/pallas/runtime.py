@@ -71,6 +71,48 @@ def attention_path(seq_len: int, d_head: int, backend: str | None = None) -> str
     return "xla"
 
 
+#: Keys a step of the paged decode kernel copies and computes on, and what
+#: its four VMEM buffers (K and V, two each) may hold together.
+PAGED_GROUP_KEYS = 256
+PAGED_BUFFER_BYTES = 4 * 1024 * 1024
+
+
+def paged_group_blocks(block_size: int, width: int, itemsize: int) -> int:
+    """Pool blocks to a step of `paged_decode_attention`: enough for
+    :data:`PAGED_GROUP_KEYS` keys (a step's fixed cost is spread over them,
+    and a chain is walked in whole groups, so a larger group computes on
+    more dead rows of short chains), fewer where four buffers of
+    ``width``-wide rows would pass :data:`PAGED_BUFFER_BYTES`; at least
+    one."""
+    fit = PAGED_BUFFER_BYTES // (4 * block_size * width * itemsize)
+    return max(1, min(PAGED_GROUP_KEYS // block_size, fit))
+
+
+def decode_attention_path(
+    one_row: bool, blocks_per_slot: int, block_size: int, width: int,
+    itemsize: int, backend: str | None = None,
+) -> str:
+    """``"paged"`` or ``"xla"`` for a step's attention over a dense block
+    pool (`models/decode.DenseRows`): the paged-native kernel, which reads
+    the blocks the slots hold and no others, on the TPU for one row a slot
+    (the decode tick) where the pool's rows are whole lane tiles, its
+    blocks whole sublane tiles at either width, and the table at least one
+    group wide; gathered rows under XLA elsewhere - several rows a slot (a
+    verify pass), tables shorter than a group (tiny contexts), unaligned
+    test shapes, and every other backend, where the kernel would run in
+    interpret mode."""
+    backend = backend or jax.default_backend()
+    if (
+        backend == "tpu"
+        and one_row
+        and width % 128 == 0
+        and block_size % 16 == 0
+        and blocks_per_slot >= paged_group_blocks(block_size, width, itemsize)
+    ):
+        return "paged"
+    return "xla"
+
+
 def interpret_mode() -> bool:
     """Pallas TPU kernels run in interpret mode on non-TPU backends
     (CPU tests, debugging); compiled Mosaic otherwise."""

@@ -105,16 +105,20 @@ class ModelConfig:
     attention_impl: str = "auto"
     # "xla" | "pallas" (fused SwiGLU kernel; swiglu FFNs only)
     ffn_impl: str = "xla"
-    #: Decode-step attention against the KV cache: "xla" (grouped einsum,
-    #: materialized scores) | "pallas" (flash-decoding streamed reduction,
-    #: kernels/pallas/decode_attention.py) | "paged" (paged-NATIVE flash
-    #: decode: the block table is consumed inside the kernel's index maps,
-    #: so the serving tick reads K/V straight out of the block pool with no
-    #: contiguous gather transient; only meaningful with the paged serving
-    #: engine — the dense cache has no block table, so dense decode treats
-    #: it as "pallas").  Inference-only knob — the training attention path
-    #: is attention_impl.
-    decode_attention_impl: str = "xla"
+    #: Decode-step attention against the KV cache.  ``"auto"``, the
+    #: default: the paged serving engine's one-row tick over a dense block
+    #: pool takes what `kernels.pallas.runtime.decode_attention_path`
+    #: chooses from the shape and the backend - on the TPU the paged-native
+    #: kernel, which reads the blocks the slots hold straight out of the
+    #: pool, elsewhere gathered rows under XLA; the dense cache's
+    #: `decode_step` takes "xla".  The other values FORCE a path (parity
+    #: tests, benchmarks): ``"xla"`` (materialized scores) | ``"paged"``
+    #: (the paged-native kernel; the dense cache has no block table and
+    #: treats it as "pallas") | ``"pallas"`` (the contiguous flash-decoding
+    #: kernel of the dense cache, kernels/pallas/decode_attention.py; a
+    #: block pool has no such kernel and takes it as "auto").
+    #: Inference-only - the training attention path is attention_impl.
+    decode_attention_impl: str = "auto"
     #: q/k tile of the ring-flash schedules (`parallel/sp.py`, the only
     #: reader: its per-device shards are not the sequence, and tiny in
     #: tests).  Every other flash call takes its tiles from the shape
@@ -317,10 +321,10 @@ class ModelConfig:
             raise ValueError(
                 f'moe_dispatch={self.moe_dispatch!r} must be "einsum" or "gather"'
             )
-        if self.decode_attention_impl not in ("xla", "pallas", "paged"):
+        if self.decode_attention_impl not in ("auto", "xla", "pallas", "paged"):
             raise ValueError(
                 f"decode_attention_impl={self.decode_attention_impl!r} "
-                'must be "xla", "pallas" or "paged"'
+                'must be "auto", "xla", "pallas" or "paged"'
             )
         if self.ffn_type == "moe" and not (
             1 <= self.router_top_k <= self.n_experts

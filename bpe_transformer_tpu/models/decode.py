@@ -412,12 +412,13 @@ def gather_paged_rows(
     d_head) gathered by ``tables`` (slots, blocks_per_slot) -> (slots,
     blocks_per_slot * block_size, kv_heads * d_head), a key position a row.
 
-    This one gather is the XLA read path of the paged pool: the decode
-    tick and the verify pass attend over the rows as they are
+    This one gather is the XLA read path of the paged pool: the verify
+    pass, and the decode tick where the paged-native kernel is not chosen
+    (`DenseRows.attention_path`), attend over the rows as they are
     (`xla_rows_attention`), so nothing as large as the gathered chains is
-    ever re-laid out.  The buffer is transient (one layer at a time) —
-    only the block pool is resident, which is where paging's memory win
-    lives.
+    ever re-laid out.  It reads every slot's whole table row whatever the
+    slot holds.  The buffer is transient (one layer at a time) — only the
+    block pool is resident, which is where paging's memory win lives.
 
     An int8 pool passes its per-block-per-head ``scale`` pool
     ``(num_blocks, kv_heads)`` and the ``dtype`` to dequantize to: the
@@ -441,9 +442,9 @@ def gather_paged_kv(
 ) -> Array:
     """:func:`gather_paged_rows` with the heads split out: (slots, kv_heads,
     blocks_per_slot * block_size, d_head), layout-identical to the dense
-    cache, for the readers that want that (chunked prefill's one slot, the
-    contiguous Pallas flash-decoding kernel).  The split is a transpose of
-    the gathered transient, never of the pool."""
+    cache, for the reader that wants that (chunked prefill's one slot).
+    The split is a transpose of the gathered transient, never of the
+    pool."""
     rows = gather_paged_rows(buf, tables, scale, dtype)
     s, keys, width = rows.shape
     with jax.named_scope("pool_gather"):
@@ -609,9 +610,16 @@ class DenseRows:
     rescale-on-grow one after another (:func:`_quantize_decode_row`, the
     write order of as many plain ticks; the readers of a several-row pass
     see each block's FINAL scale, so its int8 logits match plain ticks
-    within quantization error, not bitwise), and every slot's chain, as
-    large as the pool, is attended as rows (`xla_rows_attention`).
-    ``config.decode_attention_impl`` governs the one-row step alone."""
+    within quantization error, not bitwise).
+
+    How the rows of a tick or a verify pass attend is a choice of the shape
+    and the backend (:meth:`attention_path`): the one-row tick on the TPU
+    reads the pool in place through the paged-native kernel, which copies
+    the blocks a slot holds and no others (idle slots none); elsewhere, and
+    for several rows a slot, every slot's whole table is gathered, as large
+    as the pool, and attended as rows (`xla_rows_attention`).
+    ``config.decode_attention_impl`` forces ``"xla"`` or ``"paged"`` on the
+    one-row step (parity tests); no other step asks it."""
 
     #: Every row goes through the FFN, and a MoE layer's counts are dropped.
     ffn_rows = tally = None
@@ -664,9 +672,28 @@ class DenseRows:
             (arr, scale), tuple(jnp.swapaxes(a, 0, 1) for a in (rows, *at)),
         )[0]
 
+    @staticmethod
+    def attention_path(config, one_row: bool, blocks_per_slot: int, layer_pool) -> str:
+        """``"paged"`` or ``"xla"``: how the rows of a tick (``one_row``) or
+        a verify pass attend over this pool.
+        `runtime.decode_attention_path` chooses from the shape and the
+        backend unless the config forces the one-row step's path (the dense
+        cache's ``"pallas"`` kernel has no block table: here it is the
+        choice too)."""
+        from bpe_transformer_tpu.kernels.pallas.runtime import (
+            decode_attention_path,
+        )
+
+        if one_row and config.decode_attention_impl in ("xla", "paged"):
+            return config.decode_attention_impl
+        _, block_size, width = layer_pool["k"].shape
+        return decode_attention_path(
+            one_row, blocks_per_slot, block_size, width,
+            layer_pool["k"].dtype.itemsize,
+        )
+
     def attend(self, layer, q, layer_pool):
         from bpe_transformer_tpu.kernels.pallas.decode_attention import (
-            decode_attention,
             paged_decode_attention,
             xla_rows_attention,
         )
@@ -675,7 +702,6 @@ class DenseRows:
         kv_heads = config.num_kv_heads or config.num_heads
         k_pool, v_pool = layer_pool["k"], layer_pool["v"]
         k_scale, v_scale = layer_pool.get("k_scale"), layer_pool.get("v_scale")
-        impl = config.decode_attention_impl if self.one_row else "xla"
         if self.chunk:
             # One slot's chain, heads split out: an activation-sized
             # transpose (the scores are per head).
@@ -693,22 +719,17 @@ class DenseRows:
                 att = jnp.einsum(
                     "bhqk,bhkd->bhqd", probs, _expand_kv(v_cache, config)
                 )
-        elif impl == "paged":
-            # The paged-NATIVE flash kernel, straight against the pool: the
-            # block table is consumed inside its index maps, int8 blocks
-            # dequantize in registers, no contiguous transient.
+        elif self.attention_path(
+            config, self.one_row, tables.shape[-1], layer_pool
+        ) == "paged":
+            # Straight out of the pool: each slot's live blocks, a group a
+            # step; a slot that is not valid holds no key and copies none.
+            key_counts = self.positions + 1
+            if self.valid is not None:
+                key_counts = jnp.where(self.valid, key_counts, 0)
             att = paged_decode_attention(
-                q[:, :, 0], k_pool, v_pool, tables, self.positions,
+                q[:, :, 0], k_pool, v_pool, tables, key_counts,
                 k_scale=k_scale, v_scale=v_scale,
-            )[:, :, None, :]
-        elif impl == "pallas":
-            # The contiguous flash-decoding kernel wants the dense cache's
-            # layout: the heads split out of the gathered rows.
-            att = decode_attention(
-                q[:, :, 0],
-                gather_paged_kv(k_pool, tables, kv_heads, k_scale, q.dtype),
-                gather_paged_kv(v_pool, tables, kv_heads, v_scale, q.dtype),
-                self.positions,
             )[:, :, None, :]
         else:
             att = xla_rows_attention(
@@ -815,6 +836,12 @@ class GroupedPages:
             addresses(config.layer_window(layer))
             for layer in range(config.num_layers)
         ]
+
+    @staticmethod
+    def attention_path(config, one_row: bool, blocks_per_slot: int, layer_pool) -> str:
+        """``"ragged"``, JAX's ragged paged kernel, on the TPU; ``"xla"``,
+        its stand-in, elsewhere (`kernels/pallas/ragged_attention.py`)."""
+        return "ragged" if jax.default_backend() == "tpu" else "xla"
 
     @jax.named_scope("pool_write")
     def write(self, layer, pages, k, v):

@@ -420,12 +420,22 @@ class PagedEngine:
         # first position is the slot's base.
         self._window_tables = np.zeros((slots, max(self.window_cap, 1)), np.int32)
         self._window_base = np.zeros(slots, np.int32)
-        #: What the attention of the grouped programs needs, summed over
-        #: layers, counted here from the positions (no device read):
-        #: visible (query, key) pairs and distinct KV positions to stream,
-        #: a window layer's capped by its window.
+        #: What the attention of the ticks (and, over window pool groups,
+        #: of the chunks) needs, summed over layers, counted here from the
+        #: positions (no device read): visible (query, key) pairs and
+        #: distinct KV positions to stream, a window layer's capped by its
+        #: window.
         self.attn_pairs = 0
         self.attn_kv_positions = 0
+        #: Key positions the ticks' live slots held, and key positions
+        #: their tables address (every slot's whole row, what a gather
+        #: through the table reads): `tick_live_key_share`.
+        self.tick_live_keys = 0
+        self.tick_table_keys = 0
+        #: How the tick's rows attend: the cache kind's choice.
+        self.tick_attention_path = cache_kind(config).attention_path(
+            config, True, self.blocks_per_slot, self._pool[0]
+        )
         self._window_layers = sum(
             config.layer_window(layer) is not None
             for layer in range(config.num_layers)
@@ -592,6 +602,11 @@ class PagedEngine:
         out["kv_window_blocks_recycled"] = self._window_recycled
         out["attn_pairs"] = self.attn_pairs
         out["attn_kv_positions"] = self.attn_kv_positions
+        out["tick_attention_path"] = self.tick_attention_path
+        out["tick_live_key_share"] = (
+            100.0 * self.tick_live_keys / self.tick_table_keys
+            if self.tick_table_keys else None
+        )
         out["moe_tokens_routed"] = int(self.moe_counts[0])
         out["moe_rows_local"] = int(self.moe_counts[1])
         out["moe_expert_groups"] = int(self.moe_counts[2])
@@ -1313,20 +1328,24 @@ class PagedEngine:
         if not self._active.any():
             return []
         with Phase("serve/tick_dispatch", self.clock) as dispatch:
+            window = self.config.sliding_window
             if self.grouped:
-                window = self.config.sliding_window
                 for slot in np.flatnonzero(self._active):
                     self._advance_window(
                         int(slot), int(self._positions[slot]) - window + 1
                     )
-                # One query a live slot: pairs and KV positions are alike.
-                seen = self._positions[self._active].astype(np.int64) + 1
-                keys_read = int(
-                    (self.config.num_layers - self._window_layers) * seen.sum()
-                    + self._window_layers * np.minimum(seen, window).sum()
+            # One query a live slot: pairs and KV positions are alike.
+            seen = self._positions[self._active].astype(np.int64) + 1
+            live = int(seen.sum())
+            keys_read = (self.config.num_layers - self._window_layers) * live
+            if self.grouped:
+                keys_read += self._window_layers * int(
+                    np.minimum(seen, window).sum()
                 )
-                self.attn_pairs += keys_read
-                self.attn_kv_positions += keys_read
+            self.attn_pairs += keys_read
+            self.attn_kv_positions += keys_read
+            self.tick_live_keys += live
+            self.tick_table_keys += self._tables.size * self.block_size
             tokens, positions, keys, _, moe = self._in_place(
                 "tick", self._tick_jit,
                 self._params, self._lm_head, self._pool, self._moe_pending,

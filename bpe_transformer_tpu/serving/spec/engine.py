@@ -49,7 +49,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from bpe_transformer_tpu.models.config import ModelConfig
-from bpe_transformer_tpu.models.decode import paged_forward, slot_cache
+from bpe_transformer_tpu.models.decode import (
+    cache_kind,
+    paged_forward,
+    slot_cache,
+)
 from bpe_transformer_tpu.serving.engine import (
     SlotPoolEngine,
     TickEvent,
@@ -254,6 +258,10 @@ class SpecEngine(PagedEngine):
             )
         super().__init__(params, config, min_bucket=min_bucket, **paged_kwargs)
         self._refuse_grouped("speculative decoding (its verify pass rewinds)")
+        # This engine's tick is the verify pass: several rows a slot.
+        self.tick_attention_path = cache_kind(config).attention_path(
+            config, False, self.blocks_per_slot, self._pool[0]
+        )
         if isinstance(draft, DraftSpec):
             # Build the draft from the engine's COMPUTE-DTYPE params: a
             # truncated view then shares the very arrays the target runs

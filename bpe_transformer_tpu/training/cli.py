@@ -69,7 +69,7 @@ def _load_model_config(args, stored: dict | None = None) -> ModelConfig:
             cfg,
             attention_impl="auto",
             ffn_impl="xla",
-            decode_attention_impl="xla",
+            decode_attention_impl="auto",
             remat=False,
             remat_policy="none",
             scan_layers=False,
@@ -1648,12 +1648,13 @@ def build_parser() -> argparse.ArgumentParser:
                    "blocks at fixed memory")
     p.add_argument("--decode-attention",
                    choices=("xla", "pallas", "paged"), default=None,
-                   help="decode-step attention: 'paged' (with --paged) is "
-                   "the block-pool-native flash kernel — the block table "
-                   "is consumed inside the kernel's index maps, deleting "
-                   "the per-tick contiguous KV gather; 'pallas' is flash "
-                   "decode over the gathered cache; default: checkpoint "
-                   "config (xla)")
+                   help="force the decode-step attention: 'paged' (with "
+                   "--paged) is the block-pool-native flash kernel, which "
+                   "reads the blocks the slots hold straight out of the "
+                   "pool; 'xla' gathers the tables' rows; 'pallas' is the "
+                   "dense cache's flash decode (a block pool takes the "
+                   "default); default: chosen from the shape and the "
+                   "backend (the kernel on the TPU)")
     p.add_argument("--weight-dtype", choices=("act", "int8"), default="act",
                    help="serving weight storage width: 'int8' quantizes "
                    "the matmul weights per output channel at engine build "

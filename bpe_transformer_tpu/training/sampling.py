@@ -81,7 +81,7 @@ def generate_ids(
 
     # Sliding-window fallback (prompt + continuation exceed the context
     # window): full forward per token.
-    if config.decode_attention_impl != "xla":
+    if config.decode_attention_impl not in ("auto", "xla"):
         import sys
 
         print(

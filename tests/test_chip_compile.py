@@ -251,10 +251,9 @@ def test_decode_attention(one_chip, heads, d_head):
     )
 
 
-@pytest.mark.parametrize("block_size", [16, 32, 128])
-@pytest.mark.parametrize("kv_dtype", [BF16, I8], ids=["bf16", "int8"])
-def test_paged_decode_attention(one_chip, kv_dtype, block_size):
-    slots, heads, d_head, num_blocks = 8, 12, 64, 515
+def _paged_attention_args(slots, heads, d_head, block_size, kv_dtype, num_blocks):
+    """``(fn, shapes)`` of one `paged_decode_attention` call over a pool of
+    ``num_blocks`` blocks and a table one context (1,024 keys) wide."""
     pool = ((num_blocks, block_size, heads * d_head), kv_dtype)
     shapes = [
         ((slots, heads, d_head), BF16), pool, pool,
@@ -263,17 +262,41 @@ def test_paged_decode_attention(one_chip, kv_dtype, block_size):
     if kv_dtype == I8:
         shapes += [((num_blocks, heads), F32)] * 2
 
-        def fn(q, k, v, tables, pos, ks, vs):
+        def fn(q, k, v, tables, counts, ks, vs):
             return paged_decode_attention(
-                q, k, v, tables, pos, k_scale=ks, v_scale=vs, interpret=False
+                q, k, v, tables, counts, k_scale=ks, v_scale=vs,
+                interpret=False,
             )
     else:
 
-        def fn(q, k, v, tables, pos):
+        def fn(q, k, v, tables, counts):
             return paged_decode_attention(
-                q, k, v, tables, pos, interpret=False
+                q, k, v, tables, counts, interpret=False
             )
 
+    return fn, shapes
+
+
+@pytest.mark.parametrize("block_size", [16, 32, 128])
+@pytest.mark.parametrize("kv_dtype", [BF16, I8], ids=["bf16", "int8"])
+def test_paged_decode_attention(one_chip, kv_dtype, block_size):
+    fn, shapes = _paged_attention_args(8, 12, 64, block_size, kv_dtype, 515)
+    _compile(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("kv_dtype", [BF16, I8], ids=["bf16", "int8"])
+@pytest.mark.parametrize(
+    "slots,heads", [(128, 12), (64, 16)],
+    ids=["small.serve.decode-heavy", "medium.serve.prefill-heavy"],
+)
+def test_paged_decode_attention_at_the_cells_shapes(
+    one_chip, slots, heads, kv_dtype
+):
+    """The serve cells' own ticks: 128 slots x 64 blocks of 16 x 768 and 64
+    x 64 of 16 x 1,024, over a pool that holds every slot's whole table."""
+    fn, shapes = _paged_attention_args(
+        slots, heads, 64, 16, kv_dtype, slots * 64 + 1
+    )
     _compile(fn, one_chip, *shapes)
 
 
@@ -297,10 +320,14 @@ def _described(tree, one_chip):
     )
 
 
-def _pool_program(name, config, one_chip, kv_dtype, layers_as_calls=True):
+def _pool_program(
+    name, config, one_chip, kv_dtype, layers_as_calls=True,
+    slots=POOL_SLOTS, blocks=POOL_BLOCKS,
+):
     """``(jitted program, its arguments described on the chip, the pool)``
     for one of the engine's pool programs, jitted as `PagedEngine` jits it
-    on the TPU (``layers_as_calls=False``: without the compiler option)."""
+    on the TPU (``layers_as_calls=False``: without the compiler option),
+    over ``slots`` slots and a pool of ``blocks`` blocks."""
     import functools
 
     from bpe_transformer_tpu.utils.compile_cache import layered_program_options
@@ -312,7 +339,7 @@ def _pool_program(name, config, one_chip, kv_dtype, layers_as_calls=True):
     from bpe_transformer_tpu.serving.engine import prepare_serving_weights
     from bpe_transformer_tpu.serving.kvpool import paged_engine as pe
 
-    slots, bs = POOL_SLOTS, POOL_BLOCK
+    bs = POOL_BLOCK
     nbs = config.context_length // bs
 
     def weights():
@@ -322,9 +349,7 @@ def _pool_program(name, config, one_chip, kv_dtype, layers_as_calls=True):
     params, lm_head = _described(jax.eval_shape(weights), one_chip)
     pool = _described(
         jax.eval_shape(
-            lambda: init_kv_pool(
-                config, POOL_BLOCKS, bs, BF16, kv_dtype=kv_dtype
-            )
+            lambda: init_kv_pool(config, blocks, bs, BF16, kv_dtype=kv_dtype)
         ),
         one_chip,
     )
@@ -426,14 +451,53 @@ def test_pool_programs_hold_no_pool_copy(one_chip, name, kv_dtype):
         assert memory.temp_size_in_bytes < kv_bytes // (2 * config.num_layers)
 
 
-def test_pool_programs_compile_their_layers_as_calls(one_chip):
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The program's own choices as it makes them on the chip (they ask
+    `jax.default_backend()`, which says "cpu" beside a described device):
+    the dense tick takes the paged-native kernel, compiled by Mosaic."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["act", "int8"])
+def test_the_tick_reads_the_pool_in_place(one_chip, on_tpu, kv_dtype):
+    """The tick at the small cell's 128 slots (two layers): the pool is
+    aliased whole and never copied, nothing of gathered-rows shape exists -
+    neither the table's blocks ``[8192,16,768]`` nor its rows
+    ``[128,1024,768]`` at any width - the kernel is there once a layer, and
+    the temporaries stay under 100 MB where the gathered K and V were 201
+    MB each."""
+    import dataclasses
+    import re
+
+    config = dataclasses.replace(GPT2_SMALL_32K, num_layers=2)
+    jitted, args, pool = _pool_program(
+        "tick", config, one_chip, kv_dtype, slots=128, blocks=128 * 64 + 1
+    )
+    compiled = jitted.lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= config.num_layers
+    assert "paged_decode_attention" in text
+    leaves = jax.tree_util.tree_leaves(pool)
+    assert _pool_copies(text, {_shape_text(a) for a in leaves if a.ndim == 3}) == []
+    gathered = re.compile(r"\[(8192,16|128,64,16|128,1024),768\]")
+    assert [line[:160] for line in text.splitlines() if gathered.search(line)] == []
+    memory = compiled.memory_analysis()
+    kv_bytes = sum(a.size * a.dtype.itemsize for a in leaves if a.ndim == 3)
+    assert memory.alias_size_in_bytes >= kv_bytes
+    assert memory.temp_size_in_bytes < 100e6
+
+
+def test_pool_programs_compile_their_layers_as_calls(one_chip, monkeypatch):
     """With one pool alive the chip has memory to spare, and XLA then
     writes every layer's code out: gpt2-medium's tick went 20 -> 78 MB and
     its 1,024-token chunk program 26 -> 126 MB, which a 192 MiB compile
     cache cannot keep, so every start compiled cold (PERF.md §6 PR 30).
     `layered_program_options` asks for the layers as calls; at two layers
     the executable is already a third smaller, and the gap grows with the
-    depth."""
+    depth.  The tick over the paged-native kernel (the TPU's choice, PR 32)
+    is no larger than the tick over gathered rows: a Mosaic call a layer
+    costs less text than the gather and the rows attention it replaces."""
     import dataclasses
 
     from jax.experimental import serialize_executable
@@ -445,6 +509,12 @@ def test_pool_programs_compile_their_layers_as_calls(one_chip):
         compiled = jitted.lower(*args).compile()
         size[as_calls] = len(serialize_executable.serialize(compiled)[0])
     assert size[True] < 0.8 * size[False], size
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jitted, args, _ = _pool_program("tick", config, one_chip, None)
+    compiled = jitted.lower(*args).compile()
+    assert "paged_decode_attention" in compiled.as_text()
+    size["paged"] = len(serialize_executable.serialize(compiled)[0])
+    assert size["paged"] <= 1.02 * size[True], size
 
 
 def test_a_four_dimensional_pool_would_be_copied(one_chip):

@@ -737,9 +737,9 @@ def _per_head(scale, d):
 def test_paged_decode_attention_matches_gathered_xla(
     slots, heads, kv_heads, block_size, nbs, d
 ):
-    """The paged-NATIVE kernel (block table consumed in the index maps)
-    equals the gather-then-attend reference at ragged per-slot frontiers
-    — including slots parked on the trash block (inactive)."""
+    """The paged-NATIVE kernel (pool blocks copied through the table, a
+    slot's live ones) equals the gather-then-attend reference at ragged
+    per-slot key counts."""
     from bpe_transformer_tpu.kernels.pallas.decode_attention import (
         paged_decode_attention,
         xla_decode_attention,
@@ -760,13 +760,127 @@ def test_paged_decode_attention_matches_gathered_xla(
     )[:slots]
     q = jnp.asarray(rng.standard_normal((slots, heads, d)).astype(np.float32))
 
-    out = paged_decode_attention(q, k_pool, v_pool, tables, pos,
+    out = paged_decode_attention(q, k_pool, v_pool, tables, pos + 1,
                                  interpret=True)
     ref = xla_decode_attention(
         q, gather_paged_kv(k_pool, tables, kv_heads),
         gather_paged_kv(v_pool, tables, kv_heads), pos,
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+def _quantized_pool(rng, num_blocks, kv_heads, block_size, d):
+    """``(int8 pool, per-block-per-head scales)`` of random rows."""
+    rows = _paged_pool(rng, num_blocks, kv_heads, block_size, d)
+    scale = jnp.asarray(
+        (np.abs(rng.standard_normal((num_blocks, kv_heads))) / 40 + 0.01)
+        .astype(np.float32)
+    )
+    quantized = jnp.clip(jnp.round(rows / _per_head(scale, d)), -127, 127)
+    return quantized.astype(jnp.int8), scale
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize(
+    "heads,kv_heads,d",
+    [(12, 12, 64), (16, 16, 64), (8, 2, 64)],
+    ids=["small-12x64", "medium-16x64", "gqa-8over2"],
+)
+def test_paged_decode_attention_walks_ragged_chains(heads, kv_heads, d, kv_dtype):
+    """One call over slots whose chains end everywhere a walk by groups can
+    go wrong - no key at all (an idle slot: zeros, nothing copied), one key,
+    one short of a block's edge, exactly on it, one group and a key, the
+    whole table - at the cells' head shapes and a GQA one, over the
+    activation-width pool and the int8 one: the XLA rows path's numbers
+    (`xla_rows_attention` over `gather_paged_rows`, which is what the tick
+    computes where the kernel is not chosen)."""
+    from bpe_transformer_tpu.kernels.pallas.decode_attention import (
+        paged_decode_attention,
+        xla_rows_attention,
+    )
+    from bpe_transformer_tpu.kernels.pallas.runtime import paged_group_blocks
+    from bpe_transformer_tpu.models.decode import gather_paged_rows
+
+    rng = np.random.default_rng(heads * 10 + kv_heads)
+    block_size, width = 16, kv_heads * d
+    act = jnp.bfloat16
+    group = paged_group_blocks(block_size, width, 1 if kv_dtype == "int8" else 2)
+    nbs = group + 4
+    group_keys = group * block_size
+    counts = jnp.asarray(
+        [0, 1, 3 * block_size - 1, 3 * block_size, group_keys + 1,
+         nbs * block_size],
+        jnp.int32,
+    )
+    slots = counts.shape[0]
+    num_blocks = slots * nbs + 1
+    tables = jnp.asarray(
+        rng.permutation(np.arange(1, num_blocks)).reshape(slots, nbs), jnp.int32
+    )
+    q = jnp.asarray(rng.standard_normal((slots, heads, d)), act)
+    if kv_dtype == "int8":
+        k_pool, k_scale = _quantized_pool(rng, num_blocks, kv_heads, block_size, d)
+        v_pool, v_scale = _quantized_pool(rng, num_blocks, kv_heads, block_size, d)
+    else:
+        k_pool = _paged_pool(rng, num_blocks, kv_heads, block_size, d).astype(act)
+        v_pool = _paged_pool(rng, num_blocks, kv_heads, block_size, d).astype(act)
+        k_scale = v_scale = None
+
+    out = paged_decode_attention(
+        q, k_pool, v_pool, tables, counts, k_scale=k_scale, v_scale=v_scale,
+        interpret=True,
+    )
+    assert out.shape == (slots, heads, d) and out.dtype == act
+    visible = jnp.arange(nbs * block_size)[None, None, :] < counts[:, None, None]
+    ref = xla_rows_attention(
+        q[:, :, None],
+        gather_paged_rows(k_pool, tables, k_scale, act),
+        gather_paged_rows(v_pool, tables, v_scale, act),
+        visible,
+    )[:, :, 0]
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    assert not out[0].any()  # the idle slot: zeros, whatever its table says
+    # Both sides round probabilities and outputs to bfloat16.
+    np.testing.assert_allclose(out[1:], ref[1:], atol=4e-2)
+
+
+@pytest.mark.parametrize(
+    "one_row,blocks_per_slot,block_size,width,itemsize,backend,expected",
+    [
+        (True, 64, 16, 768, 2, "tpu", "paged"),    # small.serve.decode-heavy
+        (True, 64, 16, 1024, 2, "tpu", "paged"),   # medium.serve.prefill-heavy
+        (True, 64, 16, 768, 1, "tpu", "paged"),    # the int8 pool
+        (False, 64, 16, 768, 2, "tpu", "xla"),     # a verify pass
+        (True, 64, 16, 768, 2, "cpu", "xla"),      # interpret mode elsewhere
+        (True, 4, 16, 768, 2, "tpu", "xla"),       # a table under one group
+        (True, 64, 8, 768, 2, "tpu", "xla"),       # blocks under a bf16 tile
+        (True, 64, 16, 192, 2, "tpu", "xla"),      # rows off the lane width
+    ],
+)
+def test_decode_attention_path_follows_shape_and_backend(
+    one_row, blocks_per_slot, block_size, width, itemsize, backend, expected
+):
+    from bpe_transformer_tpu.kernels.pallas.runtime import decode_attention_path
+
+    assert decode_attention_path(
+        one_row, blocks_per_slot, block_size, width, itemsize, backend
+    ) == expected
+
+
+@pytest.mark.parametrize(
+    "block_size,width,itemsize,expected",
+    [
+        (16, 768, 2, 16),     # gpt2-small-32k: 256 keys, 4 x 384 KB
+        (16, 1024, 2, 16),    # gpt2-medium: 4 x 512 KB
+        (128, 1024, 2, 2),    # 256 keys are two blocks
+        (16, 8192, 4, 2),     # wide float32 rows: the buffers' 4 MB bound
+        (512, 8192, 4, 1),    # never under one block
+    ],
+)
+def test_paged_group_blocks(block_size, width, itemsize, expected):
+    from bpe_transformer_tpu.kernels.pallas.runtime import paged_group_blocks
+
+    assert paged_group_blocks(block_size, width, itemsize) == expected
 
 
 @pytest.mark.parametrize(
@@ -834,20 +948,8 @@ def test_paged_decode_attention_int8_matches_dequant_reference():
     rng = np.random.default_rng(11)
     slots, heads, kv_heads, block_size, nbs, d = 2, 8, 4, 8, 4, 16
     num_blocks = slots * nbs + 1
-    kf = _paged_pool(rng, num_blocks, kv_heads, block_size, d)
-    vf = _paged_pool(rng, num_blocks, kv_heads, block_size, d)
-    k_scale = jnp.asarray(
-        (np.abs(rng.standard_normal((num_blocks, kv_heads))) / 40 + 0.01)
-        .astype(np.float32)
-    )
-    v_scale = jnp.asarray(
-        (np.abs(rng.standard_normal((num_blocks, kv_heads))) / 40 + 0.01)
-        .astype(np.float32)
-    )
-    k_per_head = _per_head(k_scale, d)
-    v_per_head = _per_head(v_scale, d)
-    kq = jnp.clip(jnp.round(kf / k_per_head), -127, 127).astype(jnp.int8)
-    vq = jnp.clip(jnp.round(vf / v_per_head), -127, 127).astype(jnp.int8)
+    kq, k_scale = _quantized_pool(rng, num_blocks, kv_heads, block_size, d)
+    vq, v_scale = _quantized_pool(rng, num_blocks, kv_heads, block_size, d)
     tables = jnp.asarray(
         rng.permutation(np.arange(1, num_blocks)).reshape(slots, nbs),
         jnp.int32,
@@ -856,11 +958,11 @@ def test_paged_decode_attention_int8_matches_dequant_reference():
     q = jnp.asarray(rng.standard_normal((slots, heads, d)).astype(np.float32))
 
     out = paged_decode_attention(
-        q, kq, vq, tables, pos, k_scale=k_scale, v_scale=v_scale,
+        q, kq, vq, tables, pos + 1, k_scale=k_scale, v_scale=v_scale,
         interpret=True,
     )
-    kd = kq.astype(jnp.float32) * k_per_head
-    vd = vq.astype(jnp.float32) * v_per_head
+    kd = kq.astype(jnp.float32) * _per_head(k_scale, d)
+    vd = vq.astype(jnp.float32) * _per_head(v_scale, d)
     ref = xla_decode_attention(
         q, gather_paged_kv(kd, tables, kv_heads),
         gather_paged_kv(vd, tables, kv_heads), pos,
@@ -869,9 +971,9 @@ def test_paged_decode_attention_int8_matches_dequant_reference():
 
 
 def test_paged_decode_attention_single_compile_across_state():
-    """tables/pos ride scalar prefetch: one jitted program serves every
-    table layout and frontier (the paged tick's bounded-compile
-    contract)."""
+    """tables and key counts ride scalar prefetch: one jitted program
+    serves every table layout and chain length (the paged tick's
+    bounded-compile contract)."""
     from bpe_transformer_tpu.kernels.pallas.decode_attention import (
         paged_decode_attention,
         xla_decode_attention,
@@ -896,7 +998,7 @@ def test_paged_decode_attention_single_compile_across_state():
             jnp.int32,
         )
         pos = jnp.asarray(r2.integers(0, nbs * block_size, slots), jnp.int32)
-        out = f(q, k_pool, v_pool, tables, pos)
+        out = f(q, k_pool, v_pool, tables, pos + 1)
         ref = xla_decode_attention(
             q, gather_paged_kv(k_pool, tables, kv_heads),
             gather_paged_kv(v_pool, tables, kv_heads), pos,

@@ -625,6 +625,81 @@ def test_paged_native_bounded_compilation(native_engine, int8_engine):
     assert int8_engine.compiled_programs() <= len(int8_engine.buckets) + 1
 
 
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["act", "int8"])
+def test_paged_tick_with_idle_slots_gives_the_xla_paths_tokens(setup, kv_dtype):
+    """Four slots, two of them never admitted (no key, nothing copied, a
+    row of zeros the tick discards) and two at ragged depths: greedy ticks
+    through the paged-native kernel emit what the gathered-rows path
+    emits, and each engine says which path it took."""
+    params, prompts = setup
+    streams = {}
+    for impl in ("xla", "paged"):
+        cfg = dataclasses.replace(CFG, decode_attention_impl=impl)
+        eng = PagedEngine(
+            params, cfg, slots=4, block_size=8, min_bucket=8, kv_dtype=kv_dtype
+        )
+        assert eng.tick_attention_path == impl
+        first = [
+            eng.admit(p, max_new_tokens=9, temperature=0.0)
+            for p in (prompts[3], prompts[0])
+        ]
+        out = {e.slot: [e.token] for e in first}
+        for _ in range(8):
+            for e in eng.tick():
+                out[e.slot].append(e.token)
+        assert eng.active_count == 0 and sorted(out) == [0, 1]
+        streams[impl] = out
+    assert streams["paged"] == streams["xla"]
+
+
+def test_tick_counts_what_its_slots_hold(setup):
+    """`stats()` of the dense engine: the ticks' key positions from the
+    host's own positions (`attn_kv_positions`, `attn_pairs`: layers x the
+    live slots' chain lengths), the share of the table's positions they
+    are (`tick_live_key_share`), and the path the tick's attention takes -
+    the choice (`runtime.decode_attention_path`: gathered rows off the
+    TPU) unless the config forces one."""
+    params, prompts = setup
+    eng = PagedEngine(params, CFG, slots=4, block_size=8, min_bucket=8)
+    gauges = eng.gauges()
+    assert gauges["tick_attention_path"] == "xla"
+    assert gauges["tick_live_key_share"] is None
+    assert gauges["attn_kv_positions"] == 0
+    for p in (prompts[2], prompts[0]):  # 12 and 3 tokens
+        eng.admit(p, max_new_tokens=4, temperature=0.0)
+    eng.tick()  # attends to 13 and 4 keys
+    eng.tick()  # 14 and 5
+    gauges = eng.gauges()
+    live = 13 + 4 + 14 + 5
+    assert gauges["attn_kv_positions"] == CFG.num_layers * live
+    assert gauges["attn_pairs"] == CFG.num_layers * live
+    table = 2 * 4 * CFG.context_length  # two ticks, every slot's whole row
+    assert gauges["tick_live_key_share"] == pytest.approx(100 * live / table)
+
+
+def test_tick_attention_path_is_chosen_on_the_tpu(setup, monkeypatch):
+    """On the TPU the one-row tick takes the kernel where the pool's shape
+    allows it - lane-wide rows, tile-high blocks, a table a group wide -
+    and gathered rows where it does not; a `SpecEngine`'s tick is the
+    verify pass, several rows a slot, and keeps the rows path."""
+    from bpe_transformer_tpu.models import GPT2_SMALL_32K
+    from bpe_transformer_tpu.models.decode import DenseRows, init_kv_pool
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pool = jax.eval_shape(
+        lambda: init_kv_pool(GPT2_SMALL_32K, 65, 16, jax.numpy.bfloat16)
+    )[0]
+    assert DenseRows.attention_path(GPT2_SMALL_32K, True, 64, pool) == "paged"
+    assert DenseRows.attention_path(GPT2_SMALL_32K, False, 64, pool) == "xla"
+    forced = dataclasses.replace(GPT2_SMALL_32K, decode_attention_impl="xla")
+    assert DenseRows.attention_path(forced, True, 64, pool) == "xla"
+    tiny = jax.eval_shape(lambda: init_kv_pool(CFG, 9, 8))[0]
+    assert DenseRows.attention_path(CFG, True, 4, tiny) == "xla"
+    paged = dataclasses.replace(CFG, decode_attention_impl="paged")
+    assert DenseRows.attention_path(paged, True, 4, tiny) == "paged"
+    assert DenseRows.attention_path(paged, False, 4, tiny) == "xla"
+
+
 def test_paged_native_tick_contains_no_gather_transient(setup):
     """ACCEPTANCE (ISSUE 9 tentpole): the compiled paged-native tick holds
     NO ``(slots, blocks_per_slot * block_size, kv_heads * d_head)``
@@ -649,7 +724,9 @@ def test_paged_native_tick_contains_no_gather_transient(setup):
     from bpe_transformer_tpu.telemetry.attribution import program_cost
 
     params, _ = setup
-    slots, bs = 2, 8
+    # Three slots: the interpreter shows the kernel's two VMEM buffers as
+    # arrays (2, a group's keys, width), which two slots' rows would equal.
+    slots, bs = 3, 8
     nbs = CFG.context_length // bs
     kv_heads = CFG.num_kv_heads or CFG.num_heads
     pool = init_kv_pool(CFG, slots * nbs + 1, bs)

@@ -78,8 +78,15 @@ DEFAULT_BUDGET_S = 800.0
 #: inject-block programs compiled for the described v5e at gpt2-small-32k
 #: widths and two layers, the tick once more without the option for the
 #: executable's size (tests/test_chip_compile.py, 7 cases, 17-24 s a whole
-#: program).
-DEFAULT_MAX_TESTS = 845
+#: program).  Raised 845 -> 880 in PR 32 (861 collected, 29 added; the
+#: whole run 285 s with six workers): the paged-native kernel over ragged
+#: key counts at the cells' head shapes and both pool widths, the table of
+#: `decode_attention_path` and of the group size (tests/test_kernels.py, 19
+#: cases in 27 s), a tick with idle slots against the XLA path and the new
+#: `stats()` counters (tests/test_kvpool.py, 4 cases), and the kernel and
+#: the 128-slot tick compiled for the described v5e (tests/
+#: test_chip_compile.py, 6 cases, 1-10 s each).
+DEFAULT_MAX_TESTS = 880
 
 #: Pytest summary trailer: "== 398 passed, 27 deselected in 612.34s =="
 #: (also plain "in 612.34s (0:10:12)" forms).
