@@ -173,6 +173,39 @@ class ModelConfig:
     #: result only (None = all of them).  Serving only.
     experts_held: int | None = None
     expert_offset: int = 0
+    # ---- latent attention, the shortcut-connected double layer and
+    # zero-compute experts (the LongCat-Flash family; every default is the
+    # block above) ---------------------------------------------------------
+    #: "mha": q/k/v heads (``num_kv_heads`` makes it GQA).  "mla": latent
+    #: attention - queries through a ``q_lora_rank`` bottleneck, keys and
+    #: values expanded from one cached row a position of ``kv_lora_rank``
+    #: latent values and one ``qk_rope_head_dim`` rotated key shared by all
+    #: heads; a head's query and key are ``qk_nope_head_dim +
+    #: qk_rope_head_dim`` wide (only the latter rotated), its value
+    #: ``v_head_dim`` (`models/mla.py`).  Latent attention comes in the
+    #: shortcut-connected double layer and nowhere else (`double_layer`).
+    attention_kind: str = "mha"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    #: Scale the normalised query / key-value latents by ``sqrt(d_model /
+    #: rank)`` (`q_lora_scale`, `kv_lora_scale`).
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
+    #: Width of one routed expert where it is not ``d_ff``.
+    expert_d_ff: int | None = None
+    #: Router outputs past ``n_experts`` that name no weights: a token's
+    #: assignment to one returns ``gate * input`` and costs nothing.
+    n_zero_experts: int = 0
+    #: False: the chosen gates are the scores as they are (times
+    #: ``routed_scaling_factor``), not renormalised to sum to one.
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    #: The router's tree holds ``router_bias`` (router outputs,), added to
+    #: the scores for the choice of experts and not to the gates.
+    router_bias: bool = False
     # Sequence-parallel ring attention: sub-chunk each visiting K/V shard
     # so per-device score memory is O(S_local * chunk) instead of
     # O(S_local^2).  Must divide the local shard length.  None -> one full
@@ -185,9 +218,55 @@ class ModelConfig:
 
     @property
     def d_head(self) -> int:
+        """Width of a head's query and key (the softmax scale's)."""
+        if self.attention_kind == "mla":
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         if self.head_dim is not None:
             return self.head_dim
         return self.d_model // self.num_heads
+
+    @property
+    def rope_dim(self) -> int:
+        """Width of what RoPE rotates: the whole head, or latent
+        attention's rope part."""
+        return self.qk_rope_head_dim if self.attention_kind == "mla" else self.d_head
+
+    @property
+    def latent_width(self) -> int:
+        """Values latent attention caches a position and sublayer."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def q_lora_scale(self) -> float:
+        return (self.d_model / self.q_lora_rank) ** 0.5 if self.mla_scale_q_lora else 1.0
+
+    @property
+    def kv_lora_scale(self) -> float:
+        return (self.d_model / self.kv_lora_rank) ** 0.5 if self.mla_scale_kv_lora else 1.0
+
+    @property
+    def double_layer(self) -> bool:
+        """The shortcut-connected double layer: two attention sublayers, two
+        dense SwiGLU FFNs of width ``d_ff`` and one expert layer (``ffn_type
+        = "moe"``) in a layer; the expert layer reads the first sublayer's
+        normalised stream and joins at the layer's end
+        (`models/decode._block_apply`).  Derived: the one configuration with
+        latent attention has it in this layer, so it is no field until one
+        needs them apart."""
+        return self.attention_kind == "mla"
+
+    @property
+    def attn_sublayers(self) -> int:
+        """Attention sublayers, and so cache arrays, a layer."""
+        return 2 if self.double_layer else 1
+
+    @property
+    def moe_d_ff(self) -> int:
+        return self.d_ff if self.expert_d_ff is None else self.expert_d_ff
+
+    @property
+    def router_outputs(self) -> int:
+        return self.n_experts + self.n_zero_experts
 
     @property
     def local_experts(self) -> int:
@@ -215,9 +294,11 @@ class ModelConfig:
     def dropless_block(self) -> bool:
         """True for what only the serving paths and the plain forward run
         (no training step, no ``scan_layers``, no int8 weights): a layer
-        pattern, a parallel block, LayerNorm, shared or held experts."""
+        pattern, a parallel block, LayerNorm, shared or held experts, latent
+        attention, the double layer, zero experts and their router."""
         return (
-            self.has_window_layers
+            self.latent_block
+            or self.has_window_layers
             or self.parallel_block
             or self.norm_type != "rmsnorm"
             or not self.rope_on_full_layers
@@ -225,6 +306,19 @@ class ModelConfig:
             or self.experts_held is not None
             or self.moe_router != "softmax"
             or self.head_dim is not None
+        )
+
+    @property
+    def latent_block(self) -> bool:
+        """Latent attention (and with it the double layer), or any of its
+        expert layer's departures."""
+        return (
+            self.attention_kind != "mha"
+            or self.expert_d_ff is not None
+            or self.n_zero_experts > 0
+            or not self.norm_topk_prob
+            or self.routed_scaling_factor != 1.0
+            or self.router_bias
         )
 
     @property
@@ -285,21 +379,75 @@ class ModelConfig:
                 f"{self.expert_offset} must name experts of a MoE layer with "
                 f"n_experts={self.n_experts}"
             )
+        if self.attention_kind not in ("mha", "mla"):
+            raise ValueError(
+                f'attention_kind={self.attention_kind!r} must be "mha" or "mla"'
+            )
+        mla_dims = (
+            self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+            self.qk_rope_head_dim, self.v_head_dim,
+        )
+        if self.attention_kind == "mla":
+            if min(mla_dims) < 1 or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    'attention_kind="mla" needs positive q_lora_rank, '
+                    "kv_lora_rank, qk_nope_head_dim, v_head_dim and an even "
+                    f"qk_rope_head_dim (got {mla_dims})"
+                )
+            if (
+                self.num_kv_heads is not None or self.head_dim is not None
+                or self.sliding_window is not None or self.remove_rope
+                or self.remove_rmsnorm
+            ):
+                raise ValueError(
+                    "latent attention has no K/V heads, one head width of its "
+                    "own and no layer pattern: num_kv_heads, head_dim, "
+                    "sliding_window, remove_rope and remove_rmsnorm contradict it"
+                )
+        elif any(mla_dims) or self.mla_scale_q_lora or self.mla_scale_kv_lora:
+            raise ValueError(
+                "q_lora_rank .. v_head_dim and the lora scales are latent "
+                'attention\'s (attention_kind="mla")'
+            )
+        if self.double_layer and (
+            self.ffn_type != "moe" or self.parallel_block or self.use_post_norm
+            or self.norm_type != "rmsnorm"
+        ):
+            raise ValueError(
+                'the double layer is pre-norm RMSNorm around an expert layer '
+                '(ffn_type="moe"); parallel_block, use_post_norm and '
+                "LayerNorm contradict it"
+            )
+        if self.ffn_type != "moe" and (
+            self.expert_d_ff is not None or self.n_zero_experts
+            or self.router_bias
+        ):
+            raise ValueError(
+                'expert_d_ff, n_zero_experts and router_bias need ffn_type="moe"'
+            )
+        if self.n_zero_experts < 0 or (
+            self.expert_d_ff is not None and self.expert_d_ff < 1
+        ):
+            raise ValueError(
+                f"n_zero_experts={self.n_zero_experts} must be >= 0 and "
+                f"expert_d_ff={self.expert_d_ff} positive"
+            )
         if self.parallel_block and self.use_post_norm:
             raise ValueError("parallel_block has one pre-norm; use_post_norm contradicts it")
-        if self.dropless_block and not self.parallel_block:
+        if self.dropless_block and not (self.parallel_block or self.double_layer):
             raise ValueError(
                 "a layer pattern, LayerNorm, head_dim, sigmoid routing, shared "
-                "or held experts run in the parallel block only "
-                "(parallel_block=True): no configuration has them in a "
+                "or held experts run in the parallel block only (zero "
+                "experts, unnormalised gates and an expert width of its own "
+                "in the double layer too): no configuration has them in a "
                 "sequential block"
             )
         if self.scan_layers and self.dropless_block:
             raise ValueError(
                 "scan_layers runs homogeneous training blocks; a layer "
-                "pattern, the parallel block, LayerNorm, shared or held "
-                "experts are served and not trained (ROADMAP: what cannot "
-                "run yet)"
+                "pattern, the parallel block, the double layer, LayerNorm, "
+                "shared, held or zero experts are served and not trained "
+                "(ROADMAP: what cannot run yet)"
             )
         if self.head_dim is None and self.d_model % self.num_heads:
             raise ValueError(
@@ -327,11 +475,11 @@ class ModelConfig:
                 'must be "auto", "xla", "pallas" or "paged"'
             )
         if self.ffn_type == "moe" and not (
-            1 <= self.router_top_k <= self.n_experts
+            1 <= self.router_top_k <= self.router_outputs
         ):
             raise ValueError(
                 f"router_top_k={self.router_top_k} must be in "
-                f"[1, n_experts={self.n_experts}]"
+                f"[1, router outputs={self.router_outputs}]"
             )
         if self.remat_policy not in (
             "none", "full", "dots_saveable", "save_attn"

@@ -41,6 +41,14 @@ KVCache = list  # [{"k": (B, H, ctx, dh), "v": (B, H, ctx, dh)} per layer]
 
 
 def init_kv_cache(config: ModelConfig, batch: int, dtype=jnp.float32) -> KVCache:
+    if config.attention_kind == "mla":
+        # Latent attention: per layer and sublayer one buffer of latent rows
+        # (batch, 1, context_length, latent_width) - no heads.
+        shape = (batch, 1, config.context_length, config.latent_width)
+        return [
+            [jnp.zeros(shape, dtype) for _ in range(config.attn_sublayers)]
+            for _ in range(config.num_layers)
+        ]
     # GQA stores only num_kv_heads — the cache (decode's HBM footprint)
     # shrinks by the query-group factor.
     kv_heads = config.num_kv_heads or config.num_heads
@@ -84,8 +92,30 @@ def _block_apply(x, block_params, config, attend, valid=None, tally=None):
 
     Mirrors `transformer_block_aux` (models/transformer.py): pre-norm by
     default, post-norm under the ablation flag, both branches from one norm
-    under ``parallel_block``.
+    under ``parallel_block``.  Under ``double_layer`` it is the
+    shortcut-connected double layer, whose ``attend(h, sublayer)`` is called
+    for each of its two attention sublayers: with norms ``N1 .. N4``, dense
+    FFNs ``F_0, F_1`` and the expert layer ``M``, ``a = x + Attn_0(N1 x)``,
+    ``u = N2 a``, ``m = M(u)``, ``b = a + F_0(u)``, ``d = b + Attn_1(N3
+    b)``, ``y = d + F_1(N4 d) + m`` - the expert layer reads the first
+    sublayer's normalised stream and joins at the layer's end.
     """
+    if config.double_layer:
+        from bpe_transformer_tpu.ops.core import swiglu
+
+        ln, dense = block_params["ln"], block_params["dense_ffn"]
+        with jax.named_scope("block/attn"):
+            a = x + attend(_norm(x, ln[0], config), 0)
+        with jax.named_scope("block/ffn"):
+            u = _norm(a, ln[1], config)
+            m = _ffn_decode(u, block_params["ffn"], config, valid, tally)
+            with jax.named_scope("dense"):
+                b = a + swiglu(u, dense[0]["w1"], dense[0]["w2"], dense[0]["w3"])
+        with jax.named_scope("block/attn"):
+            d = b + attend(_norm(b, ln[2], config), 1)
+        with jax.named_scope("block/ffn"), jax.named_scope("dense"):
+            h = _norm(d, ln[3], config)
+            return d + swiglu(h, dense[1]["w1"], dense[1]["w2"], dense[1]["w3"]) + m
     if config.parallel_block:
         h = _norm(x, block_params["ln1"], config)
         with jax.named_scope("block/attn"):
@@ -189,6 +219,30 @@ def prefill(
     for layer, (block_params, layer_cache) in enumerate(
         zip(params["layers"], cache)
     ):
+        if config.attention_kind == "mla":
+            layer_new: list = []
+
+            def attend_latent(
+                h, sub, block_params=block_params, layer_cache=layer_cache,
+                layer_new=layer_new,
+            ):
+                from bpe_transformer_tpu.models.mla import self_attention
+
+                out, rows = self_attention(
+                    h, block_params["attn"][sub], positions, config
+                )
+                layer_new.append(
+                    lax.dynamic_update_slice(
+                        layer_cache[sub],
+                        rows[:, None].astype(layer_cache[sub].dtype),
+                        (0, 0, 0, 0),
+                    )
+                )
+                return out
+
+            x = _block_apply(x, block_params, config, attend_latent)
+            new_cache.append(layer_new)
+            continue
         window = config.layer_window(layer)
         if not use_flash:
             mask = causal
@@ -249,6 +303,31 @@ def _cache_write(buf: Array, new: Array, pos: Array) -> Array:
     )(buf, new, pos)
 
 
+def _latent_decode_attention(
+    h, sub, *, attn, config, positions, pos, active, layer_cache, layer_new
+):
+    """One latent-attention sublayer of :func:`decode_step`: the new row
+    into the dense latent cache, the query absorbed against it."""
+    from bpe_transformer_tpu.kernels.pallas.mla_attention import (
+        xla_mla_rows_attention,
+    )
+    from bpe_transformer_tpu.models import mla
+
+    def attend(q_abs, rows):
+        old = layer_cache[sub]
+        buf = _cache_write(old, rows[:, None].astype(old.dtype), pos)
+        if active is not None:
+            buf = jnp.where(active[:, None, None, None], buf, old)
+        layer_new.append(buf)
+        visible = jnp.arange(buf.shape[2])[None, :] <= jnp.reshape(pos, (-1, 1))
+        return xla_mla_rows_attention(
+            q_abs, buf[:, 0], visible, rank=config.kv_lora_rank,
+            scale=mla.softmax_scale(config),
+        )
+
+    return mla.absorbed_attention(h, attn[sub], positions, config, attend)
+
+
 def decode_step(
     params: Params,
     token: Array,
@@ -283,6 +362,18 @@ def decode_step(
     for layer, (block_params, layer_cache) in enumerate(
         zip(params["layers"], cache)
     ):
+        if config.attention_kind == "mla":
+            layer_new: list = []
+            x = _block_apply(
+                x, block_params, config,
+                partial(
+                    _latent_decode_attention, attn=block_params["attn"],
+                    config=config, positions=positions, pos=pos, active=active,
+                    layer_cache=layer_cache, layer_new=layer_new,
+                ),
+            )
+            new_cache.append(layer_new)
+            continue
         window = config.layer_window(layer)
 
         def attend(
@@ -557,7 +648,10 @@ def _quantize_chunk_rows(
 # the count of its real rows) ``(rows,)`` of ONE slot, whose ``tables`` are
 # its own rows ``(blocks,)``.
 #
-# A new architecture's cache (latent, recurrent) is one more kind.
+# Latent attention's cache is a third kind (`LatentRows`, further down): it
+# has no K/V heads to write or attend, so it provides the whole sublayer
+# (``attention``) in place of ``write`` and ``attend``.  A new
+# architecture's cache (recurrent) is one more kind.
 
 
 def _clamped(x, hi: int, rows: int):
@@ -741,7 +835,7 @@ class DenseRows:
         return merge_heads(att)
 
     @staticmethod
-    def zero_counts():
+    def zero_counts(config=None):
         """No routing counts ride along."""
         return None
 
@@ -777,7 +871,27 @@ def init_grouped_kv_pool(
     ]
 
 
-class GroupedPages:
+class _RoutingCounts:
+    """What a kind that carries the dropless expert layers' routing counts
+    shares: ``tally``, the list the forward collects them in, is the
+    kind's own."""
+
+    @staticmethod
+    def zero_counts(config):
+        """[tokens routed, assignments on held experts, non-empty expert
+        groups] and, where the config has zero experts, [assignments on
+        them], int32: what a program is handed and hands back
+        (`moe.dropless_moe`'s counts)."""
+        return jnp.zeros((4 if config.n_zero_experts else 3,), jnp.int32)
+
+    def counts(self):
+        """The tally summed over the layers (zeros without a MoE layer)."""
+        if not self.tally:
+            return self.zero_counts(self.config)
+        return jnp.sum(jnp.stack(self.tally), axis=0)
+
+
+class GroupedPages(_RoutingCounts):
     """`init_grouped_kv_pool`'s pool.  ``tables`` is a dict: ``"full"`` and
     ``"window"`` page rows, and ``"window_base"``, the absolute position of
     the first row entry of the window group (rows there start at the slot's
@@ -873,20 +987,119 @@ class GroupedPages:
             att = jnp.where(self.ffn_rows[:, None, None], att, 0)
         return att.reshape(slots, rows, heads * d_head)
 
-    @staticmethod
-    def zero_counts():
-        """[tokens routed, assignments on held experts, non-empty expert
-        groups], int32: what a program is handed and hands back."""
-        return jnp.zeros((3,), jnp.int32)
 
-    def counts(self):
-        """The tally summed over the layers (zeros without a MoE layer)."""
-        if not self.tally:
-            return self.zero_counts()
-        return jnp.sum(jnp.stack(self.tally), axis=0)
+# Latent attention caches one row a position and attention sublayer, the
+# normalised latent beside the rotated shared key, with no heads
+# (`models/mla.py`): a pool array a sublayer, a layer's side by side in the
+# pool's list (sublayer ``j`` of layer ``l`` is entry ``l * sublayers + j``).
+
+
+def init_latent_pool(
+    config: ModelConfig, num_blocks: int, block_size: int, dtype=jnp.float32
+) -> list:
+    """``{"c": (num_blocks, block_size, row width)}`` for each attention
+    sublayer of each layer.  Block 0 is the trash block, as in every pool.
+
+    A row is ``latent_width`` values padded with zeros to whole 128-lane
+    tiles (576 -> 640): the device lays a row out in whole tiles whatever
+    its shape says, and the v5e compiler slices a block out of the pool only
+    along whole tiles (`tests/test_chip_compile.py`)."""
+    shape = (num_blocks, block_size, -(-config.latent_width // 128) * 128)
+    return [
+        {"c": jnp.zeros(shape, dtype)}
+        for _ in range(config.num_layers * config.attn_sublayers)
+    ]
+
+
+class LatentRows(_RoutingCounts):
+    """`init_latent_pool`'s pool: one chain of blocks a slot, as `DenseRows`
+    has (so the radix prefix cache, whose bookkeeping is block ids, shares
+    whole frozen blocks of latent rows between slots), rows of
+    ``latent_width`` values.  A tick's one row a slot attends in the
+    absorbed form straight out of the pool (`mla_paged_attention`: the
+    kernel on the TPU, gathered rows elsewhere); a chunk's rows attend,
+    absorbed too, over the slot's gathered chain, the prefix an earlier
+    request wrote included, in a loop that follows the chunk's last position
+    (`xla_mla_chunk_attention`, which says why absorbed).  Several rows a
+    slot (a verify pass) have no form here.  Routing counts of the expert
+    layers ride along as in `GroupedPages`."""
+
+    def __init__(self, config, tables, positions, valid, block_size, chunk=None):
+        if positions.ndim != 1:
+            raise NotImplementedError(
+                "several rows a slot (a verify pass) over a latent pool"
+            )
+        self.config, self.tables, self.chunk = config, tables, chunk
+        self.tally: list = []
+        tokens = positions.shape[0]
+        self.ffn_rows = jnp.ones((tokens,), bool) if valid is None else valid
+        rows = tokens if chunk is not None else 1
+        at = _clamped(positions, config.context_length - 1, rows)
+        self.positions = positions
+        self.rope_positions = at if chunk is not None else at[:, None]
+        self.write_ids, self.offsets = _block_addresses(
+            tables, at, valid, block_size, rows
+        )
+
+    @staticmethod
+    def attention_path(config, one_row: bool, blocks_per_slot: int, layer_pool) -> str:
+        """``"mla_paged"``, the kernel, or ``"xla"``: how a tick's rows
+        attend (`mla_attention.mla_paged_path`)."""
+        from bpe_transformer_tpu.kernels.pallas.mla_attention import (
+            mla_paged_path,
+        )
+
+        _, block_size, width = layer_pool["c"].shape
+        return mla_paged_path(block_size, width, config.kv_lora_rank)
+
+    def attention(self, h, attn, layer_pool, new_pool):
+        """One sublayer: ``h`` (slots, rows, d_model) -> the same shape; the
+        new latent rows go to ``(write_ids, offsets)`` of the sublayer's
+        pool array, appended to ``new_pool`` updated."""
+        from bpe_transformer_tpu.kernels.pallas.mla_attention import (
+            mla_paged_attention,
+        )
+        from bpe_transformer_tpu.models import mla
+
+        config, pool_arr = self.config, layer_pool["c"]
+
+        def write(rows):
+            with jax.named_scope("pool_write"):
+                pad = pool_arr.shape[-1] - rows.shape[-1]
+                written = pool_arr.at[self.write_ids, self.offsets].set(
+                    jnp.pad(rows, ((0, 0), (0, pad))).astype(pool_arr.dtype)
+                )
+            new_pool.append({"c": written})
+            return written
+
+        if self.chunk is None:
+            def attend(q_abs, rows):
+                key_counts = jnp.where(self.ffn_rows, self.positions + 1, 0)
+                return mla_paged_attention(
+                    q_abs, write(rows[:, 0]), self.tables, key_counts,
+                    rank=config.kv_lora_rank, scale=mla.softmax_scale(config),
+                )
+
+            return mla.absorbed_attention(
+                h, attn, self.rope_positions, config, attend
+            )
+        start, chunk_len = self.chunk
+        q_nope, q_rope = mla.queries(h, attn, self.rope_positions, config)
+        rows = mla.latent_rows(h, attn, self.rope_positions, config)
+        written = write(rows[0])
+        with jax.named_scope("pool_gather"):
+            chain = written[self.tables].reshape(-1, written.shape[-1])
+            chain = chain[:, : config.latent_width]
+        att = mla.rows_attention(
+            q_nope[0], q_rope[0], chain, attn, self.positions,
+            start + chunk_len, config,
+        )
+        return linear(att[None], attn["output_proj"])
 
 
 def cache_kind(config: ModelConfig):
+    if config.attention_kind == "mla":
+        return LatentRows
     return GroupedPages if config.has_window_layers else DenseRows
 
 
@@ -896,6 +1109,10 @@ def init_paged_pool(
 ):
     """The pool of the config's cache kind.  ``num_window_blocks`` sizes the
     window group where the kind has one."""
+    if cache_kind(config) is LatentRows:
+        if kv_dtype is not None:
+            raise ValueError("a latent pool holds its rows at the activation width")
+        return init_latent_pool(config, num_blocks, block_size, dtype)
     if cache_kind(config) is GroupedPages:
         if kv_dtype is not None:
             raise ValueError("window pool groups hold K/V at the activation width")
@@ -930,7 +1147,14 @@ def chunk_cache(
     )
 
 
-def _cached_attention(h, attn, config, cache, layer, layer_pool, new_pool):
+def _cached_attention(
+    h, sublayer=0, *, attn, config, cache, layer, layer_pool, new_pool
+):
+    """One attention sublayer over the paged pool; ``layer_pool`` is the
+    layer's entries of the pool's list, one a sublayer."""
+    if config.attention_kind == "mla":
+        return cache.attention(h, attn[sublayer], layer_pool[sublayer], new_pool)
+    (layer_pool,) = layer_pool
     q, k, v = _project_qkv(h, attn, config)
     q, k = _rope_qk(q, k, cache.rope_positions, config, layer)
     layer_pool = cache.write(layer, layer_pool, k, v)
@@ -968,13 +1192,14 @@ def paged_forward(
     counts (None for a kind that carries none)."""
     x = _embed(params, tokens)
     new_pool: list = []
-    for layer, (block_params, layer_pool) in enumerate(zip(params["layers"], pool)):
+    per_layer = config.attn_sublayers
+    for layer, block_params in enumerate(params["layers"]):
         x = _block_apply(
             x, block_params, config,
             partial(
                 _cached_attention, attn=block_params["attn"], config=config,
-                cache=cache, layer=layer, layer_pool=layer_pool,
-                new_pool=new_pool,
+                cache=cache, layer=layer, new_pool=new_pool,
+                layer_pool=pool[layer * per_layer: (layer + 1) * per_layer],
             ),
             valid=cache.ffn_rows, tally=cache.tally,
         )

@@ -42,10 +42,11 @@ from bpe_transformer_tpu.ops.core import silu
 
 def init_moe_params(rng: jax.Array, config: ModelConfig, dtype=jnp.float32) -> dict:
     """Stacked expert weights + router for one MoE FFN layer: the router
-    over all ``n_experts``, the stacks of the experts held here
-    (``config.local_experts``), and a ``"shared"`` stack where the config
-    has shared experts."""
-    e, d, ff = config.n_experts, config.d_model, config.d_ff
+    over all its outputs (``n_experts`` and the zero experts after them),
+    the stacks of the experts held here (``config.local_experts``), a
+    ``"shared"`` stack where the config has shared experts and a
+    ``"router_bias"`` of zeros where it has one."""
+    e, d, ff = config.router_outputs, config.d_model, config.moe_d_ff
     held, shared = config.local_experts, config.n_shared_experts
 
     def dense(key, shape, std=0.02):
@@ -60,6 +61,8 @@ def init_moe_params(rng: jax.Array, config: ModelConfig, dtype=jnp.float32) -> d
         "w2": dense(k[2], (held, d, ff)),
         "w3": dense(k[3], (held, ff, d)),
     }
+    if config.router_bias:
+        params["router_bias"] = jnp.zeros((e,), jnp.float32)
     if shared:
         ks = jax.random.split(jax.random.fold_in(rng, 1), 3)
         params["shared"] = {
@@ -195,11 +198,16 @@ def switch_ffn(
 # ------------------------------------------------------------ dropless path
 
 
-def route(tokens: Array, router: Array, config: ModelConfig) -> tuple[Array, Array]:
-    """Scores over all ``n_experts`` in float32, the ``router_top_k``
-    largest and their gates: ``(expert ids (n, k), gates (n, k))``.  Softmax
-    top-1 keeps the raw probability (Switch); every other case renormalizes
-    the chosen scores to sum to one."""
+def route(
+    tokens: Array, router: Array, config: ModelConfig, bias: Array | None = None
+) -> tuple[Array, Array]:
+    """Scores over all the router's outputs in float32, the
+    ``router_top_k`` largest - of ``scores + bias`` where the router has a
+    selection ``bias`` - and their gates: ``(expert ids (n, k), gates (n,
+    k))``.  Softmax top-1 keeps the raw probability (Switch), as does every
+    choice without ``norm_topk_prob``; every other case renormalizes the
+    chosen scores to sum to one.  Gates are scaled by
+    ``routed_scaling_factor``."""
     logits = jnp.einsum(
         "nd,ed->ne", tokens.astype(jnp.float32), router.astype(jnp.float32)
     )
@@ -207,10 +215,18 @@ def route(tokens: Array, router: Array, config: ModelConfig) -> tuple[Array, Arr
         scores = jax.nn.sigmoid(logits)
     else:
         scores = jax.nn.softmax(logits, axis=-1)
-    top_s, top_i = jax.lax.top_k(scores, config.router_top_k)
-    if config.moe_router == "softmax" and config.router_top_k == 1:
-        return top_i, top_s
-    return top_i, top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+    if bias is None:
+        top_s, top_i = jax.lax.top_k(scores, config.router_top_k)
+    else:
+        _, top_i = jax.lax.top_k(scores + bias, config.router_top_k)
+        top_s = jnp.take_along_axis(scores, top_i, axis=-1)
+    if config.norm_topk_prob and not (
+        config.moe_router == "softmax" and config.router_top_k == 1
+    ):
+        top_s = top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+    if config.routed_scaling_factor != 1.0:
+        top_s = top_s * config.routed_scaling_factor
+    return top_i, top_s
 
 
 def dropless_moe(
@@ -234,8 +250,16 @@ def dropless_moe(
     part alone.  Shapes are static: the sorted buffer has a row for every
     assignment there could be, ``tokens * router_top_k``.
 
+    Router outputs from ``n_experts`` on are **zero experts**
+    (``n_zero_experts``): they have no weights and live with the token, so
+    every process computes their part whole - the token's gates on them,
+    summed, times the layer's input - and their assignments never enter the
+    sort or the grouped matmul, whose rows so vary by token.
+
     ``counts`` is int32 ``[tokens routed, assignments held here, non-empty
-    expert groups computed]`` - the ``moe_*`` counters of ``stats()``.
+    expert groups computed]`` and, where the config has zero experts,
+    ``[assignments on zero experts]`` - the ``moe_*`` counters of
+    ``stats()`` (a config without them compiles to the programs it had).
     """
     from bpe_transformer_tpu.kernels.pallas.grouped_matmul import grouped_matmul
 
@@ -247,11 +271,17 @@ def dropless_moe(
     kn = n * top_k
 
     with jax.named_scope("block/moe/router"):
-        top_i, gates = route(tokens, moe_params["router"], config)
+        top_i, gates = route(
+            tokens, moe_params["router"], config, moe_params.get("router_bias")
+        )
+        # A zero expert's id lies past every held expert's: never local.
         local = top_i - config.expert_offset
         is_local = (local >= 0) & (local < held)
+        is_zero = top_i >= config.n_experts if config.n_zero_experts else None
         if valid is not None:
             is_local &= valid.reshape(n)[:, None]
+            if is_zero is not None:
+                is_zero &= valid.reshape(n)[:, None]
         # Row r of the flat assignment list is (token r // k, rank r % k);
         # assignments that are not ours sort behind every held expert.
         key = jnp.where(is_local, local, held).reshape(kn)
@@ -259,10 +289,13 @@ def dropless_moe(
         group_sizes = jnp.zeros((held,), jnp.int32).at[key].add(1, mode="drop")
         rows_local = jnp.sum(group_sizes)
         routed = n if valid is None else jnp.sum(valid)
-        counts = jnp.stack([
+        counts = [
             jnp.asarray(routed, jnp.int32), rows_local,
             jnp.sum(group_sizes > 0).astype(jnp.int32),
-        ])
+        ]
+        if is_zero is not None:
+            counts.append(jnp.sum(is_zero).astype(jnp.int32))
+        counts = jnp.stack(counts)
 
     with jax.named_scope("block/moe/experts"):
         sorted_in = jnp.take(tokens, order // top_k, axis=0)  # (kn, d)
@@ -277,6 +310,13 @@ def dropless_moe(
         picked = jnp.take(sorted_out, sorted_row, axis=0).astype(jnp.float32)
         picked = jnp.where(is_local.reshape(kn, 1), picked * gates.reshape(kn, 1), 0.0)
         out = jnp.sum(picked.reshape(n, top_k, d), axis=1).astype(tokens.dtype)
+
+    if config.n_zero_experts:
+        with jax.named_scope("block/moe/zero"):
+            zero_gate = jnp.sum(jnp.where(is_zero, gates, 0.0), axis=-1)
+            out = out + (
+                zero_gate[:, None] * tokens.astype(jnp.float32)
+            ).astype(tokens.dtype)
 
     if config.n_shared_experts:
         with jax.named_scope("block/moe/shared"):

@@ -63,6 +63,28 @@ def init_params(
     layers = []
     for i in range(config.num_layers):
         k = jax.random.split(keys[2 + i], 7)
+        if config.double_layer:
+            from bpe_transformer_tpu.models.mla import init_mla_params
+            from bpe_transformer_tpu.models.moe import init_moe_params
+
+            # Two attention sublayers, two dense FFNs, four norms and the
+            # expert layer (`models/decode._block_apply`).
+            layers.append(
+                {
+                    "attn": [init_mla_params(k[j], config, dtype) for j in (0, 1)],
+                    "ln": [jnp.ones((d,), dtype) for _ in range(4)],
+                    "dense_ffn": [
+                        {
+                            "w1": dense(kk[0], ff, d),
+                            "w2": dense(kk[1], d, ff),
+                            "w3": dense(kk[2], ff, d),
+                        }
+                        for kk in (jax.random.split(k[j], 3) for j in (2, 3))
+                    ],
+                    "ffn": init_moe_params(k[4], config, dtype),
+                }
+            )
+            continue
         if config.ffn_type == "moe":
             from bpe_transformer_tpu.models.moe import init_moe_params
 
@@ -536,6 +558,20 @@ def forward_hidden(
                 "an attention_fn override replaces every layer's attention; "
                 "this config's layers differ in kind"
             )
+    if config.double_layer:
+        # The served arrangement is the only one there is; latent attention
+        # runs over the sequence's own rows (`models/mla.py`).
+        from bpe_transformer_tpu.models.decode import _block_apply
+        from bpe_transformer_tpu.models.mla import self_attention
+
+        for block_params in compute_params["layers"]:
+            x = _block_apply(
+                x, block_params, config,
+                lambda h, sub, attn=block_params["attn"]: self_attention(
+                    h, attn[sub], positions, config
+                )[0],
+            )
+    elif config.dropless_block:
         for layer, block_params in enumerate(compute_params["layers"]):
             x = _patterned_block(
                 x, block_params, config, layer, rope_cos_sin, positions

@@ -300,6 +300,12 @@ class SlotPoolEngine:
     ):
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
+        if config.attention_kind == "mla":
+            raise ValueError(
+                "the dense slot pool keeps K and V heads a slot; a config "
+                "with latent attention is served by the paged engine "
+                "(ROADMAP: what cannot run yet)"
+            )
         self.config = config
         self.n_slots = slots
         ctx = config.context_length
@@ -488,10 +494,12 @@ class SlotPoolEngine:
             self.release(slot)
         return TickEvent(slot=slot, token=token, finished=finished)
 
-    def tick(self) -> list[TickEvent]:
+    def tick(self, dispatched=None) -> list[TickEvent]:
         """One batched decode step across every occupied slot: returns each
         active slot's sampled token, retiring slots that hit their stop id
-        or token budget."""
+        or token budget.  ``dispatched``, where given, is called once the
+        tick's program is in the device's queue and before the host waits
+        on its tokens: host work done there costs the device nothing."""
         if not self._active.any():
             return []
         with Phase("serve/tick_dispatch", self.clock) as dispatch:
@@ -500,6 +508,8 @@ class SlotPoolEngine:
                 self._positions, self._active, self._keys, self._temps,
                 self._top_ks, self._top_ps,
             )
+        if dispatched is not None:
+            dispatched()
         with Phase("serve/tick_wait", self.clock) as wait:
             tokens = np.asarray(tokens)
             self._tokens = tokens.copy()

@@ -292,16 +292,33 @@ class PagedEngine:
         #: Two pool groups (`models/decode.GroupedPages`): a config with
         #: sliding-window layers keeps a window group beside the full one.
         #: Every other config is the one-group case: the full group alone
-        #: (`DenseRows`).  The programs and the pool are the cache kind's;
-        #: what is read here is the host's own bookkeeping of the window
-        #: group, and what cannot run over it yet.
+        #: (`DenseRows`, or `LatentRows` under latent attention: one chain
+        #: a slot either way, so the radix prefix cache carries over).  The
+        #: programs and the pool are the cache kind's; what is read here is
+        #: the host's own bookkeeping of the window group, and what cannot
+        #: run over a kind yet.
         self.grouped = config.has_window_layers
+        #: Latent rows in the pool (`models/decode.LatentRows`).
+        self.latent = config.attention_kind == "mla"
         if config.dropless_block and weight_dtype is not None:
             raise ValueError(
                 "weight_dtype quantizes the dense block's weight tree "
-                "(ops/quant.py); this config's parallel block, held and "
-                "shared experts are served at the activation width only"
+                "(ops/quant.py); this config's parallel block or double "
+                "layer, its held, shared and zero experts are served at the "
+                "activation width only"
             )
+        if self.latent:
+            unsupported = {
+                'kv_dtype="int8" (latent rows have no heads to scale by)':
+                    kv_dtype is not None,
+                "fused_sampling": fused_sampling,
+            }
+            for what, asked in unsupported.items():
+                if asked:
+                    raise ValueError(
+                        f"{what} is not supported over a latent pool "
+                        "(ROADMAP: what cannot run yet); pass it off"
+                    )
         if self.grouped:
             unsupported = {
                 "prefix_cache=True (the radix cache shares whole chains; a "
@@ -411,9 +428,18 @@ class PagedEngine:
         #: counter: int8's decode scatter is a whole-block rescale RMW
         #: (~block_size rows, bounded at one block per slot per layer),
         #: amortized small against the context-sized read.
-        self.kv_bytes_per_token = (
-            2 * config.num_layers * kv_heads * config.d_head * itemsize
-        )
+        #: Attention sublayers that read the cache a tick: a layer's one, or
+        #: the double layer's two.
+        self._attn_sublayers = config.num_layers * config.attn_sublayers
+        if self.latent:
+            # One latent row a position and sublayer, no K and V.
+            self.kv_bytes_per_token = (
+                self._attn_sublayers * config.latent_width * itemsize
+            )
+        else:
+            self.kv_bytes_per_token = (
+                2 * config.num_layers * kv_heads * config.d_head * itemsize
+            )
 
         self._tables = np.zeros((slots, self.blocks_per_slot), np.int32)
         # Window group: a slot's row starts at its first live block, whose
@@ -442,12 +468,15 @@ class PagedEngine:
         )
         #: Routing counts of the dropless expert layers, summed over layers:
         #: [tokens routed, assignments on held experts, non-empty expert
-        #: groups].  The totals are kept here; the device carries only the
-        #: chunks' counts since the last tick, which the tick hands over
-        #: with its own.
-        self.moe_counts = np.zeros(3, np.int64)
+        #: groups, assignments on zero experts] (the device's vector ends at
+        #: three where the config has no zero experts: the fourth reads 0).
+        #: The totals are kept here; the device carries only the chunks'
+        #: counts since the last tick, which the tick hands over with its
+        #: own.
+        self.moe_counts = np.zeros(4, np.int64)
         self.last_tick_moe_rows_local = 0
-        self._moe_pending = cache_kind(config).zero_counts()
+        self.last_tick_moe_zero_assignments = 0
+        self._moe_pending = cache_kind(config).zero_counts(config)
         self._tokens = np.zeros(slots, np.int32)
         self._positions = np.zeros(slots, np.int32)
         self._active = np.zeros(slots, bool)
@@ -610,6 +639,7 @@ class PagedEngine:
         out["moe_tokens_routed"] = int(self.moe_counts[0])
         out["moe_rows_local"] = int(self.moe_counts[1])
         out["moe_expert_groups"] = int(self.moe_counts[2])
+        out["moe_zero_assignments"] = int(self.moe_counts[3])
         out["prefill_pending_tokens"] = self.pending_prefill_tokens()
         out["prefill_pending_slots"] = len(self._prefilling)
         out["kv_pool_bytes"] = self.kv_pool_bytes
@@ -686,6 +716,14 @@ class PagedEngine:
                 "run yet)"
             )
 
+    def _refuse_latent(self, what: str) -> None:
+        if self.latent:
+            raise NotImplementedError(
+                f"{what} is not supported over a latent pool: the migration "
+                "wire ships K and V blocks of heads, and a verify pass has "
+                "no latent form (ROADMAP: what cannot run yet)"
+            )
+
     def _advance_window(self, slot: int, lo_pos: int) -> None:
         """Recycle ``slot``'s window blocks that lie wholly below
         ``lo_pos`` (positions no later query of the slot reads)."""
@@ -698,7 +736,7 @@ class PagedEngine:
         """Add what the attention of queries ``start .. end - 1`` of one
         slot needs, over the layers of both kinds (plain integers)."""
         window = self.config.sliding_window
-        full_layers = self.config.num_layers - self._window_layers
+        full_layers = self._attn_sublayers - self._window_layers
         full_pairs = (end * (end + 1) - start * (start + 1)) // 2
         # Queries from position window - 1 on see exactly window keys.
         capped = max(end - max(start, window - 1), 0)
@@ -763,6 +801,7 @@ class PagedEngine:
         Raises :class:`NoFreeBlocksError` when the pool is dry — the
         caller shrinks its speculation window instead of parking."""
         self._refuse_grouped("extend_blocks (speculative scratch)")
+        self._refuse_latent("extend_blocks (speculative scratch)")
         info = self._slots[slot]
         if info is None:
             raise ValueError(f"slot {slot} is not occupied")
@@ -884,6 +923,7 @@ class PagedEngine:
         the payload meta.
         """
         self._refuse_grouped("KV migration (export_slot)")
+        self._refuse_latent("KV migration (export_slot)")
         info = self._slots[slot]
         if info is None:
             raise ValueError(f"slot {slot} is not occupied")
@@ -955,6 +995,7 @@ class PagedEngine:
         dtype mismatch is a configuration error, caught before any block
         is allocated (HTTP 400, not a half-grafted slot)."""
         self._refuse_grouped("KV migration (import_slot)")
+        self._refuse_latent("KV migration (import_slot)")
         if meta.get("format") != 1:
             raise ValueError(
                 f"unsupported payload format {meta.get('format')!r}"
@@ -1230,12 +1271,14 @@ class PagedEngine:
             "window_base": pick(self._window_base),
         }
 
-    def prefill_step(self, slot: int) -> TickEvent | None:
+    def prefill_step(self, slot: int, dispatched=None) -> TickEvent | None:
         """Run ONE prefill chunk for ``slot``.  Returns ``None`` while
         chunks remain; on the final chunk, samples the request's first
         token, activates the slot for decode ticks, indexes the prompt's
         full blocks into the prefix cache, and returns the admission
-        :class:`TickEvent` (exactly the dense engine's ``admit`` result)."""
+        :class:`TickEvent` (exactly the dense engine's ``admit`` result).
+        ``dispatched`` is called once the chunk's program is in the device's
+        queue, before the final chunk's token is waited on (as `tick`'s)."""
         info = self._slots[slot]
         if info is None or slot not in self._prefilling:
             raise ValueError(f"slot {slot} has no pending prefill")
@@ -1264,6 +1307,8 @@ class PagedEngine:
             np.int32(chunk_len), key_in, info.temp_enc, info.top_k_enc,
             info.top_p_enc, pool_at=-2,
         )
+        if dispatched is not None:
+            dispatched()
         info.next_pos += chunk_len
         if not final:
             return None
@@ -1322,9 +1367,9 @@ class PagedEngine:
             if event is not None:
                 return event
 
-    def tick(self) -> list[TickEvent]:
+    def tick(self, dispatched=None) -> list[TickEvent]:
         """One batched decode step across every occupied slot — semantics
-        identical to the dense engine's tick."""
+        identical to the dense engine's tick, ``dispatched`` included."""
         if not self._active.any():
             return []
         with Phase("serve/tick_dispatch", self.clock) as dispatch:
@@ -1337,7 +1382,7 @@ class PagedEngine:
             # One query a live slot: pairs and KV positions are alike.
             seen = self._positions[self._active].astype(np.int64) + 1
             live = int(seen.sum())
-            keys_read = (self.config.num_layers - self._window_layers) * live
+            keys_read = (self._attn_sublayers - self._window_layers) * live
             if self.grouped:
                 keys_read += self._window_layers * int(
                     np.minimum(seen, window).sum()
@@ -1353,6 +1398,8 @@ class PagedEngine:
                 self._active, self._keys, self._temps, self._top_ks,
                 self._top_ps, pool_at=-2,
             )
+        if dispatched is not None:
+            dispatched()
         with Phase("serve/tick_wait", self.clock) as wait:
             tokens = np.asarray(tokens)
             self._tokens = tokens.copy()
@@ -1361,8 +1408,10 @@ class PagedEngine:
             if moe is not None:
                 # The same read as the tokens: no sync of its own.
                 moe_since, moe_tick, self._moe_pending = moe
-                self.moe_counts += np.asarray(moe_since)
-                self.last_tick_moe_rows_local = int(np.asarray(moe_tick)[1])
+                moe_since, moe_tick = np.asarray(moe_since), np.asarray(moe_tick)
+                self.moe_counts[: moe_since.size] += moe_since
+                self.last_tick_moe_rows_local = int(moe_tick[1])
+                self.last_tick_moe_zero_assignments = int(moe_tick[3:].sum())
         self.ticks += 1
 
         events: list[TickEvent] = []

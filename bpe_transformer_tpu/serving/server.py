@@ -271,6 +271,18 @@ class ServingEngine:
                 "roles (KV migration) are not supported over window pool "
                 "groups (ROADMAP: what cannot run yet)"
             )
+        if config.attention_kind == "mla" and not paged:
+            raise ValueError(
+                "a config with latent attention is served by the paged "
+                "engine (paged=True): its latent pool lives there"
+            )
+        if config.attention_kind == "mla" and (speculate_k or role != "both"):
+            raise ValueError(
+                "speculative decoding (a verify pass of several rows a slot) "
+                "and the prefill/decode roles (KV migration ships K and V "
+                "heads) are not supported over a latent pool (ROADMAP: what "
+                "cannot run yet)"
+            )
         if speculate_k:
             from bpe_transformer_tpu.serving.spec.engine import SpecEngine
 
@@ -407,11 +419,15 @@ class ServingEngine:
         self._last_record_t = self._t0
         self._last_record_tokens = 0
         #: The open tick period (see :meth:`_open_period`): what the worker
-        #: has spent, phase by phase, since the last decode tick's deliver.
+        #: has spent, phase by phase, since the last decode tick's end.
         self._period = self._open_period(self._t0)
         self._entries: dict[str, _Entry] = {}
         self._entries_lock = threading.Lock()
         self._slot_entries: dict[int, _Entry] = {}
+        #: The last tick's tokens, each with its request and finish reason,
+        #: held until the next program is in the device's queue
+        #: (:meth:`_publish`).
+        self._unpublished: list[tuple[_Entry, int, str | None]] = []
         #: Per-request trace ring (newest last): the finished requests'
         #: phase timelines behind /statusz "recent_requests" — the same
         #: numbers the serve/* spans carry, queryable from a live server
@@ -523,6 +539,7 @@ class ServingEngine:
         if self._thread is not None:
             self._thread.join(timeout=30)
             self._thread = None
+        self._publish()
         drain = self.scheduler.pop_ready(self.scheduler.max_queue)
         for qe in drain.admit + drain.expired + drain.cancelled:
             self._finish(qe.item, "cancelled")
@@ -1164,6 +1181,7 @@ class ServingEngine:
         except BaseException as exc:  # noqa: BLE001 — fail loudly, unblock callers
             self._worker_error = exc
             self._running = False
+            self._publish()  # what the last tick finished did not fail
             self.metrics.record_error(repr(exc), source="worker")
             self.flightrecorder.record("worker_error", error=repr(exc))
             if self._telemetry is not None:
@@ -1209,11 +1227,13 @@ class ServingEngine:
         # every queued and in-flight session leaves as a KV payload (or a
         # whole queue entry) before anything else runs this iteration.
         if self._draining and (self._evacuate_peers or self._evacuate_urls):
+            self._publish()  # a session leaves with its stream up to date
             worked |= self._evacuate_step()
 
         # Controller-initiated hot rebalancing (ISSUE 20): export victim
         # sessions and relay them to the requested peer without draining.
         if self._rebalance_queue:
+            self._publish()
             worked |= self._rebalance_step()
 
         with self._phase("admit") as admit:
@@ -1228,12 +1248,13 @@ class ServingEngine:
             # worst instant a replica can die.
             self._decode_ticks += 1
             self.faults.at_decode_tick(self._decode_ticks)
-            events = self.engine.tick()
+            events = self.engine.tick(dispatched=self._publish)
             tick = self.engine.last_tick_s  # (dispatch, wait, emit)
-            with self._phase("deliver") as deliver:
-                self._deliver(events, sum(tick))
-            self._close_period(tick, deliver, n_events=len(events))
+            self._deliver(events, sum(tick))
+            self._close_period(tick, n_events=len(events))
             worked = True
+        else:
+            self._publish()  # no program to publish behind
         self._maybe_emit_engine_record()
         return worked
 
@@ -1328,27 +1349,28 @@ class ServingEngine:
         return Phase(f"serve/{name}", self._clock)
 
     def _open_period(self, t: float) -> dict:
-        """A tick period runs from the end of one decode tick's deliver to
-        the end of the next one's, so the periods tile the worker's time.
-        The phases before the tick accumulate here as they happen."""
+        """A tick period runs from the end of one decode tick to the end
+        of the next one, so the periods tile the worker's time.  The phases
+        around the tick accumulate here as they happen; ``deliver_s`` is the
+        publishing of the tick before (:meth:`_publish`)."""
         return {
             "t": t, "admit_s": 0.0, "prefill_s": 0.0, "chunks": 0,
-            "prefill_tokens": 0, "idle_s": 0.0,
+            "prefill_tokens": 0, "idle_s": 0.0, "deliver_s": 0.0,
             "tokens_before": self.engine.tokens_emitted,
         }
 
-    def _close_period(self, tick, deliver: Phase, n_events: int) -> None:
-        """End the period at the end of ``deliver`` and account for it
+    def _close_period(self, tick, n_events: int) -> None:
+        """End the period now, at the end of its tick, and account for it
         once: the ``kind="tick"`` record, the cumulative phase seconds of
         ``ServingMetrics`` and the flight recorder's coalesced tick entry
         all carry these same clock pairs."""
-        end = deliver.start + deliver.dur_s
+        end = self._clock()
         period, self._period = self._period, self._open_period(end)
         dispatch_s, wait_s, emit_s = tick
         seconds = {
             "admit": period["admit_s"], "prefill": period["prefill_s"],
             "dispatch": dispatch_s, "wait": wait_s, "emit": emit_s,
-            "deliver": deliver.dur_s, "idle": period["idle_s"],
+            "deliver": period["deliver_s"], "idle": period["idle_s"],
         }
         dur_s = end - period["t"]
         # Kept explicit so nothing hides: the engine record, the request
@@ -1381,10 +1403,13 @@ class ServingEngine:
                 "batch": self.engine.tokens_emitted - period["tokens_before"],
                 "queue_depth": self.scheduler.depth,
                 # Assignments of the tick's tokens that landed on experts
-                # held here (dropless expert layers of the grouped engine;
-                # 0 elsewhere).
+                # held here and on zero experts (dropless expert layers of
+                # the paged engine; 0 elsewhere).
                 "moe_rows_local": getattr(
                     self.engine, "last_tick_moe_rows_local", 0
+                ),
+                "moe_zero_assignments": getattr(
+                    self.engine, "last_tick_moe_zero_assignments", 0
                 ),
             }
         )
@@ -2007,10 +2032,16 @@ class ServingEngine:
                 chunk_tokens = self.engine.next_chunk_tokens(slot)
                 if not budget.admits(chunk_tokens):
                     return worked  # budget spent: decode tick runs next
+                delivered = self._period["deliver_s"]
                 with self._phase("prefill_chunk") as chunk:
-                    event = self.engine.prefill_step(slot)
+                    event = self.engine.prefill_step(
+                        slot, dispatched=self._publish
+                    )
                 entry.prefill_s += chunk.dur_s
-                self._period["prefill_s"] += chunk.dur_s
+                # A publish behind the chunk is the period's deliver.
+                self._period["prefill_s"] += chunk.dur_s - (
+                    self._period["deliver_s"] - delivered
+                )
                 self._period["chunks"] += 1
                 self._period["prefill_tokens"] += chunk_tokens
                 budget.spend(chunk_tokens)
@@ -2051,16 +2082,35 @@ class ServingEngine:
             self._slot_entries[event.slot] = entry
 
     def _deliver(self, events: list[TickEvent], tick_s: float) -> None:
+        """Take a tick's events to their requests, now, while a slot still
+        names its request; the streams get them in :meth:`_publish`."""
         self.metrics.on_decode_tick(len(events), tick_s)
         for event in events:
             entry = self._slot_entries.get(event.slot)
             if entry is None:
                 continue  # released between admit and tick (cancellation)
             entry.tokens.append(event.token)
-            entry.stream.put(event.token)
             if event.finished:
                 del self._slot_entries[event.slot]
-                self._finish(entry, event.finished)
+            self._unpublished.append((entry, event.token, event.finished))
+
+    def _publish(self) -> None:
+        """Put the held tokens on their requests' streams and finish the
+        requests that ended (the ``serve/deliver`` phase).  Every reader
+        that wakes wants the interpreter lock, so the worker does this when
+        it has nothing to launch: the engines call it once their next
+        program is in the device's queue, and the worker itself wherever
+        none follows.  At 64 readers the same puts between a tick and the
+        next launch cost the device 4 ms a tick (PERF.md section 6, PR 33)."""
+        if not self._unpublished:
+            return
+        held, self._unpublished = self._unpublished, []
+        with self._phase("deliver") as deliver:
+            for entry, token, finished in held:
+                entry.stream.put(token)
+                if finished:
+                    self._finish(entry, finished)
+        self._period["deliver_s"] += deliver.dur_s
 
     def _finish(
         self, entry: _Entry, reason: str, kv_payload: bytes | None = None

@@ -258,6 +258,7 @@ class SpecEngine(PagedEngine):
             )
         super().__init__(params, config, min_bucket=min_bucket, **paged_kwargs)
         self._refuse_grouped("speculative decoding (its verify pass rewinds)")
+        self._refuse_latent("speculative decoding (its verify pass)")
         # This engine's tick is the verify pass: several rows a slot.
         self.tick_attention_path = cache_kind(config).attention_path(
             config, False, self.blocks_per_slot, self._pool[0]
@@ -442,8 +443,8 @@ class SpecEngine(PagedEngine):
                 return b
         return self._draft_buckets[-1]
 
-    def prefill_step(self, slot: int) -> TickEvent | None:
-        event = super().prefill_step(slot)
+    def prefill_step(self, slot: int, dispatched=None) -> TickEvent | None:
+        event = super().prefill_step(slot, dispatched)
         if event is None or event.finished:
             return event
         # Final chunk landed and the slot decodes on: bring the draft's
@@ -463,10 +464,13 @@ class SpecEngine(PagedEngine):
         )
         return event
 
-    def tick(self) -> list[TickEvent]:
+    def tick(self, dispatched=None) -> list[TickEvent]:
         """One speculative tick (:meth:`_spec_tick`).  Its draft and verify
         programs each sync, so the worker's tick record gets the whole
-        tick as its wait phase and no dispatch or emit share."""
+        tick as its wait phase and no dispatch or emit share, and
+        ``dispatched`` has no program to run behind: it is called first."""
+        if dispatched is not None:
+            dispatched()
         with Phase("serve/tick_wait", self.clock) as wait:
             events = self._spec_tick()
         self.last_tick_s = (0.0, wait.dur_s, 0.0)

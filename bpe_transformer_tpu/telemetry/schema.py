@@ -44,7 +44,7 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     },
     # One decode tick of the serving worker (serving/server.py): where the
     # tick *period* went.  A period runs from the end of the previous
-    # tick's deliver to the end of this one's, so consecutive records tile
+    # tick to the end of this one, so consecutive records tile
     # the worker's time (``t`` + ``dur_s`` = the next record's ``t``, on
     # the serve/* spans' axis).  The phase fields are the clock pairs of
     # the worker's ``serve/*`` profiler annotations, summed over the
@@ -53,13 +53,15 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # prefill-chunk calls of ``prefill_tokens`` prompt tokens,
     # ``dispatch_s`` / ``wait_s`` / ``emit_s`` (the tick: the program's call
     # until it returns, blocked on the device, arrays to events),
-    # ``deliver_s`` (tokens to streams, finished requests), ``idle_s``
+    # ``deliver_s`` (the tick before's tokens to their streams and its
+    # finished requests, behind this period's first launch), ``idle_s``
     # (waiting for work) and ``other_s`` = ``dur_s`` minus the rest, kept
     # explicit.  ``batch`` is the tokens the engine emitted in the period
     # (the tick's, plus the first token of each prefill that completed in
     # it); ``queue_depth`` the scheduler's at the period's end.  Optional
     # ``moe_rows_local``: the tick's expert assignments that landed on
-    # experts held here (grouped paged engine; 0 elsewhere).
+    # experts held here (grouped paged engine; 0 elsewhere); optional
+    # ``moe_zero_assignments``: those that landed on zero-compute experts.
     "tick": {
         "kind", "t", "dur_s", "admit_s", "prefill_s", "chunks",
         "prefill_tokens", "dispatch_s", "wait_s", "emit_s", "deliver_s",

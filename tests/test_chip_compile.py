@@ -334,7 +334,7 @@ def _pool_program(
 
     options = layered_program_options("tpu") if layers_as_calls else None
 
-    from bpe_transformer_tpu.models.decode import init_kv_pool
+    from bpe_transformer_tpu.models.decode import cache_kind, init_paged_pool
     from bpe_transformer_tpu.models.transformer import init_params
     from bpe_transformer_tpu.serving.engine import prepare_serving_weights
     from bpe_transformer_tpu.serving.kvpool import paged_engine as pe
@@ -349,10 +349,13 @@ def _pool_program(
     params, lm_head = _described(jax.eval_shape(weights), one_chip)
     pool = _described(
         jax.eval_shape(
-            lambda: init_kv_pool(config, blocks, bs, BF16, kv_dtype=kv_dtype)
+            lambda: init_paged_pool(config, blocks, bs, BF16, kv_dtype=kv_dtype)
         ),
         one_chip,
     )
+    # The routing counts a kind carries (None for the dense kind).
+    moe = cache_kind(config).zero_counts(config)
+    moe = moe if moe is None else _described(moe, one_chip)
 
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -362,7 +365,7 @@ def _pool_program(
     if name == "tick":
         fn = functools.partial(pe._tick_program, config=config, block_size=bs)
         args = (
-            params, lm_head, pool, None, arr((slots, nbs), I32), arr((slots,), I32),
+            params, lm_head, pool, moe, arr((slots, nbs), I32), arr((slots,), I32),
             arr((slots,), I32), arr((slots,), jnp.bool_),
             arr((slots, 2), jnp.uint32), arr((slots,), F32),
             arr((slots,), I32), arr((slots,), F32),
@@ -371,7 +374,7 @@ def _pool_program(
     if name == "chunk":
         fn = functools.partial(pe._chunk_program, config=config, block_size=bs)
         args = (
-            params, lm_head, pool, None, arr((nbs,), I32), arr((1, 256), I32),
+            params, lm_head, pool, moe, arr((nbs,), I32), arr((1, 256), I32),
             scalar, scalar, arr((2,), jnp.uint32), arr((), F32), scalar,
             arr((), F32),
         )
@@ -639,3 +642,69 @@ def test_ragged_paged_attention(one_chip, monkeypatch, tokens, seqs, pages, wind
         fn, one_chip, ((tokens, 128, 128), BF16), ((2049, 16, 16, 128), BF16),
         ((seqs,), I32), ((seqs, pages), I32), ((seqs + 1,), I32), ((1,), I32),
     )
+
+
+# ------------------------------------- LongCat-Flash-Omni (latent attention)
+
+
+@pytest.mark.parametrize("slots", [64, 8], ids=["64_slots", "8_slots"])
+def test_mla_paged_attention(one_chip, slots):
+    """The tick's absorbed latent attention at the published widths: 64
+    heads against latent rows of 576 values in a pool whose rows are padded
+    to 640 lanes, blocks of 16, a table of 1,024 blocks.  (At 576 lanes the
+    compiler refuses the block's slice: not a whole tile.)"""
+    from bpe_transformer_tpu.kernels.pallas.mla_attention import (
+        mla_paged_attention,
+    )
+
+    def fn(q, pool, tables, counts):
+        return mla_paged_attention(
+            q, pool, tables, counts, rank=512, scale=192 ** -0.5,
+            path="mla_paged", interpret=False,
+        )
+
+    text = _compile(
+        fn, one_chip, ((slots, 64, 576), BF16), ((20481, 16, 640), BF16),
+        ((slots, 1024), I32), ((slots,), I32),
+    )
+    assert "mla_paged_attention" in text
+
+
+@pytest.mark.parametrize(
+    "rows", [768, 6144, 24576], ids=["tick_64_slots", "chunk_512", "chunk_2048"]
+)
+@pytest.mark.parametrize("d_out,d_in", [(2048, 6144), (6144, 2048)], ids=["up", "down"])
+def test_grouped_matmul_at_longcat_widths(one_chip, monkeypatch, rows, d_out, d_in):
+    """12 assignments a token, 16 experts of 2,048 held, hidden 6,144."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _compile(
+        grouped_matmul, one_chip,
+        ((rows, d_in), BF16), ((16, d_out, d_in), BF16), ((16,), I32),
+    )
+
+
+@pytest.mark.parametrize("name", ["tick", "chunk"], ids=["tick", "chunk256"])
+def test_latent_pool_programs(one_chip, on_tpu, name):
+    """The engine's two programs over a latent pool, one double layer at
+    the published widths: both kernels are there (the chunk attends in
+    XLA), the pool's two arrays are aliased whole and never copied."""
+    import json
+    from pathlib import Path
+
+    from bpe_transformer_tpu.models.config import ModelConfig
+
+    path = Path(__file__).resolve().parents[1] / "chipbench/configs/LongCat-Flash-Omni.json"
+    file = json.loads(path.read_text())
+    config = ModelConfig(**{
+        **{k: file[k] for k in file["architecture_keys"]}, "num_layers": 1,
+    })
+    jitted, args, pool = _pool_program(name, config, one_chip, None, slots=8)
+    compiled = jitted.lower(*args).compile()
+    text = compiled.as_text()
+    assert ("mla_paged_attention" in text) == (name == "tick")
+    assert "gmm" in text
+    leaves = jax.tree_util.tree_leaves(pool)
+    assert {_shape_text(a) for a in leaves} == {"bf16[2049,16,640]"} and len(leaves) == 2
+    assert _pool_copies(text, {"bf16[2049,16,640]"}) == []
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= sum(a.size * 2 for a in leaves)

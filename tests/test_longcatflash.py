@@ -1,0 +1,479 @@
+"""The LongCat-Flash block (`LongCat-Flash-Omni`'s language model) at a
+small size on the CPU: the plain forward, the dense latent cache and the
+paged engine over a latent pool (chunks expanded, ticks absorbed, a resume
+after a radix-shared prefix) against ``chipbench/reference_longcatflash.py``
+on seeded float32 weights; the share test that ties a chip's experts and the
+zero experts to the whole layer; the counts; and every refusal of what
+cannot run yet."""
+
+import dataclasses
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+
+from bpe_transformer_tpu.kernels.pallas import mla_attention  # noqa: E402
+from bpe_transformer_tpu.models.config import TS_TEST_CONFIG, ModelConfig  # noqa: E402
+from bpe_transformer_tpu.models.decode import (  # noqa: E402
+    LatentRows,
+    cache_kind,
+    decode_step,
+    init_kv_cache,
+    paged_forward,
+    prefill,
+    slot_cache,
+)
+from bpe_transformer_tpu.models.moe import dropless_moe, route  # noqa: E402
+from bpe_transformer_tpu.models.transformer import forward, init_params  # noqa: E402
+from bpe_transformer_tpu.serving.kvpool.paged_engine import PagedEngine  # noqa: E402
+from chipbench import reference_longcatflash as ref  # noqa: E402
+
+REAL, ZERO, TOP = 16, 8, 4
+
+
+def reference_cfg(held=REAL, offset=0, layers=2) -> dict:
+    """Hidden 64, 4 heads of 8 + 4 / 8, lora ranks 16 / 8, dense 128, 16
+    real experts of width 32 and 8 zero experts, 4 a token."""
+    return {
+        "hidden_size": 64, "ffn_hidden_size": 128, "expert_ffn_hidden_size": 32,
+        "num_layers": layers, "num_attention_heads": 4, "q_lora_rank": 16,
+        "kv_lora_rank": 8, "qk_nope_head_dim": 8, "qk_rope_head_dim": 4,
+        "v_head_dim": 8, "mla_scale_q_lora": True, "mla_scale_kv_lora": True,
+        "routed_scaling_factor": 6, "n_routed_experts": held, "n_experts": REAL,
+        "expert_offset": offset, "zero_expert_num": ZERO, "moe_topk": TOP,
+        "rms_norm_eps": 1e-5, "rope_theta": 10000000, "vocab_size": 64,
+        "context_length": 64,
+    }
+
+
+def program_cfg(c: dict, **more) -> ModelConfig:
+    return ModelConfig(
+        vocab_size=c["vocab_size"], context_length=c["context_length"],
+        d_model=c["hidden_size"], num_layers=c["num_layers"],
+        num_heads=c["num_attention_heads"], d_ff=c["ffn_hidden_size"],
+        rope_theta=c["rope_theta"], attention_kind="mla",
+        q_lora_rank=c["q_lora_rank"], kv_lora_rank=c["kv_lora_rank"],
+        qk_nope_head_dim=c["qk_nope_head_dim"],
+        qk_rope_head_dim=c["qk_rope_head_dim"], v_head_dim=c["v_head_dim"],
+        mla_scale_q_lora=True, mla_scale_kv_lora=True,
+        ffn_type="moe", expert_d_ff=c["expert_ffn_hidden_size"],
+        n_experts=c["n_experts"], n_zero_experts=c["zero_expert_num"],
+        router_top_k=c["moe_topk"], norm_topk_prob=False,
+        routed_scaling_factor=float(c["routed_scaling_factor"]),
+        router_bias=True, experts_held=c["n_routed_experts"],
+        expert_offset=c["expert_offset"], **more,
+    )
+
+
+def small_engine(c: dict, seed=3, **more) -> PagedEngine:
+    args = dict(slots=3, block_size=4, prefill_chunk=8, prefill_buckets=(4, 8))
+    args.update(more)
+    return PagedEngine(ref.weights_from_seed(seed, c), program_cfg(c), **args)
+
+
+SHARES = {"held_all": (REAL, 0), "held_share": (4, 4)}
+
+
+@pytest.mark.parametrize("share", SHARES)
+def test_forward_matches_reference(share):
+    c = reference_cfg(*SHARES[share])
+    w = ref.weights_from_seed(3, c)
+    tokens = np.random.default_rng(0).integers(0, 64, (2, 20))
+    ours = forward(w, jnp.asarray(tokens), program_cfg(c))
+    theirs = ref.forward_logits(w, tokens, c)
+    assert float(jnp.max(jnp.abs(ours - theirs))) < 1e-5
+
+
+def test_init_params_has_the_reference_tree():
+    c = reference_cfg(4, 4)
+    ours = init_params(jax.random.PRNGKey(0), program_cfg(c))
+    theirs = ref.weights_from_seed(3, c)
+    shapes = lambda tree: jax.tree_util.tree_map(lambda a: a.shape, tree)  # noqa: E731
+    assert shapes(ours) == shapes(theirs)
+
+
+def test_dense_cache_matches_reference():
+    """Prefill (many rows) then decode_step token by token (one row)."""
+    c = reference_cfg(4, 4)
+    pc, w = program_cfg(c), ref.weights_from_seed(3, c)
+    tokens = np.random.default_rng(1).integers(0, 64, (2, 24))
+    full = ref.forward_logits(w, tokens, c)
+    logits, cache = prefill(w, jnp.asarray(tokens[:, :9]), pc, init_kv_cache(pc, 2))
+    worst = float(jnp.max(jnp.abs(logits - full[:, 8])))
+    for t in range(9, 24):
+        logits, cache = decode_step(w, jnp.asarray(tokens[:, t]), jnp.asarray(t), cache, pc)
+        worst = max(worst, float(jnp.max(jnp.abs(logits - full[:, t]))))
+    assert worst < 1e-5
+
+
+# ------------------------------------------------- the paged engine's paths
+
+
+def served_logit_error(eng, c, tokens, plen, seed=3):
+    """Prefill ``tokens[:plen]`` in the engine's chunks, then teacher-forced
+    ticks to the end: the widest difference of a tick's logits from the
+    reference's full forward, and the slot."""
+    pc = eng.config
+    full = ref.forward_logits(ref.weights_from_seed(seed, c), tokens[None], c)[0]
+    slot = eng.begin(tokens[:plen], max_new_tokens=len(tokens) - plen, temperature=0.0)
+    while eng.prefill_step(slot) is None:
+        pass
+    worst = 0.0
+    active = np.zeros(eng.n_slots, bool)
+    active[slot] = True
+    for t in range(plen, len(tokens)):
+        tok = np.zeros(eng.n_slots, np.int32)
+        pos = np.zeros(eng.n_slots, np.int32)
+        tok[slot], pos[slot] = tokens[t], t
+        cache = slot_cache(
+            pc, eng._table_rows(), jnp.asarray(pos), jnp.asarray(active),
+            block_size=eng.block_size,
+        )
+        logits, eng._pool, _ = paged_forward(
+            eng._params, jnp.asarray(tok)[:, None], eng._pool, cache, pc,
+            eng._lm_head, row=0,
+        )
+        worst = max(worst, float(jnp.max(jnp.abs(logits[slot] - full[t]))))
+    return worst, slot
+
+
+@pytest.mark.parametrize("tick_path", ["xla", "mla_paged"])
+def test_paged_chunks_and_ticks_match_reference(tick_path, monkeypatch):
+    """A prompt of 11 in chunks of two bucket sizes (8, then 3 in the bucket
+    of 4), then 19 ticks (gathered rows under XLA, or the kernel in
+    interpret mode), all absorbed, against the reference's expanded form."""
+    monkeypatch.setattr(mla_attention, "mla_paged_path", lambda *a, **k: tick_path)
+    c = reference_cfg(4, 4)
+    eng = small_engine(c)
+    assert cache_kind(eng.config) is LatentRows
+    assert eng.tick_attention_path == tick_path
+    tokens = np.random.default_rng(2).integers(0, 64, 30)
+    worst, _ = served_logit_error(eng, c, tokens, 11)
+    assert worst < 1e-5
+    # Latent rows of 12 values, padded to a whole lane tile.
+    assert len(eng._pool) == 2 * 2 and eng._pool[0]["c"].shape[1:] == (4, 128)
+
+
+def test_resume_after_a_radix_shared_prefix_equals_the_request_served_cold():
+    """The second request shares the first's two whole prompt blocks: its
+    chunk resumes at position 8 over latent rows the first one wrote."""
+    c = reference_cfg(4, 4)
+    eng = small_engine(c, prefix_cache=True)
+    rng = np.random.default_rng(5)
+    shared = rng.integers(0, 64, 9)
+    first = np.concatenate([shared, rng.integers(0, 64, 12)])
+    second = np.concatenate([shared, rng.integers(0, 64, 15)])
+    worst, slot = served_logit_error(eng, c, first, 12)
+    assert worst < 1e-5 and eng.slot_shared_len(slot) == 0
+    worst, slot = served_logit_error(eng, c, second, 14)
+    assert eng.slot_shared_len(slot) == 8
+    assert worst < 1e-5
+    gauges = eng.gauges()
+    assert gauges["prefix_cache_hits"] == 8 and gauges["prefix_cache_misses"] == 12 + 6
+
+
+def test_engine_serves_greedy_tokens_the_reference_puts_first():
+    """Three slots at ragged depths through admit/tick, the way the worker
+    drives the engine; counters move and every block comes back."""
+    c = reference_cfg(4, 4)
+    eng = small_engine(c, prefix_cache=False)
+    w = ref.weights_from_seed(3, c)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, 64, n) for n in (13, 5, 9)]
+    seqs = [list(p) for p in prompts]
+    for seq, prompt in zip(seqs, prompts):
+        seq.append(eng.admit(prompt, max_new_tokens=12, temperature=0.0).token)
+    while eng.active_count:
+        for event in eng.tick():
+            seqs[event.slot].append(event.token)
+    for prompt, seq in zip(prompts, seqs):
+        assert len(seq) == len(prompt) + 12
+        full = ref.forward_logits(w, np.asarray(seq)[None], c)[0]
+        for i in range(len(prompt) - 1, len(seq) - 1):
+            assert float(full[i].max() - full[i, seq[i + 1]]) < 1e-5
+    gauges = eng.gauges()
+    assert gauges["kv_blocks_free"] == gauges["kv_blocks_total"]
+    routed = gauges["moe_tokens_routed"]
+    assert routed == 2 * (13 + 5 + 9 + 3 * 11)  # two layers
+    assert 0 < gauges["moe_rows_local"] < TOP * routed
+    assert 0 < gauges["moe_zero_assignments"] < TOP * routed
+    assert 0 < gauges["moe_expert_groups"] <= gauges["moe_rows_local"]
+    assert eng.last_tick_moe_zero_assignments >= 0
+    # A pair is one (query, key) of one sublayer: ticks only, 4 sublayers.
+    ticks = sum(sum(range(n + 1, n + 12)) for n in (13, 5, 9))
+    assert gauges["attn_pairs"] == gauges["attn_kv_positions"] == 4 * ticks
+    assert gauges["kv_bytes_per_token"] == 4 * 12 * 4
+    assert gauges["kv_pool_bytes"] == 4 * eng.allocator.num_blocks * 4 * 128 * 4
+
+
+def test_no_program_compiles_after_the_warm_up():
+    """One request a bucket is the cell's warm-up; chunks after chunks, a
+    chunk that resumes after a shared prefix and ticks after either then
+    run the programs that are there."""
+    eng = small_engine(reference_cfg(4, 4), prefix_cache=True)
+    rng = np.random.default_rng(0)
+
+    def begin(prompt):
+        return eng.begin(prompt, max_new_tokens=6, temperature=0.0)
+
+    for n in (3, 7):
+        slot = begin(rng.integers(0, 64, n))
+        while eng.prefill_step(slot) is None:
+            pass
+        eng.tick(), eng.tick(), eng.release(slot)
+    warm = eng.compiled_programs()
+    assert warm == len(eng.buckets) + 1
+    shared = rng.integers(0, 64, 8)
+    slots = []
+    for n in (5, 2):  # the second finds the first's two blocks in the cache
+        slots.append(begin(np.concatenate([shared, rng.integers(0, 64, n)])))
+        while eng.prefill_step(slots[-1]) is None:
+            pass
+    eng.tick(), eng.tick()
+    assert eng.slot_shared_len(slots[1]) == 8
+    assert eng.compiled_programs() == warm
+
+
+def test_the_kernel_reads_what_the_gathered_rows_read():
+    """`mla_paged_attention` in interpret mode against its XLA stand-in:
+    ragged key counts, an idle slot, tables in any order."""
+    rng = np.random.default_rng(7)
+    slots, heads, width, rank, block, blocks = 5, 4, 12, 8, 4, 6
+    pool = jnp.asarray(rng.normal(size=(40, block, width)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(slots, heads, width)), jnp.float32)
+    tables = jnp.asarray(rng.permutation(39)[: slots * blocks].reshape(slots, blocks) + 1)
+    counts = jnp.asarray([1, 24, 0, 9, 17], jnp.int32)
+    want = mla_attention.mla_paged_attention(
+        q, pool, tables, counts, rank=rank, scale=0.3, path="xla"
+    )
+    got = mla_attention.mla_paged_attention(
+        q, pool, tables, counts, rank=rank, scale=0.3, path="mla_paged"
+    )
+    live = np.asarray(counts) > 0
+    assert float(jnp.max(jnp.abs(got - want)[live])) < 1e-5
+    assert float(jnp.max(jnp.abs(got[~live]))) == 0.0
+
+
+# ------------------------------------------- the expert layer and its shares
+
+
+def layer_weights(seed=7):
+    return ref.weights_from_seed(seed, reference_cfg(layers=1))["layers"][0]["ffn"]
+
+
+def test_all_shares_add_up_to_the_uncut_layer():
+    """The share test: the real parts of all 4 shares of 4 experts, plus the
+    zero experts' part once, equal the uncut reference layer."""
+    uncut = reference_cfg(layers=1)
+    w = layer_weights()
+    h = jax.random.normal(jax.random.PRNGKey(1), (11, 64), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want, _ = ref.moe(h, w, uncut, None)
+    top_i, gates = route(h, w["router"], program_cfg(uncut), w["router_bias"])
+    zero_part = jnp.sum(jnp.where(top_i >= REAL, gates, 0.0), axis=-1)[:, None] * h
+    assert float(jnp.max(jnp.abs(zero_part))) > 0.01
+    real = jnp.zeros_like(h)
+    for offset in range(0, REAL, 4):
+        share = {**w, **{k: w[k][offset:offset + 4] for k in ("w1", "w2", "w3")}}
+        out, counts = dropless_moe(h, share, program_cfg(reference_cfg(4, offset, layers=1)))
+        real = real + out - zero_part
+        assert int(counts[0]) == 11 and int(counts[1]) + int(counts[3]) <= TOP * 11
+    assert float(jnp.max(jnp.abs(real + zero_part - want))) < 1e-5
+
+
+def test_a_token_on_zero_experts_alone_leaves_the_grouped_matmul_no_row():
+    """A selection bias that puts every zero expert first: no assignment is
+    held, no group computed, and the layer returns ``(sum g) h``."""
+    cfg = program_cfg(reference_cfg(4, 4, layers=1))
+    w = layer_weights()
+    w = {**w, **{k: w[k][4:8] for k in ("w1", "w2", "w3")},
+         "router_bias": jnp.where(jnp.arange(REAL + ZERO) >= REAL, 10.0, 0.0)}
+    h = jax.random.normal(jax.random.PRNGKey(2), (9, 64), jnp.float32)
+    out, counts = dropless_moe(h, w, cfg)
+    assert [int(v) for v in counts] == [9, 0, 0, TOP * 9]
+    probs = jax.nn.softmax(h @ w["router"].T, axis=-1)
+    gate = 6.0 * jnp.sum(jax.lax.top_k(probs[:, REAL:], TOP)[0], axis=-1)
+    assert float(jnp.max(jnp.abs(out - gate[:, None] * h))) < 1e-6
+
+
+@pytest.mark.parametrize("rows_valid", [9, 4], ids=["all_rows", "valid_rows"])
+def test_counts_equal_a_count_by_hand(rows_valid):
+    cfg = program_cfg(reference_cfg(4, 4, layers=1))
+    w = layer_weights()
+    w = {**w, **{k: w[k][4:8] for k in ("w1", "w2", "w3")}}
+    h = jax.random.normal(jax.random.PRNGKey(3), (9, 64), jnp.float32)
+    valid = jnp.arange(9) < rows_valid
+    out, counts = dropless_moe(h, w, cfg, valid=valid)
+    top_i = np.asarray(route(h, w["router"], cfg, w["router_bias"])[0])[:rows_valid]
+    held = (top_i >= 4) & (top_i < 8)
+    assert [int(v) for v in counts] == [
+        rows_valid, int(held.sum()), len(np.unique(top_i[held])),
+        int((top_i >= REAL).sum()),
+    ]
+    whole, _ = dropless_moe(h, w, cfg)
+    assert float(jnp.max(jnp.abs(out[:rows_valid] - whole[:rows_valid]))) < 1e-6
+    assert not np.asarray(out[rows_valid:]).any()
+
+
+# --------------------------------------- how the reference scores a served run
+
+
+def test_routing_choices_follow_near_ties_of_what_is_computed_here():
+    c = {**reference_cfg(2, 2), "n_experts": 5, "zero_expert_num": 3, "moe_topk": 2}
+    # Outputs 0-4 real (2 and 3 held), 5-7 zero.
+    logits = np.asarray([
+        [3.0, 2.0, 1.0, 0.5, 0.0, -1.0, -2.0, -3.0],   # decided
+        [3.0, 2.0, 0.0, -1.0, 1.95, -1.0, -2.0, -3.0],  # 1 against 4: both absent
+        [3.0, 0.0, 2.0, -1.0, 1.95, -1.0, -2.0, -3.0],  # 2 (held) against 4
+        [3.0, 0.0, -1.0, -1.0, 2.0, 1.95, -2.0, -3.0],  # 4 (absent) against 5 (zero)
+        [3.0, 0.0, -1.0, -1.0, -1.0, 2.0, 1.95, -3.0],  # 5 against 6: both zero
+        [3.0, 0.0, -1.0, 1.95, -1.0, 2.0, -2.0, -3.0],  # 5 (zero) against 3 (held)
+    ], np.float32)
+    sets = [[sorted(s.tolist()) for _, s in row] for row in ref.routing_choices(logits, c)]
+    assert sets == [
+        [[0, 1]], [[0, 1]], [[0, 2], [0, 4]], [[0, 4], [0, 5]], [[0, 5]],
+        [[0, 5], [0, 3]],
+    ]
+
+
+def test_rows_of_decided_positions_equal_the_full_forward(monkeypatch):
+    """With no near tie to follow, every position is one row and equals the
+    full forward - computed whole, or after a prefix computed before it."""
+    monkeypatch.setattr(ref, "ROUTER_MARGIN", 0.0)
+    c = reference_cfg(4, 4)
+    w = ref.weights_from_seed(3, c)
+    tokens = np.random.default_rng(4).integers(0, 64, 24).astype(np.int32)
+    full, latents = ref.hidden_states(w, tokens, c)
+    _, before = ref.hidden_states(w, tokens[:8], c)
+    resumed, again = ref.hidden_states(w, tokens[8:], c, before=before)
+    assert float(jnp.max(jnp.abs(resumed - full[8:]))) < 1e-5
+    assert float(jnp.max(jnp.abs(again[1][1][0] - latents[1][1][0]))) < 1e-5
+    rows, origin = ref.followed_routings(w, c, tokens, latents, 5, 24)
+    assert origin.tolist() == list(range(19))
+    assert float(np.max(np.abs(rows - np.asarray(full[5:24])))) < 2e-5
+
+
+def test_served_gaps_of_the_references_own_greedy_tokens(monkeypatch):
+    """Two sequences that share their first 8 positions: the prefix goes
+    through once; the reference's own tokens read no gap, others do."""
+    monkeypatch.setattr(ref, "PREFIX_STEP", 4)
+    c = reference_cfg(4, 4)
+    w = ref.weights_from_seed(11, c, jnp.bfloat16)
+    rng = np.random.default_rng(6)
+    shared = rng.integers(0, 64, 9).tolist()
+    sequences = []
+    for extra in (2, 5):
+        prompt = shared + rng.integers(0, 64, extra).tolist()
+        ids = list(prompt)
+        for _ in range(5):
+            ids.append(int(jnp.argmax(ref.forward_logits(w, np.asarray([ids]), c)[0, -1])))
+        sequences.append((prompt, ids[len(prompt):]))
+    assert ref.shared_prefix(sequences) == 8
+    assert max(ref.served_gaps(11, c, sequences)) < 1e-4
+    wrong = [(p, [(t + 1) % 64 for t in served]) for p, served in sequences]
+    assert min(ref.served_gaps(11, c, wrong)) > 1e-3
+    assert all(g >= 0 for g in ref.served_gaps(11, c, sequences, control=True))
+
+
+# ---------------------------------------------------------------- refusals
+
+
+@pytest.mark.parametrize(
+    "more",
+    [dict(kv_dtype="int8"), dict(weight_dtype="int8"), dict(fused_sampling=True)],
+    ids=["kv_int8", "weight_int8", "fused_sampling"],
+)
+def test_engine_refuses_at_construction(more):
+    with pytest.raises(ValueError, match="latent pool|double layer"):
+        small_engine(reference_cfg(4, 4), **more)
+
+
+@pytest.mark.parametrize("what", ["extend_blocks", "export_slot", "import_slot"])
+def test_engine_refuses_speculation_scratch_and_migration(what):
+    eng = small_engine(reference_cfg(4, 4))
+    call = {
+        "extend_blocks": lambda: eng.extend_blocks(0, 8),
+        "export_slot": lambda: eng.export_slot(0),
+        "import_slot": lambda: eng.validate_import_meta({"format": 1}),
+    }[what]
+    with pytest.raises(NotImplementedError, match="latent pool"):
+        call()
+
+
+@pytest.mark.parametrize(
+    "more",
+    [dict(paged=False), dict(speculate_k=2), dict(role="prefill"), dict(role="decode")],
+    ids=["dense_engine", "speculation", "prefill_role", "decode_role"],
+)
+def test_serving_engine_refuses(more):
+    from bpe_transformer_tpu.serving.server import ServingEngine
+
+    c = reference_cfg(4, 4)
+    args = dict(paged=True, block_size=4, prefill_chunk=8)
+    args.update(more)
+    with pytest.raises(ValueError, match="latent"):
+        ServingEngine(ref.weights_from_seed(3, c), program_cfg(c), **args)
+
+
+def test_spec_and_slot_pool_engines_refuse():
+    from bpe_transformer_tpu.serving.engine import SlotPoolEngine
+    from bpe_transformer_tpu.serving.spec.draft import DraftSpec
+    from bpe_transformer_tpu.serving.spec.engine import SpecEngine
+
+    c = reference_cfg(4, 4)
+    w = ref.weights_from_seed(3, c)
+    with pytest.raises(NotImplementedError, match="latent pool"):
+        SpecEngine(w, program_cfg(c), draft=DraftSpec(), speculate_k=2,
+                   block_size=4, prefill_chunk=8)
+    with pytest.raises(ValueError, match="latent attention"):
+        SlotPoolEngine(w, program_cfg(c))
+    with pytest.raises(NotImplementedError, match="several rows a slot"):
+        slot_cache(program_cfg(c), jnp.zeros((3, 16), jnp.int32),
+                   jnp.zeros((3, 2), jnp.int32), block_size=4)
+
+
+def test_scan_layers_and_training_are_refused():
+    with pytest.raises(ValueError, match="scan_layers"):
+        program_cfg(reference_cfg(4, 4), scan_layers=True)
+    from bpe_transformer_tpu.training.train_step import make_loss_fn
+
+    with pytest.raises(ValueError, match="training is not supported"):
+        make_loss_fn(program_cfg(reference_cfg(4, 4)))
+
+
+@pytest.mark.parametrize(
+    "change, message",
+    [
+        (dict(attention_kind="mha"), "are latent"),
+        (dict(kv_lora_rank=0), "needs positive"),
+        (dict(qk_rope_head_dim=3), "even"),
+        (dict(num_kv_heads=2), "no K/V heads"),
+        (dict(parallel_block=True), "contradict"),
+        (dict(ffn_type=None, experts_held=None), "expert layer|ffn_type"),
+    ],
+    ids=["mla_alone", "no_rank", "odd_rope", "kv_heads", "parallel", "no_experts"],
+)
+def test_config_refuses_contradictions(change, message):
+    with pytest.raises(ValueError, match=message):
+        dataclasses.replace(program_cfg(reference_cfg(4, 4)), **change)
+
+
+def test_config_properties_and_defaults():
+    cfg = program_cfg(reference_cfg(4, 4))
+    assert (cfg.d_head, cfg.rope_dim, cfg.latent_width) == (12, 4, 12)
+    assert (cfg.q_lora_scale, cfg.kv_lora_scale) == (2.0, 8 ** 0.5)
+    assert (cfg.attn_sublayers, cfg.moe_d_ff, cfg.router_outputs) == (2, 32, 24)
+    assert cfg.dropless_block and cfg.local_experts == 4
+    plain = TS_TEST_CONFIG
+    assert not plain.latent_block and not plain.dropless_block
+    assert (plain.attn_sublayers, plain.rope_dim, plain.moe_d_ff) == (1, plain.d_head, plain.d_ff)
+    for field, message in [("n_zero_experts", "ffn_type"), ("q_lora_rank", "latent")]:
+        with pytest.raises(ValueError, match=message):
+            dataclasses.replace(plain, **{field: 4})
+    with pytest.raises(ValueError, match="parallel block only"):
+        dataclasses.replace(plain, ffn_type="moe", n_experts=4, norm_topk_prob=False)

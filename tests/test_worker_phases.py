@@ -148,6 +148,51 @@ def test_other_engines_hand_over_their_tick_split(params, kind):
         assert all(t["dispatch_s"] > 0 and t["emit_s"] > 0 for t in ticks)
 
 
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
+def test_a_ticks_tokens_are_published_behind_the_next_launch(params, paged):
+    """The worker holds a tick's tokens until the next program is queued,
+    and they still reach the request the slot named when the tick ran: one
+    slot, so the second request takes the slot over while the first one's
+    last token is held."""
+    engine = dict(paged=True, block_size=4, prefill_chunk=8) if paged else {}
+    serving = ServingEngine(params, CFG, slots=1, min_bucket=8, **engine)
+    held_at_launch, events_of_tick = [], []
+    real_tick = serving.engine.tick
+
+    def tick(dispatched=None):
+        def spy():
+            held_at_launch.append(len(serving._unpublished))
+            dispatched()
+            assert not serving._unpublished
+
+        events = real_tick(dispatched=spy)
+        events_of_tick.append(len(events))
+        return events
+
+    serving.engine.tick = tick
+    requests = [
+        Request(prompt_ids=tuple(range(10 * i + 1, 10 * i + 6)),
+                max_new_tokens=4 + i, temperature=0.0, seed=i)
+        for i in range(2)
+    ]
+    with serving:
+        handles = [serving.submit(r) for r in requests]
+        streamed = [list(h.tokens()) for h in handles]
+        results = [h.result(timeout=300) for h in handles]
+        alone = [
+            serving.generate(r.prompt_ids, max_new_tokens=r.max_new_tokens,
+                             temperature=0.0)
+            for r in requests
+        ]
+    assert [len(s) for s in streamed] == [4, 5]
+    for got, result, want in zip(streamed, results, alone):
+        assert tuple(got) == result.token_ids == want.token_ids
+    # What a launch found held is the tick before it, where one ran on; a
+    # tick that emptied the engine was published at once.
+    assert held_at_launch[0] == 0 and max(held_at_launch) == 1
+    assert sum(events_of_tick) == sum(len(r.token_ids) - 1 for r in results + alone)
+
+
 # ------------------------------------------------------------ the profiler
 
 

@@ -85,8 +85,14 @@ DEFAULT_BUDGET_S = 800.0
 #: cases in 27 s), a tick with idle slots against the XLA path and the new
 #: `stats()` counters (tests/test_kvpool.py, 4 cases), and the kernel and
 #: the 128-slot tick compiled for the described v5e (tests/
-#: test_chip_compile.py, 6 cases, 1-10 s each).
-DEFAULT_MAX_TESTS = 880
+#: test_chip_compile.py, 6 cases, 1-10 s each).  Raised 880 -> 940 in PR 33
+#: (907 collected, 46 added): the LongCat-Flash block against its reference
+#: on every path, the share test, the counts and each refusal
+#: (tests/test_longcatflash.py, 36 cases in about 100 s), and the latent
+#: tick kernel, the grouped matmul at its widths and the two latent pool
+#: programs compiled for the described v5e (tests/test_chip_compile.py, 10
+#: cases, 1-12 s each).
+DEFAULT_MAX_TESTS = 940
 
 #: Pytest summary trailer: "== 398 passed, 27 deselected in 612.34s =="
 #: (also plain "in 612.34s (0:10:12)" forms).
