@@ -1,28 +1,25 @@
 """Fused final-projection + sampling: the decode tick's tail as ONE kernel.
 
 The unfused tick tail is a chain: head matmul -> (slots, vocab) f32
-logits to HBM -> `filter_logits` (TWO full O(V log V) sorts for runtime
-top-k/top-p) -> masked logits to HBM -> `jax.random.categorical` (gumbel
-noise + argmax) — several vocab-sized HBM round trips and a pile of XLA
-sort programs to emit ONE token per slot.  This kernel collapses the
-whole tail: the head streams through VMEM once (int8 weights dequantize
-in registers, `ops/quant.py` layout), logits accumulate in a VMEM
-scratch and never reach HBM, and the filtering + sampling run in the
-same program.
+logits to HBM -> `filter_logits` (the top-k and nucleus threshold
+searches, each a few dozen passes over the logits in HBM) -> masked
+logits to HBM -> `jax.random.categorical` (gumbel noise + argmax) —
+several vocab-sized HBM round trips to emit ONE token per slot.  This
+kernel collapses the whole tail: the head streams through VMEM once per
+row tile (int8 weights dequantize in registers, `ops/quant.py` layout),
+logits accumulate in a VMEM scratch and never reach HBM, and the
+filtering + sampling run in the same program.
 
 **Sort-free exact filtering.**  Runtime top-k/top-p need order
-statistics (the k-th largest logit; the nucleus cutoff), which XLA gets
-from full sorts.  Here both cutoffs come from a 32-step *radix descent
-over order-preserving uint32 keys*: map each f32 logit to a uint32 whose
-integer order equals the float order (sign-flip trick), then build the
-threshold bit by bit from the MSB, counting (top-k) or mass-summing
-(top-p) against each candidate prefix.  32 vectorized passes over the
-VMEM-resident logits replace the sort — and the thresholds are EXACT
-(they land on representable key values), so the keep sets match
-`serving.engine.filter_logits`'s sorted-cutoff semantics bit for bit
-(the only fp caveat: the nucleus mass comparison sums in a different
-order than the sorted cumsum, so a logit sitting within one ulp of the
-nucleus boundary can flip — measure-zero for real logits).
+statistics (the k-th largest logit; the nucleus cutoff).  Both come from
+the 32-step radix descents over order-preserving uint32 keys of
+`ops/sampling.py` — the one implementation `serving.engine.filter_logits`
+runs under XLA too — here over the VMEM-resident logits of a row tile.
+The thresholds are EXACT (they land on representable key values), so the
+keep sets match a sorted cut-off's bit for bit (the only fp caveat: the
+nucleus mass comparison sums in a different order than a sorted cumsum,
+so a logit sitting within one ulp of the nucleus boundary can flip —
+measure-zero for real logits).
 
 **Sampling.**  ``jax.random.categorical(key, masked)`` IS
 ``argmax(masked + gumbel(key, shape))`` — so the caller draws the gumbel
@@ -57,6 +54,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from bpe_transformer_tpu.kernels.pallas.runtime import pick_block
 from bpe_transformer_tpu.ops.core import MASK_VALUE as NEG_INF
+from bpe_transformer_tpu.ops.sampling import (
+    nucleus_threshold,
+    okey,
+    topk_threshold,
+)
 
 SUBLANES = 8
 LANE = 128
@@ -76,21 +78,12 @@ def _pick_block_v(v: int, target: int = 2048) -> tuple[int, int]:
 
 def _pick_block_r(r_pad: int, v_pad: int) -> int:
     """Row tile: every vocab-sized operand, the scratch and the finalize's
-    temporaries live at this many rows, and Mosaic unrolls the 64 radix
-    passes over all of them — so rows shrink as the vocabulary grows (32
-    rows up to 8k, 8 rows at 32k; 24 rows at 32k overflowed the v5e's
-    16 MB scoped VMEM)."""
+    temporaries live at this many rows, and the 64 radix passes run over
+    all of them — so rows shrink as the vocabulary grows (32 rows up to
+    8k, 8 rows at 32k; 24 rows at 32k overflowed the v5e's 16 MB scoped
+    VMEM while the passes were unrolled)."""
     rows = 32 * 8192 // v_pad // SUBLANES * SUBLANES
     return pick_block(r_pad, min(32, max(SUBLANES, rows)), SUBLANES)
-
-
-def _okey(x):
-    """f32 -> uint32 whose unsigned integer order equals the float order
-    (IEEE sign-flip trick; NaN-free inputs assumed)."""
-    b = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
-    return jnp.where(
-        (b >> jnp.uint32(31)) > 0, ~b, b | jnp.uint32(0x80000000)
-    )
 
 
 def _argmax_first(x):
@@ -99,39 +92,6 @@ def _argmax_first(x):
     m = jnp.max(x, axis=-1, keepdims=True)
     iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
     return jnp.min(jnp.where(x == m, iota, v), axis=-1, keepdims=True)
-
-
-def _topk_threshold(keys, kk):
-    """Per row, the uint32 key of the ``kk``-th largest entry (ties give
-    the shared key): radix descent for the largest ``t`` with
-    ``count(keys >= t) >= kk``.  ``keys`` (R, V) uint32, ``kk`` (R, 1)
-    int32 in [1, V]."""
-    t = jnp.zeros(kk.shape, jnp.uint32)
-    for bit in range(31, -1, -1):
-        cand = t | jnp.uint32(1 << bit)
-        cnt = jnp.sum(
-            (keys >= cand).astype(jnp.int32), axis=-1, keepdims=True
-        )
-        t = jnp.where(cnt >= kk, cand, t)
-    return t
-
-
-def _nucleus_threshold(keys, e, p_mass):
-    """Per row, the smallest uint32 ``t`` whose strictly-above mass
-    ``sum(e[keys > t])`` is below ``p_mass`` — the value-space nucleus
-    cutoff (an entry x is kept iff the mass strictly above it is < p,
-    which is exactly the sorted-cumsum keep rule of ``filter_logits``).
-    ``e`` must be 0 at already-dropped entries."""
-    t = jnp.zeros(p_mass.shape, jnp.uint32)
-    for bit in range(31, -1, -1):
-        # Max completion with this bit still 0: if even it satisfies the
-        # predicate, the minimum does too with bit 0; else the bit is 1.
-        trial = t | jnp.uint32((1 << bit) - 1)
-        g = jnp.sum(
-            jnp.where(keys > trial, e, 0.0), axis=-1, keepdims=True
-        )
-        t = jnp.where(g < p_mass, t, t | jnp.uint32(1 << bit))
-    return t
 
 
 def _filter_rows(logits, temps, top_ks, top_ps, vocab):
@@ -148,18 +108,18 @@ def _filter_rows(logits, temps, top_ks, top_ps, vocab):
         logits = jnp.where(cols < vocab, logits, NEG_INF)
     greedy = _argmax_first(logits)
     scaled = logits / jnp.maximum(temps, 1e-6)
-    keys = _okey(scaled)
+    keys = okey(scaled)
 
     kk_raw = top_ks.astype(jnp.int32)
     kk = jnp.where(kk_raw > 0, jnp.clip(kk_raw, 1, vocab), vocab)
-    tk = _topk_threshold(keys, kk)
+    tk = topk_threshold(keys, kk)
     keep_k = keys >= tk
     masked1 = jnp.where(keep_k, scaled, NEG_INF)
 
     m2 = jnp.max(masked1, axis=-1, keepdims=True)
     e = jnp.where(keep_k, jnp.exp(masked1 - m2), 0.0)
     z = jnp.sum(e, axis=-1, keepdims=True)
-    tp = _nucleus_threshold(keys, e, top_ps * z)
+    tp = nucleus_threshold(keys, e, top_ps * z)
     # The max (and its value-ties) always survives, as in filter_logits'
     # keep[..., 0] = True — value-based masking keeps every tie.
     keep = keep_k & ((keys >= tp) | (masked1 == m2))

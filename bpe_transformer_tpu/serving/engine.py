@@ -46,10 +46,16 @@ from jax import lax
 from bpe_transformer_tpu.models.config import ModelConfig
 from bpe_transformer_tpu.models.decode import decode_step, init_kv_cache, prefill
 from bpe_transformer_tpu.models.transformer import lm_head_weight
+from bpe_transformer_tpu.ops.sampling import (
+    nucleus_threshold,
+    okey,
+    topk_threshold,
+)
 from bpe_transformer_tpu.telemetry.spans import Phase
 
-#: Runtime encodings for "knob disabled" — the sampler is branch-free so
-#: every slot shares one program regardless of which knobs are in play.
+#: Runtime encodings for "knob disabled" — the knobs are values the
+#: sampler reads, so every slot shares one program regardless of which
+#: knobs are in play.
 TOP_K_DISABLED = 0
 TOP_P_DISABLED = 2.0
 
@@ -124,57 +130,98 @@ def default_prefill_buckets(
 
 def filter_logits(logits, temps, top_ks, top_ps):
     """Temperature-scale + top-k/top-p mask ``(batch, vocab)`` logits with
-    RUNTIME ``(batch,)`` knobs — the filtering half of :func:`sample_tokens`.
+    RUNTIME ``(batch,)`` knobs — the filtering half of :func:`sample_tokens`:
+    the scaled logits with the dropped entries at ``-inf``.
 
     Split out so the speculative-decoding accept/resample math
     (`serving/spec/`) can reach the *modified distribution* itself
     (``softmax`` of this return value), not just a sample from it: the
     Leviathan acceptance rule must compare draft and target probabilities
     under exactly the knobs the sampler would have applied.
+
+    Both filters are value thresholds with ties kept, found without a sort
+    (`ops/sampling.py`), and each search runs only where a row of the
+    batch asks for its filter: ``top_ks <= 0`` and ``top_ps >= 1`` are
+    "off", and a row with a filter off keeps everything whatever the other
+    rows ask for.  ``top_p == 1.0`` exactly is off too: the sorted
+    cumulative sum this replaced (until PR 34) dropped the tail whose mass
+    float32 rounds away under 1.0.  The predicates read the knobs alone,
+    never the temperatures, so a row's result does not depend on its
+    neighbours.
     """
     vocab = logits.shape[-1]
     scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+    k_on, p_on = (top_ks > 0)[:, None], (top_ps < 1.0)[:, None]
+    # The threshold key that keeps a whole row.
+    keep_all = jnp.zeros((logits.shape[0], 1), jnp.uint32)
+    # Each search makes its own keys: keys made once out here and handed to
+    # the two `cond`s are an operand the loops read from HBM on every pass,
+    # 0.95 ms a 128-row tick against 0.33 (PERF.md section 6, PR 34).
 
     # top-k: keep everything >= the k-th largest (ties included, matching
-    # the static sampler); k <= 0 disables by using the minimum as cutoff.
+    # the static sampler), k clipped to the vocabulary.
     with jax.named_scope("sample/top_k"):
-        sorted_desc = jnp.sort(scaled, axis=-1)[..., ::-1]
-        k_idx = jnp.where(top_ks > 0, jnp.clip(top_ks, 1, vocab), vocab) - 1
-        kth = jnp.take_along_axis(sorted_desc, k_idx[:, None], axis=-1)
-        masked = jnp.where(scaled < kth, -jnp.inf, scaled)
 
-    # top-p over the top-k-masked distribution (softmax renormalizes the
-    # survivors, as the static sampler does by masking before nucleus).
+        def kth_largest():
+            kk = jnp.clip(top_ks, 1, vocab)[:, None]
+            return jnp.where(k_on, topk_threshold(okey(scaled), kk), keep_all)
+
+        tk = lax.cond(jnp.any(k_on), kth_largest, lambda: keep_all)
+
+    # top-p over the top-k survivors' softmax (renormalized over them, as
+    # the static sampler does by masking before nucleus): an entry stays
+    # while the mass strictly above it is under top_p; the row's maximum
+    # always stays.
     with jax.named_scope("sample/top_p"):
-        sorted_m = jnp.sort(masked, axis=-1)[..., ::-1]
-        probs = jax.nn.softmax(sorted_m, axis=-1)
-        cum = jnp.cumsum(probs, axis=-1)
-        keep = (cum - probs) < top_ps[:, None]  # mass BEFORE each token
-        keep = keep.at[:, 0].set(True)  # the argmax always survives
-        cutoff = jnp.min(jnp.where(keep, sorted_m, jnp.inf), axis=-1)
-        return jnp.where(masked < cutoff[:, None], -jnp.inf, masked)
+
+        def nucleus():
+            keys = okey(scaled)
+            top = jnp.max(scaled, axis=-1, keepdims=True)
+            e = jnp.where(keys >= tk, jnp.exp(scaled - top), 0.0)
+            z = jnp.sum(e, axis=-1, keepdims=True)
+            tp = nucleus_threshold(keys, e, top_ps[:, None] * z)
+            return jnp.where(p_on, jnp.minimum(tp, okey(top)), keep_all)
+
+        tp = lax.cond(jnp.any(p_on), nucleus, lambda: keep_all)
+
+    return jnp.where(okey(scaled) >= jnp.maximum(tk, tp), scaled, -jnp.inf)
 
 
 def sample_tokens(logits, keys, temps, top_ks, top_ps):
     """Per-row sampling with RUNTIME knobs: ``temps`` (0 = greedy),
-    ``top_ks`` (0 = disabled), ``top_ps`` (>= 1 effectively disabled).
+    ``top_ks`` (0 = disabled), ``top_ps`` (>= 1 disabled).
 
     Mirrors `models/decode._sample_from_logits` semantics per row — scale by
     temperature, top-k threshold with ties kept, then nucleus filtering on
     the top-k-renormalized distribution (:func:`filter_logits`) — but with
     every knob a traced ``(batch,)`` vector, so one compiled program serves
-    any knob mix.  The cost is a full O(V log V) sort instead of
-    ``lax.top_k`` — the price of runtime ``k``; at serving batch sizes the
-    decode forward dominates.
+    any knob mix.  A greedy row's token is the raw ``argmax`` and never
+    reads its filtered row, so its knobs go to the filter as "off": a
+    batch of greedy rows runs neither search.
     """
-    # Two blocks of one scope: the ops keep the order they had, so the
-    # compiled program is the one it was (scopes are metadata only).
     with jax.named_scope("sample/draw"):
         greedy = jnp.argmax(logits, axis=-1)
-    masked = filter_logits(logits, temps, top_ks, top_ps)
+    sampling = temps > 0.0
+    masked = filter_logits(
+        logits, temps,
+        jnp.where(sampling, top_ks, TOP_K_DISABLED),
+        jnp.where(sampling, top_ps, TOP_P_DISABLED),
+    )
     with jax.named_scope("sample/draw"):
         sampled = jax.vmap(jax.random.categorical)(keys, masked)
-        return jnp.where(temps > 0.0, sampled, greedy)
+        return jnp.where(sampling, sampled, greedy)
+
+
+def filters_asked(active, temps, top_ks, top_ps) -> tuple[bool, bool]:
+    """``(top-k, top-p)``: whether a live sampled slot asks for that filter
+    - what the two searches of the tick's sampler hang on (the tick program
+    hands a vacant slot over as greedy), read on the host from the arrays
+    the engine is about to hand the program."""
+    sampled = active & (temps > 0.0)
+    return (
+        bool((sampled & (top_ks > 0)).any()),
+        bool((sampled & (top_ps < 1.0)).any()),
+    )
 
 
 def _prefill_program(
@@ -244,7 +291,10 @@ def _tick_program(
             params, tokens, positions, cache, config, lm_head=lm_head,
             active=active,
         )
-        nxt = sample_tokens(logits, subs, temps, top_ks, top_ps)
+        # A vacant slot goes in as a greedy row: it asks for no search.
+        nxt = sample_tokens(
+            logits, subs, jnp.where(active, temps, 0.0), top_ks, top_ps
+        )
     nxt = jnp.where(active, nxt, tokens)
     keys_next = jnp.where(active[:, None], keys_next, keys)
     positions = jnp.where(active, positions + 1, positions)
@@ -366,6 +416,10 @@ class SlotPoolEngine:
         )
 
         self.ticks = 0
+        #: Ticks in which a live sampled slot asked for top-k / for top-p:
+        #: how often each of the sampler's searches ran.
+        self.sample_topk_ticks = 0
+        self.sample_topp_ticks = 0
         self.tokens_emitted = 0
         #: The clock of the tick phases; the serving worker sets its own.
         self.clock = time.monotonic
@@ -503,6 +557,11 @@ class SlotPoolEngine:
         if not self._active.any():
             return []
         with Phase("serve/tick_dispatch", self.clock) as dispatch:
+            asked = filters_asked(
+                self._active, self._temps, self._top_ks, self._top_ps
+            )
+            self.sample_topk_ticks += asked[0]
+            self.sample_topp_ticks += asked[1]
             tokens, positions, keys, self._cache = self._tick_jit(
                 self._params, self._lm_head, self._cache, self._tokens,
                 self._positions, self._active, self._keys, self._temps,

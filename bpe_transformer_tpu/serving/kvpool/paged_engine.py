@@ -73,6 +73,7 @@ from bpe_transformer_tpu.serving.engine import (
     SlotPoolEngine,
     TickEvent,
     default_prefill_buckets,
+    filters_asked,
     gumbel_rows,
     prepare_serving_weights,
     sample_tokens,
@@ -153,7 +154,10 @@ def _tick_program(
         gumbel = gumbel_rows(subs, config.vocab_size)
         nxt = fused_head_sample(out, lm_head, temps, top_ks, top_ps, gumbel)
     else:
-        nxt = sample_tokens(out, subs, temps, top_ks, top_ps)
+        # A vacant slot goes in as a greedy row: it asks for no search.
+        nxt = sample_tokens(
+            out, subs, jnp.where(active, temps, 0.0), top_ks, top_ps
+        )
     nxt = jnp.where(active, nxt, tokens)
     keys_next = jnp.where(active[:, None], keys_next, keys)
     positions = jnp.where(active, positions + 1, positions)
@@ -542,6 +546,10 @@ class PagedEngine:
         )
 
         self.ticks = 0
+        #: Ticks in which a live sampled slot asked for top-k / for top-p:
+        #: how often each of the sampler's searches ran.
+        self.sample_topk_ticks = 0
+        self.sample_topp_ticks = 0
         self.tokens_emitted = 0
         #: The clock of the tick phases; the serving worker sets its own.
         self.clock = time.monotonic
@@ -1391,6 +1399,11 @@ class PagedEngine:
             self.attn_kv_positions += keys_read
             self.tick_live_keys += live
             self.tick_table_keys += self._tables.size * self.block_size
+            asked = filters_asked(
+                self._active, self._temps, self._top_ks, self._top_ps
+            )
+            self.sample_topk_ticks += asked[0]
+            self.sample_topp_ticks += asked[1]
             tokens, positions, keys, _, moe = self._in_place(
                 "tick", self._tick_jit,
                 self._params, self._lm_head, self._pool, self._moe_pending,

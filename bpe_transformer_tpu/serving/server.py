@@ -945,7 +945,7 @@ class ServingEngine:
         # norms/ffn intermediates at d_ff ~ 2.7 d) — an estimate, labeled
         # as such.  The vocab-sized tail: unfused pays ~3 (slots, vocab)
         # f32 round trips (logits, filter_logits' masked copy, the
-        # categorical gumbel; the sort passes are extra, uncounted);
+        # categorical gumbel; the search passes are extra, uncounted);
         # fused still pays ONE — the caller-side gumbel tensor the kernel
         # reads (drawing it in-kernel would delete it; noted, not done) —
         # so fusion shrinks the term 3x, never to zero.
@@ -988,6 +988,9 @@ class ServingEngine:
             "active_slots": self.engine.active_count,
             "queue_depth": self.scheduler.depth,
             "ticks": self.engine.ticks,
+            # Ticks whose sampler ran its top-k / its nucleus search.
+            "sample_topk_ticks": self.engine.sample_topk_ticks,
+            "sample_topp_ticks": self.engine.sample_topp_ticks,
             "tokens_emitted": self.engine.tokens_emitted,
             "requests_finished": self._requests_finished,
             "compiled_programs": self.engine.compiled_programs(),

@@ -242,9 +242,16 @@ def _propose_program(
     import jax.numpy as jnp
 
     from bpe_transformer_tpu.models.decode import decode_step
-    from bpe_transformer_tpu.serving.engine import filter_logits
+    from bpe_transformer_tpu.serving.engine import (
+        TOP_K_DISABLED,
+        TOP_P_DISABLED,
+        filter_logits,
+    )
 
     vocab = config.vocab_size
+    # A vacant slot asks for no search.
+    top_ks = jnp.where(active, top_ks, TOP_K_DISABLED)
+    top_ps = jnp.where(active, top_ps, TOP_P_DISABLED)
 
     def body(carry, _):
         tok, pos, cache, keys = carry

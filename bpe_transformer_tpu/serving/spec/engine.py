@@ -55,10 +55,13 @@ from bpe_transformer_tpu.models.decode import (
     slot_cache,
 )
 from bpe_transformer_tpu.serving.engine import (
+    TOP_K_DISABLED,
+    TOP_P_DISABLED,
     SlotPoolEngine,
     TickEvent,
     default_prefill_buckets,
     filter_logits,
+    filters_asked,
 )
 from bpe_transformer_tpu.serving.kvpool.blocks import NoFreeBlocksError
 from bpe_transformer_tpu.serving.kvpool.paged_engine import PagedEngine
@@ -184,7 +187,12 @@ def _spec_verify_program(
         # threshold.
         flat = logits.reshape(s * k1, vocab)
         rep = lambda a: jnp.repeat(a, k1, axis=0)  # noqa: E731
-        filt = filter_logits(flat, rep(temps), rep(top_ks), rep(top_ps))
+        # A vacant slot's rows ask for no search.
+        filt = filter_logits(
+            flat, rep(temps),
+            rep(jnp.where(active, top_ks, TOP_K_DISABLED)),
+            rep(jnp.where(active, top_ps, TOP_P_DISABLED)),
+        )
         p_soft = jax.nn.softmax(filt, axis=-1).reshape(s, k1, vocab)
         greedy_tok = jnp.argmax(logits, axis=-1)  # (S, K+1)
         p_greedy = jax.nn.one_hot(greedy_tok, vocab, dtype=p_soft.dtype)
@@ -511,6 +519,11 @@ class SpecEngine(PagedEngine):
                 room = min(room, backed - 1 - p)
             rooms[slot] = room
 
+        asked = filters_asked(
+            self._active, self._temps, self._top_ks, self._top_ps
+        )
+        self.sample_topk_ticks += asked[0]
+        self.sample_topp_ticks += asked[1]
         # The spec engine's tick program: the pool goes through it donated.
         out, n_emit, keys, _ = self._in_place(
             "tick", self._verify_jit,

@@ -491,6 +491,73 @@ def test_the_tick_reads_the_pool_in_place(one_chip, on_tpu, kv_dtype):
     assert memory.temp_size_in_bytes < 100e6
 
 
+def _sorts(text):
+    """The compiled text's sort instructions, one line each."""
+    import re
+
+    return [
+        line.strip()[:200] for line in text.splitlines()
+        if re.search(r"(?<![\w.%-])sort\(", line)
+    ]
+
+
+def _branch_signatures(text):
+    """The signature line of every computation that a ``conditional`` of
+    the compiled text branches to: its parameters' and its result's
+    types."""
+    import re
+
+    names = re.findall(
+        r" conditional\(.*?branch_computations=\{([^}]*)\}", text
+    )
+    heads = {
+        line.split(" ", 1)[0]: line
+        for line in text.splitlines() if line.startswith("%")
+    }
+    return [
+        heads[name.strip()] for group in names for name in group.split(",")
+    ]
+
+
+def test_a_sorting_sampler_would_be_caught(one_chip):
+    """What `_sorts` is for: the sampler's filter as it was until PR 34
+    compiles to stable sorts of the whole f32[128,32000] logits."""
+    (arg,) = _described((jnp.zeros((128, 32000), F32),), one_chip)
+    text = jax.jit(lambda x: jnp.sort(x, axis=-1)).lower(arg).compile().as_text()
+    assert [line for line in _sorts(text) if "[128,32000]" in line] != []
+
+
+@pytest.mark.parametrize(
+    "name", ["tick", "chunk", "verify"], ids=["tick", "chunk256", "verify2"]
+)
+def test_dense_serving_programs_hold_no_sort(one_chip, on_tpu, name):
+    """The sampler finds its two cut-offs by threshold search
+    (`ops/sampling.py`): the tick, a chunk and the verify pass at the small
+    cell's 128 slots and 32,000 columns (two layers) compile for the v5e
+    to a text with no sort at all - two stable sorts of f32[128,32000]
+    were 59% of the tick's device time (PERF.md, PR 34).  The searches'
+    scopes are there, under a conditional each, and each search makes its
+    uint32 keys inside its branch: what crosses into a branch is the
+    float32 logits and per-row vectors, never a key for every logit -
+    a search handed its keys from outside reads them from HBM on all 32
+    passes, 0.72 against 0.13 ms a 128-row tick (PERF.md section 6, PR 34,
+    call C)."""
+    import dataclasses
+    import re
+
+    config = dataclasses.replace(GPT2_SMALL_32K, num_layers=2)
+    jitted, args, _ = _pool_program(
+        name, config, one_chip, None, slots=128, blocks=128 * 64 + 1
+    )
+    text = jitted.lower(*args).compile().as_text()
+    assert _sorts(text) == []
+    assert "sample/top_k" in text and "sample/top_p" in text
+    branches = _branch_signatures(text)
+    assert len(branches) == 4  # search or keep all, for either filter
+    wide = [re.findall(r"(\w+)\[\d+,32000\]", line) for line in branches]
+    assert sorted(map(tuple, wide)) == [(), (), ("f32",), ("f32",)]
+
+
 def test_pool_programs_compile_their_layers_as_calls(one_chip, monkeypatch):
     """With one pool alive the chip has memory to spare, and XLA then
     writes every layer's code out: gpt2-medium's tick went 20 -> 78 MB and
@@ -557,8 +624,7 @@ def _as_head(head_args):
 
 
 #: One head width per (kernel, vocab): the int8 head differs from the bf16
-#: one by a (block_v, 1) scale tile, and each of these compiles costs 2-7 s
-#: (Mosaic unrolls the 64 radix passes over the whole row tile).
+#: one by a (block_v, 1) scale tile, and each of these compiles costs 2-7 s.
 SAMPLE_HEADS = [
     pytest.param(10_000, 512, I8, id="v10000-int8"),
     pytest.param(32_000, 768, BF16, id="v32000-bf16"),
@@ -703,6 +769,9 @@ def test_latent_pool_programs(one_chip, on_tpu, name):
     text = compiled.as_text()
     assert ("mla_paged_attention" in text) == (name == "tick")
     assert "gmm" in text
+    # The expert layer may sort assignments by expert (`models/moe.py`); the
+    # sampler sorts nothing, so no sort has a vocabulary-wide operand.
+    assert [line for line in _sorts(text) if f",{config.vocab_size}]" in line] == []
     leaves = jax.tree_util.tree_leaves(pool)
     assert {_shape_text(a) for a in leaves} == {"bf16[2049,16,640]"} and len(leaves) == 2
     assert _pool_copies(text, {"bf16[2049,16,640]"}) == []

@@ -91,8 +91,14 @@ DEFAULT_BUDGET_S = 800.0
 #: (tests/test_longcatflash.py, 36 cases in about 100 s), and the latent
 #: tick kernel, the grouped matmul at its widths and the two latent pool
 #: programs compiled for the described v5e (tests/test_chip_compile.py, 10
-#: cases, 1-12 s each).
-DEFAULT_MAX_TESTS = 940
+#: cases, 1-12 s each).  Raised 940 -> 975 in PR 34 (942 collected, 33
+#: added): the sort-free sampler against the sorting body as its oracle,
+#: the `cond`s taken either way, the counters and a vacant slot's row
+#: (tests/test_sampler.py, 29 cases in about 40 s), and the tick, a chunk
+#: and the verify pass compiled for the described v5e with no sort and no
+#: key crossing into a branch (tests/test_chip_compile.py, 4 cases and one
+#: assertion in the latent programs', 2-8 s each).
+DEFAULT_MAX_TESTS = 975
 
 #: Pytest summary trailer: "== 398 passed, 27 deselected in 612.34s =="
 #: (also plain "in 612.34s (0:10:12)" forms).
