@@ -113,9 +113,12 @@ def decode_attention(
     *,
     block_k: int = 256,
     interpret: bool | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     """One decode step of attention: ``softmax(q k^T / sqrt(d)) v`` over
     cache positions ``<= pos``, streamed blockwise (see module docstring).
+    ``scale`` (here and in every function below) replaces ``1 / sqrt(d)``
+    where a config multiplies its scores by something else.
 
     ``interpret=None`` resolves via ``runtime.interpret_mode()`` (compiled
     Mosaic on TPU, interpreter elsewhere), like the sibling kernels.
@@ -181,7 +184,8 @@ def decode_attention(
 
     kernel = functools.partial(
         _decode_kernel,
-        scale=1.0 / (d**0.5),  # true head dim, not the lane-padded one
+        # true head dim, not the lane-padded one
+        scale=1.0 / (d**0.5) if scale is None else scale,
         block_k=block_k,
         num_k_blocks=nk,
         kv_heads=kv_heads,
@@ -362,9 +366,10 @@ def _paged_decode_kernel(
         ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "scale"))
 def _paged_decode_impl(
-    q, k_pool, v_pool, tables, key_counts, k_scale, v_scale, interpret
+    q, k_pool, v_pool, tables, key_counts, k_scale, v_scale, interpret,
+    scale=None,
 ):
     from bpe_transformer_tpu.kernels.pallas.runtime import paged_group_blocks
 
@@ -448,7 +453,7 @@ def _paged_decode_impl(
 
     kernel = functools.partial(
         _paged_decode_kernel,
-        scale=1.0 / (d**0.5),
+        scale=1.0 / (d**0.5) if scale is None else scale,
         block_size=block_size,
         group_blocks=group,
         slots=slots,
@@ -493,6 +498,7 @@ def paged_decode_attention(
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
     interpret: bool | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     """Paged-NATIVE flash decode: one decode step of attention read straight
     out of the KV block pool, and only the blocks the slots hold.
@@ -556,12 +562,13 @@ def paged_decode_attention(
             f"kv_heads={kv_heads})"
         )
     return _paged_decode_impl(
-        q, k_pool, v_pool, tables, key_counts, k_scale, v_scale, interpret
+        q, k_pool, v_pool, tables, key_counts, k_scale, v_scale, interpret,
+        scale,
     )
 
 
 @jax.named_scope("decode_attn")
-def xla_rows_attention(q, k_rows, v_rows, visible):
+def xla_rows_attention(q, k_rows, v_rows, visible, scale: float | None = None):
     """Materialized-scores attention over KV kept AS THE POOL HOLDS IT:
     ``k_rows``/``v_rows`` ``(batch, keys, kv_heads * d_head)``, a key's
     heads side by side along the lanes (`models/decode.gather_paged_rows`).
@@ -589,7 +596,10 @@ def xla_rows_attention(q, k_rows, v_rows, visible):
     q_cols = jnp.einsum("bhqd,hk->bkdhq", q, owner).reshape(
         batch, width, heads * queries
     )
-    scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    scale = (
+        1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32)) if scale is None
+        else jnp.float32(scale)
+    )
     scores = jnp.einsum("bcj,bjn->bnc", k_rows, q_cols) * scale
     scores = scores.reshape(batch, heads, queries, keys)
     scores = jnp.where(visible[:, None], scores, -jnp.inf)
@@ -601,7 +611,10 @@ def xla_rows_attention(q, k_rows, v_rows, visible):
 
 
 @jax.named_scope("decode_attn")
-def xla_decode_attention(q, k_cache, v_cache, pos, window: int | None = None):
+def xla_decode_attention(
+    q, k_cache, v_cache, pos, window: int | None = None,
+    scale: float | None = None,
+):
     """Materialized-scores formulation: the grouped einsum straight against
     the compact GQA cache (the per-token hot path reads only
     ``kv_heads * ctx`` values — no head expansion), f32 scores + softmax.
@@ -615,7 +628,10 @@ def xla_decode_attention(q, k_cache, v_cache, pos, window: int | None = None):
     qg = q.reshape(batch, kv_heads, num_heads // kv_heads, 1, d)
     # f32 scale promotes the scores out of bf16 before masking/softmax,
     # matching the kernel's f32 score accumulation.
-    scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    scale = (
+        1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32)) if scale is None
+        else jnp.float32(scale)
+    )
     scores = jnp.einsum("bkgqd,bkcd->bkgqc", qg, k_cache) * scale
     # pos is a scalar (whole batch at one depth) or (batch,) — per-sequence
     # causal frontiers for the serving engine's ragged slot pool.

@@ -206,6 +206,37 @@ class ModelConfig:
     #: The router's tree holds ``router_bias`` (router outputs,), added to
     #: the scores for the choice of experts and not to the gates.
     router_bias: bool = False
+    # ---- state-space layers beside attention layers, the four multipliers
+    # and a shared expert of its own width (the Granite-4.0-H family; every
+    # default is the block above) ------------------------------------------
+    #: Mixer kind by layer: with a period ``p > 0`` layer ``i`` is an
+    #: attention layer where ``i % p == attn_layer_offset`` and a Mamba-2
+    #: state-space layer elsewhere (`models/ssm.py`: no cache of positions
+    #: but one recurrent state a sequence); 0, the default: every layer
+    #: attends.  The block is the sequential pre-norm one
+    #: (`hybrid_block`).
+    attn_layer_period: int = 0
+    attn_layer_offset: int = 0
+    #: The Mamba-2 mixer: ``ssm_heads`` heads of ``ssm_head_dim`` channels
+    #: (inner width their product), a state of ``ssm_state`` values a
+    #: channel, ``B`` and ``C`` shared by all heads (one group), a depthwise
+    #: causal convolution ``ssm_conv`` wide, the scan in chunks of
+    #: ``ssm_chunk`` positions.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    #: ``x_0 = embedding_multiplier * E[token]``; every branch joins the
+    #: stream times ``residual_multiplier``; attention scores are ``(q . k)
+    #: * attention_multiplier`` (None: ``d_head ** -0.5``); logits are
+    #: divided by ``logits_scaling``.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float | None = None
+    logits_scaling: float = 1.0
+    #: Width of a shared expert where it is not a routed expert's.
+    shared_d_ff: int | None = None
     # Sequence-parallel ring attention: sub-chunk each visiting K/V shard
     # so per-device score memory is O(S_local * chunk) instead of
     # O(S_local^2).  Must divide the local shard length.  None -> one full
@@ -265,6 +296,47 @@ class ModelConfig:
         return self.d_ff if self.expert_d_ff is None else self.expert_d_ff
 
     @property
+    def shared_ff(self) -> int:
+        return self.moe_d_ff if self.shared_d_ff is None else self.shared_d_ff
+
+    @property
+    def hybrid_block(self) -> bool:
+        """State-space layers among the attention layers: the sequential
+        pre-norm block with the mixer by layer (`layer_is_ssm`), the
+        multipliers and an added shared expert
+        (`models/decode._block_apply`)."""
+        return self.attn_layer_period > 0
+
+    def layer_is_ssm(self, layer: int) -> bool:
+        """Whether layer ``layer`` (0-based) is a state-space layer."""
+        return (
+            self.hybrid_block
+            and layer % self.attn_layer_period != self.attn_layer_offset
+        )
+
+    @property
+    def ssm_layers(self) -> int:
+        return sum(self.layer_is_ssm(i) for i in range(self.num_layers))
+
+    @property
+    def ssm_inner(self) -> int:
+        """Channels of the mixer's inner stream."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_channels(self) -> int:
+        """Channels through the convolution: the inner stream, ``B`` and
+        ``C``."""
+        return self.ssm_inner + 2 * self.ssm_state
+
+    @property
+    def attention_scale(self) -> float:
+        """What attention scores are multiplied by."""
+        if self.attention_multiplier is not None:
+            return self.attention_multiplier
+        return self.d_head ** -0.5
+
+    @property
     def router_outputs(self) -> int:
         return self.n_experts + self.n_zero_experts
 
@@ -295,9 +367,11 @@ class ModelConfig:
         """True for what only the serving paths and the plain forward run
         (no training step, no ``scan_layers``, no int8 weights): a layer
         pattern, a parallel block, LayerNorm, shared or held experts, latent
-        attention, the double layer, zero experts and their router."""
+        attention, the double layer, zero experts and their router,
+        state-space layers."""
         return (
             self.latent_block
+            or self.hybrid_block
             or self.has_window_layers
             or self.parallel_block
             or self.norm_type != "rmsnorm"
@@ -434,7 +508,51 @@ class ModelConfig:
             )
         if self.parallel_block and self.use_post_norm:
             raise ValueError("parallel_block has one pre-norm; use_post_norm contradicts it")
-        if self.dropless_block and not (self.parallel_block or self.double_layer):
+        ssm_dims = (self.ssm_heads, self.ssm_head_dim, self.ssm_state)
+        if self.hybrid_block:
+            if not 0 <= self.attn_layer_offset < self.attn_layer_period:
+                raise ValueError(
+                    f"attn_layer_offset={self.attn_layer_offset} must lie in "
+                    f"[0, attn_layer_period={self.attn_layer_period})"
+                )
+            if min(ssm_dims) < 1 or self.ssm_conv < 2 or self.ssm_chunk < 1:
+                raise ValueError(
+                    "state-space layers need positive ssm_heads, ssm_head_dim, "
+                    f"ssm_state and ssm_chunk and ssm_conv >= 2 (got {ssm_dims}, "
+                    f"{self.ssm_conv}, {self.ssm_chunk})"
+                )
+            if (
+                self.sliding_window is not None or self.attention_kind != "mha"
+                or self.parallel_block or self.use_post_norm
+                or self.remove_rmsnorm or self.norm_type != "rmsnorm"
+            ):
+                raise ValueError(
+                    "state-space layers come in the sequential pre-norm RMSNorm "
+                    "block beside plain attention layers: sliding_window, "
+                    'attention_kind="mla", parallel_block, use_post_norm, '
+                    "remove_rmsnorm and LayerNorm contradict them"
+                )
+        elif self.attn_layer_period < 0 or self.attn_layer_offset or any(ssm_dims):
+            raise ValueError(
+                "attn_layer_offset and ssm_heads .. ssm_state are the hybrid "
+                "block's (attn_layer_period > 0)"
+            )
+        elif (
+            self.embedding_multiplier != 1.0 or self.residual_multiplier != 1.0
+            or self.attention_multiplier is not None
+            or self.logits_scaling != 1.0 or self.shared_d_ff is not None
+        ):
+            raise ValueError(
+                "the multipliers and shared_d_ff are the hybrid block's "
+                "(attn_layer_period > 0): no other block applies them"
+            )
+        if self.shared_d_ff is not None and not (
+            self.n_shared_experts and self.shared_d_ff >= 1
+        ):
+            raise ValueError("shared_d_ff is the positive width of n_shared_experts > 0")
+        if self.dropless_block and not (
+            self.parallel_block or self.double_layer or self.hybrid_block
+        ):
             raise ValueError(
                 "a layer pattern, LayerNorm, head_dim, sigmoid routing, shared "
                 "or held experts run in the parallel block only (zero "
@@ -445,7 +563,8 @@ class ModelConfig:
         if self.scan_layers and self.dropless_block:
             raise ValueError(
                 "scan_layers runs homogeneous training blocks; a layer "
-                "pattern, the parallel block, the double layer, LayerNorm, "
+                "pattern, state-space layers, the parallel block, the double "
+                "layer, LayerNorm, "
                 "shared, held or zero experts are served and not trained "
                 "(ROADMAP: what cannot run yet)"
             )

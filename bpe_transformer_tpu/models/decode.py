@@ -53,10 +53,28 @@ def init_kv_cache(config: ModelConfig, batch: int, dtype=jnp.float32) -> KVCache
     # shrinks by the query-group factor.
     kv_heads = config.num_kv_heads or config.num_heads
     shape = (batch, kv_heads, config.context_length, config.d_head)
+    if config.hybrid_block:
+        # A state-space layer's entry is its recurrent state, whatever the
+        # context (`models/ssm.py`).
+        from bpe_transformer_tpu.models.ssm import init_ssm_state
+
+        return [
+            init_ssm_state(config, batch, dtype) if config.layer_is_ssm(layer)
+            else {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+            for layer in range(config.num_layers)
+        ]
     return [
         {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
         for _ in range(config.num_layers)
     ]
+
+
+def _softmax_scale(config):
+    """What the materialized scores are multiplied by: ``d_head ** -0.5``,
+    or the config's own multiplier."""
+    if config.attention_multiplier is None:
+        return 1.0 / jnp.sqrt(jnp.asarray(config.d_head, jnp.float32))
+    return jnp.float32(config.attention_multiplier)
 
 
 def _rope_qk(q, k, positions, config, layer: int = 0):
@@ -92,7 +110,9 @@ def _block_apply(x, block_params, config, attend, valid=None, tally=None):
 
     Mirrors `transformer_block_aux` (models/transformer.py): pre-norm by
     default, post-norm under the ablation flag, both branches from one norm
-    under ``parallel_block``.  Under ``double_layer`` it is the
+    under ``parallel_block``; under ``hybrid_block`` ``a = x + r * Mixer(N1
+    x)``, ``y = a + r * (M(N2 a) + S(N2 a))`` with the residual multiplier
+    ``r``, ``attend`` being the layer's mixer.  Under ``double_layer`` it is the
     shortcut-connected double layer, whose ``attend(h, sublayer)`` is called
     for each of its two attention sublayers: with norms ``N1 .. N4``, dense
     FFNs ``F_0, F_1`` and the expert layer ``M``, ``a = x + Attn_0(N1 x)``,
@@ -116,6 +136,16 @@ def _block_apply(x, block_params, config, attend, valid=None, tally=None):
         with jax.named_scope("block/ffn"), jax.named_scope("dense"):
             h = _norm(d, ln[3], config)
             return d + swiglu(h, dense[1]["w1"], dense[1]["w2"], dense[1]["w3"]) + m
+    if config.hybrid_block:
+        # Sequential and pre-norm; the layer's mixer (attention, or the
+        # state-space mixer where the tree has "ssm") and the expert layer
+        # with its shared expert each join times the residual multiplier.
+        r = config.residual_multiplier
+        with jax.named_scope("block/ssm" if "ssm" in block_params else "block/attn"):
+            x = x + r * attend(_norm(x, block_params["ln1"], config))
+        with jax.named_scope("block/ffn"):
+            h = _norm(x, block_params["ln2"], config)
+            return x + r * _ffn_decode(h, block_params["ffn"], config, valid, tally)
     if config.parallel_block:
         h = _norm(x, block_params["ln1"], config)
         with jax.named_scope("block/attn"):
@@ -142,9 +172,20 @@ def _norm(x, w, config):
     return layernorm(x, w) if config.norm_type == "layernorm" else rmsnorm(x, w)
 
 
-def _embed(params, token_ids):
+def _embed(params, token_ids, config):
     with jax.named_scope("embed"):
-        return embedding(params["token_embeddings"], token_ids)
+        x = embedding(params["token_embeddings"], token_ids)
+        if config.embedding_multiplier != 1.0:
+            x = x * config.embedding_multiplier
+        return x
+
+
+def _logits(x, head, config):
+    """Float32 logits of the final-norm rows ``x``."""
+    logits = head_logits(x, head)
+    if config.logits_scaling != 1.0:
+        logits = logits / config.logits_scaling
+    return logits
 
 
 def _final_norm(x, params, config):
@@ -193,7 +234,7 @@ def prefill(
     """
     batch, plen = token_ids.shape
     positions = jnp.arange(plen)
-    x = _embed(params, token_ids)
+    x = _embed(params, token_ids, config)
     # Long prompts take the flash kernel (forced by the config, or chosen
     # from the prompt's shape under "auto"): the materialized path needs an
     # O(plen^2) score buffer per layer, which is exactly the memory wall
@@ -210,9 +251,15 @@ def prefill(
     use_flash = (
         attention_plan(config, plen)[0] == "flash"
         and not config.has_window_layers
+        and config.attention_multiplier is None  # the kernel's scale is d_head's
     )
+    if config.hybrid_block and last_pos is not None:
+        raise NotImplementedError(
+            "a recurrent state has no padded prefill: the rows behind "
+            "last_pos would enter it (the paged engine's chunks mask them)"
+        )
     if not use_flash:
-        scale = 1.0 / jnp.sqrt(jnp.asarray(config.d_head, jnp.float32))
+        scale = _softmax_scale(config)
         causal = jnp.tril(jnp.ones((plen, plen), bool))
 
     new_cache = []
@@ -242,6 +289,16 @@ def prefill(
 
             x = _block_apply(x, block_params, config, attend_latent)
             new_cache.append(layer_new)
+            continue
+        if config.layer_is_ssm(layer):
+            from bpe_transformer_tpu.models.ssm import mamba2
+
+            def mixer(h, block_params=block_params):
+                out, state = mamba2(h, block_params["ssm"], config)
+                new_cache.append(state)
+                return out
+
+            x = _block_apply(x, block_params, config, mixer)
             continue
         window = config.layer_window(layer)
         if not use_flash:
@@ -287,8 +344,7 @@ def prefill(
     else:
         idx = jnp.reshape(last_pos, (-1, 1, 1))
         last = jnp.take_along_axis(x, idx, axis=1)[:, 0]
-    logits = head_logits(last, head)
-    return logits, new_cache
+    return _logits(last, head, config), new_cache
 
 
 def _cache_write(buf: Array, new: Array, pos: Array) -> Array:
@@ -355,7 +411,7 @@ def decode_step(
     fused sample-in-kernel tick (`kernels/pallas/sample.py`) owns the
     projection then, so logits never materialize in HBM.
     """
-    x = _embed(params, token[:, None])  # (B, 1, d)
+    x = _embed(params, token[:, None], config)  # (B, 1, d)
     positions = pos[None] if jnp.ndim(pos) == 0 else pos[:, None]  # (1,)|(B,1)
 
     new_cache = []
@@ -373,6 +429,18 @@ def decode_step(
                 ),
             )
             new_cache.append(layer_new)
+            continue
+        if config.layer_is_ssm(layer):
+            from bpe_transformer_tpu.models.ssm import mamba2_step
+
+            def mixer(h, block_params=block_params, layer_cache=layer_cache):
+                out, state = mamba2_step(
+                    h[:, 0], block_params["ssm"], config, layer_cache, active
+                )
+                new_cache.append(state)
+                return out[:, None]
+
+            x = _block_apply(x, block_params, config, mixer)
             continue
         window = config.layer_window(layer)
 
@@ -402,7 +470,8 @@ def decode_step(
                 )
 
                 att = xla_decode_attention(
-                    q[:, :, 0], k_cache, v_cache, pos, window=window
+                    q[:, :, 0], k_cache, v_cache, pos, window=window,
+                    scale=config.attention_multiplier,
                 )
             elif config.decode_attention_impl in ("pallas", "paged"):
                 # Flash-decoding kernel: the cache streams through VMEM
@@ -413,7 +482,10 @@ def decode_step(
                     decode_attention,
                 )
 
-                att = decode_attention(q[:, :, 0], k_cache, v_cache, pos)
+                att = decode_attention(
+                    q[:, :, 0], k_cache, v_cache, pos,
+                    scale=config.attention_multiplier,
+                )
             else:
                 # Materialized grouped einsum — the same single
                 # implementation the kernel parity tests pin against.
@@ -421,7 +493,10 @@ def decode_step(
                     xla_decode_attention,
                 )
 
-                att = xla_decode_attention(q[:, :, 0], k_cache, v_cache, pos)
+                att = xla_decode_attention(
+                    q[:, :, 0], k_cache, v_cache, pos,
+                    scale=config.attention_multiplier,
+                )
             att = merge_heads(att[:, :, None, :])
             return linear(att, block_params["attn"]["output_proj"])
 
@@ -431,8 +506,7 @@ def decode_step(
     if return_hidden:
         return x[:, 0], new_cache
     head = lm_head_weight(params, config) if lm_head is None else lm_head
-    logits = head_logits(x[:, 0], head)
-    return logits, new_cache
+    return _logits(x[:, 0], head, config), new_cache
 
 
 # --------------------------------------------------------- paged KV memory
@@ -650,8 +724,10 @@ def _quantize_chunk_rows(
 #
 # Latent attention's cache is a third kind (`LatentRows`, further down): it
 # has no K/V heads to write or attend, so it provides the whole sublayer
-# (``attention``) in place of ``write`` and ``attend``.  A new
-# architecture's cache (recurrent) is one more kind.
+# (``attention``) in place of ``write`` and ``attend``.  A recurrent state
+# beside the K/V of a few attention layers is a fourth (`RecurrentRows`):
+# it provides the state-space layers whole (``mixer``) and is `DenseRows`
+# for the rest.
 
 
 def _clamped(x, hi: int, rows: int):
@@ -802,7 +878,7 @@ class DenseRows:
             k_cache = gather_paged_kv(k_pool, tables[None], kv_heads, k_scale, q.dtype)
             v_cache = gather_paged_kv(v_pool, tables[None], kv_heads, v_scale, q.dtype)
             with jax.named_scope("chunk_attn"):
-                scale = 1.0 / jnp.sqrt(jnp.asarray(config.d_head, jnp.float32))
+                scale = _softmax_scale(config)
                 scores = jnp.einsum(
                     "bhqd,bhkd->bhqk", q, _expand_kv(k_cache, config)
                 ) * scale
@@ -824,13 +900,14 @@ class DenseRows:
             att = paged_decode_attention(
                 q[:, :, 0], k_pool, v_pool, tables, key_counts,
                 k_scale=k_scale, v_scale=v_scale,
+                scale=config.attention_multiplier,
             )[:, :, None, :]
         else:
             att = xla_rows_attention(
                 q,
                 gather_paged_rows(k_pool, tables, k_scale, q.dtype),
                 gather_paged_rows(v_pool, tables, v_scale, q.dtype),
-                self.visible,
+                self.visible, scale=config.attention_multiplier,
             )
         return merge_heads(att)
 
@@ -1097,18 +1174,139 @@ class LatentRows(_RoutingCounts):
         return linear(att[None], attn["output_proj"])
 
 
+# A state-space layer keeps no rows of positions: one recurrent state a
+# sequence, whatever its length (`models/ssm.py`).  Its pool entry is a row
+# a SLOT - ``{"ssm": (slots + 1, heads, head_dim, state) float32, "conv":
+# (slots + 1, k - 1, channels)}``, the last row trash, as block 0 is of the
+# pools of positions - beside the K and V pools of the config's attention
+# layers, which are `DenseRows`' own.
+
+
+def init_recurrent_pool(
+    config: ModelConfig, num_blocks: int, block_size: int, slots: int,
+    dtype=jnp.float32,
+) -> list:
+    """A layer's entry by its kind: `init_kv_pool`'s K and V rows for an
+    attention layer, zeroed state rows for a state-space layer."""
+    from bpe_transformer_tpu.models.ssm import init_ssm_state
+
+    kv_heads = config.num_kv_heads or config.num_heads
+    shape = (num_blocks, block_size, kv_heads * config.d_head)
+    return [
+        init_ssm_state(config, slots + 1, dtype) if config.layer_is_ssm(layer)
+        else {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        for layer in range(config.num_layers)
+    ]
+
+
+class RecurrentRows(_RoutingCounts):
+    """`init_recurrent_pool`'s pool.  The attention layers are `DenseRows`'
+    by composition (``write``, ``attend``: a chain of blocks a slot, the
+    paged-native kernel on the tick); a state-space layer is provided whole
+    (:meth:`mixer`), its state addressed **by slot id**:
+
+    * a tick's row ``s`` is slot ``s``; rows that are not ``valid`` (idle
+      slots, slots still prefilling) are sent to the trash row, so a tick
+      never touches their state (`kernels/pallas/ssm.ssm_state_update`
+      updates the addressed rows in place), and keep their conv rows;
+    * a chunk is one slot's, ``tables = {"blocks": its table row, "slot":
+      its id}``: it starts from the slot's state, **or from zeros where it
+      starts at position 0** (an admission: nothing of the slot's last
+      tenant is read), runs the chunked scan over its bucket with the
+      padded rows masked out of state and conv rows, and leaves the state
+      after its last real row for the prompt's next chunk or first tick.
+
+    Several rows a slot (a verify pass) would need the state of every row
+    to roll back to: no form here.  Routing counts ride along as in
+    `GroupedPages`."""
+
+    def __init__(self, config, tables, positions, valid, block_size, chunk=None):
+        if positions.ndim != 1:
+            raise NotImplementedError(
+                "several rows a slot (a verify pass) over a recurrent state"
+            )
+        self.config, self.chunk = config, chunk
+        self.tally: list = []
+        tokens = positions.shape[0]
+        self.ffn_rows = jnp.ones((tokens,), bool) if valid is None else valid
+        if chunk is not None:
+            tables, self.slot = tables["blocks"], tables["slot"]
+        else:
+            self.state_ids = jnp.where(self.ffn_rows, jnp.arange(tokens), tokens)
+        self.rows = DenseRows(config, tables, positions, valid, block_size, chunk)
+        self.rope_positions = self.rows.rope_positions
+
+    @staticmethod
+    def attention_path(config, one_row: bool, blocks_per_slot: int, layer_pool) -> str:
+        return DenseRows.attention_path(config, one_row, blocks_per_slot, layer_pool)
+
+    def write(self, layer, layer_pool, k, v):
+        return self.rows.write(layer, layer_pool, k, v)
+
+    def attend(self, layer, q, layer_pool):
+        return self.rows.attend(layer, q, layer_pool)
+
+    def mixer(self, h, ssm, layer_pool, new_pool):
+        """One state-space layer: ``h`` (slots, rows, d_model) -> the same
+        shape; the layer's state rows, updated, are appended to
+        ``new_pool``."""
+        from bpe_transformer_tpu.kernels.pallas.ssm import ssm_state_update
+        from bpe_transformer_tpu.models import ssm as mamba
+
+        config = self.config
+        if self.chunk is None:
+            slots = h.shape[0]
+            z, x, b, c, dt, a, conv = mamba.step_inputs(
+                h[:, 0], ssm, config, layer_pool["conv"][:slots], self.ffn_rows
+            )
+            y, states = ssm_state_update(
+                layer_pool["ssm"], self.state_ids, x, dt, a, b, c,
+                ssm["D"].astype(jnp.float32),
+            )
+            with jax.named_scope("pool_write"):
+                conv = layer_pool["conv"].at[:slots].set(conv)
+            new_pool.append({"ssm": states, "conv": conv})
+            return mamba.step_output(y, z, ssm, config)[:, None]
+        start, _ = self.chunk
+        with jax.named_scope("pool_gather"):
+            state = {
+                name: jnp.where(
+                    start == 0, 0,
+                    lax.dynamic_index_in_dim(arr, self.slot, 0, keepdims=True),
+                ).astype(arr.dtype)
+                for name, arr in layer_pool.items()
+            }
+        out, state = mamba.mamba2(h, ssm, config, state, self.ffn_rows[None])
+        with jax.named_scope("pool_write"):
+            new_pool.append({
+                name: lax.dynamic_update_slice_in_dim(
+                    arr, state[name].astype(arr.dtype), self.slot, 0
+                )
+                for name, arr in layer_pool.items()
+            })
+        return out
+
+
 def cache_kind(config: ModelConfig):
     if config.attention_kind == "mla":
         return LatentRows
+    if config.hybrid_block:
+        return RecurrentRows
     return GroupedPages if config.has_window_layers else DenseRows
 
 
 def init_paged_pool(
     config: ModelConfig, num_blocks: int, block_size: int, dtype=jnp.float32,
     *, kv_dtype: str | None = None, num_window_blocks: int = 0,
+    slots: int = 0,
 ):
     """The pool of the config's cache kind.  ``num_window_blocks`` sizes the
-    window group where the kind has one."""
+    window group where the kind has one, ``slots`` the state rows of a
+    recurrent one."""
+    if cache_kind(config) is RecurrentRows:
+        if kv_dtype is not None:
+            raise ValueError("a recurrent pool holds K/V at the activation width")
+        return init_recurrent_pool(config, num_blocks, block_size, slots, dtype)
     if cache_kind(config) is LatentRows:
         if kv_dtype is not None:
             raise ValueError("a latent pool holds its rows at the activation width")
@@ -1148,13 +1346,16 @@ def chunk_cache(
 
 
 def _cached_attention(
-    h, sublayer=0, *, attn, config, cache, layer, layer_pool, new_pool
+    h, sublayer=0, *, attn, config, cache, layer, layer_pool, new_pool, ssm=None
 ):
-    """One attention sublayer over the paged pool; ``layer_pool`` is the
-    layer's entries of the pool's list, one a sublayer."""
+    """One attention sublayer - or, where the layer's tree has ``ssm``, its
+    state-space mixer - over the paged pool; ``layer_pool`` is the layer's
+    entries of the pool's list, one a sublayer."""
     if config.attention_kind == "mla":
         return cache.attention(h, attn[sublayer], layer_pool[sublayer], new_pool)
     (layer_pool,) = layer_pool
+    if ssm is not None:
+        return cache.mixer(h, ssm, layer_pool, new_pool)
     q, k, v = _project_qkv(h, attn, config)
     q, k = _rope_qk(q, k, cache.rope_positions, config, layer)
     layer_pool = cache.write(layer, layer_pool, k, v)
@@ -1190,15 +1391,16 @@ def paged_forward(
     fused sample-in-kernel tail owns the head projection then, so logits
     never materialize in HBM); the updated pool; and the kind's routing
     counts (None for a kind that carries none)."""
-    x = _embed(params, tokens)
+    x = _embed(params, tokens, config)
     new_pool: list = []
     per_layer = config.attn_sublayers
     for layer, block_params in enumerate(params["layers"]):
         x = _block_apply(
             x, block_params, config,
             partial(
-                _cached_attention, attn=block_params["attn"], config=config,
+                _cached_attention, attn=block_params.get("attn"), config=config,
                 cache=cache, layer=layer, new_pool=new_pool,
+                ssm=block_params.get("ssm"),
                 layer_pool=pool[layer * per_layer: (layer + 1) * per_layer],
             ),
             valid=cache.ffn_rows, tally=cache.tally,
@@ -1210,7 +1412,7 @@ def paged_forward(
         x = jnp.take_along_axis(x, jnp.reshape(row, (-1, 1, 1)), axis=1)[:, 0]
     if not return_hidden:
         head = lm_head_weight(params, config) if lm_head is None else lm_head
-        x = head_logits(x, head)
+        x = _logits(x, head, config)
     return x, new_pool, cache.counts()
 
 

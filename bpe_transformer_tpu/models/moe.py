@@ -44,8 +44,9 @@ def init_moe_params(rng: jax.Array, config: ModelConfig, dtype=jnp.float32) -> d
     """Stacked expert weights + router for one MoE FFN layer: the router
     over all its outputs (``n_experts`` and the zero experts after them),
     the stacks of the experts held here (``config.local_experts``), a
-    ``"shared"`` stack where the config has shared experts and a
-    ``"router_bias"`` of zeros where it has one."""
+    ``"shared"`` stack where the config has shared experts (of the routed
+    width, or ``shared_d_ff``) and a ``"router_bias"`` of zeros where it has
+    one."""
     e, d, ff = config.router_outputs, config.d_model, config.moe_d_ff
     held, shared = config.local_experts, config.n_shared_experts
 
@@ -65,10 +66,11 @@ def init_moe_params(rng: jax.Array, config: ModelConfig, dtype=jnp.float32) -> d
         params["router_bias"] = jnp.zeros((e,), jnp.float32)
     if shared:
         ks = jax.random.split(jax.random.fold_in(rng, 1), 3)
+        sff = config.shared_ff
         params["shared"] = {
-            "w1": dense(ks[0], (shared, ff, d)),
-            "w2": dense(ks[1], (shared, d, ff)),
-            "w3": dense(ks[2], (shared, ff, d)),
+            "w1": dense(ks[0], (shared, sff, d)),
+            "w2": dense(ks[1], (shared, d, sff)),
+            "w3": dense(ks[2], (shared, sff, d)),
         }
     return params
 
@@ -243,7 +245,9 @@ def dropless_moe(
     experts' results.  What the absent experts would add is left out: with
     ``experts_held=None`` that is nothing, with a share it is the other
     processes' part of an expert-parallel layer.  Shared experts, where the
-    config has them, see every token and are averaged and added.
+    config has them, see every token and are averaged and added (one shared
+    expert is added whole: its average is itself), at their own width where
+    the config gives one (``shared_d_ff``).
 
     ``valid`` (tokens,) bool leaves rows out of the expert computation
     altogether (padded chunk rows, idle slots); their output is the shared

@@ -95,18 +95,20 @@ def init_params(
                 "w2": dense(k[5], d, ff),
                 "w3": dense(k[6], ff, d),
             }
-        layers.append(
-            {
+        if config.layer_is_ssm(i):
+            from bpe_transformer_tpu.models.ssm import init_ssm_params
+
+            mixer = {"ssm": init_ssm_params(k[0], config, dtype)}
+        else:
+            mixer = {
                 "attn": {
                     "q_proj": dense(k[0], d_q, d),
                     "k_proj": dense(k[1], d_kv, d),
                     "v_proj": dense(k[2], d_kv, d),
                     "output_proj": dense(k[3], d, d_q),
-                },
-                "ln1": jnp.ones((d,), dtype),
-                "ffn": ffn_params,
+                }
             }
-        )
+        layers.append({**mixer, "ln1": jnp.ones((d,), dtype), "ffn": ffn_params})
         if not config.parallel_block:  # one norm a block otherwise
             layers[-1]["ln2"] = jnp.ones((d,), dtype)
     params = {
@@ -524,6 +526,8 @@ def _forward_prologue(
         x = embedding(
             compute_params["token_embeddings"], token_ids
         ).astype(act_dtype)
+        if config.embedding_multiplier != 1.0:
+            x = x * config.embedding_multiplier
 
     rope_cos_sin = None
     if not config.remove_rope:
@@ -571,6 +575,17 @@ def forward_hidden(
                     h, attn[sub], positions, config
                 )[0],
             )
+    elif config.hybrid_block:
+        # The served arrangement again; a layer's mixer is the state-space
+        # scan over the whole sequence or plain causal attention under the
+        # config's own score multiplier.
+        from bpe_transformer_tpu.models.decode import _block_apply
+
+        for block_params in compute_params["layers"]:
+            x = _block_apply(
+                x, block_params, config,
+                lambda h, p=block_params: _hybrid_mixer(h, p, config, positions),
+            )
     elif config.dropless_block:
         for layer, block_params in enumerate(compute_params["layers"]):
             x = _patterned_block(
@@ -590,6 +605,30 @@ def forward_hidden(
             aux_total = aux_total + aux
 
     return _final_norm(x, compute_params, config), aux_total
+
+
+def _hybrid_mixer(h: Array, block_params: dict, config: ModelConfig, positions) -> Array:
+    """A hybrid block's mixer over a whole sequence from its start."""
+    if "ssm" in block_params:
+        from bpe_transformer_tpu.models.ssm import mamba2
+
+        return mamba2(h, block_params["ssm"], config)[0]
+    def attention_fn(q, k, v):
+        # Materialized causal scores under the config's own multiplier.
+        scores = jnp.einsum("...qd,...kd->...qk", q, k).astype(jnp.float32)
+        scores = jnp.where(
+            jnp.tril(jnp.ones(scores.shape[-2:], bool)),
+            scores * config.attention_scale, -jnp.inf,
+        )
+        weights = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        return jnp.einsum("...qk,...kv->...qv", weights, v)
+
+    attn = block_params["attn"]
+    return multihead_self_attention(
+        h, attn["q_proj"], attn["k_proj"], attn["v_proj"], attn["output_proj"],
+        config.num_heads, num_kv_heads=config.num_kv_heads, positions=positions,
+        rope_cos_sin=None, causal=True, attention_fn=attention_fn,
+    )
 
 
 def _final_norm(x: Array, compute_params: Params, config: ModelConfig) -> Array:
@@ -753,6 +792,8 @@ def forward(
     # LM head: activation-dtype matmul, f32 accumulation (ops/core.py
     # head_logits — f32 logits for stable loss/sampling at full MXU rate).
     logits = head_logits(x, lm_head_weight(params, config))
+    if config.logits_scaling != 1.0:
+        logits = logits / config.logits_scaling
     if return_aux:
         return logits, aux_total
     return logits

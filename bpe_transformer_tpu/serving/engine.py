@@ -356,6 +356,13 @@ class SlotPoolEngine:
                 "with latent attention is served by the paged engine "
                 "(ROADMAP: what cannot run yet)"
             )
+        if config.hybrid_block:
+            raise ValueError(
+                "the dense slot pool prefills a padded bucket, whose padding "
+                "would enter a recurrent state; a config with state-space "
+                "layers is served by the paged engine (ROADMAP: what cannot "
+                "run yet)"
+            )
         self.config = config
         self.n_slots = slots
         ctx = config.context_length

@@ -11,8 +11,10 @@ bounded-compile-count guarantees), with three new behaviors:
   *chain of block ids* in a block table that the decode tick and chunk
   prefill read through (gather) and write through (scatter).  How a pool
   is laid out, written and attended is its *cache kind*'s business
-  (`models/decode.py`: `DenseRows`, or `GroupedPages` for a config with
-  sliding-window layers); the two programs here are one forward over
+  (`models/decode.py`: `DenseRows`, `GroupedPages` for a config with
+  sliding-window layers, `LatentRows` for latent attention, `RecurrentRows`
+  where state-space layers keep a recurrent state a slot beside the K/V of
+  the attention layers); the two programs here are one forward over
   whichever the config has.  Pool
   capacity is a knob (``num_blocks``) decoupled from ``slots *
   context_length``.  **One pool is alive at a time and no program copies
@@ -304,6 +306,9 @@ class PagedEngine:
         self.grouped = config.has_window_layers
         #: Latent rows in the pool (`models/decode.LatentRows`).
         self.latent = config.attention_kind == "mla"
+        #: State-space layers' recurrent state, a row a slot, beside the
+        #: K/V blocks of the attention layers (`models/decode.RecurrentRows`).
+        self.recurrent = config.hybrid_block
         if config.dropless_block and weight_dtype is not None:
             raise ValueError(
                 "weight_dtype quantizes the dense block's weight tree "
@@ -311,31 +316,35 @@ class PagedEngine:
                 "layer, its held, shared and zero experts are served at the "
                 "activation width only"
             )
-        if self.latent:
-            unsupported = {
-                'kv_dtype="int8" (latent rows have no heads to scale by)':
-                    kv_dtype is not None,
-                "fused_sampling": fused_sampling,
-            }
+
+        def refuse(over: str, unsupported: dict) -> None:
             for what, asked in unsupported.items():
                 if asked:
                     raise ValueError(
-                        f"{what} is not supported over a latent pool "
+                        f"{what} is not supported over {over} "
                         "(ROADMAP: what cannot run yet); pass it off"
                     )
+
+        if self.latent:
+            refuse("a latent pool", {
+                'kv_dtype="int8" (latent rows have no heads to scale by)':
+                    kv_dtype is not None,
+                "fused_sampling": fused_sampling,
+            })
+        if self.recurrent:
+            refuse("a recurrent state", {
+                "prefix_cache=True (a shared chain of blocks says nothing of "
+                "the recurrent state at its end)": prefix_cache,
+                'kv_dtype="int8"': kv_dtype is not None,
+                "fused_sampling": fused_sampling,
+            })
         if self.grouped:
-            unsupported = {
+            refuse("window pool groups", {
                 "prefix_cache=True (the radix cache shares whole chains; a "
                 "window group recycles its blocks)": prefix_cache,
                 'kv_dtype="int8"': kv_dtype is not None,
                 "fused_sampling": fused_sampling,
-            }
-            for what, asked in unsupported.items():
-                if asked:
-                    raise ValueError(
-                        f"{what} is not supported over window pool groups "
-                        "(ROADMAP: what cannot run yet); pass it off"
-                    )
+            })
             window = config.sliding_window
             chunk = min(prefill_chunk or ctx, ctx)
             if window % block_size or chunk % block_size:
@@ -411,7 +420,7 @@ class PagedEngine:
         self.fused_sampling = bool(fused_sampling)
         self._pool = init_paged_pool(
             config, num_blocks, block_size, act_dtype, kv_dtype=kv_dtype,
-            num_window_blocks=num_window_blocks,
+            num_window_blocks=num_window_blocks, slots=slots,
         )
         #: "int8" for quantized pools, else the activation dtype name —
         #: the /statusz + stats() label.
@@ -421,10 +430,15 @@ class PagedEngine:
         #: Resident bytes of the whole KV pool (scale pools included):
         #: int8 quarters the f32 pool (halves bf16) at fixed block count —
         #: or, held fixed, buys 2-4x the blocks.
-        self.kv_pool_bytes = sum(
-            int(arr.size) * arr.dtype.itemsize
-            for arr in jax.tree_util.tree_leaves(self._pool)
-        )
+        #: State-space layers' state rows are counted apart
+        #: (``ssm_state_bytes``): a slot's share of them does not grow
+        #: with its context.
+        from bpe_transformer_tpu.ops.quant import tree_bytes
+
+        self.ssm_state_bytes = tree_bytes(
+            [entry for entry in self._pool if "ssm" in entry]
+        ) if self.recurrent else 0
+        self.kv_pool_bytes = tree_bytes(self._pool) - self.ssm_state_bytes
         #: KV footprint per token POSITION at pool width across all layers
         #: (k + v) — the unit of the attention READ stream, which scales
         #: with context and dominates the decode tick's HBM traffic; this
@@ -434,7 +448,9 @@ class PagedEngine:
         #: amortized small against the context-sized read.
         #: Attention sublayers that read the cache a tick: a layer's one, or
         #: the double layer's two.
-        self._attn_sublayers = config.num_layers * config.attn_sublayers
+        self._ssm_layers = config.ssm_layers
+        attn_layers = config.num_layers - self._ssm_layers
+        self._attn_sublayers = attn_layers * config.attn_sublayers
         if self.latent:
             # One latent row a position and sublayer, no K and V.
             self.kv_bytes_per_token = (
@@ -442,7 +458,7 @@ class PagedEngine:
             )
         else:
             self.kv_bytes_per_token = (
-                2 * config.num_layers * kv_heads * config.d_head * itemsize
+                2 * attn_layers * kv_heads * config.d_head * itemsize
             )
 
         self._tables = np.zeros((slots, self.blocks_per_slot), np.int32)
@@ -464,8 +480,17 @@ class PagedEngine:
         self.tick_table_keys = 0
         #: How the tick's rows attend: the cache kind's choice.
         self.tick_attention_path = cache_kind(config).attention_path(
-            config, True, self.blocks_per_slot, self._pool[0]
+            config, True, self.blocks_per_slot, self._first_attention_entry()
         )
+        #: State-space layers: slot-layers the ticks updated (live slots x
+        #: state-space layers), real and bucket rows x state-space layers
+        #: through the chunks' scans, admissions that started from a zero
+        #: state, and the last tick's slot-layers.
+        self.ssm_tick_state_rows = 0
+        self.ssm_chunk_tokens = 0
+        self.ssm_chunk_rows = 0
+        self.ssm_state_resets = 0
+        self.last_tick_ssm_state_rows = 0
         self._window_layers = sum(
             config.layer_window(layer) is not None
             for layer in range(config.num_layers)
@@ -580,6 +605,14 @@ class PagedEngine:
             + self._inject_jit._cache_size()
         )
 
+    def _first_attention_entry(self):
+        """The pool entry of the first attention (sub)layer."""
+        first = next(
+            layer for layer in range(self.config.num_layers)
+            if not self.config.layer_is_ssm(layer)
+        )
+        return self._pool[first * self.config.attn_sublayers]
+
     def bucket_for(self, length: int) -> int:
         """The smallest chunk bucket holding ``length`` tokens (lengths
         beyond the chunk size run as multiple chunks of the largest)."""
@@ -650,6 +683,11 @@ class PagedEngine:
         out["moe_zero_assignments"] = int(self.moe_counts[3])
         out["prefill_pending_tokens"] = self.pending_prefill_tokens()
         out["prefill_pending_slots"] = len(self._prefilling)
+        out["ssm_tick_state_rows"] = self.ssm_tick_state_rows
+        out["ssm_chunk_tokens"] = self.ssm_chunk_tokens
+        out["ssm_chunk_rows"] = self.ssm_chunk_rows
+        out["ssm_state_resets"] = self.ssm_state_resets
+        out["ssm_state_bytes"] = self.ssm_state_bytes
         out["kv_pool_bytes"] = self.kv_pool_bytes
         out["kv_bytes_per_token"] = self.kv_bytes_per_token
         # From the compiled programs themselves (`_in_place`): the pool's
@@ -732,6 +770,15 @@ class PagedEngine:
                 "no latent form (ROADMAP: what cannot run yet)"
             )
 
+    def _refuse_recurrent(self, what: str) -> None:
+        if self.recurrent:
+            raise NotImplementedError(
+                f"{what} is not supported over a recurrent state: it is "
+                "the state after a slot's last token and of no earlier one, "
+                "and the migration wire ships blocks of positions (ROADMAP: "
+                "what cannot run yet)"
+            )
+
     def _advance_window(self, slot: int, lo_pos: int) -> None:
         """Recycle ``slot``'s window blocks that lie wholly below
         ``lo_pos`` (positions no later query of the slot reads)."""
@@ -810,6 +857,7 @@ class PagedEngine:
         caller shrinks its speculation window instead of parking."""
         self._refuse_grouped("extend_blocks (speculative scratch)")
         self._refuse_latent("extend_blocks (speculative scratch)")
+        self._refuse_recurrent("extend_blocks (speculative scratch)")
         info = self._slots[slot]
         if info is None:
             raise ValueError(f"slot {slot} is not occupied")
@@ -868,6 +916,8 @@ class PagedEngine:
                 f"new_len={new_len} outside [0, "
                 f"{self.config.context_length}]"
             )
+        if new_len < int(self._positions[slot]):
+            self._refuse_recurrent("rewind below the written frontier")
         bs = self.block_size
         needed = -(-new_len // bs)
         floor = max(needed, keep_blocks or 0)
@@ -932,6 +982,7 @@ class PagedEngine:
         """
         self._refuse_grouped("KV migration (export_slot)")
         self._refuse_latent("KV migration (export_slot)")
+        self._refuse_recurrent("KV migration (export_slot)")
         info = self._slots[slot]
         if info is None:
             raise ValueError(f"slot {slot} is not occupied")
@@ -1004,6 +1055,7 @@ class PagedEngine:
         is allocated (HTTP 400, not a half-grafted slot)."""
         self._refuse_grouped("KV migration (import_slot)")
         self._refuse_latent("KV migration (import_slot)")
+        self._refuse_recurrent("KV migration (import_slot)")
         if meta.get("format") != 1:
             raise ValueError(
                 f"unsupported payload format {meta.get('format')!r}"
@@ -1271,6 +1323,9 @@ class PagedEngine:
         chunk's row (ROADMAP D11).  A tick's caller reads its results back
         before it touches a table."""
         pick = (lambda a: a) if slot is None else (lambda a: a[slot].copy())
+        if self.recurrent and slot is not None:
+            # A chunk addresses its slot's state rows by the slot's id.
+            return {"blocks": pick(self._tables), "slot": np.int32(slot)}
         if not self.grouped:
             return pick(self._tables)
         return {
@@ -1308,6 +1363,11 @@ class PagedEngine:
                 slot, info.next_pos - self.config.sliding_window + 1
             )
             self._count_attention(info.next_pos, info.next_pos + chunk_len)
+        if self.recurrent:
+            self.ssm_chunk_tokens += self._ssm_layers * chunk_len
+            self.ssm_chunk_rows += self._ssm_layers * bucket
+            # The chunk program starts a chunk at position 0 from zeros.
+            self.ssm_state_resets += int(info.next_pos == 0)
         tok, key, _, self._moe_pending = self._in_place(
             f"chunk_{bucket}", self._chunk_jit,
             self._params, self._lm_head, self._pool, self._moe_pending,
@@ -1399,6 +1459,8 @@ class PagedEngine:
             self.attn_kv_positions += keys_read
             self.tick_live_keys += live
             self.tick_table_keys += self._tables.size * self.block_size
+            self.last_tick_ssm_state_rows = self._ssm_layers * len(seen)
+            self.ssm_tick_state_rows += self.last_tick_ssm_state_rows
             asked = filters_asked(
                 self._active, self._temps, self._top_ks, self._top_ps
             )
@@ -1446,7 +1508,9 @@ class PagedEngine:
 
     def release(self, slot: int) -> None:
         """Free a slot: drop its block references (blocks still indexed by
-        the prefix cache survive for future hits), clear its table row."""
+        the prefix cache survive for future hits), clear its table row.  A
+        recurrent state needs no free: the slot's next tenant starts from
+        zeros."""
         info = self._slots[slot]
         self._active[slot] = False
         self._slots[slot] = None

@@ -283,6 +283,18 @@ class ServingEngine:
                 "heads) are not supported over a latent pool (ROADMAP: what "
                 "cannot run yet)"
             )
+        if config.hybrid_block and not paged:
+            raise ValueError(
+                "a config with state-space layers is served by the paged "
+                "engine (paged=True): its recurrent state rows live there"
+            )
+        if config.hybrid_block and (speculate_k or role != "both"):
+            raise ValueError(
+                "speculative decoding (its verify pass rewinds) and the "
+                "prefill/decode roles (KV migration ships blocks of "
+                "positions) are not supported over a recurrent state "
+                "(ROADMAP: what cannot run yet)"
+            )
         if speculate_k:
             from bpe_transformer_tpu.serving.spec.engine import SpecEngine
 
@@ -1360,7 +1372,17 @@ class ServingEngine:
             "t": t, "admit_s": 0.0, "prefill_s": 0.0, "chunks": 0,
             "prefill_tokens": 0, "idle_s": 0.0, "deliver_s": 0.0,
             "tokens_before": self.engine.tokens_emitted,
+            "ssm_chunk_before": self._ssm_chunk_counts(),
         }
+
+    def _ssm_chunk_counts(self) -> tuple:
+        """The engine's ``(ssm_chunk_tokens, ssm_chunk_rows)``: real and
+        bucket rows x state-space layers through the chunks' scans so far
+        (zeros for an engine without such layers)."""
+        return (
+            getattr(self.engine, "ssm_chunk_tokens", 0),
+            getattr(self.engine, "ssm_chunk_rows", 0),
+        )
 
     def _close_period(self, tick, n_events: int) -> None:
         """End the period now, at the end of its tick, and account for it
@@ -1393,6 +1415,10 @@ class ServingEngine:
         )
         if self._telemetry is None:
             return
+        ssm_chunk = tuple(
+            now - before for now, before in
+            zip(self._ssm_chunk_counts(), period["ssm_chunk_before"])
+        )
         self._telemetry.emit(
             {
                 "kind": "tick",
@@ -1414,6 +1440,14 @@ class ServingEngine:
                 "moe_zero_assignments": getattr(
                     self.engine, "last_tick_moe_zero_assignments", 0
                 ),
+                # State-space slot-layers the tick updated (live slots x
+                # state-space layers) and the period's chunks' real and
+                # bucket rows x state-space layers (0 without such layers).
+                "ssm_tick_state_rows": getattr(
+                    self.engine, "last_tick_ssm_state_rows", 0
+                ),
+                "ssm_chunk_tokens": ssm_chunk[0],
+                "ssm_chunk_rows": ssm_chunk[1],
             }
         )
 

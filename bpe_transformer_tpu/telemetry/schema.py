@@ -61,7 +61,11 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # it); ``queue_depth`` the scheduler's at the period's end.  Optional
     # ``moe_rows_local``: the tick's expert assignments that landed on
     # experts held here (grouped paged engine; 0 elsewhere); optional
-    # ``moe_zero_assignments``: those that landed on zero-compute experts.
+    # ``moe_zero_assignments``: those that landed on zero-compute experts;
+    # optional ``ssm_tick_state_rows``: state-space slot-layers the tick
+    # updated (live slots x state-space layers), and ``ssm_chunk_tokens`` /
+    # ``ssm_chunk_rows``: real and bucket rows x state-space layers through
+    # the scans of the period's prefill chunks.
     "tick": {
         "kind", "t", "dur_s", "admit_s", "prefill_s", "chunks",
         "prefill_tokens", "dispatch_s", "wait_s", "emit_s", "deliver_s",
@@ -145,7 +149,13 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # and lower by an array's size as soon as one stops being donated —
     # and ``tick_temp_bytes``, the tick (or spec verify) program's
     # temporaries, where a pool-sized layout copy coming back would show.
-    # Both null before a program has run.
+    # Both null before a program has run.  ``stats()`` also carries the
+    # recurrent state's counters (0 without state-space layers):
+    # ``ssm_tick_state_rows`` (slot-layers the ticks updated),
+    # ``ssm_chunk_tokens`` / ``ssm_chunk_rows`` (real and bucket rows x
+    # state-space layers through the chunks' scans), ``ssm_state_resets``
+    # (admissions from a zero state) and the gauge ``ssm_state_bytes``
+    # (``kv_pool_bytes`` stays K and V alone).
     "kvpool": {
         "kind", "t", "blocks_total", "blocks_free", "blocks_shared",
         "prefix_hits", "prefix_misses",

@@ -349,7 +349,9 @@ def _pool_program(
     params, lm_head = _described(jax.eval_shape(weights), one_chip)
     pool = _described(
         jax.eval_shape(
-            lambda: init_paged_pool(config, blocks, bs, BF16, kv_dtype=kv_dtype)
+            lambda: init_paged_pool(
+                config, blocks, bs, BF16, kv_dtype=kv_dtype, slots=slots
+            )
         ),
         one_chip,
     )
@@ -373,8 +375,11 @@ def _pool_program(
         return jax.jit(fn, **layered), args, pool
     if name == "chunk":
         fn = functools.partial(pe._chunk_program, config=config, block_size=bs)
+        table_row = arr((nbs,), I32)
+        if config.hybrid_block:  # a chunk addresses its slot's state by id
+            table_row = {"blocks": table_row, "slot": scalar}
         args = (
-            params, lm_head, pool, moe, arr((nbs,), I32), arr((1, 256), I32),
+            params, lm_head, pool, moe, table_row, arr((1, 256), I32),
             scalar, scalar, arr((2,), jnp.uint32), arr((), F32), scalar,
             arr((), F32),
         )
@@ -777,3 +782,98 @@ def test_latent_pool_programs(one_chip, on_tpu, name):
     assert _pool_copies(text, {"bf16[2049,16,640]"}) == []
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= sum(a.size * 2 for a in leaves)
+
+
+# ------------------------------ granite-4.0-h-small (state-space layers)
+
+
+@pytest.mark.parametrize("slots", [96, 8], ids=["96_slots", "8_slots"])
+def test_ssm_state_update(one_chip, on_tpu, slots):
+    """The tick's one-step update at the published widths: 128 heads of 64
+    channels over a state of 128, float32 states a row a slot and one of
+    trash, updated in place (aliased whole, no temporary of their size)."""
+    from bpe_transformer_tpu.kernels.pallas.ssm import ssm_state_update
+
+    def fn(states, ids, x, dt, a, b, c, d_skip):
+        return ssm_state_update(states, ids, x, dt, a, b, c, d_skip, path="pallas")
+
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in (
+            ((slots + 1, 128, 64, 128), F32), ((slots,), I32), ((slots, 128, 64), BF16),
+            ((slots, 128), F32), ((128,), F32), ((slots, 128), BF16),
+            ((slots, 128), BF16), ((128,), F32),
+        )
+    ]
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ssm_state_update" in text
+    memory = compiled.memory_analysis()
+    states = (slots + 1) * 128 * 64 * 128 * 4
+    assert memory.alias_size_in_bytes == states
+    assert memory.temp_size_in_bytes < states // 16
+
+
+@pytest.mark.parametrize(
+    "rows", [960, 2560, 10240], ids=["tick_96_slots", "chunk_256", "chunk_1024"]
+)
+@pytest.mark.parametrize("d_out,d_in", [(768, 4096), (4096, 768)], ids=["up", "down"])
+def test_grouped_matmul_at_granite_widths(one_chip, monkeypatch, rows, d_out, d_in):
+    """10 assignments a token, 36 experts of 768 held, hidden 4,096: an
+    output width and a contraction of 768 (`grouped_matmul._tiling`)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _compile(
+        grouped_matmul, one_chip,
+        ((rows, d_in), BF16), ((36, d_out, d_in), BF16), ((36,), I32),
+    )
+
+
+def test_paged_decode_attention_at_granite_widths(one_chip):
+    """The attention layer's tick: 32 query heads over 8 KV heads of 128,
+    96 slots, a table one context (4,096 keys) wide, scores times 1/128."""
+
+    def fn(q, k, v, tables, counts):
+        return paged_decode_attention(
+            q, k, v, tables, counts, interpret=False, scale=0.0078125
+        )
+
+    pool = ((12289, 16, 8 * 128), BF16)
+    _compile(
+        fn, one_chip, ((96, 32, 128), BF16), pool, pool, ((96, 256), I32),
+        ((96,), I32),
+    )
+
+
+@pytest.mark.parametrize("name", ["tick", "chunk"], ids=["tick", "chunk256"])
+def test_recurrent_pool_programs(one_chip, on_tpu, name):
+    """The engine's two programs over a recurrent pool, a Mamba-2 layer and
+    an attention layer at the published widths: the kernels are there (the
+    chunk scans and attends in XLA), and the pool - state rows, conv rows, K
+    and V - is aliased whole and never copied."""
+    import json
+    from pathlib import Path
+
+    from bpe_transformer_tpu.models.config import ModelConfig
+
+    path = Path(__file__).resolve().parents[1] / "chipbench/configs/granite-4.0-h-small.json"
+    file = json.loads(path.read_text())
+    config = ModelConfig(**{
+        **{k: file[k] for k in file["architecture_keys"]}, "num_layers": 2,
+        "attn_layer_period": 2, "attn_layer_offset": 1,
+    })
+    jitted, args, pool = _pool_program(name, config, one_chip, None, slots=8)
+    compiled = jitted.lower(*args).compile()
+    text = compiled.as_text()
+    # By the Mosaic calls themselves (an instruction is named after its
+    # kernel): the text's header tables name functions of the whole trace.
+    calls = " ".join(l.split("=")[0] for l in text.splitlines() if " custom-call(" in l)
+    assert ("ssm_state_update" in calls) == (name == "tick")
+    assert ("paged_decode_attention" in calls) == (name == "tick")
+    assert "gmm" in calls
+    assert [line for line in _sorts(text) if f",{config.vocab_size}]" in line] == []
+    leaves = jax.tree_util.tree_leaves(pool)
+    shapes = {_shape_text(a) for a in leaves}
+    assert shapes == {"f32[9,128,64,128]", "bf16[9,3,8448]", "bf16[2049,16,1024]"}
+    assert _pool_copies(text, {"f32[9,128,64,128]", "bf16[2049,16,1024]"}) == []
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in leaves)

@@ -97,8 +97,17 @@ DEFAULT_BUDGET_S = 800.0
 #: (tests/test_sampler.py, 29 cases in about 40 s), and the tick, a chunk
 #: and the verify pass compiled for the described v5e with no sort and no
 #: key crossing into a branch (tests/test_chip_compile.py, 4 cases and one
-#: assertion in the latent programs', 2-8 s each).
-DEFAULT_MAX_TESTS = 975
+#: assertion in the latent programs', 2-8 s each).  Raised 975 -> 1040 in
+#: PR 36 (1,004 collected, 62 added): the Granite-4.0-H block against its
+#: reference on every path - the mixer's three forms, the dense cache, paged
+#: chunks and ticks with a slot mid-prefill, a slot's next tenant, idle
+#: slots bit for bit - the share test, the multipliers, the counters and
+#: each refusal (tests/test_granitehybrid.py, 51 cases in about 165 s in
+#: one process), and the state-update kernel, the grouped matmul and the
+#: paged decode kernel at its widths and the two recurrent pool programs
+#: compiled for the described v5e (tests/test_chip_compile.py, 11 cases,
+#: 1-8 s each).
+DEFAULT_MAX_TESTS = 1040
 
 #: Pytest summary trailer: "== 398 passed, 27 deselected in 612.34s =="
 #: (also plain "in 612.34s (0:10:12)" forms).
