@@ -53,6 +53,7 @@ Compile count: one program per chunk bucket + one tick, asserted by
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import time
@@ -94,7 +95,8 @@ __all__ = ["PagedEngine", "PagedSlotInfo", "NoFreeBlocksError"]
 
 def _chunk_program(
     params, lm_head, pool, moe_pending, table_row, chunk, start, chunk_len,
-    key, temp, top_k, top_p, *, config: ModelConfig, block_size: int,
+    key, temp, top_k, top_p, carry, slot, final, *, config: ModelConfig,
+    block_size: int,
 ):
     """One chunk-bucket-shaped prefill step + first-token sampling.  The
     sampled token/key are meaningful only for a prompt's FINAL chunk (the
@@ -105,7 +107,16 @@ def _chunk_program(
     routing counts of the chunks since the last tick where the cache kind
     carries any (None where it does not: no argument, no output); the
     chunk's own are added and the next tick hands them to the host, never
-    this program."""
+    this program.
+
+    ``carry`` is the decode carry ``(tokens, positions, keys)`` as the
+    launch before left it on the device.  A ``final`` chunk writes its
+    ``slot``'s entry - the first token, the prompt's length, the split key
+    - and so makes the slot a row of the next tick with no read in
+    between; any other chunk hands the carry on as it came.  It rides
+    through both programs in launch order, as ``moe_pending`` does, and is
+    never donated: the launch before still holds the array the host has
+    yet to read."""
     bucket = chunk.shape[1]
     cache = chunk_cache(
         config, table_row, start, chunk_len, bucket, block_size=block_size
@@ -119,7 +130,16 @@ def _chunk_program(
     tok = sample_tokens(
         logits, sub[None], temp[None], top_k[None], top_p[None]
     )[0]
-    return tok, key, pool, None if counts is None else moe_pending + counts
+    with jax.named_scope("carry_write"):
+        tokens, positions, keys = carry
+        carry = (
+            tokens.at[slot].set(jnp.where(final, tok, tokens[slot])),
+            positions.at[slot].set(
+                jnp.where(final, start + chunk_len, positions[slot])
+            ),
+            keys.at[slot].set(jnp.where(final, key, keys[slot])),
+        )
+    return tok, carry, pool, None if counts is None else moe_pending + counts
 
 
 def _tick_program(
@@ -246,22 +266,74 @@ class PagedSlotInfo:
     request_id: str | None = None
 
 
+@dataclasses.dataclass
+class _Launch:
+    """One program in the device's queue whose result the host has yet to
+    read: a decode tick, or a prompt's final chunk."""
+
+    tokens: jax.Array  # a tick's token a slot; a final chunk's one token
+    #: ``(slot, tenant)`` of every row the host will emit: the slots live at
+    #: dispatch and who held each (a final chunk: its one slot).
+    rows: tuple
+    #: A tick's routing counts ``(since the last tick, its own)`` and the
+    #: state-space slot-layers it updated.
+    moe: tuple | None = None
+    ssm_state_rows: int = 0
+    first: bool = False  # a final chunk: the token is its slot's first
+
+
 class PagedEngine:
     """Paged-KV continuous-batching engine (see module docstring).
 
     Single-threaded like the dense engine: one caller drives
     :meth:`begin`/:meth:`prefill_step`/:meth:`tick`/:meth:`release` (or
     the :meth:`admit` convenience that runs a whole prefill at once).
+
+    **The decode carry lives on the device.**  ``tokens``, ``positions`` and
+    ``keys`` of one launch are the next launch's arguments as the device
+    arrays they came back as (``_carry``); a prompt's final chunk writes its
+    slot's entry there.  So no program's result has to be read before the
+    next program is queued: :meth:`launch` queues a tick and starts the copy
+    of its tokens to the host, :meth:`collect` reads the oldest unread
+    launch, and :meth:`tick` is the one after the other.  A caller that
+    runs one launch ahead (the serving worker: ``launch()`` for tick n+1,
+    then ``collect()`` for tick n) gets the same tokens in the same order.
+    The host keeps its own ``_positions`` by arithmetic - a dispatched live
+    slot advances by one - and reads back tokens only.
+
+    *Finishing one launch late.*  A finish by length is a count the host
+    has at dispatch: such a slot is not live in the next launch.  A finish
+    by ``stop_id``, a cancellation and an eviction are found after the next
+    launch was queued with the slot live; that row is stale.  Every launch
+    records who held each live slot (``_tenant``, bumped at every
+    :meth:`release`), and :meth:`collect` emits a row only to the tenant
+    that launched it.  On the device a stale row is harmless: it writes
+    position p+1 of the old chain through the table captured at dispatch,
+    inside the chain :meth:`blocks_needed` reserved and beyond the prompt's
+    full blocks (all the radix cache shares); a block freed and allocated
+    again is overwritten by the new tenant's chunk, which is queued later
+    and so runs later; a recurrent state row is reset by the next tenant's
+    first chunk; a window group's recycled block is read by launch n
+    before launch n+1 writes it, by device order; and the slot's entry of
+    the carry is rewritten by the next tenant's final chunk before a tick
+    runs it.  (Where the cache kind counts routing, a stale row's tokens
+    are in the counts: the fixed-shape tick computed them.)
+
+    Every host-side reader or writer of the carry outside the two halves
+    - :meth:`rewind`, :meth:`export_slot`, :meth:`import_slot`, a
+    speculative tick - goes through :meth:`read_carry` /
+    :meth:`write_carry`, which :meth:`flush` first.
     """
 
     #: Optional flight recorder (telemetry/flightrecorder.py), attached by
     #: the serving engine: KV rewinds (speculative rejections, host-side
     #: truncations) are pool decisions the incident ring should show.
     recorder = None
-    #: Seconds of the last tick's three phases, ``(dispatch, wait, emit)``
-    #: — the ``_tick_jit`` call until it returns (argument transfer and
-    #: enqueue), the reads that block on the device, the Python loop from
-    #: arrays to events — handed to the serving worker as plain data for
+    #: Seconds ``(dispatch, wait, emit)`` since the last :meth:`launch`
+    #: began: that launch's dispatch — the ``_tick_jit`` call until it
+    #: returns (argument transfer and enqueue) —, and of every launch read
+    #: since then the host's blocked time on it and the Python loop from
+    #: array to events — handed to the serving worker as plain data for
     #: its ``tick`` record.  Each is also a ``serve/tick_*`` annotation in
     #: a profiler's trace.
     last_tick_s = (0.0, 0.0, 0.0)
@@ -506,10 +578,25 @@ class PagedEngine:
         self.last_tick_moe_rows_local = 0
         self.last_tick_moe_zero_assignments = 0
         self._moe_pending = cache_kind(config).zero_counts(config)
-        self._tokens = np.zeros(slots, np.int32)
+        #: The decode carry ``(tokens, positions, keys)``, on the device.
+        self._carry = jax.device_put((
+            np.zeros(slots, np.int32), np.zeros(slots, np.int32),
+            np.zeros((slots, 2), np.uint32),
+        ))
+        #: The host's own positions, kept by arithmetic.
         self._positions = np.zeros(slots, np.int32)
+        #: The slots the next launch runs.
         self._active = np.zeros(slots, bool)
-        self._keys = np.zeros((slots, 2), np.uint32)
+        #: Tokens a slot may still launch: a finish by length is known at
+        #: dispatch, and takes the slot out of the next launch.
+        self._budget = np.zeros(slots, np.int64)
+        #: Who holds a slot: bumped at every release.
+        self._tenant = np.zeros(slots, np.int64)
+        #: Launches the host has yet to read, oldest first, and the events
+        #: a :meth:`flush` read on a caller's behalf, held for the next
+        #: :meth:`collect`.
+        self._unread: collections.deque = collections.deque()
+        self._held: list[TickEvent] = []
         self._temps = np.zeros(slots, np.float32)
         self._top_ks = np.full(slots, TOP_K_DISABLED, np.int32)
         self._top_ps = np.full(slots, TOP_P_DISABLED, np.float32)
@@ -571,6 +658,13 @@ class PagedEngine:
         )
 
         self.ticks = 0
+        #: Ticks queued while the one before was unread, rows a launch
+        #: computed for a tenant that had left before they were read, and
+        #: the times a reader or writer of the carry had to read unread
+        #: launches first.
+        self.ticks_overlapped = 0
+        self.tick_stale_rows = 0
+        self.carry_flushes = 0
         #: Ticks in which a live sampled slot asked for top-k / for top-p:
         #: how often each of the sampler's searches ran.
         self.sample_topk_ticks = 0
@@ -588,6 +682,13 @@ class PagedEngine:
     @property
     def free_slots(self) -> int:
         return sum(1 for info in self._slots if info is None)
+
+    @property
+    def unread(self) -> int:
+        """Launches whose events :meth:`collect` has yet to hand over: those
+        the host has not read, and as one more what a :meth:`flush` read
+        and holds."""
+        return len(self._unread) + bool(self._held)
 
     def compiled_programs(self) -> int:
         """XLA programs compiled by this engine so far — bounded by
@@ -683,6 +784,9 @@ class PagedEngine:
         out["moe_zero_assignments"] = int(self.moe_counts[3])
         out["prefill_pending_tokens"] = self.pending_prefill_tokens()
         out["prefill_pending_slots"] = len(self._prefilling)
+        out["ticks_overlapped"] = self.ticks_overlapped
+        out["tick_stale_rows"] = self.tick_stale_rows
+        out["carry_flushes"] = self.carry_flushes
         out["ssm_tick_state_rows"] = self.ssm_tick_state_rows
         out["ssm_chunk_tokens"] = self.ssm_chunk_tokens
         out["ssm_chunk_rows"] = self.ssm_chunk_rows
@@ -903,9 +1007,12 @@ class PagedEngine:
           rather than repaired.
 
         Returns ``{"released": n_blocks, "cow": bool}``.  The caller owns
-        position/sampling state — this is a KV-memory primitive.
+        position/sampling state (:meth:`write_carry`) — this is a KV-memory
+        primitive.  Unread launches are read first (:meth:`flush`): what
+        they emitted is part of the frontier the caller rolls back from.
         """
         self._refuse_grouped("rewind")
+        self.flush()
         info = self._slots[slot]
         if info is None:
             raise ValueError(f"slot {slot} is not occupied")
@@ -983,6 +1090,7 @@ class PagedEngine:
         self._refuse_grouped("KV migration (export_slot)")
         self._refuse_latent("KV migration (export_slot)")
         self._refuse_recurrent("KV migration (export_slot)")
+        tokens, positions, keys = self.read_carry()
         info = self._slots[slot]
         if info is None:
             raise ValueError(f"slot {slot} is not occupied")
@@ -995,7 +1103,7 @@ class PagedEngine:
         # recycled garbage the importer re-reserves locally — shipping
         # them would inflate the transfer (the disaggregated path's
         # dominant cost) with bytes nobody reads.
-        frontier = int(self._positions[slot]) if decoding else info.next_pos
+        frontier = int(positions[slot]) if decoding else info.next_pos
         n_written = -(-frontier // self.block_size)
         ids = info.block_ids[:n_written]
         per_block = [
@@ -1040,9 +1148,9 @@ class PagedEngine:
             "temperature": float(info.temp_enc),
             "top_k": int(info.top_k_enc),
             "top_p": float(info.top_p_enc),
-            "token": int(self._tokens[slot]),
-            "position": int(self._positions[slot]),
-            "key": [int(k) for k in self._keys[slot]],
+            "token": int(tokens[slot]),
+            "position": int(positions[slot]),
+            "key": [int(k) for k in keys[slot]],
             "request_id": info.request_id,
         }
         if extra_meta:
@@ -1207,12 +1315,15 @@ class PagedEngine:
         )
         self._slots[slot] = info
         if meta["decoding"]:
-            self._tokens[slot] = int(meta["token"])
-            self._positions[slot] = int(meta["position"])
-            self._keys[slot] = np.asarray(meta["key"], np.uint32)
+            tokens, positions, keys = self.read_carry()
+            tokens[slot] = int(meta["token"])
+            positions[slot] = int(meta["position"])
+            keys[slot] = np.asarray(meta["key"], np.uint32)
+            self.write_carry(tokens, positions, keys)
             self._temps[slot] = info.temp_enc
             self._top_ks[slot] = info.top_k_enc
             self._top_ps[slot] = info.top_p_enc
+            self._budget[slot] = info.max_new_tokens - info.generated
             self._active[slot] = True
             if self.prefix_cache is not None:
                 full = plen // self.block_size
@@ -1314,15 +1425,14 @@ class PagedEngine:
 
     def _table_rows(self, slot: int | None = None):
         """The block tables as the cache kind takes them: every slot's rows
-        (a tick), or one slot's (a chunk).  A chunk's are its own COPY.  A
-        non-final chunk returns without reading anything back, so its
-        program may still be waiting when the host next rewrites the row
-        (`_write_window_row` recycles in place), and the CPU backend reads a
+        (a tick), or one slot's (a chunk) - a COPY either way.  No launch
+        is read back before the host goes on: its program may still be
+        waiting when the host next rewrites a row (an admission, a release,
+        `_write_window_row` recycling in place), and the CPU backend reads a
         numpy argument that happens to lie 64-byte aligned where it lies,
-        without copying it: the chunk then attended through the next
-        chunk's row (ROADMAP D11).  A tick's caller reads its results back
-        before it touches a table."""
-        pick = (lambda a: a) if slot is None else (lambda a: a[slot].copy())
+        without copying it: a chunk then attended through the next chunk's
+        row (ROADMAP D11).  The copy belongs to its launch alone."""
+        pick = (lambda a: a.copy()) if slot is None else (lambda a: a[slot].copy())
         if self.recurrent and slot is not None:
             # A chunk addresses its slot's state rows by the slot's id.
             return {"blocks": pick(self._tables), "slot": np.int32(slot)}
@@ -1334,14 +1444,46 @@ class PagedEngine:
             "window_base": pick(self._window_base),
         }
 
-    def prefill_step(self, slot: int, dispatched=None) -> TickEvent | None:
-        """Run ONE prefill chunk for ``slot``.  Returns ``None`` while
-        chunks remain; on the final chunk, samples the request's first
-        token, activates the slot for decode ticks, indexes the prompt's
-        full blocks into the prefix cache, and returns the admission
-        :class:`TickEvent` (exactly the dense engine's ``admit`` result).
-        ``dispatched`` is called once the chunk's program is in the device's
-        queue, before the final chunk's token is waited on (as `tick`'s)."""
+    # ------------------------------------------------------ the decode carry
+
+    def flush(self) -> None:
+        """Read every unread launch now, out of the two halves' order, and
+        hold its events for the next :meth:`collect`: what a reader or
+        writer of the carry, or of the memory an unread launch may still
+        write, does first."""
+        if self._unread:
+            self.carry_flushes += 1
+            self._hold_unread()
+
+    def _hold_unread(self, keep: int = 0) -> None:
+        """Read all but the newest ``keep`` unread launches and hold their
+        events for the next :meth:`collect`."""
+        while len(self._unread) > keep:
+            self._held += self._read(self._unread.popleft())
+
+    def read_carry(self) -> tuple:
+        """The carry ``(tokens, positions, keys)`` on the host, as writable
+        copies, with nothing unread behind it."""
+        self.flush()
+        return tuple(np.array(part) for part in self._carry)
+
+    def write_carry(self, tokens, positions, keys) -> None:
+        """Replace the carry (and the host's own positions) from host
+        arrays; the device gets copies no one else holds."""
+        self.flush()
+        self._positions = np.array(positions, np.int32)
+        self._carry = jax.device_put((
+            np.array(tokens, np.int32), self._positions.copy(),
+            np.array(keys, np.uint32),
+        ))
+
+    def launch_chunk(self, slot: int) -> bool:
+        """Queue ONE prefill chunk for ``slot`` and read nothing; returns
+        whether it was the prompt's final chunk.  A final chunk samples the
+        request's first token and writes the slot's entry of the carry on
+        the device, so the slot is a row of the next :meth:`launch`; the
+        prompt's full blocks are indexed into the prefix cache; the token is
+        the next unread launch's to hand over (:meth:`collect`)."""
         info = self._slots[slot]
         if info is None or slot not in self._prefilling:
             raise ValueError(f"slot {slot} has no pending prefill")
@@ -1368,30 +1510,31 @@ class PagedEngine:
             self.ssm_chunk_rows += self._ssm_layers * bucket
             # The chunk program starts a chunk at position 0 from zeros.
             self.ssm_state_resets += int(info.next_pos == 0)
-        tok, key, _, self._moe_pending = self._in_place(
+        tok, self._carry, _, self._moe_pending = self._in_place(
             f"chunk_{bucket}", self._chunk_jit,
             self._params, self._lm_head, self._pool, self._moe_pending,
             self._table_rows(slot), padded, np.int32(info.next_pos),
             np.int32(chunk_len), key_in, info.temp_enc, info.top_k_enc,
-            info.top_p_enc, pool_at=-2,
+            info.top_p_enc, self._carry, np.int32(slot), np.bool_(final),
+            pool_at=-2,
         )
-        if dispatched is not None:
-            dispatched()
         info.next_pos += chunk_len
         if not final:
-            return None
+            return False
 
+        tok.copy_to_host_async()
+        self._unread.append(
+            _Launch(tok, ((slot, int(self._tenant[slot])),), first=True)
+        )
         self._prefilling.remove(slot)
-        token = int(tok)
-        self._tokens[slot] = token
         self._positions[slot] = plen
-        self._keys[slot] = np.asarray(key)
         self._temps[slot] = info.temp_enc
         self._top_ks[slot] = info.top_k_enc
         self._top_ps[slot] = info.top_p_enc
-        self._active[slot] = True
-        info.generated = 1
-        self.tokens_emitted += 1
+        # The first token is one of the budget: a request for one token is
+        # never a row of a tick.
+        self._budget[slot] = info.max_new_tokens - 1
+        self._active[slot] = self._budget[slot] > 0
         if self.prefix_cache is not None:
             full = plen // self.block_size
             if full:
@@ -1399,10 +1542,25 @@ class PagedEngine:
                     [int(t) for t in info.prompt[: full * self.block_size]],
                     info.block_ids[:full],
                 )
-        finished = SlotPoolEngine._finish_reason(info, token)
-        if finished:
-            self.release(slot)
-        return TickEvent(slot=slot, token=token, finished=finished)
+        return True
+
+    def prefill_step(self, slot: int, dispatched=None) -> TickEvent | None:
+        """Run ONE prefill chunk for ``slot``: :meth:`launch_chunk`, and
+        after a final chunk the read of its token at once.  Returns ``None``
+        while chunks remain, and on the final chunk the admission
+        :class:`TickEvent` (exactly the dense engine's ``admit`` result).
+        ``dispatched`` is called once the chunk's program is in the device's
+        queue, before the final chunk's token is waited on (as `tick`'s).
+        Launches that were unread before it are read first, their events
+        held for the next :meth:`collect`."""
+        final = self.launch_chunk(slot)
+        if dispatched is not None:
+            dispatched()
+        if not final:
+            return None
+        self._hold_unread(keep=1)
+        (event,) = self._read(self._unread.popleft())
+        return event
 
     def admit(
         self,
@@ -1435,66 +1593,98 @@ class PagedEngine:
             if event is not None:
                 return event
 
-    def tick(self, dispatched=None) -> list[TickEvent]:
-        """One batched decode step across every occupied slot — semantics
-        identical to the dense engine's tick, ``dispatched`` included."""
+    def launch(self) -> bool:
+        """Queue one batched decode step across every live slot and read
+        nothing: the carry goes in as the device arrays the launch before
+        left, comes back as device arrays, and the copy of the tokens to the
+        host starts here.  Returns whether there was a live slot to launch
+        for.  What the host counts of a tick - positions, attention pairs,
+        a finish by length - it counts here, from its own arithmetic."""
         if not self._active.any():
-            return []
+            return False
         with Phase("serve/tick_dispatch", self.clock) as dispatch:
+            live = np.flatnonzero(self._active)
             window = self.config.sliding_window
             if self.grouped:
-                for slot in np.flatnonzero(self._active):
+                for slot in live:
                     self._advance_window(
                         int(slot), int(self._positions[slot]) - window + 1
                     )
             # One query a live slot: pairs and KV positions are alike.
-            seen = self._positions[self._active].astype(np.int64) + 1
-            live = int(seen.sum())
-            keys_read = (self._attn_sublayers - self._window_layers) * live
+            seen = self._positions[live].astype(np.int64) + 1
+            keys_live = int(seen.sum())
+            keys_read = (
+                self._attn_sublayers - self._window_layers
+            ) * keys_live
             if self.grouped:
                 keys_read += self._window_layers * int(
                     np.minimum(seen, window).sum()
                 )
             self.attn_pairs += keys_read
             self.attn_kv_positions += keys_read
-            self.tick_live_keys += live
+            self.tick_live_keys += keys_live
             self.tick_table_keys += self._tables.size * self.block_size
-            self.last_tick_ssm_state_rows = self._ssm_layers * len(seen)
-            self.ssm_tick_state_rows += self.last_tick_ssm_state_rows
+            ssm_state_rows = self._ssm_layers * len(live)
+            self.ssm_tick_state_rows += ssm_state_rows
             asked = filters_asked(
                 self._active, self._temps, self._top_ks, self._top_ps
             )
             self.sample_topk_ticks += asked[0]
             self.sample_topp_ticks += asked[1]
+            tokens, positions, keys = self._carry
+            # The host's arrays go in as copies: this launch may still be
+            # waiting when an admission or a release next rewrites them.
             tokens, positions, keys, _, moe = self._in_place(
                 "tick", self._tick_jit,
                 self._params, self._lm_head, self._pool, self._moe_pending,
-                self._table_rows(), self._tokens, self._positions,
-                self._active, self._keys, self._temps, self._top_ks,
-                self._top_ps, pool_at=-2,
+                self._table_rows(), tokens, positions, self._active.copy(),
+                keys, self._temps.copy(), self._top_ks.copy(),
+                self._top_ps.copy(), pool_at=-2,
             )
-        if dispatched is not None:
-            dispatched()
-        with Phase("serve/tick_wait", self.clock) as wait:
-            tokens = np.asarray(tokens)
-            self._tokens = tokens.copy()
-            self._positions = np.asarray(positions).copy()
-            self._keys = np.asarray(keys).copy()
+            self._carry = (tokens, positions, keys)
+            tokens.copy_to_host_async()
             if moe is not None:
-                # The same read as the tokens: no sync of its own.
-                moe_since, moe_tick, self._moe_pending = moe
-                moe_since, moe_tick = np.asarray(moe_since), np.asarray(moe_tick)
+                # They come to the host with the tokens.
+                *moe, self._moe_pending = moe
+                for counts in moe:
+                    counts.copy_to_host_async()
+            self.ticks_overlapped += any(
+                not unread.first for unread in self._unread
+            )
+            self._unread.append(_Launch(
+                tokens,
+                tuple(zip(live.tolist(), self._tenant[live].tolist())),
+                moe, ssm_state_rows,
+            ))
+            self.ticks += 1
+            self._positions[live] += 1
+            self._budget[live] -= 1
+            self._active[live[self._budget[live] <= 0]] = False
+        self.last_tick_s = (dispatch.dur_s, 0.0, 0.0)
+        return True
+
+    def _read(self, launch: _Launch) -> list[TickEvent]:
+        """Read one launch's tokens (the host blocks here if the device has
+        yet to finish it) and turn its rows into events: each row to the
+        tenant that launched it, a row whose tenant has left to no one."""
+        with Phase("serve/tick_wait", self.clock) as wait:
+            tokens = np.asarray(launch.tokens).reshape(-1).tolist()
+            if launch.moe is not None:
+                moe_since, moe_tick = map(np.asarray, launch.moe)
                 self.moe_counts[: moe_since.size] += moe_since
                 self.last_tick_moe_rows_local = int(moe_tick[1])
                 self.last_tick_moe_zero_assignments = int(moe_tick[3:].sum())
-        self.ticks += 1
-
         events: list[TickEvent] = []
         with Phase("serve/tick_emit", self.clock) as emit:
-            for slot in np.flatnonzero(self._active):
-                slot = int(slot)
+            if not launch.first:
+                self.last_tick_ssm_state_rows = launch.ssm_state_rows
+            held = self._tenant.tolist()  # a release bumps its own slot only
+            for slot, tenant in launch.rows:
+                if held[slot] != tenant:
+                    self.tick_stale_rows += 1
+                    continue
                 info = self._slots[slot]
-                token = int(tokens[slot])
+                token = tokens[0 if launch.first else slot]
                 info.generated += 1
                 self.tokens_emitted += 1
                 finished = SlotPoolEngine._finish_reason(info, token)
@@ -1503,7 +1693,33 @@ class PagedEngine:
                 events.append(
                     TickEvent(slot=slot, token=token, finished=finished)
                 )
-        self.last_tick_s = (dispatch.dur_s, wait.dur_s, emit.dur_s)
+        dispatch_s, wait_s, emit_s = self.last_tick_s
+        self.last_tick_s = (
+            dispatch_s, wait_s + wait.dur_s, emit_s + emit.dur_s
+        )
+        return events
+
+    def collect(self) -> list[TickEvent]:
+        """The events of the oldest unread launch - a tick's, or the first
+        token of a final chunk - or, before any of those, what a
+        :meth:`flush` read and holds.  The last tick's routing counts and
+        state rows (``last_tick_*``) are then those of the tick read
+        here."""
+        if self._held:
+            events, self._held = self._held, []
+            return events
+        return self._read(self._unread.popleft()) if self._unread else []
+
+    def tick(self, dispatched=None) -> list[TickEvent]:
+        """One batched decode step across every occupied slot — semantics
+        identical to the dense engine's tick, ``dispatched`` included:
+        :meth:`launch`, then :meth:`collect` until nothing is unread."""
+        self.launch()
+        if dispatched is not None:
+            dispatched()
+        events = self.collect()
+        while self.unread:
+            events += self.collect()
         return events
 
     def release(self, slot: int) -> None:
@@ -1514,6 +1730,8 @@ class PagedEngine:
         info = self._slots[slot]
         self._active[slot] = False
         self._slots[slot] = None
+        # A row an unread launch computed for this tenant is no one's now.
+        self._tenant[slot] += 1
         if slot in self._prefilling:
             self._prefilling.remove(slot)
         if info is not None and info.block_ids:

@@ -199,10 +199,15 @@ class ServingMetrics:
                 counts[1] += int(prompt_tokens)
                 counts[2] += max(float(seconds), 0.0)
 
-    def on_decode_tick(self, tokens: int, seconds: float) -> None:
-        """Account one batched decode tick (tokens sampled, wall time)."""
+    def on_decode_tokens(self, tokens: int) -> None:
+        """Account the tokens of the decode ticks the worker has read."""
         with self._lock:
             self.decode_tokens += int(tokens)
+
+    def on_decode_seconds(self, seconds: float) -> None:
+        """Account one tick period's decode wall time (the engine's
+        dispatch, wait and emit phases)."""
+        with self._lock:
             self.decode_seconds += max(float(seconds), 0.0)
 
     def on_worker_period(self, seconds: dict) -> None:

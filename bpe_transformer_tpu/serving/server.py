@@ -146,13 +146,17 @@ class _Entry:
         "request", "tokens", "stream", "done", "result", "slot",
         "t_submit", "t_decode_start", "queue_wait_s", "prefill_s",
         "cancel_requested", "bucket", "t_prefill_start", "programs_before",
-        "shared_tokens", "migrated_in",
+        "shared_tokens", "migrated_in", "t_chunks_queued",
     )
 
     def __init__(self, request: Request, t_submit: float):
         self.request = request
         self.tokens: list[int] = []
-        self.stream: queue.Queue = queue.Queue()
+        #: The request's tokens on their way to its reader.  A C-level queue:
+        #: since the worker runs a launch ahead it seldom blocks, so what a
+        #: put and a reader's wake-up take of the interpreter lock is the
+        #: worker's own time (PERF.md section 6, PR 37).
+        self.stream: queue.SimpleQueue = queue.SimpleQueue()
         self.done = threading.Event()
         self.result: Result | None = None
         self.slot: int | None = None
@@ -166,6 +170,9 @@ class _Entry:
         self.programs_before = 0  # compile counter at admission (paged)
         self.shared_tokens = 0  # prefix-cache-reused prompt tokens (paged)
         self.migrated_in = False  # arrived as a KV graft (ISSUE 15)
+        #: When the final chunk was queued with its token left unread (the
+        #: paged engine's worker): the prefill lasts until that read.
+        self.t_chunks_queued: float | None = None
 
 
 class RequestHandle:
@@ -332,6 +339,11 @@ class ServingEngine:
                 weight_dtype=weight_dtype, fused_sampling=fused_sampling,
             )
         self.paged = paged
+        #: The worker runs the plain paged engine one launch ahead
+        #: (`PagedEngine.launch` for tick n+1, then `collect` for tick n).
+        #: The dense pool's tick and a speculative one read back what they
+        #: launch: theirs is the composed ``tick()``.
+        self._runs_ahead = paged and not speculate_k
         # The engine times its tick's dispatch/wait/emit phases on the
         # worker's clock, so they add up with the worker's own phases.
         self.engine.clock = clock
@@ -551,7 +563,8 @@ class ServingEngine:
         if self._thread is not None:
             self._thread.join(timeout=30)
             self._thread = None
-        self._publish()
+        if self._worker_error is None:
+            self._settle()  # what the device had finished is not cancelled
         drain = self.scheduler.pop_ready(self.scheduler.max_queue)
         for qe in drain.admit + drain.expired + drain.cancelled:
             self._finish(qe.item, "cancelled")
@@ -1196,7 +1209,10 @@ class ServingEngine:
         except BaseException as exc:  # noqa: BLE001 — fail loudly, unblock callers
             self._worker_error = exc
             self._running = False
-            self._publish()  # what the last tick finished did not fail
+            try:
+                self._settle()  # what the queued launches finished did not fail
+            except Exception:  # noqa: BLE001 — the engine itself is what failed
+                self._publish()
             self.metrics.record_error(repr(exc), source="worker")
             self.flightrecorder.record("worker_error", error=repr(exc))
             if self._telemetry is not None:
@@ -1242,13 +1258,13 @@ class ServingEngine:
         # every queued and in-flight session leaves as a KV payload (or a
         # whole queue entry) before anything else runs this iteration.
         if self._draining and (self._evacuate_peers or self._evacuate_urls):
-            self._publish()  # a session leaves with its stream up to date
+            self._settle()  # a session leaves with its stream up to date
             worked |= self._evacuate_step()
 
         # Controller-initiated hot rebalancing (ISSUE 20): export victim
         # sessions and relay them to the requested peer without draining.
         if self._rebalance_queue:
-            self._publish()
+            self._settle()
             worked |= self._rebalance_step()
 
         with self._phase("admit") as admit:
@@ -1257,21 +1273,47 @@ class ServingEngine:
 
         worked |= self._advance_prefills()
 
-        if self.engine.active_count:
+        launched = self.engine.active_count > 0
+        if launched:
             # Chaos hook: SIGKILL-mid-decode fires here, between slots
             # holding live KV and the tick that would advance them — the
             # worst instant a replica can die.
             self._decode_ticks += 1
             self.faults.at_decode_tick(self._decode_ticks)
+        if self._runs_ahead and (launched or self.engine.unread):
+            # Tick n+1 goes into the device's queue before tick n is read;
+            # so does whatever a final chunk of this iteration left unread
+            # (its slot's first token).  Only the newest launch stays
+            # unread, and not even that one where nothing will follow it.
+            self.engine.launch()
+            events = []
+            while self.engine.unread > int(
+                launched and self.engine.active_count > 0
+            ):
+                events += self.engine.collect()
+            self._deliver(events)
+            self._publish()  # the device has the next launch to run meanwhile
+        elif launched:
             events = self.engine.tick(dispatched=self._publish)
-            tick = self.engine.last_tick_s  # (dispatch, wait, emit)
-            self._deliver(events, sum(tick))
-            self._close_period(tick, n_events=len(events))
-            worked = True
+            self._deliver(events)
         else:
             self._publish()  # no program to publish behind
+        if launched:
+            tick = self.engine.last_tick_s  # (dispatch, wait, emit)
+            self.metrics.on_decode_seconds(sum(tick))
+            self._close_period(tick, n_events=len(events))
+            worked = True
         self._maybe_emit_engine_record()
         return worked
+
+    def _settle(self) -> None:
+        """Read every launch the engine has queued and not read, take the
+        tokens to their requests and publish them: before a session leaves
+        (evacuation, rebalancing), at shutdown and on a worker error."""
+        if self._runs_ahead:
+            self.engine.flush()
+            self._deliver(self.engine.collect())
+        self._publish()
 
     def _admit_step(self) -> bool:
         """The admission part of one iteration (the ``serve/admit`` phase):
@@ -1373,7 +1415,17 @@ class ServingEngine:
             "prefill_tokens": 0, "idle_s": 0.0, "deliver_s": 0.0,
             "tokens_before": self.engine.tokens_emitted,
             "ssm_chunk_before": self._ssm_chunk_counts(),
+            "carry_before": self._carry_counts(),
         }
+
+    def _carry_counts(self) -> tuple:
+        """The engine's ``(ticks_overlapped, tick_stale_rows,
+        carry_flushes)`` (zeros for an engine that reads back what it
+        launches)."""
+        return tuple(
+            getattr(self.engine, name, 0)
+            for name in ("ticks_overlapped", "tick_stale_rows", "carry_flushes")
+        )
 
     def _ssm_chunk_counts(self) -> tuple:
         """The engine's ``(ssm_chunk_tokens, ssm_chunk_rows)``: real and
@@ -1419,6 +1471,10 @@ class ServingEngine:
             now - before for now, before in
             zip(self._ssm_chunk_counts(), period["ssm_chunk_before"])
         )
+        overlapped, stale_rows, carry_flushes = (
+            now - before for now, before in
+            zip(self._carry_counts(), period["carry_before"])
+        )
         self._telemetry.emit(
             {
                 "kind": "tick",
@@ -1431,7 +1487,16 @@ class ServingEngine:
                 # the first token of each prefill that completed in it.
                 "batch": self.engine.tokens_emitted - period["tokens_before"],
                 "queue_depth": self.scheduler.depth,
-                # Assignments of the tick's tokens that landed on experts
+                # Whether the period's launch was queued while the one
+                # before was unread, the rows it read that a launch had
+                # computed for a tenant since gone, and the times a reader
+                # of the carry had to read unread launches first.
+                "overlapped": overlapped,
+                "stale_rows": stale_rows,
+                "carry_flushes": carry_flushes,
+                # Of the tick that was READ in the period (one launch behind
+                # the one it queued, where the worker runs ahead):
+                # assignments of its tokens that landed on experts
                 # held here and on zero experts (dropless expert layers of
                 # the paged engine; 0 elsewhere).
                 "moe_rows_local": getattr(
@@ -1440,7 +1505,7 @@ class ServingEngine:
                 "moe_zero_assignments": getattr(
                     self.engine, "last_tick_moe_zero_assignments", 0
                 ),
-                # State-space slot-layers the tick updated (live slots x
+                # State-space slot-layers that tick updated (live slots x
                 # state-space layers) and the period's chunks' real and
                 # bucket rows x state-space layers (0 without such layers).
                 "ssm_tick_state_rows": getattr(
@@ -2071,9 +2136,18 @@ class ServingEngine:
                     return worked  # budget spent: decode tick runs next
                 delivered = self._period["deliver_s"]
                 with self._phase("prefill_chunk") as chunk:
-                    event = self.engine.prefill_step(
-                        slot, dispatched=self._publish
-                    )
+                    if self._runs_ahead and not entry.request.migrate:
+                        # Queued and not read: a final chunk's token comes
+                        # with the tick before it, behind the next launch.
+                        event = None
+                        queued = self.engine.launch_chunk(slot)
+                    else:
+                        # A prefix that leaves as a KV payload is no row of
+                        # a tick here: read at once, nothing follows.
+                        queued = False
+                        event = self.engine.prefill_step(
+                            slot, dispatched=self._publish
+                        )
                 entry.prefill_s += chunk.dur_s
                 # A publish behind the chunk is the period's deliver.
                 self._period["prefill_s"] += chunk.dur_s - (
@@ -2083,14 +2157,21 @@ class ServingEngine:
                 self._period["prefill_tokens"] += chunk_tokens
                 budget.spend(chunk_tokens)
                 worked = True
+                if queued:
+                    entry.t_chunks_queued = chunk.start + chunk.dur_s
+                    break
                 if event is not None:
-                    del self._prefill_entries[slot]
                     self._complete_prefill(entry, event)
                     break
         return worked
 
     def _complete_prefill(self, entry: _Entry, event: TickEvent) -> None:
         request = entry.request
+        del self._prefill_entries[entry.slot]
+        if entry.t_chunks_queued is not None:
+            # The final chunk was queued with its token unread: the prefill
+            # lasted until this read.
+            entry.prefill_s += self._clock() - entry.t_chunks_queued
         self.metrics.on_prefill(
             entry.bucket,
             # COMPUTED prompt tokens: the prefix-cache-shared prefix paid
@@ -2118,11 +2199,18 @@ class ServingEngine:
         else:
             self._slot_entries[event.slot] = entry
 
-    def _deliver(self, events: list[TickEvent], tick_s: float) -> None:
+    def _deliver(self, events: list[TickEvent]) -> None:
         """Take a tick's events to their requests, now, while a slot still
-        names its request; the streams get them in :meth:`_publish`."""
-        self.metrics.on_decode_tick(len(events), tick_s)
+        names its request; the streams get them in :meth:`_publish`.  A
+        slot still among the prefills names a request whose final chunk was
+        queued with its token unread: this is that token (the engine hands
+        no row to a slot's next tenant)."""
+        first = 0
         for event in events:
+            if event.slot in self._prefill_entries:
+                self._complete_prefill(self._prefill_entries[event.slot], event)
+                first += 1
+                continue
             entry = self._slot_entries.get(event.slot)
             if entry is None:
                 continue  # released between admit and tick (cancellation)
@@ -2130,6 +2218,7 @@ class ServingEngine:
             if event.finished:
                 del self._slot_entries[event.slot]
             self._unpublished.append((entry, event.token, event.finished))
+        self.metrics.on_decode_tokens(len(events) - first)
 
     def _publish(self) -> None:
         """Put the held tokens on their requests' streams and finish the

@@ -473,6 +473,13 @@ class SpecEngine(PagedEngine):
         )
         return event
 
+    def launch(self) -> bool:
+        raise NotImplementedError(
+            "a speculative tick reads the carry on the host between its "
+            "draft and its verify program, so it does not split into a "
+            "launch and a collect: call tick()"
+        )
+
     def tick(self, dispatched=None) -> list[TickEvent]:
         """One speculative tick (:meth:`_spec_tick`).  Its draft and verify
         programs each sync, so the worker's tick record gets the whole
@@ -493,9 +500,12 @@ class SpecEngine(PagedEngine):
         if not self._active.any():
             return []
         t0 = time.perf_counter()
+        # The carry on the host (the base engine keeps it on the device):
+        # headroom, acceptance and rewind below are host decisions.
+        tokens, positions, keys = self.read_carry()
         d_toks, d_probs, self._draft_cache, d_keys = self._propose_jit(
             self.draft.params, self.draft.lm_head, self._draft_cache,
-            self._tokens, self._positions, self._active, self._draft_keys,
+            tokens, positions, self._active, self._draft_keys,
             self._temps, self._top_ks, self._top_ps,
         )
         jax.block_until_ready(d_toks)
@@ -511,7 +521,7 @@ class SpecEngine(PagedEngine):
         for slot in np.flatnonzero(self._active):
             slot = int(slot)
             info = self._slots[slot]
-            p = int(self._positions[slot])
+            p = int(positions[slot])
             room = min(self.k, ctx - 1 - p)
             try:
                 self.extend_blocks(slot, p + room + 1)
@@ -529,20 +539,20 @@ class SpecEngine(PagedEngine):
         out, n_emit, keys, _ = self._in_place(
             "tick", self._verify_jit,
             self._params, self._lm_head, self._pool, self._tables,
-            self._tokens, d_toks, d_probs, self._positions, rooms,
-            self._active, self._keys, self._temps, self._top_ks,
+            tokens, d_toks, d_probs, positions, rooms,
+            self._active, keys, self._temps, self._top_ks,
             self._top_ps,
         )
         out = np.asarray(out)
         n_emit = np.asarray(n_emit)
-        self._keys = np.asarray(keys).copy()
+        keys = np.asarray(keys)
         self.ticks += 1
 
         events: list[TickEvent] = []
         for slot in np.flatnonzero(self._active):
             slot = int(slot)
             info = self._slots[slot]
-            p = int(self._positions[slot])
+            p = int(positions[slot])
             room = int(rooms[slot])
             emit = int(n_emit[slot])
             self.spec_proposed += room
@@ -563,8 +573,8 @@ class SpecEngine(PagedEngine):
                 if finished:
                     break
             new_p = p + emitted
-            self._tokens[slot] = int(out[slot, emitted - 1])
-            self._positions[slot] = new_p
+            tokens[slot] = int(out[slot, emitted - 1])
+            positions[slot] = new_p
             if finished:
                 self.release(slot)
             else:
@@ -579,6 +589,7 @@ class SpecEngine(PagedEngine):
                         info.prompt_len, info.max_new_tokens
                     ),
                 )
+        self.write_carry(tokens, positions, keys)
         now = time.perf_counter()
         self.draft_time_s += t_draft - t0
         self.tick_time_s += now - t0

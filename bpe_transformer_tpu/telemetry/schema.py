@@ -44,21 +44,34 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     },
     # One decode tick of the serving worker (serving/server.py): where the
     # tick *period* went.  A period runs from the end of the previous
-    # tick to the end of this one, so consecutive records tile
+    # launch's period to the end of this one's, so consecutive records tile
     # the worker's time (``t`` + ``dur_s`` = the next record's ``t``, on
-    # the serve/* spans' axis).  The phase fields are the clock pairs of
-    # the worker's ``serve/*`` profiler annotations, summed over the
-    # period: ``admit_s`` (cancellations, backlog expiry, grafts, the
-    # scheduler pop, every admission), ``prefill_s`` over ``chunks``
-    # prefill-chunk calls of ``prefill_tokens`` prompt tokens,
-    # ``dispatch_s`` / ``wait_s`` / ``emit_s`` (the tick: the program's call
-    # until it returns, blocked on the device, arrays to events),
-    # ``deliver_s`` (the tick before's tokens to their streams and its
-    # finished requests, behind this period's first launch), ``idle_s``
-    # (waiting for work) and ``other_s`` = ``dur_s`` minus the rest, kept
-    # explicit.  ``batch`` is the tokens the engine emitted in the period
-    # (the tick's, plus the first token of each prefill that completed in
-    # it); ``queue_depth`` the scheduler's at the period's end.  Optional
+    # the serve/* spans' axis): one record a launch.  The phase fields are
+    # the clock pairs of the worker's ``serve/*`` profiler annotations,
+    # summed over the period: ``admit_s`` (cancellations, backlog expiry,
+    # grafts, the scheduler pop, every admission), ``prefill_s`` over
+    # ``chunks`` prefill-chunk calls of ``prefill_tokens`` prompt tokens
+    # (the paged engine's worker queues a chunk and reads nothing: dispatch
+    # and bookkeeping), ``dispatch_s`` (the tick program's call until it
+    # returns), ``wait_s`` (the host blocked on the oldest unread launch:
+    # the paged engine's worker queues tick n+1 first and then reads tick n
+    # and the first token of each final chunk queued behind it, so this is
+    # what of their device time the host's own work had not covered; the
+    # dense and the speculative engine read back the tick they launched),
+    # ``emit_s`` (arrays to events, of the launches read), ``deliver_s``
+    # (tokens to their streams and the finished requests: behind the next
+    # launch either way), ``idle_s`` (waiting for work) and ``other_s`` =
+    # ``dur_s`` minus the rest, kept explicit.  ``batch`` is the tokens the
+    # engine emitted in the period (those of the launches it read);
+    # ``queue_depth`` the scheduler's at the period's end.  Optional
+    # ``overlapped``: 1 where the period's launch was queued while the one
+    # before was unread; ``stale_rows``: rows read in it that a launch had
+    # computed for a tenant since gone (a finish by stop id, a cancellation
+    # or an eviction found one launch late); ``carry_flushes``: times a
+    # reader or writer of the decode carry (migration, rewind) had to read
+    # unread launches first (all 0 for the engines that do not run ahead).
+    # The ``moe_*`` and ``ssm_tick_*`` fields are those of the tick that
+    # was READ in the period.  Optional
     # ``moe_rows_local``: the tick's expert assignments that landed on
     # experts held here (grouped paged engine; 0 elsewhere); optional
     # ``moe_zero_assignments``: those that landed on zero-compute experts;

@@ -347,10 +347,16 @@ def _pool_program(
         return prepare_serving_weights(params, config, None)[:2]
 
     params, lm_head = _described(jax.eval_shape(weights), one_chip)
+    # A config with window layers keeps a window group beside the full one:
+    # window + one chunk (256 here) of positions a slot, as the engine sizes it.
+    window_cap = (
+        (config.sliding_window + 256) // bs if config.has_window_layers else 0
+    )
     pool = _described(
         jax.eval_shape(
             lambda: init_paged_pool(
-                config, blocks, bs, BF16, kv_dtype=kv_dtype, slots=slots
+                config, blocks, bs, BF16, kv_dtype=kv_dtype, slots=slots,
+                num_window_blocks=slots * window_cap + 1 if window_cap else 0,
             )
         ),
         one_chip,
@@ -364,24 +370,38 @@ def _pool_program(
 
     scalar = arr((), I32)
     layered = dict(donate_argnums=(2,), compiler_options=options)
+    # The decode carry: a launch's tokens, positions and keys.
+    tokens, positions, keys = (
+        arr((slots,), I32), arr((slots,), I32), arr((slots, 2), jnp.uint32)
+    )
+    def tables(*lead):
+        """The block tables as `PagedEngine._table_rows` hands them over."""
+        if not window_cap:
+            return arr((*lead, nbs), I32)
+        return {
+            "full": arr((*lead, nbs), I32),
+            "window": arr((*lead, window_cap), I32),
+            "window_base": arr(lead, I32),
+        }
+
     if name == "tick":
         fn = functools.partial(pe._tick_program, config=config, block_size=bs)
         args = (
-            params, lm_head, pool, moe, arr((slots, nbs), I32), arr((slots,), I32),
-            arr((slots,), I32), arr((slots,), jnp.bool_),
-            arr((slots, 2), jnp.uint32), arr((slots,), F32),
+            params, lm_head, pool, moe, tables(slots), tokens,
+            positions, arr((slots,), jnp.bool_), keys, arr((slots,), F32),
             arr((slots,), I32), arr((slots,), F32),
         )
         return jax.jit(fn, **layered), args, pool
     if name == "chunk":
         fn = functools.partial(pe._chunk_program, config=config, block_size=bs)
-        table_row = arr((nbs,), I32)
+        table_row = tables()
         if config.hybrid_block:  # a chunk addresses its slot's state by id
             table_row = {"blocks": table_row, "slot": scalar}
         args = (
             params, lm_head, pool, moe, table_row, arr((1, 256), I32),
             scalar, scalar, arr((2,), jnp.uint32), arr((), F32), scalar,
-            arr((), F32),
+            arr((), F32), (tokens, positions, keys), scalar,
+            arr((), jnp.bool_),
         )
         return jax.jit(fn, **layered), args, pool
     if name == "verify":
@@ -457,6 +477,71 @@ def test_pool_programs_hold_no_pool_copy(one_chip, name, kv_dtype):
     if kv_dtype is None:
         assert memory.alias_size_in_bytes == kv_bytes
         assert memory.temp_size_in_bytes < kv_bytes // (2 * config.num_layers)
+
+
+def _cell_config(cell: str):
+    """A serve cell's configuration at its published widths, cut to the
+    fewest layers that hold one of each kind it has."""
+    import dataclasses
+    import json
+    from pathlib import Path
+
+    from bpe_transformer_tpu.models.config import ModelConfig
+
+    if cell in ("small", "medium"):
+        preset = GPT2_SMALL_32K if cell == "small" else GPT2_MEDIUM
+        return dataclasses.replace(preset, num_layers=2)
+    name, cut = {
+        "cmdaplus": ("command-a-plus-05-2026", {}),  # one period as it is
+        "longcat": ("LongCat-Flash-Omni", {"num_layers": 1}),
+        "granite": ("granite-4.0-h-small", {
+            "num_layers": 2, "attn_layer_period": 2, "attn_layer_offset": 1,
+        }),
+    }[cell]
+    path = Path(__file__).resolve().parents[1] / f"chipbench/configs/{name}.json"
+    file = json.loads(path.read_text())
+    return ModelConfig(**{**{k: file[k] for k in file["architecture_keys"]}, **cut})
+
+
+def _donated(lowered_text: str) -> int:
+    """Arguments of the lowered module's ``main`` that the caller donates."""
+    import re
+
+    head = lowered_text[lowered_text.index("func.func public @main("):]
+    head = head[: head.index(") -> ")]
+    return len(re.findall(r"tf\.aliasing_output|jax\.buffer_donor", head))
+
+
+@pytest.mark.parametrize("name", ["tick", "chunk"], ids=["tick", "chunk256"])
+@pytest.mark.parametrize(
+    "cell", ["small", "medium", "cmdaplus", "longcat", "granite"]
+)
+def test_the_carry_rides_through_both_programs_undonated(one_chip, on_tpu, cell, name):
+    """The decode carry (ISSUE 37) lowered for the described v5e, at the
+    cells' widths: the tick takes ``tokens``, ``positions`` and ``keys`` in
+    the shapes and types it always took and hands them back, with no write
+    of its own into them; a chunk takes all three, writes its slot's entry
+    of each (three scatters under ``carry_write``, nothing else there but
+    their selects) and hands them back; and neither program donates
+    anything but the pool - the launch before still holds the arrays the
+    host has yet to read."""
+    config = _cell_config(cell)
+    jitted, args, pool = _pool_program(name, config, one_chip, None, slots=8)
+    carry = [((8,), I32), ((8,), I32), ((8, 2), jnp.uint32)]
+    outs = jax.tree_util.tree_leaves(jax.eval_shape(jitted, *args))
+    if name == "tick":
+        assert [(a.shape, a.dtype) for a in (args[5], args[6], args[8])] == carry
+        assert [(o.shape, o.dtype) for o in outs[:3]] == carry
+    else:
+        assert [(o.shape, o.dtype) for o in outs[1:4]] == carry
+    traced = jitted.trace(*args)
+    assert _donated(traced.lower().as_text()) == len(jax.tree_util.tree_leaves(pool))
+    written = [
+        str(eqn.primitive) for eqn in traced.jaxpr.eqns
+        if "carry_write" in str(eqn.source_info.name_stack)
+    ]
+    assert written.count("scatter") == (3 if name == "chunk" else 0)
+    assert (written != []) == (name == "chunk")
 
 
 @pytest.fixture

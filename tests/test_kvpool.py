@@ -1027,6 +1027,14 @@ def _drive_to_decode(engine, prompt, **knobs):
     return slot
 
 
+def _set_cursor(engine, slot, position):
+    """The caller owns the decode cursor (as SpecEngine does): it moves it
+    through the carry's owner, which keeps device and host in step."""
+    tokens, positions, keys = engine.read_carry()
+    positions[slot] = position
+    engine.write_carry(tokens, positions, keys)
+
+
 def test_rewind_within_block_is_bookkeeping(setup):
     """Frontier rollback inside a block releases nothing and copies
     nothing: abandoned rows stay in the pool, invisible behind the
@@ -1179,7 +1187,7 @@ def test_rewind_then_regrow_int8_scales_coherent(setup):
     # decode cursor is host state, so emulate the spec engine's usage —
     # roll KV back and the cursor with it.
     engine.rewind(slot, 10, keep_blocks=2)
-    engine._positions[slot] = 10
+    _set_cursor(engine, slot, 10)
     for _ in range(4):
         engine.tick()
     scale_after = np.asarray(engine._pool[0]["k_scale"])[b1]
@@ -1193,7 +1201,7 @@ def test_rewind_then_regrow_int8_scales_coherent(setup):
     # from the previous occupancy).
     engine.rewind(slot, 8, keep_blocks=1)
     assert len(engine._slots[slot].block_ids) == 1
-    engine._positions[slot] = 8
+    _set_cursor(engine, slot, 8)
     engine.extend_blocks(slot, 16)
     b1_new = engine._slots[slot].block_ids[1]
     engine.tick()  # writes position 8 = offset 0 of the regrown block
@@ -1420,6 +1428,106 @@ def test_export_import_roundtrip_token_identical(
         slot_b = dst.import_slot(payload)
         out += _continue_on(dst, slot_b, event)
         assert out == ref, f"migration divergence for {kn}"
+
+
+def _payloads_equal(a: dict, b: dict) -> None:
+    meta_a = {k: v for k, v in a["meta"].items() if k != "export_s"}
+    meta_b = {k: v for k, v in b["meta"].items() if k != "export_s"}
+    assert meta_a == meta_b
+    for layer_a, layer_b in zip(a["layers"], b["layers"]):
+        assert set(layer_a) == set(layer_b)
+        for name in layer_a:
+            np.testing.assert_array_equal(layer_a[name], layer_b[name])
+
+
+@pytest.mark.parametrize("seeded", [False, True], ids=["greedy", "seeded"])
+def test_export_after_overlapped_launches_is_the_synchronous_payload(
+    setup, migration_target, seeded
+):
+    """ISSUE 37: `export_slot` reads the decode carry, which lives on the
+    device, so it flushes first.  A slot exported with a launch unread (the
+    worker's order: tick n+1 queued before tick n is read) ships the payload
+    the synchronous order ships after the same number of ticks, the tokens
+    the flush read are handed over by the next `collect`, and the importer
+    continues to the monolithic run's tokens."""
+    params, prompts = setup
+    kn = dict(temperature=0.9, top_k=7, seed=3) if seeded else dict(temperature=0.0)
+    engines = [
+        PagedEngine(params, CFG, slots=2, block_size=8, min_bucket=8)
+        for _ in range(2)
+    ]
+    sync, ahead = engines
+    first = [e.admit(prompts[3], max_new_tokens=8, **kn) for e in engines]
+    assert first[0] == first[1]
+    slot = first[0].slot
+    want = [first[0].token] + [sync.tick()[0].token for _ in range(3)]
+    got = [first[1].token]
+    assert ahead.launch()
+    for _ in range(2):  # tick n+1 queued, then tick n read
+        assert ahead.launch()
+        got += [e.token for e in ahead.collect()]
+    assert ahead.unread == 1 and ahead.carry_flushes == 0
+    payload = ahead.export_slot(slot)  # flushes the third launch
+    assert ahead.carry_flushes == 1 and ahead.unread == 1  # held, not lost
+    got += [e.token for e in ahead.collect()]
+    assert got == want and ahead.unread == 0
+    _payloads_equal(payload, sync.export_slot(slot))
+    assert payload["meta"]["generated"] == 4
+    assert payload["meta"]["token"] == want[-1]
+    # The importer writes the carry (a flush of its own: nothing unread)
+    # and decodes on, one launch ahead, to the same tokens.
+    ref = _run(sync, prompts[3], max_new_tokens=8, **kn)
+    assert ref[:4] == want
+    dst = migration_target
+    slot_b = dst.import_slot(payload_from_bytes(payload_to_bytes(payload)))
+    rest = []
+    launched = dst.launch()
+    while dst.unread:
+        launched = dst.launch()
+        while dst.unread > int(launched and dst.active_count > 0):
+            rest += [e.token for e in dst.collect() if e.slot == slot_b]
+    assert got + rest == ref
+    for engine in engines:
+        engine.release(slot)
+
+
+def test_rewind_after_overlapped_launches_is_the_synchronous_rewind(setup):
+    """ISSUE 37: `rewind` flushes.  Rolled back with a launch unread, a slot
+    releases the blocks and then decodes the tokens it does after the same
+    ticks read one by one."""
+    params, prompts = setup
+    engines = [
+        PagedEngine(params, CFG, slots=1, block_size=8, min_bucket=8,
+                    prefix_cache=False)
+        for _ in range(2)
+    ]
+    sync, ahead = engines
+    slots = [
+        _drive_to_decode(e, prompts[0], max_new_tokens=24, temperature=0.0)
+        for e in engines
+    ]  # 3 tokens of prompt
+    tokens = [[], []]
+    for _ in range(9):  # positions 3..11: across the first block boundary
+        tokens[0] += [e.token for e in sync.tick()]
+    assert ahead.launch()
+    for _ in range(8):
+        assert ahead.launch()
+        tokens[1] += [e.token for e in ahead.collect()]
+    assert ahead.unread == 1
+    out = [
+        e.rewind(slot, 6, keep_blocks=1) for e, slot in zip(engines, slots)
+    ]
+    # Four blocks reserved (3 + 24 positions), one kept.
+    assert out[0] == out[1] == {"released": 3, "cow": False}
+    assert ahead.carry_flushes == 1 and sync.carry_flushes == 0
+    tokens[1] += [e.token for e in ahead.collect()]  # what the flush read
+    assert tokens[0] == tokens[1] and len(tokens[1]) == 9
+    assert int(ahead._positions[slots[1]]) == int(sync._positions[slots[0]]) == 12
+    for engine, slot in zip(engines, slots):
+        _set_cursor(engine, slot, 6)
+        engine.extend_blocks(slot, 16)
+    after = [[e.token for _ in range(4) for e in engine.tick()] for engine in engines]
+    assert after[0] == after[1] and len(after[0]) == 4
 
 
 def test_import_mid_prefill_frontier_resumes(setup, dense_engine):
@@ -1720,7 +1828,7 @@ def audited_lifecycle(request, setup):
         event = engine.prefill_step(slot)
     engine.tick()
     assert engine.rewind(slot, 4)["cow"]
-    engine._positions[slot] = 4  # the caller owns the cursor (as SpecEngine)
+    _set_cursor(engine, slot, 4)
     engine.tick()
     payload = payload_from_bytes(payload_to_bytes(engine.export_slot(slot)))
     engine.release(slot)

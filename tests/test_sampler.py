@@ -392,6 +392,10 @@ def _tiny_programs():
             params, head, pool, None, tables[0], np.zeros((1, 16), np.int32),
             np.int32(0), np.int32(9), np.zeros(2, np.uint32),
             np.float32(1.0), np.int32(50), np.float32(0.9),
+            # The decode carry, the chunk's slot, and that it is final.
+            (np.zeros(slots, np.int32), np.zeros(slots, np.int32),
+             np.zeros((slots, 2), np.uint32)),
+            np.int32(0), np.bool_(True),
         )),
     }
 

@@ -322,6 +322,37 @@ def test_sampled_generation_respects_stop_and_length(setup, spec_engine):
     assert stop == greedy[: greedy.index(stop_id) + 1]
 
 
+def test_greedy_spec_equals_the_paged_engine_one_launch_ahead(setup, spec_engine):
+    """ISSUE 37: the plain paged engine keeps its decode carry on the
+    device and may run one launch ahead; a speculative tick reads the carry
+    on the host through the same owner (`read_carry` / `write_carry`) and
+    does not split.  Greedy tokens agree across the two, and the carry the
+    speculative engine writes back is the one its host arithmetic says."""
+    params, prompts = setup
+    plain = PagedEngine(params, CFG, slots=2, block_size=8, min_bucket=8)
+    slot = plain.begin(prompts[2], max_new_tokens=9, temperature=0.0)
+    while not plain.launch_chunk(slot):
+        pass
+    ahead = []
+    while plain.unread:
+        launched = plain.launch()
+        while plain.unread > int(launched and plain.active_count > 0):
+            ahead += [e.token for e in plain.collect()]
+    assert plain.ticks_overlapped > 0
+    assert ahead == _run(spec_engine, prompts[2], max_new_tokens=9,
+                         temperature=0.0)
+    with pytest.raises(NotImplementedError, match="tick\\(\\)"):
+        spec_engine.launch()
+    event = spec_engine.admit(prompts[2], max_new_tokens=9, temperature=0.0)
+    spec_engine.tick()
+    tokens, positions, _ = spec_engine.read_carry()
+    assert np.array_equal(positions, spec_engine._positions)
+    generated = spec_engine._slots[event.slot].generated
+    assert int(positions[event.slot]) == len(prompts[2]) + generated - 1
+    assert int(tokens[event.slot]) == ahead[generated - 1]
+    spec_engine.release(event.slot)
+
+
 # ------------------------------------------------------- compile bound
 
 

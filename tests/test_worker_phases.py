@@ -81,6 +81,15 @@ def test_one_tick_record_per_decode_tick(served):
     # Every prompt (no two share a prefix) went through in chunks of <= 8.
     assert sum(t["prefill_tokens"] for t in ticks) == sum(3 + 3 * i for i in range(5))
     assert sum(t["chunks"] for t in ticks) >= 5
+    # One launch ahead: a launch found the one before it unread (or not:
+    # the first one, and one after the engine had run empty), no row went
+    # to a tenant that had left, and no reader of the carry cut in.
+    assert sum(t["overlapped"] for t in ticks) == (
+        after["ticks_overlapped"] - before["ticks_overlapped"]
+    ) > 0
+    assert all(t["overlapped"] in (0, 1) for t in ticks)
+    assert sum(t["stale_rows"] for t in ticks) == after["tick_stale_rows"] == 0
+    assert sum(t["carry_flushes"] for t in ticks) == after["carry_flushes"] == 0
 
 
 @pytest.mark.parametrize("phase", WORKER_PHASES)
@@ -148,14 +157,40 @@ def test_other_engines_hand_over_their_tick_split(params, kind):
         assert all(t["dispatch_s"] > 0 and t["emit_s"] > 0 for t in ticks)
 
 
-@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
-def test_a_ticks_tokens_are_published_behind_the_next_launch(params, paged):
-    """The worker holds a tick's tokens until the next program is queued,
-    and they still reach the request the slot named when the tick ran: one
-    slot, so the second request takes the slot over while the first one's
-    last token is held."""
-    engine = dict(paged=True, block_size=4, prefill_chunk=8) if paged else {}
-    serving = ServingEngine(params, CFG, slots=1, min_bucket=8, **engine)
+def two_on_one_slot():
+    return [
+        Request(prompt_ids=tuple(range(10 * i + 1, 10 * i + 6)),
+                max_new_tokens=4 + i, temperature=0.0, seed=i)
+        for i in range(2)
+    ]
+
+
+def serve_two_on_one_slot(serving):
+    """Two requests through one slot, so the second takes the slot over
+    while the first one's last token is still on its way; returns what was
+    streamed, the results, and the same two served alone afterwards."""
+    requests = two_on_one_slot()
+    with serving:
+        handles = [serving.submit(r) for r in requests]
+        streamed = [list(h.tokens()) for h in handles]
+        results = [h.result(timeout=300) for h in handles]
+        alone = [
+            serving.generate(r.prompt_ids, max_new_tokens=r.max_new_tokens,
+                             temperature=0.0)
+            for r in requests
+        ]
+    assert [len(s) for s in streamed] == [4, 5]
+    for got, result, want in zip(streamed, results, alone):
+        assert tuple(got) == result.token_ids == want.token_ids
+    return results, alone
+
+
+def test_a_ticks_tokens_are_published_behind_the_next_launch(params):
+    """The worker holds a dense tick's tokens until the next program is
+    queued, and they still reach the request the slot named when the tick
+    ran: one slot, so the second request takes the slot over while the
+    first one's last token is held."""
+    serving = ServingEngine(params, CFG, slots=1, min_bucket=8)
     held_at_launch, events_of_tick = [], []
     real_tick = serving.engine.tick
 
@@ -170,27 +205,123 @@ def test_a_ticks_tokens_are_published_behind_the_next_launch(params, paged):
         return events
 
     serving.engine.tick = tick
-    requests = [
-        Request(prompt_ids=tuple(range(10 * i + 1, 10 * i + 6)),
-                max_new_tokens=4 + i, temperature=0.0, seed=i)
-        for i in range(2)
-    ]
-    with serving:
-        handles = [serving.submit(r) for r in requests]
-        streamed = [list(h.tokens()) for h in handles]
-        results = [h.result(timeout=300) for h in handles]
-        alone = [
-            serving.generate(r.prompt_ids, max_new_tokens=r.max_new_tokens,
-                             temperature=0.0)
-            for r in requests
-        ]
-    assert [len(s) for s in streamed] == [4, 5]
-    for got, result, want in zip(streamed, results, alone):
-        assert tuple(got) == result.token_ids == want.token_ids
+    results, alone = serve_two_on_one_slot(serving)
     # What a launch found held is the tick before it, where one ran on; a
     # tick that emptied the engine was published at once.
     assert held_at_launch[0] == 0 and max(held_at_launch) == 1
     assert sum(events_of_tick) == sum(len(r.token_ids) - 1 for r in results + alone)
+
+
+def test_a_paged_ticks_tokens_are_read_behind_the_next_launch(params):
+    """The worker runs the paged engine one launch ahead: a launch finds
+    the one before it unread wherever a slot decodes on, a collect never
+    leaves more than the newest launch unread, and every token still
+    reaches the request the slot named when its launch was queued."""
+    serving = ServingEngine(
+        params, CFG, slots=1, min_bucket=8, paged=True, block_size=4,
+        prefill_chunk=8,
+    )
+    engine = serving.engine
+    unread_at_launch, unread_after_collect = [], []
+    real_launch, real_collect = engine.launch, engine.collect
+
+    def launch():
+        unread_at_launch.append(engine.unread)
+        return real_launch()
+
+    def collect():
+        events = real_collect()
+        unread_after_collect.append(engine.unread)
+        return events
+
+    engine.launch, engine.collect = launch, collect
+    results, alone = serve_two_on_one_slot(serving)
+    stats = serving.stats()
+    # Unread at a launch: the tick before it or the final chunk's token (one
+    # slot: never both); a request's last launch is known by its length and
+    # read at once.
+    assert set(unread_at_launch) == {1} and max(unread_after_collect) == 1
+    assert stats["ticks_overlapped"] > 0 and stats["tick_stale_rows"] == 0
+    assert stats["ticks"] == sum(len(r.token_ids) - 1 for r in results + alone)
+    assert engine.unread == 0
+
+
+def test_a_warm_up_of_one_request_a_bucket_leaves_nothing_to_compile(params):
+    """The benchmark warms a cell with one short request a prefill bucket,
+    one after the other: each program then runs with the kinds of argument
+    the window's overlapped launches hand it (the carry as device arrays,
+    the host's tables and knobs as copies), and a burst compiles nothing."""
+    with ServingEngine(
+        params, CFG, slots=3, min_bucket=8, paged=True, block_size=4,
+        prefill_chunk=16, prefill_buckets=(8, 16),
+    ) as serving:
+        for bucket in serving.engine.buckets:
+            # (No two warm-up prompts share a prefix: a radix hit would
+            # leave the longer one's bucket unwarmed.)
+            serving.generate(tuple(range(100 - bucket, 100)), max_new_tokens=3,
+                             temperature=0.0)
+        warm = serving.stats()
+        handles = [
+            serving.submit(Request(
+                prompt_ids=tuple(range(i + 1, i + 4 + 4 * (i % 4))),
+                max_new_tokens=3 + i, temperature=float(i % 2), top_k=20, seed=i,
+            ))
+            for i in range(8)
+        ]
+        assert all(h.result(timeout=300).finish_reason == "length" for h in handles)
+        after = serving.stats()
+    assert warm["compiled_programs"] == len(serving.engine.buckets) + 1
+    assert after["compiled_programs"] == warm["compiled_programs"]
+    assert after["ticks_overlapped"] > warm["ticks_overlapped"]
+
+
+@pytest.mark.parametrize("how", ["close", "worker_error"])
+def test_an_unread_launch_is_read_at_shutdown_and_on_a_worker_error(params, how):
+    """A launch the device has finished is not lost with the worker: its
+    tokens reach their request before the request ends cancelled or in
+    error, and nothing stays unread."""
+    serving = ServingEngine(
+        params, CFG, slots=2, min_bucket=8, paged=True, block_size=4,
+        prefill_chunk=8,
+    )
+    engine = serving.engine
+    launches = []
+    real_launch = engine.launch
+
+    def launch():
+        if how == "worker_error" and len(launches) == 3:
+            raise RuntimeError("injected: the fourth launch fails")
+        launched = real_launch()
+        launches.append(launched)
+        if how == "close" and len(launches) == 3:
+            serving._running = False  # the worker stops with this one unread
+        return launched
+
+    engine.launch = launch
+    serving.start()
+    handle = serving.submit(Request(
+        prompt_ids=(1, 2, 3, 4, 5), max_new_tokens=20, temperature=0.0, seed=0,
+    ))
+    if how == "close":
+        serving._thread.join(timeout=300)
+        assert engine.unread == 1
+        serving.close()
+        want = "cancelled"
+    else:
+        want = "error"
+    result = handle.result(timeout=300)
+    serving.close()
+    assert result.finish_reason == want
+    # The first token and one a launch: all three launches were read.
+    assert len(result.token_ids) == 1 + 3
+    assert engine.unread == 0
+    alone = ServingEngine(
+        params, CFG, slots=2, min_bucket=8, paged=True, block_size=4,
+        prefill_chunk=8,
+    )
+    with alone:
+        whole = alone.generate((1, 2, 3, 4, 5), max_new_tokens=20, temperature=0.0)
+    assert result.token_ids == whole.token_ids[:4]
 
 
 # ------------------------------------------------------------ the profiler
@@ -313,15 +444,16 @@ def paged_programs(params):
     eng = pe.PagedEngine(params, CFG, slots=2, block_size=4, min_bucket=8)
     tick = compiled_text(
         functools.partial(pe._tick_program, config=CFG, block_size=4),
-        eng._params, eng._lm_head, eng._pool, None, eng._tables, eng._tokens,
-        eng._positions, eng._active, eng._keys, eng._temps, eng._top_ks,
-        eng._top_ps,
+        eng._params, eng._lm_head, eng._pool, None, eng._tables,
+        eng._carry[0], eng._carry[1], eng._active, eng._carry[2], eng._temps,
+        eng._top_ks, eng._top_ps,
     )
     chunk = compiled_text(
         functools.partial(pe._chunk_program, config=CFG, block_size=4),
         eng._params, eng._lm_head, eng._pool, None, eng._tables[0],
         np.zeros((1, 8), np.int32), np.int32(0), np.int32(5),
         jax.random.PRNGKey(0), np.float32(1.0), np.int32(0), np.float32(1.0),
+        eng._carry, np.int32(0), np.bool_(True),
     )
     return {"tick": tick, "chunk": chunk}
 
@@ -348,6 +480,11 @@ def test_serving_program_carries_scope(paged_programs, program, scope):
     assert carries(paged_programs[program], scope)
 
 
+def test_only_the_chunk_writes_the_carry(paged_programs):
+    assert carries(paged_programs["chunk"], "carry_write")
+    assert not carries(paged_programs["tick"], "carry_write")
+
+
 def test_scopes_are_metadata_only(params):
     """The tick program compiles to the same instructions with the scopes
     as without: only ``metadata={...}`` differs."""
@@ -357,9 +494,9 @@ def test_scopes_are_metadata_only(params):
 
     eng = pe.PagedEngine(params, CFG, slots=2, block_size=4, min_bucket=8)
     args = (
-        eng._params, eng._lm_head, eng._pool, None, eng._tables, eng._tokens,
-        eng._positions, eng._active, eng._keys, eng._temps, eng._top_ks,
-        eng._top_ps,
+        eng._params, eng._lm_head, eng._pool, None, eng._tables,
+        eng._carry[0], eng._carry[1], eng._active, eng._carry[2], eng._temps,
+        eng._top_ks, eng._top_ps,
     )
     program = functools.partial(pe._tick_program, config=CFG, block_size=4)
     with_scopes = compiled_text(program, *args)
