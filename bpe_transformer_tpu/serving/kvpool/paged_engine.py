@@ -93,6 +93,40 @@ from bpe_transformer_tpu.utils.compile_cache import layered_program_options
 __all__ = ["PagedEngine", "PagedSlotInfo", "NoFreeBlocksError"]
 
 
+#: The two dispatch phases in parts, ``{program: (phase, parts)}``: each part
+#: is a ``serve/<phase>/<part>`` annotation nested in its phase
+#: (``serve/tick_dispatch`` is :meth:`PagedEngine.launch`'s own,
+#: ``serve/prefill_chunk`` the serving worker's around
+#: :meth:`PagedEngine.launch_chunk`), and its seconds, summed over every
+#: launch, are the gauge ``launch_<program>_<part>_s``.  ``call`` is the
+#: jitted call until it returns: argument transfers and the enqueue.
+LAUNCH_PARTS = {
+    "tick": ("tick_dispatch", ("prepare", "call", "after")),
+    "chunk": ("prefill_chunk", ("key", "prepare", "call", "after")),
+}
+
+
+class _Part(Phase):
+    """A part of a dispatch phase: a :class:`Phase` entered once a launch
+    that sums its seconds, ``total_s``.  Made once an engine (a fresh one a
+    launch costs the launch twice as much) and handed the engine's clock of
+    the day as it is entered: ``with part.at(clock): ...``."""
+
+    __slots__ = ("total_s",)
+
+    def __init__(self, name: str):
+        super().__init__(name)
+        self.total_s = 0.0
+
+    def at(self, clock) -> "_Part":
+        self._clock = clock
+        return self
+
+    def __exit__(self, *exc) -> None:
+        super().__exit__(*exc)
+        self.total_s += self.dur_s
+
+
 def _chunk_program(
     params, lm_head, pool, moe_pending, table_row, chunk, start, chunk_len,
     key, temp, top_k, top_p, carry, slot, final, *, config: ModelConfig,
@@ -670,6 +704,14 @@ class PagedEngine:
         self.sample_topk_ticks = 0
         self.sample_topp_ticks = 0
         self.tokens_emitted = 0
+        #: Chunks queued, and the parts of the two dispatch phases
+        #: (`LAUNCH_PARTS`).
+        self.chunk_launches = 0
+        self._parts = {
+            (program, part): _Part(f"serve/{phase}/{part}")
+            for program, (phase, parts) in LAUNCH_PARTS.items()
+            for part in parts
+        }
         #: The clock of the tick phases; the serving worker sets its own.
         self.clock = time.monotonic
 
@@ -787,6 +829,9 @@ class PagedEngine:
         out["ticks_overlapped"] = self.ticks_overlapped
         out["tick_stale_rows"] = self.tick_stale_rows
         out["carry_flushes"] = self.carry_flushes
+        out["chunk_launches"] = self.chunk_launches
+        for (program, part), timed in self._parts.items():
+            out[f"launch_{program}_{part}_s"] = timed.total_s
         out["ssm_tick_state_rows"] = self.ssm_tick_state_rows
         out["ssm_chunk_tokens"] = self.ssm_chunk_tokens
         out["ssm_chunk_rows"] = self.ssm_chunk_rows
@@ -1487,62 +1532,69 @@ class PagedEngine:
         info = self._slots[slot]
         if info is None or slot not in self._prefilling:
             raise ValueError(f"slot {slot} has no pending prefill")
-        plen = info.prompt_len
-        chunk_len = min(self.prefill_chunk, plen - info.next_pos)
-        bucket = self.bucket_for(chunk_len)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :chunk_len] = info.prompt[
-            info.next_pos: info.next_pos + chunk_len
-        ]
-        final = info.next_pos + chunk_len == plen
         # Key discipline = dense prefill: the request key is split ONCE, on
         # the final chunk; earlier chunks get a throwaway key and their
         # sampled token/key outputs are discarded.
-        key_in = jax.random.PRNGKey(info.seed)
-        if self.grouped:
-            # The chunk's first query reads back to next_pos - window + 1.
-            self._advance_window(
-                slot, info.next_pos - self.config.sliding_window + 1
-            )
-            self._count_attention(info.next_pos, info.next_pos + chunk_len)
-        if self.recurrent:
-            self.ssm_chunk_tokens += self._ssm_layers * chunk_len
-            self.ssm_chunk_rows += self._ssm_layers * bucket
-            # The chunk program starts a chunk at position 0 from zeros.
-            self.ssm_state_resets += int(info.next_pos == 0)
-        tok, self._carry, _, self._moe_pending = self._in_place(
-            f"chunk_{bucket}", self._chunk_jit,
-            self._params, self._lm_head, self._pool, self._moe_pending,
-            self._table_rows(slot), padded, np.int32(info.next_pos),
-            np.int32(chunk_len), key_in, info.temp_enc, info.top_k_enc,
-            info.top_p_enc, self._carry, np.int32(slot), np.bool_(final),
-            pool_at=-2,
-        )
-        info.next_pos += chunk_len
-        if not final:
-            return False
-
-        tok.copy_to_host_async()
-        self._unread.append(
-            _Launch(tok, ((slot, int(self._tenant[slot])),), first=True)
-        )
-        self._prefilling.remove(slot)
-        self._positions[slot] = plen
-        self._temps[slot] = info.temp_enc
-        self._top_ks[slot] = info.top_k_enc
-        self._top_ps[slot] = info.top_p_enc
-        # The first token is one of the budget: a request for one token is
-        # never a row of a tick.
-        self._budget[slot] = info.max_new_tokens - 1
-        self._active[slot] = self._budget[slot] > 0
-        if self.prefix_cache is not None:
-            full = plen // self.block_size
-            if full:
-                self.prefix_cache.insert(
-                    [int(t) for t in info.prompt[: full * self.block_size]],
-                    info.block_ids[:full],
+        with self._parts["chunk", "key"].at(self.clock):
+            key_in = jax.random.PRNGKey(info.seed)
+        with self._parts["chunk", "prepare"].at(self.clock):
+            plen = info.prompt_len
+            chunk_len = min(self.prefill_chunk, plen - info.next_pos)
+            bucket = self.bucket_for(chunk_len)
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :chunk_len] = info.prompt[
+                info.next_pos: info.next_pos + chunk_len
+            ]
+            final = info.next_pos + chunk_len == plen
+            if self.grouped:
+                # The chunk's first query reads back to next_pos - window + 1.
+                self._advance_window(
+                    slot, info.next_pos - self.config.sliding_window + 1
                 )
-        return True
+                self._count_attention(info.next_pos, info.next_pos + chunk_len)
+            if self.recurrent:
+                self.ssm_chunk_tokens += self._ssm_layers * chunk_len
+                self.ssm_chunk_rows += self._ssm_layers * bucket
+                # The chunk program starts a chunk at position 0 from zeros.
+                self.ssm_state_resets += int(info.next_pos == 0)
+            args = (
+                self._params, self._lm_head, self._pool, self._moe_pending,
+                self._table_rows(slot), padded, np.int32(info.next_pos),
+                np.int32(chunk_len), key_in, info.temp_enc, info.top_k_enc,
+                info.top_p_enc, self._carry, np.int32(slot), np.bool_(final),
+            )
+        with self._parts["chunk", "call"].at(self.clock):
+            tok, self._carry, _, self._moe_pending = self._in_place(
+                f"chunk_{bucket}", self._chunk_jit, *args, pool_at=-2
+            )
+            del args  # the old carry and the copies go here, as they did
+        with self._parts["chunk", "after"].at(self.clock):
+            self.chunk_launches += 1
+            info.next_pos += chunk_len
+            if not final:
+                return False
+
+            tok.copy_to_host_async()
+            self._unread.append(
+                _Launch(tok, ((slot, int(self._tenant[slot])),), first=True)
+            )
+            self._prefilling.remove(slot)
+            self._positions[slot] = plen
+            self._temps[slot] = info.temp_enc
+            self._top_ks[slot] = info.top_k_enc
+            self._top_ps[slot] = info.top_p_enc
+            # The first token is one of the budget: a request for one token
+            # is never a row of a tick.
+            self._budget[slot] = info.max_new_tokens - 1
+            self._active[slot] = self._budget[slot] > 0
+            if self.prefix_cache is not None:
+                full = plen // self.block_size
+                if full:
+                    self.prefix_cache.insert(
+                        [int(t) for t in info.prompt[: full * self.block_size]],
+                        info.block_ids[:full],
+                    )
+            return True
 
     def prefill_step(self, slot: int, dispatched=None) -> TickEvent | None:
         """Run ONE prefill chunk for ``slot``: :meth:`launch_chunk`, and
@@ -1603,63 +1655,70 @@ class PagedEngine:
         if not self._active.any():
             return False
         with Phase("serve/tick_dispatch", self.clock) as dispatch:
-            live = np.flatnonzero(self._active)
-            window = self.config.sliding_window
-            if self.grouped:
-                for slot in live:
-                    self._advance_window(
-                        int(slot), int(self._positions[slot]) - window + 1
+            with self._parts["tick", "prepare"].at(self.clock):
+                live = np.flatnonzero(self._active)
+                window = self.config.sliding_window
+                if self.grouped:
+                    for slot in live:
+                        self._advance_window(
+                            int(slot), int(self._positions[slot]) - window + 1
+                        )
+                # One query a live slot: pairs and KV positions are alike.
+                seen = self._positions[live].astype(np.int64) + 1
+                keys_live = int(seen.sum())
+                keys_read = (
+                    self._attn_sublayers - self._window_layers
+                ) * keys_live
+                if self.grouped:
+                    keys_read += self._window_layers * int(
+                        np.minimum(seen, window).sum()
                     )
-            # One query a live slot: pairs and KV positions are alike.
-            seen = self._positions[live].astype(np.int64) + 1
-            keys_live = int(seen.sum())
-            keys_read = (
-                self._attn_sublayers - self._window_layers
-            ) * keys_live
-            if self.grouped:
-                keys_read += self._window_layers * int(
-                    np.minimum(seen, window).sum()
+                self.attn_pairs += keys_read
+                self.attn_kv_positions += keys_read
+                self.tick_live_keys += keys_live
+                self.tick_table_keys += self._tables.size * self.block_size
+                ssm_state_rows = self._ssm_layers * len(live)
+                self.ssm_tick_state_rows += ssm_state_rows
+                asked = filters_asked(
+                    self._active, self._temps, self._top_ks, self._top_ps
                 )
-            self.attn_pairs += keys_read
-            self.attn_kv_positions += keys_read
-            self.tick_live_keys += keys_live
-            self.tick_table_keys += self._tables.size * self.block_size
-            ssm_state_rows = self._ssm_layers * len(live)
-            self.ssm_tick_state_rows += ssm_state_rows
-            asked = filters_asked(
-                self._active, self._temps, self._top_ks, self._top_ps
-            )
-            self.sample_topk_ticks += asked[0]
-            self.sample_topp_ticks += asked[1]
-            tokens, positions, keys = self._carry
-            # The host's arrays go in as copies: this launch may still be
-            # waiting when an admission or a release next rewrites them.
-            tokens, positions, keys, _, moe = self._in_place(
-                "tick", self._tick_jit,
-                self._params, self._lm_head, self._pool, self._moe_pending,
-                self._table_rows(), tokens, positions, self._active.copy(),
-                keys, self._temps.copy(), self._top_ks.copy(),
-                self._top_ps.copy(), pool_at=-2,
-            )
-            self._carry = (tokens, positions, keys)
-            tokens.copy_to_host_async()
-            if moe is not None:
-                # They come to the host with the tokens.
-                *moe, self._moe_pending = moe
-                for counts in moe:
-                    counts.copy_to_host_async()
-            self.ticks_overlapped += any(
-                not unread.first for unread in self._unread
-            )
-            self._unread.append(_Launch(
-                tokens,
-                tuple(zip(live.tolist(), self._tenant[live].tolist())),
-                moe, ssm_state_rows,
-            ))
-            self.ticks += 1
-            self._positions[live] += 1
-            self._budget[live] -= 1
-            self._active[live[self._budget[live] <= 0]] = False
+                self.sample_topk_ticks += asked[0]
+                self.sample_topp_ticks += asked[1]
+                tokens, positions, keys = self._carry
+                # The host's arrays go in as copies: this launch may still
+                # be waiting when an admission or a release next rewrites
+                # them.
+                args = (
+                    self._params, self._lm_head, self._pool,
+                    self._moe_pending, self._table_rows(), tokens, positions,
+                    self._active.copy(), keys, self._temps.copy(),
+                    self._top_ks.copy(), self._top_ps.copy(),
+                )
+            with self._parts["tick", "call"].at(self.clock):
+                tokens, positions, keys, _, moe = self._in_place(
+                    "tick", self._tick_jit, *args, pool_at=-2
+                )
+                del args  # the copies go with the call, as they did
+            with self._parts["tick", "after"].at(self.clock):
+                self._carry = (tokens, positions, keys)
+                tokens.copy_to_host_async()
+                if moe is not None:
+                    # They come to the host with the tokens.
+                    *moe, self._moe_pending = moe
+                    for counts in moe:
+                        counts.copy_to_host_async()
+                self.ticks_overlapped += any(
+                    not unread.first for unread in self._unread
+                )
+                self._unread.append(_Launch(
+                    tokens,
+                    tuple(zip(live.tolist(), self._tenant[live].tolist())),
+                    moe, ssm_state_rows,
+                ))
+                self.ticks += 1
+                self._positions[live] += 1
+                self._budget[live] -= 1
+                self._active[live[self._budget[live] <= 0]] = False
         self.last_tick_s = (dispatch.dur_s, 0.0, 0.0)
         return True
 

@@ -146,6 +146,11 @@ class ServingMetrics:
         #: a period closes): is a slow replica waiting on the device
         #: (``wait``) or on its own host (everything else)?
         self.worker_phase_seconds = dict.fromkeys(WORKER_PHASES, 0.0)
+        #: The worker thread's CPU seconds, and the seconds of its periods
+        #: outside ``wait`` and ``idle`` it was off the CPU: the sums of the
+        #: ``tick`` record's ``cpu_s`` and ``host_offcpu_s``.
+        self.worker_cpu_seconds = 0.0
+        self.worker_offcpu_seconds = 0.0
         #: KV migration traffic (ISSUE 15): sessions and payload bytes
         #: that LEFT this replica (prefill-role exports + drain
         #: evacuations) and that ARRIVED (grafted imports).
@@ -210,12 +215,17 @@ class ServingMetrics:
         with self._lock:
             self.decode_seconds += max(float(seconds), 0.0)
 
-    def on_worker_period(self, seconds: dict) -> None:
+    def on_worker_period(
+        self, seconds: dict, cpu_s: float, offcpu_s: float
+    ) -> None:
         """Account one closed tick period: ``{phase: seconds}`` over
-        :data:`WORKER_PHASES`."""
+        :data:`WORKER_PHASES`, the worker thread's CPU seconds and its
+        off-CPU seconds outside ``wait`` and ``idle``."""
         with self._lock:
             for phase, value in seconds.items():
                 self.worker_phase_seconds[phase] += value
+            self.worker_cpu_seconds += cpu_s
+            self.worker_offcpu_seconds += offcpu_s
 
     def on_migration(self, direction: str, nbytes: int) -> None:
         """Account one KV-slot migration: ``direction`` is ``"out"``
@@ -290,6 +300,8 @@ class ServingMetrics:
                     phase: round(value, 6)
                     for phase, value in self.worker_phase_seconds.items()
                 },
+                "worker_cpu_seconds": round(self.worker_cpu_seconds, 6),
+                "worker_offcpu_seconds": round(self.worker_offcpu_seconds, 6),
                 "migrations_out": self.migrations_out,
                 "migrations_in": self.migrations_in,
                 "migration_bytes_out": self.migration_bytes_out,
@@ -359,6 +371,8 @@ def render_prometheus(
         decode_tokens = metrics.decode_tokens
         decode_seconds = metrics.decode_seconds
         worker_seconds = dict(metrics.worker_phase_seconds)
+        worker_cpu = metrics.worker_cpu_seconds
+        worker_offcpu = metrics.worker_offcpu_seconds
         migrations = (
             metrics.migrations_out, metrics.migrations_in,
             metrics.migration_bytes_out, metrics.migration_bytes_in,
@@ -437,6 +451,15 @@ def render_prometheus(
          "other): wait is blocked on the device, the rest is host work.",
          [({"phase": phase}, round(value, 6))
           for phase, value in worker_seconds.items()])
+    emit("worker_cpu_seconds_total", "counter",
+         "CPU seconds of the serving worker's thread.",
+         [({}, round(worker_cpu, 6))])
+    emit("worker_offcpu_seconds_total", "counter",
+         "Seconds the worker thread was off the CPU where it had work to do "
+         "- waiting for the interpreter lock, the host's scheduler or a "
+         "sleeping runtime call; not device time: its tick periods "
+         "outside wait and idle, less its CPU seconds.",
+         [({}, round(worker_offcpu, 6))])
 
     # KV migration traffic (ISSUE 15): how many sessions left/arrived as
     # KV payloads, and the bytes moved — the disaggregated fleet's

@@ -52,7 +52,9 @@ from bpe_transformer_tpu.telemetry.alerts import (
 )
 from bpe_transformer_tpu.telemetry.flightrecorder import FlightRecorder
 from bpe_transformer_tpu.telemetry.resources import (
+    gc_pauses,
     install_compile_counter,
+    install_gc_counter,
     sample_resources,
 )
 from bpe_transformer_tpu.telemetry.spans import Phase
@@ -250,8 +252,10 @@ class ServingEngine:
     ):
         # Count XLA compiles (the engine's bucketed prefills included) into
         # the process-wide telemetry.resources counter before the first
-        # program builds.
+        # program builds; and the collector's pauses, which stop the worker
+        # whichever thread they fall to.
         install_compile_counter()
+        install_gc_counter()
         if role not in ("prefill", "decode", "both"):
             raise ValueError(
                 f'role={role!r} must be "prefill", "decode", or "both"'
@@ -1031,6 +1035,9 @@ class ServingEngine:
             "alerts_firing": len(self._alerts.active()),
             **self.metrics.snapshot(),
         }
+        # The interpreter's collector, process-wide: every thread stands
+        # still for a collection.
+        stats.update(gc_pauses())
         if self.paged:
             stats.update(self.engine.gauges())
             stats["block_size"] = self.engine.block_size
@@ -1113,6 +1120,13 @@ class ServingEngine:
                 phase: round(seconds, 6)
                 for phase, seconds in self.metrics.worker_phase_seconds.items()
             },
+            # The worker thread's CPU seconds, and what of its own phases
+            # it was off the CPU: the interpreter lock, the host's scheduler
+            # or a sleeping runtime call - not device time.
+            "worker_cpu_seconds": round(self.metrics.worker_cpu_seconds, 6),
+            "worker_offcpu_seconds": round(
+                self.metrics.worker_offcpu_seconds, 6
+            ),
             "resources": resources,
             "last_errors": self.metrics.last_errors(),
         }
@@ -1405,14 +1419,16 @@ class ServingEngine:
         writes no span record; its seconds go into the ``tick`` record."""
         return Phase(f"serve/{name}", self._clock)
 
-    def _open_period(self, t: float) -> dict:
+    def _open_period(self, t: float, cpu: float = 0.0) -> dict:
         """A tick period runs from the end of one decode tick to the end
         of the next one, so the periods tile the worker's time.  The phases
         around the tick accumulate here as they happen; ``deliver_s`` is the
-        publishing of the tick before (:meth:`_publish`)."""
+        publishing of the tick before (:meth:`_publish`).  ``cpu`` is what
+        the worker thread's CPU clock reads now: 0 at the thread's start."""
         return {
             "t": t, "admit_s": 0.0, "prefill_s": 0.0, "chunks": 0,
             "prefill_tokens": 0, "idle_s": 0.0, "deliver_s": 0.0,
+            "cpu_before": cpu, "gc_before": gc_pauses()["gc_pause_s"],
             "tokens_before": self.engine.tokens_emitted,
             "ssm_chunk_before": self._ssm_chunk_counts(),
             "carry_before": self._carry_counts(),
@@ -1442,7 +1458,9 @@ class ServingEngine:
         ``ServingMetrics`` and the flight recorder's coalesced tick entry
         all carry these same clock pairs."""
         end = self._clock()
-        period, self._period = self._period, self._open_period(end)
+        period, self._period = (
+            self._period, self._open_period(end, time.thread_time())
+        )
         dispatch_s, wait_s, emit_s = tick
         seconds = {
             "admit": period["admit_s"], "prefill": period["prefill_s"],
@@ -1453,7 +1471,19 @@ class ServingEngine:
         # Kept explicit so nothing hides: the engine record, the request
         # spans' emission, a finished prefill's hand-over, loop overhead.
         seconds["other"] = dur_s - sum(seconds.values())
-        self.metrics.on_worker_period(seconds)
+        # The worker thread's CPU seconds over the period - ONE read of its
+        # CPU clock a period, here: on the chip machines a read is a system
+        # call of 6 us that steps by 10 ms, and two more of them around the
+        # tick's call cost 1-3% of a 10 ms period - and what of the period
+        # it was neither on the CPU nor where being off it is the purpose
+        # (blocked on the device, waiting for work): a wait for the
+        # interpreter lock, the host's scheduler, a runtime call asleep with
+        # the lock let go.  Time the worker wanted and did not get, less the
+        # little CPU it used while it waited; never clamped: where the clock
+        # steps, one period's reading says nothing and sums do.
+        cpu_s = self._period["cpu_before"] - period["cpu_before"]
+        offcpu_s = dur_s - wait_s - period["idle_s"] - cpu_s
+        self.metrics.on_worker_period(seconds, cpu_s, offcpu_s)
         # Tick summary, coalesced: consecutive ticks merge into one ring
         # entry (count + refreshed fields) so steady-state decode chatter
         # cannot evict the rare decision events around it.
@@ -1481,6 +1511,13 @@ class ServingEngine:
                 "t": round(period["t"] - self._t0, 6),
                 "dur_s": round(dur_s, 6),
                 **{f"{k}_s": round(v, 6) for k, v in seconds.items()},
+                "cpu_s": round(cpu_s, 6),
+                "host_offcpu_s": round(offcpu_s, 6),
+                # The collector's seconds in the period, whichever thread
+                # it ran on.
+                "gc_s": round(
+                    self._period["gc_before"] - period["gc_before"], 6
+                ),
                 "chunks": period["chunks"],
                 "prefill_tokens": period["prefill_tokens"],
                 # Tokens the engine emitted in the period: the tick's, and

@@ -52,6 +52,7 @@ from bpe_transformer_tpu.telemetry.resources import (
     compile_cache_hits,
     compile_events,
     install_compile_counter,
+    install_gc_counter,
     record_compile_events,
     sample_resources,
     tree_bytes_per_device,
@@ -107,6 +108,7 @@ __all__ = [
     "group_norms",
     "health_metrics",
     "install_compile_counter",
+    "install_gc_counter",
     "nonfinite_count",
     "nonfinite_fields",
     "profile_trace",

@@ -22,6 +22,10 @@ telemetry stream:
   engine's bucketed prefills) plus :func:`record_compile_events` for code
   that compiles outside jax's event stream.  A counter that keeps climbing
   after warmup is the recompile-storm signature.
+- **Collector pauses** — a process-wide count of the interpreter's garbage
+  collections and the seconds they took (:func:`install_gc_counter`, one
+  ``gc.callbacks`` hook): a collection stops every Python thread of the
+  process, whichever thread set it off.
 
 Everything here is **sync-free** (no ``device_get``, no blocking on async
 dispatch) so sampling can ride the existing once-per-``log_every`` metric
@@ -32,6 +36,7 @@ import from the jax-free report/monitor tools.
 
 from __future__ import annotations
 
+import gc
 import sys
 import threading
 import time
@@ -58,6 +63,51 @@ _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 #: process whose hit counter climbs while wall compile time stays flat is
 #: warm-starting as designed.
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+#: Process-wide collector accounting (:func:`install_gc_counter`): seconds
+#: inside collections, their count over all generations, and the oldest
+#: generation's apart.  Written by the one thread that collects (a
+#: collection holds the interpreter lock from ``start`` to ``stop``).
+_gc_pause_s = 0.0
+_gc_collections = 0
+_gc_gen2_collections = 0
+_gc_started = 0.0
+_gc_installed = False
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_started, _gc_pause_s, _gc_collections, _gc_gen2_collections
+    if phase == "start":
+        _gc_started = time.perf_counter()
+    elif _gc_started:
+        _gc_pause_s += time.perf_counter() - _gc_started
+        _gc_collections += 1
+        _gc_gen2_collections += info.get("generation") == 2
+        _gc_started = 0.0
+
+
+def install_gc_counter() -> None:
+    """Hook the interpreter's collector (``gc.callbacks``) into
+    :func:`gc_pauses`.  Idempotent; between collections it costs nothing.
+    Collections before installation are not counted."""
+    global _gc_installed
+    with _compile_lock:
+        if not _gc_installed:
+            gc.callbacks.append(_on_gc)
+            _gc_installed = True
+
+
+def gc_pauses() -> dict:
+    """``{"gc_pause_s", "gc_collections", "gc_gen2_collections"}`` of this
+    process so far: seconds inside garbage collections (every Python thread
+    stands still for them), how many there were, and how many of the oldest
+    generation (the long ones)."""
+    return {
+        "gc_pause_s": _gc_pause_s,
+        "gc_collections": _gc_collections,
+        "gc_gen2_collections": _gc_gen2_collections,
+    }
 
 
 def record_compile_events(n: int = 1, duration_s: float = 0.0) -> int:
@@ -256,6 +306,9 @@ def sample_resources(**extra) -> dict:
         # while compile_time_s stays flat on a warm --compile-cache start.
         "compile_cache_hits": compile_cache_hits(),
     }
+    # The collector's pauses (not schema-required; 0 until
+    # install_gc_counter has run).
+    record.update(gc_pauses())
     mem = device_memory_stats()
     record["hbm_bytes_in_use"] = mem["bytes_in_use"] if mem else None
     record["hbm_peak_bytes_in_use"] = mem["peak_bytes_in_use"] if mem else None

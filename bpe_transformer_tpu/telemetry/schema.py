@@ -61,7 +61,21 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # ``emit_s`` (arrays to events, of the launches read), ``deliver_s``
     # (tokens to their streams and the finished requests: behind the next
     # launch either way), ``idle_s`` (waiting for work) and ``other_s`` =
-    # ``dur_s`` minus the rest, kept explicit.  ``batch`` is the tokens the
+    # ``dur_s`` minus the rest, kept explicit.  Optional (ISSUE 38), from
+    # a second clock, the worker thread's CPU clock (``time.thread_time``;
+    # a read is a system call, so it is read once a period): ``cpu_s``, the
+    # thread's CPU seconds over the period (beside ``dur_s``), and
+    # ``host_offcpu_s`` = ``dur_s - wait_s - idle_s - cpu_s``, what of the
+    # period outside the two phases whose purpose is to be off the CPU the
+    # worker was off it - waiting for the interpreter lock, descheduled by
+    # the host, or asleep in a runtime call that let go of the lock: time
+    # the worker wanted and did not get, not device time (less the little
+    # CPU it used while it waited).  Never clamped at 0: a kernel that
+    # counts CPU time by its scheduler's tick (the chip machines': 10 ms)
+    # hands out multiples of it, one record then reads a tick too much or
+    # too little, and only sums and means over many records say anything.
+    # ``gc_s``: the seconds of the period the interpreter's collector
+    # stopped every thread, whichever thread ran it.  ``batch`` is the tokens the
     # engine emitted in the period (those of the launches it read);
     # ``queue_depth`` the scheduler's at the period's end.  Optional
     # ``overlapped``: 1 where the period's launch was queued while the one
@@ -95,6 +109,10 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # holding its share.  ``hbm_peak_bytes_in_use`` is the allocator's
     # high-water mark, which on the v5e runtime does NOT include a
     # program's temporaries (PERF.md §6, PR 22) — a floor, not the peak.
+    # Optional ``gc_pause_s`` / ``gc_collections`` / ``gc_gen2_collections``
+    # (ISSUE 38): the process's seconds inside garbage collections so far,
+    # their count over all generations and the oldest generation's apart
+    # (0 until ``install_gc_counter`` has hooked the collector).
     "resources": {
         "kind", "time_unix", "host_rss_bytes", "live_buffer_bytes",
         "compile_events", "hbm_bytes_in_use", "hbm_peak_bytes_in_use",
@@ -168,7 +186,15 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # ``ssm_chunk_tokens`` / ``ssm_chunk_rows`` (real and bucket rows x
     # state-space layers through the chunks' scans), ``ssm_state_resets``
     # (admissions from a zero state) and the gauge ``ssm_state_bytes``
-    # (``kv_pool_bytes`` stays K and V alone).
+    # (``kv_pool_bytes`` stays K and V alone); and the two dispatch phases
+    # in parts (ISSUE 38; ``paged_engine.LAUNCH_PARTS``), clock seconds
+    # summed over every launch: ``launch_tick_{prepare,call,after}_s`` of
+    # the ``ticks`` launches - host arithmetic and the argument copies, the
+    # jitted call until it returns (argument transfers and the enqueue),
+    # the book-keeping behind it - and
+    # ``launch_chunk_{key,prepare,call,after}_s`` of the ``chunk_launches``
+    # chunks - the request's PRNG key, the padded row and the table row,
+    # the jitted call, the book-keeping and a final chunk's radix insert.
     "kvpool": {
         "kind", "t", "blocks_total", "blocks_free", "blocks_shared",
         "prefix_hits", "prefix_misses",

@@ -44,22 +44,26 @@ class Phase:
     It emits no record: the caller decides what the seconds feed (a span
     record, a ``tick`` record's field, a counter).  With no profiler session
     the annotation costs one ``TraceMe`` check; in a process without jax it
-    is not made at all.  Enter and exit on one thread.
+    is not made at all.  Enter and exit on one thread; one that has exited
+    may be entered again.
     """
 
-    __slots__ = ("start", "dur_s", "_clock", "_annotation")
+    __slots__ = ("start", "dur_s", "_name", "_clock", "_annotation")
 
     def __init__(self, name: str, clock=time.perf_counter):
+        self._name = name
         self._clock = clock
         self.dur_s = 0.0
-        jax = sys.modules.get("jax")
-        self._annotation = (
-            jax.profiler.TraceAnnotation(name) if jax is not None else None
-        )
 
     def __enter__(self) -> "Phase":
-        if self._annotation is not None:
-            self._annotation.__enter__()
+        # A fresh annotation each time: one that has ended does not start
+        # again in a profiler's session.
+        jax = sys.modules.get("jax")
+        self._annotation = annotation = (
+            jax.profiler.TraceAnnotation(self._name) if jax is not None else None
+        )
+        if annotation is not None:
+            annotation.__enter__()
         self.start = self._clock()
         return self
 

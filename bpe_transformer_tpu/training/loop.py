@@ -42,6 +42,7 @@ from bpe_transformer_tpu.telemetry import (
     flatten_dynamics,
     flatten_health,
     install_compile_counter,
+    install_gc_counter,
     nonfinite_fields,
     run_manifest,
     sample_resources,
@@ -222,6 +223,7 @@ def train(
     # Arm the process-wide compile counter before the first trace so every
     # jit cache miss of this run lands in the kind="resources" records.
     install_compile_counter()
+    install_gc_counter()
 
     if loop.health_stats and loop.parallel in ("sp", "pp"):
         raise ValueError(
@@ -1015,76 +1017,79 @@ def train(
             if iteration % loop.log_every == 0 or is_last:
                 with telemetry.phase("train/sync"):
                     fetched = jax.device_get(metrics)  # the device sync point
-                dyn_flat = None
-                if dynamics:
-                    # Already on host — the dynamics pytree rode the fetch
-                    # above; flattening costs no device round-trip.
-                    dyn_flat = flatten_dynamics(fetched["dynamics"])
-                last_loss = float(fetched["loss"])
-                rates = timer.snapshot()
-                real_steps = iteration - prev_sync_iteration - excluded_steps
-                step_wall_s = rates["window_seconds"] / max(real_steps, 1)
-                prev_sync_iteration = iteration
-                excluded_steps = 0
-                record = {
-                    "step": iteration,
-                    "loss": last_loss,
-                    "lr": float(fetched["lr"]),
-                    "grad_norm": float(fetched["grad_norm"]),
-                    "tokens_per_sec": rates["tokens_per_sec"],
-                    "tokens_per_sec_per_chip": rates["tokens_per_sec_per_chip"],
-                    "step_wall_s": step_wall_s,
-                    "window_seconds": rates["window_seconds"],
-                }
-                if "mfu" in rates:
-                    record["mfu"] = rates["mfu"]
-                if loop.health_stats:
-                    record.update(flatten_health(fetched["health"]))
-                if dyn_flat and "first_nonfinite" in dyn_flat:
-                    # Localization rides the step record so the watchdog's
-                    # nonfinite event (and NonFiniteError message) names
-                    # the offending tensor path, not just "loss is NaN".
-                    record["nonfinite_path"] = dyn_flat["first_nonfinite"]
-                history.append(record)
-                # The decision ring's heartbeat: values already on the host
-                # from the fetch above (zero extra syncs — the fetch-count
-                # test pins this), coalesced so steady-state logging holds
-                # ONE ring slot and a preemption/NaN dump still shows the
-                # last healthy step alongside the failure events.
-                recorder.record(
-                    "step",
-                    coalesce=True,
-                    step=iteration,
-                    loss=last_loss,
-                    step_wall_s=round(step_wall_s, 6),
-                )
-                # Through the narrator, not sinks.log directly: emit() holds
-                # the telemetry lock (the watchdog thread writes hang events
-                # through the same JSONL handle) and counts the record for
-                # the footer's record_counts.
-                telemetry.emit(record)
-                if dyn_flat is not None and (
-                    iteration % loop.dynamics_every == 0 or is_last
-                ):
-                    telemetry.emit(dynamics_record(iteration, dyn_flat))
-                # Resource accounting rides the same once-per-log_every
-                # boundary: sample_resources is sync-free (RSS, live-buffer
-                # metadata, device memory_stats, compile counter), so HBM
-                # headroom and recompile trends cost zero extra host syncs.
-                # params/opt-state bytes are PER-CHIP (shard-shape metadata)
-                # — the number that shows the ZeRO-1 memory win directly.
-                telemetry.emit(
-                    sample_resources(
+                # Host work between the sync and the next dispatch: the
+                # device's idle gap under it has this name in a trace.
+                with telemetry.phase("train/log"):
+                    dyn_flat = None
+                    if dynamics:
+                        # Already on host — the dynamics pytree rode the fetch
+                        # above; flattening costs no device round-trip.
+                        dyn_flat = flatten_dynamics(fetched["dynamics"])
+                    last_loss = float(fetched["loss"])
+                    rates = timer.snapshot()
+                    real_steps = iteration - prev_sync_iteration - excluded_steps
+                    step_wall_s = rates["window_seconds"] / max(real_steps, 1)
+                    prev_sync_iteration = iteration
+                    excluded_steps = 0
+                    record = {
+                        "step": iteration,
+                        "loss": last_loss,
+                        "lr": float(fetched["lr"]),
+                        "grad_norm": float(fetched["grad_norm"]),
+                        "tokens_per_sec": rates["tokens_per_sec"],
+                        "tokens_per_sec_per_chip": rates["tokens_per_sec_per_chip"],
+                        "step_wall_s": step_wall_s,
+                        "window_seconds": rates["window_seconds"],
+                    }
+                    if "mfu" in rates:
+                        record["mfu"] = rates["mfu"]
+                    if loop.health_stats:
+                        record.update(flatten_health(fetched["health"]))
+                    if dyn_flat and "first_nonfinite" in dyn_flat:
+                        # Localization rides the step record so the watchdog's
+                        # nonfinite event (and NonFiniteError message) names
+                        # the offending tensor path, not just "loss is NaN".
+                        record["nonfinite_path"] = dyn_flat["first_nonfinite"]
+                    history.append(record)
+                    # The decision ring's heartbeat: values already on the host
+                    # from the fetch above (zero extra syncs — the fetch-count
+                    # test pins this), coalesced so steady-state logging holds
+                    # ONE ring slot and a preemption/NaN dump still shows the
+                    # last healthy step alongside the failure events.
+                    recorder.record(
+                        "step",
+                        coalesce=True,
                         step=iteration,
-                        params_bytes=tree_bytes_per_device(params),
-                        opt_state_bytes=tree_bytes_per_device(opt_state),
+                        loss=last_loss,
+                        step_wall_s=round(step_wall_s, 6),
                     )
-                )
-                log_fn(
-                    f"step {record['step']:>6d}  loss {record['loss']:.4f}  "
-                    f"lr {record['lr']:.2e}  gnorm {record['grad_norm']:.3f}  "
-                    f"tok/s {record['tokens_per_sec']:,.0f}"
-                )
+                    # Through the narrator, not sinks.log directly: emit() holds
+                    # the telemetry lock (the watchdog thread writes hang events
+                    # through the same JSONL handle) and counts the record for
+                    # the footer's record_counts.
+                    telemetry.emit(record)
+                    if dyn_flat is not None and (
+                        iteration % loop.dynamics_every == 0 or is_last
+                    ):
+                        telemetry.emit(dynamics_record(iteration, dyn_flat))
+                    # Resource accounting rides the same once-per-log_every
+                    # boundary: sample_resources is sync-free (RSS, live-buffer
+                    # metadata, device memory_stats, compile counter), so HBM
+                    # headroom and recompile trends cost zero extra host syncs.
+                    # params/opt-state bytes are PER-CHIP (shard-shape metadata)
+                    # — the number that shows the ZeRO-1 memory win directly.
+                    telemetry.emit(
+                        sample_resources(
+                            step=iteration,
+                            params_bytes=tree_bytes_per_device(params),
+                            opt_state_bytes=tree_bytes_per_device(opt_state),
+                        )
+                    )
+                    log_fn(
+                        f"step {record['step']:>6d}  loss {record['loss']:.4f}  "
+                        f"lr {record['lr']:.2e}  gnorm {record['grad_norm']:.3f}  "
+                        f"tok/s {record['tokens_per_sec']:,.0f}"
+                    )
                 if (
                     loop.attribution_every
                     and iteration % loop.attribution_every == 0

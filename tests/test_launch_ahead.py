@@ -15,7 +15,10 @@ import pytest
 
 from bpe_transformer_tpu.models.config import TS_TEST_CONFIG
 from bpe_transformer_tpu.models.transformer import init_params
-from bpe_transformer_tpu.serving.kvpool.paged_engine import PagedEngine
+from bpe_transformer_tpu.serving.kvpool.paged_engine import (
+    LAUNCH_PARTS,
+    PagedEngine,
+)
 from tests import test_cohere2moe as cohere
 from tests import test_granitehybrid as granite
 from tests import test_longcatflash as longcat
@@ -163,6 +166,52 @@ def test_one_launch_ahead_gives_every_request_the_same_tokens(kind):
         ) > 0
     if ahead.recurrent:
         assert gauges["ssm_state_resets"] == len(requests)
+
+
+def launch_seconds(engine, program: str) -> dict:
+    """``{part: seconds}`` of a program's launches so far."""
+    gauges = engine.gauges()
+    return {
+        part: gauges[f"launch_{program}_{part}_s"]
+        for part in LAUNCH_PARTS[program][1]
+    }
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_every_cache_kind_times_its_launches_in_the_same_parts(kind):
+    """Whatever the cache kind, a tick's dispatch is timed in three parts
+    and a chunk's in four under the same keys of `gauges()`; a tick's three
+    add up to its dispatch."""
+    engine = KINDS[kind]()
+    timed = {
+        f"launch_{program}_{part}_s"
+        for program, (_, parts) in LAUNCH_PARTS.items() for part in parts
+    }
+    gauges = engine.gauges()
+    assert {key for key in gauges if key.startswith("launch_")} == timed
+    assert gauges["chunk_launches"] == 0 and not any(gauges[k] for k in timed)
+
+    slot = engine.begin(
+        prompt_ids=list(range(40, 51)), max_new_tokens=5, temperature=0.0
+    )
+    while slot in engine.pending_prefills():  # 8 tokens, then 3
+        engine.launch_chunk(slot)
+    assert engine.gauges()["chunk_launches"] == 2
+    between = []
+    for _ in range(4):
+        was = launch_seconds(engine, "tick")
+        assert engine.launch()
+        now = launch_seconds(engine, "tick")
+        parts_s = sum(now[part] - was[part] for part in now)
+        dispatch_s = engine.last_tick_s[0]
+        assert parts_s <= dispatch_s
+        between.append(dispatch_s - parts_s - 0.05 * dispatch_s)
+    # Between the parts: two clock reads a part (the host may have had the
+    # thread off its core in one of the four).
+    assert sorted(between)[-2] <= 250e-6
+    engine.flush()
+    gauges = engine.gauges()
+    assert all(gauges[key] > 0 for key in timed)
 
 
 def test_tick_is_launch_then_collect():
