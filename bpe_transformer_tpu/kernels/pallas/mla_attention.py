@@ -19,13 +19,25 @@ What latent attention caches is one row a position and sublayer,
   reference's form (``chipbench/reference_longcatflash.py``) and nowhere in
   the program.
 
-On the TPU the tick's form is a Pallas kernel that walks each slot's live
-blocks through the block table straight out of the pool, as
-`decode_attention.paged_decode_attention` does for K/V heads (device events
-``mla_paged_attention.N``); elsewhere it is a gather and a masked softmax
-in XLA.  Many query rows of one sequence (:func:`xla_mla_chunk_attention`:
-a chunk that resumes after a cached prefix, a dense cache's prefill, the
-plain forward) run in XLA on every backend, a loop over key blocks whose
+On the TPU the tick's form is two Pallas kernels that copy blocks through
+the block table straight out of the pool, as
+`decode_attention.paged_decode_attention` does for K/V heads, under one
+softmax state.  The radix prefix cache puts the *same physical blocks* at
+the head of many slots' table rows (a system prompt: 8,192 of a slot's
+~9,300 keys in the benchmark's cell), so the first kernel
+(``mla_paged_attention_shared.N``) attends that chain **once** for all the
+slots that hold it - their heads' query rows side by side in tiles of
+`MLA_SHARED_TILE_ROWS`, whole MXU tiles, the keys read once a tile and not
+once a slot - and hands on the unnormalised state ``(m, l, acc)`` of every
+(slot, head); the second (``mla_paged_attention.N``) walks each slot's own
+blocks from there, one slot a grid step, and normalises.  Which blocks are
+shared is read from the tables a tick (:func:`shared_prefix`: no flag, no
+knob); with nothing shared the first kernel walks no group and the second
+is the whole walk.  Exact attention in another order (Hydragen, Juravsky et
+al. 2024; FlashInfer's cascade attention).  Elsewhere the tick's form is a
+gather and a masked softmax in XLA.  Many query rows of one sequence
+(:func:`xla_mla_chunk_attention`: a chunk that resumes after a cached
+prefix, a dense cache's prefill, the plain forward) run in XLA on every backend, a loop over key blocks whose
 trip count follows the last position, absorbed too: on the v5e 15.9 ms
 against 18.3-21.3 expanded for 1,024 rows after 8,192 positions, for all
 its 2.4 times the FLOPs - 64 heads' keys of 192 make small matrix
@@ -49,6 +61,25 @@ from bpe_transformer_tpu.kernels.pallas.decode_attention import NEG_INF
 #: On the v5e, 64 slots of ~9,300 keys: 2.82 ms a call at 256, 2.41 at 512,
 #: 2.21 at 1,024 (PERF.md section 6, PR 33).
 MLA_GROUP_KEYS = 1024
+#: Query rows a step of the shared pass holds against a group of keys: the
+#: heads of 16 slots at 64 heads.  The keys are the MXU's stationary operand
+#: and the query rows stream past them, so a step's rate grows with its
+#: rows.  On the v5e, 64 slots on a chain of 8,192 keys: the pass 0.938 ms
+#: at 256 rows, 0.705-0.721 at 512, 0.616-0.637 at 1,024, 0.705-0.711 at
+#: 2,048 (its FLOPs need 0.37; PERF.md section 6, PR 39).
+MLA_SHARED_TILE_ROWS = 1024
+#: A tile's scores and probabilities are 10 MB at 1,024 x 1,024, beside
+#: its queries, its state and two groups of keys: over the default 16 MiB.
+MLA_SHARED_VMEM_BYTES = 64 * 1024 * 1024
+#: Fewest slots on one chain for which the shared pass is taken: a tile's
+#: step costs the same whatever the number of member rows in it (0.159 ms
+#: for a chain of 8,192 keys) and a member saves ~0.029 ms of its own walk.
+#: On the v5e, 64 slots of ~9,300 keys, both kernels together: 4 members
+#: 2.197 ms against 2.155 with the pass off, 6 members 2.143, 8 members
+#: 2.082, 16 members 1.850, all 64 0.914-0.941 (PERF.md section 6, PR 39).
+MLA_SHARED_MIN_SLOTS = 6
+#: Lanes behind a softmax state's accumulator for its maximum and its sum.
+_STATE_LANES = 128
 #: Keys a step of the chunk's loop scores and folds into its softmax.
 MLA_CHUNK_KEY_BLOCK = 1024
 
@@ -73,43 +104,261 @@ def xla_mla_rows_attention(q_abs, rows, visible, *, rank: int, scale: float):
     ).astype(q_abs.dtype)
 
 
-# --------------------------------------------------------- absorbed, kernel
+# ------------------------------------------------ the chain the slots share
+
+
+def shared_prefix(tables, key_counts, block_size: int, xp=jnp):
+    """Which leading blocks of their table rows a tick's slots share:
+    ``(shared_blocks (slots,), reference slot)``.
+
+    The reference is the first slot of the largest family of slots that
+    start with the same block (not "the first live slot": one slot with a
+    private copy of the prefix would defeat that).  ``shared_blocks[s]`` is
+    the leading run of slot ``s``'s row that equals the reference's, held
+    to the whole blocks its ``key_counts[s]`` reaches (so every position of
+    the run is one the slot attends; 0 for an idle slot and a slot
+    mid-prefill, whose count is 0) and to the chain's length, the longest
+    run that at least two slots hold; all zeros where fewer than
+    `MLA_SHARED_MIN_SLOTS` slots share.  One rule for the program
+    (``xp=jnp``, on the tick's own arguments) and for the host's counters
+    (``xp=np``, on the host's tables and positions)."""
+    slots, blocks = tables.shape
+    counts = xp.broadcast_to(xp.reshape(key_counts, (-1,)), (slots,))
+    whole = counts // block_size
+    sees = whole > 0
+    first = tables[:, 0]
+    family = (first[:, None] == first[None, :]) & sees[:, None] & sees[None, :]
+    reference = xp.argmax(family.sum(axis=1))
+    differs = tables != tables[reference][None, :]
+    run = xp.where(differs.any(axis=1), xp.argmax(differs, axis=1), blocks)
+    run = xp.minimum(run, whole)
+    ids = xp.arange(slots)
+    chain = xp.max(xp.where(ids == xp.argmax(run), 0, run))
+    shared = xp.minimum(run, chain)
+    enough = (shared > 0).sum() >= MLA_SHARED_MIN_SLOTS
+    return xp.where(enough, shared, 0).astype(xp.int32), reference
+
+
+def shared_split(tables, key_counts, block_size: int) -> dict:
+    """What both kernels need of :func:`shared_prefix` (a tick's sublayers
+    each ask with the same tables and counts, and XLA keeps one of the equal
+    computations: the tick compiled for the v5e holds the same instructions
+    as one that is handed the split, 5,694 at 4 sublayers; PR 39):
+    ``shared`` (slots,) blocks and ``chain`` (blocks a slot,) the
+    reference's row; ``order`` (slots,) the slots in descending order of
+    ``shared`` (members next to each other, the longest first, so that a
+    tile of query rows is full and its walk ends with its first slot's);
+    ``state_at`` (slots,) the place in that order whose state the own pass
+    fetches for a slot - its own where it shares, else (read by no one)
+    the one the slot before it fetched, so nothing is fetched; ``own``
+    (slots,) the keys a slot attends past its run and
+    ``live_from`` (slots + 1,), the first slot from s on that holds one,
+    ``slots`` where none does."""
+    tables = jnp.asarray(tables, jnp.int32)
+    slots = tables.shape[0]
+    counts = jnp.broadcast_to(
+        jnp.asarray(key_counts, jnp.int32).reshape(-1), (slots,)
+    )
+    shared, reference = shared_prefix(tables, counts, block_size)
+    ids = jnp.arange(slots, dtype=jnp.int32)
+    ahead = (shared[None, :] > shared[:, None]) | (
+        (shared[None, :] == shared[:, None]) & (ids[None, :] < ids[:, None])
+    )
+    place = ahead.sum(axis=1, dtype=jnp.int32)
+    sharer = jax.lax.cummax(jnp.where(shared > 0, ids, -1))
+    own = counts - shared * block_size
+    live_from = jnp.append(
+        jax.lax.cummin(jnp.where(own > 0, ids, slots), reverse=True),
+        jnp.int32(slots),
+    )
+    return {
+        "shared": shared, "chain": tables[reference],
+        "order": jnp.zeros_like(ids).at[place].set(ids),
+        "state_at": jnp.where(sharer >= 0, place[sharer], 0), "own": own,
+        "live_from": live_from,
+    }
+
+
+# --------------------------------------------------------- absorbed, kernels
+
+
+def _group_copies(pool_hbm, buf, sems, block_of, live, b, go, block_size):
+    """Start (``go``) or await the copies of ``live`` pool blocks, block
+    ``i`` of them ``block_of(i)``, into buffer ``b``."""
+
+    def one(i, carry):
+        block = block_of(i) if go else 0
+        rows = pl.ds(pl.multiple_of(i * block_size, block_size), block_size)
+        copy = pltpu.make_async_copy(
+            pool_hbm.at[block], buf.at[b, rows], sems.at[b]
+        )
+        copy.start() if go else copy.wait()
+        return carry
+
+    jax.lax.fori_loop(0, live, one, 0)
+
+
+def _fold_group(q, kv, visible, state, *, scale: float, rank: int):
+    """One group of keys into a running softmax: ``q`` (rows, width), ``kv``
+    (keys, width) whose first ``rank`` lanes are the values, ``visible``
+    (rows, keys) or None where every key is, ``state`` = ``(m, l, acc)``;
+    returns the new state."""
+    m_prev, l_prev, acc = state
+    s = jax.lax.dot_general(
+        q, kv, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale                                       # (rows, keys)
+    if visible is not None:
+        s = jnp.where(visible, s, NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc = acc * alpha + jax.lax.dot_general(
+        p.astype(kv.dtype), kv[:, :rank], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return m_new, l_new, acc
+
+
+def _mla_shared_kernel(
+    chain_ref, shared_ref, _walking_ref, q_ref, pool_hbm, state_ref, buf, sems,
+    turn, m_run, l_run, *, scale: float, block_size: int, group_blocks: int,
+    tile_slots: int, heads_pad: int, rank: int,
+):
+    """The shared pass: one tile of ``tile_slots`` slots' query rows a grid
+    step (slots in descending order of their shared blocks, ``shared_ref``,
+    so a tile walks as far as its first slot shares and a tile of
+    non-members walks nothing; ``_walking_ref`` is the block specs' alone),
+    inside it a loop over the chain's groups of
+    ``group_blocks`` blocks, copied through the reference's row
+    ``chain_ref`` into one of two buffers while the other is computed on; a
+    tile's last group starts the copies of the next tile's first.  A row
+    sees the keys below its own slot's run.  Leaves the unnormalised state
+    of the rows that share in ``state_ref``: the accumulator in its first
+    ``rank`` lanes, the running maximum and sum in the two lanes after
+    them (`_STATE_LANES` wide in all); what it leaves for a row that
+    shares nothing (in a tile that walked: keys all masked, each at a
+    probability of one; in one that did not: nothing written) is read by
+    no one."""
+    tile = pl.program_id(0)
+    group_keys = group_blocks * block_size
+    rows = tile_slots * heads_pad
+
+    def blocks_of(t):          # ``shared_ref`` ends with a tile of zeros
+        return shared_ref[t * tile_slots]
+
+    def copies(t, group, b, go):
+        first = group * group_blocks
+        live = jnp.minimum(blocks_of(t) - first, group_blocks)
+        _group_copies(
+            pool_hbm, buf, sems, lambda i: chain_ref[first + i], live, b, go,
+            block_size,
+        )
+
+    @pl.when(tile == 0)
+    def _open():
+        # Rows no copy has reached are multiplied by a probability of
+        # exactly zero: they must hold numbers.
+        buf[...] = jnp.zeros_like(buf)
+        turn[0] = 0
+
+        @pl.when(blocks_of(0) > 0)
+        def _():
+            copies(0, 0, 0, True)
+
+    groups = pl.cdiv(blocks_of(tile), group_blocks)
+
+    @pl.when(groups > 0)
+    def _walk():
+        q = q_ref[...]                              # (rows, width)
+        row_slot = (
+            jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // heads_pad
+        )
+        limit = jnp.zeros((rows, 1), jnp.int32)
+        for j in range(tile_slots):
+            limit = jnp.where(
+                row_slot == j, shared_ref[tile * tile_slots + j] * block_size,
+                limit,
+            )
+        # Below the run of the tile's last slot, its shortest, every row
+        # sees every key.
+        all_see = shared_ref[tile * tile_slots + tile_slots - 1] * block_size
+        acc_ref = state_ref.at[:, :rank]
+        fold = functools.partial(_fold_group, scale=scale, rank=rank)
+        m_run[...] = jnp.full_like(m_run, NEG_INF)
+        l_run[...] = jnp.zeros_like(l_run)
+        acc_ref[...] = jnp.zeros((rows, rank), jnp.float32)
+
+        def group_step(g, carry):
+            b = turn[0]
+            last = g + 1 == groups
+
+            @pl.when(
+                jnp.logical_or(jnp.logical_not(last), blocks_of(tile + 1) > 0)
+            )
+            def _():
+                copies(
+                    jnp.where(last, tile + 1, tile), jnp.where(last, 0, g + 1),
+                    1 - b, True,
+                )
+
+            copies(tile, g, b, False)
+            kv = buf[b]                             # (group_keys, width)
+            state = (m_run[...], l_run[...], acc_ref[...])
+
+            def masked():
+                cols = jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, group_keys), 1
+                ) + g * group_keys
+                return fold(q, kv, cols < limit, state)
+
+            m_run[...], l_run[...], acc_ref[...] = jax.lax.cond(
+                (g + 1) * group_keys <= all_see,
+                lambda: fold(q, kv, None, state), masked,
+            )
+            turn[0] = 1 - b
+            return carry
+
+        jax.lax.fori_loop(0, groups, group_step, 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _STATE_LANES), 1)
+        state_ref[:, rank:] = jnp.where(
+            lane == 0, m_run[...], jnp.where(lane == 1, l_run[...], 0.0)
+        )
 
 
 def _mla_paged_kernel(
-    tables_ref, counts_ref, live_from_ref, q_ref, pool_hbm, o_ref, buf, sems,
-    turn, *, scale: float, block_size: int, group_blocks: int, slots: int,
-    rank: int,
+    tables_ref, counts_ref, starts_ref, live_from_ref, state_at_ref, q_ref,
+    state_ref, pool_hbm, o_ref, buf, sems, turn, *, scale: float,
+    block_size: int, group_blocks: int, slots: int, rank: int,
 ):
-    """One slot a grid step; inside it a loop over the slot's live groups of
-    ``group_blocks`` pool blocks (trip count from the slot's key count).
-    The pool stays in HBM: each live block of a group is copied through the
-    block table into one of two VMEM buffers while the other buffer's group
-    is computed on, and a slot's last group starts the copies of the next
-    live slot's first.  A group is read once: ``(heads, width) x (keys,
-    width)`` gives every head's scores, and the same buffer's first
-    ``rank`` lanes are the values of ``(heads, keys) x (keys, rank)``."""
+    """The own pass: one slot a grid step; inside it a loop over the groups
+    of ``group_blocks`` pool blocks of the slot's own keys - ``counts_ref``
+    of them, from block ``starts_ref`` of its row on (trip count from the
+    count) - that starts at the state the shared pass left for the slot
+    (``state_ref``, as `_mla_shared_kernel` lays it out; the empty state
+    for a slot that shares nothing, ``starts_ref`` 0, whose block nobody
+    wrote) and normalises at its end.  The pool stays in HBM: each live
+    block of a group is copied through the block table into one of two VMEM
+    buffers while the other buffer's group is computed on, and a slot's
+    last group starts the copies of the next live slot's first.  A group is
+    read once: ``(heads, width) x (keys, width)`` gives every head's scores,
+    and the same buffer's first ``rank`` lanes are the values of ``(heads,
+    keys) x (keys, rank)``."""
     slot = pl.program_id(0)
     group_keys = group_blocks * block_size
 
     def copies(s, group, b, go):
         """Start (``go``) or await the copies of group ``group`` of slot
-        ``s`` into buffer ``b``: its live blocks and no others."""
-        first = group * group_blocks
+        ``s``'s own keys into buffer ``b``: its live blocks and no others."""
+        ahead = group * group_blocks
         live = jnp.minimum(
-            pl.cdiv(counts_ref[s], block_size) - first, group_blocks
+            pl.cdiv(counts_ref[s], block_size) - ahead, group_blocks
         )
-
-        def one(i, carry):
-            block = tables_ref[s, first + i] if go else 0
-            rows = pl.ds(pl.multiple_of(i * block_size, block_size), block_size)
-            copy = pltpu.make_async_copy(
-                pool_hbm.at[block], buf.at[b, rows], sems.at[b]
-            )
-            copy.start() if go else copy.wait()
-            return carry
-
-        jax.lax.fori_loop(0, live, one, 0)
+        first = starts_ref[s] + ahead
+        _group_copies(
+            pool_hbm, buf, sems, lambda i: tables_ref[s, first + i], live, b,
+            go, block_size,
+        )
 
     @pl.when(slot == 0)
     def _open():
@@ -125,10 +374,8 @@ def _mla_paged_kernel(
     count = counts_ref[slot]
     groups = pl.cdiv(count, group_keys)
     q = q_ref[0]                                    # (heads_pad, width)
-    heads_pad = q.shape[0]
 
-    def group_step(g, carry):
-        m_prev, l_prev, acc = carry
+    def group_step(g, state):
         b = turn[0]
         last = g + 1 == groups
         nxt_slot = jnp.where(last, live_from_ref[slot + 1], slot)
@@ -139,28 +386,20 @@ def _mla_paged_kernel(
 
         copies(slot, g, b, False)
         kv = buf[b]                                 # (group_keys, width)
-        s = jax.lax.dot_general(
-            q, kv, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                   # (heads_pad, group_keys)
-        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + g * group_keys
-        s = jnp.where(cols < count, s, NEG_INF)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p.astype(kv.dtype), kv[:, :rank], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        cols = jax.lax.broadcasted_iota(
+            jnp.int32, (q.shape[0], group_keys), 1
+        ) + g * group_keys
         turn[0] = 1 - b
-        return m_new, l_new, acc
+        return _fold_group(q, kv, cols < count, state, scale=scale, rank=rank)
 
+    shares = starts_ref[slot] > 0
+    state = state_ref[0]
     _, l, acc = jax.lax.fori_loop(
         0, groups, group_step,
         (
-            jnp.full((heads_pad, 1), NEG_INF, jnp.float32),
-            jnp.zeros((heads_pad, 1), jnp.float32),
-            jnp.zeros((heads_pad, rank), jnp.float32),
+            jnp.where(shares, state[:, rank:rank + 1], NEG_INF),
+            jnp.where(shares, state[:, rank + 1:rank + 2], 0.0),
+            jnp.where(shares, state[:, :rank], 0.0),
         ),
     )
     # A slot with no keys walks no group: zeros over the guard, finite.
@@ -171,24 +410,79 @@ def _mla_paged_kernel(
 def _mla_paged_impl(q_abs, pool, tables, key_counts, rank, scale, interpret):
     slots, num_heads, width = q_abs.shape
     _, block_size, _ = pool.shape
+    split = shared_split(tables, key_counts, block_size)
     nbs = tables.shape[1]
     group = max(1, min(MLA_GROUP_KEYS // block_size, nbs))
     # Whole sublane tiles at the rows' width (16 rows of bfloat16).
     heads_pad = pl.cdiv(num_heads, 16) * 16
     q_rows = jnp.pad(q_abs, ((0, 0), (0, heads_pad - num_heads), (0, 0)))
-    counts = jnp.broadcast_to(
-        jnp.asarray(key_counts, jnp.int32).reshape(-1), (slots,)
-    )
-    # live_from[s]: the first slot from s on that holds a key, ``slots``
-    # where none does (entry ``slots`` too).
-    index = jnp.where(counts > 0, jnp.arange(slots, dtype=jnp.int32), slots)
-    live_from = jnp.append(
-        jax.lax.cummin(index, reverse=True), jnp.int32(slots)
+    buffers = [
+        pltpu.VMEM((2, group * block_size, width), pool.dtype),
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.SMEM((1,), jnp.int32),               # the buffer in turn
+    ]
+
+    # The shared pass, over the slots in descending order of their runs.
+    tile_slots = max(1, min(MLA_SHARED_TILE_ROWS // heads_pad, slots))
+    tiles = pl.cdiv(slots, tile_slots)
+    places = tiles * tile_slots
+    tile_rows = tile_slots * heads_pad
+    q_placed = jnp.pad(
+        q_rows[split["order"]], ((0, places - slots), (0, 0), (0, 0))
+    ).reshape(places * heads_pad, width)
+    # A tile of zeros more: the last tile asks what the next one shares.
+    shared_placed = jnp.pad(
+        split["shared"][split["order"]], (0, places - slots + tile_slots)
     )
 
-    def at_slot(*block):
+    # A tile that walks nothing reads and writes nothing: the tiles past
+    # the last that walks (the order is descending) all take the block of
+    # the first of them, fetched once and written back once.
+    walking = jnp.minimum(
+        jnp.sum(shared_placed[: places : tile_slots] > 0, dtype=jnp.int32),
+        tiles - 1,
+    )
+
+    def at_tile(lanes):
         return pl.BlockSpec(
-            (1, *block), lambda s, *_: (s,) + (0,) * len(block),
+            (tile_rows, lanes),
+            lambda t, chain, shared, walking: (jnp.minimum(t, walking[0]), 0),
+            memory_space=pltpu.VMEM,
+        )
+
+    state = pl.pallas_call(
+        functools.partial(
+            _mla_shared_kernel, scale=scale, block_size=block_size,
+            group_blocks=group, tile_slots=tile_slots, heads_pad=heads_pad,
+            rank=rank,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(tiles,),
+            in_specs=[at_tile(width), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=at_tile(rank + _STATE_LANES),
+            scratch_shapes=buffers + [
+                pltpu.VMEM((tile_rows, 1), jnp.float32),   # running maximum
+                pltpu.VMEM((tile_rows, 1), jnp.float32),   # running sum
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(
+            (places * heads_pad, rank + _STATE_LANES), jnp.float32
+        ),
+        # The buffers and the turn are carried from tile to tile.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=MLA_SHARED_VMEM_BYTES,
+        ),
+        interpret=interpret,
+        name="mla_paged_attention_shared",
+    )(split["chain"], shared_placed, walking.reshape(1), q_placed, pool)
+
+    # The own pass: each slot's keys past its run.
+    def at_slot(*block, placed=False):
+        return pl.BlockSpec(
+            (1, *block),
+            lambda s, *refs: (refs[4][s] if placed else s,) + (0,) * len(block),
             memory_space=pltpu.VMEM,
         )
 
@@ -198,17 +492,15 @@ def _mla_paged_impl(q_abs, pool, tables, key_counts, rank, scale, interpret):
             group_blocks=group, slots=slots, rank=rank,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=5,
             grid=(slots,),
             in_specs=[
-                at_slot(heads_pad, width), pl.BlockSpec(memory_space=pl.ANY),
+                at_slot(heads_pad, width),
+                at_slot(heads_pad, rank + _STATE_LANES, placed=True),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=at_slot(heads_pad, rank),
-            scratch_shapes=[
-                pltpu.VMEM((2, group * block_size, width), pool.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SMEM((1,), jnp.int32),       # the buffer in turn
-            ],
+            scratch_shapes=buffers,
         ),
         out_shape=jax.ShapeDtypeStruct((slots, heads_pad, rank), jnp.float32),
         # The buffers and the turn are carried from slot to slot.
@@ -217,7 +509,11 @@ def _mla_paged_impl(q_abs, pool, tables, key_counts, rank, scale, interpret):
         ),
         interpret=interpret,
         name="mla_paged_attention",
-    )(jnp.asarray(tables, jnp.int32), counts, live_from, q_rows, pool)
+    )(
+        jnp.asarray(tables, jnp.int32), split["own"], split["shared"],
+        split["live_from"], split["state_at"], q_rows,
+        state.reshape(places, heads_pad, rank + _STATE_LANES), pool,
+    )
     return out[:, :num_heads].astype(q_abs.dtype)
 
 
@@ -246,7 +542,8 @@ def mla_paged_attention(
     interpret: bool | None = None,
 ) -> jax.Array:
     """One decode step of absorbed latent attention read straight out of
-    the latent block pool, and only the blocks the slots hold.
+    the latent block pool, and only the blocks the slots hold - a chain of
+    blocks that several slots share once for all of them.
 
     ``q_abs`` (slots, heads, latent width) are the absorbed queries,
     ``pool`` (num_blocks, block_size, width) one sublayer's latent rows,
@@ -255,9 +552,10 @@ def mla_paged_attention(
     score), ``tables`` (slots, blocks_per_slot) each slot's chain of block
     ids, ``key_counts`` (slots,) how many positions of its chain a slot
     attends to (``position + 1`` for the token just written, 0 for an idle
-    slot: it copies nothing and yields zeros).  Returns (slots, heads, rank): each head's softmax-weighted sum
-    of the rows' first ``rank`` values.  ``path`` forces ``"mla_paged"``
-    (parity tests: interpret mode off the TPU) or ``"xla"``."""
+    slot: it copies nothing and yields zeros).  Returns (slots, heads,
+    rank): each head's softmax-weighted sum of the rows' first ``rank``
+    values.  ``path`` forces ``"mla_paged"`` (parity tests: interpret mode
+    off the TPU) or ``"xla"``."""
     _, block_size, width = pool.shape
     if q_abs.shape[-1] > width or tables.shape[0] != q_abs.shape[0]:
         raise ValueError(

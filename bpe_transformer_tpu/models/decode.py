@@ -1093,8 +1093,11 @@ class LatentRows(_RoutingCounts):
     has (so the radix prefix cache, whose bookkeeping is block ids, shares
     whole frozen blocks of latent rows between slots), rows of
     ``latent_width`` values.  A tick's one row a slot attends in the
-    absorbed form straight out of the pool (`mla_paged_attention`: the
-    kernel on the TPU, gathered rows elsewhere); a chunk's rows attend,
+    absorbed form straight out of the pool (`mla_paged_attention`: on the
+    TPU two kernels, the chain of blocks that the tick's slots share
+    attended once for all of them and each slot's own blocks after it -
+    which blocks those are it reads from the tables, `shared_prefix` - and
+    gathered rows elsewhere); a chunk's rows attend,
     absorbed too, over the slot's gathered chain, the prefix an earlier
     request wrote included, in a loop that follows the chunk's last position
     (`xla_mla_chunk_attention`, which says why absorbed).  Several rows a
@@ -1120,7 +1123,7 @@ class LatentRows(_RoutingCounts):
 
     @staticmethod
     def attention_path(config, one_row: bool, blocks_per_slot: int, layer_pool) -> str:
-        """``"mla_paged"``, the kernel, or ``"xla"``: how a tick's rows
+        """``"mla_paged"``, the kernels, or ``"xla"``: how a tick's rows
         attend (`mla_attention.mla_paged_path`)."""
         from bpe_transformer_tpu.kernels.pallas.mla_attention import (
             mla_paged_path,

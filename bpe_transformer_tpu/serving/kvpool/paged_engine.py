@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bpe_transformer_tpu.kernels.pallas import mla_attention
 from bpe_transformer_tpu.models.config import ModelConfig
 from bpe_transformer_tpu.models.decode import (
     cache_kind,
@@ -313,6 +314,9 @@ class _Launch:
     #: state-space slot-layers it updated.
     moe: tuple | None = None
     ssm_state_rows: int = 0
+    #: A latent tick's ``(key positions x sublayers, member slots)`` of the
+    #: shared pass; None without a latent pool.
+    attn_shared: tuple | None = None
     first: bool = False  # a final chunk: the token is its slot's first
 
 
@@ -588,6 +592,20 @@ class PagedEngine:
         self.tick_attention_path = cache_kind(config).attention_path(
             config, True, self.blocks_per_slot, self._first_attention_entry()
         )
+        #: A latent pool under the tick's kernels: key positions the ticks'
+        #: slots attended through the shared pass - the chain of blocks
+        #: several slots' rows start with, attended once for all of them -
+        #: x sublayers (the unit of ``attn_kv_positions``), the slots on
+        #: the chain summed over ticks, and the last tick's own two (None
+        #: without a latent pool: the ``tick`` record leaves them out).  The
+        #: program's own rule (`mla_attention.shared_prefix`) on the host's
+        #: tables and positions.
+        self._shares_chain = (
+            self.latent and self.tick_attention_path == "mla_paged"
+        )
+        self.attn_shared_kv_positions = 0
+        self.attn_shared_slots = 0
+        self.last_tick_attn_shared = (0, 0) if self.latent else None
         #: State-space layers: slot-layers the ticks updated (live slots x
         #: state-space layers), real and bucket rows x state-space layers
         #: through the chunks' scans, admissions that started from a zero
@@ -815,6 +833,9 @@ class PagedEngine:
         out["kv_window_blocks_recycled"] = self._window_recycled
         out["attn_pairs"] = self.attn_pairs
         out["attn_kv_positions"] = self.attn_kv_positions
+        if self.latent:
+            out["attn_shared_kv_positions"] = self.attn_shared_kv_positions
+            out["attn_shared_slots"] = self.attn_shared_slots
         out["tick_attention_path"] = self.tick_attention_path
         out["tick_live_key_share"] = (
             100.0 * self.tick_live_keys / self.tick_table_keys
@@ -1677,6 +1698,20 @@ class PagedEngine:
                 self.attn_kv_positions += keys_read
                 self.tick_live_keys += keys_live
                 self.tick_table_keys += self._tables.size * self.block_size
+                attn_shared = (0, 0) if self.latent else None
+                if self._shares_chain:
+                    shared, _ = mla_attention.shared_prefix(
+                        self._tables,
+                        np.where(self._active, self._positions + 1, 0),
+                        self.block_size, xp=np,
+                    )
+                    attn_shared = (
+                        self._attn_sublayers * self.block_size
+                        * int(shared.sum()),
+                        int(np.count_nonzero(shared)),
+                    )
+                    self.attn_shared_kv_positions += attn_shared[0]
+                    self.attn_shared_slots += attn_shared[1]
                 ssm_state_rows = self._ssm_layers * len(live)
                 self.ssm_tick_state_rows += ssm_state_rows
                 asked = filters_asked(
@@ -1713,7 +1748,7 @@ class PagedEngine:
                 self._unread.append(_Launch(
                     tokens,
                     tuple(zip(live.tolist(), self._tenant[live].tolist())),
-                    moe, ssm_state_rows,
+                    moe, ssm_state_rows, attn_shared,
                 ))
                 self.ticks += 1
                 self._positions[live] += 1
@@ -1737,6 +1772,7 @@ class PagedEngine:
         with Phase("serve/tick_emit", self.clock) as emit:
             if not launch.first:
                 self.last_tick_ssm_state_rows = launch.ssm_state_rows
+                self.last_tick_attn_shared = launch.attn_shared
             held = self._tenant.tolist()  # a release bumps its own slot only
             for slot, tenant in launch.rows:
                 if held[slot] != tenant:

@@ -1505,6 +1505,14 @@ class ServingEngine:
             now - before for now, before in
             zip(self._carry_counts(), period["carry_before"])
         )
+        # Key positions x sublayers that the tick read in the period
+        # attended through the latent kernels' shared pass, and the slots on
+        # the shared chain: over a latent pool alone.
+        attn_shared = getattr(self.engine, "last_tick_attn_shared", None)
+        shared_pass = {} if attn_shared is None else {
+            "attn_shared_kv_positions": attn_shared[0],
+            "attn_shared_slots": attn_shared[1],
+        }
         self._telemetry.emit(
             {
                 "kind": "tick",
@@ -1550,6 +1558,7 @@ class ServingEngine:
                 ),
                 "ssm_chunk_tokens": ssm_chunk[0],
                 "ssm_chunk_rows": ssm_chunk[1],
+                **shared_pass,
             }
         )
 

@@ -92,7 +92,11 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # optional ``ssm_tick_state_rows``: state-space slot-layers the tick
     # updated (live slots x state-space layers), and ``ssm_chunk_tokens`` /
     # ``ssm_chunk_rows``: real and bucket rows x state-space layers through
-    # the scans of the period's prefill chunks.
+    # the scans of the period's prefill chunks; optional
+    # ``attn_shared_kv_positions`` / ``attn_shared_slots``: key positions x
+    # sublayers that tick's slots attended through the latent kernels'
+    # shared pass, and the slots on the shared chain (over a latent pool
+    # alone).
     "tick": {
         "kind", "t", "dur_s", "admit_s", "prefill_s", "chunks",
         "prefill_tokens", "dispatch_s", "wait_s", "emit_s", "deliver_s",
@@ -186,7 +190,12 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # ``ssm_chunk_tokens`` / ``ssm_chunk_rows`` (real and bucket rows x
     # state-space layers through the chunks' scans), ``ssm_state_resets``
     # (admissions from a zero state) and the gauge ``ssm_state_bytes``
-    # (``kv_pool_bytes`` stays K and V alone); and the two dispatch phases
+    # (``kv_pool_bytes`` stays K and V alone); over a latent pool alone
+    # ``attn_shared_kv_positions`` (of ``attn_kv_positions``, those the
+    # ticks' slots attended through the shared pass: the chain of blocks
+    # several slots' rows start with, attended once for all of them) and
+    # ``attn_shared_slots`` (slots on the chain, summed over ticks); and
+    # the two dispatch phases
     # in parts (ISSUE 38; ``paged_engine.LAUNCH_PARTS``), clock seconds
     # summed over every launch: ``launch_tick_{prepare,call,after}_s`` of
     # the ``ticks`` launches - host arithmetic and the argument copies, the

@@ -803,12 +803,24 @@ def test_ragged_paged_attention(one_chip, monkeypatch, tokens, seqs, pages, wind
 # ------------------------------------- LongCat-Flash-Omni (latent attention)
 
 
+def _mla_kernels(text) -> set:
+    """The latent tick's Mosaic kernels in a compiled text, by the names
+    their device events carry (the instruction's, less its number)."""
+    import re
+
+    return set(re.findall(
+        r"%(mla_paged_attention[a-z_]*)\.\d+ = [^\n]*custom-call\(", text
+    ))
+
+
 @pytest.mark.parametrize("slots", [64, 8], ids=["64_slots", "8_slots"])
 def test_mla_paged_attention(one_chip, slots):
     """The tick's absorbed latent attention at the published widths: 64
     heads against latent rows of 576 values in a pool whose rows are padded
     to 640 lanes, blocks of 16, a table of 1,024 blocks.  (At 576 lanes the
-    compiler refuses the block's slice: not a whole tile.)"""
+    compiler refuses the block's slice: not a whole tile.)  Two kernels, the
+    shared pass and the own pass, and both device events start with the
+    name that `mla_paged_attention_roofline` reads."""
     from bpe_transformer_tpu.kernels.pallas.mla_attention import (
         mla_paged_attention,
     )
@@ -823,7 +835,7 @@ def test_mla_paged_attention(one_chip, slots):
         fn, one_chip, ((slots, 64, 576), BF16), ((20481, 16, 640), BF16),
         ((slots, 1024), I32), ((slots,), I32),
     )
-    assert "mla_paged_attention" in text
+    assert _mla_kernels(text) == {"mla_paged_attention", "mla_paged_attention_shared"}
 
 
 @pytest.mark.parametrize(
@@ -857,7 +869,10 @@ def test_latent_pool_programs(one_chip, on_tpu, name):
     jitted, args, pool = _pool_program(name, config, one_chip, None, slots=8)
     compiled = jitted.lower(*args).compile()
     text = compiled.as_text()
-    assert ("mla_paged_attention" in text) == (name == "tick")
+    assert _mla_kernels(text) == (
+        {"mla_paged_attention", "mla_paged_attention_shared"}
+        if name == "tick" else set()
+    )
     assert "gmm" in text
     # The expert layer may sort assignments by expert (`models/moe.py`); the
     # sampler sorts nothing, so no sort has a vocabulary-wide operand.

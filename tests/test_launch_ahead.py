@@ -214,6 +214,18 @@ def test_every_cache_kind_times_its_launches_in_the_same_parts(kind):
     assert all(gauges[key] > 0 for key in timed)
 
 
+@pytest.mark.parametrize("kind", KINDS)
+def test_every_cache_kind_hands_out_the_same_gauges(kind):
+    """`gauges()` has the same keys over every cache kind, but for the two
+    counts of the latent tick's shared pass, which the latent kind alone
+    carries (0 here: on the CPU its ticks take gathered rows)."""
+    gauges = KINDS[kind]().gauges()
+    shared_pass = {"attn_shared_kv_positions", "attn_shared_slots"}
+    assert set(gauges) - shared_pass == set(dense_engine().gauges())
+    assert (set(gauges) & shared_pass == shared_pass) == (kind == "latent")
+    assert not any(gauges.get(key) for key in shared_pass)
+
+
 def test_tick_is_launch_then_collect():
     """`tick()` is `launch()` then `collect()`: two engines, one driven by
     the composition and one by its halves, step for step."""

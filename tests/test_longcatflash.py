@@ -259,6 +259,248 @@ def test_the_kernel_reads_what_the_gathered_rows_read():
     assert float(jnp.max(jnp.abs(got[~live]))) == 0.0
 
 
+# ------------------------------------- the chain of blocks the slots share
+
+#: One block pool for every case: 6 slots of up to 8 blocks of 4 rows.
+CHAIN = [1, 2, 3, 4, 5]  # the blocks a shared prefix lies in
+
+
+def _rows(*own, shared=5, chain=CHAIN):
+    """A table row: ``shared`` blocks of the chain, then its own."""
+    row = list(chain[:shared]) + list(own)
+    return row + [0] * (8 - len(row))
+
+
+SHARED_CASES = {
+    # name: (rows of the table, key counts, the rule's shared blocks)
+    "every_slot_shares": (
+        [_rows(10, 11), _rows(12), _rows(13, 14, 15), _rows(16), _rows(17), _rows(18)],
+        [26, 21, 32, 22, 23, 24], [5, 5, 5, 5, 5, 5],
+    ),
+    "none_shares": (
+        [_rows(10 + 7 * s, 11 + 7 * s, 12 + 7 * s, shared=0) for s in range(6)],
+        [9, 12, 5, 1, 10, 11], [0] * 6,
+    ),
+    # Slots 1 and 4 prefilled the same tokens before the cache held them
+    # (`private_copies` writes their blocks): other ids, no run.
+    "private_copies": (
+        [_rows(10), _rows(11, chain=[20, 21, 22, 23, 24]), _rows(12, 13),
+         _rows(14), _rows(15, chain=[30, 31, 32, 33, 34]), _rows(16)],
+        [22, 23, 27, 21, 24, 22], [5, 0, 5, 5, 0, 5],
+    ),
+    # Groups are 2 blocks here: chains of 5 and 3 blocks end inside one,
+    # and a fork of the radix tree leaves slot 3 on the first 3 alone.
+    "a_chain_that_ends_inside_a_group": (
+        [_rows(10), _rows(11, 12), _rows(13), _rows(14, 15, 16, shared=3),
+         _rows(17), _rows(18)],
+        [23, 26, 21, 22, 24, 22], [5, 5, 5, 3, 5, 5],
+    ),
+    "a_member_with_no_keys_of_its_own": (
+        [_rows(10), _rows(), _rows(11), _rows(), _rows(12), _rows(13)],
+        [22, 20, 23, 20, 21, 24], [5, 5, 5, 5, 5, 5],
+    ),
+    # Slot 1 idles (an empty row), slot 3 is mid-prefill after a cache hit
+    # (the chain is in its row, its count is 0).
+    "idle_and_prefilling_slots_among_members": (
+        [_rows(10), [0] * 8, _rows(11), _rows(12), _rows(13), _rows(14)],
+        [22, 0, 23, 0, 21, 24], [5, 0, 5, 0, 5, 5],
+    ),
+    # Slot 2's row runs with the reference's for 5 blocks and its count
+    # reaches into the third: the two whole blocks it sees are shared.
+    "a_row_that_matches_past_its_count": (
+        [_rows(10), _rows(11), _rows(), _rows(12), _rows(13), _rows(14)],
+        [22, 23, 10, 21, 24, 22], [5, 5, 2, 5, 5, 5],
+    ),
+    # Two slots on a chain are fewer than `MLA_SHARED_MIN_SLOTS` (3 here).
+    "too_few_members": (
+        [_rows(10), _rows(11)] + [
+            _rows(12 + 7 * s, 13 + 7 * s, 14 + 7 * s, shared=0) for s in range(4)
+        ],
+        [22, 23, 9, 12, 5, 10], [0] * 6,
+    ),
+    # The largest family decides the reference, not the first live slot.
+    "the_first_slot_holds_a_private_copy": (
+        [_rows(10, chain=[20, 21, 22, 23, 24]), _rows(11), _rows(12), _rows(13),
+         _rows(14), _rows(15)],
+        [22, 23, 21, 24, 22, 23], [0, 5, 5, 5, 5, 5],
+    ),
+}
+
+
+@pytest.fixture
+def small_groups(monkeypatch):
+    """Groups of 2 blocks and tiles of 2 slots' heads, so 6 slots and 5
+    shared blocks make several tiles and several groups; the pass is taken
+    from 3 slots on a chain."""
+    monkeypatch.setattr(mla_attention, "MLA_GROUP_KEYS", 8)
+    monkeypatch.setattr(mla_attention, "MLA_SHARED_TILE_ROWS", 32)
+    monkeypatch.setattr(mla_attention, "MLA_SHARED_MIN_SLOTS", 3)
+    mla_attention._mla_paged_impl.clear_cache()
+    yield
+    mla_attention._mla_paged_impl.clear_cache()
+
+
+@pytest.mark.parametrize("case", SHARED_CASES)
+def test_the_shared_pass_and_the_own_pass_read_what_the_gathered_rows_read(
+    case, small_groups
+):
+    """The two kernels (interpret mode) against `xla_mla_rows_attention`
+    over the gathered rows, and the rule's two forms against each other."""
+    rows, counts, want_shared = SHARED_CASES[case]
+    rng = np.random.default_rng(11)
+    heads, width, rank, block = 4, 12, 8, 4
+    pool = rng.normal(size=(60, block, width)).astype(np.float32)
+    for copy in ([20, 21, 22, 23, 24], [30, 31, 32, 33, 34]):
+        pool[copy] = pool[CHAIN]  # private copies of the same tokens
+    pool = jnp.asarray(pool)
+    q = jnp.asarray(rng.normal(size=(len(rows), heads, width)), jnp.float32)
+    tables, counts = np.asarray(rows, np.int32), np.asarray(counts, np.int32)
+
+    shared, reference = mla_attention.shared_prefix(tables, counts, block, xp=np)
+    assert shared.tolist() == want_shared
+    traced, traced_reference = jax.jit(
+        lambda t, c: mla_attention.shared_prefix(t, c, block)
+    )(tables, counts)
+    assert traced.tolist() == want_shared and int(traced_reference) == reference
+
+    gathered = pool[tables].reshape(len(rows), -1, width)
+    visible = jnp.arange(gathered.shape[1]) < counts[:, None]
+    want = mla_attention.xla_mla_rows_attention(
+        q, gathered, visible, rank=rank, scale=0.3
+    )
+    got = mla_attention.mla_paged_attention(
+        q, pool, jnp.asarray(tables), jnp.asarray(counts), rank=rank,
+        scale=0.3, path="mla_paged",
+    )
+    live = counts > 0
+    assert float(jnp.max(jnp.abs(got - want)[live])) < 1e-5
+    assert float(jnp.max(jnp.abs(got[~live]), initial=0.0)) == 0.0
+
+
+@pytest.mark.parametrize("members, taken", [(5, False), (6, True)])
+def test_the_shared_pass_is_taken_from_six_members(members, taken):
+    """The constant as the package ships it (no monkeypatch): five slots on
+    a chain run today's walk alone, six take the shared pass - in both of
+    the rule's forms."""
+    assert mla_attention.MLA_SHARED_MIN_SLOTS == 6
+    rows = [_rows(10 + s) for s in range(members)] + [
+        _rows(40 + 3 * s, 41 + 3 * s, shared=0) for s in range(8 - members)
+    ]
+    tables = np.asarray(rows, np.int32)
+    counts = np.full(8, 22, np.int32)
+    want = [5 * taken] * members + [0] * (8 - members)
+    shared, _ = mla_attention.shared_prefix(tables, counts, 4, xp=np)
+    assert shared.tolist() == want
+    traced, _ = jax.jit(lambda t, c: mla_attention.shared_prefix(t, c, 4))(
+        tables, counts
+    )
+    assert traced.tolist() == want
+
+
+def _serve_after_one_prefix(monkeypatch, rule):
+    """Four greedy requests behind one 9-token prefix (two whole blocks)
+    through the kernels in interpret mode, `shared_prefix` replaced by
+    ``rule``: every request's tokens, the engine's gauges, and the shared
+    blocks of every tick as the program itself computed them."""
+    monkeypatch.setattr(mla_attention, "mla_paged_path", lambda *a, **k: "mla_paged")
+    monkeypatch.setattr(mla_attention, "MLA_SHARED_MIN_SLOTS", 3)
+    # The kernels' launcher is jitted on its own and reads the rule and the
+    # constant when it is traced.
+    mla_attention._mla_paged_impl.clear_cache()
+    in_program = []
+
+    def recording(tables, key_counts, block_size, xp=jnp):
+        shared, reference = rule(tables, key_counts, block_size, xp=xp)
+        if xp is jnp:
+            jax.debug.callback(lambda v: in_program.append(np.asarray(v)), shared)
+        return shared, reference
+
+    monkeypatch.setattr(mla_attention, "shared_prefix", recording)
+    c = reference_cfg(4, 4)
+    eng = small_engine(c, slots=4, prefix_cache=True)
+    assert eng.tick_attention_path == "mla_paged"
+    rng = np.random.default_rng(9)
+    prefix = rng.integers(0, 64, 9)
+    prompts = [np.concatenate([prefix, rng.integers(0, 64, n)]) for n in (3, 6, 2, 5)]
+    served = {}
+    for prompt, new in zip(prompts, (7, 5, 9, 6)):
+        event = eng.admit(prompt, max_new_tokens=new, temperature=0.0)
+        served[event.slot] = [event.token]
+    assert [eng.slot_shared_len(s) for s in sorted(served)] == [0, 8, 8, 8]
+    while eng.active_count:
+        for event in eng.tick():
+            served[event.slot].append(event.token)
+    jax.effects_barrier()
+    return served, {**eng.gauges(), "ticks": eng.ticks}, in_program
+
+
+def test_the_shared_pass_serves_the_tokens_of_the_walk_alone(monkeypatch):
+    """The same requests with the rule as it is and with a rule that finds
+    no chain (every slot walks its whole row, as before there was a shared
+    pass) are served the same tokens; and what the host counted of the
+    shared pass is what the program's own rule found, tick by tick."""
+    rule = mla_attention.shared_prefix
+
+    def no_chain(tables, key_counts, block_size, xp=jnp):
+        shared, reference = rule(tables, key_counts, block_size, xp=xp)
+        return shared * 0, reference
+
+    try:
+        served, gauges, in_program = _serve_after_one_prefix(monkeypatch, rule)
+        alone, gauges_alone, none = _serve_after_one_prefix(monkeypatch, no_chain)
+    finally:
+        mla_attention._mla_paged_impl.clear_cache()
+    assert served == alone and len(served) == 4
+    # Every sublayer of a tick asks the rule (4 here).
+    assert len(in_program) == 4 * gauges["ticks"] == 4 * gauges_alone["ticks"]
+    assert len(none) == len(in_program)
+    assert all(not shared.any() for shared in none)
+    assert gauges_alone["attn_shared_kv_positions"] == 0
+    assert gauges_alone["attn_shared_slots"] == 0
+    # Blocks of 4: the prefix's two blocks in every live row while at least
+    # `MLA_SHARED_MIN_SLOTS` (3 here) of the requests live.
+    assert gauges["attn_shared_kv_positions"] == 4 * sum(
+        int(shared.sum()) for shared in in_program
+    )
+    assert 4 * gauges["attn_shared_slots"] == sum(
+        int((shared > 0).sum()) for shared in in_program
+    )
+    assert {int(v) for shared in in_program for v in shared} == {0, 2}
+    assert 0 < gauges["attn_shared_kv_positions"] < gauges["attn_kv_positions"]
+    assert gauges["attn_kv_positions"] == gauges_alone["attn_kv_positions"]
+
+
+@pytest.mark.parametrize("pool", ["latent", "dense"])
+def test_the_tick_record_carries_the_shared_pass_over_a_latent_pool_alone(pool):
+    """A served request's `tick` records hold the shared pass's two counts
+    over a latent pool (0 here: gathered rows on the CPU) and leave them
+    out over any other."""
+    from bpe_transformer_tpu.serving.server import Request, ServingEngine
+    from bpe_transformer_tpu.telemetry.schema import validate_record
+    from bpe_transformer_tpu.telemetry.spans import Telemetry
+
+    if pool == "latent":
+        c = reference_cfg(4, 4)
+        weights, config = ref.weights_from_seed(3, c), program_cfg(c)
+    else:
+        config = dataclasses.replace(TS_TEST_CONFIG, vocab_size=64, context_length=32)
+        weights = init_params(jax.random.PRNGKey(0), config)
+    records = []
+    with ServingEngine(
+        weights, config, slots=2, min_bucket=8, paged=True, block_size=4,
+        prefill_chunk=8, telemetry=Telemetry(sink=records.append),
+    ) as serving:
+        request = Request(prompt_ids=(5, 6, 7), max_new_tokens=4, temperature=0.0)
+        serving.submit(request).result(timeout=300)
+    ticks = [r for r in records if r.get("kind") == "tick"]
+    assert ticks
+    for record in ticks:
+        validate_record(record)
+        held = {"attn_shared_kv_positions", "attn_shared_slots"} & set(record)
+        assert len(held) == (2 if pool == "latent" else 0)
+        assert not any(record[key] for key in held)
+
+
 # ------------------------------------------- the expert layer and its shares
 
 
