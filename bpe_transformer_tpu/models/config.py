@@ -237,6 +237,23 @@ class ModelConfig:
     logits_scaling: float = 1.0
     #: Width of a shared expert where it is not a routed expert's.
     shared_d_ff: int | None = None
+    # ---- chunked linear attention over a window, the unit-offset norm and
+    # several prediction heads (the EvaByte family; every default is the
+    # block above) ---------------------------------------------------------
+    #: ``attention_kind="eva"`` (`models/eva.py`): a query attends exactly
+    #: to the keys of its own window of ``eva_window`` positions up to
+    #: itself, and to one summary row for every chunk of ``eva_chunk``
+    #: positions of every earlier window, in one softmax.  The window is a
+    #: multiple of the chunk and the context of the window.
+    eva_window: int = 0
+    eva_chunk: int = 0
+    #: ``norm(x) = x / rms(x) * (1 + g)``: the norms' weights are offsets
+    #: from one.
+    norm_unit_offset: bool = False
+    #: Prediction heads: the head's width is ``vocab_size *
+    #: num_pred_heads``, head-major; head 0 is the next token and what
+    #: generation samples, head ``j`` the token ``j`` further on.
+    num_pred_heads: int = 1
     # Sequence-parallel ring attention: sub-chunk each visiting K/V shard
     # so per-device score memory is O(S_local * chunk) instead of
     # O(S_local^2).  Must divide the local shard length.  None -> one full
@@ -274,6 +291,25 @@ class ModelConfig:
     @property
     def kv_lora_scale(self) -> float:
         return (self.d_model / self.kv_lora_rank) ** 0.5 if self.mla_scale_kv_lora else 1.0
+
+    @property
+    def eva_block(self) -> bool:
+        """Chunked linear attention (`models/eva.py`): the sequential
+        pre-norm block with the residual stream carried in float32 over
+        activations of ``activation_dtype`` (the published
+        ``fp32_skip_add``; `models/decode._block_apply`).  Derived, as
+        `double_layer` is: the one configuration with this attention adds
+        so."""
+        return self.attention_kind == "eva"
+
+    @property
+    def eva_chunks_per_window(self) -> int:
+        return self.eva_window // self.eva_chunk
+
+    @property
+    def head_width(self) -> int:
+        """Outputs of the head: every prediction head's ``vocab_size``."""
+        return self.vocab_size * self.num_pred_heads
 
     @property
     def double_layer(self) -> bool:
@@ -368,10 +404,14 @@ class ModelConfig:
         (no training step, no ``scan_layers``, no int8 weights): a layer
         pattern, a parallel block, LayerNorm, shared or held experts, latent
         attention, the double layer, zero experts and their router,
-        state-space layers."""
+        state-space layers, chunked linear attention with its unit-offset
+        norm and prediction heads."""
         return (
             self.latent_block
             or self.hybrid_block
+            or self.eva_block
+            or self.norm_unit_offset
+            or self.num_pred_heads != 1
             or self.has_window_layers
             or self.parallel_block
             or self.norm_type != "rmsnorm"
@@ -387,7 +427,7 @@ class ModelConfig:
         """Latent attention (and with it the double layer), or any of its
         expert layer's departures."""
         return (
-            self.attention_kind != "mha"
+            self.attention_kind == "mla"
             or self.expert_d_ff is not None
             or self.n_zero_experts > 0
             or not self.norm_topk_prob
@@ -453,9 +493,49 @@ class ModelConfig:
                 f"{self.expert_offset} must name experts of a MoE layer with "
                 f"n_experts={self.n_experts}"
             )
-        if self.attention_kind not in ("mha", "mla"):
+        if self.attention_kind not in ("mha", "mla", "eva"):
             raise ValueError(
-                f'attention_kind={self.attention_kind!r} must be "mha" or "mla"'
+                f'attention_kind={self.attention_kind!r} must be "mha", '
+                '"mla" or "eva"'
+            )
+        if self.eva_block:
+            if (
+                self.eva_chunk < 1 or self.eva_window < self.eva_chunk
+                or self.eva_window % self.eva_chunk
+                or self.context_length % self.eva_window
+            ):
+                raise ValueError(
+                    f'attention_kind="eva" needs eva_chunk={self.eva_chunk} >= '
+                    f"1 dividing eva_window={self.eva_window}, which divides "
+                    f"context_length={self.context_length}"
+                )
+            if (
+                self.sliding_window is not None or self.hybrid_block
+                or self.num_kv_heads not in (None, self.num_heads)
+                or self.parallel_block or self.use_post_norm
+                or self.remove_rmsnorm or self.remove_rope
+                or self.norm_type != "rmsnorm"
+                or self.ffn_type not in (None, "swiglu")
+                or self.num_pred_heads < 1 or self.tie_embeddings
+            ):
+                raise ValueError(
+                    "chunked linear attention summarises every K/V head's "
+                    "rotated keys by window and comes in the sequential "
+                    "pre-norm RMSNorm block with a dense SwiGLU and an untied "
+                    "head: sliding_window, state-space layers "
+                    "(attn_layer_period), num_kv_heads < num_heads, "
+                    "parallel_block, use_post_norm, remove_rmsnorm, "
+                    "remove_rope, LayerNorm, another ffn_type and "
+                    "tie_embeddings contradict it"
+                )
+        elif (
+            self.eva_window or self.eva_chunk or self.norm_unit_offset
+            or self.num_pred_heads != 1
+        ):
+            raise ValueError(
+                "eva_window, eva_chunk, norm_unit_offset and num_pred_heads "
+                'are chunked linear attention\'s (attention_kind="eva"): no '
+                "other block applies them"
             )
         mla_dims = (
             self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
@@ -552,6 +632,7 @@ class ModelConfig:
             raise ValueError("shared_d_ff is the positive width of n_shared_experts > 0")
         if self.dropless_block and not (
             self.parallel_block or self.double_layer or self.hybrid_block
+            or self.eva_block
         ):
             raise ValueError(
                 "a layer pattern, LayerNorm, head_dim, sigmoid routing, shared "
@@ -564,7 +645,7 @@ class ModelConfig:
             raise ValueError(
                 "scan_layers runs homogeneous training blocks; a layer "
                 "pattern, state-space layers, the parallel block, the double "
-                "layer, LayerNorm, "
+                "layer, chunked linear attention, LayerNorm, "
                 "shared, held or zero experts are served and not trained "
                 "(ROADMAP: what cannot run yet)"
             )

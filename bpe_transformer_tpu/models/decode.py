@@ -41,6 +41,12 @@ KVCache = list  # [{"k": (B, H, ctx, dh), "v": (B, H, ctx, dh)} per layer]
 
 
 def init_kv_cache(config: ModelConfig, batch: int, dtype=jnp.float32) -> KVCache:
+    if config.eva_block:
+        raise NotImplementedError(
+            "chunked linear attention has no dense cache of a row a position: "
+            "it is served over the paged engine's summary-and-window cache "
+            "(`EvaRows`), and its whole-sequence form is `transformer.forward`"
+        )
     if config.attention_kind == "mla":
         # Latent attention: per layer and sublayer one buffer of latent rows
         # (batch, 1, context_length, latent_width) - no heads.
@@ -136,6 +142,16 @@ def _block_apply(x, block_params, config, attend, valid=None, tally=None):
         with jax.named_scope("block/ffn"), jax.named_scope("dense"):
             h = _norm(d, ln[3], config)
             return d + swiglu(h, dense[1]["w1"], dense[1]["w2"], dense[1]["w3"]) + m
+    if config.eva_block:
+        # Sequential and pre-norm, the stream and both adds in float32 over
+        # branches at the activation width (the published fp32_skip_add).
+        act = jnp.dtype(config.activation_dtype)
+        x = x.astype(jnp.float32)
+        with jax.named_scope("block/attn"):
+            x = x + attend(_norm(x, block_params["ln1"], config).astype(act))
+        with jax.named_scope("block/ffn"):
+            h = _norm(x, block_params["ln2"], config).astype(act)
+            return x + _ffn_decode(h, block_params["ffn"], config, valid, tally)
     if config.hybrid_block:
         # Sequential and pre-norm; the layer's mixer (attention, or the
         # state-space mixer where the tree has "ssm") and the expert layer
@@ -169,6 +185,9 @@ def _block_apply(x, block_params, config, attend, valid=None, tally=None):
 def _norm(x, w, config):
     if config.remove_rmsnorm:
         return x
+    if config.norm_unit_offset:
+        # The offset is added at float32, as `transformer._maybe_norm` says.
+        return rmsnorm(x, 1.0 + w.astype(jnp.float32))
     return layernorm(x, w) if config.norm_type == "layernorm" else rmsnorm(x, w)
 
 
@@ -190,7 +209,9 @@ def _logits(x, head, config):
 
 def _final_norm(x, params, config):
     with jax.named_scope("final_norm"):
-        return _norm(x, params["ln_final"], config)
+        x = _norm(x, params["ln_final"], config)
+        # A float32 residual stream ends here.
+        return x.astype(config.activation_dtype) if config.eva_block else x
 
 
 def _project_qkv(h, attn, config):
@@ -1290,9 +1311,167 @@ class RecurrentRows(_RoutingCounts):
         return out
 
 
+# Chunked linear attention (`models/eva.py`) keeps the exact K/V of a
+# sequence's open window and one summary row for every chunk of the windows
+# it has closed.  Its pool is `init_kv_pool`'s - a row is K and V of
+# ``heads * d_head`` whether it is a position's or a chunk's summary - and a
+# block is a chunk (``block_size == eva_chunk``), so a summary is a block's
+# summary and a block of summaries those of ``block_size`` blocks.
+
+
+def eva_table_geometry(config: ModelConfig, block_size: int) -> tuple[int, int, int]:
+    """``(summary blocks a window, window blocks, table width)`` of a slot's
+    table row over a summary-and-window cache: the summary blocks of every
+    closed window, then the open window's blocks, then the open window's
+    own summary blocks, written and not yet visible - ``windows *
+    summary_blocks + window_blocks`` entries at the longest context."""
+    window, chunk = config.eva_window, config.eva_chunk
+    if block_size != chunk or (window // chunk) % block_size:
+        raise ValueError(
+            f"a summary-and-window cache keeps a chunk a block and whole "
+            f"blocks of summaries a window: block_size={block_size} must "
+            f"equal eva_chunk={chunk} and its square divide "
+            f"eva_window={window}"
+        )
+    per_window = window // chunk // block_size
+    return per_window, window // block_size, (
+        config.context_length // window * per_window + window // block_size
+    )
+
+
+class EvaRows:
+    """A summary-and-window cache over `init_kv_pool`'s pool.  A slot's
+    table row is **its visible summary blocks followed by its open window's
+    blocks** (`eva_table_geometry`; the serving engine rewrites the row each
+    time a window closes), so the rows a query attends - every summary of
+    every closed window, then its own window up to itself - are the leading
+    ``key_count`` rows of the row's chain: the position at ``r`` of window
+    ``w`` is chain row ``at = w * chunks_per_window + r``, and attends
+    ``at + 1`` rows.
+
+    * A tick's one row a slot is written at ``at`` and attends under that
+      plain length, so it is the dense pool's tick - `DenseRows` over chain
+      rows in the place of positions, by composition: its write, and its
+      two paths to attend (the paged-native kernel on the TPU, gathered
+      rows elsewhere).  The row
+      that completes a chunk (``r % chunk == chunk - 1``) also reads its
+      block back whole and writes the block's summary
+      (`eva.chunk_summaries`) among the open window's pending summaries,
+      ``window`` rows past the window's start; every other row's summary,
+      computed alike (one program), goes to the trash block.
+    * A chunk is one slot's, block-aligned and inside one window (the
+      engine cuts prompts so): its rows are written, its whole blocks
+      summarised from the rows in hand, and its queries attend over the
+      slot's gathered chain in `eva.xla_eva_chunk_attention`'s loop.
+
+    Several rows a slot (a verify pass) would straddle a window's closing:
+    no form here.  No routing counts ride along."""
+
+    ffn_rows = tally = None
+
+    def __init__(self, config, tables, positions, valid, block_size, chunk=None):
+        if positions.ndim != 1:
+            raise NotImplementedError(
+                "several rows a slot (a verify pass) over a summary-and-"
+                "window cache"
+            )
+        eva_table_geometry(config, block_size)
+        self.config, self.tables, self.chunk = config, tables, chunk
+        window, per_chunk = config.eva_window, config.eva_chunk
+        rows = 1 if chunk is None else positions.shape[0]
+        pos = _clamped(positions, config.context_length - 1, rows)
+        self.rope_positions = pos[:, None] if chunk is None else pos
+        # Chain rows: the closed windows' summaries, then the open window.
+        self.n_summaries = pos // window * config.eva_chunks_per_window
+        #: The dense kind over chain rows in the place of positions: where
+        #: a row is written, and how a tick's row attends.
+        self.rows = DenseRows(
+            config, tables, self.n_summaries + pos % window, valid, block_size,
+            chunk,
+        )
+        # Where the summaries go: the pending rows lie a window past the
+        # open window's first row.
+        if chunk is None:
+            closes = pos % per_chunk == per_chunk - 1
+            summary_at = self.n_summaries + window + pos % window // per_chunk
+            summary_valid = closes if valid is None else closes & valid
+        else:
+            start, chunk_len = chunk
+            whole = jnp.arange(rows // per_chunk)
+            summary_at = (
+                start // window * config.eva_chunks_per_window + window
+                + start % window // per_chunk + whole
+            )
+            summary_valid = (whole + 1) * per_chunk <= chunk_len
+        self.summary_ids, self.summary_offsets = _block_addresses(
+            tables, summary_at, summary_valid, block_size, summary_at.shape[0]
+        )
+
+    @staticmethod
+    def attention_path(config, one_row: bool, blocks_per_slot: int, layer_pool) -> str:
+        return DenseRows.attention_path(config, one_row, blocks_per_slot, layer_pool)
+
+    def attention(self, h, attn, layer, layer_pool, new_pool):
+        """One sublayer: ``h`` (slots, rows, d_model) -> the same shape; the
+        layer's K and V arrays, the new rows and summaries written, are
+        appended to ``new_pool``."""
+        from bpe_transformer_tpu.models import eva
+
+        config = self.config
+        q, k, v = eva.project_qkv(h, attn, self.rope_positions, config)
+        written = self.rows.write(layer, layer_pool, k, v)
+        heads, d_head, per_chunk = config.num_heads, config.d_head, config.eva_chunk
+        if self.chunk is None:
+            # The block the row landed in, whole, as the pool now holds it.
+            with jax.named_scope("pool_gather"):
+                k_blocks, v_blocks = (
+                    jnp.swapaxes(
+                        written[name][self.rows.write_ids].reshape(
+                            -1, per_chunk, heads, d_head
+                        ), 1, 2,
+                    )[:, :, None]
+                    for name in ("k", "v")
+                )
+        else:
+            k_blocks, v_blocks = (
+                x[0].reshape(heads, -1, per_chunk, d_head) for x in (k, v)
+            )
+        k_sum, v_sum = eva.chunk_summaries(
+            k_blocks, v_blocks, attn["eva_mu"], attn["eva_phi"]
+        )
+        if self.chunk is not None:  # (heads, chunks, d_head) -> a row a chunk
+            k_sum, v_sum = (jnp.swapaxes(x, 0, 1) for x in (k_sum, v_sum))
+        for name, rows in (("k", k_sum), ("v", v_sum)):
+            with jax.named_scope("pool_write"):
+                written[name] = written[name].at[
+                    self.summary_ids, self.summary_offsets
+                ].set(rows.reshape(rows.shape[0], -1).astype(q.dtype))
+        new_pool.append(written)
+
+        if self.chunk is None:
+            # A plain length over the chain: the dense kind's tick.
+            with jax.named_scope("eva_attn"):
+                att = self.rows.attend(layer, q, written)
+        else:
+            start, chunk_len = self.chunk
+            k_rows, v_rows = (
+                gather_paged_kv(written[name], self.tables[None], heads)[0]
+                for name in ("k", "v")
+            )
+            att = merge_heads(eva.xla_eva_chunk_attention(
+                q[0], k_rows, v_rows, self.n_summaries[0],
+                start % config.eva_window, chunk_len,
+            )[None])
+        return linear(att, attn["output_proj"])
+
+    zero_counts = counts = staticmethod(DenseRows.zero_counts)
+
+
 def cache_kind(config: ModelConfig):
     if config.attention_kind == "mla":
         return LatentRows
+    if config.eva_block:
+        return EvaRows
     if config.hybrid_block:
         return RecurrentRows
     return GroupedPages if config.has_window_layers else DenseRows
@@ -1306,6 +1485,10 @@ def init_paged_pool(
     """The pool of the config's cache kind.  ``num_window_blocks`` sizes the
     window group where the kind has one, ``slots`` the state rows of a
     recurrent one."""
+    if cache_kind(config) is EvaRows and kv_dtype is not None:
+        raise ValueError(
+            "a summary-and-window pool holds its rows at the activation width"
+        )
     if cache_kind(config) is RecurrentRows:
         if kv_dtype is not None:
             raise ValueError("a recurrent pool holds K/V at the activation width")
@@ -1357,6 +1540,8 @@ def _cached_attention(
     if config.attention_kind == "mla":
         return cache.attention(h, attn[sublayer], layer_pool[sublayer], new_pool)
     (layer_pool,) = layer_pool
+    if config.eva_block:
+        return cache.attention(h, attn, layer, layer_pool, new_pool)
     if ssm is not None:
         return cache.mixer(h, ssm, layer_pool, new_pool)
     q, k, v = _project_qkv(h, attn, config)

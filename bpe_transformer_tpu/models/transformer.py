@@ -55,6 +55,8 @@ def init_params(
         ).astype(dtype)
 
     d, ff, v = config.d_model, config.d_ff, config.vocab_size
+    # A norm's weight at rest: one, or the offset from it.
+    unit = jnp.zeros if config.norm_unit_offset else jnp.ones
     # GQA: K/V project to num_kv_heads * d_head rows (== d for plain MHA);
     # Q to num_heads * d_head (== d unless the config sets head_dim).
     d_q = config.num_heads * config.d_head
@@ -99,6 +101,10 @@ def init_params(
             from bpe_transformer_tpu.models.ssm import init_ssm_params
 
             mixer = {"ssm": init_ssm_params(k[0], config, dtype)}
+        elif config.eva_block:
+            from bpe_transformer_tpu.models.eva import init_eva_params
+
+            mixer = {"attn": init_eva_params(k[0], config, dtype)}
         else:
             mixer = {
                 "attn": {
@@ -108,16 +114,16 @@ def init_params(
                     "output_proj": dense(k[3], d, d_q),
                 }
             }
-        layers.append({**mixer, "ln1": jnp.ones((d,), dtype), "ffn": ffn_params})
+        layers.append({**mixer, "ln1": unit((d,), dtype), "ffn": ffn_params})
         if not config.parallel_block:  # one norm a block otherwise
-            layers[-1]["ln2"] = jnp.ones((d,), dtype)
+            layers[-1]["ln2"] = unit((d,), dtype)
     params = {
         "token_embeddings": dense(keys[0], v, d),
         "layers": layers,
-        "ln_final": jnp.ones((d,), dtype),
+        "ln_final": unit((d,), dtype),
     }
     if not config.tie_embeddings:
-        params["lm_head"] = dense(keys[1], v, d)
+        params["lm_head"] = dense(keys[1], config.head_width, d)
     return params
 
 
@@ -175,6 +181,10 @@ def _maybe_norm(x: Array, weight: Array, config: ModelConfig) -> Array:
         return x
     if config.norm_type == "layernorm":
         return layernorm(x, weight)
+    if config.norm_unit_offset:
+        # The offset is added at float32: one plus a 16-bit weight is not a
+        # 16-bit number.
+        return rmsnorm(x, 1.0 + weight.astype(jnp.float32))
     return rmsnorm(x, weight)
 
 
@@ -575,6 +585,19 @@ def forward_hidden(
                     h, attn[sub], positions, config
                 )[0],
             )
+    elif config.eva_block:
+        # The served arrangement again, over the whole sequence with the
+        # two masks written out (`models/eva.py`).
+        from bpe_transformer_tpu.models.decode import _block_apply
+        from bpe_transformer_tpu.models.eva import self_attention
+
+        for block_params in compute_params["layers"]:
+            x = _block_apply(
+                x, block_params, config,
+                lambda h, attn=block_params["attn"]: self_attention(
+                    h, attn, positions, config
+                ),
+            )
     elif config.hybrid_block:
         # The served arrangement again; a layer's mixer is the state-space
         # scan over the whole sequence or plain causal attention under the
@@ -633,7 +656,9 @@ def _hybrid_mixer(h: Array, block_params: dict, config: ModelConfig, positions) 
 
 def _final_norm(x: Array, compute_params: Params, config: ModelConfig) -> Array:
     with jax.named_scope("final_norm"):
-        return _maybe_norm(x, compute_params["ln_final"], config)
+        x = _maybe_norm(x, compute_params["ln_final"], config)
+        # A float32 residual stream ends here.
+        return x.astype(config.activation_dtype) if config.eva_block else x
 
 
 def _scan_blocks(

@@ -187,6 +187,15 @@ def filter_logits(logits, temps, top_ks, top_ps):
     return jnp.where(okey(scaled) >= jnp.maximum(tk, tp), scaled, -jnp.inf)
 
 
+def next_token_logits(logits, config: ModelConfig):
+    """What generation samples from: the head's logits, or prediction head
+    0's ``vocab_size`` of them where the head has several (head-major:
+    `ModelConfig.num_pred_heads`)."""
+    if config.num_pred_heads == 1:
+        return logits
+    return logits[..., : config.vocab_size]
+
+
 def sample_tokens(logits, keys, temps, top_ks, top_ps):
     """Per-row sampling with RUNTIME knobs: ``temps`` (0 = greedy),
     ``top_ks`` (0 = disabled), ``top_ps`` (>= 1 disabled).
@@ -355,6 +364,12 @@ class SlotPoolEngine:
                 "the dense slot pool keeps K and V heads a slot; a config "
                 "with latent attention is served by the paged engine "
                 "(ROADMAP: what cannot run yet)"
+            )
+        if config.eva_block:
+            raise ValueError(
+                "the dense slot pool keeps a row a position; a config with "
+                "chunked linear attention is served by the paged engine over "
+                "its summary-and-window cache (ROADMAP: what cannot run yet)"
             )
         if config.hybrid_block:
             raise ValueError(

@@ -67,6 +67,7 @@ from bpe_transformer_tpu.models.config import ModelConfig
 from bpe_transformer_tpu.models.decode import (
     cache_kind,
     chunk_cache,
+    eva_table_geometry,
     init_paged_pool,
     paged_forward,
     slot_cache,
@@ -79,6 +80,7 @@ from bpe_transformer_tpu.serving.engine import (
     default_prefill_buckets,
     filters_asked,
     gumbel_rows,
+    next_token_logits,
     prepare_serving_weights,
     sample_tokens,
 )
@@ -163,7 +165,8 @@ def _chunk_program(
     with jax.named_scope("key_split"):
         key, sub = jax.random.split(key)
     tok = sample_tokens(
-        logits, sub[None], temp[None], top_k[None], top_p[None]
+        next_token_logits(logits, config), sub[None], temp[None],
+        top_k[None], top_p[None],
     )[0]
     with jax.named_scope("carry_write"):
         tokens, positions, keys = carry
@@ -213,7 +216,8 @@ def _tick_program(
     else:
         # A vacant slot goes in as a greedy row: it asks for no search.
         nxt = sample_tokens(
-            out, subs, jnp.where(active, temps, 0.0), top_ks, top_ps
+            next_token_logits(out, config), subs,
+            jnp.where(active, temps, 0.0), top_ks, top_ps,
         )
     nxt = jnp.where(active, nxt, tokens)
     keys_next = jnp.where(active[:, None], keys_next, keys)
@@ -299,6 +303,12 @@ class PagedSlotInfo:
     #: metadata for /statusz and cross-replica tracing, like the dense
     #: engine's SlotInfo.request_id.
     request_id: str | None = None
+    #: Over a summary-and-window cache: how many of ``block_ids``, the
+    #: leading ones, are the open window's (the rest hold summaries, a
+    #: window's in a row), and the window the slot's table row is laid out
+    #: for (-1: not yet).
+    window_blocks: int = 0
+    window: int = -1
 
 
 @dataclasses.dataclass
@@ -317,6 +327,9 @@ class _Launch:
     #: A latent tick's ``(key positions x sublayers, member slots)`` of the
     #: shared pass; None without a latent pool.
     attn_shared: tuple | None = None
+    #: Over a summary-and-window cache a tick's ``(rows attended x layers,
+    #: those of them that are summaries)``; None otherwise.
+    attn_summary: tuple | None = None
     first: bool = False  # a final chunk: the token is its slot's first
 
 
@@ -419,6 +432,10 @@ class PagedEngine:
         #: State-space layers' recurrent state, a row a slot, beside the
         #: K/V blocks of the attention layers (`models/decode.RecurrentRows`).
         self.recurrent = config.hybrid_block
+        #: A summary-and-window cache (`models/decode.EvaRows`): a slot
+        #: holds its open window's blocks and a block of summaries for
+        #: every ``block_size`` blocks of the windows it has closed.
+        self.eva = config.eva_block
         if config.dropless_block and weight_dtype is not None:
             raise ValueError(
                 "weight_dtype quantizes the dense block's weight tree "
@@ -448,6 +465,16 @@ class PagedEngine:
                 'kv_dtype="int8"': kv_dtype is not None,
                 "fused_sampling": fused_sampling,
             })
+        if self.eva:
+            refuse("a summary-and-window cache", {
+                "prefix_cache=True (a cached chain's closed windows have no "
+                "exact rows left for a prompt that ends inside them)":
+                    prefix_cache,
+                'kv_dtype="int8" (a summary row shares no block scale with '
+                "the rows it pools)": kv_dtype is not None,
+                "fused_sampling (the fused tail projects the head's whole "
+                "width; generation samples prediction head 0)": fused_sampling,
+            })
         if self.grouped:
             refuse("window pool groups", {
                 "prefix_cache=True (the radix cache shares whole chains; a "
@@ -466,6 +493,24 @@ class PagedEngine:
         self.n_slots = slots
         self.block_size = block_size
         self.blocks_per_slot = ctx // block_size
+        #: Blocks the longest request holds at once: the table's width, or
+        #: over a summary-and-window cache a window and the summary blocks
+        #: of every window but the last (the table there also has room for
+        #: the open window's pending summaries).
+        self.max_chain = self.blocks_per_slot
+        if self.eva:
+            per_window, window_blocks, self.blocks_per_slot = (
+                eva_table_geometry(config, block_size)
+            )
+            self._eva_blocks = (per_window, window_blocks)
+            self.max_chain = self.blocks_per_slot - per_window
+            if prefill_chunk is None:
+                prefill_chunk = config.eva_window
+            if config.eva_window % min(prefill_chunk, ctx):
+                raise ValueError(
+                    f"prefill_chunk={prefill_chunk} must divide eva_window="
+                    f"{config.eva_window}: a chunk lies inside one window"
+                )
         if prefill_chunk is None:
             prefill_chunk = ctx
         if prefill_chunk < 1 or (
@@ -493,12 +538,18 @@ class PagedEngine:
         # would never compile anyway — the compile bound only shrinks).
         chunk_ladder = tuple(b for b in ladder if b < self.prefill_chunk)
         self.buckets = chunk_ladder + (self.prefill_chunk,)
+        if self.eva and any(b % block_size for b in self.buckets):
+            raise ValueError(
+                f"prefill buckets {self.buckets} must be multiples of "
+                f"block_size={block_size}: a chunk's whole blocks are "
+                "summarised from its rows"
+            )
 
         # Pool capacity: default exactly the dense slot pool's (every slot
         # can hold a full context) + the reserved trash block; prefix
         # sharing makes the same capacity serve MORE concurrent work.
         if num_blocks is None:
-            num_blocks = slots * self.blocks_per_slot + 1
+            num_blocks = slots * self.max_chain + 1
         self.allocator = BlockAllocator(num_blocks, block_size)
         #: The window group's allocator and, per slot, its chain.  The group
         #: is a reservation, not a knob: a chain holds at most window + one
@@ -583,6 +634,16 @@ class PagedEngine:
         #: window.
         self.attn_pairs = 0
         self.attn_kv_positions = 0
+        #: Over a summary-and-window cache: of the ticks' ``attn_kv_positions``
+        #: those that are summary rows; summary rows written by ticks and
+        #: chunks, x layers; windows closed (a table row laid out anew);
+        #: and the last tick's own ``(rows attended x layers, summaries of
+        #: them)`` (None over another kind: the ``tick`` record leaves them
+        #: out).  A chunk's attention adds to ``attn_pairs`` alone.
+        self.attn_summary_kv_positions = 0
+        self.eva_summary_rows = 0
+        self.eva_windows_closed = 0
+        self.last_tick_attn_summary = (0, 0) if self.eva else None
         #: Key positions the ticks' live slots held, and key positions
         #: their tables address (every slot's whole row, what a gather
         #: through the table reads): `tick_live_key_share`.
@@ -836,6 +897,14 @@ class PagedEngine:
         if self.latent:
             out["attn_shared_kv_positions"] = self.attn_shared_kv_positions
             out["attn_shared_slots"] = self.attn_shared_slots
+        if self.eva:
+            out["attn_summary_kv_positions"] = self.attn_summary_kv_positions
+            out["eva_summary_rows"] = self.eva_summary_rows
+            out["eva_windows_closed"] = self.eva_windows_closed
+            out["kv_summary_blocks_used"] = sum(
+                len(info.block_ids) - info.window_blocks
+                for info in self._slots if info is not None
+            )
         out["tick_attention_path"] = self.tick_attention_path
         out["tick_live_key_share"] = (
             100.0 * self.tick_live_keys / self.tick_table_keys
@@ -923,31 +992,64 @@ class PagedEngine:
         self._pool = out[pool_at] if isinstance(out, tuple) else out
         return out
 
-    def _refuse_grouped(self, what: str) -> None:
-        if self.grouped:
-            raise NotImplementedError(
-                f"{what} is not supported over window pool groups: a "
-                "recycled window block cannot be rolled back, copied or "
-                "shipped as part of a whole chain (ROADMAP: what cannot "
-                "run yet)"
-            )
+    # Why a cache kind (the engine's flag of that name) cannot take an
+    # operation written for one chain of K/V blocks of positions.
+    _REFUSALS = {
+        "grouped": (
+            "window pool groups: a recycled window block cannot be rolled "
+            "back, copied or shipped as part of a whole chain"
+        ),
+        "latent": (
+            "a latent pool: the migration wire ships K and V blocks of "
+            "heads, and a verify pass has no latent form"
+        ),
+        "recurrent": (
+            "a recurrent state: it is the state after a slot's last token "
+            "and of no earlier one, and the migration wire ships blocks of "
+            "positions"
+        ),
+        "eva": (
+            "a summary-and-window cache: a closed window's exact rows are "
+            "gone, so nothing rolls back across a closing, and the "
+            "migration wire ships one chain of positions"
+        ),
+    }
 
-    def _refuse_latent(self, what: str) -> None:
-        if self.latent:
-            raise NotImplementedError(
-                f"{what} is not supported over a latent pool: the migration "
-                "wire ships K and V blocks of heads, and a verify pass has "
-                "no latent form (ROADMAP: what cannot run yet)"
-            )
+    def _refuse(self, what: str, *kinds: str) -> None:
+        """Raise if this engine's cache is one of ``kinds`` (all by default)."""
+        for kind in kinds or self._REFUSALS:
+            if getattr(self, kind):
+                raise NotImplementedError(
+                    f"{what} is not supported over {self._REFUSALS[kind]} "
+                    "(ROADMAP: what cannot run yet)"
+                )
 
-    def _refuse_recurrent(self, what: str) -> None:
-        if self.recurrent:
-            raise NotImplementedError(
-                f"{what} is not supported over a recurrent state: it is "
-                "the state after a slot's last token and of no earlier one, "
-                "and the migration wire ships blocks of positions (ROADMAP: "
-                "what cannot run yet)"
-            )
+    def _enter_window(self, slot: int, position: int) -> None:
+        """Lay ``slot``'s table row out for the window ``position`` lies in:
+        the summary blocks of the windows before it, the window's blocks -
+        the same blocks window after window: a closed window's exact rows
+        are dropped, and counted as recycled - and the blocks its own
+        summaries are written to (trash where the request ends before the
+        window closes)."""
+        info = self._slots[slot]
+        window = position // self.config.eva_window
+        if window == info.window:
+            return
+        per_window, window_blocks = self._eva_blocks
+        held = info.window_blocks
+        summaries = info.block_ids[held:]
+        row = self._tables[slot]
+        row[:] = 0
+        visible = window * per_window
+        row[:visible] = summaries[:visible]
+        row[visible: visible + held] = info.block_ids[:held]
+        pending = summaries[visible: visible + per_window]
+        at = visible + window_blocks
+        row[at: at + len(pending)] = pending
+        if info.window >= 0:
+            self.eva_windows_closed += window - info.window
+            self._window_recycled += held
+        info.window = window
 
     def _advance_window(self, slot: int, lo_pos: int) -> None:
         """Recycle ``slot``'s window blocks that lie wholly below
@@ -1006,7 +1108,20 @@ class PagedEngine:
         ctx = self.config.context_length
         eff = min(max_new_tokens, ctx - prompt_len)
         span = min(prompt_len + eff, ctx)
+        if self.eva:
+            return sum(self._eva_chain(span))
         return -(-span // self.block_size)  # ceil
+
+    def _eva_chain(self, span: int) -> tuple[int, int]:
+        """``(window blocks, summary blocks)`` of a request of ``span``
+        positions over a summary-and-window cache: the blocks of its
+        longest window, and a window's summary blocks for every window it
+        lives to close."""
+        per_window, window_blocks = self._eva_blocks
+        return (
+            min(-(-span // self.block_size), window_blocks),
+            (span - 1) // self.config.eva_window * per_window,
+        )
 
     def _alloc_blocks(self, n: int) -> list:
         """Allocate ``n`` fresh blocks, evicting prefix-cache LRU leaves to
@@ -1025,9 +1140,7 @@ class PagedEngine:
         :meth:`rewind` returns whatever the acceptance didn't keep).
         Raises :class:`NoFreeBlocksError` when the pool is dry — the
         caller shrinks its speculation window instead of parking."""
-        self._refuse_grouped("extend_blocks (speculative scratch)")
-        self._refuse_latent("extend_blocks (speculative scratch)")
-        self._refuse_recurrent("extend_blocks (speculative scratch)")
+        self._refuse("extend_blocks (speculative scratch)")
         info = self._slots[slot]
         if info is None:
             raise ValueError(f"slot {slot} is not occupied")
@@ -1077,7 +1190,7 @@ class PagedEngine:
         primitive.  Unread launches are read first (:meth:`flush`): what
         they emitted is part of the frontier the caller rolls back from.
         """
-        self._refuse_grouped("rewind")
+        self._refuse("rewind", "grouped", "eva")
         self.flush()
         info = self._slots[slot]
         if info is None:
@@ -1090,7 +1203,7 @@ class PagedEngine:
                 f"{self.config.context_length}]"
             )
         if new_len < int(self._positions[slot]):
-            self._refuse_recurrent("rewind below the written frontier")
+            self._refuse("rewind below the written frontier", "recurrent")
         bs = self.block_size
         needed = -(-new_len // bs)
         floor = max(needed, keep_blocks or 0)
@@ -1153,9 +1266,7 @@ class PagedEngine:
         a speculative importer re-prefills its draft from) is merged into
         the payload meta.
         """
-        self._refuse_grouped("KV migration (export_slot)")
-        self._refuse_latent("KV migration (export_slot)")
-        self._refuse_recurrent("KV migration (export_slot)")
+        self._refuse("KV migration (export_slot)")
         tokens, positions, keys = self.read_carry()
         info = self._slots[slot]
         if info is None:
@@ -1227,9 +1338,7 @@ class PagedEngine:
         """Reject a payload this engine cannot graft — geometry or pool
         dtype mismatch is a configuration error, caught before any block
         is allocated (HTTP 400, not a half-grafted slot)."""
-        self._refuse_grouped("KV migration (import_slot)")
-        self._refuse_latent("KV migration (import_slot)")
-        self._refuse_recurrent("KV migration (import_slot)")
+        self._refuse("KV migration (import_slot)")
         if meta.get("format") != 1:
             raise ValueError(
                 f"unsupported payload format {meta.get('format')!r}"
@@ -1460,8 +1569,9 @@ class PagedEngine:
                 raise
             self._write_window_row(slot)
         block_ids = matched + fresh
-        self._tables[slot, : len(block_ids)] = block_ids
-        self._tables[slot, len(block_ids):] = 0
+        if not self.eva:  # `_enter_window` lays such a row out, below
+            self._tables[slot, : len(block_ids)] = block_ids
+            self._tables[slot, len(block_ids):] = 0
 
         shared_len = len(matched) * self.block_size
         if self.prefix_cache is not None:
@@ -1486,6 +1596,11 @@ class PagedEngine:
             request_id=request_id,
         )
         self._slots[slot] = info
+        if self.eva:
+            info.window_blocks = self._eva_chain(
+                min(plen + max_new_tokens, ctx)
+            )[0]
+            self._enter_window(slot, 0)
         self._prefilling.append(slot)
         return slot
 
@@ -1573,6 +1688,21 @@ class PagedEngine:
                     slot, info.next_pos - self.config.sliding_window + 1
                 )
                 self._count_attention(info.next_pos, info.next_pos + chunk_len)
+            if self.eva:
+                self._enter_window(slot, info.next_pos)
+                width, per_chunk = (
+                    self.config.eva_window, self.config.eva_chunk
+                )
+                summaries = (
+                    info.next_pos // width * self.config.eva_chunks_per_window
+                )
+                first = summaries + info.next_pos % width
+                self.attn_pairs += self._attn_sublayers * (
+                    chunk_len * first + chunk_len * (chunk_len + 1) // 2
+                )
+                self.eva_summary_rows += self._attn_sublayers * (
+                    chunk_len // per_chunk
+                )
             if self.recurrent:
                 self.ssm_chunk_tokens += self._ssm_layers * chunk_len
                 self.ssm_chunk_rows += self._ssm_layers * bucket
@@ -1686,6 +1816,25 @@ class PagedEngine:
                         )
                 # One query a live slot: pairs and KV positions are alike.
                 seen = self._positions[live].astype(np.int64) + 1
+                attn_summary = None
+                if self.eva:
+                    # The summaries of the windows a slot has closed, then
+                    # its open window up to the row: what `EvaRows` attends.
+                    width = self.config.eva_window
+                    for slot in live:
+                        self._enter_window(int(slot), int(self._positions[slot]))
+                    at = seen - 1
+                    summaries = at // width * self.config.eva_chunks_per_window
+                    seen = summaries + at % width + 1
+                    per_chunk = self.config.eva_chunk
+                    self.eva_summary_rows += self._attn_sublayers * int(
+                        np.count_nonzero(at % per_chunk == per_chunk - 1)
+                    )
+                    attn_summary = (
+                        self._attn_sublayers * int(seen.sum()),
+                        self._attn_sublayers * int(summaries.sum()),
+                    )
+                    self.attn_summary_kv_positions += attn_summary[1]
                 keys_live = int(seen.sum())
                 keys_read = (
                     self._attn_sublayers - self._window_layers
@@ -1748,7 +1897,7 @@ class PagedEngine:
                 self._unread.append(_Launch(
                     tokens,
                     tuple(zip(live.tolist(), self._tenant[live].tolist())),
-                    moe, ssm_state_rows, attn_shared,
+                    moe, ssm_state_rows, attn_shared, attn_summary,
                 ))
                 self.ticks += 1
                 self._positions[live] += 1
@@ -1773,6 +1922,7 @@ class PagedEngine:
             if not launch.first:
                 self.last_tick_ssm_state_rows = launch.ssm_state_rows
                 self.last_tick_attn_shared = launch.attn_shared
+                self.last_tick_attn_summary = launch.attn_summary
             held = self._tenant.tolist()  # a release bumps its own slot only
             for slot, tenant in launch.rows:
                 if held[slot] != tenant:

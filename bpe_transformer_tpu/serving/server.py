@@ -294,6 +294,20 @@ class ServingEngine:
                 "heads) are not supported over a latent pool (ROADMAP: what "
                 "cannot run yet)"
             )
+        if config.eva_block and not paged:
+            raise ValueError(
+                "a config with chunked linear attention is served by the "
+                "paged engine (paged=True): its summary-and-window cache "
+                "lives there"
+            )
+        if config.eva_block and (speculate_k or role != "both"):
+            raise ValueError(
+                "speculative decoding (a verify pass of several rows a slot "
+                "would straddle a window's closing) and the prefill/decode "
+                "roles (KV migration ships one chain of positions) are not "
+                "supported over a summary-and-window cache (ROADMAP: what "
+                "cannot run yet)"
+            )
         if config.hybrid_block and not paged:
             raise ValueError(
                 "a config with state-space layers is served by the paged "
@@ -1513,6 +1527,14 @@ class ServingEngine:
             "attn_shared_kv_positions": attn_shared[0],
             "attn_shared_slots": attn_shared[1],
         }
+        # Rows x layers the tick attended over a summary-and-window cache,
+        # and those of them that are summaries.
+        attn_summary = getattr(self.engine, "last_tick_attn_summary", None)
+        if attn_summary is not None:
+            shared_pass = {
+                "attn_kv_positions": attn_summary[0],
+                "attn_summary_kv_positions": attn_summary[1],
+            }
         self._telemetry.emit(
             {
                 "kind": "tick",

@@ -265,9 +265,9 @@ class SpecEngine(PagedEngine):
                 f"speculate_k must be >= 1, got {speculate_k}"
             )
         super().__init__(params, config, min_bucket=min_bucket, **paged_kwargs)
-        self._refuse_grouped("speculative decoding (its verify pass rewinds)")
-        self._refuse_latent("speculative decoding (its verify pass)")
-        self._refuse_recurrent("speculative decoding (its verify pass rewinds)")
+        self._refuse("speculative decoding (its verify pass rewinds)", "grouped")
+        self._refuse("speculative decoding (its verify pass)", "latent")
+        self._refuse("speculative decoding (its verify pass rewinds)", "recurrent")
         # This engine's tick is the verify pass: several rows a slot.
         self.tick_attention_path = cache_kind(config).attention_path(
             config, False, self.blocks_per_slot, self._pool[0]

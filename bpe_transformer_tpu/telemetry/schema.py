@@ -96,7 +96,9 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # ``attn_shared_kv_positions`` / ``attn_shared_slots``: key positions x
     # sublayers that tick's slots attended through the latent kernels'
     # shared pass, and the slots on the shared chain (over a latent pool
-    # alone).
+    # alone); optional ``attn_kv_positions`` / ``attn_summary_kv_positions``:
+    # cached rows x layers that tick attended and those of them that are
+    # chunk summaries (over a summary-and-window cache alone).
     "tick": {
         "kind", "t", "dur_s", "admit_s", "prefill_s", "chunks",
         "prefill_tokens", "dispatch_s", "wait_s", "emit_s", "deliver_s",
@@ -194,7 +196,16 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # ``attn_shared_kv_positions`` (of ``attn_kv_positions``, those the
     # ticks' slots attended through the shared pass: the chain of blocks
     # several slots' rows start with, attended once for all of them) and
-    # ``attn_shared_slots`` (slots on the chain, summed over ticks); and
+    # ``attn_shared_slots`` (slots on the chain, summed over ticks); over a
+    # summary-and-window cache alone ``attn_summary_kv_positions`` (of the
+    # ticks' ``attn_kv_positions``, the rows that are chunk summaries),
+    # ``eva_summary_rows`` (summaries written by ticks and chunks, x
+    # layers), ``eva_windows_closed`` (a slot's table row laid out anew)
+    # and the gauge ``kv_summary_blocks_used`` (there ``attn_pairs`` holds
+    # the chunks' pairs too, ``kv_bytes_per_token`` is one cached ROW over
+    # the layers - a position's or a chunk's summary - and
+    # ``kv_window_blocks_recycled`` counts a closed window's blocks, which
+    # the slot keeps for its next window); and
     # the two dispatch phases
     # in parts (ISSUE 38; ``paged_engine.LAUNCH_PARTS``), clock seconds
     # summed over every launch: ``launch_tick_{prepare,call,after}_s`` of

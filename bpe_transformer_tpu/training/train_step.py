@@ -72,7 +72,8 @@ def make_loss_fn(
             "training is not supported for this block (a layer pattern, the "
             "parallel block, LayerNorm, sigmoid routing, shared or held "
             "experts; latent attention, the double layer, zero experts; "
-            "state-space layers, whose scan has no backward): it "
+            "state-space layers, whose scan has no backward; chunked linear "
+            "attention): it "
             "has no load-balance loss and no backward-tested "
             "path; it is served and checked against its plain forward only "
             "(ROADMAP: what cannot run yet)"

@@ -322,12 +322,13 @@ def _described(tree, one_chip):
 
 def _pool_program(
     name, config, one_chip, kv_dtype, layers_as_calls=True,
-    slots=POOL_SLOTS, blocks=POOL_BLOCKS,
+    slots=POOL_SLOTS, blocks=POOL_BLOCKS, bucket=256,
 ):
     """``(jitted program, its arguments described on the chip, the pool)``
     for one of the engine's pool programs, jitted as `PagedEngine` jits it
     on the TPU (``layers_as_calls=False``: without the compiler option),
-    over ``slots`` slots and a pool of ``blocks`` blocks."""
+    over ``slots`` slots and a pool of ``blocks`` blocks; a chunk is of
+    ``bucket`` rows."""
     import functools
 
     from bpe_transformer_tpu.utils.compile_cache import layered_program_options
@@ -341,6 +342,10 @@ def _pool_program(
 
     bs = POOL_BLOCK
     nbs = config.context_length // bs
+    if config.eva_block:  # summary blocks, then a window's: `EvaRows`
+        from bpe_transformer_tpu.models.decode import eva_table_geometry
+
+        nbs = eva_table_geometry(config, bs)[2]
 
     def weights():
         params = init_params(jax.random.PRNGKey(0), config)
@@ -398,7 +403,7 @@ def _pool_program(
         if config.hybrid_block:  # a chunk addresses its slot's state by id
             table_row = {"blocks": table_row, "slot": scalar}
         args = (
-            params, lm_head, pool, moe, table_row, arr((1, 256), I32),
+            params, lm_head, pool, moe, table_row, arr((1, bucket), I32),
             scalar, scalar, arr((2,), jnp.uint32), arr((), F32), scalar,
             arr((), F32), (tokens, positions, keys), scalar,
             arr((), jnp.bool_),
@@ -977,3 +982,68 @@ def test_recurrent_pool_programs(one_chip, on_tpu, name):
     assert _pool_copies(text, {"f32[9,128,64,128]", "bf16[2049,16,1024]"}) == []
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in leaves)
+
+
+# ------------------------------------------- the summary-and-window cache
+
+
+def _evabyte_config(**cut):
+    import json
+    from pathlib import Path
+
+    from bpe_transformer_tpu.models.config import ModelConfig
+
+    path = Path(__file__).resolve().parents[1] / "chipbench/configs/EvaByte.json"
+    file = json.loads(path.read_text())
+    return ModelConfig(**{**{k: file[k] for k in file["architecture_keys"]}, **cut})
+
+
+def test_paged_decode_attention_at_evabyte_widths(one_chip):
+    """The summary-and-window tick: 32 query heads over 32 K/V heads of 128
+    (rows of 4,096 lanes, where gpt2 has 768 and granite 1,024), 32 slots, a
+    table of 16 x 8 summary blocks and a window's 128."""
+
+    def fn(q, k, v, tables, counts):
+        return paged_decode_attention(q, k, v, tables, counts, interpret=False)
+
+    pool = ((5825, 16, 32 * 128), BF16)
+    _compile(
+        fn, one_chip, ((32, 32, 128), BF16), pool, pool, ((32, 256), I32),
+        ((32,), I32),
+    )
+
+
+@pytest.mark.parametrize(
+    "name, bucket", [("tick", 0), ("chunk", 2048), ("chunk", 512)],
+    ids=["tick", "chunk2048", "chunk512"],
+)
+def test_evabyte_pool_programs(one_chip, on_tpu, name, bucket):
+    """The engine's two programs over the summary-and-window cache at the
+    cell's widths - 32 slots, a pool of 5,825 blocks, chunks of the cell's
+    largest and smallest bucket - cut to 2 of its 8 layers (every layer is
+    the same block): the tick attends through the dense pool's paged kernel
+    and no other Mosaic call, the chunk attends and summarises in XLA, the
+    pool is aliased whole and never copied, and neither program's
+    temporaries come near what the chip has beside weights and pool (the
+    tick's stay under 64 MB, a 2,048-row chunk's under 512 MB: the full
+    cell holds 3.26 GB of weights and 12.22 GB of pool of the chip's 16.9:
+    15.9 GB with a chunk's temporaries, and the chip ran it)."""
+    config = _evabyte_config(num_layers=2)
+    jitted, args, pool = _pool_program(
+        name, config, one_chip, None, slots=32, blocks=5825, bucket=bucket or 256
+    )
+    compiled = jitted.lower(*args).compile()
+    text = compiled.as_text()
+    calls = " ".join(l.split("=")[0] for l in text.splitlines() if " custom-call(" in l)
+    assert ("paged_decode_attention" in calls) == (name == "tick")
+    assert [line for line in _sorts(text) if f",{config.head_width}]" in line] == []
+    leaves = jax.tree_util.tree_leaves(pool)
+    assert {_shape_text(a) for a in leaves} == {"bf16[5825,16,4096]"}
+    assert _pool_copies(text, {"bf16[5825,16,4096]"}) == []
+    memory = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in leaves)
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < (64 if name == "tick" else 512) * 2**20
+    # All eight layers of pool, the weights and the temporaries fit the chip.
+    weights = 2 * (8 * 202_391_552 + 11_800_576)
+    assert 4 * pool_bytes + weights + memory.temp_size_in_bytes < 16.0e9

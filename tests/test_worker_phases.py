@@ -577,13 +577,30 @@ def test_same_records_with_and_without_a_session(traced, served, byte_data):
     # differ between two runs; the tokens they emit may not.
     assert sum(t["batch"] for t in traced_ticks) == sum(t["batch"] for t in ticks)
     # The engine record and what rides its cadence come by the clock; every
-    # other record comes by the work, with a session or without.
+    # other record comes by the work, with a session or without.  The
+    # watchdog is fed at each engine record, so its alerts, and the
+    # black-box dump an alert triggers, come by the clock too: they are
+    # compared apart, below (ROADMAP D13).
     by_clock = {"engine", "resources", "roofline", "kvpool", "tick"}
+    by_watchdog = {"alert", "blackbox"}
 
     def by_work(stream):
-        return sorted(k for k in (r["kind"] for r in stream) if k not in by_clock)
+        return sorted(
+            k for k in (r["kind"] for r in stream)
+            if k not in by_clock | by_watchdog
+        )
 
     assert by_work(records) == by_work(plain)
+    # On a slow host the decode phase spans enough engine records for the
+    # one rule that extrapolates a gauge over time to fire, in either run:
+    # the tiny pool's free blocks fall through the run's ticks and
+    # `block_exhaustion` projects it dry.  No other rule may fire in either
+    # run, a dump comes only with an alert, and a session changes neither.
+    for stream in (records, plain):
+        alerts = [r for r in stream if r["kind"] == "alert"]
+        assert {r["rule"] for r in alerts} <= {"block_exhaustion"}
+        dumps = sum(r["kind"] == "blackbox" for r in stream)
+        assert dumps <= sum(r["state"] == "firing" for r in alerts)
     untraced = train_some(byte_data)
     assert [r["loss"] for r in summary["history"]] == [
         r["loss"] for r in untraced["history"]

@@ -112,8 +112,18 @@ DEFAULT_BUDGET_S = 800.0
 #: worker's off-CPU seconds, a forced collection in its period, a held
 #: interpreter lock showing as off-CPU seconds (tests/test_worker_phases.py,
 #: 15 cases in about 20 s) and the same parts from every cache kind
-#: (tests/test_launch_ahead.py, 4 cases, 3-7 s each).
-DEFAULT_MAX_TESTS = 1075
+#: (tests/test_launch_ahead.py, 4 cases, 3-7 s each).  Raised 1075 -> 1150
+#: in PR 40 (1,123 collected, 57 added): EvaByte's block against its
+#: reference on every path - the whole sequence, paged chunks and ticks
+#: across window closings with a slot mid-prefill, ticks alone, every row of
+#: a chunk, the paged kernel interpreted, a slot's next tenant - the
+#: visibility rule, the blocks and counters and each refusal
+#: (tests/test_evabyte.py, 53 cases in about 100 s in one process), and the
+#: paged decode kernel at its widths and the tick and two chunk programs
+#: over the summary-and-window cache compiled for the described v5e
+#: (tests/test_chip_compile.py, 4 cases, 3-9 s each); the whole run 468 s
+#: with six workers.
+DEFAULT_MAX_TESTS = 1150
 
 #: Pytest summary trailer: "== 398 passed, 27 deselected in 612.34s =="
 #: (also plain "in 612.34s (0:10:12)" forms).
