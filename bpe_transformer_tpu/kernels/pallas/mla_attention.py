@@ -13,11 +13,12 @@ What latent attention caches is one row a position and sublayer,
   is applied to the ``rank``-wide output afterwards.  A cached position
   costs its own bytes and nothing is expanded: the form of a decode tick,
   one query row a slot against thousands of positions.
-* **expanded**: keys and values of every head are expanded from the latent
-  rows and attended as plain heads.  A (query, key) pair costs ``2 x (192 +
-  128)`` FLOPs a head so against ``2 x (576 + 512)`` absorbed; it is the
-  reference's form (``chipbench/reference_longcatflash.py``) and nowhere in
-  the program.
+* **expanded** (:func:`mla_chunk_attention`): keys and values of every head
+  are expanded from the latent rows and attended as plain heads.  A (query,
+  key) pair costs ``2 x (192 + 128)`` FLOPs a head so against ``2 x (576 +
+  512)`` absorbed, and a key costs ``2 x 512 x 256`` a head once to expand:
+  the form of many query rows, and the reference's
+  (``chipbench/reference_longcatflash.py``).
 
 On the TPU the tick's form is two Pallas kernels that copy blocks through
 the block table straight out of the pool, as
@@ -35,13 +36,34 @@ shared is read from the tables a tick (:func:`shared_prefix`: no flag, no
 knob); with nothing shared the first kernel walks no group and the second
 is the whole walk.  Exact attention in another order (Hydragen, Juravsky et
 al. 2024; FlashInfer's cascade attention).  Elsewhere the tick's form is a
-gather and a masked softmax in XLA.  Many query rows of one sequence
-(:func:`xla_mla_chunk_attention`: a chunk that resumes after a cached
-prefix, a dense cache's prefill, the plain forward) run in XLA on every backend, a loop over key blocks whose
-trip count follows the last position, absorbed too: on the v5e 15.9 ms
-against 18.3-21.3 expanded for 1,024 rows after 8,192 positions, for all
-its 2.4 times the FLOPs - 64 heads' keys of 192 make small matrix
-products, one shared row of 576 a large one (PERF.md section 6, PR 33).
+gather and a masked softmax in XLA.
+
+Many query rows of one sequence (a chunk that resumes after a cached prefix,
+a dense cache's prefill, the plain forward) take either form, chosen from
+the shapes and the backend in one place (:func:`mla_chunk_path`, asked by
+`models/mla.rows_attention`).  A bucket of `MLA_CHUNK_MIN_ROWS` rows or more
+on the TPU, at widths of whole lane tiles, attends **expanded** inside one
+Pallas kernel (``mla_chunk_attention.N``): a grid step takes one head and
+one block of latent rows, makes that head's keys and values of the block in
+VMEM, scores, masks only the blocks that reach into the chunk and folds
+into a float32 softmax state - neither K, V nor a score tile reaches HBM,
+and blocks past the last position are neither copied nor computed.
+Everything else (every other backend, unaligned test shapes, a short chunk)
+runs :func:`xla_mla_chunk_attention`, the **absorbed** flash loop in XLA
+whose trip count follows the last position.  On the v5e, one sublayer after
+8,192 cached positions (PERF.md section 6, PR 41; 64 heads, bfloat16):
+
+    query rows          128     256     512     1,024
+    absorbed loop, ms   1.11    2.37    5.92    15.84
+    expanded kernel     1.62    1.90    2.65     4.16
+
+and 2,048 rows from position 0 8.22 against 1.96.  The kernel's step is
+7.1 us at 1,024 rows x 1,024 keys (1.07 GFLOP as the MXU sees it: 77% of
+its peak); the loop's is the same product as the tick's shared pass, whose
+own kernel holds 58-60% (PR 39), so an absorbed kernel could not have
+caught up: the FLOPs had to go.  (PR 33 had read the two forms as two XLA
+programs, 15.9 ms absorbed against 18.3-21.3 expanded: expanded *in XLA*
+writes 64 heads' K and V and float32 score tiles to HBM.)
 """
 
 from __future__ import annotations
@@ -80,8 +102,21 @@ MLA_SHARED_VMEM_BYTES = 64 * 1024 * 1024
 MLA_SHARED_MIN_SLOTS = 6
 #: Lanes behind a softmax state's accumulator for its maximum and its sum.
 _STATE_LANES = 128
-#: Keys a step of the chunk's loop scores and folds into its softmax.
+#: Keys a step of the chunk's loop, or of its kernel, scores and folds into
+#: its softmax.  The kernel on the v5e, 1,024 rows after 8,192 positions:
+#: 4.46 ms a call at 512, 4.08 at 1,024, 4.50 at 2,048; 512 rows: 2.59,
+#: 2.52, 2.64 (PERF.md section 6, PR 41).
 MLA_CHUNK_KEY_BLOCK = 1024
+#: Query rows a step of the chunk's kernel holds against a block of keys: a
+#: block is expanded once a head and tile, so the tile is the whole bucket
+#: up to here (a 1,024 x 1,024 float32 score tile and its probabilities are
+#: 10 MB, as the shared pass's).
+MLA_CHUNK_TILE_ROWS = 1024
+#: Fewest query rows for which a chunk attends in the expanded form: by
+#: FLOPs the forms break even at ~170 rows a key.  On the v5e after 8,192
+#: positions, kernel against loop: 128 rows 1.62 against 1.11 ms, 256 rows
+#: 1.90 against 2.37, 512 rows 2.65 against 5.92 (PERF.md section 6, PR 41).
+MLA_CHUNK_MIN_ROWS = 256
 
 
 # ------------------------------------------------------------ absorbed, XLA
@@ -644,3 +679,187 @@ def xla_mla_chunk_attention(
     )
     out = (acc / jnp.maximum(l, 1e-30)).astype(q_nope.dtype)
     return jnp.einsum("hqc,hdc->hqd", out, kv_b[:, nope:])
+
+
+# ------------------------------------------- many query rows, expanded, kernel
+
+
+def _mla_chunk_kernel(
+    limits_ref, clear_ref, q_ref, pos_ref, rows_ref, w_ref, o_ref, m_run,
+    l_run, acc, *, scale: float, block: int, rank: int, nope: int,
+):
+    """One head's queries of one tile against one block of latent rows a
+    grid step (tile, head, key block; the key blocks innermost, under one
+    softmax state): the block's keys and values of this head are expanded
+    from its latent rows here, in VMEM, scored, and folded into ``(m_run,
+    l_run, acc)``; the last step normalises.  ``limits_ref[tile]`` is how
+    many leading keys any of the tile's queries sees - blocks past it are
+    neither copied (the block spec holds the last live block) nor computed
+    - and ``clear_ref[tile]`` how many every one of them sees: a block
+    wholly below it takes no mask."""
+    tile, step = pl.program_id(0), pl.program_id(2)
+
+    @pl.when(step == 0)
+    def _open():
+        m_run[...] = jnp.full_like(m_run, NEG_INF)
+        l_run[...] = jnp.zeros_like(l_run)
+        acc[...] = jnp.zeros_like(acc)
+
+    @pl.when(step * block < limits_ref[tile])
+    def _fold():
+        rows = rows_ref[...]                        # (block, rank + rope')
+        kv = jax.lax.dot_general(
+            rows[:, :rank], w_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ).astype(rows.dtype)                        # (block, nope + v)
+        # A head's key: its own ``nope`` values beside the one rotated key
+        # all heads share, as the query's two parts lie.
+        k = jnp.concatenate([kv[:, :nope], rows[:, rank:]], axis=-1)
+        s = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale                                   # (queries, block)
+
+        def fold(s):
+            m_prev = m_run[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_run[...] = l_run[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc[...] = acc[...] * alpha + jax.lax.dot_general(
+                p.astype(kv.dtype), kv[:, nope:], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_run[...] = m_new
+
+        every_row_sees = (step + 1) * block <= clear_ref[tile]
+
+        @pl.when(every_row_sees)
+        def _():
+            fold(s)
+
+        @pl.when(jnp.logical_not(every_row_sees))
+        def _():
+            key_pos = step * block + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1
+            )
+            fold(jnp.where(key_pos <= pos_ref[...], s, NEG_INF))
+
+    @pl.when(step == pl.num_programs(2) - 1)
+    def _close():
+        o_ref[0] = (acc[...] / jnp.maximum(l_run[...], 1e-30)).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "block", "tile_rows", "interpret")
+)
+def _mla_chunk_impl(
+    q, rows, kv_b, q_positions, n_keys, scale, block, tile_rows, interpret
+):
+    heads, queries, q_width = q.shape
+    keys, width = rows.shape
+    rank = kv_b.shape[-1]
+    nope = q_width - (width - rank)
+    v = kv_b.shape[1] - nope
+    tiles = queries // tile_rows
+    positions = q_positions.astype(jnp.int32).reshape(tiles, tile_rows)
+    limits = jnp.minimum(jnp.int32(n_keys), positions.max(axis=1) + 1)
+    clear = positions.min(axis=1) + 1
+
+    def live(step, limits, tile):
+        """The block a step reads: its own, or the tile's last live one
+        again (no new copy) once past what the tile sees."""
+        return jnp.minimum(step, jnp.maximum(pl.cdiv(limits[tile], block) - 1, 0))
+
+    return pl.pallas_call(
+        functools.partial(
+            _mla_chunk_kernel, scale=scale, block=block, rank=rank, nope=nope
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(tiles, heads, keys // block),
+            in_specs=[
+                pl.BlockSpec(
+                    (1, tile_rows, q_width), lambda t, h, s, *_: (h, t, 0)
+                ),
+                pl.BlockSpec((tile_rows, 1), lambda t, h, s, *_: (t, 0)),
+                pl.BlockSpec(
+                    (block, width),
+                    lambda t, h, s, limits, clear: (live(s, limits, t), 0),
+                ),
+                pl.BlockSpec(
+                    (1, nope + v, rank), lambda t, h, s, *_: (h, 0, 0)
+                ),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, tile_rows, v), lambda t, h, s, *_: (h, t, 0)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((tile_rows, 1), jnp.float32),   # running maximum
+                pltpu.VMEM((tile_rows, 1), jnp.float32),   # running sum
+                pltpu.VMEM((tile_rows, v), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((heads, queries, v), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=MLA_SHARED_VMEM_BYTES,
+        ),
+        interpret=interpret,
+        name="mla_chunk_attention",
+    )(limits, clear, q, positions.reshape(-1, 1), rows, kv_b)
+
+
+def mla_chunk_path(
+    queries: int, nope: int, v: int, rank: int, backend: str | None = None
+) -> str:
+    """``"mla_chunk"``, the expanded form in the kernel, on the TPU for a
+    bucket of `MLA_CHUNK_MIN_ROWS` query rows or more in whole tiles, where
+    a head's key, its value and the latent are whole lane tiles (the rotated
+    key is padded to them, as the pool pads it); ``"xla"``, the absorbed
+    loop, elsewhere (every other backend, where the kernel would run in
+    interpret mode; unaligned test shapes; a short chunk, whose keys'
+    expansion would cost more than the absorbed form's wider rows)."""
+    backend = backend or jax.default_backend()
+    tile_rows = min(MLA_CHUNK_TILE_ROWS, queries)
+    if (
+        backend == "tpu" and queries >= MLA_CHUNK_MIN_ROWS
+        and tile_rows % 128 == 0 and queries % tile_rows == 0
+        and nope % 128 == 0 and v % 128 == 0 and rank % 128 == 0
+    ):
+        return "mla_chunk"
+    return "xla"
+
+
+@jax.named_scope("mla_chunk_attn")
+def mla_chunk_attention(
+    q_nope, q_rope, rows, kv_b, q_positions, n_keys, *, scale: float,
+    interpret: bool | None = None,
+):
+    """`xla_mla_chunk_attention`'s contract in the **expanded** form, one
+    Pallas kernel: every head's keys and values of a block of latent rows
+    are made from the rows where they are attended, in VMEM, and neither
+    they nor a score reach HBM.  ``rows`` may come as a pool pads them
+    (zeros up to whole lane tiles past ``rank + rope``); the queries' shapes
+    are `mla_chunk_path`'s to admit."""
+    queries = q_nope.shape[1]
+    rank, rope = kv_b.shape[-1], q_rope.shape[-1]
+    lanes = -(-rope // 128) * 128
+    keys = rows.shape[0]
+    block = min(MLA_CHUNK_KEY_BLOCK, -(-keys // 128) * 128)
+    # Whole blocks of whole lane tiles: a row past the last position is
+    # masked like any other, and a padded lane meets a zero of the query.
+    rows = jnp.pad(
+        rows, ((0, -keys % block), (0, max(rank + lanes - rows.shape[1], 0)))
+    )[:, : rank + lanes]
+    q = jnp.concatenate(
+        [q_nope, jnp.pad(q_rope, ((0, 0), (0, 0), (0, lanes - rope)))], axis=-1
+    )
+    if interpret is None:
+        from bpe_transformer_tpu.kernels.pallas.runtime import interpret_mode
+
+        interpret = interpret_mode()
+    return _mla_chunk_impl(
+        q, rows, kv_b, q_positions, n_keys, float(scale), block,
+        min(MLA_CHUNK_TILE_ROWS, queries), interpret,
+    )

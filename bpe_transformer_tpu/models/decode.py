@@ -1118,10 +1118,13 @@ class LatentRows(_RoutingCounts):
     TPU two kernels, the chain of blocks that the tick's slots share
     attended once for all of them and each slot's own blocks after it -
     which blocks those are it reads from the tables, `shared_prefix` - and
-    gathered rows elsewhere); a chunk's rows attend,
-    absorbed too, over the slot's gathered chain, the prefix an earlier
-    request wrote included, in a loop that follows the chunk's last position
-    (`xla_mla_chunk_attention`, which says why absorbed).  Several rows a
+    gathered rows elsewhere); a chunk's rows attend over the slot's
+    gathered chain, the prefix an earlier request wrote included, as far as
+    the chunk's last position - a bucket of 256 rows or more on the TPU in
+    the expanded form inside one kernel, each block of rows up-projected
+    where it is attended, anything else in the absorbed loop
+    (`mla.rows_attention` chooses; `kernels/pallas/mla_attention.py` says
+    why, with both forms' times).  Several rows a
     slot (a verify pass) have no form here.  Routing counts of the expert
     layers ride along as in `GroupedPages`."""
 
@@ -1190,7 +1193,6 @@ class LatentRows(_RoutingCounts):
         written = write(rows[0])
         with jax.named_scope("pool_gather"):
             chain = written[self.tables].reshape(-1, written.shape[-1])
-            chain = chain[:, : config.latent_width]
         att = mla.rows_attention(
             q_nope[0], q_rope[0], chain, attn, self.positions,
             start + chunk_len, config,

@@ -16,15 +16,19 @@ k_r) / sqrt(nope + rope)`` and a causal float32 softmax over ``v``.
 
 **What is cached** is the *latent row* ``[c ; rope(k_r)]``
 (``config.latent_width`` values a position, no heads): :func:`latent_rows`.
-Every path writes it and attends it **absorbed**
-(`kernels/pallas/mla_attention.py`): the key up-projection folded into the
-query, the value's applied to the output, the rows attended as they are - a
-decode step's one row against a long cache (:func:`absorb_query` /
-:func:`unabsorb_output` around the cache's own attention) and many rows of
-one sequence (:func:`rows_attention`: a paged chunk after a cached prefix, a
-dense cache's prefill, the plain forward).  The *expanded* form, keys and
-values of every head from the rows, is the same sum and the reference's;
-``tests/test_longcatflash.py`` holds every path to it.
+Every path writes it.  A decode step's one row against a long cache attends
+it **absorbed** (`kernels/pallas/mla_attention.py`): the key up-projection
+folded into the query, the value's applied to the output, the rows attended
+as they are (:func:`absorb_query` / :func:`unabsorb_output` around the
+cache's own attention).  Many rows of one sequence (:func:`rows_attention`:
+a paged chunk after a cached prefix, a dense cache's prefill, the plain
+forward) attend **expanded** - keys and values of every head made from the
+rows, inside one kernel - where the bucket is 256 rows or more on the TPU
+and the widths are whole lane tiles, and absorbed in an XLA loop elsewhere
+(`mla_attention.mla_chunk_path`: the expanded form does 2.2 times fewer
+FLOPs from ~170 query rows a key on).  Both are the same sum, the expanded
+order the reference's; ``tests/test_longcatflash.py`` holds every path to
+it.
 """
 
 from __future__ import annotations
@@ -118,17 +122,39 @@ def softmax_scale(config: ModelConfig) -> float:
     return config.d_head ** -0.5
 
 
+def rows_attention_path(queries: int, config: ModelConfig) -> str:
+    """The form ``queries`` rows of one sequence attend in, ``"mla_chunk"``
+    (expanded, the kernel) or ``"xla"`` (the absorbed loop):
+    `mla_attention.mla_chunk_path` at the config's widths.  One rule for
+    :func:`rows_attention` and for the engine's counters."""
+    from bpe_transformer_tpu.kernels.pallas.mla_attention import mla_chunk_path
+
+    return mla_chunk_path(
+        queries, config.qk_nope_head_dim, config.v_head_dim, config.kv_lora_rank
+    )
+
+
 def rows_attention(
     q_nope, q_rope, rows, p: dict, q_positions, n_keys, config: ModelConfig
 ) -> Array:
     """One sequence's queries (heads, queries, .) against its latent
-    ``rows`` (keys, latent_width) from position 0: (queries, heads * v),
-    before the output projection."""
+    ``rows`` (keys, latent_width, or as a pool pads them: zeros past it)
+    from position 0: (queries, heads * v), before the output projection.
+    The form is chosen here, from the shapes and the backend
+    (`mla_attention.mla_chunk_path`): a bucket of many rows on the TPU
+    expands each block of rows into every head's keys and values inside one
+    kernel, everything else folds the up-projections into query and output
+    and loops over the rows as they lie."""
     from bpe_transformer_tpu.kernels.pallas.mla_attention import (
+        mla_chunk_attention,
         xla_mla_chunk_attention,
     )
 
-    out = xla_mla_chunk_attention(
+    if rows_attention_path(q_nope.shape[1], config) == "mla_chunk":
+        attend = mla_chunk_attention
+    else:
+        attend, rows = xla_mla_chunk_attention, rows[:, : config.latent_width]
+    out = attend(
         q_nope, q_rope, rows, _kv_b(p, config), q_positions, n_keys,
         scale=softmax_scale(config),
     )

@@ -63,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bpe_transformer_tpu.kernels.pallas import mla_attention
+from bpe_transformer_tpu.models import mla
 from bpe_transformer_tpu.models.config import ModelConfig
 from bpe_transformer_tpu.models.decode import (
     cache_kind,
@@ -667,6 +668,18 @@ class PagedEngine:
         self.attn_shared_kv_positions = 0
         self.attn_shared_slots = 0
         self.last_tick_attn_shared = (0, 0) if self.latent else None
+        #: A latent pool's chunks: the visible (query, key) pairs their
+        #: attention needs x sublayers, and those of them whose launch's
+        #: bucket attends in the expanded form's kernel
+        #: (`mla.rows_attention_path`, the rule `mla.rows_attention` asks).
+        #: Not in ``attn_pairs``, which counts a latent pool's ticks alone.
+        self.chunk_attn_pairs = 0
+        self.chunk_attn_kernel_pairs = 0
+        self._chunk_kernel_buckets = frozenset(
+            bucket for bucket in self.buckets
+            if self.latent
+            and mla.rows_attention_path(bucket, config) == "mla_chunk"
+        )
         #: State-space layers: slot-layers the ticks updated (live slots x
         #: state-space layers), real and bucket rows x state-space layers
         #: through the chunks' scans, admissions that started from a zero
@@ -897,6 +910,8 @@ class PagedEngine:
         if self.latent:
             out["attn_shared_kv_positions"] = self.attn_shared_kv_positions
             out["attn_shared_slots"] = self.attn_shared_slots
+            out["chunk_attn_pairs"] = self.chunk_attn_pairs
+            out["chunk_attn_kernel_pairs"] = self.chunk_attn_kernel_pairs
         if self.eva:
             out["attn_summary_kv_positions"] = self.attn_summary_kv_positions
             out["eva_summary_rows"] = self.eva_summary_rows
@@ -1077,6 +1092,12 @@ class PagedEngine:
         self.attn_kv_positions += full_layers * end + self._window_layers * (
             end - max(start - window + 1, 0)
         )
+
+    def _chunk_pairs(self, rows: int, before: int) -> int:
+        """Visible (query, key) pairs of a chunk of ``rows`` queries after
+        ``before`` cached rows - all of those and its own causal half - over
+        the attention sublayers."""
+        return self._attn_sublayers * (rows * before + rows * (rows + 1) // 2)
 
     def _write_window_row(self, slot: int) -> None:
         """The slot's window row starts at its chain's first live block,
@@ -1697,12 +1718,15 @@ class PagedEngine:
                     info.next_pos // width * self.config.eva_chunks_per_window
                 )
                 first = summaries + info.next_pos % width
-                self.attn_pairs += self._attn_sublayers * (
-                    chunk_len * first + chunk_len * (chunk_len + 1) // 2
-                )
+                self.attn_pairs += self._chunk_pairs(chunk_len, first)
                 self.eva_summary_rows += self._attn_sublayers * (
                     chunk_len // per_chunk
                 )
+            if self.latent:
+                pairs = self._chunk_pairs(chunk_len, info.next_pos)
+                self.chunk_attn_pairs += pairs
+                if bucket in self._chunk_kernel_buckets:
+                    self.chunk_attn_kernel_pairs += pairs
             if self.recurrent:
                 self.ssm_chunk_tokens += self._ssm_layers * chunk_len
                 self.ssm_chunk_rows += self._ssm_layers * bucket

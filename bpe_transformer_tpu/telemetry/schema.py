@@ -196,7 +196,11 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # ``attn_shared_kv_positions`` (of ``attn_kv_positions``, those the
     # ticks' slots attended through the shared pass: the chain of blocks
     # several slots' rows start with, attended once for all of them) and
-    # ``attn_shared_slots`` (slots on the chain, summed over ticks); over a
+    # ``attn_shared_slots`` (slots on the chain, summed over ticks),
+    # ``chunk_attn_pairs`` (visible (query, key) pairs of the chunks x
+    # sublayers; ``attn_pairs`` stays the ticks') and
+    # ``chunk_attn_kernel_pairs`` (those of launches whose bucket attends
+    # in the expanded form's kernel, `mla_attention.mla_chunk_path`); over a
     # summary-and-window cache alone ``attn_summary_kv_positions`` (of the
     # ticks' ``attn_kv_positions``, the rows that are chunk summaries),
     # ``eva_summary_rows`` (summaries written by ticks and chunks, x

@@ -809,12 +809,13 @@ def test_ragged_paged_attention(one_chip, monkeypatch, tokens, seqs, pages, wind
 
 
 def _mla_kernels(text) -> set:
-    """The latent tick's Mosaic kernels in a compiled text, by the names
-    their device events carry (the instruction's, less its number)."""
+    """The latent attention's Mosaic kernels in a compiled text, by the
+    names their device events carry (the instruction's, less its number)."""
     import re
 
     return set(re.findall(
-        r"%(mla_paged_attention[a-z_]*)\.\d+ = [^\n]*custom-call\(", text
+        r"%(mla_(?:paged|chunk)_attention[a-z_]*)\.\d+ = [^\n]*custom-call\(",
+        text,
     ))
 
 
@@ -843,6 +844,34 @@ def test_mla_paged_attention(one_chip, slots):
     assert _mla_kernels(text) == {"mla_paged_attention", "mla_paged_attention_shared"}
 
 
+@pytest.mark.parametrize("queries", [256, 512, 1024, 2048])
+def test_mla_chunk_attention(one_chip, queries):
+    """A chunk's expanded latent attention at the published widths: 64
+    heads of 128 + 64 / 128 over a slot's gathered chain of 16,384 rows as
+    the pool pads them (640 lanes), every bucket the rule admits (2,048 is
+    two tiles of 1,024 rows); the device event's name is the one
+    `mla_chunk_attention_roofline` reads, and not the tick's."""
+    from bpe_transformer_tpu.kernels.pallas.mla_attention import (
+        mla_chunk_attention,
+        mla_chunk_path,
+    )
+
+    assert mla_chunk_path(queries, 128, 128, 512, backend="tpu") == "mla_chunk"
+
+    def fn(q_nope, q_rope, rows, kv_b, positions, n_keys):
+        return mla_chunk_attention(
+            q_nope, q_rope, rows, kv_b, positions, n_keys, scale=192 ** -0.5,
+            interpret=False,
+        )
+
+    text = _compile(
+        fn, one_chip, ((64, queries, 128), BF16), ((64, queries, 64), BF16),
+        ((16384, 640), BF16), ((64, 256, 512), BF16), ((queries,), I32),
+        ((), I32),
+    )
+    assert _mla_kernels(text) == {"mla_chunk_attention"}
+
+
 @pytest.mark.parametrize(
     "rows", [768, 6144, 24576], ids=["tick_64_slots", "chunk_512", "chunk_2048"]
 )
@@ -859,8 +888,9 @@ def test_grouped_matmul_at_longcat_widths(one_chip, monkeypatch, rows, d_out, d_
 @pytest.mark.parametrize("name", ["tick", "chunk"], ids=["tick", "chunk256"])
 def test_latent_pool_programs(one_chip, on_tpu, name):
     """The engine's two programs over a latent pool, one double layer at
-    the published widths: both kernels are there (the chunk attends in
-    XLA), the pool's two arrays are aliased whole and never copied."""
+    the published widths: the tick's two kernels or the chunk's one are
+    there (256 rows: the smallest bucket that attends in the expanded
+    form), the pool's two arrays are aliased whole and never copied."""
     import json
     from pathlib import Path
 
@@ -876,7 +906,7 @@ def test_latent_pool_programs(one_chip, on_tpu, name):
     text = compiled.as_text()
     assert _mla_kernels(text) == (
         {"mla_paged_attention", "mla_paged_attention_shared"}
-        if name == "tick" else set()
+        if name == "tick" else {"mla_chunk_attention"}
     )
     assert "gmm" in text
     # The expert layer may sort assignments by expert (`models/moe.py`); the

@@ -217,13 +217,17 @@ def test_every_cache_kind_times_its_launches_in_the_same_parts(kind):
 @pytest.mark.parametrize("kind", KINDS)
 def test_every_cache_kind_hands_out_the_same_gauges(kind):
     """`gauges()` has the same keys over every cache kind, but for the two
-    counts of the latent tick's shared pass, which the latent kind alone
-    carries (0 here: on the CPU its ticks take gathered rows)."""
+    counts of the latent tick's shared pass and the two of a latent chunk's
+    pairs, which the latent kind alone carries (0 here: on the CPU its
+    ticks take gathered rows, and nothing has been prefilled)."""
     gauges = KINDS[kind]().gauges()
-    shared_pass = {"attn_shared_kv_positions", "attn_shared_slots"}
-    assert set(gauges) - shared_pass == set(dense_engine().gauges())
-    assert (set(gauges) & shared_pass == shared_pass) == (kind == "latent")
-    assert not any(gauges.get(key) for key in shared_pass)
+    latent_alone = {
+        "attn_shared_kv_positions", "attn_shared_slots", "chunk_attn_pairs",
+        "chunk_attn_kernel_pairs",
+    }
+    assert set(gauges) - latent_alone == set(dense_engine().gauges())
+    assert (set(gauges) & latent_alone == latent_alone) == (kind == "latent")
+    assert not any(gauges.get(key) for key in latent_alone)
 
 
 def test_tick_is_launch_then_collect():

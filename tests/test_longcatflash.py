@@ -397,6 +397,149 @@ def test_the_shared_pass_is_taken_from_six_members(members, taken):
     assert traced.tolist() == want
 
 
+# ------------------------------------ a chunk's rows in the expanded form
+
+#: Toy widths that keep `mla_chunk_path`'s lane rule: 2 heads of 128 + 64 /
+#: 128 over a latent of 128.
+CHUNK_HEADS, CHUNK_NOPE, CHUNK_ROPE, CHUNK_V, CHUNK_RANK = 2, 128, 64, 128, 128
+#: name: (first position, key positions any query sees); key blocks are
+#: `MLA_CHUNK_KEY_BLOCK` = 1,024 as shipped, the table holds 3,072 rows.
+CHUNK_CASES = {
+    "from_position_0": (0, None),
+    "ends_inside_a_key_block": (None, 1500),
+    "ends_at_a_key_block": (None, 2048),
+    "ends_one_past_a_key_block": (None, 2049),
+    "padded_rows_past_the_chunk": (1100, None),
+}
+
+
+def _expanded_reference(q_nope, q_rope, rows, kv_b, positions, scale):
+    """Every head's keys and values from the rows, a masked softmax over
+    all of them at once: float32, nothing blocked."""
+    nope, rank = q_nope.shape[-1], kv_b.shape[-1]
+    k_nope = jnp.einsum("kc,hdc->hkd", rows[:, :rank], kv_b[:, :nope])
+    v = jnp.einsum("kc,hdc->hkd", rows[:, :rank], kv_b[:, nope:])
+    scores = (
+        jnp.einsum("hqd,hkd->hqk", q_nope, k_nope)
+        + jnp.einsum("hqr,kr->hqk", q_rope, rows[:, rank:])
+    ) * scale
+    visible = jnp.arange(rows.shape[0])[None, :] <= positions[:, None]
+    probs = jax.nn.softmax(jnp.where(visible, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("hqk,hkd->hqd", probs, v)
+
+
+@pytest.mark.parametrize("case", CHUNK_CASES)
+@pytest.mark.parametrize("queries", [256, 512, 1024])
+def test_the_chunk_kernel_attends_what_the_loop_and_the_expanded_form_attend(
+    queries, case
+):
+    """`mla_chunk_attention` (interpret mode) against the absorbed loop and
+    against a plain float32 expanded reference, on the chunk's own rows;
+    a padded row past them comes out finite and is no one's."""
+    assert mla_attention.MLA_CHUNK_KEY_BLOCK == 1024
+    start, n_keys = CHUNK_CASES[case]
+    padded = 57 if case == "padded_rows_past_the_chunk" else 0
+    chunk_len = queries - padded
+    start = n_keys - chunk_len if start is None else start
+    n_keys = start + chunk_len
+    rng = np.random.default_rng(queries + len(case))
+
+    def draw(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    q_nope = draw(CHUNK_HEADS, queries, CHUNK_NOPE)
+    q_rope = draw(CHUNK_HEADS, queries, CHUNK_ROPE)
+    rows = draw(3072, CHUNK_RANK + CHUNK_ROPE)
+    kv_b = draw(CHUNK_HEADS, CHUNK_NOPE + CHUNK_V, CHUNK_RANK) * 0.1
+    positions = start + jnp.arange(queries)
+    args = (q_nope, q_rope, rows, kv_b, positions, n_keys)
+    got = mla_attention.mla_chunk_attention(*args, scale=0.07, interpret=True)
+    loop = mla_attention.xla_mla_chunk_attention(*args, scale=0.07)
+    plain = _expanded_reference(q_nope, q_rope, rows, kv_b, positions, 0.07)
+    assert got.shape == (CHUNK_HEADS, queries, CHUNK_V)
+    assert float(jnp.max(jnp.abs(got - loop)[:, :chunk_len])) < 2e-5
+    assert float(jnp.max(jnp.abs(got - plain)[:, :chunk_len])) < 2e-5
+    assert bool(jnp.isfinite(got).all())
+    # Rows as a pool pads them (zeros to whole lane tiles) read the same.
+    wide = jnp.pad(rows, ((0, 0), (0, 64)))
+    again = mla_attention.mla_chunk_attention(
+        q_nope, q_rope, wide, kv_b, positions, n_keys, scale=0.07, interpret=True
+    )
+    assert bool(jnp.array_equal(again, got))
+
+
+@pytest.mark.parametrize(
+    "backend, queries, widths, want",
+    [
+        ("tpu", 1024, (128, 128, 512), "mla_chunk"),
+        ("tpu", 256, (128, 128, 512), "mla_chunk"),
+        ("tpu", 2048, (128, 128, 512), "mla_chunk"),
+        ("cpu", 1024, (128, 128, 512), "xla"),
+        ("gpu", 1024, (128, 128, 512), "xla"),
+        ("tpu", 128, (128, 128, 512), "xla"),     # under the row threshold
+        ("tpu", 1536, (128, 128, 512), "xla"),    # no whole tiles of 1,024
+        ("tpu", 1024, (8, 8, 8), "xla"),          # this file's test widths
+        ("tpu", 1024, (128, 64, 512), "xla"),
+        ("tpu", 1024, (128, 128, 192), "xla"),
+    ],
+)
+def test_the_chunk_takes_the_kernel_on_the_tpu_from_256_aligned_rows(
+    backend, queries, widths, want
+):
+    """The constants as the package ships them; the rule both ways."""
+    assert mla_attention.MLA_CHUNK_MIN_ROWS == 256
+    assert mla_attention.MLA_CHUNK_TILE_ROWS == 1024
+    assert mla_attention.mla_chunk_path(queries, *widths, backend=backend) == want
+    if backend == "cpu":  # the backend here, asked of jax
+        assert mla_attention.mla_chunk_path(queries, *widths) == want
+
+
+#: A prompt of 11 is two chunks: 8 rows from position 0 in the bucket of 8,
+#: then 3 rows after 8 in the bucket of 4; 2 double layers = 4 sublayers.
+TWO_CHUNKS = 4 * (8 * 9 // 2), 4 * (3 * 8 + 3 * 4 // 2)
+CHUNK_RULES = {
+    "loop": (None, 0),
+    "kernel": (lambda *a, **k: "mla_chunk", sum(TWO_CHUNKS)),
+    "kernel_from_8_rows": (
+        lambda queries, *a, **k: "mla_chunk" if queries >= 8 else "xla",
+        TWO_CHUNKS[0],
+    ),
+}
+
+
+@pytest.mark.parametrize("rule", CHUNK_RULES)
+def test_a_latent_chunks_pairs_equal_a_count_by_hand(rule, monkeypatch):
+    """`chunk_attn_pairs` / `chunk_attn_kernel_pairs` for a two-chunk
+    prompt, the chunks served by the form the rule names (the kernel in
+    interpret mode) against the reference; `attn_pairs` stays the ticks'."""
+    path, kernel_pairs = CHUNK_RULES[rule]
+    if path is not None:
+        monkeypatch.setattr(mla_attention, "mla_chunk_path", path)
+    c = reference_cfg(4, 4)
+    eng = small_engine(c)
+    tokens = np.random.default_rng(2).integers(0, 64, 14)
+    worst, _ = served_logit_error(eng, c, tokens, 11)
+    assert worst < 1e-5
+    gauges = eng.gauges()
+    assert gauges["chunk_attn_pairs"] == sum(TWO_CHUNKS)
+    assert gauges["chunk_attn_kernel_pairs"] == kernel_pairs
+    # `served_logit_error` forces its ticks past the engine: none counted.
+    assert gauges["attn_pairs"] == 0 and eng.chunk_launches == 2
+
+
+def test_no_chunk_pairs_off_the_latent_kind():
+    config = dataclasses.replace(TS_TEST_CONFIG, vocab_size=64, context_length=32)
+    eng = PagedEngine(
+        init_params(jax.random.PRNGKey(0), config), config, slots=2,
+        block_size=4, prefill_chunk=8, prefill_buckets=(4, 8),
+    )
+    eng.admit(np.arange(11) % 64, max_new_tokens=2, temperature=0.0)
+    eng.tick()
+    gauges = eng.gauges()
+    assert not {"chunk_attn_pairs", "chunk_attn_kernel_pairs"} & set(gauges)
+    assert gauges["attn_pairs"] > 0
+
+
 def _serve_after_one_prefix(monkeypatch, rule):
     """Four greedy requests behind one 9-token prefix (two whole blocks)
     through the kernels in interpret mode, `shared_prefix` replaced by
