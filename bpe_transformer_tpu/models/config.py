@@ -14,6 +14,14 @@ from pathlib import Path
 from typing import Any
 
 
+#: What a letter of ``ModelConfig.layer_pattern`` puts in a layer: ``(its
+#: mixer, whether the feed-forward part follows)``.
+LAYER_KINDS = {
+    "M": ("ssm", False), "*": ("attn", False), "E": (None, True),
+    "m": ("ssm", True), "a": ("attn", True),
+}
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     vocab_size: int
@@ -206,25 +214,35 @@ class ModelConfig:
     #: The router's tree holds ``router_bias`` (router outputs,), added to
     #: the scores for the choice of experts and not to the gates.
     router_bias: bool = False
-    # ---- state-space layers beside attention layers, the four multipliers
-    # and a shared expert of its own width (the Granite-4.0-H family; every
-    # default is the block above) ------------------------------------------
-    #: Mixer kind by layer: with a period ``p > 0`` layer ``i`` is an
-    #: attention layer where ``i % p == attn_layer_offset`` and a Mamba-2
-    #: state-space layer elsewhere (`models/ssm.py`: no cache of positions
-    #: but one recurrent state a sequence); 0, the default: every layer
-    #: attends.  The block is the sequential pre-norm one
-    #: (`hybrid_block`).
+    # ---- layers that differ in kind by a pattern - state-space mixers
+    # beside attention layers, each with the feed-forward part or one
+    # sublayer a layer - the four multipliers, a shared expert of its own
+    # width and an expert's activation (every default is the block above) --
+    #: The kind of every layer, a letter a layer (`LAYER_KINDS`, `layer_kinds`):
+    #: ``"M"`` a Mamba-2 state-space mixer alone (`models/ssm.py`: no cache
+    #: of positions but one recurrent state a sequence), ``"*"`` attention
+    #: alone, ``"E"`` the feed-forward part alone (no mixer, so no cache of
+    #: any kind) - layers of one pre-norm sublayer each - and ``"m"`` / ``"a"``
+    #: a state-space / attention mixer followed by the feed-forward part.
+    #: None, the default, with a period of 0: every layer attends and feeds
+    #: forward.  The block is the sequential pre-norm one (`hybrid_block`).
+    layer_pattern: str | None = None
+    #: The periodic pattern in two numbers: with a period ``p > 0`` layer
+    #: ``i`` is ``"a"`` where ``i % p == attn_layer_offset`` and ``"m"``
+    #: elsewhere (`layer_kinds` spells it out; not beside ``layer_pattern``).
     attn_layer_period: int = 0
     attn_layer_offset: int = 0
     #: The Mamba-2 mixer: ``ssm_heads`` heads of ``ssm_head_dim`` channels
     #: (inner width their product), a state of ``ssm_state`` values a
-    #: channel, ``B`` and ``C`` shared by all heads (one group), a depthwise
-    #: causal convolution ``ssm_conv`` wide, the scan in chunks of
+    #: channel, ``B`` and ``C`` in ``ssm_groups`` groups (head ``h`` reads
+    #: those of group ``h // (ssm_heads // ssm_groups)``, and the gated norm
+    #: runs over each group's channels apart; 1: shared by all heads), a
+    #: depthwise causal convolution ``ssm_conv`` wide, the scan in chunks of
     #: ``ssm_chunk`` positions.
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_state: int = 0
+    ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_chunk: int = 256
     #: ``x_0 = embedding_multiplier * E[token]``; every branch joins the
@@ -237,6 +255,10 @@ class ModelConfig:
     logits_scaling: float = 1.0
     #: Width of a shared expert where it is not a routed expert's.
     shared_d_ff: int | None = None
+    #: What an expert of the dropless layer computes, routed and shared
+    #: alike: ``"swiglu"`` ``w2 (silu(w1 u) * w3 u)``, three matrices, or
+    #: ``"relu2"`` ``w2 relu(w1 u)^2``, two (its tree has no ``w3``).
+    expert_activation: str = "swiglu"
     # ---- chunked linear attention over a window, the unit-offset norm and
     # several prediction heads (the EvaByte family; every default is the
     # block above) ---------------------------------------------------------
@@ -337,22 +359,50 @@ class ModelConfig:
 
     @property
     def hybrid_block(self) -> bool:
-        """State-space layers among the attention layers: the sequential
-        pre-norm block with the mixer by layer (`layer_is_ssm`), the
+        """Layers that differ in kind (`layer_kinds`): the sequential
+        pre-norm block with each layer's sublayers by its kind - a
+        state-space or attention mixer, the feed-forward part, or both - the
         multipliers and an added shared expert
         (`models/decode._block_apply`)."""
-        return self.attn_layer_period > 0
+        return self.layer_pattern is not None or self.attn_layer_period > 0
+
+    @property
+    def layer_kinds(self) -> str:
+        """The kind of every layer, a letter of `LAYER_KINDS` a layer: the
+        pattern itself, the period and offset spelt out, or every layer
+        ``"a"``."""
+        if self.layer_pattern is not None:
+            return self.layer_pattern
+        if self.attn_layer_period > 0:
+            return "".join(
+                "a" if i % self.attn_layer_period == self.attn_layer_offset else "m"
+                for i in range(self.num_layers)
+            )
+        return "a" * self.num_layers
+
+    def layer_mixer(self, layer: int) -> str | None:
+        """Layer ``layer``'s (0-based) mixer: ``"ssm"``, ``"attn"`` or None."""
+        return LAYER_KINDS[self.layer_kinds[layer]][0]
+
+    def layer_has_ffn(self, layer: int) -> bool:
+        return LAYER_KINDS[self.layer_kinds[layer]][1]
 
     def layer_is_ssm(self, layer: int) -> bool:
         """Whether layer ``layer`` (0-based) is a state-space layer."""
-        return (
-            self.hybrid_block
-            and layer % self.attn_layer_period != self.attn_layer_offset
-        )
+        return self.layer_mixer(layer) == "ssm"
+
+    def _layers_with(self, mixer) -> int:
+        return sum(LAYER_KINDS[kind][0] == mixer for kind in self.layer_kinds)
 
     @property
     def ssm_layers(self) -> int:
-        return sum(self.layer_is_ssm(i) for i in range(self.num_layers))
+        """Layers that keep a recurrent state a sequence."""
+        return self._layers_with("ssm")
+
+    @property
+    def attn_layers(self) -> int:
+        """Layers that keep K/V (or latent rows) a position."""
+        return self._layers_with("attn")
 
     @property
     def ssm_inner(self) -> int:
@@ -361,9 +411,9 @@ class ModelConfig:
 
     @property
     def ssm_conv_channels(self) -> int:
-        """Channels through the convolution: the inner stream, ``B`` and
-        ``C``."""
-        return self.ssm_inner + 2 * self.ssm_state
+        """Channels through the convolution: the inner stream and every
+        group's ``B`` and ``C``."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def attention_scale(self) -> float:
@@ -403,9 +453,10 @@ class ModelConfig:
         """True for what only the serving paths and the plain forward run
         (no training step, no ``scan_layers``, no int8 weights): a layer
         pattern, a parallel block, LayerNorm, shared or held experts, latent
-        attention, the double layer, zero experts and their router,
-        state-space layers, chunked linear attention with its unit-offset
-        norm and prediction heads."""
+        attention, the double layer, zero experts and their router, layers
+        by a pattern of kinds (state-space mixers, one sublayer a layer), an
+        expert activation of its own, chunked linear attention with its
+        unit-offset norm and prediction heads."""
         return (
             self.latent_block
             or self.hybrid_block
@@ -420,6 +471,7 @@ class ModelConfig:
             or self.experts_held is not None
             or self.moe_router != "softmax"
             or self.head_dim is not None
+            or self.expert_activation != "swiglu"
         )
 
     @property
@@ -523,7 +575,7 @@ class ModelConfig:
                     "rotated keys by window and comes in the sequential "
                     "pre-norm RMSNorm block with a dense SwiGLU and an untied "
                     "head: sliding_window, state-space layers "
-                    "(attn_layer_period), num_kv_heads < num_heads, "
+                    "(layer_pattern, attn_layer_period), num_kv_heads < num_heads, "
                     "parallel_block, use_post_norm, remove_rmsnorm, "
                     "remove_rope, LayerNorm, another ffn_type and "
                     "tie_embeddings contradict it"
@@ -590,10 +642,25 @@ class ModelConfig:
             raise ValueError("parallel_block has one pre-norm; use_post_norm contradicts it")
         ssm_dims = (self.ssm_heads, self.ssm_head_dim, self.ssm_state)
         if self.hybrid_block:
-            if not 0 <= self.attn_layer_offset < self.attn_layer_period:
+            if self.layer_pattern is None:
+                if not 0 <= self.attn_layer_offset < self.attn_layer_period:
+                    raise ValueError(
+                        f"attn_layer_offset={self.attn_layer_offset} must lie in "
+                        f"[0, attn_layer_period={self.attn_layer_period})"
+                    )
+            elif self.attn_layer_period or self.attn_layer_offset:
                 raise ValueError(
-                    f"attn_layer_offset={self.attn_layer_offset} must lie in "
-                    f"[0, attn_layer_period={self.attn_layer_period})"
+                    "layer_pattern names every layer's kind: attn_layer_period "
+                    "and attn_layer_offset beside it say the same twice"
+                )
+            elif (
+                len(self.layer_pattern) != self.num_layers
+                or set(self.layer_pattern) - set(LAYER_KINDS)
+            ):
+                raise ValueError(
+                    f"layer_pattern={self.layer_pattern!r} must name each of "
+                    f"num_layers={self.num_layers} layers by one of "
+                    f"{''.join(LAYER_KINDS)!r}"
                 )
             if min(ssm_dims) < 1 or self.ssm_conv < 2 or self.ssm_chunk < 1:
                 raise ValueError(
@@ -601,30 +668,49 @@ class ModelConfig:
                     f"ssm_state and ssm_chunk and ssm_conv >= 2 (got {ssm_dims}, "
                     f"{self.ssm_conv}, {self.ssm_chunk})"
                 )
+            if self.ssm_groups < 1 or self.ssm_heads % self.ssm_groups:
+                raise ValueError(
+                    f"ssm_groups={self.ssm_groups} must divide "
+                    f"ssm_heads={self.ssm_heads}"
+                )
             if (
                 self.sliding_window is not None or self.attention_kind != "mha"
                 or self.parallel_block or self.use_post_norm
                 or self.remove_rmsnorm or self.norm_type != "rmsnorm"
             ):
                 raise ValueError(
-                    "state-space layers come in the sequential pre-norm RMSNorm "
-                    "block beside plain attention layers: sliding_window, "
+                    "layers by a pattern of kinds come in the sequential "
+                    "pre-norm RMSNorm block, state-space mixers beside plain "
+                    "attention layers: sliding_window, "
                     'attention_kind="mla", parallel_block, use_post_norm, '
                     "remove_rmsnorm and LayerNorm contradict them"
                 )
-        elif self.attn_layer_period < 0 or self.attn_layer_offset or any(ssm_dims):
+        elif (
+            self.attn_layer_period < 0 or self.attn_layer_offset
+            or any(ssm_dims) or self.ssm_groups != 1
+        ):
             raise ValueError(
-                "attn_layer_offset and ssm_heads .. ssm_state are the hybrid "
-                "block's (attn_layer_period > 0)"
+                "attn_layer_offset and ssm_heads .. ssm_groups are the hybrid "
+                "block's (layer_pattern, or attn_layer_period > 0)"
             )
         elif (
             self.embedding_multiplier != 1.0 or self.residual_multiplier != 1.0
             or self.attention_multiplier is not None
             or self.logits_scaling != 1.0 or self.shared_d_ff is not None
+            or self.expert_activation != "swiglu"
         ):
             raise ValueError(
-                "the multipliers and shared_d_ff are the hybrid block's "
-                "(attn_layer_period > 0): no other block applies them"
+                "the multipliers, shared_d_ff and expert_activation are the "
+                "hybrid block's (layer_pattern, or attn_layer_period > 0): no "
+                "other block applies them"
+            )
+        if self.expert_activation not in ("swiglu", "relu2") or (
+            self.expert_activation != "swiglu" and self.ffn_type != "moe"
+        ):
+            raise ValueError(
+                f"expert_activation={self.expert_activation!r} must be "
+                '"swiglu" or "relu2", the latter of an expert layer '
+                '(ffn_type="moe")'
             )
         if self.shared_d_ff is not None and not (
             self.n_shared_experts and self.shared_d_ff >= 1

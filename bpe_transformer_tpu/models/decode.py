@@ -60,15 +60,16 @@ def init_kv_cache(config: ModelConfig, batch: int, dtype=jnp.float32) -> KVCache
     kv_heads = config.num_kv_heads or config.num_heads
     shape = (batch, kv_heads, config.context_length, config.d_head)
     if config.hybrid_block:
-        # A state-space layer's entry is its recurrent state, whatever the
-        # context (`models/ssm.py`).
+        # A layer's entry by its mixer: K and V rows, a state-space layer's
+        # recurrent state, whatever the context (`models/ssm.py`), nothing.
         from bpe_transformer_tpu.models.ssm import init_ssm_state
 
-        return [
-            init_ssm_state(config, batch, dtype) if config.layer_is_ssm(layer)
-            else {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-            for layer in range(config.num_layers)
-        ]
+        def entry(mixer):
+            if mixer == "ssm":
+                return init_ssm_state(config, batch, dtype)
+            return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)} if mixer else {}
+
+        return [entry(config.layer_mixer(i)) for i in range(config.num_layers)]
     return [
         {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
         for _ in range(config.num_layers)
@@ -118,7 +119,10 @@ def _block_apply(x, block_params, config, attend, valid=None, tally=None):
     default, post-norm under the ablation flag, both branches from one norm
     under ``parallel_block``; under ``hybrid_block`` ``a = x + r * Mixer(N1
     x)``, ``y = a + r * (M(N2 a) + S(N2 a))`` with the residual multiplier
-    ``r``, ``attend`` being the layer's mixer.  Under ``double_layer`` it is the
+    ``r``, ``attend`` being the layer's mixer - each of the two sublayers
+    where the layer's tree has it (a layer of one sublayer has one norm and
+    one branch; ``attend`` is not called without a mixer).  Under
+    ``double_layer`` it is the
     shortcut-connected double layer, whose ``attend(h, sublayer)`` is called
     for each of its two attention sublayers: with norms ``N1 .. N4``, dense
     FFNs ``F_0, F_1`` and the expert layer ``M``, ``a = x + Attn_0(N1 x)``,
@@ -156,12 +160,16 @@ def _block_apply(x, block_params, config, attend, valid=None, tally=None):
         # Sequential and pre-norm; the layer's mixer (attention, or the
         # state-space mixer where the tree has "ssm") and the expert layer
         # with its shared expert each join times the residual multiplier.
+        # The tree says which of the two the layer has (`layer_kinds`).
         r = config.residual_multiplier
-        with jax.named_scope("block/ssm" if "ssm" in block_params else "block/attn"):
-            x = x + r * attend(_norm(x, block_params["ln1"], config))
-        with jax.named_scope("block/ffn"):
-            h = _norm(x, block_params["ln2"], config)
-            return x + r * _ffn_decode(h, block_params["ffn"], config, valid, tally)
+        if "ln1" in block_params:
+            with jax.named_scope("block/ssm" if "ssm" in block_params else "block/attn"):
+                x = x + r * attend(_norm(x, block_params["ln1"], config))
+        if "ffn" in block_params:
+            with jax.named_scope("block/ffn"):
+                h = _norm(x, block_params["ln2"], config)
+                x = x + r * _ffn_decode(h, block_params["ffn"], config, valid, tally)
+        return x
     if config.parallel_block:
         h = _norm(x, block_params["ln1"], config)
         with jax.named_scope("block/attn"):
@@ -311,6 +319,10 @@ def prefill(
             x = _block_apply(x, block_params, config, attend_latent)
             new_cache.append(layer_new)
             continue
+        if config.layer_mixer(layer) is None:  # no cache of any kind
+            x = _block_apply(x, block_params, config, None)
+            new_cache.append(layer_cache)
+            continue
         if config.layer_is_ssm(layer):
             from bpe_transformer_tpu.models.ssm import mamba2
 
@@ -450,6 +462,10 @@ def decode_step(
                 ),
             )
             new_cache.append(layer_new)
+            continue
+        if config.layer_mixer(layer) is None:  # no cache of any kind
+            x = _block_apply(x, block_params, config, None)
+            new_cache.append(layer_cache)
             continue
         if config.layer_is_ssm(layer):
             from bpe_transformer_tpu.models.ssm import mamba2_step
@@ -1205,24 +1221,28 @@ class LatentRows(_RoutingCounts):
 # a SLOT - ``{"ssm": (slots + 1, heads, head_dim, state) float32, "conv":
 # (slots + 1, k - 1, channels)}``, the last row trash, as block 0 is of the
 # pools of positions - beside the K and V pools of the config's attention
-# layers, which are `DenseRows`' own.
+# layers, which are `DenseRows`' own, and the empty entries of the layers
+# that have no mixer and so no cache of any kind.
 
 
 def init_recurrent_pool(
     config: ModelConfig, num_blocks: int, block_size: int, slots: int,
     dtype=jnp.float32,
 ) -> list:
-    """A layer's entry by its kind: `init_kv_pool`'s K and V rows for an
-    attention layer, zeroed state rows for a state-space layer."""
+    """A layer's entry by its mixer (`ModelConfig.layer_mixer`):
+    `init_kv_pool`'s K and V rows for an attention layer, zeroed state rows
+    for a state-space layer, an empty entry for a layer without one."""
     from bpe_transformer_tpu.models.ssm import init_ssm_state
 
     kv_heads = config.num_kv_heads or config.num_heads
     shape = (num_blocks, block_size, kv_heads * config.d_head)
-    return [
-        init_ssm_state(config, slots + 1, dtype) if config.layer_is_ssm(layer)
-        else {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-        for layer in range(config.num_layers)
-    ]
+
+    def entry(mixer):
+        if mixer == "ssm":
+            return init_ssm_state(config, slots + 1, dtype)
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)} if mixer else {}
+
+    return [entry(config.layer_mixer(i)) for i in range(config.num_layers)]
 
 
 class RecurrentRows(_RoutingCounts):
@@ -1585,13 +1605,15 @@ def paged_forward(
     new_pool: list = []
     per_layer = config.attn_sublayers
     for layer, block_params in enumerate(params["layers"]):
+        layer_pool = pool[layer * per_layer: (layer + 1) * per_layer]
+        if config.layer_mixer(layer) is None:
+            new_pool.extend(layer_pool)  # its empty entry, as it came
         x = _block_apply(
             x, block_params, config,
             partial(
                 _cached_attention, attn=block_params.get("attn"), config=config,
                 cache=cache, layer=layer, new_pool=new_pool,
-                ssm=block_params.get("ssm"),
-                layer_pool=pool[layer * per_layer: (layer + 1) * per_layer],
+                ssm=block_params.get("ssm"), layer_pool=layer_pool,
             ),
             valid=cache.ffn_rows, tally=cache.tally,
         )

@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from jax import Array
 
 from bpe_transformer_tpu.models.config import ModelConfig
-from bpe_transformer_tpu.ops.core import silu
+from bpe_transformer_tpu.ops.core import relu2, silu
 
 
 def init_moe_params(rng: jax.Array, config: ModelConfig, dtype=jnp.float32) -> dict:
@@ -46,7 +46,8 @@ def init_moe_params(rng: jax.Array, config: ModelConfig, dtype=jnp.float32) -> d
     the stacks of the experts held here (``config.local_experts``), a
     ``"shared"`` stack where the config has shared experts (of the routed
     width, or ``shared_d_ff``) and a ``"router_bias"`` of zeros where it has
-    one."""
+    one.  An expert is ``w1``, ``w2`` and - the SwiGLU's third matrix, which
+    ``expert_activation="relu2"`` has not - ``w3``."""
     e, d, ff = config.router_outputs, config.d_model, config.moe_d_ff
     held, shared = config.local_experts, config.n_shared_experts
 
@@ -55,23 +56,22 @@ def init_moe_params(rng: jax.Array, config: ModelConfig, dtype=jnp.float32) -> d
             jax.random.truncated_normal(key, -3.0, 3.0, shape, jnp.float32) * std
         ).astype(dtype)
 
+    def experts(keys, count, width):
+        stack = {
+            "w1": dense(keys[0], (count, width, d)),
+            "w2": dense(keys[1], (count, d, width)),
+        }
+        if config.expert_activation == "swiglu":
+            stack["w3"] = dense(keys[2], (count, width, d))
+        return stack
+
     k = jax.random.split(rng, 4)
-    params = {
-        "router": dense(k[0], (e, d)),
-        "w1": dense(k[1], (held, ff, d)),
-        "w2": dense(k[2], (held, d, ff)),
-        "w3": dense(k[3], (held, ff, d)),
-    }
+    params = {"router": dense(k[0], (e, d)), **experts(k[1:], held, ff)}
     if config.router_bias:
         params["router_bias"] = jnp.zeros((e,), jnp.float32)
     if shared:
         ks = jax.random.split(jax.random.fold_in(rng, 1), 3)
-        sff = config.shared_ff
-        params["shared"] = {
-            "w1": dense(ks[0], (shared, sff, d)),
-            "w2": dense(ks[1], (shared, d, sff)),
-            "w3": dense(ks[2], (shared, sff, d)),
-        }
+        params["shared"] = experts(ks, shared, config.shared_ff)
     return params
 
 
@@ -239,10 +239,11 @@ def dropless_moe(
 
     Every token is routed over all ``n_experts``; the assignments that land
     on the experts held here (``expert_offset .. + local_experts``) are
-    sorted by expert and go through one grouped matmul per SwiGLU matrix
-    (`kernels/pallas/grouped_matmul.py`), which visits only experts that got
-    a row; each token's output is the gate-weighted sum of its held
-    experts' results.  What the absent experts would add is left out: with
+    sorted by expert and go through one grouped matmul per matrix of an
+    expert - three of a SwiGLU, two around a squared ReLU
+    (``expert_activation``; `kernels/pallas/grouped_matmul.py`) - which
+    visits only experts that got a row; each token's output is the
+    gate-weighted sum of its held experts' results.  What the absent experts would add is left out: with
     ``experts_held=None`` that is nothing, with a share it is the other
     processes' part of an expert-parallel layer.  Shared experts, where the
     config has them, see every token and are averaged and added (one shared
@@ -304,8 +305,12 @@ def dropless_moe(
     with jax.named_scope("block/moe/experts"):
         sorted_in = jnp.take(tokens, order // top_k, axis=0)  # (kn, d)
         up = grouped_matmul(sorted_in, moe_params["w1"], group_sizes)
-        lin = grouped_matmul(sorted_in, moe_params["w3"], group_sizes)
-        sorted_out = grouped_matmul(silu(up) * lin, moe_params["w2"], group_sizes)
+        if config.expert_activation == "relu2":
+            hidden = relu2(up)
+        else:
+            lin = grouped_matmul(sorted_in, moe_params["w3"], group_sizes)
+            hidden = silu(up) * lin
+        sorted_out = grouped_matmul(hidden, moe_params["w2"], group_sizes)
         # Back to assignment order.  Rows past rows_local were not computed
         # (their memory is whatever it was): selected out, never scaled.
         sorted_row = jnp.zeros((kn,), jnp.int32).at[order].set(
@@ -326,7 +331,11 @@ def dropless_moe(
         with jax.named_scope("block/moe/shared"):
             shared = moe_params["shared"]
             up = jnp.einsum("nd,jfd->njf", tokens, shared["w1"])
-            lin = jnp.einsum("nd,jfd->njf", tokens, shared["w3"])
-            both = jnp.einsum("njf,jdf->nd", silu(up) * lin, shared["w2"])
+            if config.expert_activation == "relu2":
+                hidden = relu2(up)
+            else:
+                lin = jnp.einsum("nd,jfd->njf", tokens, shared["w3"])
+                hidden = silu(up) * lin
+            both = jnp.einsum("njf,jdf->nd", hidden, shared["w2"])
             out = out + (both / config.n_shared_experts).astype(tokens.dtype)
     return out.reshape(orig_shape), counts

@@ -4,19 +4,25 @@ For the normalised stream ``u`` (``config`` names in brackets)::
 
     [z ; xBC ; dt] = W_in u             [ssm_inner + ssm_conv_channels + ssm_heads]
     xBC_t = silu(b_c + sum_j w_c[:, j] * xBC_{t - (k - 1) + j})   [ssm_conv = k]
-    [x ; B ; C] = xBC                   [heads x ssm_head_dim ; ssm_state ; ssm_state]
+    [x ; B ; C] = xBC                   [heads x ssm_head_dim ; groups x ssm_state, twice]
     dt = softplus(dt + dt_bias)         a head;   A = -exp(A_log)
-    H_t = exp(dt_t A) H_{t-1} + (dt_t x_t) (x) B_t     [a head: head_dim x ssm_state]
-    y_t = H_t C_t + D x_t
-    out = W_out RMSNorm(y * silu(z))    [one norm over all inner channels]
+    H_t = exp(dt_t A) H_{t-1} + (dt_t x_t) (x) B_t[g]  [a head: head_dim x ssm_state]
+    y_t = H_t C_t[g] + D x_t
+    out = W_out RMSNorm_g(y * silu(z))  [a norm over each group's inner channels]
 
-``B`` and ``C`` are shared by all heads (one group); the convolution is
-depthwise and causal, zeros left of the sequence's start.  **What a sequence
-keeps** between calls is ``{"ssm": H (heads, head_dim, ssm_state) float32,
-"conv": the last k - 1 pre-activation xBC rows}`` - it does not grow with
-the context.
+``B`` and ``C`` come in ``ssm_groups`` groups: head ``h`` reads those of group
+``g = h // (heads // groups)``, and the gated norm runs over each group's
+channels apart under one weight of ``ssm_inner``.  With one group - the
+default - they are shared by all heads, there is one norm over all inner
+channels, and ``B``, ``C`` carry no group axis anywhere below (the programs
+of a one-group config are what they were before there were groups).  The
+convolution is depthwise and causal, zeros left of the sequence's start.
+**What a sequence keeps** between calls is ``{"ssm": H (heads, head_dim,
+ssm_state) float32, "conv": the last k - 1 pre-activation xBC rows}`` - it
+does not grow with the context.
 
-Three forms that agree (``tests/test_granitehybrid.py``): :func:`mamba2` over
+Three forms that agree (``tests/test_granitehybrid.py``, with groups
+``tests/test_nemotronh.py``): :func:`mamba2` over
 a whole sequence, chunked (``ssm_chunk`` positions at a time the recurrence
 is a masked matrix product, between chunks a carried state); the same from a
 carried state, leaving one (a prefill chunk); :func:`mamba2_step`, one
@@ -107,15 +113,27 @@ def _step_sizes(dt_raw, p, valid):
 
 
 def _split_xbc(xbc, config):
-    inner, n = config.ssm_inner, config.ssm_state
+    """``(x (..., heads, channels), B, C)``: ``B`` and ``C`` (..., state
+    values) of one group, (..., groups, state values) of several."""
+    inner, groups = config.ssm_inner, config.ssm_groups
+    n = groups * config.ssm_state
     x = xbc[..., :inner].reshape(*xbc.shape[:-1], config.ssm_heads, config.ssm_head_dim)
-    return x, xbc[..., inner:inner + n], xbc[..., inner + n:]
+    b, c = xbc[..., inner:inner + n], xbc[..., inner + n:]
+    if groups > 1:
+        b, c = (v.reshape(*v.shape[:-1], groups, config.ssm_state) for v in (b, c))
+    return x, b, c
 
 
-def _gate_out(y, z, p):
-    """``y`` (..., inner) float32 gated by ``z``, normalised, projected."""
+def _gate_out(y, z, p, groups: int):
+    """``y`` (..., inner) float32 gated by ``z``, normalised a group of
+    channels at a time, projected."""
     with jax.named_scope("block/ssm/gate_norm"):
-        g = rmsnorm(y.astype(z.dtype) * silu(z), p["norm"])
+        g = y.astype(z.dtype) * silu(z)
+        if groups == 1:
+            g = rmsnorm(g, p["norm"])
+        else:
+            by_group = g.reshape(*g.shape[:-1], groups, -1)
+            g = rmsnorm(by_group, p["norm"].reshape(groups, -1)).reshape(g.shape)
     with jax.named_scope("block/ssm/out_proj"):
         return linear(g, p["out_proj"])
 
@@ -128,7 +146,26 @@ def chunked_scan(x, dt, a, b, c, state, chunk: int):
     without the skip term.  Inside a chunk ``Y = ((C B^T) o L)(dt * X)``
     with ``L_ts = exp(sum_{s < r <= t} dt_r a)``; a chunk's end state is
     ``(prod decay) H + sum_s (prod_{r > s} decay_r) dt_s x_s (x) B_s``; the
-    incoming state adds ``C_t (prod_{r <= t} decay_r) H``."""
+    incoming state adds ``C_t (prod_{r <= t} decay_r) H``.
+
+    ``b`` and ``c`` of several groups (batch, T, groups, state values): the
+    heads of a group are a recurrence of their own under the group's ``B``
+    and ``C``, so the one-group scan is mapped over the groups."""
+    if b.ndim == 4:
+        groups = b.shape[2]
+
+        def by_group(v, axis):  # heads -> (groups, heads a group), groups first
+            split = v.reshape(*v.shape[:axis], groups, -1, *v.shape[axis + 1:])
+            return jnp.moveaxis(split, axis, 0)
+
+        y, state = jax.vmap(
+            lambda x, dt, a, b, c, state: chunked_scan(x, dt, a, b, c, state, chunk)
+        )(
+            by_group(x, 2), by_group(dt, 2), by_group(a, 0),
+            jnp.moveaxis(b, 2, 0), jnp.moveaxis(c, 2, 0), by_group(state, 1),
+        )
+        y, state = jnp.moveaxis(y, 0, 2), jnp.moveaxis(state, 0, 1)
+        return y.reshape(x.shape), state.reshape(state.shape[0], -1, *state.shape[3:])
     batch, t, heads, channels = x.shape
     size = min(chunk, t)
     pad = -t % size
@@ -197,14 +234,14 @@ def mamba2(
         a = -jnp.exp(p["A_log"].astype(jnp.float32))
         y, ssm = chunked_scan(x, dt, a, b, c, state["ssm"], config.ssm_chunk)
         y = y + p["D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
-    out = _gate_out(y.reshape(batch, t, config.ssm_inner), z, p)
+    out = _gate_out(y.reshape(batch, t, config.ssm_inner), z, p, config.ssm_groups)
     return out, {"ssm": ssm, "conv": conv.astype(state["conv"].dtype)}
 
 
 def step_inputs(u: Array, p: dict, config: ModelConfig, conv: Array, valid: Array | None):
     """One row a sequence, ``u`` (rows, d_model) behind its ``conv`` rows
-    (rows, k - 1, channels): ``(z, x (rows, heads, channels), b, c, dt
-    (rows, heads) float32, a, the next conv rows)``; rows that are not
+    (rows, k - 1, channels): ``(z, x (rows, heads, channels), b, c (as
+    `_split_xbc` gives them), dt (rows, heads) float32, a, the next conv rows)``; rows that are not
     ``valid`` get ``dt = 0`` and keep their conv rows."""
     z, xbc_pre, dt_raw = _project(u, p, config)
     with jax.named_scope("block/ssm/conv"):
@@ -220,7 +257,7 @@ def step_inputs(u: Array, p: dict, config: ModelConfig, conv: Array, valid: Arra
 
 def step_output(y: Array, z: Array, p: dict, config: ModelConfig) -> Array:
     """``y`` (rows, heads, channels) float32 -> (rows, d_model)."""
-    return _gate_out(y.reshape(y.shape[0], config.ssm_inner), z, p)
+    return _gate_out(y.reshape(y.shape[0], config.ssm_inner), z, p, config.ssm_groups)
 
 
 def mamba2_step(

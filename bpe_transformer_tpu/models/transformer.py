@@ -87,36 +87,41 @@ def init_params(
                 }
             )
             continue
-        if config.ffn_type == "moe":
-            from bpe_transformer_tpu.models.moe import init_moe_params
-
-            ffn_params = init_moe_params(k[4], config, dtype)
-        else:
-            ffn_params = {
-                "w1": dense(k[4], ff, d),
-                "w2": dense(k[5], d, ff),
-                "w3": dense(k[6], ff, d),
-            }
+        # The layer's sublayers by its kind (`ModelConfig.layer_kinds`): a
+        # mixer under ln1, the feed-forward part under ln2 (under ln1 too
+        # in the parallel block), or one of the two alone.
+        layer = {}
         if config.layer_is_ssm(i):
             from bpe_transformer_tpu.models.ssm import init_ssm_params
 
-            mixer = {"ssm": init_ssm_params(k[0], config, dtype)}
+            layer["ssm"] = init_ssm_params(k[0], config, dtype)
         elif config.eva_block:
             from bpe_transformer_tpu.models.eva import init_eva_params
 
-            mixer = {"attn": init_eva_params(k[0], config, dtype)}
-        else:
-            mixer = {
-                "attn": {
-                    "q_proj": dense(k[0], d_q, d),
-                    "k_proj": dense(k[1], d_kv, d),
-                    "v_proj": dense(k[2], d_kv, d),
-                    "output_proj": dense(k[3], d, d_q),
-                }
+            layer["attn"] = init_eva_params(k[0], config, dtype)
+        elif config.layer_mixer(i):
+            layer["attn"] = {
+                "q_proj": dense(k[0], d_q, d),
+                "k_proj": dense(k[1], d_kv, d),
+                "v_proj": dense(k[2], d_kv, d),
+                "output_proj": dense(k[3], d, d_q),
             }
-        layers.append({**mixer, "ln1": unit((d,), dtype), "ffn": ffn_params})
-        if not config.parallel_block:  # one norm a block otherwise
-            layers[-1]["ln2"] = unit((d,), dtype)
+        if config.layer_mixer(i):
+            layer["ln1"] = unit((d,), dtype)
+        if config.layer_has_ffn(i):
+            if config.ffn_type == "moe":
+                from bpe_transformer_tpu.models.moe import init_moe_params
+
+                layer["ffn"] = init_moe_params(k[4], config, dtype)
+            else:
+                layer["ffn"] = {
+                    "w1": dense(k[4], ff, d),
+                    "w2": dense(k[5], d, ff),
+                    "w3": dense(k[6], ff, d),
+                }
+            if not config.parallel_block:  # one norm a block otherwise
+                layer["ln2"] = unit((d,), dtype)
+        layers.append(layer)
     params = {
         "token_embeddings": dense(keys[0], v, d),
         "layers": layers,

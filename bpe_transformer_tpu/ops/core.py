@@ -94,6 +94,11 @@ def window_causal_mask(seq_len: int, window: int) -> Array:
     return (j <= i) & (i - j < window)
 
 
+def relu2(x: Array) -> Array:
+    """``relu(x)^2``, the squared ReLU."""
+    return jnp.square(jax.nn.relu(x))
+
+
 def silu(x: Array) -> Array:
     """``x * sigmoid(x)``."""
     return x * jax.nn.sigmoid(x)

@@ -14,7 +14,8 @@ bounded-compile-count guarantees), with three new behaviors:
   (`models/decode.py`: `DenseRows`, `GroupedPages` for a config with
   sliding-window layers, `LatentRows` for latent attention, `RecurrentRows`
   where state-space layers keep a recurrent state a slot beside the K/V of
-  the attention layers); the two programs here are one forward over
+  the attention layers and a layer without a mixer keeps nothing); the two
+  programs here are one forward over
   whichever the config has.  Pool
   capacity is a knob (``num_blocks``) decoupled from ``slots *
   context_length``.  **One pool is alive at a time and no program copies
@@ -431,7 +432,9 @@ class PagedEngine:
         #: Latent rows in the pool (`models/decode.LatentRows`).
         self.latent = config.attention_kind == "mla"
         #: State-space layers' recurrent state, a row a slot, beside the
-        #: K/V blocks of the attention layers (`models/decode.RecurrentRows`).
+        #: K/V blocks of the attention layers (`models/decode.RecurrentRows`);
+        #: which layer is which - and which has no cache of any kind - is
+        #: the config's pattern of kinds (`ModelConfig.layer_kinds`).
         self.recurrent = config.hybrid_block
         #: A summary-and-window cache (`models/decode.EvaRows`): a slot
         #: holds its open window's blocks and a block of summaries for
@@ -610,8 +613,10 @@ class PagedEngine:
         #: amortized small against the context-sized read.
         #: Attention sublayers that read the cache a tick: a layer's one, or
         #: the double layer's two.
+        #: Layers by what they keep (`ModelConfig.layer_kinds`): a state a
+        #: slot, K/V a position, or - a layer without a mixer - nothing.
         self._ssm_layers = config.ssm_layers
-        attn_layers = config.num_layers - self._ssm_layers
+        attn_layers = config.attn_layers
         self._attn_sublayers = attn_layers * config.attn_sublayers
         if self.latent:
             # One latent row a position and sublayer, no K and V.
@@ -844,7 +849,7 @@ class PagedEngine:
         """The pool entry of the first attention (sub)layer."""
         first = next(
             layer for layer in range(self.config.num_layers)
-            if not self.config.layer_is_ssm(layer)
+            if self.config.layer_mixer(layer) == "attn"
         )
         return self._pool[first * self.config.attn_sublayers]
 

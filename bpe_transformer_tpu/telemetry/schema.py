@@ -92,7 +92,12 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # optional ``ssm_tick_state_rows``: state-space slot-layers the tick
     # updated (live slots x state-space layers), and ``ssm_chunk_tokens`` /
     # ``ssm_chunk_rows``: real and bucket rows x state-space layers through
-    # the scans of the period's prefill chunks; optional
+    # the scans of the period's prefill chunks - layers counted by kind
+    # from the config's own pattern (``ModelConfig.layer_kinds``: a layer
+    # is a state-space mixer, an attention mixer, an expert layer, or a
+    # mixer and an expert layer both), as the ``moe_*`` fields count the
+    # layers that have an expert part and ``attn_kv_positions`` the
+    # attention layers alone; optional
     # ``attn_shared_kv_positions`` / ``attn_shared_slots``: key positions x
     # sublayers that tick's slots attended through the latent kernels'
     # shared pass, and the slots on the shared chain (over a latent pool
@@ -192,7 +197,10 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # ``ssm_chunk_tokens`` / ``ssm_chunk_rows`` (real and bucket rows x
     # state-space layers through the chunks' scans), ``ssm_state_resets``
     # (admissions from a zero state) and the gauge ``ssm_state_bytes``
-    # (``kv_pool_bytes`` stays K and V alone); over a latent pool alone
+    # (state and conv rows at their grouped widths - the conv rows carry
+    # every group's ``B`` and ``C`` - of the state-space layers alone;
+    # ``kv_pool_bytes`` stays K and V of the attention layers alone, and a
+    # layer without a mixer holds nothing); over a latent pool alone
     # ``attn_shared_kv_positions`` (of ``attn_kv_positions``, those the
     # ticks' slots attended through the shared pass: the chain of blocks
     # several slots' rows start with, attended once for all of them) and

@@ -502,6 +502,9 @@ def _cell_config(cell: str):
         "granite": ("granite-4.0-h-small", {
             "num_layers": 2, "attn_layer_period": 2, "attn_layer_offset": 1,
         }),
+        "nemotron": ("NVIDIA-Nemotron-3-Nano-30B-A3B-BF16", {
+            "num_layers": 3, "layer_pattern": "ME*",
+        }),
     }[cell]
     path = Path(__file__).resolve().parents[1] / f"chipbench/configs/{name}.json"
     file = json.loads(path.read_text())
@@ -519,7 +522,7 @@ def _donated(lowered_text: str) -> int:
 
 @pytest.mark.parametrize("name", ["tick", "chunk"], ids=["tick", "chunk256"])
 @pytest.mark.parametrize(
-    "cell", ["small", "medium", "cmdaplus", "longcat", "granite"]
+    "cell", ["small", "medium", "cmdaplus", "longcat", "granite", "nemotron"]
 )
 def test_the_carry_rides_through_both_programs_undonated(one_chip, on_tpu, cell, name):
     """The decode carry (ISSUE 37) lowered for the described v5e, at the
@@ -1010,6 +1013,94 @@ def test_recurrent_pool_programs(one_chip, on_tpu, name):
     shapes = {_shape_text(a) for a in leaves}
     assert shapes == {"f32[9,128,64,128]", "bf16[9,3,8448]", "bf16[2049,16,1024]"}
     assert _pool_copies(text, {"f32[9,128,64,128]", "bf16[2049,16,1024]"}) == []
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in leaves)
+
+
+# ------- NVIDIA-Nemotron-3-Nano-30B-A3B-BF16 (a sublayer a layer, groups)
+
+
+@pytest.mark.parametrize("slots", [192, 8], ids=["192_slots", "8_slots"])
+def test_ssm_state_update_by_group(one_chip, on_tpu, slots):
+    """The tick's one-step update with ``B`` and ``C`` by group at the
+    published widths: 64 heads of 64 channels in 8 groups over a state of
+    128 - one block of heads holds the 8 groups, whose rows come in as one
+    (8, 128) tile - float32 states updated in place (aliased whole, no
+    temporary of their size)."""
+    from bpe_transformer_tpu.kernels.pallas.ssm import ssm_state_update
+
+    def fn(states, ids, x, dt, a, b, c, d_skip):
+        return ssm_state_update(states, ids, x, dt, a, b, c, d_skip, path="pallas")
+
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in (
+            ((slots + 1, 64, 64, 128), F32), ((slots,), I32), ((slots, 64, 64), BF16),
+            ((slots, 64), F32), ((64,), F32), ((slots, 8, 128), BF16),
+            ((slots, 8, 128), BF16), ((64,), F32),
+        )
+    ]
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ssm_state_update" in text
+    memory = compiled.memory_analysis()
+    states = (slots + 1) * 64 * 64 * 128 * 4
+    assert memory.alias_size_in_bytes == states
+    assert memory.temp_size_in_bytes < states // 16
+
+
+@pytest.mark.parametrize(
+    "rows", [1152, 1536, 6144], ids=["tick_192_slots", "chunk_256", "chunk_1024"]
+)
+@pytest.mark.parametrize("d_out,d_in", [(1856, 2688), (2688, 1856)], ids=["up", "down"])
+def test_grouped_matmul_at_nemotron_widths(one_chip, monkeypatch, rows, d_out, d_in):
+    """6 assignments a token, 64 experts of 1,856 held, hidden 2,688 (21 x
+    128): an output width and a contraction of 1,856 = 14.5 x 128
+    (`grouped_matmul._tiling`)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _compile(
+        grouped_matmul, one_chip,
+        ((rows, d_in), BF16), ((64, d_out, d_in), BF16), ((64,), I32),
+    )
+
+
+def test_paged_decode_attention_at_nemotron_widths(one_chip):
+    """The attention layers' tick: 32 query heads over 2 KV heads of 128 - a
+    group of 16 query rows a KV head, rows of 256 lanes - 192 slots, a table
+    one context (3,072 keys) wide over the cell's 36,865 blocks."""
+
+    def fn(q, k, v, tables, counts):
+        return paged_decode_attention(q, k, v, tables, counts, interpret=False)
+
+    pool = ((36865, 16, 2 * 128), BF16)
+    _compile(
+        fn, one_chip, ((192, 32, 128), BF16), pool, pool, ((192, 192), I32),
+        ((192,), I32),
+    )
+
+
+@pytest.mark.parametrize("name", ["tick", "chunk"], ids=["tick", "chunk256"])
+def test_pool_programs_of_one_sublayer_layers(one_chip, on_tpu, name):
+    """The engine's two programs over a Mamba-2 layer, an expert layer and
+    an attention layer of one sublayer each at the published widths: the
+    kernels are there (the chunk scans and attends in XLA), the expert
+    layer's grouped matmuls are two an expert layer, the layer without a
+    mixer has no pool entry, and the pool - state rows, conv rows, K and V -
+    is aliased whole and never copied."""
+    config = _cell_config("nemotron")
+    jitted, args, pool = _pool_program(name, config, one_chip, None, slots=8)
+    assert [sorted(entry) for entry in pool] == [["conv", "ssm"], [], ["k", "v"]]
+    compiled = jitted.lower(*args).compile()
+    text = compiled.as_text()
+    calls = [l.split("=")[0] for l in text.splitlines() if " custom-call(" in l]
+    assert any("ssm_state_update" in c for c in calls) == (name == "tick")
+    assert any("paged_decode_attention" in c for c in calls) == (name == "tick")
+    assert sum("gmm" in c for c in calls) == 2
+    assert [line for line in _sorts(text) if f",{config.vocab_size}]" in line] == []
+    leaves = jax.tree_util.tree_leaves(pool)
+    shapes = {_shape_text(a) for a in leaves}
+    assert shapes == {"f32[9,64,64,128]", "bf16[9,3,6144]", "bf16[2049,16,256]"}
+    assert _pool_copies(text, {"f32[9,64,64,128]", "bf16[2049,16,256]"}) == []
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in leaves)
 

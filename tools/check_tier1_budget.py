@@ -129,7 +129,17 @@ DEFAULT_BUDGET_S = 800.0
 #: counters by hand (tests/test_longcatflash.py, 29 cases in about 50 s in
 #: one process), and the kernel at the published widths compiled for the
 #: described v5e (tests/test_chip_compile.py, 4 cases, 2-4 s each).
-DEFAULT_MAX_TESTS = 1200
+#: Raised 1200 -> 1275 in PR 42 (1,231 collected, 74 added): the block of
+#: one-sublayer layers against its reference on every path - the grouped
+#: mixer's three forms, the grouped state-update kernel interpreted at four
+#: head layouts, the dense cache, paged chunks and ticks, the counters by
+#: the pattern's own layers, the share test, nine parts of the block each
+#: left out, every refusal with its message (tests/test_nemotronh.py, 61
+#: cases in about 90 s with six workers), and the grouped kernel, the grouped
+#: matmul and the paged decode kernel at its widths, the two pool programs
+#: and the carry compiled for the described v5e (tests/test_chip_compile.py,
+#: 13 cases, 1-10 s each); the whole run 558 s with six workers.
+DEFAULT_MAX_TESTS = 1275
 
 #: Pytest summary trailer: "== 398 passed, 27 deselected in 612.34s =="
 #: (also plain "in 612.34s (0:10:12)" forms).
