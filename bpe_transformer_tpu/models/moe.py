@@ -199,6 +199,29 @@ def switch_ffn(
 
 # ------------------------------------------------------------ dropless path
 
+#: The name under which a served tree holds an expert layer's down
+#: projection as ``(experts, d_ff, d_model)`` in place of the torch-layout
+#: ``"w2"`` (`serving_layout`).
+W2_RELAID = "w2_relaid"
+
+
+def serving_layout(moe_params: dict) -> dict:
+    """One expert layer's tree as a serving engine holds it: the routed
+    experts' down projection ``"w2"`` ``(experts, d_model, d_ff)`` laid out
+    once as `W2_RELAID` ``(experts, d_ff, d_model)`` where the grouped
+    matmul reads that form without a copy
+    (`kernels/pallas/grouped_matmul.relaid_rhs`: an expert width that is not
+    a whole number of lane tiles), every other leaf - and every other tree,
+    whole - as it is.  `dropless_moe` takes whichever the tree holds."""
+    from bpe_transformer_tpu.kernels.pallas.grouped_matmul import relaid_rhs
+
+    w2 = moe_params.get("w2")
+    if w2 is None or w2.ndim != 3 or not relaid_rhs(w2.shape[-1]):
+        return moe_params
+    out = {name: leaf for name, leaf in moe_params.items() if name != "w2"}
+    out[W2_RELAID] = jnp.swapaxes(w2, 1, 2)
+    return out
+
 
 def route(
     tokens: Array, router: Array, config: ModelConfig, bias: Array | None = None
@@ -241,7 +264,9 @@ def dropless_moe(
     on the experts held here (``expert_offset .. + local_experts``) are
     sorted by expert and go through one grouped matmul per matrix of an
     expert - three of a SwiGLU, two around a squared ReLU
-    (``expert_activation``; `kernels/pallas/grouped_matmul.py`) - which
+    (``expert_activation``; `kernels/pallas/grouped_matmul.py`; the down
+    projection as the torch-layout ``"w2"`` of a raw tree or as a served
+    tree's `W2_RELAID`, the same product either way) - which
     visits only experts that got a row; each token's output is the
     gate-weighted sum of its held experts' results.  What the absent experts would add is left out: with
     ``experts_held=None`` that is nothing, with a share it is the other
@@ -310,7 +335,12 @@ def dropless_moe(
         else:
             lin = grouped_matmul(sorted_in, moe_params["w3"], group_sizes)
             hidden = silu(up) * lin
-        sorted_out = grouped_matmul(hidden, moe_params["w2"], group_sizes)
+        if W2_RELAID in moe_params:  # a served tree: `serving_layout`
+            sorted_out = grouped_matmul(
+                hidden, moe_params[W2_RELAID], group_sizes, transpose_rhs=False
+            )
+        else:
+            sorted_out = grouped_matmul(hidden, moe_params["w2"], group_sizes)
         # Back to assignment order.  Rows past rows_local were not computed
         # (their memory is whatever it was): selected out, never scaled.
         sorted_row = jnp.zeros((kn,), jnp.int32).at[order].set(

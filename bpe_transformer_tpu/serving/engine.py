@@ -65,7 +65,12 @@ def prepare_serving_weights(params, config: ModelConfig, weight_dtype):
     the tree + LM head to the compute dtype (mirrors ``generate_cached``),
     then — under ``weight_dtype="int8"`` — quantize the matmul weights
     per output channel (`ops/quant.py`), so every program the engine
-    compiles streams 1-byte weights and dequantizes in registers.
+    compiles streams 1-byte weights and dequantizes in registers.  An
+    expert layer's tree comes back in its serving layout
+    (`models/moe.serving_layout`: a down projection whose contraction width
+    is not a whole number of lane tiles laid out once, here, as the grouped
+    matmul reads it, under a name of its own; the same bytes, so both byte
+    counts are what they were), every other tree leaf for leaf as it came.
 
     Returns ``(params, lm_head, label, params_bytes, tick_weight_bytes)``:
     the (possibly quantized) tree and head copy, the ``weight_dtype``
@@ -93,6 +98,17 @@ def prepare_serving_weights(params, config: ModelConfig, weight_dtype):
     if weight_dtype == "int8":
         params = quantize_params(params, config)
         lm_head = quantize_weight(lm_head)
+    elif config.ffn_type == "moe":
+        from bpe_transformer_tpu.models.moe import serving_layout
+
+        # A layer at a time, over containers of our own: where the cast
+        # made the leaves ours too, a stack's torch layout goes as soon as
+        # its relaid form exists.
+        layers = list(params["layers"])
+        params = {**params, "layers": layers}
+        for i, layer in enumerate(layers):
+            if "ffn" in layer:
+                layers[i] = {**layer, "ffn": serving_layout(layer["ffn"])}
     label = "int8" if weight_dtype == "int8" else str(act_dtype)
     params_bytes = tree_bytes(params) + tree_bytes(lm_head)
     tick_weight_bytes = (

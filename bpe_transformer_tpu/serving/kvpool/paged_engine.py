@@ -74,6 +74,7 @@ from bpe_transformer_tpu.models.decode import (
     paged_forward,
     slot_cache,
 )
+from bpe_transformer_tpu.models.moe import W2_RELAID
 from bpe_transformer_tpu.serving.engine import (
     TOP_K_DISABLED,
     TOP_P_DISABLED,
@@ -582,6 +583,11 @@ class PagedEngine:
             self._params, self._lm_head, self.weight_dtype,
             self.params_bytes, self.tick_weight_bytes,
         ) = prepare_serving_weights(params, config, weight_dtype)
+        #: Expert layers whose down projection this engine holds relaid
+        #: (`models/moe.serving_layout`): the ``moe_relaid_layers`` gauge.
+        self.moe_relaid_layers = sum(
+            W2_RELAID in layer.get("ffn", ()) for layer in self._params["layers"]
+        )
         self.fused_sampling = bool(fused_sampling)
         self._pool = init_paged_pool(
             config, num_blocks, block_size, act_dtype, kv_dtype=kv_dtype,
@@ -934,6 +940,7 @@ class PagedEngine:
         out["moe_rows_local"] = int(self.moe_counts[1])
         out["moe_expert_groups"] = int(self.moe_counts[2])
         out["moe_zero_assignments"] = int(self.moe_counts[3])
+        out["moe_relaid_layers"] = self.moe_relaid_layers
         out["prefill_pending_tokens"] = self.pending_prefill_tokens()
         out["prefill_pending_slots"] = len(self._prefilling)
         out["ticks_overlapped"] = self.ticks_overlapped

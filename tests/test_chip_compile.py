@@ -1105,6 +1105,62 @@ def test_pool_programs_of_one_sublayer_layers(one_chip, on_tpu, name):
     assert memory.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in leaves)
 
 
+def _expert_stacks(params):
+    """The shapes of the expert layers' stacked matrices (an ``ffn`` tree's
+    leaves of three dimensions, the shared expert's included)."""
+    return {
+        _shape_text(leaf)
+        for layer in params["layers"] if "ffn" in layer
+        for leaf in jax.tree_util.tree_leaves(layer["ffn"]) if leaf.ndim == 3
+    }
+
+
+@pytest.mark.parametrize("name", ["tick", "chunk"], ids=["tick", "chunk256"])
+@pytest.mark.parametrize("cell", ["nemotron", "cmdaplus", "longcat", "granite"])
+def test_expert_stacks_are_read_where_they_rest(one_chip, on_tpu, cell, name):
+    """The tree the weight pipeline hands the engine, at the cells' widths:
+    no program copies or transposes an expert stack on its way into the
+    grouped matmul.  nemotron's down projection is there relaid, in the
+    shape its up projection has (`models/moe.serving_layout`: an expert
+    width of 14.5 lane tiles); the other cells' widths are whole tiles and
+    their trees the torch layout's."""
+    config = _cell_config(cell)
+    jitted, args, _ = _pool_program(name, config, one_chip, None, slots=8)
+    stacks = _expert_stacks(args[0])
+    relaid = "bf16[64,1856,2688]" in stacks
+    assert relaid == (cell == "nemotron")
+    assert "bf16[64,2688,1856]" not in stacks
+    compiled = jitted.lower(*args).compile()
+    assert _pool_copies(compiled.as_text(), stacks) == []
+    if relaid:  # ... and no temporary is as large as a stack
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2688 * 1856 * 2
+
+
+@pytest.mark.parametrize("name", ["tick", "chunk"], ids=["tick", "chunk256"])
+def test_a_torch_layout_stack_of_nemotrons_width_would_be_copied(
+    one_chip, on_tpu, monkeypatch, name
+):
+    """Why the pipeline relays it: over the torch-layout tree the same
+    programs copy the whole ``(64, 2688, 1856)`` stack before the grouped
+    matmul - the chip rests a parameter whose minor dimension is 14.5 lane
+    tiles with the 2,688 minor, the transposed call wants it as its shape
+    says - once an expert layer, in every launch (0.64 GB and 1.6 ms a layer
+    at the cell's size, PERF.md section 6, PR 43), and the temporaries hold
+    it.  If this stops holding the relayout is no longer needed; if the
+    test above fails, a change brought the copy back."""
+    from bpe_transformer_tpu.models import moe
+
+    monkeypatch.setattr(moe, "serving_layout", lambda ffn: ffn)
+    config = _cell_config("nemotron")
+    jitted, args, _ = _pool_program(name, config, one_chip, None, slots=8)
+    stack = "bf16[64,2688,1856]"
+    assert stack in _expert_stacks(args[0])
+    compiled = jitted.lower(*args).compile()
+    copies = _pool_copies(compiled.as_text(), {stack})
+    assert len(copies) == 1 and " copy(" in copies[0]
+    assert compiled.memory_analysis().temp_size_in_bytes > 64 * 2688 * 1856 * 2
+
+
 # ------------------------------------------- the summary-and-window cache
 
 

@@ -658,3 +658,163 @@ def test_config_properties_and_defaults():
     for field, value in [("ssm_groups", 2), ("expert_activation", "relu2")]:
         with pytest.raises(ValueError, match="hybrid block's"):
             dataclasses.replace(plain, **{field: value})
+
+
+# ------------------------------------------- the served tree's down projection
+
+
+def _relaid_case(held, offset, seed=7, rows=23):
+    c = reference_cfg(held, offset, layers=2)
+    raw = ref.weights_from_seed(seed, c)["layers"][1]["ffn"]
+    h = jax.random.normal(jax.random.PRNGKey(seed), (rows, 64), jnp.float32)
+    return h, raw, moe.serving_layout(raw), program_cfg(c)
+
+
+@pytest.mark.parametrize("share", SHARES)
+@pytest.mark.parametrize("real", [None, 0, 9, 23])
+def test_the_relaid_down_projection_is_the_same_layer(share, real):
+    """`dropless_moe` over a served tree - the down projection held as
+    ``(experts, d_ff, d_model)`` under `moe.W2_RELAID` - equals
+    `dropless_moe` over the torch-layout tree exactly, outputs and counts:
+    every row routed, only the first ``real`` rows ``valid``, all experts
+    held and a share of them at an ``expert_offset``."""
+    h, raw, served, config = _relaid_case(*SHARES[share])
+    assert "w2" not in served and set(served) - set(raw) == {moe.W2_RELAID}
+    assert served[moe.W2_RELAID].shape == (config.local_experts, 16, 64)
+    assert all(served[k] is raw[k] for k in raw if k != "w2")
+    valid = None if real is None else jnp.arange(h.shape[0]) < real
+    want, want_counts = moe.dropless_moe(h, raw, config, valid=valid)
+    got, got_counts = moe.dropless_moe(h, served, config, valid=valid)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got_counts), np.asarray(want_counts))
+    assert int(want_counts[1]) > 0 or real == 0
+    assert float(jnp.max(jnp.abs(want))) > 1e-3
+
+
+def test_grouped_matmul_reads_either_form_of_its_weights():
+    from bpe_transformer_tpu.kernels.pallas.grouped_matmul import grouped_matmul, relaid_rhs
+
+    rng = np.random.default_rng(2)
+    lhs = jnp.asarray(rng.standard_normal((24, 16)), jnp.float32)
+    rhs = jnp.asarray(rng.standard_normal((4, 64, 16)), jnp.float32)
+    sizes = jnp.asarray([5, 0, 12, 3], jnp.int32)  # rows past 20: no group
+    want = grouped_matmul(lhs, rhs, sizes)
+    got = grouped_matmul(lhs, jnp.swapaxes(rhs, 1, 2), sizes, transpose_rhs=False)
+    assert got.shape == want.shape == (24, 64)
+    np.testing.assert_array_equal(np.asarray(got[:20]), np.asarray(want[:20]))
+    # The rule by width: whole lane tiles keep the torch layout.
+    assert [relaid_rhs(n) for n in (1856, 16, 768, 2048, 4096)] == [True, True, False, False, False]
+
+
+def _published(name, **cut):
+    import json
+
+    path = Path(__file__).resolve().parents[1] / f"chipbench/configs/{name}.json"
+    file = json.loads(path.read_text())
+    return ModelConfig(**{**{k: file[k] for k in file["architecture_keys"]}, **cut})
+
+
+def _pipeline_shapes(config):
+    """The raw tree's and the weight pipeline's result's leaves, by path, as
+    shapes alone (published widths: nothing is allocated)."""
+    from bpe_transformer_tpu.serving.engine import prepare_serving_weights
+
+    raw = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), config))
+    counted = []
+
+    def pipeline(params):
+        served, lm_head, _, *byte_counts = prepare_serving_weights(params, config, None)
+        counted[:] = byte_counts
+        return served, lm_head
+
+    served, _ = jax.eval_shape(pipeline, raw)
+    paths = lambda tree: {  # noqa: E731
+        jax.tree_util.keystr(path): leaf.shape
+        for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
+    }
+    return paths(raw), paths(served), raw, tuple(counted)
+
+
+def _gpt2_small():
+    from bpe_transformer_tpu.models.config import GPT2_SMALL_32K
+
+    return dataclasses.replace(GPT2_SMALL_32K, num_layers=2)
+
+
+OTHER_TREES = {
+    "granite": lambda: _published(
+        "granite-4.0-h-small", num_layers=2, attn_layer_period=2, attn_layer_offset=1
+    ),
+    "cmdaplus": lambda: _published("command-a-plus-05-2026"),
+    "longcat": lambda: _published("LongCat-Flash-Omni", num_layers=1),
+    "evabyte": lambda: _published("EvaByte", num_layers=2),
+    "gpt2-small": _gpt2_small,
+}
+
+
+@pytest.mark.parametrize("cell", OTHER_TREES)
+def test_the_weight_pipeline_leaves_the_other_trees_as_they_are(cell):
+    """Expert widths of whole lane tiles (768, 4,096, 2,048) and trees
+    without an expert layer pass `prepare_serving_weights` leaf for leaf:
+    the same paths, the same shapes."""
+    before, after, _, _ = _pipeline_shapes(OTHER_TREES[cell]())
+    assert after == before
+    assert not any(moe.W2_RELAID in path for path in after)
+
+
+def test_the_weight_pipeline_relays_nemotrons_down_projections():
+    """At the published widths and the cell's 13 layers the served tree
+    holds ``w2_relaid`` (held, 1,856, 2,688) - the shape ``w1`` has - where
+    the raw tree holds ``w2`` (held, 2,688, 1,856), in each of the five
+    expert layers; every other leaf is as it came - the shared expert's
+    ``w2`` too - and both byte counts are the torch-layout tree's."""
+    from bpe_transformer_tpu.ops.quant import tree_bytes
+
+    config = _published("NVIDIA-Nemotron-3-Nano-30B-A3B-BF16")
+    before, after, raw, (params_bytes, tick_weight_bytes) = _pipeline_shapes(config)
+    moved = {path for path in before if path.endswith("['ffn']['w2']")}
+    assert len(moved) == config.layer_pattern.count("E") == 5
+    assert all(before[p] == (64, 2688, 1856) for p in moved)
+    relaid = {p.replace("['w2']", f"['{moe.W2_RELAID}']") for p in moved}
+    assert set(after) == set(before) - moved | relaid
+    assert all(after[p] == (64, 1856, 2688) for p in relaid)
+    assert all(after[p] == before[p] for p in set(after) - relaid)
+    assert before["['layers'][1]['ffn']['shared']['w2']"] == (1, 2688, 3712)
+    assert after["['layers'][1]['ffn']['w1']"] == (64, 1856, 2688)
+    half = lambda tree: tree_bytes(tree) // 2  # noqa: E731  float32 -> bfloat16
+    assert params_bytes == half(raw) + half(raw["lm_head"])
+    assert tick_weight_bytes == (
+        half(raw["layers"]) + half(raw["ln_final"]) + half(raw["lm_head"])
+    )
+
+
+def test_the_pipeline_does_not_touch_the_callers_tree():
+    from bpe_transformer_tpu.serving.engine import prepare_serving_weights
+
+    c = reference_cfg(4, 4)
+    raw = ref.weights_from_seed(3, c)
+    layers = list(raw["layers"])
+    ffns = [dict(layer["ffn"]) for layer in layers if "ffn" in layer]
+    served = prepare_serving_weights(raw, program_cfg(c), None)[0]
+    assert raw["layers"] == layers and all(a is b for a, b in zip(raw["layers"], layers))
+    kept = [layer["ffn"] for layer in raw["layers"] if "ffn" in layer]
+    assert [set(f) for f in kept] == [set(f) for f in ffns]
+    assert all(f[k] is g[k] for f, g in zip(kept, ffns) for k in g)
+    assert all(
+        moe.W2_RELAID in layer["ffn"] for layer in served["layers"] if "ffn" in layer
+    )
+
+
+def test_the_engine_says_how_many_layers_it_holds_relaid():
+    """``moe_relaid_layers``: every expert layer of the pattern here (a
+    width of 16 is no whole lane tile), none where the experts' width is
+    one or the tree has no expert layer."""
+    eng = small_engine(reference_cfg(4, 4))
+    assert eng.gauges()["moe_relaid_layers"] == PATTERN.count("E") == 3
+    wide = reference_cfg(4, 4, moe_intermediate_size=128)
+    assert small_engine(wide).gauges()["moe_relaid_layers"] == 0
+    from bpe_transformer_tpu.models.config import TS_TEST_CONFIG
+
+    dense = dataclasses.replace(TS_TEST_CONFIG, num_layers=1, context_length=32)
+    plain = PagedEngine(init_params(jax.random.PRNGKey(0), dense), dense, slots=2, block_size=4)
+    assert plain.gauges()["moe_relaid_layers"] == 0
