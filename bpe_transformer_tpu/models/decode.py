@@ -1218,11 +1218,12 @@ class LatentRows(_RoutingCounts):
 
 # A state-space layer keeps no rows of positions: one recurrent state a
 # sequence, whatever its length (`models/ssm.py`).  Its pool entry is a row
-# a SLOT - ``{"ssm": (slots + 1, heads, head_dim, state) float32, "conv":
-# (slots + 1, k - 1, channels)}``, the last row trash, as block 0 is of the
-# pools of positions - beside the K and V pools of the config's attention
-# layers, which are `DenseRows`' own, and the empty entries of the layers
-# that have no mixer and so no cache of any kind.
+# a SLOT - ``{"ssm": (slots + 1, ...) float32 in the resting layout of
+# `kernels/pallas/ssm.py` (the channels along the lanes: that module's
+# docstring describes it), "conv": (slots + 1, k - 1, channels)}``, the last
+# row trash, as block 0 is of the pools of positions - beside the K and V
+# pools of the config's attention layers, which are `DenseRows`' own, and the
+# empty entries of the layers that have no mixer and so no cache of any kind.
 
 
 def init_recurrent_pool(
@@ -1232,14 +1233,20 @@ def init_recurrent_pool(
     """A layer's entry by its mixer (`ModelConfig.layer_mixer`):
     `init_kv_pool`'s K and V rows for an attention layer, zeroed state rows
     for a state-space layer, an empty entry for a layer without one."""
+    from bpe_transformer_tpu.kernels.pallas.ssm import to_resting
     from bpe_transformer_tpu.models.ssm import init_ssm_state
 
     kv_heads = config.num_kv_heads or config.num_heads
     shape = (num_blocks, block_size, kv_heads * config.d_head)
 
+    def rows_a_slot():  # `init_ssm_state`'s rows, the states where they rest
+        state = init_ssm_state(config, slots + 1, dtype)
+        return {**state, "ssm": to_resting(state["ssm"], config.ssm_groups)}
+
     def entry(mixer):
-        if mixer == "ssm":
-            return init_ssm_state(config, slots + 1, dtype)
+        if mixer == "ssm":  # zeros at the shapes alone: nothing is relaid
+            shapes = jax.eval_shape(rows_a_slot)
+            return {name: jnp.zeros(s.shape, s.dtype) for name, s in shapes.items()}
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)} if mixer else {}
 
     return [entry(config.layer_mixer(i)) for i in range(config.num_layers)]
@@ -1254,13 +1261,16 @@ class RecurrentRows(_RoutingCounts):
     * a tick's row ``s`` is slot ``s``; rows that are not ``valid`` (idle
       slots, slots still prefilling) are sent to the trash row, so a tick
       never touches their state (`kernels/pallas/ssm.ssm_state_update`
-      updates the addressed rows in place), and keep their conv rows;
+      updates the addressed rows in place, where they rest: that module
+      describes the layout), and keep their conv rows;
     * a chunk is one slot's, ``tables = {"blocks": its table row, "slot":
       its id}``: it starts from the slot's state, **or from zeros where it
       starts at position 0** (an admission: nothing of the slot's last
       tenant is read), runs the chunked scan over its bucket with the
       padded rows masked out of state and conv rows, and leaves the state
-      after its last real row for the prompt's next chunk or first tick.
+      after its last real row for the prompt's next chunk or first tick
+      (the scan's ``(heads, channels, state values)`` is one slot's
+      relayout from and to the pool's, `from_resting` / `to_resting`).
 
     Several rows a slot (a verify pass) would need the state of every row
     to roll back to: no form here.  Routing counts ride along as in
@@ -1296,7 +1306,11 @@ class RecurrentRows(_RoutingCounts):
         """One state-space layer: ``h`` (slots, rows, d_model) -> the same
         shape; the layer's state rows, updated, are appended to
         ``new_pool``."""
-        from bpe_transformer_tpu.kernels.pallas.ssm import ssm_state_update
+        from bpe_transformer_tpu.kernels.pallas.ssm import (
+            from_resting,
+            ssm_state_update,
+            to_resting,
+        )
         from bpe_transformer_tpu.models import ssm as mamba
 
         config = self.config
@@ -1322,8 +1336,10 @@ class RecurrentRows(_RoutingCounts):
                 ).astype(arr.dtype)
                 for name, arr in layer_pool.items()
             }
+            state["ssm"] = from_resting(state["ssm"], config.ssm_head_dim)
         out, state = mamba.mamba2(h, ssm, config, state, self.ffn_rows[None])
         with jax.named_scope("pool_write"):
+            state["ssm"] = to_resting(state["ssm"], config.ssm_groups)
             new_pool.append({
                 name: lax.dynamic_update_slice_in_dim(
                     arr, state[name].astype(arr.dtype), self.slot, 0

@@ -265,11 +265,16 @@ def mamba2_step(
 ) -> tuple[Array, dict]:
     """One position a sequence: ``u`` (batch, d_model) and the batch's
     ``state`` -> ``((batch, d_model), state)``."""
-    from bpe_transformer_tpu.kernels.pallas.ssm import xla_ssm_state_update
+    from bpe_transformer_tpu.kernels.pallas.ssm import (
+        from_resting,
+        to_resting,
+        xla_ssm_state_update,
+    )
 
     z, x, b, c, dt, a, conv = step_inputs(u, p, config, state["conv"], valid)
     y, ssm = xla_ssm_state_update(
-        state["ssm"], jnp.arange(u.shape[0]), x, dt, a, b, c,
-        p["D"].astype(jnp.float32),
+        to_resting(state["ssm"], config.ssm_groups), jnp.arange(u.shape[0]), x, dt,
+        a, b, c, p["D"].astype(jnp.float32),
     )
+    ssm = from_resting(ssm, config.ssm_head_dim)
     return step_output(y, z, p, config), {"ssm": ssm, "conv": conv}

@@ -455,6 +455,39 @@ def _pool_copies(text, pool_shapes):
     ]
 
 
+def _relayouts_around(text, kernel):
+    """The ``copy`` and ``transpose`` instructions of a compiled program that
+    sit around the Mosaic call named ``kernel``: those that make one of its
+    operands or take one of its results (through bitcasts, reshapes and tuple
+    elements, which move nothing), and those XLA made for an operation traced
+    in the kernel's scope (its metadata says so)."""
+    import re
+
+    lines = [line.strip() for line in text.splitlines()]
+    defs = {line.split(" = ", 1)[0]: line for line in lines if " = " in line}
+    moves = re.compile(r" = \S+ (copy|transpose)\(")
+    passes = re.compile(r" = \S+ (bitcast|reshape|get-tuple-element)\(")
+    (call,) = [l for l in lines if l.startswith(f"%{kernel}") and " custom-call(" in l]
+    operands = re.findall(r"%[\w.-]+", call.split(" custom-call(", 1)[1].split("), ", 1)[0])
+
+    def made_by(name, depth=0):  # the instruction behind the no-ops
+        line = defs.get(name, "")
+        if passes.search(line) and depth < 4:
+            return made_by(re.findall(r"%[\w.-]+", line.split("(", 1)[1])[0], depth + 1)
+        return line
+
+    results, around = {call.split(" = ", 1)[0]}, [made_by(name) for name in operands]
+    for line in lines:  # users of the call's results, no-ops followed
+        used = set(re.findall(r"%[\w.-]+", line.split(" = ", 1)[-1]))
+        if " = " in line and used & results:
+            if passes.search(line):
+                results.add(line.split(" = ", 1)[0])
+            else:
+                around.append(line)
+    around += [l for l in lines if f"/{kernel}/" in l]
+    return sorted({line[:200] for line in around if moves.search(line)})
+
+
 @pytest.mark.parametrize(
     "name,kv_dtype",
     [("tick", None), ("chunk", None), ("copy_block", None),
@@ -925,27 +958,42 @@ def test_latent_pool_programs(one_chip, on_tpu, name):
 # ------------------------------ granite-4.0-h-small (state-space layers)
 
 
-@pytest.mark.parametrize("slots", [96, 8], ids=["96_slots", "8_slots"])
-def test_ssm_state_update(one_chip, on_tpu, slots):
-    """The tick's one-step update at the published widths: 128 heads of 64
-    channels over a state of 128, float32 states a row a slot and one of
-    trash, updated in place (aliased whole, no temporary of their size)."""
-    from bpe_transformer_tpu.kernels.pallas.ssm import ssm_state_update
+def _compiled_ssm_state_update(one_chip, slots, heads, channels, n, groups=1):
+    """``(compiled, the resting states' shape)``: `ssm_state_update`'s kernel
+    for the described chip over ``slots`` tick rows and ``slots + 1`` states
+    where they rest (`to_resting`'s shape), the states donated."""
+    import functools
 
-    def fn(states, ids, x, dt, a, b, c, d_skip):
-        return ssm_state_update(states, ids, x, dt, a, b, c, d_skip, path="pallas")
+    from bpe_transformer_tpu.kernels.pallas.ssm import ssm_state_update, to_resting
 
+    resting = jax.eval_shape(
+        functools.partial(to_resting, groups=groups),
+        jax.ShapeDtypeStruct((slots + 1, heads, channels, n), F32),
+    ).shape
+    by_row = (slots, n) if groups == 1 else (slots, groups, n)
     args = [
         jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
         for shape, dtype in (
-            ((slots + 1, 128, 64, 128), F32), ((slots,), I32), ((slots, 128, 64), BF16),
-            ((slots, 128), F32), ((128,), F32), ((slots, 128), BF16),
-            ((slots, 128), BF16), ((128,), F32),
+            (resting, F32), ((slots,), I32), ((slots, heads, channels), BF16),
+            ((slots, heads), F32), ((heads,), F32), (by_row, BF16), (by_row, BF16),
+            ((heads,), F32),
         )
     ]
+    fn = functools.partial(ssm_state_update, path="pallas")
     compiled = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "ssm_state_update" in text
+    return compiled, resting
+
+
+@pytest.mark.parametrize("slots", [96, 8], ids=["96_slots", "8_slots"])
+def test_ssm_state_update(one_chip, on_tpu, slots):
+    """The tick's one-step update at the published widths: 128 heads of 64
+    channels over a state of 128 - resting two heads a lane row, ``(slots +
+    1, 64, 128, 128)`` - float32 states a row a slot and one of trash,
+    updated in place (aliased whole, no temporary of their size)."""
+    compiled, resting = _compiled_ssm_state_update(one_chip, slots, 128, 64, 128)
+    assert resting == (slots + 1, 64, 128, 128)
     memory = compiled.memory_analysis()
     states = (slots + 1) * 128 * 64 * 128 * 4
     assert memory.alias_size_in_bytes == states
@@ -1008,11 +1056,15 @@ def test_recurrent_pool_programs(one_chip, on_tpu, name):
     assert ("ssm_state_update" in calls) == (name == "tick")
     assert ("paged_decode_attention" in calls) == (name == "tick")
     assert "gmm" in calls
+    if name == "tick":
+        # What the kernel takes lies as the layers before it left it, and its
+        # rows leave as the gate reads them: no copy, no transpose.
+        assert _relayouts_around(text, "ssm_state_update") == []
     assert [line for line in _sorts(text) if f",{config.vocab_size}]" in line] == []
     leaves = jax.tree_util.tree_leaves(pool)
     shapes = {_shape_text(a) for a in leaves}
-    assert shapes == {"f32[9,128,64,128]", "bf16[9,3,8448]", "bf16[2049,16,1024]"}
-    assert _pool_copies(text, {"f32[9,128,64,128]", "bf16[2049,16,1024]"}) == []
+    assert shapes == {"f32[9,64,128,128]", "bf16[9,3,8448]", "bf16[2049,16,1024]"}
+    assert _pool_copies(text, {"f32[9,64,128,128]", "bf16[2049,16,1024]"}) == []
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in leaves)
 
@@ -1024,29 +1076,35 @@ def test_recurrent_pool_programs(one_chip, on_tpu, name):
 def test_ssm_state_update_by_group(one_chip, on_tpu, slots):
     """The tick's one-step update with ``B`` and ``C`` by group at the
     published widths: 64 heads of 64 channels in 8 groups over a state of
-    128 - one block of heads holds the 8 groups, whose rows come in as one
-    (8, 128) tile - float32 states updated in place (aliased whole, no
-    temporary of their size)."""
-    from bpe_transformer_tpu.kernels.pallas.ssm import ssm_state_update
-
-    def fn(states, ids, x, dt, a, b, c, d_skip):
-        return ssm_state_update(states, ids, x, dt, a, b, c, d_skip, path="pallas")
-
-    args = [
-        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-        for shape, dtype in (
-            ((slots + 1, 64, 64, 128), F32), ((slots,), I32), ((slots, 64, 64), BF16),
-            ((slots, 64), F32), ((64,), F32), ((slots, 8, 128), BF16),
-            ((slots, 8, 128), BF16), ((64,), F32),
-        )
-    ]
-    compiled = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text and "ssm_state_update" in text
+    128 - resting two heads a lane row, ``(slots + 1, 32, 128, 128)``; one
+    block of head rows holds the 8 groups - float32 states updated in place
+    (aliased whole, no temporary of their size)."""
+    compiled, resting = _compiled_ssm_state_update(one_chip, slots, 64, 64, 128, 8)
+    assert resting == (slots + 1, 32, 128, 128)
     memory = compiled.memory_analysis()
     states = (slots + 1) * 64 * 64 * 128 * 4
     assert memory.alias_size_in_bytes == states
     assert memory.temp_size_in_bytes < states // 16
+
+
+@pytest.mark.parametrize(
+    "slots, heads, channels, n, groups, resting",
+    [(8, 5, 64, 128, 1, (9, 5, 128, 64)), (8, 6, 64, 128, 6, (9, 6, 128, 64)),
+     (8, 3, 256, 128, 1, (9, 3, 128, 256)), (12, 16, 64, 128, 2, (13, 8, 128, 128)),
+     (8, 24, 64, 64, 1, (9, 12, 64, 128))],
+    ids=["odd_heads", "a_head_a_group", "channels_over_a_row", "12_rows_2_groups", "state_of_64"],
+)
+def test_ssm_state_update_where_the_packing_does_not_fit(
+    one_chip, on_tpu, slots, heads, channels, n, groups, resting
+):
+    """Shapes no served configuration has: odd heads, a group of one head
+    and channels wider than a lane row rest a head a row (``k = 1``) and take
+    the same body; rows that are no whole tile of 8, a narrower state."""
+    import math
+
+    compiled, rests = _compiled_ssm_state_update(one_chip, slots, heads, channels, n, groups)
+    assert rests == resting
+    assert compiled.memory_analysis().alias_size_in_bytes == 4 * math.prod(resting)
 
 
 @pytest.mark.parametrize(
@@ -1096,11 +1154,13 @@ def test_pool_programs_of_one_sublayer_layers(one_chip, on_tpu, name):
     assert any("ssm_state_update" in c for c in calls) == (name == "tick")
     assert any("paged_decode_attention" in c for c in calls) == (name == "tick")
     assert sum("gmm" in c for c in calls) == 2
+    if name == "tick":
+        assert _relayouts_around(text, "ssm_state_update") == []
     assert [line for line in _sorts(text) if f",{config.vocab_size}]" in line] == []
     leaves = jax.tree_util.tree_leaves(pool)
     shapes = {_shape_text(a) for a in leaves}
-    assert shapes == {"f32[9,64,64,128]", "bf16[9,3,6144]", "bf16[2049,16,256]"}
-    assert _pool_copies(text, {"f32[9,64,64,128]", "bf16[2049,16,256]"}) == []
+    assert shapes == {"f32[9,32,128,128]", "bf16[9,3,6144]", "bf16[2049,16,256]"}
+    assert _pool_copies(text, {"f32[9,32,128,128]", "bf16[2049,16,256]"}) == []
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in leaves)
 

@@ -184,27 +184,138 @@ def test_rows_that_are_not_valid_leave_the_state_alone(real):
     assert all(bool(jnp.all(kept[name] == before[name])) for name in before)
 
 
-def test_the_kernel_updates_what_the_xla_update_updates():
-    """`ssm_state_update` in interpret mode against its XLA stand-in: rows
-    in any order, two rows sent to trash, the rest of the states untouched
-    bit for bit."""
+def written_out_update(state, ids, x, dt, a, b, c, d_skip):
+    """The step as `kernels/pallas/ssm.py` writes it out, over states that
+    lie ``(slots + 1, heads, channels, state values)``: what both paths over
+    the resting layout are held to."""
+    x32, b, c = (v.astype(jnp.float32) for v in (x, b, c))
+    if b.ndim == 3:  # each head its group's rows
+        b, c = (jnp.repeat(v, x.shape[1] // v.shape[1], axis=1) for v in (b, c))
+    else:
+        b, c = (jnp.broadcast_to(v[:, None], (v.shape[0], x.shape[1], v.shape[1])) for v in (b, c))
+    new = (
+        state[ids] * jnp.exp(dt * a)[:, :, None, None]
+        + (dt[:, :, None] * x32)[..., None] * b[:, :, None, :]
+    )
+    y = jnp.einsum("shpn,shn->shp", new, c, precision=jax.lax.Precision.HIGHEST)
+    return y + d_skip[None, :, None] * x32, state.at[ids].set(new)
+
+
+def update_case(heads, channels, n, groups=1, slots=6, tiles=0):
+    """Rows in any order, two of them sent to trash with ``dt = 0``: five
+    rows over 6 slots, or ``tiles`` whole tiles of 8 rows over 6 slots a
+    tile (the first tile's five rows again in each)."""
     rng = np.random.default_rng(7)
-    slots, heads, channels, n = 6, 16, 8, 128
+    ids, live = np.asarray([3, slots, 0, slots, 5]), np.asarray([1, 0, 1, 0, 1])
+    if tiles:  # slots 1, 2 and 4 of every tile's six stay unaddressed
+        at = [t * slots + np.asarray([3, 0, 5, 3, 0, 5, 3, 0]) for t in range(tiles)]
+        ids = np.concatenate([np.where(np.arange(8) < 3, a, tiles * slots) for a in at])
+        live, slots = (ids < tiles * slots).astype(int), tiles * slots
+    rows = len(ids)
+    by_row = (rows, n) if groups == 1 else (rows, groups, n)
     state = jnp.asarray(rng.normal(size=(slots + 1, heads, channels, n)), jnp.float32)
-    ids = jnp.asarray([3, slots, 0, slots, 5], jnp.int32)
-    x = jnp.asarray(rng.normal(size=(5, heads, channels)), jnp.float32)
-    dt = jnp.asarray(rng.uniform(0.01, 1.0, (5, heads)), jnp.float32)
-    dt = dt * jnp.asarray([1, 0, 1, 0, 1], jnp.float32)[:, None]  # trash rows: dt = 0
+    x = jnp.asarray(rng.normal(size=(rows, heads, channels)), jnp.float32)
+    dt = jnp.asarray(rng.uniform(0.01, 1.0, (rows, heads)), jnp.float32)
+    dt = dt * jnp.asarray(live, jnp.float32)[:, None]  # trash rows: dt = 0
     a = -jnp.asarray(rng.uniform(1, 16, heads), jnp.float32)
-    b, c = (jnp.asarray(rng.normal(size=(5, n)), jnp.float32) for _ in range(2))
+    b, c = (jnp.asarray(rng.normal(size=by_row), jnp.float32) for _ in range(2))
     d_skip = jnp.asarray(rng.normal(size=heads), jnp.float32)
-    want_y, want = ssm_kernel.ssm_state_update(state, ids, x, dt, a, b, c, d_skip, path="xla")
-    got_y, got = ssm_kernel.ssm_state_update(state, ids, x, dt, a, b, c, d_skip, path="pallas")
+    return state, jnp.asarray(ids, jnp.int32), x, dt, a, b, c, d_skip
+
+
+def updated_where_it_rests(path, state, ids, x, dt, a, b, c, d_skip):
+    """`ssm_state_update` over the resting layout, its states handed back as
+    they were given; the rows nobody addressed rest bit for bit."""
+    groups = 1 if b.ndim == 2 else b.shape[1]
+    rests = ssm_kernel.to_resting(state, groups)
+    y, got = ssm_kernel.ssm_state_update(rests, ids, x, dt, a, b, c, d_skip, path=path)
+    assert got.shape == rests.shape and got.dtype == jnp.float32
+    for row in (1, 2, 4):
+        assert bool(jnp.all(got[row] == rests[row]))
+    return y, ssm_kernel.from_resting(got, x.shape[2])
+
+
+@pytest.mark.parametrize("path", ["xla", "pallas"])
+@pytest.mark.parametrize(
+    "heads, channels, n, k, tiles",
+    [(16, 64, 16, 2, 0), (128, 64, 16, 2, 0), (128, 64, 16, 2, 2), (5, 64, 16, 1, 0),
+     (16, 8, 128, 16, 0), (4, 128, 16, 1, 0)],
+    ids=["two_heads_a_row", "two_blocks_of_rows", "two_blocks_two_tiles_of_rows", "odd_heads",
+         "sixteen_heads_a_row", "a_head_fills_a_row"],
+)
+def test_the_kernel_updates_what_the_xla_update_updates(path, heads, channels, n, k, tiles):
+    """`ssm_state_update` over the resting layout - the XLA stand-in, and
+    the kernel in interpret mode - against the recurrence written out over
+    ``(heads, channels, state values)``: rows in any order, two rows sent to
+    trash, the rest of the states untouched bit for bit; five rows (one
+    tile of them), or two tiles of 8 (a row is then one sublane of its
+    tile's ``x``, ``b``, ``c`` and ``y``)."""
+    case = update_case(heads, channels, n, tiles=tiles)
+    slots = case[0].shape[0]
+    assert ssm_kernel.heads_a_row(heads, channels) == k
+    assert ssm_kernel.to_resting(case[0]).shape == (slots, heads // k, n, k * channels)
+    want_y, want = written_out_update(*case)
+    got_y, got = updated_where_it_rests(path, *case)
     assert float(jnp.max(jnp.abs(got_y - want_y))) < 1e-4
     assert float(jnp.max(jnp.abs(got - want))) < 1e-5
-    for row in (1, 2, 4):
-        assert bool(jnp.all(got[row] == state[row]))
-    assert float(jnp.max(jnp.abs(got[3] - state[3]))) > 0.1
+    assert float(jnp.max(jnp.abs(got[3] - case[0][3]))) > 0.1
+
+
+@pytest.mark.parametrize(
+    "heads, channels, groups, k",
+    [(128, 64, 1, 2), (64, 64, 8, 2), (8, 16, 1, 8), (8, 16, 4, 1), (6, 64, 6, 1), (3, 256, 1, 1)],
+    ids=["granite", "nemotron", "tiny_granite", "tiny_nemotron", "a_head_a_group", "wider_than_a_row"],
+)
+def test_to_and_from_the_resting_layout_is_a_bijection(heads, channels, groups, k):
+    """Every element has one place: there and back is the identity both
+    ways, ``k`` heads lie side by side along the lanes, and element ``[s, r,
+    n, j * channels + p]`` is ``H[p, n]`` of head ``r * k + j``."""
+    n = 8
+    state = jnp.arange(2 * heads * channels * n, dtype=jnp.float32).reshape(2, heads, channels, n)
+    rests = ssm_kernel.to_resting(state, groups)
+    assert rests.shape == (2, heads // k, n, k * channels)
+    assert bool(jnp.all(ssm_kernel.from_resting(rests, channels) == state))
+    assert bool(jnp.all(ssm_kernel.to_resting(ssm_kernel.from_resting(rests, channels), groups) == rests))
+    r, j, p, i = heads // k - 1, k - 1, channels - 2, 3
+    assert float(rests[1, r, i, j * channels + p]) == float(state[1, r * k + j, p, i])
+
+
+@pytest.mark.parametrize("start", [0, 8], ids=["an_admission", "a_carried_chunk"])
+def test_a_chunks_end_state_rests_in_the_pool_as_the_scan_left_it(start):
+    """`RecurrentRows.mixer` over one slot's chunk (5 real rows in a bucket
+    of 8): the state the pool then holds for the slot, read back through
+    `from_resting`, is `chunked_scan`'s end state (from zeros where the chunk
+    starts at 0, else from what the slot held), and no other row moved."""
+    from bpe_transformer_tpu.models.decode import chunk_cache, init_recurrent_pool
+
+    _, pc, p, u = mixer_case(8)
+    rng = np.random.default_rng(11)
+    layer_pool = init_recurrent_pool(pc, 9, 4, 3)[0]
+    layer_pool = {
+        name: jnp.asarray(rng.normal(size=arr.shape), arr.dtype)
+        for name, arr in layer_pool.items()
+    }
+    assert layer_pool["ssm"].shape == (3 + 1, 1, 16, 8 * 16)
+    held = {
+        "ssm": ssm_kernel.from_resting(layer_pool["ssm"][1:2], 16),
+        "conv": layer_pool["conv"][1:2],
+    }
+    cache = chunk_cache(
+        pc, {"blocks": jnp.zeros(16, jnp.int32), "slot": jnp.int32(1)},
+        jnp.int32(start), jnp.int32(5), 8, block_size=4,
+    )
+    new_pool = []
+    got_out = cache.mixer(u, p, layer_pool, new_pool)
+    want_out, want = ssm.mamba2(
+        u, p, pc, held if start else None, jnp.arange(8)[None] < 5
+    )
+    assert float(jnp.max(jnp.abs(got_out[:, :5] - want_out[:, :5]))) < 1e-5
+    (now,) = new_pool
+    assert now["ssm"].shape == layer_pool["ssm"].shape
+    assert float(jnp.max(jnp.abs(ssm_kernel.from_resting(now["ssm"][1:2], 16) - want["ssm"]))) < 1e-6
+    assert float(jnp.max(jnp.abs(now["conv"][1:2] - want["conv"]))) < 1e-6
+    for row in (0, 2, 3):
+        assert all(bool(jnp.all(now[name][row] == layer_pool[name][row])) for name in now)
 
 
 # --------------------------------------------------------- the dense cache
@@ -303,7 +414,8 @@ def test_paged_chunks_and_ticks_match_reference(update, monkeypatch):
     # State rows a slot (and trash) beside K/V blocks of the attention layers.
     kinds = [sorted(entry) for entry in eng._pool]
     assert kinds == [["conv", "ssm"], ["k", "v"], ["conv", "ssm"]]
-    assert eng._pool[0]["ssm"].shape == (3 + 1, 8, 16, 16)
+    # ... where they rest: 8 heads of 16 channels side by side along the lanes.
+    assert eng._pool[0]["ssm"].shape == (3 + 1, 1, 16, 8 * 16)
     assert eng._pool[0]["ssm"].dtype == jnp.float32
     assert eng._pool[0]["conv"].shape == (3 + 1, 3, 160)
 
