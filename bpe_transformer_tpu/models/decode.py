@@ -802,6 +802,11 @@ def _block_addresses(tables, at, valid, block_size: int, rows: int):
     return ids, (at % block_size).astype(jnp.int32)
 
 
+def _activation_width_only(kv_dtype, holder: str) -> None:
+    if kv_dtype is not None:
+        raise ValueError(f"{holder} at the activation width")
+
+
 class DenseRows:
     """`init_kv_pool`'s pool: per layer K and V rows ``(blocks, block,
     kv_heads * d_head)``, at the activation width or int8 with per-block
@@ -949,6 +954,11 @@ class DenseRows:
         return merge_heads(att)
 
     @staticmethod
+    def init_pool(config, num_blocks, block_size, dtype, *, kv_dtype=None, **_):
+        """The kind's pool: of `init_paged_pool`'s keywords a kind takes its own."""
+        return init_kv_pool(config, num_blocks, block_size, dtype, kv_dtype=kv_dtype)
+
+    @staticmethod
     def zero_counts(config=None):
         """No routing counts ride along."""
         return None
@@ -1066,6 +1076,16 @@ class GroupedPages(_RoutingCounts):
         ]
 
     @staticmethod
+    def init_pool(
+        config, num_blocks, block_size, dtype, *, kv_dtype=None,
+        num_window_blocks=0, **_,
+    ):
+        _activation_width_only(kv_dtype, "window pool groups hold K/V")
+        return init_grouped_kv_pool(
+            config, num_blocks, num_window_blocks, block_size, dtype
+        )
+
+    @staticmethod
     def attention_path(config, one_row: bool, blocks_per_slot: int, layer_pool) -> str:
         """``"ragged"``, JAX's ragged paged kernel, on the TPU; ``"xla"``,
         its stand-in, elsewhere (`kernels/pallas/ragged_attention.py`)."""
@@ -1160,6 +1180,11 @@ class LatentRows(_RoutingCounts):
         self.write_ids, self.offsets = _block_addresses(
             tables, at, valid, block_size, rows
         )
+
+    @staticmethod
+    def init_pool(config, num_blocks, block_size, dtype, *, kv_dtype=None, **_):
+        _activation_width_only(kv_dtype, "a latent pool holds its rows")
+        return init_latent_pool(config, num_blocks, block_size, dtype)
 
     @staticmethod
     def attention_path(config, one_row: bool, blocks_per_slot: int, layer_pool) -> str:
@@ -1291,6 +1316,11 @@ class RecurrentRows(_RoutingCounts):
             self.state_ids = jnp.where(self.ffn_rows, jnp.arange(tokens), tokens)
         self.rows = DenseRows(config, tables, positions, valid, block_size, chunk)
         self.rope_positions = self.rows.rope_positions
+
+    @staticmethod
+    def init_pool(config, num_blocks, block_size, dtype, *, kv_dtype=None, slots=0, **_):
+        _activation_width_only(kv_dtype, "a recurrent pool holds K/V")
+        return init_recurrent_pool(config, num_blocks, block_size, slots, dtype)
 
     @staticmethod
     def attention_path(config, one_row: bool, blocks_per_slot: int, layer_pool) -> str:
@@ -1446,6 +1476,11 @@ class EvaRows:
         )
 
     @staticmethod
+    def init_pool(config, num_blocks, block_size, dtype, *, kv_dtype=None, **_):
+        _activation_width_only(kv_dtype, "a summary-and-window pool holds its rows")
+        return init_kv_pool(config, num_blocks, block_size, dtype)
+
+    @staticmethod
     def attention_path(config, one_row: bool, blocks_per_slot: int, layer_pool) -> str:
         return DenseRows.attention_path(config, one_row, blocks_per_slot, layer_pool)
 
@@ -1520,28 +1555,13 @@ def init_paged_pool(
     *, kv_dtype: str | None = None, num_window_blocks: int = 0,
     slots: int = 0,
 ):
-    """The pool of the config's cache kind.  ``num_window_blocks`` sizes the
-    window group where the kind has one, ``slots`` the state rows of a
-    recurrent one."""
-    if cache_kind(config) is EvaRows and kv_dtype is not None:
-        raise ValueError(
-            "a summary-and-window pool holds its rows at the activation width"
-        )
-    if cache_kind(config) is RecurrentRows:
-        if kv_dtype is not None:
-            raise ValueError("a recurrent pool holds K/V at the activation width")
-        return init_recurrent_pool(config, num_blocks, block_size, slots, dtype)
-    if cache_kind(config) is LatentRows:
-        if kv_dtype is not None:
-            raise ValueError("a latent pool holds its rows at the activation width")
-        return init_latent_pool(config, num_blocks, block_size, dtype)
-    if cache_kind(config) is GroupedPages:
-        if kv_dtype is not None:
-            raise ValueError("window pool groups hold K/V at the activation width")
-        return init_grouped_kv_pool(
-            config, num_blocks, num_window_blocks, block_size, dtype
-        )
-    return init_kv_pool(config, num_blocks, block_size, dtype, kv_dtype=kv_dtype)
+    """The pool of the config's cache kind (its ``init_pool``).
+    ``num_window_blocks`` sizes the window group where the kind has one,
+    ``slots`` the state rows of a recurrent one."""
+    return cache_kind(config).init_pool(
+        config, num_blocks, block_size, dtype, kv_dtype=kv_dtype,
+        num_window_blocks=num_window_blocks, slots=slots,
+    )
 
 
 def slot_cache(config, tables, positions, valid=None, *, block_size: int):

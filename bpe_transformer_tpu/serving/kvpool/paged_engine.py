@@ -14,9 +14,10 @@ bounded-compile-count guarantees), with three new behaviors:
   (`models/decode.py`: `DenseRows`, `GroupedPages` for a config with
   sliding-window layers, `LatentRows` for latent attention, `RecurrentRows`
   where state-space layers keep a recurrent state a slot beside the K/V of
-  the attention layers and a layer without a mixer keeps nothing); the two
-  programs here are one forward over
-  whichever the config has.  Pool
+  the attention layers and a layer without a mixer keeps nothing, `EvaRows`
+  for a summary-and-window cache); the two programs here are one forward
+  over whichever the config has, and what the HOST must know of the kind
+  is its host half's (`kvpool/host_cache.py`, ``PagedEngine.cache``).  Pool
   capacity is a knob (``num_blocks``) decoupled from ``slots *
   context_length``.  **One pool is alive at a time and no program copies
   it:** it rests on the device in the layout the programs index, and every
@@ -63,18 +64,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bpe_transformer_tpu.kernels.pallas import mla_attention
-from bpe_transformer_tpu.models import mla
 from bpe_transformer_tpu.models.config import ModelConfig
 from bpe_transformer_tpu.models.decode import (
     cache_kind,
     chunk_cache,
-    eva_table_geometry,
     init_paged_pool,
     paged_forward,
     slot_cache,
 )
 from bpe_transformer_tpu.models.moe import W2_RELAID
+from bpe_transformer_tpu.ops.quant import tree_bytes
 from bpe_transformer_tpu.serving.engine import (
     TOP_K_DISABLED,
     TOP_P_DISABLED,
@@ -87,11 +86,8 @@ from bpe_transformer_tpu.serving.engine import (
     prepare_serving_weights,
     sample_tokens,
 )
-from bpe_transformer_tpu.serving.kvpool.blocks import (
-    BlockAllocator,
-    NoFreeBlocksError,
-    WindowChain,
-)
+from bpe_transformer_tpu.serving.kvpool.blocks import BlockAllocator, NoFreeBlocksError
+from bpe_transformer_tpu.serving.kvpool.host_cache import HOST_HALVES
 from bpe_transformer_tpu.serving.kvpool.radix import RadixPrefixCache
 from bpe_transformer_tpu.telemetry.spans import Phase
 from bpe_transformer_tpu.utils.compile_cache import layered_program_options
@@ -306,12 +302,6 @@ class PagedSlotInfo:
     #: metadata for /statusz and cross-replica tracing, like the dense
     #: engine's SlotInfo.request_id.
     request_id: str | None = None
-    #: Over a summary-and-window cache: how many of ``block_ids``, the
-    #: leading ones, are the open window's (the rest hold summaries, a
-    #: window's in a row), and the window the slot's table row is laid out
-    #: for (-1: not yet).
-    window_blocks: int = 0
-    window: int = -1
 
 
 @dataclasses.dataclass
@@ -323,16 +313,11 @@ class _Launch:
     #: ``(slot, tenant)`` of every row the host will emit: the slots live at
     #: dispatch and who held each (a final chunk: its one slot).
     rows: tuple
-    #: A tick's routing counts ``(since the last tick, its own)`` and the
-    #: state-space slot-layers it updated.
+    #: A tick's routing counts ``(since the last tick, its own)``, and what
+    #: its cache kind counted of it at dispatch (`host_cache`'s
+    #: ``before_tick``): the ``tick`` record's fields of that kind.
     moe: tuple | None = None
-    ssm_state_rows: int = 0
-    #: A latent tick's ``(key positions x sublayers, member slots)`` of the
-    #: shared pass; None without a latent pool.
-    attn_shared: tuple | None = None
-    #: Over a summary-and-window cache a tick's ``(rows attended x layers,
-    #: those of them that are summaries)``; None otherwise.
-    attn_summary: tuple | None = None
+    counts: dict = dataclasses.field(default_factory=dict)
     first: bool = False  # a final chunk: the token is its slot's first
 
 
@@ -421,26 +406,6 @@ class PagedEngine:
                 f"block_size={block_size} must divide "
                 f"context_length={ctx}"
             )
-        #: Two pool groups (`models/decode.GroupedPages`): a config with
-        #: sliding-window layers keeps a window group beside the full one.
-        #: Every other config is the one-group case: the full group alone
-        #: (`DenseRows`, or `LatentRows` under latent attention: one chain
-        #: a slot either way, so the radix prefix cache carries over).  The
-        #: programs and the pool are the cache kind's; what is read here is
-        #: the host's own bookkeeping of the window group, and what cannot
-        #: run over a kind yet.
-        self.grouped = config.has_window_layers
-        #: Latent rows in the pool (`models/decode.LatentRows`).
-        self.latent = config.attention_kind == "mla"
-        #: State-space layers' recurrent state, a row a slot, beside the
-        #: K/V blocks of the attention layers (`models/decode.RecurrentRows`);
-        #: which layer is which - and which has no cache of any kind - is
-        #: the config's pattern of kinds (`ModelConfig.layer_kinds`).
-        self.recurrent = config.hybrid_block
-        #: A summary-and-window cache (`models/decode.EvaRows`): a slot
-        #: holds its open window's blocks and a block of summaries for
-        #: every ``block_size`` blocks of the windows it has closed.
-        self.eva = config.eva_block
         if config.dropless_block and weight_dtype is not None:
             raise ValueError(
                 "weight_dtype quantizes the dense block's weight tree "
@@ -448,76 +413,21 @@ class PagedEngine:
                 "layer, its held, shared and zero experts are served at the "
                 "activation width only"
             )
-
-        def refuse(over: str, unsupported: dict) -> None:
-            for what, asked in unsupported.items():
-                if asked:
-                    raise ValueError(
-                        f"{what} is not supported over {over} "
-                        "(ROADMAP: what cannot run yet); pass it off"
-                    )
-
-        if self.latent:
-            refuse("a latent pool", {
-                'kv_dtype="int8" (latent rows have no heads to scale by)':
-                    kv_dtype is not None,
-                "fused_sampling": fused_sampling,
-            })
-        if self.recurrent:
-            refuse("a recurrent state", {
-                "prefix_cache=True (a shared chain of blocks says nothing of "
-                "the recurrent state at its end)": prefix_cache,
-                'kv_dtype="int8"': kv_dtype is not None,
-                "fused_sampling": fused_sampling,
-            })
-        if self.eva:
-            refuse("a summary-and-window cache", {
-                "prefix_cache=True (a cached chain's closed windows have no "
-                "exact rows left for a prompt that ends inside them)":
-                    prefix_cache,
-                'kv_dtype="int8" (a summary row shares no block scale with '
-                "the rows it pools)": kv_dtype is not None,
-                "fused_sampling (the fused tail projects the head's whole "
-                "width; generation samples prediction head 0)": fused_sampling,
-            })
-        if self.grouped:
-            refuse("window pool groups", {
-                "prefix_cache=True (the radix cache shares whole chains; a "
-                "window group recycles its blocks)": prefix_cache,
-                'kv_dtype="int8"': kv_dtype is not None,
-                "fused_sampling": fused_sampling,
-            })
-            window = config.sliding_window
-            chunk = min(prefill_chunk or ctx, ctx)
-            if window % block_size or chunk % block_size:
-                raise ValueError(
-                    f"sliding_window={window} and prefill_chunk={chunk} must "
-                    f"be multiples of block_size={block_size}"
-                )
+        #: The cache kind's host half, found here and nowhere else: what
+        #: the kind refuses, its tables and chains, what a launch lays out
+        #: and counts are asked of this object, never of the config.
+        self.cache = HOST_HALVES[cache_kind(config)](
+            config, slots=slots, block_size=block_size,
+            prefill_chunk=prefill_chunk, prefix_cache=prefix_cache,
+            kv_dtype=kv_dtype is not None, fused_sampling=fused_sampling,
+        )
         self.config = config
         self.n_slots = slots
         self.block_size = block_size
-        self.blocks_per_slot = ctx // block_size
-        #: Blocks the longest request holds at once: the table's width, or
-        #: over a summary-and-window cache a window and the summary blocks
-        #: of every window but the last (the table there also has room for
-        #: the open window's pending summaries).
-        self.max_chain = self.blocks_per_slot
-        if self.eva:
-            per_window, window_blocks, self.blocks_per_slot = (
-                eva_table_geometry(config, block_size)
-            )
-            self._eva_blocks = (per_window, window_blocks)
-            self.max_chain = self.blocks_per_slot - per_window
-            if prefill_chunk is None:
-                prefill_chunk = config.eva_window
-            if config.eva_window % min(prefill_chunk, ctx):
-                raise ValueError(
-                    f"prefill_chunk={prefill_chunk} must divide eva_window="
-                    f"{config.eva_window}: a chunk lies inside one window"
-                )
+        self.blocks_per_slot = self.cache.blocks_per_slot
+        self.max_chain = self.cache.max_chain
         if prefill_chunk is None:
-            prefill_chunk = ctx
+            prefill_chunk = self.cache.default_prefill_chunk
         if prefill_chunk < 1 or (
             prefill_chunk < ctx and prefill_chunk % block_size
         ):
@@ -543,12 +453,6 @@ class PagedEngine:
         # would never compile anyway — the compile bound only shrinks).
         chunk_ladder = tuple(b for b in ladder if b < self.prefill_chunk)
         self.buckets = chunk_ladder + (self.prefill_chunk,)
-        if self.eva and any(b % block_size for b in self.buckets):
-            raise ValueError(
-                f"prefill buckets {self.buckets} must be multiples of "
-                f"block_size={block_size}: a chunk's whole blocks are "
-                "summarised from its rows"
-            )
 
         # Pool capacity: default exactly the dense slot pool's (every slot
         # can hold a full context) + the reserved trash block; prefix
@@ -556,21 +460,6 @@ class PagedEngine:
         if num_blocks is None:
             num_blocks = slots * self.max_chain + 1
         self.allocator = BlockAllocator(num_blocks, block_size)
-        #: The window group's allocator and, per slot, its chain.  The group
-        #: is a reservation, not a knob: a chain holds at most window + one
-        #: chunk of positions, and every slot can hold that much.
-        self.window_allocator = None
-        self.window_cap = 0
-        num_window_blocks = 0
-        if self.grouped:
-            self.window_cap = min(
-                (config.sliding_window + self.prefill_chunk) // block_size,
-                self.blocks_per_slot,
-            )
-            num_window_blocks = slots * self.window_cap + 1
-            self.window_allocator = BlockAllocator(num_window_blocks, block_size)
-        self._chains: list[WindowChain | None] = [None] * slots
-        self._window_recycled = 0
         self.prefix_cache = (
             RadixPrefixCache(self.allocator) if prefix_cache else None
         )
@@ -591,119 +480,34 @@ class PagedEngine:
         self.fused_sampling = bool(fused_sampling)
         self._pool = init_paged_pool(
             config, num_blocks, block_size, act_dtype, kv_dtype=kv_dtype,
-            num_window_blocks=num_window_blocks, slots=slots,
+            **self.cache.pool_keywords,
         )
         #: "int8" for quantized pools, else the activation dtype name —
         #: the /statusz + stats() label.
         self.kv_dtype = kv_dtype or str(act_dtype)
-        kv_heads = config.num_kv_heads or config.num_heads
-        itemsize = 1 if kv_dtype == "int8" else act_dtype.itemsize
+        #: How the tick's rows attend: the cache kind's choice.
+        self.tick_attention_path = cache_kind(config).attention_path(
+            config, True, self.blocks_per_slot, self._first_attention_entry()
+        )
+        self.cache.settle(self._pool, self.tick_attention_path, self.buckets)
         #: Resident bytes of the whole KV pool (scale pools included):
-        #: int8 quarters the f32 pool (halves bf16) at fixed block count —
-        #: or, held fixed, buys 2-4x the blocks.
-        #: State-space layers' state rows are counted apart
-        #: (``ssm_state_bytes``): a slot's share of them does not grow
-        #: with its context.
-        from bpe_transformer_tpu.ops.quant import tree_bytes
-
-        self.ssm_state_bytes = tree_bytes(
-            [entry for entry in self._pool if "ssm" in entry]
-        ) if self.recurrent else 0
-        self.kv_pool_bytes = tree_bytes(self._pool) - self.ssm_state_bytes
-        #: KV footprint per token POSITION at pool width across all layers
-        #: (k + v) — the unit of the attention READ stream, which scales
-        #: with context and dominates the decode tick's HBM traffic; this
-        #: is the knob int8 halves (vs bf16).  NOT a write-traffic
-        #: counter: int8's decode scatter is a whole-block rescale RMW
-        #: (~block_size rows, bounded at one block per slot per layer),
-        #: amortized small against the context-sized read.
-        #: Attention sublayers that read the cache a tick: a layer's one, or
-        #: the double layer's two.
-        #: Layers by what they keep (`ModelConfig.layer_kinds`): a state a
-        #: slot, K/V a position, or - a layer without a mixer - nothing.
-        self._ssm_layers = config.ssm_layers
-        attn_layers = config.attn_layers
-        self._attn_sublayers = attn_layers * config.attn_sublayers
-        if self.latent:
-            # One latent row a position and sublayer, no K and V.
-            self.kv_bytes_per_token = (
-                self._attn_sublayers * config.latent_width * itemsize
-            )
-        else:
-            self.kv_bytes_per_token = (
-                2 * attn_layers * kv_heads * config.d_head * itemsize
-            )
-
-        self._tables = np.zeros((slots, self.blocks_per_slot), np.int32)
-        # Window group: a slot's row starts at its first live block, whose
-        # first position is the slot's base.
-        self._window_tables = np.zeros((slots, max(self.window_cap, 1)), np.int32)
-        self._window_base = np.zeros(slots, np.int32)
-        #: What the attention of the ticks (and, over window pool groups,
-        #: of the chunks) needs, summed over layers, counted here from the
-        #: positions (no device read): visible (query, key) pairs and
-        #: distinct KV positions to stream, a window layer's capped by its
-        #: window.
-        self.attn_pairs = 0
-        self.attn_kv_positions = 0
-        #: Over a summary-and-window cache: of the ticks' ``attn_kv_positions``
-        #: those that are summary rows; summary rows written by ticks and
-        #: chunks, x layers; windows closed (a table row laid out anew);
-        #: and the last tick's own ``(rows attended x layers, summaries of
-        #: them)`` (None over another kind: the ``tick`` record leaves them
-        #: out).  A chunk's attention adds to ``attn_pairs`` alone.
-        self.attn_summary_kv_positions = 0
-        self.eva_summary_rows = 0
-        self.eva_windows_closed = 0
-        self.last_tick_attn_summary = (0, 0) if self.eva else None
+        #: int8 quarters the f32 pool (halves bf16) at fixed block count -
+        #: or, held fixed, buys 2-4x the blocks.  What the kind keeps a
+        #: slot and not a position (state-space layers' state rows) is
+        #: counted apart.
+        self.kv_pool_bytes = tree_bytes(self._pool) - self.cache.state_bytes
+        self.kv_bytes_per_token = self.cache.kv_bytes_per_token(
+            1 if kv_dtype == "int8" else act_dtype.itemsize
+        )
         #: Key positions the ticks' live slots held, and key positions
         #: their tables address (every slot's whole row, what a gather
         #: through the table reads): `tick_live_key_share`.
         self.tick_live_keys = 0
         self.tick_table_keys = 0
-        #: How the tick's rows attend: the cache kind's choice.
-        self.tick_attention_path = cache_kind(config).attention_path(
-            config, True, self.blocks_per_slot, self._first_attention_entry()
-        )
-        #: A latent pool under the tick's kernels: key positions the ticks'
-        #: slots attended through the shared pass - the chain of blocks
-        #: several slots' rows start with, attended once for all of them -
-        #: x sublayers (the unit of ``attn_kv_positions``), the slots on
-        #: the chain summed over ticks, and the last tick's own two (None
-        #: without a latent pool: the ``tick`` record leaves them out).  The
-        #: program's own rule (`mla_attention.shared_prefix`) on the host's
-        #: tables and positions.
-        self._shares_chain = (
-            self.latent and self.tick_attention_path == "mla_paged"
-        )
-        self.attn_shared_kv_positions = 0
-        self.attn_shared_slots = 0
-        self.last_tick_attn_shared = (0, 0) if self.latent else None
-        #: A latent pool's chunks: the visible (query, key) pairs their
-        #: attention needs x sublayers, and those of them whose launch's
-        #: bucket attends in the expanded form's kernel
-        #: (`mla.rows_attention_path`, the rule `mla.rows_attention` asks).
-        #: Not in ``attn_pairs``, which counts a latent pool's ticks alone.
-        self.chunk_attn_pairs = 0
-        self.chunk_attn_kernel_pairs = 0
-        self._chunk_kernel_buckets = frozenset(
-            bucket for bucket in self.buckets
-            if self.latent
-            and mla.rows_attention_path(bucket, config) == "mla_chunk"
-        )
-        #: State-space layers: slot-layers the ticks updated (live slots x
-        #: state-space layers), real and bucket rows x state-space layers
-        #: through the chunks' scans, admissions that started from a zero
-        #: state, and the last tick's slot-layers.
-        self.ssm_tick_state_rows = 0
-        self.ssm_chunk_tokens = 0
-        self.ssm_chunk_rows = 0
-        self.ssm_state_resets = 0
-        self.last_tick_ssm_state_rows = 0
-        self._window_layers = sum(
-            config.layer_window(layer) is not None
-            for layer in range(config.num_layers)
-        )
+        #: What the cache kind counted of the last tick read, and its
+        #: running counts of the chunks' work: the ``tick`` record's.
+        self.last_tick_counts = self.cache.no_tick
+        self.chunk_counts = self.cache.chunk_counts
         #: Routing counts of the dropless expert layers, summed over layers:
         #: [tokens routed, assignments on held experts, non-empty expert
         #: groups, assignments on zero experts] (the device's vector ends at
@@ -745,10 +549,9 @@ class PagedEngine:
         # (argument 2) is donated: both programs update it in place.  With
         # one pool alive the chip has memory to spare, and XLA then writes
         # every layer's code out: ask for the layers as calls
-        # (`layered_program_options`), as the train step does - not yet
-        # over the window pool groups, whose programs compile as they did
-        # (ROADMAP D10 measures that on the chip before it changes).
-        options = None if self.grouped else layered_program_options()
+        # (`layered_program_options`), as the train step does - where the
+        # cache kind says so.
+        options = layered_program_options() if self.cache.layers_as_calls else None
         self._chunk_jit = jax.jit(
             functools.partial(
                 _chunk_program, config=config, block_size=block_size
@@ -912,25 +715,7 @@ class PagedEngine:
         # The groups by name.  One group: the full group is the pool.
         out["kv_full_blocks_total"] = self.allocator.usable_blocks
         out["kv_full_blocks_free"] = self.allocator.free_count
-        window = self.window_allocator
-        out["kv_window_blocks_total"] = window.usable_blocks if window else 0
-        out["kv_window_blocks_free"] = window.free_count if window else 0
-        out["kv_window_blocks_recycled"] = self._window_recycled
-        out["attn_pairs"] = self.attn_pairs
-        out["attn_kv_positions"] = self.attn_kv_positions
-        if self.latent:
-            out["attn_shared_kv_positions"] = self.attn_shared_kv_positions
-            out["attn_shared_slots"] = self.attn_shared_slots
-            out["chunk_attn_pairs"] = self.chunk_attn_pairs
-            out["chunk_attn_kernel_pairs"] = self.chunk_attn_kernel_pairs
-        if self.eva:
-            out["attn_summary_kv_positions"] = self.attn_summary_kv_positions
-            out["eva_summary_rows"] = self.eva_summary_rows
-            out["eva_windows_closed"] = self.eva_windows_closed
-            out["kv_summary_blocks_used"] = sum(
-                len(info.block_ids) - info.window_blocks
-                for info in self._slots if info is not None
-            )
+        out.update(self.cache.gauges())
         out["tick_attention_path"] = self.tick_attention_path
         out["tick_live_key_share"] = (
             100.0 * self.tick_live_keys / self.tick_table_keys
@@ -949,11 +734,6 @@ class PagedEngine:
         out["chunk_launches"] = self.chunk_launches
         for (program, part), timed in self._parts.items():
             out[f"launch_{program}_{part}_s"] = timed.total_s
-        out["ssm_tick_state_rows"] = self.ssm_tick_state_rows
-        out["ssm_chunk_tokens"] = self.ssm_chunk_tokens
-        out["ssm_chunk_rows"] = self.ssm_chunk_rows
-        out["ssm_state_resets"] = self.ssm_state_resets
-        out["ssm_state_bytes"] = self.ssm_state_bytes
         out["kv_pool_bytes"] = self.kv_pool_bytes
         out["kv_bytes_per_token"] = self.kv_bytes_per_token
         # From the compiled programs themselves (`_in_place`): the pool's
@@ -1019,107 +799,6 @@ class PagedEngine:
         self._pool = out[pool_at] if isinstance(out, tuple) else out
         return out
 
-    # Why a cache kind (the engine's flag of that name) cannot take an
-    # operation written for one chain of K/V blocks of positions.
-    _REFUSALS = {
-        "grouped": (
-            "window pool groups: a recycled window block cannot be rolled "
-            "back, copied or shipped as part of a whole chain"
-        ),
-        "latent": (
-            "a latent pool: the migration wire ships K and V blocks of "
-            "heads, and a verify pass has no latent form"
-        ),
-        "recurrent": (
-            "a recurrent state: it is the state after a slot's last token "
-            "and of no earlier one, and the migration wire ships blocks of "
-            "positions"
-        ),
-        "eva": (
-            "a summary-and-window cache: a closed window's exact rows are "
-            "gone, so nothing rolls back across a closing, and the "
-            "migration wire ships one chain of positions"
-        ),
-    }
-
-    def _refuse(self, what: str, *kinds: str) -> None:
-        """Raise if this engine's cache is one of ``kinds`` (all by default)."""
-        for kind in kinds or self._REFUSALS:
-            if getattr(self, kind):
-                raise NotImplementedError(
-                    f"{what} is not supported over {self._REFUSALS[kind]} "
-                    "(ROADMAP: what cannot run yet)"
-                )
-
-    def _enter_window(self, slot: int, position: int) -> None:
-        """Lay ``slot``'s table row out for the window ``position`` lies in:
-        the summary blocks of the windows before it, the window's blocks -
-        the same blocks window after window: a closed window's exact rows
-        are dropped, and counted as recycled - and the blocks its own
-        summaries are written to (trash where the request ends before the
-        window closes)."""
-        info = self._slots[slot]
-        window = position // self.config.eva_window
-        if window == info.window:
-            return
-        per_window, window_blocks = self._eva_blocks
-        held = info.window_blocks
-        summaries = info.block_ids[held:]
-        row = self._tables[slot]
-        row[:] = 0
-        visible = window * per_window
-        row[:visible] = summaries[:visible]
-        row[visible: visible + held] = info.block_ids[:held]
-        pending = summaries[visible: visible + per_window]
-        at = visible + window_blocks
-        row[at: at + len(pending)] = pending
-        if info.window >= 0:
-            self.eva_windows_closed += window - info.window
-            self._window_recycled += held
-        info.window = window
-
-    def _advance_window(self, slot: int, lo_pos: int) -> None:
-        """Recycle ``slot``'s window blocks that lie wholly below
-        ``lo_pos`` (positions no later query of the slot reads)."""
-        recycled = self._chains[slot].advance(lo_pos)
-        if recycled:
-            self._window_recycled += recycled
-            self._write_window_row(slot)
-
-    def _count_attention(self, start: int, end: int) -> None:
-        """Add what the attention of queries ``start .. end - 1`` of one
-        slot needs, over the layers of both kinds (plain integers)."""
-        window = self.config.sliding_window
-        full_layers = self._attn_sublayers - self._window_layers
-        full_pairs = (end * (end + 1) - start * (start + 1)) // 2
-        # Queries from position window - 1 on see exactly window keys.
-        capped = max(end - max(start, window - 1), 0)
-        uncapped_end = end - capped
-        window_pairs = (
-            uncapped_end * (uncapped_end + 1) - start * (start + 1)
-        ) // 2 + capped * window
-        self.attn_pairs += (
-            full_layers * full_pairs + self._window_layers * window_pairs
-        )
-        self.attn_kv_positions += full_layers * end + self._window_layers * (
-            end - max(start - window + 1, 0)
-        )
-
-    def _chunk_pairs(self, rows: int, before: int) -> int:
-        """Visible (query, key) pairs of a chunk of ``rows`` queries after
-        ``before`` cached rows - all of those and its own causal half - over
-        the attention sublayers."""
-        return self._attn_sublayers * (rows * before + rows * (rows + 1) // 2)
-
-    def _write_window_row(self, slot: int) -> None:
-        """The slot's window row starts at its chain's first live block,
-        whose first position is the slot's base."""
-        chain = self._chains[slot]
-        row = self._window_tables[slot]
-        row[:] = 0
-        row[: len(chain.ids)] = chain.ids
-        self._window_base[slot] = chain.first * self.block_size
-
     def _validate(self, prompt: np.ndarray, max_new_tokens: int) -> None:
         plen = prompt.shape[0]
         ctx = self.config.context_length
@@ -1140,21 +819,7 @@ class PagedEngine:
         prefix-cache credit): every position the request may ever write."""
         ctx = self.config.context_length
         eff = min(max_new_tokens, ctx - prompt_len)
-        span = min(prompt_len + eff, ctx)
-        if self.eva:
-            return sum(self._eva_chain(span))
-        return -(-span // self.block_size)  # ceil
-
-    def _eva_chain(self, span: int) -> tuple[int, int]:
-        """``(window blocks, summary blocks)`` of a request of ``span``
-        positions over a summary-and-window cache: the blocks of its
-        longest window, and a window's summary blocks for every window it
-        lives to close."""
-        per_window, window_blocks = self._eva_blocks
-        return (
-            min(-(-span // self.block_size), window_blocks),
-            (span - 1) // self.config.eva_window * per_window,
-        )
+        return self.cache.blocks_needed(min(prompt_len + eff, ctx))
 
     def _alloc_blocks(self, n: int) -> list:
         """Allocate ``n`` fresh blocks, evicting prefix-cache LRU leaves to
@@ -1173,7 +838,7 @@ class PagedEngine:
         :meth:`rewind` returns whatever the acceptance didn't keep).
         Raises :class:`NoFreeBlocksError` when the pool is dry — the
         caller shrinks its speculation window instead of parking."""
-        self._refuse("extend_blocks (speculative scratch)")
+        self.cache.refuse("extend_blocks")
         info = self._slots[slot]
         if info is None:
             raise ValueError(f"slot {slot} is not occupied")
@@ -1184,7 +849,7 @@ class PagedEngine:
         fresh = self._alloc_blocks(extra)
         start = len(info.block_ids)
         info.block_ids.extend(fresh)
-        self._tables[slot, start: start + len(fresh)] = fresh
+        self.cache.tables[slot, start: start + len(fresh)] = fresh
 
     def rewind(
         self, slot: int, new_len: int, *, keep_blocks: int | None = None
@@ -1223,7 +888,7 @@ class PagedEngine:
         primitive.  Unread launches are read first (:meth:`flush`): what
         they emitted is part of the frontier the caller rolls back from.
         """
-        self._refuse("rewind", "grouped", "eva")
+        self.cache.refuse("rewind")
         self.flush()
         info = self._slots[slot]
         if info is None:
@@ -1236,7 +901,7 @@ class PagedEngine:
                 f"{self.config.context_length}]"
             )
         if new_len < int(self._positions[slot]):
-            self._refuse("rewind below the written frontier", "recurrent")
+            self.cache.refuse("rewind_below_frontier")
         bs = self.block_size
         needed = -(-new_len // bs)
         floor = max(needed, keep_blocks or 0)
@@ -1246,7 +911,7 @@ class PagedEngine:
             info.block_ids = info.block_ids[:floor]
             self.allocator.deref(dropped)
             released = len(dropped)
-            self._tables[slot, floor:] = 0
+            self.cache.tables[slot, floor:] = 0
         # The block the next write lands in must be exclusively owned:
         # rewinding into a radix-shared region would otherwise scribble
         # over blocks other chains still read.
@@ -1262,7 +927,7 @@ class PagedEngine:
                 )
                 self.allocator.deref([shared])
                 info.block_ids[idx] = fresh
-                self._tables[slot, idx] = fresh
+                self.cache.tables[slot, idx] = fresh
                 cow = True
         info.shared_len = min(info.shared_len, new_len)
         if self.recorder is not None:
@@ -1299,7 +964,7 @@ class PagedEngine:
         a speculative importer re-prefills its draft from) is merged into
         the payload meta.
         """
-        self._refuse("KV migration (export_slot)")
+        self.cache.refuse("export_slot")
         tokens, positions, keys = self.read_carry()
         info = self._slots[slot]
         if info is None:
@@ -1371,7 +1036,7 @@ class PagedEngine:
         """Reject a payload this engine cannot graft — geometry or pool
         dtype mismatch is a configuration error, caught before any block
         is allocated (HTTP 400, not a half-grafted slot)."""
-        self._refuse("KV migration (import_slot)")
+        self.cache.refuse("import_slot")
         if meta.get("format") != 1:
             raise ValueError(
                 f"unsupported payload format {meta.get('format')!r}"
@@ -1481,8 +1146,7 @@ class PagedEngine:
             ),
         )
         fresh = self._alloc_blocks(chain)
-        self._tables[slot, :chain] = fresh
-        self._tables[slot, chain:] = 0
+        self.cache.admit(slot, fresh)
         # The wire's heads-major blocks become the pool's rows on the host
         # (one slot's blocks), then land through the inject program.
         at_rest = [
@@ -1576,13 +1240,6 @@ class PagedEngine:
                 f"request needs {need} KV blocks; the pool holds "
                 f"{self.allocator.usable_blocks}"
             )
-        if self.grouped and min(need, self.window_cap) > (
-            self.window_allocator.usable_blocks
-        ):
-            raise ValueError(
-                f"request needs {min(need, self.window_cap)} window KV "
-                f"blocks; the pool holds {self.window_allocator.usable_blocks}"
-            )
         matched: list[int] = []
         if self.prefix_cache is not None:
             matched = self.prefix_cache.match([int(t) for t in prompt])
@@ -1592,19 +1249,12 @@ class PagedEngine:
             if matched:
                 self.allocator.deref(matched)
             raise
-        if self.grouped:
-            try:
-                self._chains[slot] = WindowChain(
-                    self.window_allocator, self.window_cap, need
-                )
-            except NoFreeBlocksError:
-                self.allocator.deref(fresh)
-                raise
-            self._write_window_row(slot)
         block_ids = matched + fresh
-        if not self.eva:  # `_enter_window` lays such a row out, below
-            self._tables[slot, : len(block_ids)] = block_ids
-            self._tables[slot, len(block_ids):] = 0
+        try:
+            self.cache.admit(slot, block_ids)
+        except NoFreeBlocksError:
+            self.allocator.deref(fresh)
+            raise
 
         shared_len = len(matched) * self.block_size
         if self.prefix_cache is not None:
@@ -1629,34 +1279,8 @@ class PagedEngine:
             request_id=request_id,
         )
         self._slots[slot] = info
-        if self.eva:
-            info.window_blocks = self._eva_chain(
-                min(plen + max_new_tokens, ctx)
-            )[0]
-            self._enter_window(slot, 0)
         self._prefilling.append(slot)
         return slot
-
-    def _table_rows(self, slot: int | None = None):
-        """The block tables as the cache kind takes them: every slot's rows
-        (a tick), or one slot's (a chunk) - a COPY either way.  No launch
-        is read back before the host goes on: its program may still be
-        waiting when the host next rewrites a row (an admission, a release,
-        `_write_window_row` recycling in place), and the CPU backend reads a
-        numpy argument that happens to lie 64-byte aligned where it lies,
-        without copying it: a chunk then attended through the next chunk's
-        row (ROADMAP D11).  The copy belongs to its launch alone."""
-        pick = (lambda a: a.copy()) if slot is None else (lambda a: a[slot].copy())
-        if self.recurrent and slot is not None:
-            # A chunk addresses its slot's state rows by the slot's id.
-            return {"blocks": pick(self._tables), "slot": np.int32(slot)}
-        if not self.grouped:
-            return pick(self._tables)
-        return {
-            "full": pick(self._tables),
-            "window": pick(self._window_tables),
-            "window_base": pick(self._window_base),
-        }
 
     # ------------------------------------------------------ the decode carry
 
@@ -1715,38 +1339,10 @@ class PagedEngine:
                 info.next_pos: info.next_pos + chunk_len
             ]
             final = info.next_pos + chunk_len == plen
-            if self.grouped:
-                # The chunk's first query reads back to next_pos - window + 1.
-                self._advance_window(
-                    slot, info.next_pos - self.config.sliding_window + 1
-                )
-                self._count_attention(info.next_pos, info.next_pos + chunk_len)
-            if self.eva:
-                self._enter_window(slot, info.next_pos)
-                width, per_chunk = (
-                    self.config.eva_window, self.config.eva_chunk
-                )
-                summaries = (
-                    info.next_pos // width * self.config.eva_chunks_per_window
-                )
-                first = summaries + info.next_pos % width
-                self.attn_pairs += self._chunk_pairs(chunk_len, first)
-                self.eva_summary_rows += self._attn_sublayers * (
-                    chunk_len // per_chunk
-                )
-            if self.latent:
-                pairs = self._chunk_pairs(chunk_len, info.next_pos)
-                self.chunk_attn_pairs += pairs
-                if bucket in self._chunk_kernel_buckets:
-                    self.chunk_attn_kernel_pairs += pairs
-            if self.recurrent:
-                self.ssm_chunk_tokens += self._ssm_layers * chunk_len
-                self.ssm_chunk_rows += self._ssm_layers * bucket
-                # The chunk program starts a chunk at position 0 from zeros.
-                self.ssm_state_resets += int(info.next_pos == 0)
+            self.cache.before_chunk(slot, info.next_pos, chunk_len, bucket)
             args = (
                 self._params, self._lm_head, self._pool, self._moe_pending,
-                self._table_rows(slot), padded, np.int32(info.next_pos),
+                self.cache.table_rows(slot), padded, np.int32(info.next_pos),
                 np.int32(chunk_len), key_in, info.temp_enc, info.top_k_enc,
                 info.top_p_enc, self._carry, np.int32(slot), np.bool_(final),
             )
@@ -1844,61 +1440,11 @@ class PagedEngine:
         with Phase("serve/tick_dispatch", self.clock) as dispatch:
             with self._parts["tick", "prepare"].at(self.clock):
                 live = np.flatnonzero(self._active)
-                window = self.config.sliding_window
-                if self.grouped:
-                    for slot in live:
-                        self._advance_window(
-                            int(slot), int(self._positions[slot]) - window + 1
-                        )
-                # One query a live slot: pairs and KV positions are alike.
-                seen = self._positions[live].astype(np.int64) + 1
-                attn_summary = None
-                if self.eva:
-                    # The summaries of the windows a slot has closed, then
-                    # its open window up to the row: what `EvaRows` attends.
-                    width = self.config.eva_window
-                    for slot in live:
-                        self._enter_window(int(slot), int(self._positions[slot]))
-                    at = seen - 1
-                    summaries = at // width * self.config.eva_chunks_per_window
-                    seen = summaries + at % width + 1
-                    per_chunk = self.config.eva_chunk
-                    self.eva_summary_rows += self._attn_sublayers * int(
-                        np.count_nonzero(at % per_chunk == per_chunk - 1)
-                    )
-                    attn_summary = (
-                        self._attn_sublayers * int(seen.sum()),
-                        self._attn_sublayers * int(summaries.sum()),
-                    )
-                    self.attn_summary_kv_positions += attn_summary[1]
-                keys_live = int(seen.sum())
-                keys_read = (
-                    self._attn_sublayers - self._window_layers
-                ) * keys_live
-                if self.grouped:
-                    keys_read += self._window_layers * int(
-                        np.minimum(seen, window).sum()
-                    )
-                self.attn_pairs += keys_read
-                self.attn_kv_positions += keys_read
-                self.tick_live_keys += keys_live
-                self.tick_table_keys += self._tables.size * self.block_size
-                attn_shared = (0, 0) if self.latent else None
-                if self._shares_chain:
-                    shared, _ = mla_attention.shared_prefix(
-                        self._tables,
-                        np.where(self._active, self._positions + 1, 0),
-                        self.block_size, xp=np,
-                    )
-                    attn_shared = (
-                        self._attn_sublayers * self.block_size
-                        * int(shared.sum()),
-                        int(np.count_nonzero(shared)),
-                    )
-                    self.attn_shared_kv_positions += attn_shared[0]
-                    self.attn_shared_slots += attn_shared[1]
-                ssm_state_rows = self._ssm_layers * len(live)
-                self.ssm_tick_state_rows += ssm_state_rows
+                seen, counted = self.cache.before_tick(
+                    live, self._positions, self._active
+                )
+                self.tick_live_keys += int(seen.sum())
+                self.tick_table_keys += self.cache.tables.size * self.block_size
                 asked = filters_asked(
                     self._active, self._temps, self._top_ks, self._top_ps
                 )
@@ -1910,7 +1456,7 @@ class PagedEngine:
                 # them.
                 args = (
                     self._params, self._lm_head, self._pool,
-                    self._moe_pending, self._table_rows(), tokens, positions,
+                    self._moe_pending, self.cache.table_rows(), tokens, positions,
                     self._active.copy(), keys, self._temps.copy(),
                     self._top_ks.copy(), self._top_ps.copy(),
                 )
@@ -1933,7 +1479,7 @@ class PagedEngine:
                 self._unread.append(_Launch(
                     tokens,
                     tuple(zip(live.tolist(), self._tenant[live].tolist())),
-                    moe, ssm_state_rows, attn_shared, attn_summary,
+                    moe, counted,
                 ))
                 self.ticks += 1
                 self._positions[live] += 1
@@ -1956,9 +1502,7 @@ class PagedEngine:
         events: list[TickEvent] = []
         with Phase("serve/tick_emit", self.clock) as emit:
             if not launch.first:
-                self.last_tick_ssm_state_rows = launch.ssm_state_rows
-                self.last_tick_attn_shared = launch.attn_shared
-                self.last_tick_attn_summary = launch.attn_summary
+                self.last_tick_counts = launch.counts
             held = self._tenant.tolist()  # a release bumps its own slot only
             for slot, tenant in launch.rows:
                 if held[slot] != tenant:
@@ -2017,9 +1561,4 @@ class PagedEngine:
             self._prefilling.remove(slot)
         if info is not None and info.block_ids:
             self.allocator.deref(info.block_ids)
-        self._tables[slot, :] = 0
-        if self._chains[slot] is not None:
-            self._chains[slot].release()
-            self._chains[slot] = None
-            self._window_tables[slot, :] = 0
-            self._window_base[slot] = 0
+        self.cache.release(slot)

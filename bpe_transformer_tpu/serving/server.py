@@ -71,6 +71,10 @@ __all__ = [
 
 _STREAM_END = object()
 
+#: What a ``tick`` record reads of the state-space layers where the engine,
+#: or its cache kind, has none: the one place those zeros come from.
+_TICK_COUNTS_OFF = {"ssm_tick_state_rows": 0, "ssm_chunk_tokens": 0, "ssm_chunk_rows": 0}
+
 
 class DuplicateRequestError(ValueError):
     """A request id already in flight on this replica.  Subclasses
@@ -1444,7 +1448,7 @@ class ServingEngine:
             "prefill_tokens": 0, "idle_s": 0.0, "deliver_s": 0.0,
             "cpu_before": cpu, "gc_before": gc_pauses()["gc_pause_s"],
             "tokens_before": self.engine.tokens_emitted,
-            "ssm_chunk_before": self._ssm_chunk_counts(),
+            "chunk_counts_before": self._chunk_counts(),
             "carry_before": self._carry_counts(),
         }
 
@@ -1457,14 +1461,10 @@ class ServingEngine:
             for name in ("ticks_overlapped", "tick_stale_rows", "carry_flushes")
         )
 
-    def _ssm_chunk_counts(self) -> tuple:
-        """The engine's ``(ssm_chunk_tokens, ssm_chunk_rows)``: real and
-        bucket rows x state-space layers through the chunks' scans so far
-        (zeros for an engine without such layers)."""
-        return (
-            getattr(self.engine, "ssm_chunk_tokens", 0),
-            getattr(self.engine, "ssm_chunk_rows", 0),
-        )
+    def _chunk_counts(self) -> dict:
+        """The running counts of the engine's cache kind of its chunks' work
+        (`kvpool/host_cache.py`; {} for an engine or a kind that counts none)."""
+        return getattr(self.engine, "chunk_counts", dict)()
 
     def _close_period(self, tick, n_events: int) -> None:
         """End the period now, at the end of its tick, and account for it
@@ -1511,30 +1511,15 @@ class ServingEngine:
         )
         if self._telemetry is None:
             return
-        ssm_chunk = tuple(
-            now - before for now, before in
-            zip(self._ssm_chunk_counts(), period["ssm_chunk_before"])
-        )
+        before = period["chunk_counts_before"]
+        chunk_counts = {
+            name: now - before[name]
+            for name, now in self._chunk_counts().items()
+        }
         overlapped, stale_rows, carry_flushes = (
             now - before for now, before in
             zip(self._carry_counts(), period["carry_before"])
         )
-        # Key positions x sublayers that the tick read in the period
-        # attended through the latent kernels' shared pass, and the slots on
-        # the shared chain: over a latent pool alone.
-        attn_shared = getattr(self.engine, "last_tick_attn_shared", None)
-        shared_pass = {} if attn_shared is None else {
-            "attn_shared_kv_positions": attn_shared[0],
-            "attn_shared_slots": attn_shared[1],
-        }
-        # Rows x layers the tick attended over a summary-and-window cache,
-        # and those of them that are summaries.
-        attn_summary = getattr(self.engine, "last_tick_attn_summary", None)
-        if attn_summary is not None:
-            shared_pass = {
-                "attn_kv_positions": attn_summary[0],
-                "attn_summary_kv_positions": attn_summary[1],
-            }
         self._telemetry.emit(
             {
                 "kind": "tick",
@@ -1574,13 +1559,14 @@ class ServingEngine:
                 ),
                 # State-space slot-layers that tick updated (live slots x
                 # state-space layers) and the period's chunks' real and
-                # bucket rows x state-space layers (0 without such layers).
-                "ssm_tick_state_rows": getattr(
-                    self.engine, "last_tick_ssm_state_rows", 0
-                ),
-                "ssm_chunk_tokens": ssm_chunk[0],
-                "ssm_chunk_rows": ssm_chunk[1],
-                **shared_pass,
+                # bucket rows x state-space layers: 0 without such layers.
+                **_TICK_COUNTS_OFF,
+                # What the engine's cache kind counted of that tick at its
+                # dispatch (a latent pool: its shared pass; a summary-and-
+                # window cache: rows attended and the summaries among them)
+                # and of the period's chunks (`kvpool/host_cache.py`).
+                **getattr(self.engine, "last_tick_counts", {}),
+                **chunk_counts,
             }
         )
 

@@ -265,9 +265,7 @@ class SpecEngine(PagedEngine):
                 f"speculate_k must be >= 1, got {speculate_k}"
             )
         super().__init__(params, config, min_bucket=min_bucket, **paged_kwargs)
-        self._refuse("speculative decoding (its verify pass rewinds)", "grouped")
-        self._refuse("speculative decoding (its verify pass)", "latent")
-        self._refuse("speculative decoding (its verify pass rewinds)", "recurrent")
+        self.cache.refuse("speculate")
         # This engine's tick is the verify pass: several rows a slot.
         self.tick_attention_path = cache_kind(config).attention_path(
             config, False, self.blocks_per_slot, self._pool[0]
@@ -538,7 +536,7 @@ class SpecEngine(PagedEngine):
         # The spec engine's tick program: the pool goes through it donated.
         out, n_emit, keys, _ = self._in_place(
             "tick", self._verify_jit,
-            self._params, self._lm_head, self._pool, self._tables,
+            self._params, self._lm_head, self._pool, self.cache.tables,
             tokens, d_toks, d_probs, positions, rooms,
             self._active, keys, self._temps, self._top_ks,
             self._top_ps,

@@ -380,7 +380,7 @@ def _pool_program(
         arr((slots,), I32), arr((slots,), I32), arr((slots, 2), jnp.uint32)
     )
     def tables(*lead):
-        """The block tables as `PagedEngine._table_rows` hands them over."""
+        """The block tables as `PagedEngine.cache.table_rows` hands them over."""
         if not window_cap:
             return arr((*lead, nbs), I32)
         return {

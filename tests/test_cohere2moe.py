@@ -30,6 +30,7 @@ from bpe_transformer_tpu.serving.kvpool.blocks import (  # noqa: E402
     NoFreeBlocksError,
     WindowChain,
 )
+from bpe_transformer_tpu.serving.kvpool.host_cache import HostDenseRows  # noqa: E402
 from bpe_transformer_tpu.serving.kvpool.paged_engine import PagedEngine  # noqa: E402
 from chipbench import reference_cohere2moe as ref  # noqa: E402
 
@@ -196,9 +197,9 @@ def test_attention_counters_count_pairs_and_positions():
         return pairs, reach
 
     for start, end in [(0, 4), (3, 9), (8, 12), (20, 21)]:
-        before = eng.attn_pairs, eng.attn_kv_positions
-        eng._count_attention(start, end)
-        got = eng.attn_pairs - before[0], eng.attn_kv_positions - before[1]
+        before = eng.cache.attn_pairs, eng.cache.attn_kv_positions
+        eng.cache.count_attention(start, end)
+        got = eng.cache.attn_pairs - before[0], eng.cache.attn_kv_positions - before[1]
         assert got == brute(start, end)
 
 
@@ -244,19 +245,19 @@ def aligned64(array: np.ndarray) -> np.ndarray:
 
 
 def test_a_chunk_is_handed_its_own_rows():
-    """`_write_window_row` rewrites a slot's row in place while the last
+    """`HostGroupedPages._write_window_row` rewrites a slot's row in place while the last
     chunk's program may still be waiting: what a chunk program is handed
     shares no memory with the engine's tables."""
     eng = small_engine(reference_cfg(2, 2, layers=4))
     for slot in range(eng.n_slots):
-        for handed in eng._table_rows(slot).values():
-            for table in (eng._tables, eng._window_tables, eng._window_base):
+        for handed in eng.cache.table_rows(slot).values():
+            for table in (eng.cache.tables, eng.cache.window_tables, eng.cache.window_base):
                 assert not np.shares_memory(handed, table)
     one_group = PagedEngine(
         init_params(jax.random.PRNGKey(0), TS_TEST_CONFIG), TS_TEST_CONFIG,
         slots=2, block_size=4, prefix_cache=False,
     )
-    assert not np.shares_memory(one_group._table_rows(1), one_group._tables)
+    assert not np.shares_memory(one_group.cache.table_rows(1), one_group.cache.tables)
 
 
 def test_paged_two_groups_match_reference_logits_past_the_window():
@@ -269,7 +270,8 @@ def test_paged_two_groups_match_reference_logits_past_the_window():
     D11: 0.29 here, one run in five where the alignment was chance)."""
     c = reference_cfg(2, 2)
     eng = small_engine(c)
-    eng._tables, eng._window_tables = aligned64(eng._tables), aligned64(eng._window_tables)
+    eng.cache.tables = aligned64(eng.cache.tables)
+    eng.cache.window_tables = aligned64(eng.cache.window_tables)
     pc, w = eng.config, ref.weights_from_seed(3, c)
     tokens = np.random.default_rng(2).integers(0, 64, 30)
     full = ref.forward_logits(w, tokens[None], c)[0]
@@ -277,19 +279,19 @@ def test_paged_two_groups_match_reference_logits_past_the_window():
     slot = eng.begin(tokens[:plen], max_new_tokens=17, temperature=0.0)
     while eng.prefill_step(slot) is None:
         pass
-    assert eng._window_recycled > 0  # recycled while still prefilling
-    chain = eng._chains[slot]
+    assert eng.cache.window_recycled > 0  # recycled while still prefilling
+    chain = eng.cache.chains[slot]
     worst, longest = 0.0, len(chain.ids)
     active = np.zeros(eng.n_slots, bool)
     active[slot] = True
     for t in range(plen, 30):
-        eng._advance_window(slot, t - WINDOW + 1)
+        eng.cache.advance_window(slot, t - WINDOW + 1)
         longest = max(longest, len(chain.ids))
         tok = np.zeros(eng.n_slots, np.int32)
         pos = np.zeros(eng.n_slots, np.int32)
         tok[slot], pos[slot] = tokens[t], t
         cache = slot_cache(
-            pc, eng._table_rows(), jnp.asarray(pos), jnp.asarray(active),
+            pc, eng.cache.table_rows(), jnp.asarray(pos), jnp.asarray(active),
             block_size=2,
         )
         logits, eng._pool, _ = paged_forward(
@@ -298,8 +300,8 @@ def test_paged_two_groups_match_reference_logits_past_the_window():
         )
         worst = max(worst, float(jnp.max(jnp.abs(logits[slot] - full[t]))))
     assert worst < 2e-6
-    assert longest <= eng.window_cap == (WINDOW + 4) // 2
-    assert chain.first > 0 and eng._window_base[slot] == chain.first * 2
+    assert longest <= eng.cache.window_cap == (WINDOW + 4) // 2
+    assert chain.first > 0 and eng.cache.window_base[slot] == chain.first * 2
 
 
 def test_engine_serves_greedy_tokens_the_reference_puts_first():
@@ -417,7 +419,7 @@ def test_one_group_case_is_todays_engine():
     cfg = dataclasses.replace(TS_TEST_CONFIG, vocab_size=64)
     params = init_params(jax.random.PRNGKey(0), cfg)
     eng = PagedEngine(params, cfg, slots=2, block_size=4, prefix_cache=False)
-    assert not eng.grouped and eng.window_allocator is None
+    assert type(eng.cache) is HostDenseRows  # no window group, no second allocator
     assert isinstance(eng._pool[0], dict) and set(eng._pool[0]) == {"k", "v"}
     first = eng.admit(np.arange(5), max_new_tokens=4, temperature=0.0)
     tokens = [first.token] + [e.token for _ in range(3) for e in eng.tick()]

@@ -251,13 +251,13 @@ def _all_logits(params, lm_head, pool, cache, tokens, *, config):
 def forced_tick(eng, slot, token, position):
     """One teacher-forced tick of ``slot`` alone, its table row laid out as
     `launch` lays it out: the float32 logits of every prediction head."""
-    eng._enter_window(slot, position)
+    eng.cache.enter_window(slot, position)
     tok = np.zeros((eng.n_slots, 1), np.int32)
     pos = np.zeros(eng.n_slots, np.int32)
     active = np.zeros(eng.n_slots, bool)
     tok[slot], pos[slot], active[slot] = token, position, True
     cache = slot_cache(
-        eng.config, jnp.asarray(eng._table_rows()), jnp.asarray(pos),
+        eng.config, jnp.asarray(eng.cache.table_rows()), jnp.asarray(pos),
         jnp.asarray(active), block_size=eng.block_size,
     )
     logits, eng._pool = _all_logits(
@@ -311,7 +311,7 @@ def test_paged_chunks_and_ticks_match_reference(plen):
     worst, slot = served_logit_error(eng, tokens, plen, another_slot_prefills)
     assert worst < TOL
     assert not eng.pending_prefills()
-    assert eng._slots[slot].window == 3
+    assert eng.cache.window[slot] == 3
 
 
 def test_ticks_alone_from_position_0():
@@ -333,11 +333,11 @@ def test_every_row_of_a_chunk_matches_reference():
     slot = begin(eng, tokens)
     for start in range(0, 70, 16):
         n = min(16, 70 - start)
-        eng._enter_window(slot, start)
+        eng.cache.enter_window(slot, start)
         padded = np.zeros((1, 16), np.int32)
         padded[0, :n] = tokens[start:start + n]
         cache = chunk_cache(
-            eng.config, jnp.asarray(eng._table_rows(slot)), jnp.int32(start),
+            eng.config, jnp.asarray(eng.cache.table_rows(slot)), jnp.int32(start),
             jnp.int32(n), 16, block_size=CHUNK,
         )
         logits, eng._pool = _all_logits(
@@ -373,7 +373,7 @@ def test_pending_summaries_are_invisible_until_their_window_closes():
     while eng.prefill_step(slot) is None:
         pass
     per_window, window_blocks, _ = eva_table_geometry(eng.config, CHUNK)
-    row = eng._tables[slot]
+    row = eng.cache.tables[slot]
     pending = row[per_window + window_blocks: 2 * per_window + window_blocks]
     assert pending.all()
     # Chunks 0 and 1 of window 1 (positions 32-39) are summarised already.
@@ -402,12 +402,12 @@ def test_a_request_holds_one_window_and_a_summary_block_per_sixteen_blocks():
     free = eng.allocator.free_count
     slot = begin(eng, tokens_of(70), 30)
     info = eng._slots[slot]
-    assert (info.window_blocks, len(info.block_ids)) == (8, 8 + 3 * 2)
-    row = eng._tables[slot]
+    assert (eng.cache.window_blocks[slot], len(info.block_ids)) == (8, 8 + 3 * 2)
+    row = eng.cache.tables[slot]
     assert list(row[:8]) == info.block_ids[:8] and list(row[8:10]) == info.block_ids[8:10]
     assert not row[10:].any()
     eng.release(slot)
-    assert eng.allocator.free_count == free and not eng._tables[slot].any()
+    assert eng.allocator.free_count == free and not eng.cache.tables[slot].any()
 
 
 def test_engine_serves_greedy_tokens_the_reference_puts_first():
@@ -444,9 +444,10 @@ def test_engine_serves_greedy_tokens_the_reference_puts_first():
     assert stats["eva_windows_closed"] == 3
     assert stats["kv_window_blocks_recycled"] == 3 * 8
     assert stats["kv_summary_blocks_used"] == 0  # released at the finish
-    assert eng.last_tick_attn_summary == (
-        layers * (3 * per_window + 103 % WINDOW + 1), layers * 3 * per_window
-    )
+    assert eng.last_tick_counts == {
+        "attn_kv_positions": layers * (3 * per_window + 103 % WINDOW + 1),
+        "attn_summary_kv_positions": layers * 3 * per_window,
+    }
 
 
 def test_a_slots_next_tenant_serves_as_a_fresh_engine_does():

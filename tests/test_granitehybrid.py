@@ -355,7 +355,7 @@ def forced_tick(eng, slot, token, position):
     active = np.zeros(eng.n_slots, bool)
     tok[slot], pos[slot], active[slot] = token, position, True
     logits, eng._pool = _tick_logits(
-        eng._params, eng._lm_head, eng._pool, eng._table_rows(), tok, pos, active,
+        eng._params, eng._lm_head, eng._pool, eng.cache.table_rows(), tok, pos, active,
         config=eng.config, block_size=eng.block_size,
     )
     return logits[slot]
@@ -485,7 +485,7 @@ def test_engine_serves_greedy_tokens_the_reference_puts_first():
     assert gauges["ssm_chunk_rows"] == ssm_layers * (8 + 8 + 8 + 8 + 4)
     assert gauges["ssm_state_resets"] == 3
     assert gauges["ssm_tick_state_rows"] == ssm_layers * 3 * 11
-    assert eng.last_tick_ssm_state_rows == ssm_layers * 3
+    assert eng.last_tick_counts == {"ssm_tick_state_rows": ssm_layers * 3}
     assert gauges["ssm_state_bytes"] == ssm_layers * 4 * (8 * 16 * 16 + 3 * 160) * 4
     # Attention is counted for the attention layers alone, K/V bytes too.
     ticks = sum(sum(range(n + 1, n + 12)) for n in lengths)

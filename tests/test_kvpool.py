@@ -1076,7 +1076,7 @@ def test_rewind_across_block_boundary_releases_blocks(setup):
     assert result["released"] == 1 and not result["cow"]
     assert engine.allocator.free_count == free_before + 1
     assert len(engine._slots[slot].block_ids) == 3
-    assert list(engine._tables[slot][3:]) == [0]
+    assert list(engine.cache.tables[slot][3:]) == [0]
     # Without the floor the frontier math rules: 13 tokens need 2 blocks.
     result = engine.rewind(slot, 13)
     assert result["released"] == 1
@@ -1141,7 +1141,7 @@ def test_rewind_into_radix_shared_block_copies_on_write(setup):
     assert result["cow"] and result["released"] >= 1
     fresh = info.block_ids[0]
     assert fresh != shared
-    assert engine._tables[slot][0] == fresh
+    assert engine.cache.tables[slot][0] == fresh
     # The shared copy lost exactly this slot's reference; the cache still
     # serves it, bytes untouched.
     assert engine.allocator.refcount(shared) == rc_before - 1

@@ -15,6 +15,10 @@ import pytest
 
 from bpe_transformer_tpu.models.config import TS_TEST_CONFIG
 from bpe_transformer_tpu.models.transformer import init_params
+from bpe_transformer_tpu.serving.kvpool.host_cache import (
+    HostGroupedPages,
+    HostRecurrentRows,
+)
 from bpe_transformer_tpu.serving.kvpool.paged_engine import (
     LAUNCH_PARTS,
     PagedEngine,
@@ -157,14 +161,14 @@ def test_one_launch_ahead_gives_every_request_the_same_tokens(kind):
     gauges = ahead.gauges()
     if ahead.prefix_cache is not None:
         assert gauges["prefix_cache_hits"] >= 8  # the shared two blocks
-    if ahead.grouped:
+    if isinstance(ahead.cache, HostGroupedPages):
         # Window blocks were recycled at a launch while the launch before,
         # which reads them, was unread (a stale row's launch recycles too:
         # a few more than the other order).
         assert gauges["kv_window_blocks_recycled"] >= (
             sync.gauges()["kv_window_blocks_recycled"]
         ) > 0
-    if ahead.recurrent:
+    if isinstance(ahead.cache, HostRecurrentRows):
         assert gauges["ssm_state_resets"] == len(requests)
 
 

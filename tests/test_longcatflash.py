@@ -131,7 +131,7 @@ def served_logit_error(eng, c, tokens, plen, seed=3):
         pos = np.zeros(eng.n_slots, np.int32)
         tok[slot], pos[slot] = tokens[t], t
         cache = slot_cache(
-            pc, eng._table_rows(), jnp.asarray(pos), jnp.asarray(active),
+            pc, eng.cache.table_rows(), jnp.asarray(pos), jnp.asarray(active),
             block_size=eng.block_size,
         )
         logits, eng._pool, _ = paged_forward(

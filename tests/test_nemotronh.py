@@ -390,7 +390,7 @@ def test_engine_serves_greedy_tokens_and_counts_by_the_pattern():
     assert gauges["ssm_chunk_rows"] == ssm_layers * (8 + 8 + 8 + 8 + 4)
     assert gauges["ssm_state_resets"] == 3
     assert gauges["ssm_tick_state_rows"] == ssm_layers * 3 * 11
-    assert eng.last_tick_ssm_state_rows == ssm_layers * 3
+    assert eng.last_tick_counts == {"ssm_tick_state_rows": ssm_layers * 3}
     # The grouped widths: conv rows of 128 + 2 x 4 x 16 channels.
     assert gauges["ssm_state_bytes"] == ssm_layers * 4 * (8 * 16 * 16 + 3 * 256) * 4
     ticks = sum(sum(range(n + 1, n + 12)) for n in lengths)

@@ -647,13 +647,13 @@ def paged_programs(params):
     eng = pe.PagedEngine(params, CFG, slots=2, block_size=4, min_bucket=8)
     tick = compiled_text(
         functools.partial(pe._tick_program, config=CFG, block_size=4),
-        eng._params, eng._lm_head, eng._pool, None, eng._tables,
+        eng._params, eng._lm_head, eng._pool, None, eng.cache.tables,
         eng._carry[0], eng._carry[1], eng._active, eng._carry[2], eng._temps,
         eng._top_ks, eng._top_ps,
     )
     chunk = compiled_text(
         functools.partial(pe._chunk_program, config=CFG, block_size=4),
-        eng._params, eng._lm_head, eng._pool, None, eng._tables[0],
+        eng._params, eng._lm_head, eng._pool, None, eng.cache.tables[0],
         np.zeros((1, 8), np.int32), np.int32(0), np.int32(5),
         jax.random.PRNGKey(0), np.float32(1.0), np.int32(0), np.float32(1.0),
         eng._carry, np.int32(0), np.bool_(True),
@@ -697,7 +697,7 @@ def test_scopes_are_metadata_only(params):
 
     eng = pe.PagedEngine(params, CFG, slots=2, block_size=4, min_bucket=8)
     args = (
-        eng._params, eng._lm_head, eng._pool, None, eng._tables,
+        eng._params, eng._lm_head, eng._pool, None, eng.cache.tables,
         eng._carry[0], eng._carry[1], eng._active, eng._carry[2], eng._temps,
         eng._top_ks, eng._top_ps,
     )

@@ -69,13 +69,13 @@ def read(config: dict, model_config, seed: int, prompt_len: int, ticks: int,
     for position in range(prompt_len, len(tokens)):
         # One teacher-forced tick of the slot alone, its table row laid out
         # as `launch` lays it out.
-        eng._enter_window(slot, position)
+        eng.cache.enter_window(slot, position)
         tok = np.zeros((eng.n_slots, 1), np.int32)
         pos = np.zeros(eng.n_slots, np.int32)
         active = np.zeros(eng.n_slots, bool)
         tok[slot], pos[slot], active[slot] = tokens[position], position, True
         logits, eng._pool = all_logits(
-            eng._params, eng._pool, eng._table_rows(), pos, active, tok, eng._lm_head
+            eng._params, eng._pool, eng.cache.table_rows(), pos, active, tok, eng._lm_head
         )
         ours.append(np.asarray(logits[slot, 0], np.float32))
     ours = np.stack(ours).reshape(ticks, heads, vocab)
