@@ -1,5 +1,10 @@
-"""Attention of new queries against paged K/V, window or full: the one
-attention of the grouped pools' tick and chunk programs.
+"""Attention of new queries against paged K/V, window or full: the
+attention of `models/decode.GroupedPages`' tick and chunk programs - the
+grouped pool whose two groups share one shape: every layer the same K/V
+heads, a value as wide as its key, no sink (a period of window layers over
+plain GQA).  Groups that differ in shape keep rows of keys and values
+(`models/decode.GroupedRows`) and are read by
+`kernels/pallas/sink_attention.py`.
 
 Pages are ``(num_pages, page_size, 2 * kv_heads, d_head)`` with K and V of
 one KV head side by side on the head axis (K even, V odd): one page of one

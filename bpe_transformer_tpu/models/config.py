@@ -18,8 +18,15 @@ from typing import Any
 #: mixer, whether the feed-forward part follows)``.
 LAYER_KINDS = {
     "M": ("ssm", False), "*": ("attn", False), "E": (None, True),
-    "m": ("ssm", True), "a": ("attn", True),
+    "m": ("ssm", True), "a": ("attn", True), "w": ("attn", True),
+    "A": ("attn", True),
 }
+#: The letter whose attention sees a sliding window (every other attention
+#: layer sees everything before it), and the one whose feed-forward part is
+#: the dense SwiGLU of ``d_ff`` whatever ``ffn_type`` says of the other
+#: layers (a leading dense layer before expert layers).
+WINDOW_KIND = "w"
+DENSE_FFN_KIND = "A"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -153,7 +160,9 @@ class ModelConfig:
     #: (128 heads x 128 over a hidden size of 4,096).
     head_dim: int | None = None
     #: Sliding-window attention: key j is visible to query i iff
-    #: ``0 <= i - j < sliding_window``.  Layers ``l`` with ``(l + 1) %
+    #: ``0 <= i - j < sliding_window``.  Which layers are window layers is
+    #: `layer_kinds`' to say (``"w"``): a ``layer_pattern`` names
+    #: them one by one, or a period does - layers ``l`` with ``(l + 1) %
     #: sliding_window_pattern != 0`` are window layers and every
     #: ``sliding_window_pattern``-th layer attends to everything; with the
     #: default pattern of 1 every layer is a full layer.
@@ -223,7 +232,10 @@ class ModelConfig:
     #: of positions but one recurrent state a sequence), ``"*"`` attention
     #: alone, ``"E"`` the feed-forward part alone (no mixer, so no cache of
     #: any kind) - layers of one pre-norm sublayer each - and ``"m"`` / ``"a"``
-    #: a state-space / attention mixer followed by the feed-forward part.
+    #: a state-space / attention mixer followed by the feed-forward part;
+    #: ``"w"`` is ``"a"`` under the sliding window, and ``"A"`` is ``"a"``
+    #: with the dense SwiGLU of ``d_ff`` as its feed-forward part where the
+    #: others' is the expert layer (`WINDOW_KIND`, `DENSE_FFN_KIND`).
     #: None, the default, with a period of 0: every layer attends and feeds
     #: forward.  The block is the sequential pre-norm one (`hybrid_block`).
     layer_pattern: str | None = None
@@ -255,6 +267,26 @@ class ModelConfig:
     logits_scaling: float = 1.0
     #: Width of a shared expert where it is not a routed expert's.
     shared_d_ff: int | None = None
+    # ---- attention layers that differ in more than their mask, by kind
+    # (window or full; a ``layer_pattern`` with window layers, every default
+    # the block above): K/V heads, the rotation's base, a sink in the
+    # softmax; and for both kinds a value narrower than the key, a rotation
+    # of a part of the head, a scale on the values ------------------------
+    #: K/V heads of the window layers where they are not ``num_kv_heads``.
+    window_kv_heads: int | None = None
+    #: RoPE's base in the window layers where it is not ``rope_theta``.
+    window_rope_theta: float | None = None
+    # Outside latent attention ``v_head_dim`` (above) is the width of a
+    # head's value where it is not the key's (0: ``d_head``), and
+    # ``qk_rope_head_dim`` the leading part of a head that RoPE rotates (0:
+    # all of it; the rest passes unrotated).
+    #: A learned scalar a query head that joins the softmax's denominator
+    #: and carries no value (the tree's ``attn["sink"]``, float32): ``p_ij
+    #: = exp(s_ij - m) / (sum_j' exp(s_ij' - m) + exp(b_h - m))`` - in the
+    #: window layers; the full layers have none.
+    sink_on_window_layers: bool = False
+    #: The values are multiplied by this before the weighted sum.
+    attention_value_scale: float = 1.0
     #: What an expert of the dropless layer computes, routed and shared
     #: alike: ``"swiglu"`` ``w2 (silu(w1 u) * w3 u)``, three matrices, or
     #: ``"relu2"`` ``w2 relu(w1 u)^2``, two (its tree has no ``w3``).
@@ -297,9 +329,14 @@ class ModelConfig:
 
     @property
     def rope_dim(self) -> int:
-        """Width of what RoPE rotates: the whole head, or latent
-        attention's rope part."""
-        return self.qk_rope_head_dim if self.attention_kind == "mla" else self.d_head
+        """Width of what RoPE rotates: the whole head, its leading
+        ``qk_rope_head_dim`` values, or latent attention's rope part."""
+        return self.qk_rope_head_dim or self.d_head
+
+    @property
+    def value_dim(self) -> int:
+        """Width of a head's value (and of its part of the output)."""
+        return self.v_head_dim or self.d_head
 
     @property
     def latent_width(self) -> int:
@@ -378,6 +415,11 @@ class ModelConfig:
                 "a" if i % self.attn_layer_period == self.attn_layer_offset else "m"
                 for i in range(self.num_layers)
             )
+        if self.sliding_window is not None and self.sliding_window_pattern > 1:
+            return "".join(
+                "w" if (i + 1) % self.sliding_window_pattern else "a"
+                for i in range(self.num_layers)
+            )
         return "a" * self.num_layers
 
     def layer_mixer(self, layer: int) -> str | None:
@@ -386,6 +428,11 @@ class ModelConfig:
 
     def layer_has_ffn(self, layer: int) -> bool:
         return LAYER_KINDS[self.layer_kinds[layer]][1]
+
+    def layer_ffn_is_dense(self, layer: int) -> bool:
+        """Whether layer ``layer``'s feed-forward part is the dense SwiGLU
+        of ``d_ff`` although the config's is the expert layer."""
+        return self.layer_kinds[layer] == DENSE_FFN_KIND
 
     def layer_is_ssm(self, layer: int) -> bool:
         """Whether layer ``layer`` (0-based) is a state-space layer."""
@@ -433,14 +480,46 @@ class ModelConfig:
 
     @property
     def has_window_layers(self) -> bool:
-        return self.sliding_window is not None and self.sliding_window_pattern > 1
+        return WINDOW_KIND in self.layer_kinds
 
     def layer_window(self, layer: int) -> int | None:
         """The sliding window of layer ``layer`` (0-based), None for a
         full-attention layer."""
-        if self.has_window_layers and (layer + 1) % self.sliding_window_pattern:
+        if self.layer_kinds[layer] == WINDOW_KIND:
             return self.sliding_window
         return None
+
+    def layer_kv_heads(self, layer: int | None = None) -> int:
+        """K/V heads of layer ``layer``: the window layers' own where the
+        config gives them, else ``num_kv_heads`` (None: a full layer's)."""
+        if (
+            layer is not None and self.window_kv_heads is not None
+            and self.layer_window(layer) is not None
+        ):
+            return self.window_kv_heads
+        return self.num_kv_heads or self.num_heads
+
+    def layer_rope_theta(self, layer: int) -> float:
+        if self.window_rope_theta is not None and self.layer_window(layer) is not None:
+            return self.window_rope_theta
+        return self.rope_theta
+
+    def layer_sink(self, layer: int) -> bool:
+        """Whether layer ``layer``'s softmax has the learned sink."""
+        return self.sink_on_window_layers and self.layer_window(layer) is not None
+
+    @property
+    def split_attention(self) -> bool:
+        """Attention layers that differ by kind in the shape of what they
+        cache, a value narrower than the key, a partial rotation, a sink or
+        a value scale: what only `models/decode.GroupedRows` and the
+        kernels of `kernels/pallas/sink_attention.py` serve."""
+        return (
+            self.window_kv_heads is not None or self.window_rope_theta is not None
+            or self.sink_on_window_layers
+            or self.attention_value_scale != 1.0
+            or (self.attention_kind != "mla" and bool(self.v_head_dim or self.qk_rope_head_dim))
+        )
 
     def layer_rope(self, layer: int) -> bool:
         """Whether layer ``layer`` rotates q and k."""
@@ -610,10 +689,15 @@ class ModelConfig:
                     "own and no layer pattern: num_kv_heads, head_dim, "
                     "sliding_window, remove_rope and remove_rmsnorm contradict it"
                 )
-        elif any(mla_dims) or self.mla_scale_q_lora or self.mla_scale_kv_lora:
+        elif (
+            any(mla_dims[:3]) or self.mla_scale_q_lora or self.mla_scale_kv_lora
+            or (any(mla_dims[3:]) and not self.has_window_layers)
+        ):
             raise ValueError(
                 "q_lora_rank .. v_head_dim and the lora scales are latent "
-                'attention\'s (attention_kind="mla")'
+                'attention\'s (attention_kind="mla"); outside it a '
+                "layer_pattern with window layers alone takes a "
+                "qk_rope_head_dim and a v_head_dim"
             )
         if self.double_layer and (
             self.ffn_type != "moe" or self.parallel_block or self.use_post_norm
@@ -662,19 +746,28 @@ class ModelConfig:
                     f"num_layers={self.num_layers} layers by one of "
                     f"{''.join(LAYER_KINDS)!r}"
                 )
-            if min(ssm_dims) < 1 or self.ssm_conv < 2 or self.ssm_chunk < 1:
+            if self.ssm_layers == 0:
+                if any(ssm_dims) or self.ssm_groups != 1:
+                    raise ValueError(
+                        "ssm_heads .. ssm_groups are the state-space layers' "
+                        f"and layer_pattern={self.layer_pattern!r} has none"
+                    )
+            elif min(ssm_dims) < 1 or self.ssm_conv < 2 or self.ssm_chunk < 1:
                 raise ValueError(
                     "state-space layers need positive ssm_heads, ssm_head_dim, "
                     f"ssm_state and ssm_chunk and ssm_conv >= 2 (got {ssm_dims}, "
                     f"{self.ssm_conv}, {self.ssm_chunk})"
                 )
-            if self.ssm_groups < 1 or self.ssm_heads % self.ssm_groups:
+            if self.ssm_layers and (
+                self.ssm_groups < 1 or self.ssm_heads % self.ssm_groups
+            ):
                 raise ValueError(
                     f"ssm_groups={self.ssm_groups} must divide "
                     f"ssm_heads={self.ssm_heads}"
                 )
             if (
-                self.sliding_window is not None or self.attention_kind != "mha"
+                (self.sliding_window is not None and not self.has_window_layers)
+                or self.attention_kind != "mha"
                 or self.parallel_block or self.use_post_norm
                 or self.remove_rmsnorm or self.norm_type != "rmsnorm"
             ):
@@ -684,6 +777,24 @@ class ModelConfig:
                     "attention layers: sliding_window, "
                     'attention_kind="mla", parallel_block, use_post_norm, '
                     "remove_rmsnorm and LayerNorm contradict them"
+                )
+            if self.has_window_layers and (
+                self.sliding_window is None or self.sliding_window_pattern != 1
+                or self.ssm_layers or "*" in self.layer_kinds
+                or not self.rope_on_full_layers
+            ):
+                raise ValueError(
+                    f"layer_pattern={self.layer_pattern!r} names window "
+                    "layers: it needs a sliding_window, says itself which "
+                    "layers are windowed (sliding_window_pattern stays 1), "
+                    "rotates every layer, and no cache kind holds a window "
+                    "group beside a recurrent state or a layer of attention "
+                    "alone"
+                )
+            if DENSE_FFN_KIND in self.layer_kinds and self.ffn_type != "moe":
+                raise ValueError(
+                    f"layer_pattern={self.layer_pattern!r} names layers whose "
+                    'feed-forward part is dense beside expert layers: ffn_type="moe"'
                 )
         elif (
             self.attn_layer_period < 0 or self.attn_layer_offset
@@ -704,6 +815,30 @@ class ModelConfig:
                 "hybrid block's (layer_pattern, or attn_layer_period > 0): no "
                 "other block applies them"
             )
+        if self.split_attention:
+            if not (self.layer_pattern is not None and self.has_window_layers):
+                raise ValueError(
+                    "window_kv_heads, window_rope_theta, the sink, "
+                    "attention_value_scale and, outside latent attention, "
+                    "qk_rope_head_dim and v_head_dim are a layer_pattern's "
+                    "with window layers: no other block's cache holds them"
+                )
+            if self.qk_rope_head_dim % 2 or not (
+                0 <= self.qk_rope_head_dim <= self.d_head
+            ) or self.v_head_dim < 0 or self.attention_multiplier is not None:
+                raise ValueError(
+                    f"qk_rope_head_dim={self.qk_rope_head_dim} must be even "
+                    f"and at most d_head={self.d_head}, v_head_dim="
+                    f"{self.v_head_dim} not negative, and the scores' scale "
+                    "d_head ** -0.5 (no attention_multiplier)"
+                )
+            if self.window_kv_heads is not None and (
+                self.window_kv_heads < 1 or self.num_heads % self.window_kv_heads
+            ):
+                raise ValueError(
+                    f"window_kv_heads={self.window_kv_heads} must divide "
+                    f"num_heads={self.num_heads}"
+                )
         if self.expert_activation not in ("swiglu", "relu2") or (
             self.expert_activation != "swiglu" and self.ffn_type != "moe"
         ):

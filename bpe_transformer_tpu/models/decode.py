@@ -87,10 +87,19 @@ def _softmax_scale(config):
 def _rope_qk(q, k, positions, config, layer: int = 0):
     if not config.layer_rope(layer):
         return q, k
-    cos, sin = rope_tables(config.d_head, config.context_length, config.rope_theta)
+    cos, sin = rope_tables(
+        config.rope_dim, config.context_length, config.layer_rope_theta(layer)
+    )
     # Keep the compute dtype (bf16 decode must not promote to f32 here).
     cos, sin = cos.astype(q.dtype), sin.astype(q.dtype)
     pos = jnp.expand_dims(positions, axis=-2)  # broadcast over heads
+    if config.rope_dim != config.d_head:
+        # The head's leading part rotates, the rest passes.
+        def rotate(x):
+            part = apply_rope(x[..., : config.rope_dim], pos, cos, sin)
+            return jnp.concatenate([part, x[..., config.rope_dim:]], axis=-1)
+
+        return rotate(q), rotate(k)
     return apply_rope(q, pos, cos, sin), apply_rope(k, pos, cos, sin)
 
 
@@ -100,6 +109,12 @@ def _ffn_decode(x, ffn, config, valid=None, tally=None):
     many are routed alike and nothing is dropped, whatever capacity the
     training forward runs at.  ``valid`` leaves rows out of the expert
     computation; ``tally`` (a list) collects the layer's routing counts."""
+    if config.ffn_type == "moe" and "router" not in ffn:
+        # A dense layer beside expert layers (`config.DENSE_FFN_KIND`).
+        from bpe_transformer_tpu.ops.core import swiglu
+
+        with jax.named_scope("dense"):
+            return swiglu(x, ffn["w1"], ffn["w2"], ffn["w3"])
     if config.ffn_type == "moe":
         from bpe_transformer_tpu.models.moe import dropless_moe
 
@@ -222,11 +237,13 @@ def _final_norm(x, params, config):
         return x.astype(config.activation_dtype) if config.eva_block else x
 
 
-def _project_qkv(h, attn, config):
-    kv_heads = config.num_kv_heads or config.num_heads
+def _project_qkv(h, attn, config, layer: int | None = None):
+    kv_heads = config.layer_kv_heads(layer)
     q = split_heads(linear(h, attn["q_proj"]), config.num_heads)
     k = split_heads(linear(h, attn["k_proj"]), kv_heads)
     v = split_heads(linear(h, attn["v_proj"]), kv_heads)
+    if config.attention_value_scale != 1.0:
+        v = v * jnp.asarray(config.attention_value_scale, v.dtype)
     return q, k, v
 
 
@@ -968,11 +985,16 @@ class DenseRows:
 
 # A config whose layers differ in kind (sliding-window and full attention)
 # keeps two pool groups: a full layer's pool holds a slot's whole chain, a
-# window layer's only the pages still inside the window.  Both use the page
-# layout of `kernels/pallas/ragged_attention.py` - ``(pages, page_size,
-# 2 * kv_heads, d_head)``, K and V of a head side by side - which the
-# device's default tiling holds as is, so the programs donate the pool and
-# update it in place with no copy at their edges.
+# window layer's only the pages still inside the window.  Where the two
+# kinds differ in their mask alone - a period of window layers, every layer
+# the same K/V heads of one width - both groups use the page layout of
+# `kernels/pallas/ragged_attention.py`, JAX's ragged paged kernel: ``(pages,
+# page_size, 2 * kv_heads, d_head)``, K and V of a head side by side - which
+# the device's default tiling holds as is, so the programs donate the pool
+# and update it in place with no copy at their edges (`GroupedPages`).  Where
+# they differ in the shape of what they cache (K/V heads by group, a value
+# narrower than its key), each layer keeps rows of its own width and
+# `kernels/pallas/sink_attention.py` reads them (`GroupedRows`, further down).
 
 
 def init_grouped_kv_pool(
@@ -1016,7 +1038,9 @@ class _RoutingCounts:
 
 
 class GroupedPages(_RoutingCounts):
-    """`init_grouped_kv_pool`'s pool.  ``tables`` is a dict: ``"full"`` and
+    """`init_grouped_kv_pool`'s pool: both groups in the one page layout of
+    JAX's ragged paged kernel, one ``kv_heads`` and one ``d_head`` for K and
+    V of every layer.  ``tables`` is a dict: ``"full"`` and
     ``"window"`` page rows, and ``"window_base"``, the absolute position of
     the first row entry of the window group (rows there start at the slot's
     first live page; full rows start at position 0).  A layer's new K/V
@@ -1120,6 +1144,164 @@ class GroupedPages(_RoutingCounts):
             # there must not reach the next layer's K/V.
             att = jnp.where(self.ffn_rows[:, None, None], att, 0)
         return att.reshape(slots, rows, heads * d_head)
+
+
+# Attention layers that differ by kind in the SHAPE of what they cache (K/V
+# heads by group, a key wider than its value) keep the two groups of
+# `GroupedPages` over rows laid out as `DenseRows`' are: a position's keys,
+# every K/V head's side by side along the lanes, and behind them its values,
+# each at its own width - ``(blocks, block_size, kv_heads * (d_head +
+# value_dim))`` with the layer's own ``kv_heads``.  JAX's ragged kernel
+# wants K and V of one width interleaved by head; these rows are read by the
+# kernels of `kernels/pallas/sink_attention.py`.
+
+
+def init_grouped_row_pool(
+    config: ModelConfig, num_full_blocks: int, num_window_blocks: int,
+    block_size: int, dtype=jnp.float32,
+) -> list:
+    """One array of rows a layer, sized by its group and its K/V heads.
+    Block 0 of every array is the trash block."""
+    return [
+        jnp.zeros(
+            (
+                num_full_blocks if config.layer_window(layer) is None
+                else num_window_blocks,
+                block_size,
+                config.layer_kv_heads(layer) * (config.d_head + config.value_dim),
+            ),
+            dtype,
+        )
+        for layer in range(config.num_layers)
+    ]
+
+
+class GroupedRows(_RoutingCounts):
+    """`init_grouped_row_pool`'s pool.  ``tables`` is `GroupedPages`' dict
+    (``"full"`` and ``"window"`` rows of block ids and ``"window_base"``, the
+    absolute position of the window row's first entry).  A layer's new keys
+    and values go to its group's blocks as one row a position and are
+    attended from there under the layer's own mask and sink
+    (`kernels/pallas/sink_attention.py`): a tick's one row a slot straight
+    out of the pool, the blocks the slot holds inside what it may see and no
+    others; a chunk's rows against the slot's gathered chain - a window
+    layer's chain is window + chunk long whatever the context - by flash
+    accumulation.  On the CPU both are a gather and a masked softmax.
+    Either one row a slot (a tick) or one slot's chunk.  Routing counts
+    ride along as in `GroupedPages`."""
+
+    def __init__(self, config, tables, positions, valid, block_size, chunk=None):
+        if positions.ndim != 1:
+            raise NotImplementedError(
+                "several rows a slot (a verify pass) over window pool groups"
+            )
+        self.config, self.chunk = config, chunk
+        self.tally: list = []
+        tokens = positions.shape[0]
+        self.ffn_rows = jnp.ones((tokens,), bool) if valid is None else valid
+        rows = tokens if chunk is not None else 1
+        at = _clamped(positions, config.context_length - 1, rows)
+        self.rope_positions = at if chunk is not None else at[:, None]
+        window = config.sliding_window
+
+        def addresses(windowed: bool):
+            """``(block rows, block ids, offsets, index of each row's own
+            key in its block row, the first key it sees)`` of a layer."""
+            block_rows, base = (
+                (tables["window"], tables["window_base"]) if windowed
+                else (tables["full"], 0)
+            )
+            rel = (at - base).astype(jnp.int32)
+            first = (
+                jnp.maximum(rel - window + 1, 0) if windowed
+                else jnp.zeros_like(rel)
+            )
+            return (
+                block_rows,
+                *_block_addresses(block_rows, rel, self.ffn_rows, block_size, rows),
+                rel, first,
+            )
+
+        groups = {kind: addresses(kind) for kind in (False, True)}
+        self.layers = [
+            groups[config.layer_window(layer) is not None]
+            for layer in range(config.num_layers)
+        ]
+
+    @staticmethod
+    def init_pool(
+        config, num_blocks, block_size, dtype, *, kv_dtype=None,
+        num_window_blocks=0, **_,
+    ):
+        _activation_width_only(kv_dtype, "window pool groups hold K/V")
+        return init_grouped_row_pool(
+            config, num_blocks, num_window_blocks, block_size, dtype
+        )
+
+    @staticmethod
+    def attention_path(config, one_row: bool, blocks_per_slot: int, layer_pool) -> str:
+        """``"sink_paged"``, the tick's kernel, on the TPU at whole tiles;
+        ``"xla"`` elsewhere (`sink_attention.sink_paged_path`)."""
+        from bpe_transformer_tpu.kernels.pallas.sink_attention import (
+            sink_paged_path,
+        )
+
+        _, block_size, width = layer_pool.shape
+        kv_heads = width // (config.d_head + config.value_dim)
+        return sink_paged_path(block_size, width, kv_heads * config.d_head)
+
+    @jax.named_scope("pool_write")
+    def write(self, layer, rows_pool, k, v):
+        """A position's keys and, behind them, its values: one row."""
+        _, block_ids, offsets, _, _ = self.layers[layer]
+        k, v = (_token_rows(x, self.chunk is not None, True) for x in (k, v))
+        rows = jnp.concatenate(
+            [k.reshape(k.shape[0], -1), v.reshape(v.shape[0], -1)], axis=-1
+        )
+        return rows_pool.at[block_ids, offsets].set(rows.astype(rows_pool.dtype))
+
+    def attend(self, layer, q, rows_pool, sink=None):
+        from bpe_transformer_tpu.kernels.pallas import sink_attention
+
+        config = self.config
+        window = config.layer_window(layer)
+        kv_heads = config.layer_kv_heads(layer)
+        block_rows, _, _, rel, first = self.layers[layer]
+        slots, _, rows, d_head = q.shape
+        width = rows_pool.shape[-1]
+        with jax.named_scope("attn_window" if window is not None else "attn_full"):
+            if self.chunk is None:
+                counts = jnp.where(self.ffn_rows, rel + 1, 0)
+                if self.attention_path(config, True, 0, rows_pool) == "sink_paged":
+                    att = sink_attention.sink_paged_attention(
+                        q[:, :, 0], rows_pool, block_rows, counts, first, sink,
+                        kv_heads=kv_heads, window=window is not None,
+                    )
+                else:
+                    with jax.named_scope("pool_gather"):
+                        chains = rows_pool[block_rows].reshape(slots, -1, width)
+                    k, v = sink_attention.split_rows(chains, kv_heads, d_head)
+                    att = sink_attention.xla_sink_attention(
+                        jnp.swapaxes(q, 1, 2), k, v, (counts - 1)[:, None],
+                        sink=sink, first=first[:, None],
+                    )[:, 0]
+                return att.reshape(slots, 1, -1)
+            with jax.named_scope("pool_gather"):
+                chain = rows_pool[block_rows].reshape(-1, width)
+                k, v = sink_attention.split_rows(chain, kv_heads, d_head)
+            queries = jnp.swapaxes(q[0], 0, 1)       # (rows, heads, d_head)
+            if sink_attention.sink_chunk_path(rows, chain.shape[0], window) == "sink_chunk":
+                att = sink_attention.sink_chunk_attention(
+                    queries, k, v, rel[0], sink, window=window
+                )
+            else:
+                att = sink_attention.xla_sink_attention(
+                    queries[None], k[None], v[None], rel[None], window=window,
+                    sink=sink,
+                )[0]
+            # What a padded row computed must not reach the next layer's K/V.
+            att = jnp.where(self.ffn_rows[:, None, None], att, 0)
+            return att.reshape(1, rows, -1)
 
 
 # Latent attention caches one row a position and attention sublayer, the
@@ -1545,9 +1727,12 @@ def cache_kind(config: ModelConfig):
         return LatentRows
     if config.eva_block:
         return EvaRows
-    if config.hybrid_block:
-        return RecurrentRows
-    return GroupedPages if config.has_window_layers else DenseRows
+    if config.has_window_layers:
+        # However the window layers were spelt (a period or a pattern's
+        # letters): pages while the two kinds differ in their mask alone,
+        # rows once they differ in the shape of what they cache.
+        return GroupedRows if config.split_attention else GroupedPages
+    return RecurrentRows if config.hybrid_block else DenseRows
 
 
 def init_paged_pool(
@@ -1602,11 +1787,15 @@ def _cached_attention(
         return cache.attention(h, attn, layer, layer_pool, new_pool)
     if ssm is not None:
         return cache.mixer(h, ssm, layer_pool, new_pool)
-    q, k, v = _project_qkv(h, attn, config)
+    q, k, v = _project_qkv(h, attn, config, layer)
     q, k = _rope_qk(q, k, cache.rope_positions, config, layer)
     layer_pool = cache.write(layer, layer_pool, k, v)
     new_pool.append(layer_pool)
-    return linear(cache.attend(layer, q, layer_pool), attn["output_proj"])
+    if "sink" in attn:
+        att = cache.attend(layer, q, layer_pool, sink=attn["sink"])
+    else:
+        att = cache.attend(layer, q, layer_pool)
+    return linear(att, attn["output_proj"])
 
 
 def paged_forward(

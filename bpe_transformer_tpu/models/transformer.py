@@ -60,7 +60,6 @@ def init_params(
     # GQA: K/V project to num_kv_heads * d_head rows (== d for plain MHA);
     # Q to num_heads * d_head (== d unless the config sets head_dim).
     d_q = config.num_heads * config.d_head
-    d_kv = (config.num_kv_heads or config.num_heads) * config.d_head
     keys = jax.random.split(rng, 2 + config.num_layers)
     layers = []
     for i in range(config.num_layers):
@@ -100,16 +99,22 @@ def init_params(
 
             layer["attn"] = init_eva_params(k[0], config, dtype)
         elif config.layer_mixer(i):
+            # K/V heads by the layer's kind, a value at its own width, and
+            # the sink's logit a query head where the kind has one (for a
+            # config whose layers are alike: d_kv twice and d_q again).
+            kv_heads = config.layer_kv_heads(i)
             layer["attn"] = {
                 "q_proj": dense(k[0], d_q, d),
-                "k_proj": dense(k[1], d_kv, d),
-                "v_proj": dense(k[2], d_kv, d),
-                "output_proj": dense(k[3], d, d_q),
+                "k_proj": dense(k[1], kv_heads * config.d_head, d),
+                "v_proj": dense(k[2], kv_heads * config.value_dim, d),
+                "output_proj": dense(k[3], d, config.num_heads * config.value_dim),
             }
+            if config.layer_sink(i):
+                layer["attn"]["sink"] = jnp.zeros((config.num_heads,), jnp.float32)
         if config.layer_mixer(i):
             layer["ln1"] = unit((d,), dtype)
         if config.layer_has_ffn(i):
-            if config.ffn_type == "moe":
+            if config.ffn_type == "moe" and not config.layer_ffn_is_dense(i):
                 from bpe_transformer_tpu.models.moe import init_moe_params
 
                 layer["ffn"] = init_moe_params(k[4], config, dtype)
@@ -609,10 +614,12 @@ def forward_hidden(
         # config's own score multiplier.
         from bpe_transformer_tpu.models.decode import _block_apply
 
-        for block_params in compute_params["layers"]:
+        for layer, block_params in enumerate(compute_params["layers"]):
             x = _block_apply(
                 x, block_params, config,
-                lambda h, p=block_params: _hybrid_mixer(h, p, config, positions),
+                lambda h, p=block_params, layer=layer: _hybrid_mixer(
+                    h, p, config, positions, layer
+                ),
             )
     elif config.dropless_block:
         for layer, block_params in enumerate(compute_params["layers"]):
@@ -635,12 +642,35 @@ def forward_hidden(
     return _final_norm(x, compute_params, config), aux_total
 
 
-def _hybrid_mixer(h: Array, block_params: dict, config: ModelConfig, positions) -> Array:
+def _hybrid_mixer(
+    h: Array, block_params: dict, config: ModelConfig, positions, layer: int = 0
+) -> Array:
     """A hybrid block's mixer over a whole sequence from its start."""
     if "ssm" in block_params:
         from bpe_transformer_tpu.models.ssm import mamba2
 
         return mamba2(h, block_params["ssm"], config)[0]
+    if config.has_window_layers:
+        # Window and full layers by kind, as the paged forward projects,
+        # rotates and attends them, the whole sequence its own chain.
+        from bpe_transformer_tpu.kernels.pallas.sink_attention import (
+            xla_sink_attention,
+        )
+        from bpe_transformer_tpu.models.decode import _project_qkv, _rope_qk
+
+        attn = block_params["attn"]
+        q, k, v = _project_qkv(h, attn, config, layer)
+        q, k = _rope_qk(q, k, positions, config, layer)
+        q, k, v = (jnp.swapaxes(x, -3, -2) for x in (q, k, v))
+        lead = q.shape[:-3]
+        q, k, v = (x.reshape(-1, *x.shape[-3:]) for x in (q, k, v))
+        scope = "attn_full" if config.layer_window(layer) is None else "attn_window"
+        with jax.named_scope(scope):
+            att = xla_sink_attention(
+                q, k, v, jnp.broadcast_to(positions, q.shape[:2]),
+                window=config.layer_window(layer), sink=attn.get("sink"),
+            )
+        return linear(att.reshape(*lead, att.shape[1], -1), attn["output_proj"])
     def attention_fn(q, k, v):
         # Materialized causal scores under the config's own multiplier.
         scores = jnp.einsum("...qd,...kd->...qk", q, k).astype(jnp.float32)

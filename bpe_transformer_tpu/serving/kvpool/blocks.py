@@ -170,3 +170,48 @@ class WindowChain:
     def release(self) -> None:
         self.allocator.deref(self.ids)
         self.ids = []
+
+
+class GrowingWindowChain:
+    """One slot's chain in a window group that is **not** a reservation: the
+    blocks from ``first`` (a logical block index) on, taken one by one as
+    the slot's launches reach them (:meth:`reach`) and given back as soon as
+    the launch that read them is queued (:meth:`advance`).  `WindowChain`
+    holds ``window + chunk`` positions a slot from admission on; where the
+    window is far shorter than a chunk that is many windows' worth held by
+    every slot for the one chunk in flight, so here the group is sized for
+    what the slots hold *between* launches - the blocks back from a slot's
+    next query's window start - and one chunk's beside them, and it is the
+    caller (`host_cache.HostGroupedRows`) that cuts every chain back before
+    it lets one reach ahead."""
+
+    def __init__(self, allocator: BlockAllocator):
+        self.allocator = allocator
+        self.first = 0
+        self.ids: list[int] = []
+
+    def advance(self, lo_pos: int) -> int:
+        """Positions below ``lo_pos`` will not be read again: free the
+        blocks wholly below it.  Returns how many."""
+        dead = min(max(lo_pos, 0) // self.allocator.block_size - self.first, len(self.ids))
+        if dead <= 0:
+            return 0
+        self.allocator.deref(self.ids[:dead])
+        self.first, self.ids = self.first + dead, self.ids[dead:]
+        return dead
+
+    def reach(self, lo_pos: int, last_pos: int) -> bool:
+        """Hold the blocks of positions ``lo_pos .. last_pos`` (what lies
+        below was given up by :meth:`advance`).  Returns whether the chain
+        grew."""
+        block_size = self.allocator.block_size
+        if not self.ids:  # nothing live: the chain starts at the window
+            self.first = max(lo_pos, 0) // block_size
+        ahead = last_pos // block_size + 1 - self.first - len(self.ids)
+        if ahead > 0:
+            self.ids = self.ids + self.allocator.alloc(ahead)
+        return ahead > 0
+
+    def release(self) -> None:
+        self.allocator.deref(self.ids)
+        self.ids = []

@@ -16,10 +16,13 @@ import numpy as np
 from bpe_transformer_tpu.kernels.pallas import mla_attention
 from bpe_transformer_tpu.models import mla
 from bpe_transformer_tpu.models.decode import (
-    DenseRows, EvaRows, GroupedPages, LatentRows, RecurrentRows, eva_table_geometry,
+    DenseRows, EvaRows, GroupedPages, GroupedRows, LatentRows, RecurrentRows,
+    eva_table_geometry,
 )
 from bpe_transformer_tpu.ops.quant import tree_bytes
-from bpe_transformer_tpu.serving.kvpool.blocks import BlockAllocator, WindowChain
+from bpe_transformer_tpu.serving.kvpool.blocks import (
+    BlockAllocator, GrowingWindowChain, WindowChain,
+)
 
 __all__ = ["HOST_HALVES"]
 
@@ -216,8 +219,9 @@ class HostGroupedPages(HostDenseRows):
                 f"be multiples of block_size={block_size}"
             )
         self.window_cap = min((window + chunk) // block_size, self.blocks_per_slot)
-        self.pool_keywords = {"num_window_blocks": slots * self.window_cap + 1}
-        self.window_allocator = BlockAllocator(slots * self.window_cap + 1, block_size)
+        blocks = self._window_group_blocks()
+        self.pool_keywords = {"num_window_blocks": blocks}
+        self.window_allocator = BlockAllocator(blocks, block_size)
         self.chains: list[WindowChain | None] = [None] * slots
         self.window_tables = np.zeros((slots, max(self.window_cap, 1)), np.int32)
         self.window_base = np.zeros(slots, np.int32)
@@ -226,9 +230,17 @@ class HostGroupedPages(HostDenseRows):
             config.layer_window(layer) is not None for layer in range(config.num_layers)
         )
 
+    def _window_group_blocks(self) -> int:
+        """The window group's size, its trash block included: every slot's
+        reservation of window + one chunk."""
+        return self.slots * self.window_cap + 1
+
+    def _new_chain(self, need: int):
+        """The window chain of a request whose full chain is ``need`` blocks."""
+        return WindowChain(self.window_allocator, self.window_cap, need)
+
     def admit(self, slot: int, block_ids: list) -> None:
-        chain = WindowChain(self.window_allocator, self.window_cap, len(block_ids))
-        self.chains[slot] = chain
+        self.chains[slot] = self._new_chain(len(block_ids))
         self._write_window_row(slot)
         super().admit(slot, block_ids)
 
@@ -279,9 +291,11 @@ class HostGroupedPages(HostDenseRows):
         row[: len(chain.ids)] = chain.ids
         self.window_base[slot] = chain.first * self.block_size
 
-    def count_attention(self, start: int, end: int) -> None:
-        """Add what the attention of queries ``start .. end - 1`` of one
-        slot needs, over the layers of both kinds (plain integers)."""
+    def attention_needs(self, start: int, end: int) -> tuple[int, int, int, int]:
+        """What the attention of queries ``start .. end - 1`` of one slot
+        needs: ``(visible pairs of the full layers, of the window layers,
+        key positions of the full layers, of the window layers)``, each
+        over its group's layers (plain integers)."""
         window = self.config.sliding_window
         full_layers = self.attn_sublayers - self._window_layers
         full_pairs = (end * (end + 1) - start * (start + 1)) // 2
@@ -291,10 +305,19 @@ class HostGroupedPages(HostDenseRows):
         window_pairs = (
             uncapped_end * (uncapped_end + 1) - start * (start + 1)
         ) // 2 + capped * window
-        self.attn_pairs += full_layers * full_pairs + self._window_layers * window_pairs
-        self.attn_kv_positions += full_layers * end + self._window_layers * (
-            end - max(start - window + 1, 0)
+        return (
+            full_layers * full_pairs, self._window_layers * window_pairs,
+            full_layers * end,
+            self._window_layers * (end - max(start - window + 1, 0)),
         )
+
+    def count_attention(self, start: int, end: int) -> tuple[int, int, int, int]:
+        """Add what the attention of queries ``start .. end - 1`` of one
+        slot needs, over the layers of both kinds; returns it by group."""
+        needs = self.attention_needs(start, end)
+        self.attn_pairs += needs[0] + needs[1]
+        self.attn_kv_positions += needs[2] + needs[3]
+        return needs
 
     def gauges(self) -> dict:
         return {
@@ -303,6 +326,100 @@ class HostGroupedPages(HostDenseRows):
             "kv_window_blocks_free": self.window_allocator.free_count,
             "kv_window_blocks_recycled": self.window_recycled,
         }
+
+
+class HostGroupedRows(HostGroupedPages):
+    """`GroupedRows`' host half: `HostGroupedPages`' two groups and window
+    rows, over a window group that is **not** a reservation a slot.  A window
+    far shorter than a chunk (128 positions under chunks of 2,048) makes
+    ``window + chunk`` blocks a slot seventeen windows' worth for every slot
+    at once, of which a slot between its launches needs one: the blocks back
+    from its next query's window start.  So a slot's chain is taken block by
+    block as its launches reach them and cut back **behind the launch that
+    read them** - at the next launch of any slot, which the device runs after
+    it - and the group holds ``window // block_size + 1`` blocks a slot and
+    one chunk's beside them.  Attention is counted by group."""
+
+    #: Key positions the ticks' slots attended, and the chunks' (a chunk
+    #: reads what lies before it back to its first row's window start, and
+    #: its own rows), each x its group's layers; the visible (query, key)
+    #: pairs of the chunks likewise.
+    attn_full_kv_positions = attn_window_kv_positions = 0
+    chunk_attn_full_kv_positions = chunk_attn_window_kv_positions = 0
+    chunk_attn_full_pairs = chunk_attn_window_pairs = 0
+    counters = HostDenseRows.counters + (
+        "attn_full_kv_positions", "attn_window_kv_positions",
+        "chunk_attn_full_kv_positions", "chunk_attn_window_kv_positions",
+        "chunk_attn_full_pairs", "chunk_attn_window_pairs",
+    )
+    no_tick = {"attn_full_kv_positions": 0, "attn_window_kv_positions": 0}
+
+    def _lay_out(self, prefill_chunk) -> None:
+        super()._lay_out(prefill_chunk)
+        #: Where a slot's next query's window starts, once the launch that
+        #: read further back is queued: blocks below it are dead.
+        self._cut_at: dict[int, int] = {}
+
+    def _window_group_blocks(self) -> int:
+        window_blocks = self.config.sliding_window // self.block_size + 1
+        return self.slots * window_blocks + self.window_cap + 1
+
+    def _new_chain(self, need: int):
+        return GrowingWindowChain(self.window_allocator)
+
+    def kv_bytes_per_token(self, itemsize: int) -> int:
+        config = self.config
+        return sum(
+            config.layer_kv_heads(layer) * (config.d_head + config.value_dim)
+            for layer in range(config.num_layers)
+        ) * itemsize
+
+    def release(self, slot: int) -> None:
+        super().release(slot)
+        self._cut_at.pop(slot, None)
+
+    def _cut_back(self) -> None:
+        """Free what the launches queued so far have read for the last time."""
+        for slot, lo_pos in self._cut_at.items():
+            self.advance_window(slot, lo_pos)
+        self._cut_at.clear()
+
+    def _reach(self, slot: int, lo_pos: int, last_pos: int) -> None:
+        """``slot``'s chain from the block of ``lo_pos`` to that of ``last_pos``."""
+        self.advance_window(slot, lo_pos)
+        if self.chains[slot].reach(lo_pos, last_pos):
+            self._write_window_row(slot)
+
+    def before_chunk(self, slot: int, start: int, chunk_len: int, bucket: int) -> None:
+        window, end = self.config.sliding_window, start + chunk_len
+        self._cut_back()
+        self._reach(slot, start - window + 1, end - 1)
+        self._cut_at[slot] = end - window + 1
+        full_pairs, window_pairs, full_keys, window_keys = self.count_attention(start, end)
+        self.chunk_attn_full_pairs += full_pairs
+        self.chunk_attn_window_pairs += window_pairs
+        self.chunk_attn_full_kv_positions += full_keys
+        self.chunk_attn_window_kv_positions += window_keys
+
+    def before_tick(self, live, positions, active):
+        window = self.config.sliding_window
+        self._cut_back()
+        for slot in live:
+            at = int(positions[slot])
+            self._reach(int(slot), at - window + 1, at)
+        seen = positions[live].astype(np.int64) + 1
+        counts = {
+            "attn_full_kv_positions": (
+                (self.attn_sublayers - self._window_layers) * int(seen.sum())
+            ),
+            "attn_window_kv_positions": (
+                self._window_layers * int(np.minimum(seen, window).sum())
+            ),
+        }
+        self.attn_full_kv_positions += counts["attn_full_kv_positions"]
+        self.attn_window_kv_positions += counts["attn_window_kv_positions"]
+        self._count_tick(sum(counts.values()))
+        return seen, counts
 
 
 class HostLatentRows(HostDenseRows):
@@ -599,5 +716,6 @@ class HostEvaRows(HostDenseRows):
 #: A cache kind's host half by its device half (`models/decode.cache_kind`).
 HOST_HALVES = {
     DenseRows: HostDenseRows, GroupedPages: HostGroupedPages,
+    GroupedRows: HostGroupedRows,
     LatentRows: HostLatentRows, RecurrentRows: HostRecurrentRows, EvaRows: HostEvaRows,
 }

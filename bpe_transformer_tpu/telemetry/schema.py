@@ -103,7 +103,11 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # shared pass, and the slots on the shared chain (over a latent pool
     # alone); optional ``attn_kv_positions`` / ``attn_summary_kv_positions``:
     # cached rows x layers that tick attended and those of them that are
-    # chunk summaries (over a summary-and-window cache alone).
+    # chunk summaries (over a summary-and-window cache alone); optional
+    # ``attn_full_kv_positions`` / ``attn_window_kv_positions``: key
+    # positions that tick's slots attended in the full-attention layers and
+    # - inside the window - in the window layers, each x its group's layers
+    # (over rows of keys and values by group alone, `decode.GroupedRows`).
     "tick": {
         "kind", "t", "dur_s", "admit_s", "prefill_s", "chunks",
         "prefill_tokens", "dispatch_s", "wait_s", "emit_s", "deliver_s",
@@ -217,7 +221,18 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # the chunks' pairs too, ``kv_bytes_per_token`` is one cached ROW over
     # the layers - a position's or a chunk's summary - and
     # ``kv_window_blocks_recycled`` counts a closed window's blocks, which
-    # the slot keeps for its next window); and
+    # the slot keeps for its next window); over rows of keys and values by
+    # group alone (`decode.GroupedRows`) the attention's counts by group,
+    # each x its group's layers: ``attn_full_kv_positions`` /
+    # ``attn_window_kv_positions`` (the ticks': a live slot's context, and
+    # what of it lies inside the window), ``chunk_attn_full_kv_positions``
+    # / ``chunk_attn_window_kv_positions`` (the chunks': back to a chunk's
+    # first row's window start in a window layer) and
+    # ``chunk_attn_full_pairs`` / ``chunk_attn_window_pairs`` (the chunks'
+    # visible (query, key) pairs) - there ``attn_pairs`` and
+    # ``attn_kv_positions`` hold both groups', ticks and chunks, and
+    # ``kv_window_blocks_recycled`` the window group's blocks given back
+    # behind the launch that read them; and
     # the two dispatch phases
     # in parts (ISSUE 38; ``paged_engine.LAUNCH_PARTS``), clock seconds
     # summed over every launch: ``launch_tick_{prepare,call,after}_s`` of

@@ -322,13 +322,14 @@ def _described(tree, one_chip):
 
 def _pool_program(
     name, config, one_chip, kv_dtype, layers_as_calls=True,
-    slots=POOL_SLOTS, blocks=POOL_BLOCKS, bucket=256,
+    slots=POOL_SLOTS, blocks=POOL_BLOCKS, bucket=256, prefill_chunk=256,
 ):
     """``(jitted program, its arguments described on the chip, the pool)``
     for one of the engine's pool programs, jitted as `PagedEngine` jits it
     on the TPU (``layers_as_calls=False``: without the compiler option),
     over ``slots`` slots and a pool of ``blocks`` blocks; a chunk is of
-    ``bucket`` rows."""
+    ``bucket`` rows, the engine's ``prefill_chunk`` (which sizes a window
+    group's rows) ``prefill_chunk``."""
     import functools
 
     from bpe_transformer_tpu.utils.compile_cache import layered_program_options
@@ -352,16 +353,24 @@ def _pool_program(
         return prepare_serving_weights(params, config, None)[:2]
 
     params, lm_head = _described(jax.eval_shape(weights), one_chip)
-    # A config with window layers keeps a window group beside the full one:
-    # window + one chunk (256 here) of positions a slot, as the engine sizes it.
-    window_cap = (
-        (config.sliding_window + 256) // bs if config.has_window_layers else 0
-    )
+    # A config with window layers keeps a window group beside the full one,
+    # its rows and its size the kind's host half's (window + one chunk of
+    # positions a row), as the engine asks them.
+    window_cap = window_blocks = 0
+    if config.has_window_layers:
+        from bpe_transformer_tpu.serving.kvpool.host_cache import HOST_HALVES
+
+        host = HOST_HALVES[cache_kind(config)](
+            config, slots=slots, block_size=bs, prefill_chunk=prefill_chunk,
+            prefix_cache=False, kv_dtype=False, fused_sampling=False,
+        )
+        window_cap = host.window_cap
+        window_blocks = host.pool_keywords["num_window_blocks"]
     pool = _described(
         jax.eval_shape(
             lambda: init_paged_pool(
                 config, blocks, bs, BF16, kv_dtype=kv_dtype, slots=slots,
-                num_window_blocks=slots * window_cap + 1 if window_cap else 0,
+                num_window_blocks=window_blocks,
             )
         ),
         one_chip,
@@ -400,7 +409,8 @@ def _pool_program(
     if name == "chunk":
         fn = functools.partial(pe._chunk_program, config=config, block_size=bs)
         table_row = tables()
-        if config.hybrid_block:  # a chunk addresses its slot's state by id
+        if config.hybrid_block and not config.has_window_layers:
+            # A chunk addresses its slot's recurrent state by the slot's id.
             table_row = {"blocks": table_row, "slot": scalar}
         args = (
             params, lm_head, pool, moe, table_row, arr((1, bucket), I32),
@@ -538,6 +548,8 @@ def _cell_config(cell: str):
         "nemotron": ("NVIDIA-Nemotron-3-Nano-30B-A3B-BF16", {
             "num_layers": 3, "layer_pattern": "ME*",
         }),
+        # The leading dense layer, a window layer and a full layer that routes.
+        "mimo": ("MiMo-V2.5", {"num_layers": 3, "layer_pattern": "Awa"}),
     }[cell]
     path = Path(__file__).resolve().parents[1] / f"chipbench/configs/{name}.json"
     file = json.loads(path.read_text())
@@ -1284,3 +1296,87 @@ def test_evabyte_pool_programs(one_chip, on_tpu, name, bucket):
     # All eight layers of pool, the weights and the temporaries fit the chip.
     weights = 2 * (8 * 202_391_552 + 11_800_576)
     assert 4 * pool_bytes + weights + memory.temp_size_in_bytes < 16.0e9
+
+
+# ------------------------------- rows of keys and values by group (MiMo-V2.5)
+
+
+@pytest.mark.parametrize("group", ["full", "window"])
+def test_sink_paged_attention(one_chip, group):
+    """The tick's kernel at the cell's widths, 64 slots: 64 query heads of
+    192 over 4 K/V heads and a chain of 2,048 blocks (the full group; the
+    whole table rides scalar prefetch), or over 8 K/V heads with a sink and
+    rows of 136 blocks (the window group).  A row is K at 192 and V at 128 a
+    head: 1,280 / 2,560 lanes, whole tiles."""
+    from bpe_transformer_tpu.kernels.pallas.sink_attention import (
+        sink_paged_attention,
+    )
+
+    kv_heads, blocks, row = (4, 73729, 2048) if group == "full" else (8, 713, 136)
+    shapes = [
+        ((64, 64, 192), BF16), ((blocks, 16, kv_heads * 320), BF16),
+        ((64, row), I32), ((64,), I32), ((64,), I32),
+    ]
+    if group == "window":
+        shapes.append(((64,), F32))
+
+    def fn(q, pool, tables, counts, firsts, sink=None):
+        return sink_paged_attention(
+            q, pool, tables, counts, firsts, sink, kv_heads=kv_heads,
+            window=group == "window", interpret=False,
+        )
+
+    text = _compile(fn, one_chip, *shapes)
+    assert f"sink_paged_attention_{group}" in text
+
+
+@pytest.mark.parametrize("bucket", [512, 2048])
+@pytest.mark.parametrize("group", ["full", "window"])
+def test_sink_chunk_attention(one_chip, group, bucket):
+    """The chunk's kernel at the cell's widths and buckets: a full layer's
+    gathered chain of 32,768 keys in blocks of 512, a window layer's of 2,176
+    in blocks of 128, of which a block of 128 queries walks three."""
+    from bpe_transformer_tpu.kernels.pallas.sink_attention import (
+        chunk_tiles,
+        sink_chunk_attention,
+    )
+
+    kv_heads, keys, window = (4, 32768, None) if group == "full" else (8, 2176, 128)
+    assert chunk_tiles(bucket, keys, window)[2] == (64 if group == "full" else 3)
+    shapes = [
+        ((bucket, 64, 192), BF16), ((keys, kv_heads, 192), BF16),
+        ((keys, kv_heads, 128), BF16), ((), I32),
+    ]
+    if group == "window":
+        shapes.append(((64,), F32))
+
+    def fn(q, k, v, at, sink=None):
+        return sink_chunk_attention(q, k, v, at, sink, window=window, interpret=False)
+
+    text = _compile(fn, one_chip, *shapes)
+    assert f"sink_chunk_attention_{group}" in text
+
+
+@pytest.mark.parametrize("name", ["tick", "chunk"], ids=["tick", "chunk2048"])
+def test_grouped_row_pool_programs(one_chip, on_tpu, name):
+    """``mimo.serve.long-context``'s tick and chunk programs at its widths,
+    slots, pool and bucket, cut to three layers (the dense leading layer, a
+    window layer, a full layer that routes): both groups' kernels are there
+    under their own names, the pool - a row K at 192 and V at 128 a head,
+    1,280 lanes in the full group and 2,560 in the window group - is
+    aliased whole and never copied, and the experts go through `gmm`."""
+    config = _cell_config("mimo")
+    jitted, args, pool = _pool_program(
+        name, config, one_chip, None, False, slots=64, blocks=73729,
+        bucket=2048, prefill_chunk=2048,
+    )
+    assert sorted({a.shape for a in pool}) == [(713, 16, 2560), (73729, 16, 1280)]
+    compiled = jitted.lower(*args).compile()
+    text = compiled.as_text()
+    kernel = "sink_paged_attention" if name == "tick" else "sink_chunk_attention"
+    assert f"{kernel}_full" in text and f"{kernel}_window" in text
+    assert "gmm" in text and "block/ffn/dense" in text
+    assert _pool_copies(text, {_shape_text(a) for a in pool}) == []
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= sum(a.size * 2 for a in pool)
+    assert memory.temp_size_in_bytes < 700e6

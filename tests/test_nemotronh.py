@@ -632,7 +632,7 @@ def test_a_verify_pass_padded_prefill_and_training_are_refused():
     "change, message",
     [
         (dict(layer_pattern="MEM*EM"), "must name each of num_layers=7"),
-        (dict(layer_pattern="MEM*EMx"), "by one of 'M\\*Ema'"),
+        (dict(layer_pattern="MEM*EMx"), "by one of 'M\\*EmawA'"),
         (dict(attn_layer_period=3), "say the same twice"),
         (dict(ssm_groups=3), "ssm_groups=3 must divide ssm_heads=8"),
         (dict(ssm_groups=0), "must divide"),

@@ -148,7 +148,18 @@ DEFAULT_BUDGET_S = 800.0
 #: (8 cases), and the kernel at five shapes the packing does not fit compiled
 #: for the described v5e (tests/test_chip_compile.py, 1-2 s each); the whole
 #: run 573 s with six workers.
-DEFAULT_MAX_TESTS = 1325
+#: Raised 1325 -> 1400 in PR 46 (1,369 collected, 69 added): MiMo-V2.5's
+#: block against its reference - the forward on two shares, prefill then
+#: teacher-forced ticks through the two groups of rows at three prompt
+#: lengths, each mechanism (the sink, two rotations' bases, the rotated
+#: part, the value scale) left out in the forward and in the engine, the
+#: share against the whole, the window group that is no reservation, the
+#: counters by group, the two kernels in interpret mode (ten cases), the
+#: cache kind by shape and not by spelling, every refusal
+#: (tests/test_mimov2.py, 61 cases in about 75 s with six workers)
+#: and the two kernels and the cell's tick and chunk programs compiled for
+#: the described v5e (tests/test_chip_compile.py, 8 cases, 1-20 s each).
+DEFAULT_MAX_TESTS = 1400
 
 #: Pytest summary trailer: "== 398 passed, 27 deselected in 612.34s =="
 #: (also plain "in 612.34s (0:10:12)" forms).
