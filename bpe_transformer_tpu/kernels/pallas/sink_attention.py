@@ -31,9 +31,15 @@ device events tell the groups apart:
   the sink as the softmax's starting state.
 * :func:`sink_chunk_attention` (``sink_chunk_attention_full`` / ``_window``)
   - the chunk: one slot's rows against its gathered chain, flash accumulation
-  over blocks of keys, so no (chunk x context) score reaches HBM; the blocks
-  of keys a block of queries walks begin at its window's start and end at its
-  last row's own key, so a window layer reads nothing outside window + chunk.
+  over blocks of keys, so no (chunk x context) score reaches HBM.  A grid
+  step is a block of query rows of one K/V head, whose keys and values lie in
+  VMEM (fetched once a head); the walk over blocks of keys is a loop INSIDE
+  the step, bounded by :func:`chunk_walk` from the chunk's position: the
+  blocks every row of the query block sees whole are folded with no mask -
+  matmul, scale, max / exp / sum, rescale, matmul - the one or two the
+  diagonal crosses (and, under a window, its lower edge) under the mask, and
+  a block no row sees costs nothing: no step, no copy, no branch.  A window
+  layer's chain is window + chunk long and its walk two or three blocks.
 
 Elsewhere (the CPU's tests) the same contracts are a gather and a masked
 softmax in XLA (:func:`xla_sink_attention`); ``interpret=True`` runs the
@@ -58,11 +64,20 @@ from bpe_transformer_tpu.ops.core import MASK_VALUE as NEG_INF
 
 #: Keys a step of the tick's kernel copies and computes on.
 PAGED_GROUP_KEYS = 256
-#: Query rows and keys a step of the chunk's kernel computes on (every query
-#: head of a K/V head at once: 16 x 128 rows of scores a step at 16-to-1).
+#: The chunk kernel's blocks.  `CHUNK_QUERY_ROWS`: query rows of a grid step,
+#: every query head of a K/V head at once (16 x 128 rows of scores at
+#: 16-to-1) - and the keys of a window layer's block, so that a walk reads
+#: little outside the window.  `CHUNK_KEYS`: keys of a full layer's block,
+#: one trip of the loop inside the step (2,048 x 256 float32 scores: of the
+#: blocks of 128 rows timed on the v5e at 256, 512 and 1,024 keys the
+#: fastest, PERF.md section 6, PR 47).
 CHUNK_QUERY_ROWS = 128
-CHUNK_KEYS = 512
+CHUNK_KEYS = 256
 CHUNK_VMEM_LIMIT_BYTES = 96 * 1024 * 1024
+#: What a K/V head's keys and values held in VMEM may take, one of the two
+#: buffers the pipeline keeps: a third of the limit each, and the last third
+#: for a step's scores and its state.
+CHUNK_HELD_BYTES = CHUNK_VMEM_LIMIT_BYTES // 3
 
 
 def sink_logits(sink, heads: int):
@@ -367,79 +382,147 @@ def sink_paged_path(block_size: int, width: int, k_width: int) -> str:
 def _chunk_kernel(
     at_ref, q_ref, sink_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     scale: float, window: int | None, tq: int, tk: int, key_blocks: int,
+    held_blocks: int, guard: bool,
 ):
-    """One block of ``tq`` query rows of every query head of one K/V head
-    against one block of ``tk`` keys; the grid's last axis walks the blocks
-    from the one that holds the first row's window start to the one that
-    holds the last row's own key (`_key_block`), flash accumulation from the
-    sink's state."""
-    i, j = pl.program_id(1), pl.program_id(2)
-    first, last = _block_range(at_ref[0], i, tq, tk, window, key_blocks)
+    """One block of ``tq`` query rows of every query head of one K/V head a
+    grid step, against the ``held_blocks`` blocks of ``tk`` keys of the
+    head's chain that lie in VMEM (the whole chain where it fits; else the
+    grid's last axis moves over its parts, under one softmax state).  The
+    walk over key blocks is here, not in the grid: `chunk_walk` gives, from
+    the chunk's position, the blocks an edge crosses below (a window's lower
+    edge), the ``clear`` blocks every row sees whole, and the blocks an edge
+    crosses above (the diagonal).  A clear block is folded with no mask at
+    all; a block outside the walk costs nothing.  Flash accumulation from
+    the sink's state.  ``guard``: a row may meet a block of which it sees
+    nothing while its running maximum is still `NEG_INF` (a window and no
+    sink), so the probabilities are masked too."""
+    i, part = pl.program_id(1), pl.program_id(2)
+    first, clear_lo, clear_hi, end = chunk_walk(
+        at_ref[0], i, tq, tk, window, key_blocks, jnp.minimum, jnp.maximum
+    )
+    group, lanes = q_ref.shape[1], m_ref.shape[1]
+    held_from = part * held_blocks
 
-    @pl.when(j == 0)
+    @pl.when(part == 0)
     def _start():
-        sink = sink_ref[0]                          # (group * tq, 1)
-        m_ref[...] = jnp.broadcast_to(sink, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(
-            jnp.where(sink > 0.5 * NEG_INF, 1.0, 0.0), l_ref.shape
-        )
+        sink = sink_ref[0]                          # (group * tq, lanes)
+        m_ref[...] = sink
+        l_ref[...] = jnp.where(sink > 0.5 * NEG_INF, 1.0, 0.0)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(first + j <= last)
-    def _step():
-        group = q_ref.shape[1]
+    def fold(block, edge: bool):
         q = q_ref[0].reshape(group * tq, q_ref.shape[-1])
-        k, v = k_ref[0], v_ref[0]
+        at = pl.ds(pl.multiple_of((block - held_from) * tk, tk), tk)
+        k, v = k_ref[0, at, :], v_ref[0, at, :]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale                                   # (group * tq, tk)
-        row = jax.lax.broadcasted_iota(jnp.int32, (group * tq, 1), 0)
-        q_at = at_ref[0] + i * tq + jax.lax.rem(row, tq)
-        key_at = (first + j) * tk + jax.lax.broadcasted_iota(
-            jnp.int32, (1, tk), 1
-        )
-        seen = key_at <= q_at
-        if window is not None:
-            seen &= q_at - key_at < window
-        s = jnp.where(seen, s, NEG_INF)
-        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
+        if edge:
+            row = jax.lax.broadcasted_iota(jnp.int32, (group * tq, 1), 0)
+            q_at = at_ref[0] + i * tq + jax.lax.rem(row, tq)
+            key_at = block * tk + jax.lax.broadcasted_iota(
+                jnp.int32, (1, tk), 1
+            )
+            seen = key_at <= q_at
+            if window is not None:
+                seen &= q_at - key_at < window
+            s = jnp.where(seen, s, NEG_INF)
+        # The state lies a row's value in every lane: a reduction's column
+        # meets it with one broadcast, and it meets the accumulator with none.
+        m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p = jnp.exp(s - pltpu.repeat(m_new, tk // lanes, axis=1))
+        if edge and guard:
+            p = jnp.where(seen, p, 0.0)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * pltpu.repeat(
+            alpha, acc_ref.shape[1] // lanes, axis=1
+        ) + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        m_ref[...] = m_new
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    def walk(lo, hi, edge: bool):
+        """Fold blocks ``lo .. hi - 1``, those of them that are held."""
+        jax.lax.fori_loop(
+            jnp.maximum(lo, held_from),
+            jnp.minimum(hi, held_from + held_blocks),
+            lambda block, _: fold(block, edge), None,
+        )
+
+    if window is not None:
+        walk(first, clear_lo, True)
+    walk(clear_lo, clear_hi, False)
+    walk(clear_hi, end, True)
+
+    @pl.when(part == pl.num_programs(2) - 1)
     def _end():
-        out = acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
+        out = acc_ref[...] / pltpu.repeat(
+            jnp.maximum(l_ref[...], 1e-30), acc_ref.shape[1] // lanes, axis=1
+        )
         o_ref[0] = out.reshape(o_ref.shape[1:]).astype(o_ref.dtype)
 
 
-def _block_range(q_at0, i, tq: int, tk: int, window, key_blocks: int):
-    """``(first, last)`` block of keys that query block ``i`` walks: from
-    its first row's window start (block 0 without a window) to its last
-    row's own key, inside the chain."""
-    lo = 0 if window is None else jnp.maximum(q_at0 + i * tq - window + 1, 0)
-    hi = jnp.clip(q_at0 + (i + 1) * tq - 1, 0, key_blocks * tk - 1)
-    return jnp.minimum(lo // tk, key_blocks - 1), hi // tk
+def chunk_walk(
+    q_at0, i, tq: int, tk: int, window, key_blocks: int, minimum=min,
+    maximum=max,
+):
+    """``(first, clear_lo, clear_hi, end)``: the blocks of ``tk`` keys that
+    query block ``i`` of a chunk whose first row sits at ``q_at0`` walks.
+    ``first .. end - 1`` are the blocks that hold a key any of its rows
+    sees, inside the chain: from its first row's window start (block 0
+    without a window) to its last row's own key.  ``clear_lo .. clear_hi -
+    1`` of them lie wholly inside what EVERY row sees - up to the first
+    row's own key and, under a window, from the last row's window start -
+    and take no mask; the others, below and above, are crossed by an edge.
+    ``first <= clear_lo <= clear_hi <= end``.
 
-
-def chunk_tiles(rows: int, keys: int, window) -> tuple[int, int, int]:
-    """``(query rows, keys, steps over keys)`` of the chunk kernel's grid: a
-    full layer walks every block of the chain (those past a query block's
-    last key cost a step and no copy), a window layer the few blocks a
-    query block's rows can see."""
-    tq = math.gcd(rows, CHUNK_QUERY_ROWS)
+    One definition for both sides: the host counts with it on integers
+    (`chunk_walk_blocks`), the kernel derives its loops' bounds from it on
+    traced scalars (``minimum`` / ``maximum`` are then `jnp`'s)."""
+    top = q_at0 + i * tq                            # the first row's own key
+    bottom = top + tq - 1                           # the last row's
+    end = minimum(bottom // tk + 1, key_blocks)
+    clear_hi = minimum((top + 1) // tk, end)
     if window is None:
-        tk = math.gcd(keys, CHUNK_KEYS)
-        return tq, tk, keys // tk
-    tk = math.gcd(keys, CHUNK_QUERY_ROWS)
-    return tq, tk, min(-(-(tq + window - 1) // tk) + 1, keys // tk)
+        return 0, 0, clear_hi, end
+    first = minimum(maximum(top - window + 1, 0) // tk, end)
+    reach = maximum(bottom - window + 1, 0)         # the last row's window start
+    clear_lo = minimum(maximum((reach + tk - 1) // tk, first), end)
+    return first, clear_lo, maximum(clear_hi, clear_lo), end
+
+
+def chunk_tiles(rows: int, keys: int, window) -> tuple[int, int]:
+    """``(query rows, keys)`` of a block of the chunk kernel: a window layer
+    takes blocks of keys as small as its blocks of queries, so that a walk
+    reads little outside the window."""
+    tq = math.gcd(rows, CHUNK_QUERY_ROWS)
+    return tq, math.gcd(keys, CHUNK_KEYS if window is None else CHUNK_QUERY_ROWS)
+
+
+def chunk_held_blocks(key_blocks: int, block_bytes: int) -> int:
+    """How many of a chain's ``key_blocks`` blocks a grid step of the chunk
+    kernel holds in VMEM (a block of one K/V head's keys and values takes
+    ``block_bytes`` there): all of them where they fit `CHUNK_HELD_BYTES`,
+    else the most that do and divide the chain."""
+    fit = min(max(CHUNK_HELD_BYTES // block_bytes, 1), key_blocks)
+    return max(n for n in range(1, fit + 1) if key_blocks % n == 0)
+
+
+def chunk_walk_blocks(start: int, rows: int, keys: int, window) -> tuple[int, int]:
+    """``(visited, masked)``: the key blocks the kernel's walks fold for a
+    chunk of ``rows`` query rows (the bucket: rows of padding walk like any
+    other) from position ``start`` over a chain of ``keys``, and those of
+    them folded under a mask.  A K/V head's count: every head walks alike."""
+    tq, tk = chunk_tiles(rows, keys, window)
+    visited = masked = 0
+    for i in range(rows // tq):
+        first, clear_lo, clear_hi, end = chunk_walk(start, i, tq, tk, window, keys // tk)
+        visited += end - first
+        masked += end - first - (clear_hi - clear_lo)
+    return visited, masked
 
 
 @functools.partial(jax.jit, static_argnames=("window", "name", "interpret"))
@@ -447,44 +530,59 @@ def _chunk_impl(q, k, v, q_at0, sink, *, window, name, interpret):
     rows, heads, d_key = q.shape
     keys, kv_heads, d_value = v.shape
     group = heads // kv_heads
-    tq, tk, steps = chunk_tiles(rows, keys, window)
+    tq, tk = chunk_tiles(rows, keys, window)
     key_blocks = keys // tk
+    # In VMEM a key and a value take whole lane tiles.
+    held_lanes = sum(-(-d // 128) * 128 for d in (d_key, d_value))
+    held = chunk_held_blocks(key_blocks, tk * held_lanes * k.dtype.itemsize)
     # (kv, group, rows, d) and (kv, keys, d): a K/V head's own rows together.
     qg = jnp.transpose(q.reshape(rows, kv_heads, group, d_key), (1, 2, 0, 3))
     kg, vg = jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1)
-    sink_rows = jnp.repeat(
-        sink_logits(sink, heads).reshape(kv_heads, group), tq, axis=1
-    )[..., None]                                     # (kv, group * tq, 1)
+    # A row's running maximum and denominator fill a lane tile (or what of
+    # one divides a block of keys and a value), and so does its sink.
+    state_lanes = math.gcd(tk, d_value, 128)
+    sink_rows = jnp.broadcast_to(
+        jnp.repeat(
+            sink_logits(sink, heads).reshape(kv_heads, group), tq, axis=1
+        )[..., None],
+        (kv_heads, group * tq, state_lanes),
+    )
 
-    def key_block(g, i, j, at):
-        first, last = _block_range(at[0], i, tq, tk, window, key_blocks)
-        return g, jnp.minimum(first + j, last), 0
+    def held_part(g, i, part, at):
+        """The part of the chain a step holds: its own, or the nearest one
+        its walk reaches (no new copy for a part it does not)."""
+        first, _, _, end = chunk_walk(
+            at[0], i, tq, tk, window, key_blocks, jnp.minimum, jnp.maximum
+        )
+        return g, jnp.clip(part, first // held, (end - 1) // held), 0
 
     kernel = functools.partial(
         _chunk_kernel, scale=d_key**-0.5, window=window, tq=tq, tk=tk,
-        key_blocks=key_blocks,
+        key_blocks=key_blocks, held_blocks=held,
+        guard=window is not None and sink is None,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(kv_heads, rows // tq, steps),
+            grid=(kv_heads, rows // tq, key_blocks // held),
             in_specs=[
                 pl.BlockSpec(
-                    (1, group, tq, d_key), lambda g, i, j, at: (g, 0, i, 0)
+                    (1, group, tq, d_key), lambda g, i, part, at: (g, 0, i, 0)
                 ),
                 pl.BlockSpec(
-                    (1, group * tq, 1), lambda g, i, j, at: (g, 0, 0)
+                    (1, group * tq, state_lanes),
+                    lambda g, i, part, at: (g, 0, 0),
                 ),
-                pl.BlockSpec((1, tk, d_key), key_block),
-                pl.BlockSpec((1, tk, d_value), key_block),
+                pl.BlockSpec((1, held * tk, d_key), held_part),
+                pl.BlockSpec((1, held * tk, d_value), held_part),
             ],
             out_specs=pl.BlockSpec(
-                (1, group, tq, d_value), lambda g, i, j, at: (g, 0, i, 0)
+                (1, group, tq, d_value), lambda g, i, part, at: (g, 0, i, 0)
             ),
             scratch_shapes=[
-                pltpu.VMEM((group * tq, 128), jnp.float32),   # running max
-                pltpu.VMEM((group * tq, 128), jnp.float32),   # denominator
+                pltpu.VMEM((group * tq, state_lanes), jnp.float32),  # running max
+                pltpu.VMEM((group * tq, state_lanes), jnp.float32),  # denominator
                 pltpu.VMEM((group * tq, d_value), jnp.float32),
             ],
         ),
@@ -524,7 +622,7 @@ def sink_chunk_path(rows: int, keys: int, window) -> str:
     """``"sink_chunk"`` on the TPU where the kernel's tiles are whole (query
     rows a multiple of 16, keys of 128), else ``"xla"`` (materialized
     scores; always on the CPU)."""
-    tq, tk, _ = chunk_tiles(rows, keys, window)
+    tq, tk = chunk_tiles(rows, keys, window)
     if jax.default_backend() == "tpu" and tq % 16 == 0 and tk % 128 == 0:
         return "sink_chunk"
     return "xla"

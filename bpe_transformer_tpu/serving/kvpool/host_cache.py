@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from bpe_transformer_tpu.kernels.pallas import mla_attention
+from bpe_transformer_tpu.kernels.pallas import mla_attention, sink_attention
 from bpe_transformer_tpu.models import mla
 from bpe_transformer_tpu.models.decode import (
     DenseRows, EvaRows, GroupedPages, GroupedRows, LatentRows, RecurrentRows,
@@ -347,10 +347,16 @@ class HostGroupedRows(HostGroupedPages):
     attn_full_kv_positions = attn_window_kv_positions = 0
     chunk_attn_full_kv_positions = chunk_attn_window_kv_positions = 0
     chunk_attn_full_pairs = chunk_attn_window_pairs = 0
+    #: The blocks of keys the chunk kernel's walks fold in the full layers
+    #: (a K/V head's, every query block of the launch's bucket, x the full
+    #: layers), and those of them folded under a mask - an edge crosses them
+    #: (`sink_attention.chunk_walk`, the arithmetic the kernel walks by).
+    chunk_attn_full_key_blocks = chunk_attn_full_masked_blocks = 0
     counters = HostDenseRows.counters + (
         "attn_full_kv_positions", "attn_window_kv_positions",
         "chunk_attn_full_kv_positions", "chunk_attn_window_kv_positions",
         "chunk_attn_full_pairs", "chunk_attn_window_pairs",
+        "chunk_attn_full_key_blocks", "chunk_attn_full_masked_blocks",
     )
     no_tick = {"attn_full_kv_positions": 0, "attn_window_kv_positions": 0}
 
@@ -400,6 +406,12 @@ class HostGroupedRows(HostGroupedPages):
         self.chunk_attn_window_pairs += window_pairs
         self.chunk_attn_full_kv_positions += full_keys
         self.chunk_attn_window_kv_positions += window_keys
+        visited, masked = sink_attention.chunk_walk_blocks(
+            start, bucket, self.blocks_per_slot * self.block_size, None
+        )
+        full_layers = self.attn_sublayers - self._window_layers
+        self.chunk_attn_full_key_blocks += full_layers * visited
+        self.chunk_attn_full_masked_blocks += full_layers * masked
 
     def before_tick(self, live, positions, active):
         window = self.config.sliding_window

@@ -229,7 +229,10 @@ RECORD_SCHEMAS: dict[str, set[str]] = {
     # / ``chunk_attn_window_kv_positions`` (the chunks': back to a chunk's
     # first row's window start in a window layer) and
     # ``chunk_attn_full_pairs`` / ``chunk_attn_window_pairs`` (the chunks'
-    # visible (query, key) pairs) - there ``attn_pairs`` and
+    # visible (query, key) pairs), ``chunk_attn_full_key_blocks`` /
+    # ``chunk_attn_full_masked_blocks`` (the blocks of keys the chunk
+    # kernel's walks fold in the full layers, and those of them under a
+    # mask: `sink_attention.chunk_walk`) - there ``attn_pairs`` and
     # ``attn_kv_positions`` hold both groups', ticks and chunks, and
     # ``kv_window_blocks_recycled`` the window group's blocks given back
     # behind the launch that read them; and

@@ -1330,19 +1330,24 @@ def test_sink_paged_attention(one_chip, group):
     assert f"sink_paged_attention_{group}" in text
 
 
-@pytest.mark.parametrize("bucket", [512, 2048])
+@pytest.mark.parametrize("bucket", [512, 1024, 2048])
 @pytest.mark.parametrize("group", ["full", "window"])
 def test_sink_chunk_attention(one_chip, group, bucket):
     """The chunk's kernel at the cell's widths and buckets: a full layer's
-    gathered chain of 32,768 keys in blocks of 512, a window layer's of 2,176
-    in blocks of 128, of which a block of 128 queries walks three."""
+    gathered chain of 32,768 keys held in VMEM a K/V head (128 blocks of 256:
+    25.2 MB with the key's 192 lanes in 256), a window layer's of 2,176 in 17
+    blocks of 128; the walk over them is inside the kernel, so the grid is
+    (K/V heads, blocks of 128 query rows, 1)."""
     from bpe_transformer_tpu.kernels.pallas.sink_attention import (
+        chunk_held_blocks,
         chunk_tiles,
         sink_chunk_attention,
     )
 
     kv_heads, keys, window = (4, 32768, None) if group == "full" else (8, 2176, 128)
-    assert chunk_tiles(bucket, keys, window)[2] == (64 if group == "full" else 3)
+    tq, tk = chunk_tiles(bucket, keys, window)
+    assert (tq, tk) == ((128, 256) if group == "full" else (128, 128))
+    assert chunk_held_blocks(keys // tk, tk * (256 + 128) * 2) == keys // tk
     shapes = [
         ((bucket, 64, 192), BF16), ((keys, kv_heads, 192), BF16),
         ((keys, kv_heads, 128), BF16), ((), I32),

@@ -473,11 +473,17 @@ def test_no_program_compiles_after_the_warm_up():
     assert eng.compiled_programs() == warm
 
 
-def test_attention_counters_count_by_group():
+def test_attention_counters_count_by_group(monkeypatch):
+    # Blocks of 2 query rows by 8 keys: a prompt's chunks of 4 start inside
+    # a block of keys as well as on its edge.
+    monkeypatch.setattr(sink_attention, "CHUNK_QUERY_ROWS", 2)
+    monkeypatch.setattr(sink_attention, "CHUNK_KEYS", 8)
     c = reference_cfg()  # two full layers, five window layers
     eng = small_engine(c)
     cache = eng.cache
     slot = eng.begin(np.arange(1, 20), max_new_tokens=4, temperature=0.0)
+    keys = cache.blocks_per_slot * cache.block_size
+    assert sink_attention.chunk_tiles(4, keys, None) == (2, 8)
 
     def brute(start, end):
         full_pairs = sum(q + 1 for q in range(start, end))
@@ -485,13 +491,29 @@ def test_attention_counters_count_by_group():
         return (2 * full_pairs, 5 * window_pairs, 2 * end,
                 5 * (end - max(start - WINDOW + 1, 0)))
 
+    def brute_walk(start, bucket):
+        """The full layers' walks, pair by pair: every query block of the
+        launch's bucket (rows of padding too) over the blocks of keys that
+        hold a pair one of its rows sees, and those of them that also hold
+        a pair it does not."""
+        visited = masked = 0
+        for first_row in range(start, start + bucket, 2):
+            for block in range(0, keys, 8):
+                seen = [k <= q for q in (first_row, first_row + 1)
+                        for k in range(block, block + 8)]
+                visited += any(seen)
+                masked += any(seen) and not all(seen)
+        return 2 * visited, 2 * masked
+
     names = ("chunk_attn_full_pairs", "chunk_attn_window_pairs",
              "chunk_attn_full_kv_positions", "chunk_attn_window_kv_positions")
+    walks = ("chunk_attn_full_key_blocks", "chunk_attn_full_masked_blocks")
     for start, length in [(0, 4), (4, 4), (8, 4), (12, 3)]:
-        before = [getattr(cache, n) for n in names]
+        before = [getattr(cache, n) for n in names + walks]
         cache.before_chunk(slot, start, length, 4)
-        got = tuple(getattr(cache, n) - b for n, b in zip(names, before))
-        assert got == brute(start, start + length)
+        got = tuple(getattr(cache, n) - b for n, b in zip(names + walks, before))
+        assert got == brute(start, start + length) + brute_walk(start, 4)
+    assert 0 < cache.chunk_attn_full_masked_blocks < cache.chunk_attn_full_key_blocks
     positions = np.array([14, 0, 0], np.int32)
     active = np.array([True, False, False])
     seen, counts = cache.before_tick(np.array([slot]), positions, active)
@@ -500,7 +522,7 @@ def test_attention_counters_count_by_group():
         getattr(cache, n) for n in names[:2]
     ) + sum(getattr(cache, n) for n in names[2:])
     gauges = cache.gauges()
-    assert all(gauges[n] == getattr(cache, n) for n in names)
+    assert all(gauges[n] == getattr(cache, n) for n in names + walks)
 
 
 def test_the_window_group_is_no_reservation():
@@ -633,21 +655,45 @@ def test_paged_kernel_matches_the_stand_in(kv_heads, sunk):
     assert float(jnp.max(jnp.abs(got[0]))) == 0.0  # the idle slot: zeros
 
 
-@pytest.mark.parametrize("window", [None, 5, 20], ids=["full", "window5", "window20"])
+#: rows, the chunk's positions, bytes of a head's keys and values a step holds.
+CHUNK_GEOMETRIES = {
+    # Four query blocks of 8: at the chain's start (no clear block before the
+    # first), off the key blocks' grid (40) and off the query blocks' (43),
+    # and ending in the chain's last block.
+    "chunk32": (32, (0, 40, 43, 64), None),
+    # A bucket of fewer rows than a query block takes.
+    "bucket4": (4, (0, 6, 92), None),
+    # A chain too long to hold: the grid's last axis moves over three parts.
+    "parts": (32, (0, 43, 64), 2 * 16 * 256 * 4),
+}
+
+
+@pytest.mark.parametrize("geometry", CHUNK_GEOMETRIES)
+@pytest.mark.parametrize(
+    "window", [None, 3, 5, 20, 40],
+    ids=["full", "window3", "window5", "window20", "window40"],
+)
 @pytest.mark.parametrize("sunk", [False, True], ids=["nosink", "sink"])
-def test_chunk_kernel_matches_the_stand_in(monkeypatch, window, sunk):
-    """Tiles of 8 rows by 16 keys: a chunk at the chain's start, mid-chain
-    and at its end, blocks on the diagonal and wholly visible ones."""
+def test_chunk_kernel_matches_the_stand_in(monkeypatch, window, sunk, geometry):
+    """Blocks of 8 rows by 16 keys (8 under a window): blocks on the
+    diagonal, blocks a window's lower edge crosses, wholly visible ones
+    between them (a window of 20 or 40 keys has some, one narrower than a
+    query block none) and blocks no row sees."""
+    rows, starts, held_bytes = CHUNK_GEOMETRIES[geometry]
     monkeypatch.setattr(sink_attention, "CHUNK_QUERY_ROWS", 8)
     monkeypatch.setattr(sink_attention, "CHUNK_KEYS", 16)
+    if held_bytes is not None:
+        monkeypatch.setattr(sink_attention, "CHUNK_HELD_BYTES", held_bytes)
+        tk = sink_attention.chunk_tiles(rows, 96, window)[1]
+        assert sink_attention.chunk_held_blocks(96 // tk, tk * 256 * 4) == 32 // tk
     sink_attention._chunk_impl.clear_cache()
     rng = np.random.default_rng(1)
-    rows, keys, heads, kv, dk, dv = 32, 96, 8, 2, 24, 16
+    keys, heads, kv, dk, dv = 96, 8, 2, 24, 16
     q = jnp.asarray(rng.normal(size=(rows, heads, dk)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(keys, kv, dk)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(keys, kv, dv)), jnp.float32)
     sink = jnp.asarray(rng.normal(size=(heads,)), jnp.float32) if sunk else None
-    for at0 in (0, 40, 64):
+    for at0 in starts:
         got = sink_attention.sink_chunk_attention(
             q, k, v, jnp.int32(at0), sink, window=window, interpret=True
         )
@@ -659,15 +705,72 @@ def test_chunk_kernel_matches_the_stand_in(monkeypatch, window, sunk):
     sink_attention._chunk_impl.clear_cache()
 
 
+@pytest.mark.parametrize("tq,tk", [(4, 8), (8, 4), (4, 4)])
+@pytest.mark.parametrize("window", [None, 3, 8, 20])
+def test_chunk_walk_is_the_blocks_a_query_block_sees(window, tq, tk):
+    """`chunk_walk` against the pairs themselves, for every position of a
+    chunk of three query blocks over a chain of six blocks of keys (the last
+    positions run past the chain's end, as rows of padding may): the walk is
+    exactly the blocks that hold a visible pair, the clear blocks exactly
+    those that hold no other, and the kernel's arithmetic - the same function on traced
+    scalars - gives the host's four integers."""
+    key_blocks, blocks = 6, 3
+    starts = np.arange(key_blocks * tk, dtype=np.int32)
+    traced = jax.jit(jax.vmap(jax.vmap(
+        lambda at, i: jnp.stack(sink_attention.chunk_walk(
+            at, i, tq, tk, window, key_blocks, jnp.minimum, jnp.maximum
+        )),
+        in_axes=(None, 0),
+    ), in_axes=(0, None)))(starts, jnp.arange(blocks, dtype=jnp.int32))
+    for at0 in starts:
+        for i in range(blocks):
+            first, clear_lo, clear_hi, end = walk = sink_attention.chunk_walk(
+                int(at0), i, tq, tk, window, key_blocks
+            )
+            assert all(isinstance(n, int) for n in walk)
+            assert list(traced[at0, i]) == list(walk)
+            assert 0 <= first <= clear_lo <= clear_hi <= end <= key_blocks
+            rows = range(at0 + i * tq, at0 + (i + 1) * tq)
+            for block in range(key_blocks):
+                seen = [
+                    k <= r and (window is None or r - k < window)
+                    for r in rows for k in range(block * tk, (block + 1) * tk)
+                ]
+                assert (first <= block < end) == any(seen)
+                assert (clear_lo <= block < clear_hi) == all(seen)
+
+
 def test_chunk_tiles_walk_what_a_window_can_see():
-    # The cell's shapes: a full layer walks every block of its chain's
-    # 32,768 keys, a window layer three blocks of 128 whatever the chain.
-    assert sink_attention.chunk_tiles(2048, 32768, None) == (128, 512, 64)
-    assert sink_attention.chunk_tiles(2048, 2176, 128) == (128, 128, 3)
-    assert sink_attention.chunk_tiles(512, 2176, 128) == (128, 128, 3)
+    # The cell's shapes.  A full layer: blocks of 128 query rows (x 16 query
+    # heads a K/V head) by 256 keys, a head's whole chain of 32,768 keys held
+    # in VMEM (128 blocks of 256 x (256 + 128) lanes x 2 B), so the grid is (4
+    # K/V heads, 16 query blocks, 1) and the walk is the kernel's own.  At
+    # position 12,288 the first query block folds 48 clear blocks and one on
+    # the diagonal, the last 55 and one; nothing of the chain's other 72.
+    tiles, walk = sink_attention.chunk_tiles, sink_attention.chunk_walk
+    assert tiles(2048, 32768, None) == (128, 256)
+    assert tiles(512, 32768, None) == (128, 256)
+    assert sink_attention.chunk_held_blocks(128, 256 * 384 * 2) == 128
+    assert walk(12288, 0, 128, 256, None, 128) == (0, 0, 48, 49)
+    assert walk(12288, 15, 128, 256, None, 128) == (0, 0, 55, 56)
+    assert walk(0, 0, 128, 256, None, 128) == (0, 0, 0, 1)
+    assert sink_attention.chunk_walk_blocks(12288, 2048, 32768, None) == (840, 16)
+    # A chain eight times as long is held an eighth at a time.
+    assert sink_attention.chunk_held_blocks(1024, 256 * 384 * 2) == 128
+    # A window layer: blocks of 128 keys, a chain of window + chunk whatever
+    # the context; a query block walks the two blocks of 128 its window of
+    # 128 touches (three off the blocks' grid), each under a mask.
+    assert tiles(2048, 2176, 128) == (128, 128)
+    assert tiles(512, 2176, 128) == (128, 128)
+    assert sink_attention.chunk_held_blocks(17, 128 * 384 * 2) == 17
+    assert walk(128, 0, 128, 128, 128, 17) == (0, 1, 1, 2)
+    assert walk(128, 15, 128, 128, 128, 17) == (15, 16, 16, 17)
+    assert walk(130, 3, 128, 128, 128, 17) == (3, 5, 5, 6)
+    assert sink_attention.chunk_walk_blocks(128, 2048, 2176, 128) == (32, 32)
     assert sink_attention.paged_group_blocks(16, 2048) == 16
     assert sink_attention.paged_group_blocks(16, 136) == 16
     assert sink_attention.sink_paged_path(16, 1280, 768) == "xla"  # the CPU
+    assert sink_attention.sink_chunk_path(2048, 32768, None) == "xla"
 
 
 # ---------------------------------------------------------------- refusals

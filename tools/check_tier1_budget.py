@@ -159,7 +159,15 @@ DEFAULT_BUDGET_S = 800.0
 #: (tests/test_mimov2.py, 61 cases in about 75 s with six workers)
 #: and the two kernels and the cell's tick and chunk programs compiled for
 #: the described v5e (tests/test_chip_compile.py, 8 cases, 1-20 s each).
-DEFAULT_MAX_TESTS = 1400
+#: Raised 1400 -> 1450 in PR 47 (1,407 collected, 38 added): the chunk
+#: kernel's walk inside the step - its interpreted cases 6 -> 30 (five
+#: windows by sink by three geometries: a chunk of four query blocks, a
+#: bucket under one block, a chain held in parts; a second each), the walk's
+#: four integers against the pairs themselves and against their traced twin
+#: at twelve geometries (tests/test_mimov2.py) and the 1,024 bucket compiled
+#: for the described v5e (tests/test_chip_compile.py, 2 cases); the whole
+#: run 607 s with six workers.
+DEFAULT_MAX_TESTS = 1450
 
 #: Pytest summary trailer: "== 398 passed, 27 deselected in 612.34s =="
 #: (also plain "in 612.34s (0:10:12)" forms).
