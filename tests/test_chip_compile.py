@@ -11,10 +11,11 @@ never a timing or a result.
 
 The topology is described inside a module-scoped fixture (only the xdist
 worker that is handed this file loads libtpu) and the compile happens in
-the test's own process.  Whole train steps and serving ticks at full depth
-(35-45 s each) are NOT here — they live in the builder's scratch script;
-the paged engine's pool programs are, cut to two layers (ISSUE 30: the
-compiled text is what says that no program copies the pool).
+the test's own process.  Serving ticks at full depth (35-45 s each) are
+NOT here — they live in the builder's scratch script; the paged engine's
+pool programs are, cut to two layers (ISSUE 30: the compiled text is what
+says that no program copies the pool), and so is ONE whole train step, the
+train cell's (ISSUE 48: its memory and its `loss` scope are the step's).
 """
 
 import os
@@ -196,6 +197,49 @@ def test_auto_attention_lowers_under_every_partitioning(
         )
     text = step.lower(params, opt_state, ids, ids).as_text()
     assert ("tpu_custom_call" in text) == (path == "flash")
+
+
+def _loss_scope(text, opcode):
+    """Names' scopes of the compiled text's ``opcode`` instructions that lie
+    in the `loss` scope."""
+    import re
+
+    return re.findall(
+        rf" {opcode}\([^\n]*op_name=\"([^\"]*\(loss\)[^\"]*)\"", text
+    )
+
+
+def test_train_step_at_the_train_cells_shape(one_chip, on_tpu):
+    """``small.train``'s step as the cell compiles it (gpt2-small-32k whole,
+    B=32 x S=1,024, flash attention, the layers as calls; ~35 s): the `loss`
+    scope is ONE loop - the forward's, which makes the gradients too - of
+    three products a chunk, nothing of it is left in the backward, and the
+    step's temporaries stay within 0.3 GB of the 11.54 GB they were when a
+    second loop rematerialised each chunk's logits (ISSUE 48)."""
+    from bpe_transformer_tpu.models import init_params
+    from bpe_transformer_tpu.optim.adamw import adamw_init
+    from bpe_transformer_tpu.training.train_step import (
+        TrainHParams,
+        make_train_step,
+    )
+
+    config = GPT2_SMALL_32K
+    params = _described(
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), config)), one_chip
+    )
+    opt_state = _described(jax.eval_shape(adamw_init, params), one_chip)
+    ids = jax.ShapeDtypeStruct((32, config.context_length), I32, sharding=one_chip)
+    compiled = (
+        make_train_step(config, TrainHParams())
+        .lower(params, opt_state, ids, ids)
+        .compile()
+    )
+    text = compiled.as_text()
+    assert "flash_attention_bwd" in text
+    assert _loss_scope(text, "while") == ["jit(step)/jvp(loss)/while"]
+    products = _loss_scope(text, "convolution")
+    assert len(products) == 3 and not any("transpose(" in p for p in products)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 11.54e9 + 0.3e9
 
 
 @pytest.mark.parametrize(

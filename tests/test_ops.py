@@ -222,20 +222,67 @@ def test_sdpa_fully_masked_rows_are_finite():
     assert np.isfinite(np.asarray(out)).all()
 
 
-def test_chunked_lm_cross_entropy_matches_full():
+def _loss_case(hidden_dtype=np.float32, head_dtype=np.float32):
+    """``(hidden, head, targets)`` of the chunked-loss tests: 2 x 16 tokens,
+    d 8, vocab 50."""
+    rng = np.random.default_rng(0)
+    b, s, d, v = 2, 16, 8, 50
+    hidden = jnp.asarray(rng.normal(size=(b, s, d)), hidden_dtype)
+    head = jnp.asarray(rng.normal(size=(v, d)), head_dtype)
+    targets = jnp.asarray(rng.integers(0, v, size=(b, s)))
+    return hidden, head, targets
+
+
+def _checkpointed_chunk_loss(hidden, lm_head_w, targets, chunk_size):
+    """The chunked loss as it was until ISSUE 48, kept as the plain
+    reference: a chunk's logits under ``jax.checkpoint`` inside a
+    ``lax.map``, its gradients XLA's own transposes."""
+    import jax
+    from jax import lax
+    from jax.scipy.special import logsumexp
+
+    from bpe_transformer_tpu.ops.core import head_logits
+
+    batch, seq, d = hidden.shape
+    n_chunks = seq // chunk_size
+    h = hidden.reshape(batch, n_chunks, chunk_size, d).swapaxes(0, 1)
+    t = targets.reshape(batch, n_chunks, chunk_size).swapaxes(0, 1)
+
+    @jax.checkpoint
+    def chunk_nll(args):
+        hc, tc = args
+        logits = head_logits(hc, lm_head_w)
+        target_logit = jnp.take_along_axis(
+            logits, tc[..., None].astype(jnp.int32), axis=-1
+        )[..., 0]
+        return (logsumexp(logits, axis=-1) - target_logit).sum()
+
+    return lax.map(chunk_nll, (h, t)).sum() / (batch * seq)
+
+
+def _equations(jaxpr, *primitives):
+    """Every equation of a jaxpr and of the jaxprs its equations hold, or
+    those of the named primitives alone."""
+    for eqn in jaxpr.eqns:
+        if not primitives or eqn.primitive.name in primitives:
+            yield eqn
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner, *primitives)
+
+
+@pytest.mark.parametrize("chunk", [4, 16], ids=["chunk4", "chunk_is_seq"])
+def test_chunked_lm_cross_entropy_matches_full(chunk):
     """Chunked loss == full-logits loss, in value AND gradients."""
     import jax
 
     from bpe_transformer_tpu.ops.losses import chunked_lm_cross_entropy, cross_entropy
 
-    rng = np.random.default_rng(0)
-    b, s, d, v = 2, 16, 8, 50
-    hidden = jnp.asarray(rng.normal(size=(b, s, d)).astype(np.float32))
-    head = jnp.asarray(rng.normal(size=(v, d)).astype(np.float32))
-    targets = jnp.asarray(rng.integers(0, v, size=(b, s)))
-
+    hidden, head, targets = _loss_case()
     full = lambda h, w: cross_entropy(h @ w.T, targets)
-    chunked = lambda h, w: chunked_lm_cross_entropy(h, w, targets, chunk_size=4)
+    chunked = lambda h, w: chunked_lm_cross_entropy(h, w, targets, chunk_size=chunk)
 
     np.testing.assert_allclose(
         float(chunked(hidden, head)), float(full(hidden, head)), rtol=1e-6
@@ -245,8 +292,98 @@ def test_chunked_lm_cross_entropy_matches_full():
     for a, c in zip(g_full, g_chunk):
         np.testing.assert_allclose(np.asarray(c), np.asarray(a), atol=1e-5)
 
+
+def test_chunked_lm_cross_entropy_refuses_a_chunk_that_does_not_divide():
+    from bpe_transformer_tpu.ops.losses import chunked_lm_cross_entropy
+
+    hidden, head, targets = _loss_case()
     with pytest.raises(ValueError, match="divisible"):
         chunked_lm_cross_entropy(hidden, head, targets, chunk_size=5)
+
+
+def test_chunked_lm_cross_entropy_gradients_are_the_checkpointed_loops():
+    """On the training path's dtypes (bfloat16 hidden states, a float32
+    head) the gradients made in the forward loop are, bit for bit on the
+    CPU, those XLA transposed out of the checkpointed loop: same operand
+    dtypes, same accumulation, same casts."""
+    import jax
+
+    from bpe_transformer_tpu.ops.losses import chunked_lm_cross_entropy
+
+    hidden, head, targets = _loss_case(hidden_dtype=jnp.bfloat16)
+    grads = lambda loss: jax.jit(
+        jax.value_and_grad(lambda h, w: loss(h, w, targets, 4), argnums=(0, 1))
+    )(hidden, head)
+    value, (dh, dw) = grads(chunked_lm_cross_entropy)
+    want, (want_dh, want_dw) = grads(_checkpointed_chunk_loss)
+    np.testing.assert_allclose(float(value), float(want), rtol=1e-6)
+    assert (dh.dtype, dw.dtype) == (jnp.bfloat16, jnp.float32)
+    np.testing.assert_array_equal(
+        np.asarray(dh, np.float32), np.asarray(want_dh, np.float32)
+    )
+    np.testing.assert_array_equal(np.asarray(dw), np.asarray(want_dw))
+
+
+def test_chunked_lm_cross_entropy_scales_with_its_cotangent():
+    """The backward rule is a scaling: under ``3 * loss + aux`` the loss's
+    share of both gradients is three times the gradient of the loss."""
+    import jax
+
+    from bpe_transformer_tpu.ops.losses import chunked_lm_cross_entropy
+
+    hidden, head, targets = _loss_case()
+    loss = lambda h, w: chunked_lm_cross_entropy(h, w, targets, 4)
+    aux = lambda h, w: jnp.sum(h * h) + jnp.sum(w)
+    both = lambda h, w: 3.0 * loss(h, w) + aux(h, w)
+    g_loss, g_aux, g_both = (
+        jax.grad(f, argnums=(0, 1))(hidden, head) for f in (loss, aux, both)
+    )
+    for plain, other, got in zip(g_loss, g_aux, g_both):
+        np.testing.assert_allclose(
+            np.asarray(got), 3.0 * np.asarray(plain) + np.asarray(other),
+            rtol=1e-5, atol=1e-6,
+        )
+
+
+def test_chunked_lm_cross_entropy_outside_a_grad_makes_no_gradient():
+    """The eval path: one logits-shaped product a chunk, and no equation
+    whose result has the hidden states' or the head's shape."""
+    import jax
+
+    from bpe_transformer_tpu.ops.losses import chunked_lm_cross_entropy
+
+    hidden, head, targets = _loss_case()
+    jaxpr = jax.make_jaxpr(
+        lambda h, w: chunked_lm_cross_entropy(h, w, targets, 4)
+    )(hidden, head).jaxpr
+    (_loop,) = _equations(jaxpr, "scan", "while")
+    (dot,) = _equations(jaxpr, "dot_general")
+    assert dot.outvars[0].aval.shape == (2, 4, 50)
+    made = {v.aval.shape for e in _equations(jaxpr) for v in e.outvars}
+    assert head.shape not in made and hidden.shape not in made
+
+
+def test_chunked_lm_cross_entropy_under_a_grad_is_one_loop_of_three_products():
+    """Loss and gradients come out of ONE loop whose body holds exactly
+    three products - the logits, ``dlogits @ W`` and ``dlogits^T @ h`` -
+    so no chunk's logits are computed twice."""
+    import jax
+
+    from bpe_transformer_tpu.ops.losses import chunked_lm_cross_entropy
+
+    hidden, head, targets = _loss_case(hidden_dtype=jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(
+        jax.value_and_grad(
+            lambda h, w: chunked_lm_cross_entropy(h, w, targets, 4), argnums=(0, 1)
+        )
+    )(hidden, head).jaxpr
+    (loop,) = _equations(jaxpr, "scan", "while")
+    assert loop.params["length"] == 4
+    dots = list(_equations(jaxpr, "dot_general"))
+    assert sorted(e.outvars[0].aval.shape for e in dots) == [
+        (2, 4, 8), (2, 4, 50), (50, 8),
+    ]
+    assert dots == list(_equations(loop.params["jaxpr"].jaxpr, "dot_general"))
 
 
 def test_head_logits_dtype_rule():

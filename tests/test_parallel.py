@@ -343,6 +343,54 @@ def test_pp_step_matches_single_device():
     )
 
 
+def _sp_first_step(config, x, y):
+    from bpe_transformer_tpu.parallel import make_sp_train_step, shard_sp_batch
+
+    mesh = make_mesh({"data": 2, "seq": 4})
+    params = init_params(jax.random.PRNGKey(0), config)
+    step = make_sp_train_step(config, HP, mesh)
+    return step(params, adamw_init(params), *shard_sp_batch((x, y), mesh))
+
+
+def _pp_first_step(config, x, y):
+    from bpe_transformer_tpu.parallel.pp import (
+        init_pp_opt_state,
+        make_pp_train_step,
+        shard_pp_params,
+        stack_pipeline_params,
+    )
+
+    config = dataclasses.replace(config, num_layers=4)
+    mesh = make_mesh({"data": 2, "pp": 4})
+    params = shard_pp_params(
+        stack_pipeline_params(init_params(jax.random.PRNGKey(0), config), 4), mesh
+    )
+    step = make_pp_train_step(config, HP, mesh, num_microbatches=4)
+    return step(params, init_pp_opt_state(params, mesh), *shard_batch((x, y), mesh))
+
+
+@pytest.mark.parametrize(
+    "first_step,chunk", [(_sp_first_step, 2), (_pp_first_step, 4)], ids=["sp", "pp"]
+)
+def test_chunked_loss_under_sp_and_pp(first_step, chunk):
+    """The chunked loss - a ``custom_vjp`` whose forward rule makes the
+    gradients - inside the sequence-parallel ``shard_map`` (a shard's 4
+    positions in chunks of 2) and inside the pipeline's head stage (a
+    ``cond`` in the ticks' ``scan``): loss and gradient (AdamW's first
+    moment after one step) are the full-logits step's."""
+    _, _, x, y = _setup()
+    _, full_state, full = first_step(dataclasses.replace(CFG, loss_chunk_size=0), x, y)
+    _, state, got = first_step(dataclasses.replace(CFG, loss_chunk_size=chunk), x, y)
+    np.testing.assert_allclose(float(got["loss"]), float(full["loss"]), rtol=1e-6)
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-8
+        ),
+        state.m,
+        full_state.m,
+    )
+
+
 @pytest.mark.slow
 def test_pp_grad_accum_matches_full_batch_step():
     """Gradient accumulation AROUND the pipeline: each accumulation slice
