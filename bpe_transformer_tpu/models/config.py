@@ -171,8 +171,12 @@ class ModelConfig:
     #: Whether full-attention layers rotate q and k.  False: no positional
     #: transform at all on them (window layers keep RoPE).
     rope_on_full_layers: bool = True
-    #: "rmsnorm" | "layernorm" (mean-subtracting, no bias, eps 1e-5).
+    #: "rmsnorm" | "layernorm" (mean-subtracting, no bias).
     norm_type: str = "rmsnorm"
+    #: What every norm adds to the mean square (or the variance) under the
+    #: root: the block's norms, the final norm, latent attention's norms of
+    #: its latents and a state-space mixer's gated norm.
+    norm_eps: float = 1e-5
     #: One norm a block and both branches from it:
     #: ``x + attn(norm(x)) + ffn(norm(x))``.
     parallel_block: bool = False
@@ -194,13 +198,17 @@ class ModelConfig:
     # zero-compute experts (the LongCat-Flash family; every default is the
     # block above) ---------------------------------------------------------
     #: "mha": q/k/v heads (``num_kv_heads`` makes it GQA).  "mla": latent
-    #: attention - queries through a ``q_lora_rank`` bottleneck, keys and
+    #: attention - queries through a ``q_lora_rank`` bottleneck (0: a
+    #: full-rank query projection, no bottleneck), keys and
     #: values expanded from one cached row a position of ``kv_lora_rank``
     #: latent values and one ``qk_rope_head_dim`` rotated key shared by all
     #: heads; a head's query and key are ``qk_nope_head_dim +
     #: qk_rope_head_dim`` wide (only the latter rotated), its value
-    #: ``v_head_dim`` (`models/mla.py`).  Latent attention comes in the
-    #: shortcut-connected double layer and nowhere else (`double_layer`).
+    #: ``v_head_dim`` (`models/mla.py`).  Latent attention comes in two
+    #: blocks: without a ``layer_pattern`` in the shortcut-connected double
+    #: layer (`double_layer`), under a ``layer_pattern`` of ``"a"`` and
+    #: ``"A"`` layers in the sequential pre-norm block (`hybrid_block`), one
+    #: attention sublayer a layer.
     attention_kind: str = "mha"
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -211,6 +219,22 @@ class ModelConfig:
     #: rank)`` (`q_lora_scale`, `kv_lora_scale`).
     mla_scale_q_lora: bool = False
     mla_scale_kv_lora: bool = False
+    #: Latent attention's positions stretched past the length the model was
+    #: trained at (YaRN as the DeepSeek-V2 family applies it, ``rope_scaling
+    #: .type = "deepseek_yarn"``; `ops/rope.yarn_inv_freq`): a factor of 1
+    #: is no scaling.  Pairs that turn more than ``yarn_beta_fast`` times in
+    #: ``yarn_original_context`` positions keep their frequency, pairs that
+    #: turn less than ``yarn_beta_slow`` times have it divided by
+    #: ``yarn_factor``, a line between; cos and sin are multiplied by
+    #: ``m(yarn_mscale) / m(yarn_mscale_all_dim)`` and the softmax scale by
+    #: ``m(yarn_mscale_all_dim) ** 2``, ``m(s) = 0.1 s ln(factor) + 1``
+    #: (`models/mla.softmax_scale`).
+    yarn_factor: float = 1.0
+    yarn_original_context: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     #: Width of one routed expert where it is not ``d_ff``.
     expert_d_ff: int | None = None
     #: Router outputs past ``n_experts`` that name no weights: a token's
@@ -376,10 +400,14 @@ class ModelConfig:
         dense SwiGLU FFNs of width ``d_ff`` and one expert layer (``ffn_type
         = "moe"``) in a layer; the expert layer reads the first sublayer's
         normalised stream and joins at the layer's end
-        (`models/decode._block_apply`).  Derived: the one configuration with
-        latent attention has it in this layer, so it is no field until one
-        needs them apart."""
-        return self.attention_kind == "mla"
+        (`models/decode._block_apply`).  Derived, and no field: it is
+        latent attention outside a ``layer_pattern``.  The one other block
+        with latent attention is the sequential one, which names its layers
+        by a pattern anyway (a leading dense layer is a letter of it), so
+        the pattern's absence says everything a flag would, and a
+        configuration file written before the second block - attention_kind
+        "mla" and nothing more - goes on meaning this layer."""
+        return self.attention_kind == "mla" and not self.hybrid_block
 
     @property
     def attn_sublayers(self) -> int:
@@ -555,8 +583,8 @@ class ModelConfig:
 
     @property
     def latent_block(self) -> bool:
-        """Latent attention (and with it the double layer), or any of its
-        expert layer's departures."""
+        """Latent attention (in either of its blocks), or any of the double
+        layer's expert layer's departures."""
         return (
             self.attention_kind == "mla"
             or self.expert_d_ff is not None
@@ -673,11 +701,20 @@ class ModelConfig:
             self.qk_rope_head_dim, self.v_head_dim,
         )
         if self.attention_kind == "mla":
-            if min(mla_dims) < 1 or self.qk_rope_head_dim % 2:
+            if (
+                min(mla_dims[1:]) < 1 or self.q_lora_rank < 0
+                or self.qk_rope_head_dim % 2
+            ):
                 raise ValueError(
-                    'attention_kind="mla" needs positive q_lora_rank, '
-                    "kv_lora_rank, qk_nope_head_dim, v_head_dim and an even "
-                    f"qk_rope_head_dim (got {mla_dims})"
+                    'attention_kind="mla" needs positive kv_lora_rank, '
+                    "qk_nope_head_dim, v_head_dim, an even qk_rope_head_dim "
+                    "and a q_lora_rank that is positive or 0, a full-rank "
+                    f"query (got {mla_dims})"
+                )
+            if self.mla_scale_q_lora and not self.q_lora_rank:
+                raise ValueError(
+                    "mla_scale_q_lora scales the query's latent, and "
+                    "q_lora_rank=0 (a full-rank query) has none"
                 )
             if (
                 self.num_kv_heads is not None or self.head_dim is not None
@@ -686,8 +723,19 @@ class ModelConfig:
             ):
                 raise ValueError(
                     "latent attention has no K/V heads, one head width of its "
-                    "own and no layer pattern: num_kv_heads, head_dim, "
+                    "own and no window layers: num_kv_heads, head_dim, "
                     "sliding_window, remove_rope and remove_rmsnorm contradict it"
+                )
+            if self.hybrid_block and (
+                set(self.layer_kinds) - {"a", DENSE_FFN_KIND}
+                or self.attention_multiplier is not None
+            ):
+                raise ValueError(
+                    "latent attention under a layer_pattern is the sequential "
+                    'block of "a" and "A" layers - every layer attends and '
+                    "feeds forward: a latent pool has an array a layer - at "
+                    f"its own softmax scale (got {self.layer_kinds!r}, "
+                    f"attention_multiplier={self.attention_multiplier})"
                 )
         elif (
             any(mla_dims[:3]) or self.mla_scale_q_lora or self.mla_scale_kv_lora
@@ -699,6 +747,31 @@ class ModelConfig:
                 "layer_pattern with window layers alone takes a "
                 "qk_rope_head_dim and a v_head_dim"
             )
+        if self.yarn_factor == 1.0:
+            if any(
+                getattr(self, name) != getattr(type(self), name)
+                for name in (
+                    "yarn_original_context", "yarn_beta_fast", "yarn_beta_slow",
+                    "yarn_mscale", "yarn_mscale_all_dim",
+                )
+            ):
+                raise ValueError(
+                    "yarn_original_context .. yarn_mscale_all_dim stretch "
+                    "positions by yarn_factor, which is 1 (no scaling)"
+                )
+        elif (
+            self.attention_kind != "mla" or self.yarn_factor < 1.0
+            or self.yarn_original_context < 1
+            or not 0 < self.yarn_beta_slow < self.yarn_beta_fast
+        ):
+            raise ValueError(
+                f"yarn_factor={self.yarn_factor} stretches latent attention's "
+                'positions (attention_kind="mla"; no other attention reads '
+                "it): it needs a factor >= 1, a positive "
+                "yarn_original_context and 0 < yarn_beta_slow < yarn_beta_fast"
+            )
+        if self.norm_eps <= 0:
+            raise ValueError(f"norm_eps={self.norm_eps} must be positive")
         if self.double_layer and (
             self.ffn_type != "moe" or self.parallel_block or self.use_post_norm
             or self.norm_type != "rmsnorm"
@@ -767,15 +840,14 @@ class ModelConfig:
                 )
             if (
                 (self.sliding_window is not None and not self.has_window_layers)
-                or self.attention_kind != "mha"
                 or self.parallel_block or self.use_post_norm
                 or self.remove_rmsnorm or self.norm_type != "rmsnorm"
             ):
                 raise ValueError(
                     "layers by a pattern of kinds come in the sequential "
                     "pre-norm RMSNorm block, state-space mixers beside plain "
-                    "attention layers: sliding_window, "
-                    'attention_kind="mla", parallel_block, use_post_norm, '
+                    "attention layers, or latent attention in every layer: "
+                    "sliding_window, parallel_block, use_post_norm, "
                     "remove_rmsnorm and LayerNorm contradict them"
                 )
             if self.has_window_layers and (

@@ -136,7 +136,8 @@ def _block_apply(x, block_params, config, attend, valid=None, tally=None):
     x)``, ``y = a + r * (M(N2 a) + S(N2 a))`` with the residual multiplier
     ``r``, ``attend`` being the layer's mixer - each of the two sublayers
     where the layer's tree has it (a layer of one sublayer has one norm and
-    one branch; ``attend`` is not called without a mixer).  Under
+    one branch; ``attend`` is not called without a mixer; latent attention
+    outside the double layer is a mixer here like any other).  Under
     ``double_layer`` it is the
     shortcut-connected double layer, whose ``attend(h, sublayer)`` is called
     for each of its two attention sublayers: with norms ``N1 .. N4``, dense
@@ -210,8 +211,9 @@ def _norm(x, w, config):
         return x
     if config.norm_unit_offset:
         # The offset is added at float32, as `transformer._maybe_norm` says.
-        return rmsnorm(x, 1.0 + w.astype(jnp.float32))
-    return layernorm(x, w) if config.norm_type == "layernorm" else rmsnorm(x, w)
+        return rmsnorm(x, 1.0 + w.astype(jnp.float32), config.norm_eps)
+    norm = layernorm if config.norm_type == "layernorm" else rmsnorm
+    return norm(x, w, config.norm_eps)
 
 
 def _embed(params, token_ids, config):
@@ -316,13 +318,14 @@ def prefill(
             layer_new: list = []
 
             def attend_latent(
-                h, sub, block_params=block_params, layer_cache=layer_cache,
+                h, sub=0, block_params=block_params, layer_cache=layer_cache,
                 layer_new=layer_new,
             ):
                 from bpe_transformer_tpu.models.mla import self_attention
 
                 out, rows = self_attention(
-                    h, block_params["attn"][sub], positions, config
+                    h, _latent_sublayer(block_params["attn"], sub, config),
+                    positions, config,
                 )
                 layer_new.append(
                     lax.dynamic_update_slice(
@@ -409,8 +412,14 @@ def _cache_write(buf: Array, new: Array, pos: Array) -> Array:
     )(buf, new, pos)
 
 
+def _latent_sublayer(attn, sublayer: int, config):
+    """One latent-attention sublayer's tree: the double layer holds a list
+    of two, the sequential block the one tree itself."""
+    return attn[sublayer] if config.double_layer else attn
+
+
 def _latent_decode_attention(
-    h, sub, *, attn, config, positions, pos, active, layer_cache, layer_new
+    h, sub=0, *, attn, config, positions, pos, active, layer_cache, layer_new
 ):
     """One latent-attention sublayer of :func:`decode_step`: the new row
     into the dense latent cache, the query absorbed against it."""
@@ -431,7 +440,9 @@ def _latent_decode_attention(
             scale=mla.softmax_scale(config),
         )
 
-    return mla.absorbed_attention(h, attn[sub], positions, config, attend)
+    return mla.absorbed_attention(
+        h, _latent_sublayer(attn, sub, config), positions, config, attend
+    )
 
 
 def decode_step(
@@ -1781,7 +1792,10 @@ def _cached_attention(
     state-space mixer - over the paged pool; ``layer_pool`` is the layer's
     entries of the pool's list, one a sublayer."""
     if config.attention_kind == "mla":
-        return cache.attention(h, attn[sublayer], layer_pool[sublayer], new_pool)
+        return cache.attention(
+            h, _latent_sublayer(attn, sublayer, config), layer_pool[sublayer],
+            new_pool,
+        )
     (layer_pool,) = layer_pool
     if config.eva_block:
         return cache.attention(h, attn, layer, layer_pool, new_pool)

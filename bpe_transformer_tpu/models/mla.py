@@ -10,9 +10,18 @@ For a hidden state ``h`` (``config`` names in brackets)::
     [k_nope;v] = W_kvb c   -> heads x (nope + v)     [v_head_dim]
 
 with ``s_q = sqrt(d_model / q_lora_rank)`` and ``s_kv`` likewise where the
-config scales its latents, RoPE (interleaved pairs) on ``q_rope`` and the
-one key ``k_r`` all heads share, ``scores = (q_nope . k_nope + q_rope .
-k_r) / sqrt(nope + rope)`` and a causal float32 softmax over ``v``.
+config scales its latents - or, with ``q_lora_rank = 0``, the full-rank
+query ``q = W_q h`` and no ``c_q`` - RoPE (interleaved pairs) on ``q_rope``
+and the one key ``k_r`` all heads share, ``scores = (q_nope . k_nope +
+q_rope . k_r) * scale`` and a causal float32 softmax over ``v``.  ``scale``
+is ``(nope + rope) ** -0.5``; where the config stretches its positions
+(``yarn_factor``: the frequencies of `ops/rope.yarn_inv_freq`, cos and sin
+times ``m(mscale) / m(mscale_all_dim)``) it is that times
+``m(mscale_all_dim) ** 2`` (:func:`softmax_scale`).
+
+The sublayer comes in two blocks (`ModelConfig.attention_kind`): twice a
+layer in the shortcut-connected double layer, once a layer in the
+sequential pre-norm block of a ``layer_pattern``.  Nothing here knows which.
 
 **What is cached** is the *latent row* ``[c ; rope(k_r)]``
 (``config.latent_width`` values a position, no heads): :func:`latent_rows`.
@@ -39,17 +48,30 @@ from jax import Array
 
 from bpe_transformer_tpu.models.config import ModelConfig
 from bpe_transformer_tpu.ops.core import linear
-from bpe_transformer_tpu.ops.rope import apply_rope, rope_tables
+from bpe_transformer_tpu.ops.rope import (
+    apply_rope,
+    rope_tables,
+    yarn_inv_freq,
+    yarn_mscale,
+)
 
 
 def init_mla_params(rng: jax.Array, config: ModelConfig, dtype=jnp.float32) -> dict:
     """One sublayer's tree.  ``kv_b`` is head-major: rows ``h * (nope + v)
-    ..`` are head ``h``'s key up-projection then its value's."""
+    ..`` are head ``h``'s key up-projection then its value's.  The query is
+    ``q_a``, ``q_norm``, ``q_b`` through the bottleneck, or ``q_proj``
+    alone at full rank (``q_lora_rank = 0``)."""
     d, heads = config.d_model, config.num_heads
     nope, rope, v = config.qk_nope_head_dim, config.qk_rope_head_dim, config.v_head_dim
+    if config.q_lora_rank:
+        query = {
+            "q_a": (config.q_lora_rank, d),
+            "q_b": (heads * (nope + rope), config.q_lora_rank),
+        }
+    else:
+        query = {"q_proj": (heads * (nope + rope), d)}
     shapes = {
-        "q_a": (config.q_lora_rank, d),
-        "q_b": (heads * (nope + rope), config.q_lora_rank),
+        **query,
         "kv_a": (config.kv_lora_rank + rope, d),
         "kv_b": (heads * (nope + v), config.kv_lora_rank),
         "output_proj": (d, heads * v),
@@ -61,7 +83,8 @@ def init_mla_params(rng: jax.Array, config: ModelConfig, dtype=jnp.float32) -> d
         ).astype(dtype)
         for key, (name, shape) in zip(keys, shapes.items())
     }
-    params["q_norm"] = jnp.ones((config.q_lora_rank,), dtype)
+    if config.q_lora_rank:
+        params["q_norm"] = jnp.ones((config.q_lora_rank,), dtype)
     params["kv_norm"] = jnp.ones((config.kv_lora_rank,), dtype)
     return params
 
@@ -75,13 +98,26 @@ def init_mla_params(rng: jax.Array, config: ModelConfig, dtype=jnp.float32) -> d
 
 
 def _rope(x, positions, config: ModelConfig):
-    cos, sin = rope_tables(
-        config.qk_rope_head_dim, config.context_length, config.rope_theta
-    )
+    rope = config.qk_rope_head_dim
+    if config.yarn_factor == 1.0:
+        cos, sin = rope_tables(rope, config.context_length, config.rope_theta)
+    else:
+        # Stretched positions: the frequencies pair by pair, made once on
+        # the host, and both tables at YaRN's magnitude.
+        cos, sin = rope_tables(
+            rope, config.context_length,
+            inv_freq=yarn_inv_freq(
+                rope, config.rope_theta, config.yarn_factor,
+                config.yarn_original_context, config.yarn_beta_fast,
+                config.yarn_beta_slow,
+            ),
+            magnitude=yarn_mscale(config.yarn_factor, config.yarn_mscale)
+            / yarn_mscale(config.yarn_factor, config.yarn_mscale_all_dim),
+        )
     return apply_rope(x.astype(jnp.float32), positions, cos, sin).astype(x.dtype)
 
 
-def _scaled_norm(x, weight, scale: float, eps: float = 1e-5):
+def _scaled_norm(x, weight, scale: float, eps: float):
     """``scale * RMSNorm(x) * weight``, rounded once."""
     x32 = x.astype(jnp.float32)
     inv = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
@@ -93,8 +129,14 @@ def queries(h: Array, p: dict, positions: Array, config: ModelConfig):
     q_rope (batch, heads, rows, rope))``, the latter rotated; ``positions``
     broadcasts against (batch, rows)."""
     with jax.named_scope("mla_q"):
-        c_q = _scaled_norm(linear(h, p["q_a"]), p["q_norm"], config.q_lora_scale)
-        q = linear(c_q, p["q_b"])
+        if config.q_lora_rank:
+            c_q = _scaled_norm(
+                linear(h, p["q_a"]), p["q_norm"], config.q_lora_scale,
+                config.norm_eps,
+            )
+            q = linear(c_q, p["q_b"])
+        else:
+            q = linear(h, p["q_proj"])
         q = q.reshape(*q.shape[:-1], config.num_heads, config.d_head)
         q = jnp.swapaxes(q, -2, -3)
         nope = config.qk_nope_head_dim
@@ -109,7 +151,9 @@ def latent_rows(h: Array, p: dict, positions: Array, config: ModelConfig) -> Arr
     with jax.named_scope("mla_kv"):
         kv = linear(h, p["kv_a"])
         rank = config.kv_lora_rank
-        c = _scaled_norm(kv[..., :rank], p["kv_norm"], config.kv_lora_scale)
+        c = _scaled_norm(
+            kv[..., :rank], p["kv_norm"], config.kv_lora_scale, config.norm_eps
+        )
         return jnp.concatenate([c, _rope(kv[..., rank:], positions, config)], axis=-1)
 
 
@@ -119,7 +163,12 @@ def _kv_b(p: dict, config: ModelConfig) -> Array:
 
 
 def softmax_scale(config: ModelConfig) -> float:
-    return config.d_head ** -0.5
+    """``d_head ** -0.5``, times ``m(yarn_mscale_all_dim) ** 2`` where the
+    positions are stretched (`ops/rope.yarn_mscale`; 1 without a stretch)."""
+    scale = config.d_head ** -0.5
+    if config.yarn_factor != 1.0:
+        scale *= yarn_mscale(config.yarn_factor, config.yarn_mscale_all_dim) ** 2
+    return scale
 
 
 def rows_attention_path(queries: int, config: ModelConfig) -> str:

@@ -124,16 +124,16 @@ def _split_xbc(xbc, config):
     return x, b, c
 
 
-def _gate_out(y, z, p, groups: int):
+def _gate_out(y, z, p, groups: int, eps: float):
     """``y`` (..., inner) float32 gated by ``z``, normalised a group of
     channels at a time, projected."""
     with jax.named_scope("block/ssm/gate_norm"):
         g = y.astype(z.dtype) * silu(z)
         if groups == 1:
-            g = rmsnorm(g, p["norm"])
+            g = rmsnorm(g, p["norm"], eps)
         else:
             by_group = g.reshape(*g.shape[:-1], groups, -1)
-            g = rmsnorm(by_group, p["norm"].reshape(groups, -1)).reshape(g.shape)
+            g = rmsnorm(by_group, p["norm"].reshape(groups, -1), eps).reshape(g.shape)
     with jax.named_scope("block/ssm/out_proj"):
         return linear(g, p["out_proj"])
 
@@ -234,7 +234,9 @@ def mamba2(
         a = -jnp.exp(p["A_log"].astype(jnp.float32))
         y, ssm = chunked_scan(x, dt, a, b, c, state["ssm"], config.ssm_chunk)
         y = y + p["D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
-    out = _gate_out(y.reshape(batch, t, config.ssm_inner), z, p, config.ssm_groups)
+    out = _gate_out(
+        y.reshape(batch, t, config.ssm_inner), z, p, config.ssm_groups, config.norm_eps
+    )
     return out, {"ssm": ssm, "conv": conv.astype(state["conv"].dtype)}
 
 
@@ -257,7 +259,9 @@ def step_inputs(u: Array, p: dict, config: ModelConfig, conv: Array, valid: Arra
 
 def step_output(y: Array, z: Array, p: dict, config: ModelConfig) -> Array:
     """``y`` (rows, heads, channels) float32 -> (rows, d_model)."""
-    return _gate_out(y.reshape(y.shape[0], config.ssm_inner), z, p, config.ssm_groups)
+    return _gate_out(
+        y.reshape(y.shape[0], config.ssm_inner), z, p, config.ssm_groups, config.norm_eps
+    )
 
 
 def mamba2_step(

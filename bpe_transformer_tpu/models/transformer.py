@@ -98,6 +98,11 @@ def init_params(
             from bpe_transformer_tpu.models.eva import init_eva_params
 
             layer["attn"] = init_eva_params(k[0], config, dtype)
+        elif config.attention_kind == "mla":
+            # Latent attention in the sequential block: one sublayer a layer.
+            from bpe_transformer_tpu.models.mla import init_mla_params
+
+            layer["attn"] = init_mla_params(k[0], config, dtype)
         elif config.layer_mixer(i):
             # K/V heads by the layer's kind, a value at its own width, and
             # the sink's logit a query head where the kind has one (for a
@@ -190,12 +195,12 @@ def _maybe_norm(x: Array, weight: Array, config: ModelConfig) -> Array:
     if config.remove_rmsnorm:
         return x
     if config.norm_type == "layernorm":
-        return layernorm(x, weight)
+        return layernorm(x, weight, config.norm_eps)
     if config.norm_unit_offset:
         # The offset is added at float32: one plus a 16-bit weight is not a
         # 16-bit number.
-        return rmsnorm(x, 1.0 + weight.astype(jnp.float32))
-    return rmsnorm(x, weight)
+        return rmsnorm(x, 1.0 + weight.astype(jnp.float32), config.norm_eps)
+    return rmsnorm(x, weight, config.norm_eps)
 
 
 def _patterned_block(
@@ -610,8 +615,8 @@ def forward_hidden(
             )
     elif config.hybrid_block:
         # The served arrangement again; a layer's mixer is the state-space
-        # scan over the whole sequence or plain causal attention under the
-        # config's own score multiplier.
+        # scan over the whole sequence, latent attention, or plain causal
+        # attention under the config's own score multiplier.
         from bpe_transformer_tpu.models.decode import _block_apply
 
         for layer, block_params in enumerate(compute_params["layers"]):
@@ -650,6 +655,11 @@ def _hybrid_mixer(
         from bpe_transformer_tpu.models.ssm import mamba2
 
         return mamba2(h, block_params["ssm"], config)[0]
+    if config.attention_kind == "mla":
+        # Latent attention over the sequence's own rows (`models/mla.py`).
+        from bpe_transformer_tpu.models.mla import self_attention
+
+        return self_attention(h, block_params["attn"], positions, config)[0]
     if config.has_window_layers:
         # Window and full layers by kind, as the paged forward projects,
         # rotates and attends them, the whole sequence its own chain.

@@ -70,14 +70,15 @@ def embedding(weight: Array, token_ids: Array) -> Array:
     return jnp.take(weight, token_ids, axis=0)
 
 
-def rmsnorm(x: Array, weight: Array, eps: float = 1e-5) -> Array:
-    """Root-mean-square norm with affine scale; accumulates in float32."""
+def rmsnorm(x: Array, weight: Array, eps: float) -> Array:
+    """Root-mean-square norm with affine scale; accumulates in float32.
+    ``eps`` is the caller's to give: a model's is `ModelConfig.norm_eps`."""
     x32 = x.astype(jnp.float32)
     scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     return (x32 * scale).astype(x.dtype) * weight
 
 
-def layernorm(x: Array, weight: Array, eps: float = 1e-5) -> Array:
+def layernorm(x: Array, weight: Array, eps: float) -> Array:
     """Mean-subtracting layer norm with affine scale and no bias;
     accumulates in float32."""
     x32 = x.astype(jnp.float32)

@@ -160,7 +160,9 @@ def _pp_loss_fn(
 
         def head_loss(act, targets):
             if not config.remove_rmsnorm:
-                act = rmsnorm(act, shared["ln_final"].astype(act_dtype))
+                act = rmsnorm(
+                    act, shared["ln_final"].astype(act_dtype), config.norm_eps
+                )
             from bpe_transformer_tpu.ops.losses import lm_loss
 
             head_w = shared.get("lm_head", shared["token_embeddings"])

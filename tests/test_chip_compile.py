@@ -380,7 +380,11 @@ def _pool_program(
 
     options = layered_program_options("tpu") if layers_as_calls else None
 
-    from bpe_transformer_tpu.models.decode import cache_kind, init_paged_pool
+    from bpe_transformer_tpu.models.decode import (
+        RecurrentRows,
+        cache_kind,
+        init_paged_pool,
+    )
     from bpe_transformer_tpu.models.transformer import init_params
     from bpe_transformer_tpu.serving.engine import prepare_serving_weights
     from bpe_transformer_tpu.serving.kvpool import paged_engine as pe
@@ -453,7 +457,7 @@ def _pool_program(
     if name == "chunk":
         fn = functools.partial(pe._chunk_program, config=config, block_size=bs)
         table_row = tables()
-        if config.hybrid_block and not config.has_window_layers:
+        if cache_kind(config) is RecurrentRows:
             # A chunk addresses its slot's recurrent state by the slot's id.
             table_row = {"blocks": table_row, "slot": scalar}
         args = (
@@ -594,6 +598,9 @@ def _cell_config(cell: str):
         }),
         # The leading dense layer, a window layer and a full layer that routes.
         "mimo": ("MiMo-V2.5", {"num_layers": 3, "layer_pattern": "Awa"}),
+        # The leading dense layer and a layer that routes, latent attention
+        # in both.
+        "sarvam": ("sarvam-105b", {"num_layers": 2, "layer_pattern": "Aa"}),
     }[cell]
     path = Path(__file__).resolve().parents[1] / f"chipbench/configs/{name}.json"
     file = json.loads(path.read_text())
@@ -1429,3 +1436,104 @@ def test_grouped_row_pool_programs(one_chip, on_tpu, name):
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= sum(a.size * 2 for a in pool)
     assert memory.temp_size_in_bytes < 700e6
+
+
+# ------------- sarvam-105b (latent attention in the sequential block, YaRN)
+
+
+#: ``sarvam.serve.doc-qa``'s softmax scale: ``192 ** -0.5`` times YaRN's
+#: ``m(mscale_all_dim) ** 2``.
+SARVAM_SCALE = 0.135234
+
+
+def test_mla_paged_attention_at_a_32k_table(one_chip):
+    """The tick's two kernels at ``sarvam.serve.doc-qa``'s sizes: 32 slots, a
+    table of 2,048 blocks of 16 (32,768 positions a slot), the pool's 43,751
+    blocks, under the config's scale."""
+    from bpe_transformer_tpu.kernels.pallas.mla_attention import (
+        mla_paged_attention,
+    )
+
+    def fn(q, pool, tables, counts):
+        return mla_paged_attention(
+            q, pool, tables, counts, rank=512, scale=SARVAM_SCALE,
+            path="mla_paged", interpret=False,
+        )
+
+    text = _compile(
+        fn, one_chip, ((32, 64, 576), BF16), ((43751, 16, 640), BF16),
+        ((32, 2048), I32), ((32,), I32),
+    )
+    assert _mla_kernels(text) == {"mla_paged_attention", "mla_paged_attention_shared"}
+
+
+@pytest.mark.parametrize("queries", [512, 1024, 2048])
+def test_mla_chunk_attention_at_32k_keys(one_chip, queries):
+    """A chunk's expanded latent attention over a slot's gathered chain of
+    32,768 rows - twice the keys `test_mla_chunk_attention` compiles, the
+    cell's three buckets, its scale: the grid is (tiles, 64 heads, 32 key
+    blocks), a tile's state stays in VMEM and no (chunk x context) score
+    leaves the kernel (the output is the heads' values alone)."""
+    from bpe_transformer_tpu.kernels.pallas.mla_attention import (
+        mla_chunk_attention,
+        mla_chunk_path,
+    )
+
+    assert mla_chunk_path(queries, 128, 128, 512, backend="tpu") == "mla_chunk"
+
+    def fn(q_nope, q_rope, rows, kv_b, positions, n_keys):
+        return mla_chunk_attention(
+            q_nope, q_rope, rows, kv_b, positions, n_keys, scale=SARVAM_SCALE,
+            interpret=False,
+        )
+
+    text = _compile(
+        fn, one_chip, ((64, queries, 128), BF16), ((64, queries, 64), BF16),
+        ((32768, 640), BF16), ((64, 256, 512), BF16), ((queries,), I32),
+        ((), I32),
+    )
+    assert _mla_kernels(text) == {"mla_chunk_attention"}
+    assert f"f32[64,{queries},32768]" not in text and f"bf16[64,{queries},32768]" not in text
+
+
+@pytest.mark.parametrize("rows", [256, 16384], ids=["tick_32_slots", "chunk_2048"])
+@pytest.mark.parametrize("d_out,d_in", [(2048, 4096), (4096, 2048)], ids=["up", "down"])
+def test_grouped_matmul_at_sarvam_widths(one_chip, monkeypatch, rows, d_out, d_in):
+    """8 assignments a token, 32 experts of 2,048 held, hidden 4,096."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _compile(
+        grouped_matmul, one_chip,
+        ((rows, d_in), BF16), ((32, d_out, d_in), BF16), ((32,), I32),
+    )
+
+
+@pytest.mark.parametrize("name", ["tick", "chunk"], ids=["tick", "chunk2048"])
+def test_latent_pool_programs_of_the_sequential_block(one_chip, on_tpu, name):
+    """``sarvam.serve.doc-qa``'s tick and chunk programs at its widths,
+    slots, pool and bucket, cut to two layers (the dense leading layer and a
+    layer that routes): latent attention's kernels are there - one array a
+    layer, not two - under the names the cell's metrics read, layer 0 runs
+    under ``block/ffn/dense``, the experts go through `gmm` and the shared
+    one under ``block/moe/shared``, and the pool is aliased whole and never
+    copied.  The chunk attends over a table of 2,048 blocks: 32,768 rows."""
+    config = _cell_config("sarvam")
+    assert config.layer_kinds == "Aa" and not config.double_layer
+    jitted, args, pool = _pool_program(
+        name, config, one_chip, None, slots=32, blocks=43751, bucket=2048,
+        prefill_chunk=2048,
+    )
+    leaves = jax.tree_util.tree_leaves(pool)
+    assert [a.shape for a in leaves] == [(43751, 16, 640)] * 2
+    compiled = jitted.lower(*args).compile()
+    text = compiled.as_text()
+    assert _mla_kernels(text) == (
+        {"mla_paged_attention", "mla_paged_attention_shared"}
+        if name == "tick" else {"mla_chunk_attention"}
+    )
+    assert "gmm" in text and "block/ffn/dense" in text and "block/moe/shared" in text
+    assert "mla_q" in text and "mla_kv" in text
+    assert [line for line in _sorts(text) if f",{config.vocab_size}]" in line] == []
+    assert _pool_copies(text, {"bf16[43751,16,640]"}) == []
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= sum(a.size * 2 for a in leaves)
+    assert memory.temp_size_in_bytes < 500e6

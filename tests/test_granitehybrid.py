@@ -690,8 +690,9 @@ def test_config_properties_and_defaults():
     assert not plain.hybrid_block and not plain.dropless_block and plain.ssm_layers == 0
     assert not any(plain.layer_is_ssm(i) for i in range(plain.num_layers))
     assert plain.attention_scale == plain.d_head ** -0.5 and plain.shared_ff == plain.d_ff
-    # 64, PR 40's four, PR 42's three, PR 46's four (attention layers by kind)
-    assert len(dataclasses.fields(ModelConfig)) == 75
+    # 64, PR 40's four, PR 42's three, PR 46's four (attention layers by
+    # kind), PR 49's seven (the norms' epsilon, six of stretched positions)
+    assert len(dataclasses.fields(ModelConfig)) == 82
     for field, value in [("ssm_heads", 4), ("residual_multiplier", 0.5), ("logits_scaling", 2.0)]:
         with pytest.raises(ValueError, match="hybrid block's"):
             dataclasses.replace(plain, **{field: value})

@@ -454,7 +454,7 @@ def _shared_bc(split):
 LEFT_OUT = {
     "groups_of_b_and_c": lambda mp: mp.setattr(ssm, "_split_xbc", _shared_bc(ssm._split_xbc)),
     "norm_by_group": lambda mp: mp.setattr(
-        ssm, "_gate_out", lambda y, z, p, groups, fn=ssm._gate_out: fn(y, z, p, 1)
+        ssm, "_gate_out", lambda y, z, p, groups, eps, fn=ssm._gate_out: fn(y, z, p, 1, eps)
     ),
     "the_square": lambda mp: mp.setattr(moe, "relu2", jax.nn.relu),
     "relu_for_silu": lambda mp: mp.setattr(moe, "relu2", lambda x: jnp.square(jax.nn.silu(x))),

@@ -167,7 +167,19 @@ DEFAULT_BUDGET_S = 800.0
 #: at twelve geometries (tests/test_mimov2.py) and the 1,024 bucket compiled
 #: for the described v5e (tests/test_chip_compile.py, 2 cases); the whole
 #: run 607 s with six workers.
-DEFAULT_MAX_TESTS = 1450
+#: Raised 1450 -> 1525 in PR 49 (1,475 collected, 59 added): sarvam-105b's
+#: block against its reference - YaRN's range, frequencies and scale at the
+#: published numbers, the forward on two shares, the dense latent cache,
+#: prefill then teacher-forced ticks through the latent pool at three
+#: prompt lengths by both tick paths, eleven mechanisms each left out in
+#: the forward and five of them in the engine, the radix cache over one sublayer a
+#: layer, the counters by hand, the share against the whole, the chunk
+#: kernel interpreted under the config's scale, every refusal
+#: (tests/test_sarvam.py, 49 cases in about 125 s in one process) and the
+#: two kernels at a 32k-row table, the grouped matmul at its widths and the
+#: cell's tick and chunk programs compiled for the described v5e
+#: (tests/test_chip_compile.py, 10 cases, 1-10 s each).
+DEFAULT_MAX_TESTS = 1525
 
 #: Pytest summary trailer: "== 398 passed, 27 deselected in 612.34s =="
 #: (also plain "in 612.34s (0:10:12)" forms).
