@@ -43,32 +43,46 @@ a dense cache's prefill, the plain forward) take either form, chosen from
 the shapes and the backend in one place (:func:`mla_chunk_path`, asked by
 `models/mla.rows_attention`).  A bucket of `MLA_CHUNK_MIN_ROWS` rows or more
 on the TPU, at widths of whole lane tiles, attends **expanded** inside one
-Pallas kernel (``mla_chunk_attention.N``): a grid step takes one head and
-one block of latent rows, makes that head's keys and values of the block in
-VMEM, scores, masks only the blocks that reach into the chunk and folds
-into a float32 softmax state - neither K, V nor a score tile reaches HBM,
-and blocks past the last position are neither copied nor computed.
-Everything else (every other backend, unaligned test shapes, a short chunk)
-runs :func:`xla_mla_chunk_attention`, the **absorbed** flash loop in XLA
-whose trip count follows the last position.  On the v5e, one sublayer after
-8,192 cached positions (PERF.md section 6, PR 41; 64 heads, bfloat16):
+Pallas kernel (``mla_chunk_attention.N``).  Its grid is the heads: a step
+holds one head's up-projection and the whole bucket's query rows of that
+head, and walks the blocks of `MLA_CHUNK_KERNEL_KEYS` latent rows that any
+of its rows sees in a loop *inside* the step (:func:`chunk_walk`, from the
+positions and the live key count).  A trip copies its block out of HBM into
+one of two buffers while the block before it is computed on, makes the
+head's keys and values of the block in VMEM - **once a head**, whatever the
+bucket's rows - and folds them into the float32 softmax state of each tile
+of `MLA_CHUNK_TILE_ROWS` query rows in turn: bare where the tile sees the
+whole block, under the mask where its edge crosses the block, not at all
+where it sees none of it.  Neither K, V nor a score tile reaches HBM, and a
+block past the chunk's last position costs nothing: no copy, no step, no
+branch.  Everything else (every other backend, unaligned test shapes, a
+short chunk) runs :func:`xla_mla_chunk_attention`, the **absorbed** flash
+loop in XLA whose trip count follows the last position.  On the v5e, one
+sublayer after 8,192 cached positions (64 heads, bfloat16; PERF.md section
+6, PR 50; the loop at 1,024 rows is PR 41's reading):
 
-    query rows          128     256     512     1,024
-    absorbed loop, ms   1.11    2.37    5.92    15.84
-    expanded kernel     1.62    1.90    2.65     4.16
+    query rows            128     256     512     1,024   2,048
+    absorbed loop, ms     1.12    2.35    5.92    15.84   -
+    expanded kernel       1.51    1.64    2.37     3.87   6.95
 
-and 2,048 rows from position 0 8.22 against 1.96.  The kernel's step is
-7.1 us at 1,024 rows x 1,024 keys (1.07 GFLOP as the MXU sees it: 77% of
-its peak); the loop's is the same product as the tick's shared pass, whose
-own kernel holds 58-60% (PR 39), so an absorbed kernel could not have
-caught up: the FLOPs had to go.  (PR 33 had read the two forms as two XLA
-programs, 15.9 ms absorbed against 18.3-21.3 expanded: expanded *in XLA*
-writes 64 heads' K and V and float32 score tiles to HBM.)
+and 2,048 rows from position 0 1.10 ms, after 30,000 positions 22.68
+(chains of 32,768 rows under 2,048 query rows, of 16,384 under fewer).  A
+trip over 2,048 rows takes 5.7 us where its 1,792 matrix pushes need 4.8
+(16 cycles a push on each of four MXUs): 84%.  By the
+static schedule a trip's expansion is 963 bundles for 256 pushes and an
+unmasked fold of 512 rows x 512 keys 1,603 for 384, so most of what is
+left is in the products themselves: the rotated key's 64 dead lanes of 256
+and the expansion.  The absorbed loop's product is the tick's shared
+pass's, whose own kernel holds 58-60% (PR 39), so an absorbed kernel could
+not have caught up: the FLOPs had to go.  (PR 33 had read the two forms as
+two XLA programs, 15.9 ms absorbed against 18.3-21.3 expanded: expanded *in
+XLA* writes 64 heads' K and V and float32 score tiles to HBM.)
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -102,20 +116,25 @@ MLA_SHARED_VMEM_BYTES = 64 * 1024 * 1024
 MLA_SHARED_MIN_SLOTS = 6
 #: Lanes behind a softmax state's accumulator for its maximum and its sum.
 _STATE_LANES = 128
-#: Keys a step of the chunk's loop, or of its kernel, scores and folds into
-#: its softmax.  The kernel on the v5e, 1,024 rows after 8,192 positions:
-#: 4.46 ms a call at 512, 4.08 at 1,024, 4.50 at 2,048; 512 rows: 2.59,
-#: 2.52, 2.64 (PERF.md section 6, PR 41).
+#: Keys a step of the chunk's absorbed loop scores and folds into its
+#: softmax (`xla_mla_chunk_attention`).
 MLA_CHUNK_KEY_BLOCK = 1024
-#: Query rows a step of the chunk's kernel holds against a block of keys: a
-#: block is expanded once a head and tile, so the tile is the whole bucket
-#: up to here (a 1,024 x 1,024 float32 score tile and its probabilities are
-#: 10 MB, as the shared pass's).
-MLA_CHUNK_TILE_ROWS = 1024
+#: Latent rows a trip of the chunk kernel's walk copies, expands into a
+#: head's keys and values and folds, and query rows of a tile, which meets
+#: the expanded block bare, under the mask or not at all.  A (tile, block)
+#: pair is the grain of the walk, so both are as small as the fold stays
+#: efficient: by the static schedule a fold of 512 x 512 is 1,603 bundles,
+#: of 512 x 1,024 3,448, of 1,024 x 512 3,349, of 256 x 256 614.  On the
+#: v5e, 2,048 rows after 8,192 positions / 1,024 rows after 8,192 (PERF.md
+#: section 6, PR 50), keys x rows: 512 x 512 7.23 / 3.83 ms a call, 1,024 x
+#: 512 7.46 / 3.92, 512 x 1,024 7.44 / 3.92, 1,024 x 1,024 7.53 / 3.94, 512
+#: x 256 7.86 / 4.11, 256 x 512 9.61 / 5.04.
+MLA_CHUNK_KERNEL_KEYS = 512
+MLA_CHUNK_TILE_ROWS = 512
 #: Fewest query rows for which a chunk attends in the expanded form: by
 #: FLOPs the forms break even at ~170 rows a key.  On the v5e after 8,192
-#: positions, kernel against loop: 128 rows 1.62 against 1.11 ms, 256 rows
-#: 1.90 against 2.37, 512 rows 2.65 against 5.92 (PERF.md section 6, PR 41).
+#: positions, kernel against loop: 128 rows 1.51 against 1.12 ms, 256 rows
+#: 1.64 against 2.35, 512 rows 2.37 against 5.92 (PERF.md section 6, PR 50).
 MLA_CHUNK_MIN_ROWS = 256
 
 
@@ -684,130 +703,201 @@ def xla_mla_chunk_attention(
 # ------------------------------------------- many query rows, expanded, kernel
 
 
+def chunk_walk(lo, hi, n_keys, block: int, minimum=min):
+    """``(clear, end)``: of the blocks of ``block`` keys from position 0 on,
+    the ones that query rows at positions ``lo .. hi`` (their least and
+    their greatest) walk when ``n_keys`` leading keys are live.  Blocks ``0
+    .. clear - 1`` lie wholly at or under ``lo``: every row sees every key
+    of them, and they take no mask.  Blocks ``clear .. end - 1`` hold a key
+    that some row sees and one that some row does not - an edge crosses them
+    - and are folded under the mask.  No row sees a key of a block from
+    ``end`` on: it is neither copied, expanded nor computed.  ``clear <=
+    end``.
+
+    One definition for the kernel, which walks a tile of query rows by it
+    (on traced arrays: ``minimum`` is then `jnp.minimum`), and for a test's
+    count of the pairs themselves (on integers)."""
+    end = (minimum(hi + 1, n_keys) + block - 1) // block
+    return minimum((lo + 1) // block, end), end
+
+
 def _mla_chunk_kernel(
-    limits_ref, clear_ref, q_ref, pos_ref, rows_ref, w_ref, o_ref, m_run,
-    l_run, acc, *, scale: float, block: int, rank: int, nope: int,
+    clear_ref, end_ref, q_ref, pos_ref, rows_hbm, w_ref, o_ref, buf, sems,
+    k_ref, v_ref, m_ref, l_ref, acc_ref, *, scale: float, rank: int,
+    nope: int, block: int, tile_rows: int,
 ):
-    """One head's queries of one tile against one block of latent rows a
-    grid step (tile, head, key block; the key blocks innermost, under one
-    softmax state): the block's keys and values of this head are expanded
-    from its latent rows here, in VMEM, scored, and folded into ``(m_run,
-    l_run, acc)``; the last step normalises.  ``limits_ref[tile]`` is how
-    many leading keys any of the tile's queries sees - blocks past it are
-    neither copied (the block spec holds the last live block) nor computed
-    - and ``clear_ref[tile]`` how many every one of them sees: a block
-    wholly below it takes no mask."""
-    tile, step = pl.program_id(0), pl.program_id(2)
+    """One head against the whole bucket of query rows a grid step, and the
+    walk over the blocks of ``block`` keys inside it, as far as the last
+    block any tile of ``tile_rows`` rows sees (``clear_ref``, ``end_ref``:
+    each tile's `chunk_walk`).  A trip of the walk awaits the copy of its
+    block of latent rows (``rows_hbm`` stays in HBM; two buffers, the next
+    block's copy - or, behind a head's last block, the next head's first -
+    started before the block is computed on), expands the block into this
+    head's keys and values **once**, in VMEM (``k_ref``, ``v_ref``), and
+    folds them into the softmax state of each tile in turn: with no mask
+    where the tile's every row sees the block's every key, under the mask
+    where the tile's edge crosses the block, and not at all where none of
+    its rows sees any of it.  The running maximum and sum lie a row's value
+    in every lane of ``m_ref`` / ``l_ref``, so that a reduction's column
+    meets the state with one broadcast and the state meets the accumulator
+    with none."""
+    head, heads = pl.program_id(0), pl.num_programs(0)
+    tiles = q_ref.shape[1] // tile_rows
+    lanes = m_ref.shape[1]
+    end = functools.reduce(jnp.maximum, [end_ref[t] for t in range(tiles)])
 
-    @pl.when(step == 0)
-    def _open():
-        m_run[...] = jnp.full_like(m_run, NEG_INF)
-        l_run[...] = jnp.zeros_like(l_run)
-        acc[...] = jnp.zeros_like(acc)
+    def copy(b, slot):
+        at = pl.ds(pl.multiple_of(b * block, block), block)
+        return pltpu.make_async_copy(rows_hbm.at[at], buf.at[slot], sems.at[slot])
 
-    @pl.when(step * block < limits_ref[tile])
-    def _fold():
-        rows = rows_ref[...]                        # (block, rank + rope')
+    @pl.when(jnp.logical_and(head == 0, end > 0))
+    def _first():
+        copy(0, 0).start()
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def fold(t, b, masked: bool):
+        """The expanded block ``b`` into tile ``t``'s state."""
+        rows = pl.ds(pl.multiple_of(t * tile_rows, tile_rows), tile_rows)
+        k, v = k_ref[...], v_ref[...]
+        s = jax.lax.dot_general(
+            q_ref[0, rows, :], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale                                   # (tile_rows, block)
+        if masked:
+            key_at = b * block + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block), 1
+            )
+            seen = key_at <= pltpu.repeat(pos_ref[rows, :], block // lanes, axis=1)
+            s = jnp.where(seen, s, NEG_INF)
+        m_prev = m_ref[rows, :]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - pltpu.repeat(m_new, block // lanes, axis=1))
+        l_ref[rows, :] = l_ref[rows, :] * alpha + jnp.sum(
+            p, axis=-1, keepdims=True
+        )
+        acc_ref[rows, :] = acc_ref[rows, :] * pltpu.repeat(
+            alpha, acc_ref.shape[1] // lanes, axis=1
+        ) + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[rows, :] = m_new
+
+    def trip(b, _):
+        slot = jax.lax.rem(head * end + b, 2)
+        copy(b, slot).wait()
+        more = b + 1 < end
+
+        @pl.when(jnp.logical_or(more, head + 1 < heads))
+        def _():
+            copy(jnp.where(more, b + 1, 0), 1 - slot).start()
+
+        rows = buf[slot]                            # (block, rank + rope')
         kv = jax.lax.dot_general(
             rows[:, :rank], w_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ).astype(rows.dtype)                        # (block, nope + v)
         # A head's key: its own ``nope`` values beside the one rotated key
         # all heads share, as the query's two parts lie.
-        k = jnp.concatenate([kv[:, :nope], rows[:, rank:]], axis=-1)
-        s = jax.lax.dot_general(
-            q_ref[0], k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                   # (queries, block)
+        k_ref[:, :nope] = kv[:, :nope]
+        k_ref[:, nope:] = rows[:, rank:]
+        v_ref[...] = kv[:, nope:]
 
-        def fold(s):
-            m_prev = m_run[...]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_run[...] = l_run[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc[...] = acc[...] * alpha + jax.lax.dot_general(
-                p.astype(kv.dtype), kv[:, nope:], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_run[...] = m_new
+        def tile(t, _):
+            clear = clear_ref[t]
 
-        every_row_sees = (step + 1) * block <= clear_ref[tile]
+            @pl.when(b < clear)
+            def _():
+                fold(t, b, False)
 
-        @pl.when(every_row_sees)
-        def _():
-            fold(s)
+            @pl.when(jnp.logical_and(b >= clear, b < end_ref[t]))
+            def _():
+                fold(t, b, True)
 
-        @pl.when(jnp.logical_not(every_row_sees))
-        def _():
-            key_pos = step * block + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1
-            )
-            fold(jnp.where(key_pos <= pos_ref[...], s, NEG_INF))
+        jax.lax.fori_loop(0, tiles, tile, None)
 
-    @pl.when(step == pl.num_programs(2) - 1)
-    def _close():
-        o_ref[0] = (acc[...] / jnp.maximum(l_run[...], 1e-30)).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, end, trip, None)
+    # No live key (``n_keys`` 0) walks no block: zeros over the guard.
+    o_ref[0] = (
+        acc_ref[...] / pltpu.repeat(
+            jnp.maximum(l_ref[...], 1e-30), acc_ref.shape[1] // lanes, axis=1
+        )
+    ).astype(o_ref.dtype)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("scale", "block", "tile_rows", "interpret")
-)
-def _mla_chunk_impl(
-    q, rows, kv_b, q_positions, n_keys, scale, block, tile_rows, interpret
-):
+def chunk_tiles(queries: int, keys: int) -> tuple[int, int]:
+    """``(query rows a tile, keys a block)`` of the chunk kernel for a bucket
+    of ``queries`` rows over a chain of ``keys`` rows: the module's
+    constants, held to the bucket and to the chain in whole lane tiles."""
+    return (
+        min(MLA_CHUNK_TILE_ROWS, queries),
+        min(MLA_CHUNK_KERNEL_KEYS, -(-keys // 128) * 128),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _mla_chunk_impl(q, rows, kv_b, q_positions, n_keys, scale, interpret):
     heads, queries, q_width = q.shape
     keys, width = rows.shape
     rank = kv_b.shape[-1]
     nope = q_width - (width - rank)
     v = kv_b.shape[1] - nope
-    tiles = queries // tile_rows
-    positions = q_positions.astype(jnp.int32).reshape(tiles, tile_rows)
-    limits = jnp.minimum(jnp.int32(n_keys), positions.max(axis=1) + 1)
-    clear = positions.min(axis=1) + 1
+    tile_rows, block = chunk_tiles(queries, keys)
+    # A row's running maximum and sum fill a lane tile (or what of one
+    # divides a value), and so does its position.
+    lanes = math.gcd(v, _STATE_LANES)
+    positions = q_positions.astype(jnp.int32)
+    by_tile = positions.reshape(queries // tile_rows, tile_rows)
+    clear, end = chunk_walk(
+        by_tile.min(axis=1), by_tile.max(axis=1), jnp.asarray(n_keys, jnp.int32),
+        block, jnp.minimum,
+    )
 
-    def live(step, limits, tile):
-        """The block a step reads: its own, or the tile's last live one
-        again (no new copy) once past what the tile sees."""
-        return jnp.minimum(step, jnp.maximum(pl.cdiv(limits[tile], block) - 1, 0))
+    def whole(*shape):
+        return pl.BlockSpec(shape, lambda h, *_: (0,) * len(shape))
+
+    def at_head(*shape):
+        return pl.BlockSpec((1, *shape), lambda h, *_: (h,) + (0,) * len(shape))
 
     return pl.pallas_call(
         functools.partial(
-            _mla_chunk_kernel, scale=scale, block=block, rank=rank, nope=nope
+            _mla_chunk_kernel, scale=scale, rank=rank, nope=nope, block=block,
+            tile_rows=tile_rows,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(tiles, heads, keys // block),
+            grid=(heads,),
             in_specs=[
-                pl.BlockSpec(
-                    (1, tile_rows, q_width), lambda t, h, s, *_: (h, t, 0)
-                ),
-                pl.BlockSpec((tile_rows, 1), lambda t, h, s, *_: (t, 0)),
-                pl.BlockSpec(
-                    (block, width),
-                    lambda t, h, s, limits, clear: (live(s, limits, t), 0),
-                ),
-                pl.BlockSpec(
-                    (1, nope + v, rank), lambda t, h, s, *_: (h, 0, 0)
-                ),
+                at_head(queries, q_width), whole(queries, lanes),
+                pl.BlockSpec(memory_space=pl.ANY), at_head(nope + v, rank),
             ],
-            out_specs=pl.BlockSpec(
-                (1, tile_rows, v), lambda t, h, s, *_: (h, t, 0)
-            ),
+            out_specs=at_head(queries, v),
             scratch_shapes=[
-                pltpu.VMEM((tile_rows, 1), jnp.float32),   # running maximum
-                pltpu.VMEM((tile_rows, 1), jnp.float32),   # running sum
-                pltpu.VMEM((tile_rows, v), jnp.float32),
+                pltpu.VMEM((2, block, width), rows.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((block, q_width), rows.dtype),   # a head's keys
+                pltpu.VMEM((block, v), rows.dtype),         # and its values
+                pltpu.VMEM((queries, lanes), jnp.float32),  # running maximum
+                pltpu.VMEM((queries, lanes), jnp.float32),  # running sum
+                pltpu.VMEM((queries, v), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((heads, queries, v), q.dtype),
+        # A head's last block starts the copy of the next head's first.
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=MLA_SHARED_VMEM_BYTES,
         ),
         interpret=interpret,
         name="mla_chunk_attention",
-    )(limits, clear, q, positions.reshape(-1, 1), rows, kv_b)
+    )(
+        clear, end, q, jnp.broadcast_to(positions[:, None], (queries, lanes)),
+        rows, kv_b,
+    )
 
 
 def mla_chunk_path(
@@ -842,11 +932,10 @@ def mla_chunk_attention(
     they nor a score reach HBM.  ``rows`` may come as a pool pads them
     (zeros up to whole lane tiles past ``rank + rope``); the queries' shapes
     are `mla_chunk_path`'s to admit."""
-    queries = q_nope.shape[1]
     rank, rope = kv_b.shape[-1], q_rope.shape[-1]
     lanes = -(-rope // 128) * 128
     keys = rows.shape[0]
-    block = min(MLA_CHUNK_KEY_BLOCK, -(-keys // 128) * 128)
+    _, block = chunk_tiles(q_nope.shape[1], keys)
     # Whole blocks of whole lane tiles: a row past the last position is
     # masked like any other, and a padded lane meets a zero of the query.
     rows = jnp.pad(
@@ -860,6 +949,5 @@ def mla_chunk_attention(
 
         interpret = interpret_mode()
     return _mla_chunk_impl(
-        q, rows, kv_b, q_positions, n_keys, float(scale), block,
-        min(MLA_CHUNK_TILE_ROWS, queries), interpret,
+        q, rows, kv_b, q_positions, n_keys, float(scale), interpret
     )

@@ -236,9 +236,16 @@ def self_attention(h: Array, p: dict, positions: Array, config: ModelConfig):
     q_nope, q_rope = queries(h, p, positions, config)
     rows = latent_rows(h, p, positions, config)
     n_keys = h.shape[-2]
-    att = jax.vmap(
-        lambda qn, qr, r: rows_attention(qn, qr, r, p, positions, n_keys, config)
-    )(q_nope, q_rope, rows)
+
+    def attend(qn, qr, r):
+        return rows_attention(qn, qr, r, p, positions, n_keys, config)
+
+    if rows_attention_path(n_keys, config) == "mla_chunk":
+        # The kernel copies a sequence's rows from where they lie in HBM:
+        # it takes no batch axis.
+        att = jax.lax.map(lambda one: attend(*one), (q_nope, q_rope, rows))
+    else:
+        att = jax.vmap(attend)(q_nope, q_rope, rows)
     return linear(att, p["output_proj"]), rows
 
 

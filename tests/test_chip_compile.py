@@ -947,9 +947,13 @@ def test_mla_paged_attention(one_chip, slots):
 def test_mla_chunk_attention(one_chip, queries):
     """A chunk's expanded latent attention at the published widths: 64
     heads of 128 + 64 / 128 over a slot's gathered chain of 16,384 rows as
-    the pool pads them (640 lanes), every bucket the rule admits (2,048 is
-    two tiles of 1,024 rows); the device event's name is the one
-    `mla_chunk_attention_roofline` reads, and not the tick's."""
+    the pool pads them (640 lanes), every bucket the rule admits (a grid
+    step is one head against the whole bucket, 2,048 rows in four tiles of
+    512, the chain left in HBM and copied a block of 512 rows at a time: the
+    step's buffers, its expanded block, its state and a tile's scores fit
+    `MLA_SHARED_VMEM_BYTES`, or the compile would refuse); the device
+    event's name is the one `mla_chunk_attention_roofline` reads, and not
+    the tick's."""
     from bpe_transformer_tpu.kernels.pallas.mla_attention import (
         mla_chunk_attention,
         mla_chunk_path,
@@ -968,6 +972,33 @@ def test_mla_chunk_attention(one_chip, queries):
         ((16384, 640), BF16), ((64, 256, 512), BF16), ((queries,), I32),
         ((), I32),
     )
+    assert _mla_kernels(text) == {"mla_chunk_attention"}
+
+
+def test_mla_self_attention_takes_the_chunk_kernel_a_sequence_at_a_time(
+    one_chip, on_tpu
+):
+    """The plain forward's latent sublayer over two sequences of 512 rows at
+    the published widths: the kernel copies a sequence's rows out of HBM
+    itself and so takes no batch axis (`mla.self_attention` maps over the
+    sequences where the rule picks the kernel, and `vmap`s the loop)."""
+    from bpe_transformer_tpu.models import mla
+
+    config = _cell_config("longcat")
+    assert mla.rows_attention_path(512, config) == "mla_chunk"
+    h, attn, positions = _described(
+        (
+            jax.ShapeDtypeStruct((2, 512, config.d_model), BF16),
+            jax.eval_shape(
+                lambda: mla.init_mla_params(jax.random.PRNGKey(0), config, BF16)
+            ),
+            jax.ShapeDtypeStruct((512,), I32),
+        ),
+        one_chip,
+    )
+    text = jax.jit(
+        lambda h, p, positions: mla.self_attention(h, p, positions, config)
+    ).lower(h, attn, positions).compile().as_text()
     assert _mla_kernels(text) == {"mla_chunk_attention"}
 
 
@@ -1471,9 +1502,10 @@ def test_mla_paged_attention_at_a_32k_table(one_chip):
 def test_mla_chunk_attention_at_32k_keys(one_chip, queries):
     """A chunk's expanded latent attention over a slot's gathered chain of
     32,768 rows - twice the keys `test_mla_chunk_attention` compiles, the
-    cell's three buckets, its scale: the grid is (tiles, 64 heads, 32 key
-    blocks), a tile's state stays in VMEM and no (chunk x context) score
-    leaves the kernel (the output is the heads' values alone)."""
+    cell's three buckets, its scale: the grid is the 64 heads, the walk over
+    up to 64 key blocks is inside the step, the bucket's state stays in VMEM
+    and no (chunk x context) score leaves the kernel (the output is the
+    heads' values alone)."""
     from bpe_transformer_tpu.kernels.pallas.mla_attention import (
         mla_chunk_attention,
         mla_chunk_path,

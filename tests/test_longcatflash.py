@@ -402,8 +402,9 @@ def test_the_shared_pass_is_taken_from_six_members(members, taken):
 #: Toy widths that keep `mla_chunk_path`'s lane rule: 2 heads of 128 + 64 /
 #: 128 over a latent of 128.
 CHUNK_HEADS, CHUNK_NOPE, CHUNK_ROPE, CHUNK_V, CHUNK_RANK = 2, 128, 64, 128, 128
-#: name: (first position, key positions any query sees); key blocks are
-#: `MLA_CHUNK_KEY_BLOCK` = 1,024 as shipped, the table holds 3,072 rows.
+#: name: (first position, key positions any query sees); the kernel's key
+#: blocks are `MLA_CHUNK_KERNEL_KEYS` = 512 as shipped (the absorbed loop's
+#: `MLA_CHUNK_KEY_BLOCK` = 1,024), the table holds 3,072 rows.
 CHUNK_CASES = {
     "from_position_0": (0, None),
     "ends_inside_a_key_block": (None, 1500),
@@ -436,6 +437,7 @@ def test_the_chunk_kernel_attends_what_the_loop_and_the_expanded_form_attend(
     """`mla_chunk_attention` (interpret mode) against the absorbed loop and
     against a plain float32 expanded reference, on the chunk's own rows;
     a padded row past them comes out finite and is no one's."""
+    assert mla_attention.MLA_CHUNK_KERNEL_KEYS == 512
     assert mla_attention.MLA_CHUNK_KEY_BLOCK == 1024
     start, n_keys = CHUNK_CASES[case]
     padded = 57 if case == "padded_rows_past_the_chunk" else 0
@@ -477,7 +479,8 @@ def test_the_chunk_kernel_attends_what_the_loop_and_the_expanded_form_attend(
         ("cpu", 1024, (128, 128, 512), "xla"),
         ("gpu", 1024, (128, 128, 512), "xla"),
         ("tpu", 128, (128, 128, 512), "xla"),     # under the row threshold
-        ("tpu", 1536, (128, 128, 512), "xla"),    # no whole tiles of 1,024
+        ("tpu", 1536, (128, 128, 512), "mla_chunk"),  # three tiles of 512
+        ("tpu", 1280, (128, 128, 512), "xla"),    # no whole tiles of 512
         ("tpu", 1024, (8, 8, 8), "xla"),          # this file's test widths
         ("tpu", 1024, (128, 64, 512), "xla"),
         ("tpu", 1024, (128, 128, 192), "xla"),
@@ -488,7 +491,7 @@ def test_the_chunk_takes_the_kernel_on_the_tpu_from_256_aligned_rows(
 ):
     """The constants as the package ships them; the rule both ways."""
     assert mla_attention.MLA_CHUNK_MIN_ROWS == 256
-    assert mla_attention.MLA_CHUNK_TILE_ROWS == 1024
+    assert mla_attention.MLA_CHUNK_TILE_ROWS == 512
     assert mla_attention.mla_chunk_path(queries, *widths, backend=backend) == want
     if backend == "cpu":  # the backend here, asked of jax
         assert mla_attention.mla_chunk_path(queries, *widths) == want

@@ -608,6 +608,84 @@ def test_the_chunk_kernel_attends_expanded_under_the_configs_scale():
     assert float(jnp.max(jnp.abs(other - want))) > 1e-3
 
 
+#: name: (the chunk's first position, rows of the bucket that are padding).
+#: The chain is 4,096 rows long and every case's ``n_keys`` short of it.
+WALK_CASES = {
+    "from_position_0": (0, 0),
+    "inside_a_block": (300, 0),
+    "on_a_blocks_edge": (1024, 0),
+    "padding_rows_past_n_keys": (812, 200),
+}
+
+
+@pytest.mark.parametrize("case", WALK_CASES)
+@pytest.mark.parametrize("queries", [512, 1024, 2048])
+def test_the_chunk_kernels_walk_is_the_pairs_themselves(queries, case):
+    """`mla_attention.chunk_walk`, tile by tile of every bucket of the cell,
+    against the (query, key) pairs: a block under ``clear`` is seen whole by
+    every row, a block from ``clear`` to ``end`` is crossed by an edge, no
+    row of the chunk sees a key from ``end`` on - so the blocks as the kernel
+    folds them, the mask on the crossed ones alone, are the pairs, and their
+    count is the host's `chunk_attn_kernel_pairs`."""
+    start, padding = WALK_CASES[case]
+    chain = 4096
+    tile_rows, block = mla_attention.chunk_tiles(queries, chain)
+    assert (tile_rows, block) == (512, 512)
+    chunk_len = queries - padding
+    positions = start + np.arange(queries)
+    n_keys = start + chunk_len
+    sees = np.arange(chain)[None, :] <= positions[:, None]
+    block_of = np.arange(chain) // block
+    pairs = 0
+    for t in range(queries // tile_rows):
+        rows = slice(t * tile_rows, (t + 1) * tile_rows)
+        lo, hi = int(positions[rows].min()), int(positions[rows].max())
+        clear, end = mla_attention.chunk_walk(lo, hi, n_keys, block)
+        assert 0 <= clear <= end <= -(-n_keys // block)
+        traced = mla_attention.chunk_walk(
+            jnp.int32(lo), jnp.int32(hi), jnp.int32(n_keys), block, jnp.minimum
+        )
+        assert (int(traced[0]), int(traced[1])) == (clear, end)
+        tile, real = sees[rows], positions[rows] < n_keys
+        assert tile[:, block_of < clear].all()
+        for b in range(clear, end):
+            crossed = tile[:, block_of == b]
+            assert crossed.any() and not crossed.all()
+        assert not tile[real][:, block_of >= end].any()
+        folded = (block_of < clear) | ((block_of < end) & tile)
+        assert np.array_equal(folded[real], tile[real])
+        pairs += int(folded[real].sum())
+    assert pairs == chunk_len * start + chunk_len * (chunk_len + 1) // 2
+
+
+@pytest.mark.parametrize("order", ["as_the_engine_pads", "rows_out_of_order"])
+def test_the_chunk_kernel_at_2048_ragged_rows_from_inside_a_block(order):
+    """`mla_chunk_attention` (interpret mode) against the absorbed loop at
+    the cell's largest bucket: 2,048 rows of which 1,847 are the chunk's,
+    from position 812 (inside a key block) over a chain of 4,096 rows, so
+    ``n_keys`` is short of the chain and the padding rows' positions run
+    past it; and with the rows in another order, so that every tile's walk
+    reaches from the first block to the last."""
+    heads, nope, rope, v, rank = 2, 128, 64, 128, 128
+    queries, chunk_len, start, chain = 2048, 1847, 812, 4096
+    rng = np.random.default_rng(50)
+
+    def draw(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    q_nope, q_rope = draw(heads, queries, nope), draw(heads, queries, rope)
+    rows, kv_b = draw(chain, rank + rope), draw(heads, nope + v, rank) * 0.1
+    positions = start + np.arange(queries)
+    if order == "rows_out_of_order":
+        positions = rng.permutation(positions)
+    real = positions < start + chunk_len
+    args = (q_nope, q_rope, rows, kv_b, jnp.asarray(positions), start + chunk_len)
+    got = mla_attention.mla_chunk_attention(*args, scale=0.07, interpret=True)
+    loop = mla_attention.xla_mla_chunk_attention(*args, scale=0.07)
+    assert real.sum() == chunk_len and bool(jnp.isfinite(got).all())
+    assert float(jnp.max(jnp.abs(got - loop)[:, real])) < 2e-5
+
+
 # ---------------------------------------------------------------- refusals
 
 
